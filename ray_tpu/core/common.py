@@ -35,6 +35,10 @@ def normalize_resources(
     if num_gpus:
         out[GPU] = float(num_gpus)
     if num_tpus:
+        if float(num_tpus) != int(num_tpus):
+            raise ValueError(
+                f"num_tpus={num_tpus!r}: TPU grants are whole chips (a chip "
+                "belongs to one process at a time)")
         out[TPU] = float(num_tpus)
     if memory:
         out[MEMORY] = float(memory)
@@ -44,6 +48,29 @@ def normalize_resources(
                 raise ValueError(f"Use num_cpus/num_gpus/num_tpus/memory instead of resources[{k!r}]")
             out[k] = float(v)
     return {k: v for k, v in out.items() if v != 0}
+
+
+# A process that holds chips starts slowly: the backend takes ~9 s to come
+# up on a v5e and, on a cold compile cache, its constructor compiles what
+# it runs (37 s for an LLMServer of Llama-small width; chip_smoke.py leg
+# B, PR 21). Every start-up deadline of such a process — the raylet's
+# wait for the actor constructor, the GCS's create RPC, serve's replica
+# start-up — is this multiple of a CPU worker's.
+CHIP_START_DEADLINE_FACTOR = 5
+
+
+def is_tpu_resource(name: str) -> bool:
+    """`TPU`, or one of its placement-group renamings
+    (`TPU_group_<i>_<pg>` / `TPU_group_<pg>`)."""
+    return name == TPU or name.startswith(TPU + "_group_")
+
+
+def tpu_chips_requested(resources: Dict[str, float]) -> int:
+    """Chips a resource request asks for, whether it names `TPU`
+    directly or through a placement-group bundle (a request carries one
+    of the two names, never both)."""
+    return int(sum(amt for name, amt in resources.items()
+                   if is_tpu_resource(name)))
 
 
 class ActorState(str, Enum):

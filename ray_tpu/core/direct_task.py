@@ -25,7 +25,7 @@ from collections import defaultdict, deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.core import serialization
-from ray_tpu.core.common import TaskSpec
+from ray_tpu.core.common import TPU, TaskSpec
 from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.core.ids import TaskID
 from ray_tpu.core.rpc import ConnectionLost, RpcClient
@@ -123,6 +123,11 @@ class DirectTaskTransport:
         if spec.placement_group_id is not None:
             return False
         if spec.scheduling_strategy is not None:
+            return False
+        if TPU in spec.resources:
+            # A chip-holding worker is single-use (raylet._recycle), so a
+            # lease has no warm worker to reuse, and the classic path is
+            # the one that carries a refused grant back to the caller.
             return False
         for dep in spec.dependencies():
             if not self._dep_ready_local(dep):

@@ -26,12 +26,14 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 
 from ray_tpu.core import serialization
 from ray_tpu.core.common import (
+    CHIP_START_DEADLINE_FACTOR,
     ActorInfo,
     ActorState,
     JobInfo,
     NodeInfo,
     PlacementGroupInfo,
     PlacementStrategy,
+    tpu_chips_requested,
 )
 from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.core.ids import ActorID, JobID, NodeID, ObjectID, PlacementGroupID
@@ -1520,10 +1522,15 @@ class GcsServer:
                 with self._lock:
                     self._inflight_creates[node_id] = \
                         self._inflight_creates.get(node_id, 0) + 1
+                # The raylet waits one lease timeout for the worker to
+                # register and one (more for a chip holder) for __init__.
+                init_factor = CHIP_START_DEADLINE_FACTOR \
+                    if tpu_chips_requested(spec.resources) else 1
                 try:
                     resp = create_client.call(
                         "create_actor", {"spec": spec},
-                        timeout=GLOBAL_CONFIG.worker_lease_timeout_ms / 1000.0 * 2)
+                        timeout=GLOBAL_CONFIG.worker_lease_timeout_ms
+                        / 1000.0 * (1 + init_factor))
                 finally:
                     create_client.close()
                     with self._lock:

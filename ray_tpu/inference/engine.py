@@ -294,6 +294,11 @@ class InferenceEngine:
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._spec_hist = [0] * (self._draft_len + 1)
+        # Which device answers, as jax reports it: stats() carries it so a
+        # caller can tell from outside (chip_smoke.py, benchmarks).
+        from ray_tpu._jax_env import device_info
+
+        self._device = device_info()
         self._build_programs()
         self._last_stats = self._stats_locked()
 
@@ -1107,6 +1112,7 @@ class InferenceEngine:
         running = [r for r in self._slots if r is not None
                    and r.state in (PREFILL, DECODE)]
         return {
+            **self._device,
             "queue_depth": len(self._waiting),
             "running": len(running),
             "tp": self._tp,

@@ -22,7 +22,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import flash_attention_sharded
 
 
 @dataclass(frozen=True)
@@ -95,8 +95,10 @@ class Block(nn.Module):
 
             attn = ring_attention(q, k, v, axis_name="sp", causal=True)
         elif cfg.use_flash:
-            attn = flash_attention(q, k, v, True, None,
-                                   cfg.flash_block_q, cfg.flash_block_k)
+            attn = flash_attention_sharded(
+                q, k, v, nn.logical_to_mesh_axes(("batch", "heads", None,
+                                                  None)),
+                True, None, cfg.flash_block_q, cfg.flash_block_k)
         else:
             from ray_tpu.ops.attention import mha_reference
 
@@ -169,18 +171,7 @@ def mesh_shardings_for(model: nn.Module, mesh,
 
     logical = logical_param_specs(model, sample_shape)
     rule_list = logical_axis_rules(rules, mesh_axes=mesh.axis_names)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-            else _null():
-        resolved = nn.logical_to_mesh_sharding(logical, mesh, rule_list)
-    return resolved
-
-
-class _null:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
+    return nn.logical_to_mesh_sharding(logical, mesh, rule_list)
 
 
 def init_sharded(model: nn.Module, mesh, sample_shape: Tuple[int, int],
@@ -218,9 +209,11 @@ def make_train_step(model: nn.Module, optimizer, mesh=None,
                     donate: bool = True, loss_fn=None):
     """Jitted (params, opt_state, batch) -> (params, opt_state, loss).
 
-    With a mesh: logical axis rules resolve the with_logical_constraint
-    annotations; data enters sharded ("batch" over dp+fsdp, "seq" over sp);
-    XLA places the psums over tp/sp on ICI.
+    With a mesh: the step is traced with it as the context mesh
+    (`parallel.mesh.MeshBound`), so the logical axis rules resolve the
+    with_logical_constraint annotations against it; data enters sharded
+    ("batch" over dp+fsdp, "seq" over sp); XLA places the psums over tp/sp
+    on ICI.
 
     `loss_fn(params, batch) -> (objective, displayed_loss)` customizes the
     training objective (MoE adds router losses to the cross-entropy); the
@@ -252,11 +245,13 @@ def make_train_step(model: nn.Module, optimizer, mesh=None,
         with flax_rules(rules):
             return step(params, opt_state, batch)
 
-    donate_argnums = (0, 1) if donate else ()
-    if mesh is not None:
-        with mesh:
-            return jax.jit(step_with_rules, donate_argnums=donate_argnums)
-    return jax.jit(step_with_rules, donate_argnums=donate_argnums)
+    jitted = jax.jit(step_with_rules,
+                     donate_argnums=(0, 1) if donate else ())
+    if mesh is None:
+        return jitted
+    from ray_tpu.parallel.mesh import MeshBound
+
+    return MeshBound(jitted, mesh)
 
 
 def make_eval_step(model: nn.Module):

@@ -30,7 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import flash_attention, mha_reference
+from ray_tpu.ops.attention import flash_attention_sharded, mha_reference
 
 
 @dataclass(frozen=True)
@@ -173,7 +173,9 @@ class LlamaBlock(nn.Module):
                 attn = ring_attention_sharded(q, kf, vf, cfg.sp_mesh,
                                               causal=True)
             elif cfg.use_flash:
-                attn = flash_attention(q, kf, vf, True)
+                attn = flash_attention_sharded(
+                    q, kf, vf, nn.logical_to_mesh_axes(
+                        ("batch", "heads", None, None)))
             else:
                 attn = mha_reference(q, kf, vf, causal=True)
             new_cache = None

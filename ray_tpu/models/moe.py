@@ -185,9 +185,11 @@ class MoEBlock(nn.Module):
         kf = jnp.repeat(k, groups, axis=1)
         vf = jnp.repeat(v, groups, axis=1)
         if cfg.use_flash:
-            from ray_tpu.ops.attention import flash_attention
+            from ray_tpu.ops.attention import flash_attention_sharded
 
-            attn = flash_attention(q, kf, vf, True)
+            attn = flash_attention_sharded(
+                q, kf, vf, nn.logical_to_mesh_axes(
+                    ("batch", "heads", None, None)))
         else:
             from ray_tpu.ops.attention import mha_reference
 

@@ -11,15 +11,24 @@ Layout: q,k,v [batch, heads, seq, head_dim]; grids put batch*heads and the
 output-block dim as parallel dimensions and stream the contraction dim as
 the innermost "arbitrary" dim with VMEM scratch accumulators.
 
+Dispatch is a rule, not a fallback: on platform `tpu` a call whose shape
+the kernels take goes to the kernels, and a kernel the compiler refuses
+fails the caller's compile. Calls the rule sends to the XLA reference
+(another platform, a shape the kernels do not take) are recorded with the
+reason; `pallas_status()` lists the path of every traced call.
+
 Set RAY_TPU_PALLAS_INTERPRET=1 to run the kernels in interpreter mode on
-CPU (used by tests to cover kernel logic without a chip).
+CPU (used by tests to cover kernel logic without a chip). It is a CPU
+switch and is refused on platform `tpu`.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import os
+import threading
 from typing import Optional
 
 import jax
@@ -33,9 +42,8 @@ def _interpret() -> bool:
     return os.environ.get("RAY_TPU_PALLAS_INTERPRET") == "1"
 
 
-def _compiler_params_cls(pltpu):
-    # jax >= 0.5 renamed TPUCompilerParams -> CompilerParams.
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+def _platform() -> str:
+    return jax.default_backend()
 
 
 def mha_reference(q, k, v, causal: bool = True,
@@ -157,10 +165,11 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q3, k3, v3)
     return out.reshape(batch, heads, seq_q, d), lse[..., :1]
 
@@ -312,10 +321,11 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse, delta)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
@@ -344,10 +354,11 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse, delta)
 
     shape_q = (batch, heads, seq_q, d)
@@ -379,85 +390,57 @@ def pick_block_sizes(seq: int, d: int) -> tuple:
     return bq, bk
 
 
-_PALLAS_STATUS: dict = {}  # (platform, bq, bk, d, dtype) -> bool
-_PALLAS_ERRORS: dict = {}  # same key -> repr of the probe failure
+# (pass, path, reason, shape, dtype, block_q, block_k) -> traced calls
+_CALLS: collections.Counter = collections.Counter()
+_CALLS_LOCK = threading.Lock()
 
 
-def pallas_status() -> dict:
-    """Observability for the kernel self-check: {config-key: ok} plus any
-    probe errors. Empty until the first TPU dispatch attempt."""
-    return {"status": dict(_PALLAS_STATUS), "errors": dict(_PALLAS_ERRORS)}
+def pallas_status() -> list:
+    """Which path every traced attention call of this process took: one
+    entry per distinct (pass, shape, dtype, blocks) with `path` "pallas"
+    or "reference", the dispatch rule's `reason` for a reference call, and
+    the number of traced calls. A caller that asked for flash and needs to
+    know it got flash (chip_smoke.py, bench.py) reads this."""
+    with _CALLS_LOCK:
+        items = list(_CALLS.items())
+    return [{"pass": p, "path": path, "reason": reason, "shape": list(shape),
+             "dtype": dtype, "block_q": bq, "block_k": bk, "calls": n}
+            for (p, path, reason, shape, dtype, bq, bk), n in items]
 
 
-def _pallas_selfcheck(platform: str, block_q: int, block_k: int,
-                      d: int, dtype, causal: bool) -> bool:
-    """Compile+run the kernels once at the exact production configuration
-    (block sizes, head dim, dtype); on any failure disable the Pallas path
-    for that configuration. A lowering bug must degrade to the XLA
-    fallback, never take down training (round-2 postmortem).
-
-    The probe runs in a fresh thread: JAX's trace state is thread-local, so
-    this executes eagerly (and can really catch compile errors) even when
-    the caller is mid-trace inside the user's jit."""
-    key = (platform, block_q, block_k, d, jnp.dtype(dtype).name, causal)
-    if key in _PALLAS_STATUS:
-        return _PALLAS_STATUS[key]
-    import threading
-
-    result = {}
-
-    def probe():
-        try:
-            seq = max(2 * block_k, 2 * block_q)
-            q = jnp.ones((1, 1, seq, d), dtype)
-            out, lse = _flash_forward(q, q, q, causal, 0.125,
-                                      block_q, block_k)
-            grads = _flash_backward(q, q, q, out, lse, out, causal, 0.125,
-                                    block_q, block_k)
-            jax.block_until_ready(grads)
-            result["ok"] = True
-        except Exception as e:  # noqa: BLE001 — any lowering/runtime error
-            result["ok"] = False
-            result["err"] = f"{type(e).__name__}: {e}"
-
-    t = threading.Thread(target=probe, daemon=True)
-    t.start()
-    t.join()
-    _PALLAS_STATUS[key] = result.get("ok", False)
-    if not _PALLAS_STATUS[key]:
-        # Loud degradation: falling back to the O(S^2) XLA path is ~2x
-        # slower and must be diagnosable after the fact.
-        import logging
-
-        _PALLAS_ERRORS[key] = result.get("err", "probe thread died")
-        logging.getLogger("ray_tpu.ops.attention").warning(
-            "Pallas flash-attention self-check FAILED for %s — using the "
-            "XLA fallback for this config: %s", key, _PALLAS_ERRORS[key])
-    return _PALLAS_STATUS[key]
+def reset_pallas_status() -> None:
+    """Forget the calls traced so far (a caller that asserts on one
+    program's calls clears what model init traced before it)."""
+    with _CALLS_LOCK:
+        _CALLS.clear()
 
 
-def _use_pallas(q, k, block_q: int, block_k: int,
-                causal: bool = True) -> bool:
-    if _interpret():
-        ok_platform = True
-    else:
-        try:
-            platform = q.devices().pop().platform if hasattr(q, "devices") \
-                else jax.devices()[0].platform
-        except Exception:
-            platform = jax.default_backend()
-        ok_platform = platform == "tpu" and _pallas_selfcheck(
-            platform, block_q, block_k, q.shape[-1], q.dtype, causal)
-    if not ok_platform:
-        return False
-    _, _, seq_q, d = q.shape
+def _dispatch(pass_: str, q, k, block_q: int, block_k: int) -> bool:
+    """True when the Pallas kernels take this call. Records the decision."""
+    platform = _platform()
+    seq_q, d = q.shape[2], q.shape[3]
     seq_k = k.shape[2]
-    # The kernel's causal mask assumes q and k positions share origin 0,
-    # while mha_reference aligns sequence *ends* (tril k=ks-qs); restrict
-    # the kernel to seq_q == seq_k so both paths agree, and validate k's
-    # sequence length for block divisibility.
-    return (seq_q == seq_k and seq_q % block_q == 0 and seq_k % block_k == 0
-            and d % 64 == 0)
+    if _interpret() and platform == "tpu":
+        raise RuntimeError(
+            "RAY_TPU_PALLAS_INTERPRET=1 is a CPU test switch; on platform "
+            "tpu it would run the interpreter under the kernels' name")
+    if platform != "tpu" and not _interpret():
+        reason = f"platform {platform}"
+    elif seq_q != seq_k:
+        # The kernel's causal mask assumes q and k positions share origin
+        # 0, while mha_reference aligns sequence *ends* (tril k=ks-qs).
+        reason = "seq_q != seq_k"
+    elif seq_q % block_q or seq_k % block_k:
+        reason = "seq not a multiple of the block"
+    elif d % 64:
+        reason = "head_dim not a multiple of 64"
+    else:
+        reason = ""
+    key = (pass_, "reference" if reason else "pallas", reason,
+           tuple(q.shape), jnp.dtype(q.dtype).name, block_q, block_k)
+    with _CALLS_LOCK:
+        _CALLS[key] += 1
+    return not reason
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -485,7 +468,7 @@ def _resolve(q, scale, block_q, block_k):
 
 def _attn_fwd_impl(q, k, v, causal, scale, block_q, block_k):
     scale, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _use_pallas(q, k, bq, bk, causal):
+    if _dispatch("fwd", q, k, bq, bk):
         return _flash_forward(q, k, v, causal, scale, bq, bk)
     return mha_reference(q, k, v, causal=causal, scale=scale), None
 
@@ -498,7 +481,7 @@ def _attn_fwd(q, k, v, causal, scale, block_q, block_k):
 def _attn_bwd(causal, scale, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
     scale_v, bq, bk = _resolve(q, scale, block_q, block_k)
-    if lse is not None and _use_pallas(q, k, bq, bk, causal):
+    if _dispatch("bwd", q, k, bq, bk):
         return _flash_backward(q, k, v, out, lse, g, causal, scale_v, bq, bk)
     _, vjp = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal, scale),
                      q, k, v)
@@ -506,3 +489,24 @@ def _attn_bwd(causal, scale, block_q, block_k, residuals, g):
 
 
 flash_attention.defvjp(_attn_fwd, _attn_bwd)
+
+
+def flash_attention_sharded(q, k, v, spec, causal: bool = True,
+                            scale: Optional[float] = None,
+                            block_q: int = 0, block_k: int = 0) -> jax.Array:
+    """`flash_attention` inside a partitioned jit.
+
+    The partitioner cannot split a Pallas custom call: left bare it
+    gathers q/k/v and every device runs the whole batch. `spec` is the
+    PartitionSpec of the [batch, heads, seq, head_dim] operands over the
+    context mesh (`jax.set_mesh`); each device then runs the kernels on its
+    own [b/dp, h/tp, s, d] shard. The sequence dim stays whole — sharding
+    it is ring attention's job. Without a context mesh, or with nothing to
+    shard, this is `flash_attention`."""
+    def local(q, k, v):
+        return flash_attention(q, k, v, causal, scale, block_q, block_k)
+
+    if jax.sharding.get_abstract_mesh().empty or not any(spec):
+        return local(q, k, v)
+    return jax.shard_map(local, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)

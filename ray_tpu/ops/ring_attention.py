@@ -169,12 +169,5 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = True,
     spec = P(data_axes, None, sp_axis)
     body = partial(ring_attention, axis_name=sp_axis, causal=causal,
                    scale=scale)
-    try:
-        fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _legacy
-
-        fn = _legacy(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)
-    return fn(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -99,11 +99,7 @@ def build_mesh(spec: MeshSpec, devices: Optional[Sequence] = None):
         ici = [n for n in names if n not in spec.dcn_axes]
         names = tuple(dcn + ici)
         shape = tuple(axes[n] for n in names)
-    try:
-        platform = devices[0].platform
-    except Exception:
-        platform = "cpu"
-    if platform == "tpu":
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
         if spec.dcn_axes:
@@ -116,6 +112,32 @@ def build_mesh(spec: MeshSpec, devices: Optional[Sequence] = None):
     else:
         mesh_devices = np.asarray(devices).reshape(shape)
     return jax.sharding.Mesh(mesh_devices, names)
+
+
+class MeshBound:
+    """A jitted function traced and run with `mesh` as jax's context mesh.
+
+    flax resolves `with_logical_constraint` against the context mesh at
+    trace time, and `jax.shard_map` without a mesh argument maps over it;
+    `jax.set_mesh` cannot be entered inside a trace, so it wraps the call.
+    Without it every activation constraint in models/ compiles to nothing.
+    `lower` is exposed for callers that inspect the compiled program."""
+
+    def __init__(self, jitted, mesh):
+        self._jitted = jitted
+        self.mesh = mesh
+
+    def __call__(self, *args, **kwargs):
+        import jax
+
+        with jax.set_mesh(self.mesh):
+            return self._jitted(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        import jax
+
+        with jax.set_mesh(self.mesh):
+            return self._jitted.lower(*args, **kwargs)
 
 
 def local_mesh(axis_name: str = "dp"):

@@ -68,11 +68,6 @@ def gpipe(layer_fn: Callable[[Any, jnp.ndarray], jnp.ndarray], mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
-
     def stage_fn(stage_params, x):
         # Scan this stage's local layers in order.
         def body(h, p):
@@ -119,13 +114,9 @@ def gpipe(layer_fn: Callable[[Any, jnp.ndarray], jnp.ndarray], mesh,
 
     def run(staged_params, x_mb):
         in_specs = (jax.tree.map(lambda _: P(axis), staged_params), x_spec)
-        try:
-            mapped = shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                               out_specs=x_spec, check_vma=False)
-        except TypeError:  # pragma: no cover — older jax uses check_rep
-            mapped = shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                               out_specs=x_spec, check_rep=False)
-        return mapped(staged_params, x_mb)
+        return jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
+                             out_specs=x_spec, check_vma=False)(
+                                 staged_params, x_mb)
 
     return run
 
@@ -243,8 +234,7 @@ def make_pp_train_step(mesh, n_head: int, n_microbatches: int,
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    with mesh:
-        return jax.jit(step), forward
+    return jax.jit(step), forward
 
 
 def _pp_block(p, x, n_head):

@@ -65,9 +65,6 @@ class PolicyServer:
                  explore: bool = True, seed: int = 0):
         from collections import deque
 
-        from ray_tpu._jax_env import apply_jax_platform_env
-
-        apply_jax_platform_env()
         import jax
 
         self.module = module

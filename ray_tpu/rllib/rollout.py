@@ -29,17 +29,13 @@ class RolloutWorker:
     def __init__(self, env: Any, n_envs: int = 8, seed: int = 0,
                  hidden=(64, 64), module: Optional[Any] = None,
                  jax_platform: Optional[str] = None, connectors: Any = None):
-        import os
-
-        from ray_tpu._jax_env import apply_jax_platform_env
+        import jax
 
         if jax_platform:
             # Samplers are tiny MLP forwards: pin them to host CPU so the
             # chip belongs to the learner (one JAX process per chip —
             # SURVEY.md §7 TPU process model).
-            os.environ["RAY_TPU_JAX_PLATFORM"] = jax_platform
-        apply_jax_platform_env()
-        import jax
+            jax.config.update("jax_platforms", jax_platform)
 
         self.env = make_env(env, n_envs=n_envs, seed=seed,
                             connectors=connectors)

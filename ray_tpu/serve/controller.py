@@ -25,6 +25,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.chaos.deadline import TransitionWatch
+from ray_tpu.core.common import CHIP_START_DEADLINE_FACTOR
 from ray_tpu.serve.config import (
     REPLICA_RUNNING,
     REPLICA_STARTING,
@@ -71,6 +72,8 @@ class _ReplicaInfo:
         # the replica's health stats and pushed in the routing table so
         # routers can prefer replicas that already hold an adapter.
         self.adapters: List[str] = []
+        # Consecutive health checks a LIVE replica answered too slowly.
+        self.slow_checks = 0
 
 
 class _DeploymentInfo:
@@ -834,10 +837,12 @@ class ServeController:
                         logger.info(
                             "serve: %s cold start served in %.0fms",
                             name, info.last_cold_start_ms)
+            startup_timeout_s = info.config.replica_startup_timeout_s * (
+                CHIP_START_DEADLINE_FACTOR
+                if info.config.ray_actor_options.get("num_tpus") else 1)
             if rep.state == REPLICA_STARTING and (
                     state == "dead"
-                    or time.time() - rep.started_at
-                    > info.config.replica_startup_timeout_s):
+                    or time.time() - rep.started_at > startup_timeout_s):
                 logger.warning(
                     "serve: replica %s of %s failed to start — "
                     "replacing", rep.replica_id, name)
@@ -875,9 +880,23 @@ class ServeController:
             for rep, st in zip(list(info.replicas), stats):
                 if rep.state != REPLICA_RUNNING:
                     continue
-                if st is None:
+                if st is _SLOW:
+                    # Alive but late: a replica tracing and compiling a
+                    # model of real width starves its other threads of
+                    # the GIL for seconds at a time (first seen on the
+                    # chip: a 12-layer engine's first request got its
+                    # replica replaced mid-compile). Only a run of late
+                    # answers is a hung replica.
+                    rep.slow_checks += 1
+                    logger.info("serve: replica %s of %s answered its "
+                                "health check late (%d/%d)", rep.replica_id,
+                                name, rep.slow_checks, _SLOW_CHECKS_TO_REPLACE)
+                    if rep.slow_checks >= _SLOW_CHECKS_TO_REPLACE:
+                        dead.append(rep)
+                elif st is None:
                     dead.append(rep)
                 else:
+                    rep.slow_checks = 0
                     # Deployment-exported backlog (__serve_metrics__,
                     # e.g. the inference engine's queued + running
                     # sequences) counts as pressure: streamed
@@ -1259,8 +1278,15 @@ def _try_ping(handle, timeout_s: float) -> tuple:
         return "dead", ""
 
 
+# A live replica's health answer that did not arrive in time, and how many
+# in a row make it a hung replica (a dead actor is replaced at once).
+_SLOW = object()
+_SLOW_CHECKS_TO_REPLACE = 5
+
+
 def _gather_stats(replicas) -> list:
     import ray_tpu
+    from ray_tpu.exceptions import GetTimeoutError
 
     runtime = ray_tpu._require_runtime()
     refs, out = [], []
@@ -1284,6 +1310,8 @@ def _gather_stats(replicas) -> list:
             continue
         try:
             out.append(ray_tpu.get(ref, timeout=1.0))
+        except GetTimeoutError:
+            out.append(_SLOW)
         except Exception:  # noqa: BLE001
             out.append(None)
     # Gang liveness rides the same health check: a group whose rank 0
@@ -1291,7 +1319,8 @@ def _gather_stats(replicas) -> list:
     # controller then kills and restarts the gang as one unit (any rank
     # death is a group death; docs/SHARDED.md failure semantics).
     for i, rep in enumerate(replicas):
-        if out[i] is not None and rep.group is not None:
+        if out[i] is not None and out[i] is not _SLOW \
+                and rep.group is not None:
             # Rank 0 already answered stats above — sweep only ranks > 0.
             if rep.group.dead_ranks(timeout_s=1.0,
                                     indices=range(1, rep.group.world_size)):

@@ -16,11 +16,14 @@ import asyncio
 import functools
 import inspect
 import json
+import logging
 import time
 from typing import Any, Dict, List, Tuple
 
 from ray_tpu.observability import tracing as _tracing
 from ray_tpu.serve import dataplane
+
+logger = logging.getLogger(__name__)
 
 
 class Replica:
@@ -42,7 +45,22 @@ class Replica:
             from ray_tpu import shardgroup
 
             self._shard_ctx = shardgroup.activate(shard_ctx)
+        from ray_tpu import _jax_env
+
+        t0 = time.time()
+        if _jax_env.granted_tpu_chips():
+            # A replica that holds chips: compile cache on before the
+            # deployment's first program, and start-up fails unless jax
+            # shows exactly the granted chips (never a silent CPU replica).
+            logger.info("replica %s of %s on %s (backend up in %.1fs)",
+                        replica_id, deployment_name,
+                        _jax_env.claim_devices(), time.time() - t0)
         self._user = user_cls(*init_args, **(init_kwargs or {}))
+        # Against the raylet's actor-creation deadline
+        # (worker_lease_timeout_ms): a constructor that compiles on a
+        # cold cache spends most of it here.
+        logger.info("replica %s of %s constructed in %.1fs", replica_id,
+                    deployment_name, time.time() - t0)
         self._asgi_app = self._resolve_asgi_app(user_cls)
         self._ongoing = 0
         self._processed = 0

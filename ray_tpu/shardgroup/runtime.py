@@ -74,9 +74,10 @@ def _platform_is_cpu() -> bool:
     counts as NOT-cpu — on a TPU pod nothing pins the platform and the
     SPMD path must not silently degrade; a bare-CPU process with no env
     hits the initialize_distributed fallback below instead."""
-    plat = (os.environ.get("RAY_TPU_JAX_PLATFORM")
-            or os.environ.get("JAX_PLATFORMS") or "")
-    return "cpu" in plat.lower()
+    # The first platform listed is jax's default backend: a TPU-granted
+    # worker runs with "tpu,cpu".
+    plat = os.environ.get("JAX_PLATFORMS") or ""
+    return plat.split(",")[0].strip().lower() == "cpu"
 
 
 def _kv():
@@ -186,10 +187,8 @@ def _build_stage_mesh(ctx: ShardContext):
         return None
     import jax
 
-    from ray_tpu._jax_env import apply_jax_platform_env
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
-    apply_jax_platform_env()
     devices = jax.devices()
     need = 1
     for size in axes.values():

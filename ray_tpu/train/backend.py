@@ -13,6 +13,7 @@ import logging
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
+from ray_tpu._jax_env import claim_devices
 from ray_tpu.parallel.mesh import MeshSpec
 
 logger = logging.getLogger(__name__)
@@ -85,21 +86,11 @@ def _mesh_builder_for(spec: Optional[MeshSpec]):
     return build
 
 
-def _enable_compile_cache():
-    from ray_tpu._jax_env import enable_compilation_cache
-
-    enable_compilation_cache()
-    return True
-
-
 class JaxBackend(Backend):
     def on_start(self, worker_group, backend_config: JaxConfig):
         world = len(worker_group)
         if backend_config.force_platform:
             worker_group.execute(_set_platform, backend_config.force_platform)
-        # Persistent XLA compilation cache on every train worker: repeated
-        # fits (tune trials, restarts, bench re-runs) skip cold compiles.
-        worker_group.execute(_enable_compile_cache)
         distributed = backend_config.distributed
         if distributed is None:
             distributed = world > 1
@@ -119,6 +110,13 @@ class JaxBackend(Backend):
             refs = [w.execute.remote(_init_jax_distributed, coordinator, world, rank)
                     for rank, w in enumerate(worker_group.workers)]
             ray_tpu.get(refs)
+        # Every train worker: persistent compile cache on (repeated fits,
+        # tune trials and restarts skip cold compiles), and a worker that
+        # holds a TPU grant fails here unless jax shows it exactly the
+        # granted chips. After the process group forms: this starts the
+        # backend, which jax.distributed must precede.
+        devices = worker_group.execute(claim_devices)
+        logger.info("train workers on %s", devices)
 
     def mesh_builder(self, backend_config: JaxConfig):
         return _mesh_builder_for(backend_config.mesh)

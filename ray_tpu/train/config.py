@@ -19,8 +19,10 @@ class ScalingConfig:
     """How many workers, with what resources, over what mesh.
 
     - `num_workers`: training worker processes (one JAX process per TPU host).
-    - `use_tpu` + `tpus_per_worker`: grants TPU chips; workers get
-      `TPU_VISIBLE_CHIPS`-style isolation.
+    - `use_tpu` + `tpus_per_worker`: grants TPU chips. A worker is granted
+      one chip (it sees exactly that chip, via `TPU_VISIBLE_CHIPS`) or its
+      whole host; other partial sets are refused. The worker fails at
+      start unless jax shows it exactly the granted chips.
     - `topology`: pod slice name ("v4-32", "v5e-16") — when set, overrides
       num_workers/tpus_per_worker from the slice's host layout.
     - `mesh`: logical mesh spec laid over all granted chips.

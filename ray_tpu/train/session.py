@@ -9,6 +9,7 @@ hands the worker its slice-wide `jax.sharding.Mesh` built by the JaxBackend.
 from __future__ import annotations
 
 import queue
+import sys
 import threading
 from typing import Any, Dict, Optional
 
@@ -48,6 +49,7 @@ class _TrainSession:
         self._collective_factory = collective_factory
         self._collective = None
         self._collective_lock = threading.Lock()
+        self._reported = False
 
     def collective(self):
         """This worker's handle on the run-wide host collective group
@@ -70,7 +72,16 @@ class _TrainSession:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None):
-        self.result_queue.put({"metrics": dict(metrics), "checkpoint": checkpoint})
+        metrics = dict(metrics)
+        if not self._reported and "jax" in sys.modules:
+            # The first report of a jax loop says which device produced
+            # its numbers (platform, device_kind, n_devices); the loop's
+            # own keys win.
+            from ray_tpu._jax_env import device_info
+
+            metrics = {**device_info(), **metrics}
+        self._reported = True
+        self.result_queue.put({"metrics": metrics, "checkpoint": checkpoint})
 
     def get_dataset_shard(self, name: str = "train"):
         ds = self.datasets.get(name)

@@ -32,9 +32,6 @@ class TrainWorker:
         self.world_size = world_size
         if env:
             os.environ.update(env)
-        from ray_tpu._jax_env import apply_jax_platform_env
-
-        apply_jax_platform_env()
         self._session: Optional[_TrainSession] = None
         self._thread: Optional[threading.Thread] = None
 

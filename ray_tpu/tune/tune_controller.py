@@ -39,9 +39,6 @@ class _TrialActor:
 
     def run(self, fn: Callable, config: Dict[str, Any],
             checkpoint_path: Optional[str], trial_id: str):
-        from ray_tpu._jax_env import apply_jax_platform_env
-
-        apply_jax_platform_env()
         from ray_tpu.train.session import TrainContext, _TrainSession, init_session
 
         checkpoint = Checkpoint.from_directory(checkpoint_path) \

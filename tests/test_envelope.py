@@ -18,7 +18,6 @@ def test_envelope_smoke():
             "bench._envelope_main(60, 4, 3, 40, 8)))")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["RAY_TPU_JAX_PLATFORM"] = "cpu"
     env["RAY_TPU_WORKER_LEASE_TIMEOUT_MS"] = "180000"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,

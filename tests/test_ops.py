@@ -55,6 +55,37 @@ def test_cross_attention_seq_mismatch_uses_reference_convention():
                                atol=2e-5, rtol=2e-5)
 
 
+def test_dispatch_rule_is_visible_and_never_a_fallback(monkeypatch):
+    """The path of every traced call is in pallas_status(); on platform
+    tpu a kernel that fails to lower fails the caller, and the interpret
+    switch is refused."""
+    from ray_tpu.ops import attention
+
+    q, k, v = _qkv(jax.random.PRNGKey(3), b=1, h=1, s=128)
+    attention.reset_pallas_status()
+    flash_attention(q, k, v, True)
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    flash_attention(q[:, :, :32], k, v, True)   # gated: seq_q != seq_k
+    flash_attention(q, k, v, True, None, 128, 128)
+    seen = {(e["path"], e["reason"], tuple(e["shape"]))
+            for e in attention.pallas_status()}
+    assert seen == {("reference", "platform cpu", (1, 1, 128, 64)),
+                    ("reference", "seq_q != seq_k", (1, 1, 32, 64)),
+                    ("pallas", "", (1, 1, 128, 64))}
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU test switch"):
+        flash_attention(q, k, v, True)
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+
+    def refused(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(attention, "_flash_forward", refused)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        flash_attention(q, k, v, True)
+
+
 def test_pallas_kernels_interpret_mode(monkeypatch):
     """Run the actual Pallas fwd+bwd kernels (interpreter) vs XLA."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")

@@ -5,10 +5,11 @@ hygiene after node stop (the /proc-scan idiom from the JobManager tests).
 Process model under test: ONE template per driver process (shared by
 every in-process raylet, reused across clusters), carrying a
 ``--tag rtpuforge-<driver pid>`` argv marker that every forked worker
-inherits. The template itself legitimately lingers after Node.stop (it
-self-exits on idle or parent death); its CHILDREN — the forked workers —
-must not, and cold workers carry RAY_TPU_SESSION in their exec-time
-environ for the same scan."""
+inherits. The template itself legitimately lingers after Node.stop (its
+driver stops it at interpreter exit; it self-exits on idle or when the
+driver was killed); its CHILDREN — the forked workers — must not, and
+cold workers carry RAY_TPU_SESSION in their exec-time environ for the
+same scan."""
 
 import os
 import subprocess
@@ -249,3 +250,29 @@ def test_template_dies_with_driver():
         time.sleep(0.25)
     assert _template_pids(tag) == [], \
         "template outlived its driver process"
+
+
+def test_no_process_left_when_driver_exits():
+    """A driver that ends normally has stopped everything it started by
+    the time it is gone (kill_templates at interpreter exit): nothing with
+    its tag or its session is alive at the instant it returns — not a
+    second later, which is all the ppid guard alone could promise."""
+    code = (
+        "import ray_tpu\n"
+        "ray_tpu.init(num_cpus=1)\n"
+        "raylet = ray_tpu._global_node.raylet\n"
+        "raylet.forge.wait_ready(60)\n"
+        "@ray_tpu.remote\n"
+        "def f():\n"
+        "    return 1\n"
+        "assert ray_tpu.get(f.remote(), timeout=60) == 1\n"
+        "from ray_tpu.core.worker_forge import process_tag\n"
+        "print(process_tag(), raylet.session_suffix, flush=True)\n")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-500:]
+    tag, mark = proc.stdout.strip().splitlines()[-1].split()
+    assert _template_pids(tag) == [] and _session_worker_pids(mark) == [], \
+        "a process outlived its driver's normal exit"
