@@ -1,0 +1,6 @@
+"""Roofline share of the `flash_fwd` kernel at the train shape."""
+from benchmarks.layer_metrics._common import flash_roofline_pct
+
+
+def read(facts):
+    return flash_roofline_pct(facts, "flash_fwd")
