@@ -35,7 +35,11 @@ which reuses the same programs with fewer invocations.
 The engine core is synchronous and single-threaded (`step()`); tests drive
 it directly. `EngineLoop` runs it on a background thread and is what the
 Serve deployment (`api.py`) uses; token/finish callbacks are fired outside
-the engine lock so they may bounce into an asyncio loop safely.
+the engine lock so they may bounce into an asyncio loop safely. The
+stepping thread is always in exactly one named step phase (`PHASES`
+below): the phases feed the step ledger in `stats()["steps"]` and, while
+a profile is being taken, the profiler's own trace
+(docs/OBSERVABILITY.md, "Step phases").
 
 `scheduling="static"` emulates the request-level `@serve.batch` baseline
 (gang admission, batch drains at the speed of its longest member, results
@@ -55,6 +59,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from ray_tpu.inference.kv_cache import BlockManager, RadixPrefixCache
 from ray_tpu.observability import tracing as _tracing
+from ray_tpu.observability.phases import PhaseClock
 
 logger = logging.getLogger(__name__)
 
@@ -65,6 +70,22 @@ DECODE = "DECODE"        # in a slot, emitting one token per step
 FINISHED = "FINISHED"
 FAILED = "FAILED"
 _DONE_HOLD = "DONE_HOLD"  # static mode: finished but holding its gang slot
+
+# Step phases (docs/OBSERVABILITY.md): the engine thread is in exactly one
+# of these at any moment, never two. Not to be confused with the
+# per-request spans engine.queue/prefill/decode/deliver/preempt.
+WAIT_WORK = "engine.wait_work"          # EngineLoop parked, nothing to do
+ADMIT = "engine.admit"                  # lock wait, gang release, _admit
+PREFILL_HOST = "engine.prefill.host"    # block claim, arrays, block table
+PREFILL_DISPATCH = "engine.prefill.dispatch"   # the jitted call returns
+PREFILL_SYNC = "engine.prefill.sync"    # int(nxt[0]) on a final chunk
+DECODE_HOST = "engine.decode.host"
+DECODE_DISPATCH = "engine.decode.dispatch"     # spec: draft and verify
+DECODE_SYNC = "engine.decode.sync"      # np.asarray(nxt)
+DECODE_EMIT = "engine.decode.emit"      # per-row bookkeeping
+CALLBACKS = "engine.callbacks"          # on_token/on_finish, lock released
+PHASES = (WAIT_WORK, ADMIT, PREFILL_HOST, PREFILL_DISPATCH, PREFILL_SYNC,
+          DECODE_HOST, DECODE_DISPATCH, DECODE_SYNC, DECODE_EMIT, CALLBACKS)
 
 
 @dataclass(frozen=True)
@@ -112,7 +133,10 @@ class Request:
     preemptions: int = 0
     submitted_at: float = 0.0
     admitted_at: Optional[float] = None    # first batch-slot admission
-    first_token_at: Optional[float] = None
+    first_token_at: Optional[float] = None   # token computed
+    # Taken just before this request's first on_token runs: behind
+    # first_token_at by whatever the step still had to do (a decode).
+    first_token_delivered_at: Optional[float] = None
     finished_at: Optional[float] = None
     # Trace context captured at submission: the engine's queue/prefill/
     # decode phase spans (a TTFT decomposition) re-parent to it.
@@ -282,8 +306,13 @@ class InferenceEngine:
         self._finished = 0
         self._failed = 0
         self._preemptions = 0
-        self._recomputed_tokens = 0
-        self._started_at: Optional[float] = None
+        # The step ledger: written by the stepping thread alone, published
+        # whole at the end of every step so that step_stats() needs no
+        # lock and never sees half a step.
+        self._clock = PhaseClock(PHASES)
+        self._ledger = {"n": 0, "decode": 0, "prefill": 0, "decode_rows": 0,
+                        "wall_s": 0.0}
+        self._publish_steps()
         self._rate_window: List[tuple] = []   # (t, n) recent emissions
         self._shapes = {"prefill": set(), "decode": set(),
                         "draft_prefill": set(), "propose": set(),
@@ -301,6 +330,7 @@ class InferenceEngine:
         self._device = device_info()
         self._build_programs()
         self._last_stats = self._stats_locked()
+        self._last_stats_at = time.monotonic()
 
     # ----------------------------------------------------------- programs
 
@@ -513,8 +543,6 @@ class InferenceEngine:
             # batch, FIFO within a class.
             self._waiting.append(req)
             self._waiting.sort(key=self._prio)
-            if self._started_at is None:
-                self._started_at = time.monotonic()
         return req
 
     def cancel(self, request_id: str) -> bool:
@@ -529,11 +557,7 @@ class InferenceEngine:
             if req.state == WAITING:
                 self._waiting.remove(req)
             self._finish(req, emissions, error="cancelled")
-        for fn, args in emissions:
-            try:
-                fn(*args)
-            except Exception:  # noqa: BLE001
-                pass
+        self._deliver(emissions)
         return True
 
     def has_work(self) -> bool:
@@ -548,22 +572,47 @@ class InferenceEngine:
     def step(self) -> bool:
         """One scheduler iteration: admit, one prefill chunk, one decode
         step. Returns whether any work ran. Callbacks fire after the lock
-        is released (they may hop into an asyncio loop)."""
+        is released (they may hop into an asyncio loop). One thread steps
+        an engine (the EngineLoop's, or a test's): the phase clock and
+        the step ledger are that thread's."""
+        clock = self._clock
+        t0 = time.perf_counter()
         emissions: List[tuple] = []
-        with self._lock:
-            self._release_static_gang(emissions)
-            self._admit()
-            ran = self._prefill_step(emissions)
-            if self._draft_len > 0:
-                ran = self._spec_decode_step(emissions) or ran
-            else:
-                ran = self._decode_step(emissions) or ran
-        for fn, args in emissions:
+        try:
+            clock.enter(ADMIT)
+            with self._lock:
+                self._release_static_gang(emissions)
+                self._admit()
+                ran = self._prefill_step(emissions)
+                if self._draft_len > 0:
+                    ran = self._spec_decode_step(emissions) or ran
+                else:
+                    ran = self._decode_step(emissions) or ran
+            clock.enter(CALLBACKS)
+            self._deliver(emissions)
+        finally:
+            clock.leave()
+            self._ledger["wall_s"] += time.perf_counter() - t0
+        self._ledger["n"] += bool(ran)
+        self._publish_steps()
+        return ran
+
+    def _deliver(self, emissions) -> None:
+        """Run the callbacks a step (or cancel, or fail_all) collected,
+        outside the lock, in order: (on_token or None, req, token), or
+        (on_finish, req, None)."""
+        for fn, req, token in emissions:
             try:
-                fn(*args)
+                if token is None:
+                    fn(req)
+                    continue
+                if req.first_token_delivered_at is None:
+                    req.first_token_delivered_at = time.monotonic()
+                    self._record_deliver_span(req)
+                if fn is not None:
+                    fn(req, token)
             except Exception:  # noqa: BLE001 — user callback must not
                 pass           # take down the scheduler
-        return ran
 
     def run_until_idle(self, max_steps: int = 10000) -> int:
         """Drive the loop synchronously (tests / offline batch); returns
@@ -651,9 +700,6 @@ class InferenceEngine:
             req.cached_tokens += matched_tokens
             if req.admitted_at is None:
                 req.admitted_at = time.monotonic()
-            if req.generated:
-                self._recomputed_tokens += max(
-                    0, req.total_to_prefill - matched_tokens)
             self._slots[req.slot] = req
 
     # ---------------------------------------------------------- preemption
@@ -723,6 +769,8 @@ class InferenceEngine:
         cands = [r for r in self._scheduled() if r.state == PREFILL]
         if not cands:
             return False
+        clock = self._clock
+        clock.enter(PREFILL_HOST)
         req = min(cands, key=self._prio)   # interactive first, then oldest
         total = req.total_to_prefill
         chunk = min(cfg.prefill_chunk, total - req.processed)
@@ -736,6 +784,7 @@ class InferenceEngine:
         bt = self._block_table_rows([req])
         args = (ids, bt, np.asarray([req.processed], np.int32), wmask,
                 np.asarray([chunk - 1], np.int32))
+        clock.enter(PREFILL_DISPATCH)
         if self._adapters is not None:
             aidx = np.asarray([req.adapter_row], np.int32)
             nxt, self._arenas = self._call(
@@ -752,9 +801,14 @@ class InferenceEngine:
             self._draft_arenas = self._call(
                 "draft_prefill", self._draft_prefill_fn,
                 self._draft_params, self._draft_arenas, *args[:4])
+        clock.enter(PREFILL_HOST)
         req.processed += chunk
+        self._ledger["prefill"] += 1
         if req.processed >= total:
-            self._emit_token(req, int(nxt[0]), emissions)
+            clock.enter(PREFILL_SYNC)
+            token = int(nxt[0])
+            clock.enter(PREFILL_HOST)
+            self._emit_token(req, token, emissions)
         return True
 
     # -------------------------------------------------------------- decode
@@ -763,6 +817,8 @@ class InferenceEngine:
         import numpy as np
 
         cfg = self.config
+        clock = self._clock
+        clock.enter(DECODE_HOST)
         active: List[Request] = []
         for req in list(self._scheduled()):
             if req.state != DECODE:
@@ -789,6 +845,7 @@ class InferenceEngine:
             pos[i] = req.processed
             wmask[i, 0] = True
         bt = self._block_table_rows(rows)
+        clock.enter(DECODE_DISPATCH)
         if self._adapters is not None:
             aidx = np.zeros(B, np.int32)
             for req in active:
@@ -800,7 +857,11 @@ class InferenceEngine:
             nxt, self._arenas = self._call(
                 "decode", self._decode_fn, self._params, self._arenas,
                 toks, bt, pos, wmask)
+        clock.enter(DECODE_SYNC)
         nxt = np.asarray(nxt)
+        clock.enter(DECODE_EMIT)
+        self._ledger["decode"] += 1
+        self._ledger["decode_rows"] += len(active)
         for req in active:
             req.processed += 1
             self._emit_token(req, int(nxt[req.slot]), emissions)
@@ -823,6 +884,8 @@ class InferenceEngine:
 
         cfg = self.config
         k = self._draft_len
+        clock = self._clock
+        clock.enter(DECODE_HOST)
         active: List[tuple] = []
         for req in list(self._scheduled()):
             if req.state != DECODE:
@@ -849,10 +912,13 @@ class InferenceEngine:
             pos[i] = req.processed
             wmask_seq[:allow + 1, i, 0] = True
         bt = self._block_table_rows(rows)
+        clock.enter(DECODE_DISPATCH)
         props, self._draft_arenas = self._call(
             "propose", self._propose_fn, self._draft_params,
             self._draft_arenas, toks, bt, pos, wmask_seq)
+        clock.enter(DECODE_SYNC)
         props = np.asarray(props)               # [B, k+1]; col j = d_{j+1}
+        clock.enter(DECODE_HOST)
         vtoks = np.zeros((B, k + 1), np.int32)
         vmask = np.zeros((B, k + 1), bool)
         for req, allow in active:
@@ -860,6 +926,7 @@ class InferenceEngine:
             vtoks[i, 0] = req.cur_token
             vtoks[i, 1:] = props[i, :k]
             vmask[i, :allow + 1] = True
+        clock.enter(DECODE_DISPATCH)
         if self._adapters is not None:
             aidx = np.zeros(B, np.int32)
             for req, _ in active:
@@ -871,7 +938,11 @@ class InferenceEngine:
             tgt, self._arenas = self._call(
                 "verify", self._verify_fn, self._params, self._arenas,
                 vtoks, bt, pos, vmask)
+        clock.enter(DECODE_SYNC)
         tgt = np.asarray(tgt)                   # [B, k+1] target argmaxes
+        clock.enter(DECODE_EMIT)
+        self._ledger["decode"] += 1
+        self._ledger["decode_rows"] += len(active)
         for req, allow in active:
             i = req.slot
             a = 0
@@ -940,12 +1011,12 @@ class InferenceEngine:
             # engine must not grow a tuple per token forever.
             while self._rate_window and now - self._rate_window[0][0] > 5.0:
                 self._rate_window.pop(0)
-            if req.on_token is not None:
-                emissions.append((req.on_token, (req, payload)))
+            # Queued even with no callback: delivery is stamped there.
+            emissions.append((req.on_token, req, payload))
         else:  # finish
             req.finished_at = time.monotonic()
             if req.on_finish is not None:
-                emissions.append((req.on_finish, (req,)))
+                emissions.append((req.on_finish, req, None))
 
     def _finish(self, req: Request, emissions, error: Optional[str] = None):
         req.state = FAILED if error else FINISHED
@@ -1026,11 +1097,7 @@ class InferenceEngine:
             # warm radix tree pointing at zeroed KV would serve garbage.
             if self._prefix is not None:
                 self._prefix.clear()
-        for fn, args in emissions:
-            try:
-                fn(*args)
-            except Exception:  # noqa: BLE001
-                pass
+        self._deliver(emissions)
         return failed
 
     def _release_static_gang(self, emissions):
@@ -1055,6 +1122,18 @@ class InferenceEngine:
         req.slot = None
         self._fire(req, ("finish", None), emissions)
         self._record_phase_spans(req)
+
+    def _record_deliver_span(self, req: Request):
+        """engine.deliver: first token computed -> its on_token about to
+        run. It overlaps the head of engine.decode: the rest of the step
+        that computed the token (a whole decode step, when one shared the
+        step with the final prefill chunk)."""
+        if not _tracing._ENABLED or req.trace_ctx is None:
+            return
+        _tracing.get_tracer().record_span(
+            "engine.deliver", _tracing.epoch_of(req.first_token_at),
+            _tracing.epoch_of(req.first_token_delivered_at),
+            parent_ctx=req.trace_ctx, attrs={"request": req.request_id})
 
     def _record_phase_spans(self, req: Request):
         """TTFT decomposition, recorded once per finished request under
@@ -1094,14 +1173,36 @@ class InferenceEngine:
         """Engine statistics. Non-blocking: a step mid-XLA-compile can
         hold the engine lock for seconds, and the replica's health check
         (stats with a 1s timeout) must not read that as a dead replica —
-        fall back to the last snapshot instead of parking."""
-        if not self._lock.acquire(timeout=0.2):
-            return dict(self._last_stats)
-        try:
-            self._last_stats = self._stats_locked()
-            return dict(self._last_stats)
-        finally:
-            self._lock.release()
+        fall back to the last snapshot instead of parking, and say how
+        old it is (`snapshot_age_s`, 0.0 when fresh). `steps` is fresh
+        either way."""
+        if self._lock.acquire(timeout=0.2):
+            try:
+                self._last_stats = self._stats_locked()
+                self._last_stats_at = time.monotonic()
+            finally:
+                self._lock.release()
+            age = 0.0
+        else:
+            age = time.monotonic() - self._last_stats_at
+        return {**self._last_stats, "snapshot_age_s": age,
+                "steps": self.step_stats()}
+
+    def step_stats(self) -> Dict[str, Any]:
+        """The step ledger as of the end of the last step: cumulative
+        counts and seconds, read without the engine lock. Take the
+        difference of two reads for a rate: host time of a step is
+        (wall_s - the two *.sync phases) / n; decode rows per execution
+        is decode_rows / decode."""
+        steps = self._steps
+        return {**steps, "phase_s": dict(steps["phase_s"])}
+
+    def _publish_steps(self) -> None:
+        # One assignment of a dict nobody mutates afterwards: a reader
+        # sees all of a step or none of it.
+        phase_s = dict(self._clock.seconds)
+        self._steps = {**self._ledger, "wait_work_s": phase_s[WAIT_WORK],
+                       "phase_s": phase_s}
 
     def _stats_locked(self) -> Dict[str, Any]:
         now = time.monotonic()
@@ -1122,7 +1223,6 @@ class InferenceEngine:
             "requests_finished": self._finished,
             "requests_failed": self._failed,
             "preemptions": self._preemptions,
-            "recomputed_tokens": self._recomputed_tokens,
             "prefill_compiles": self._program_compiles("prefill"),
             "decode_compiles": self._program_compiles("decode"),
             "kv": self._bm.stats(),
@@ -1216,11 +1316,15 @@ class EngineLoop:
 
     def _run(self):
         failures = 0
+        clock = self.engine._clock
         while True:
+            clock.enter(WAIT_WORK)
             with self._cv:
                 while not self._stopped and not self.engine.has_work():
                     self._cv.wait(timeout=0.05)
                 if self._stopped:
+                    clock.leave()
+                    self.engine._publish_steps()
                     return
             try:
                 self.engine.step()

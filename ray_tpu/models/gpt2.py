@@ -146,7 +146,8 @@ class GPT2(nn.Module):
         x = nn.LayerNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="ln_f")(x)
         # Tied output head: logits over the sharded vocab.
-        logits = jnp.einsum("bse,ve->bsv", x, wte.astype(cfg.dtype))
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("bse,ve->bsv", x, wte.astype(cfg.dtype))
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
 
 
@@ -229,16 +230,18 @@ def make_train_step(model: nn.Module, optimizer, mesh=None,
     if loss_fn is None:
         def loss_fn(p, batch):
             logits = model.apply(p, batch["input_ids"])
-            ce = next_token_loss(logits, batch["labels"])
+            with jax.named_scope("head_loss"):
+                ce = next_token_loss(logits, batch["labels"])
             return ce, ce
 
     def step(params, opt_state, batch):
         (_, shown), grads = jax.value_and_grad(
             lambda p: loss_fn(p, batch), has_aux=True)(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
         import optax
 
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, shown
 
     def step_with_rules(params, opt_state, batch):

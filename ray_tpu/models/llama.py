@@ -181,47 +181,53 @@ class LlamaBlock(nn.Module):
             new_cache = None
         elif len(cache) == 4:
             k_arena, v_arena, block_tables, write_mask = cache
-            nb, bsz, kvh, _ = k_arena.shape
-            max_blocks = block_tables.shape[1]
-            max_ctx = max_blocks * bsz
-            # Scatter this call's K/V into the arena. Physical slot of
-            # logical position p in row i: block_tables[i, p // bsz] * bsz
-            # + p % bsz. Masked tokens (batch padding, chunk padding) are
-            # pointed at physical block 0 — reserved as a trash block the
-            # manager never allocates — so one fixed-shape scatter handles
-            # every mix of active/idle slots without recompiling.
-            kw = k.transpose(0, 2, 1, 3).astype(k_arena.dtype)  # [b,s,kvh,d]
-            vw = v.transpose(0, 2, 1, 3).astype(v_arena.dtype)
-            blk = jnp.clip(positions // bsz, 0, max_blocks - 1)
-            phys = jnp.take_along_axis(block_tables, blk, axis=1)  # [b, s]
-            phys = jnp.where(write_mask, phys, 0)
-            flat = (phys * bsz + positions % bsz).reshape(-1)
-            k_flat = k_arena.reshape(nb * bsz, kvh, hd)
-            v_flat = v_arena.reshape(nb * bsz, kvh, hd)
-            k_flat = k_flat.at[flat].set(kw.reshape(-1, kvh, hd))
-            v_flat = v_flat.at[flat].set(vw.reshape(-1, kvh, hd))
-            # Gather each row's logical context back out of the arena.
-            slot = (block_tables * bsz)[:, :, None] \
-                + jnp.arange(bsz)[None, None, :]
-            slot = slot.reshape(b, max_ctx)
-            kf = jnp.repeat(k_flat[slot], groups, axis=2)  # [b,ctx,h,d]
-            vf = jnp.repeat(v_flat[slot], groups, axis=2)
-            # Causal over LOGICAL positions: arena slot (j, o) of a row
-            # holds logical position j*bsz+o; unwritten slots sit past
-            # every query's position (or behind trash-padded table
-            # entries) and are masked out.
-            kv_pos = jnp.arange(max_ctx)
-            mask = kv_pos[None, None, :] <= positions[:, :, None]
-            scores = jnp.einsum("bhqd,bkhd->bhqk",
-                                q.astype(jnp.float32),
-                                kf.astype(jnp.float32)) / (hd ** 0.5)
-            scores = jnp.where(mask[:, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("bhqk,bkhd->bhqd", probs,
-                              vf.astype(jnp.float32)).astype(cfg.dtype)
-            new_cache = (k_flat.reshape(nb, bsz, kvh, hd),
-                         v_flat.reshape(nb, bsz, kvh, hd),
-                         block_tables, write_mask)
+            # Named for the profiler: device ops of the paged path carry
+            # `paged_attn` in their op_name (PERF.md, Open questions).
+            with jax.named_scope("paged_attn"):
+                nb, bsz, kvh, _ = k_arena.shape
+                max_blocks = block_tables.shape[1]
+                max_ctx = max_blocks * bsz
+                # Scatter this call's K/V into the arena. Physical slot
+                # of logical position p in row i: block_tables[i, p // bsz]
+                # * bsz + p % bsz. Masked tokens (batch padding, chunk
+                # padding) are pointed at physical block 0 — reserved as a
+                # trash block the manager never allocates — so one
+                # fixed-shape scatter handles every mix of active/idle
+                # slots without recompiling.
+                kw = k.transpose(0, 2, 1, 3).astype(
+                    k_arena.dtype)                        # [b,s,kvh,d]
+                vw = v.transpose(0, 2, 1, 3).astype(v_arena.dtype)
+                blk = jnp.clip(positions // bsz, 0, max_blocks - 1)
+                phys = jnp.take_along_axis(block_tables, blk,
+                                           axis=1)        # [b, s]
+                phys = jnp.where(write_mask, phys, 0)
+                flat = (phys * bsz + positions % bsz).reshape(-1)
+                k_flat = k_arena.reshape(nb * bsz, kvh, hd)
+                v_flat = v_arena.reshape(nb * bsz, kvh, hd)
+                k_flat = k_flat.at[flat].set(kw.reshape(-1, kvh, hd))
+                v_flat = v_flat.at[flat].set(vw.reshape(-1, kvh, hd))
+                # Gather each row's logical context back out of the arena.
+                slot = (block_tables * bsz)[:, :, None] \
+                    + jnp.arange(bsz)[None, None, :]
+                slot = slot.reshape(b, max_ctx)
+                kf = jnp.repeat(k_flat[slot], groups, axis=2)  # [b,ctx,h,d]
+                vf = jnp.repeat(v_flat[slot], groups, axis=2)
+                # Causal over LOGICAL positions: arena slot (j, o) of a row
+                # holds logical position j*bsz+o; unwritten slots sit past
+                # every query's position (or behind trash-padded table
+                # entries) and are masked out.
+                kv_pos = jnp.arange(max_ctx)
+                mask = kv_pos[None, None, :] <= positions[:, :, None]
+                scores = jnp.einsum("bhqd,bkhd->bhqk",
+                                    q.astype(jnp.float32),
+                                    kf.astype(jnp.float32)) / (hd ** 0.5)
+                scores = jnp.where(mask[:, None], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+                attn = jnp.einsum("bhqk,bkhd->bhqd", probs,
+                                  vf.astype(jnp.float32)).astype(cfg.dtype)
+                new_cache = (k_flat.reshape(nb, bsz, kvh, hd),
+                             v_flat.reshape(nb, bsz, kvh, hd),
+                             block_tables, write_mask)
         else:
             k_cache, v_cache = cache                 # [b, max, kvh, d]
             max_len = k_cache.shape[1]
