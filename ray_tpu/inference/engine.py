@@ -314,6 +314,9 @@ class InferenceEngine:
                         "wall_s": 0.0}
         self._publish_steps()
         self._rate_window: List[tuple] = []   # (t, n) recent emissions
+        # Which path the paged attention of each program took when it was
+        # traced (ops/paged_attention.py's dispatch records).
+        self._paged_attn = {"decode": "not traced", "prefill": "not traced"}
         self._shapes = {"prefill": set(), "decode": set(),
                         "draft_prefill": set(), "propose": set(),
                         "verify": set()}
@@ -965,9 +968,33 @@ class InferenceEngine:
     # ------------------------------------------------------------- helpers
 
     def _call(self, name: str, fn, *args):
-        self._shapes[name].add(tuple(
-            getattr(a, "shape", None) for a in args[2:]))
-        return fn(*args)
+        shape = tuple(getattr(a, "shape", None) for a in args[2:])
+        if shape in self._shapes[name]:
+            return self._under_mesh(fn, args)
+        # A new shape is a new trace: what it adds to the dispatch rule's
+        # records is the path this program's attention took.
+        from ray_tpu.ops.paged_attention import paged_calls
+
+        self._shapes[name].add(shape)
+        before = paged_calls()
+        out = self._under_mesh(fn, args)
+        took = {key[1] for key, n in paged_calls().items()
+                if n > before.get(key, 0)}
+        if name in self._paged_attn and took:
+            self._paged_attn[name] = " | ".join(sorted(took))
+        return out
+
+    def _under_mesh(self, fn, args):
+        """With a tp mesh every program is traced and run under it as
+        jax's context mesh: that is where paged attention finds the axis
+        to `shard_map` its kernel over (the partitioner cannot split a
+        custom call; docs/SHARDED.md)."""
+        if self._mesh is None:
+            return fn(*args)
+        import jax
+
+        with jax.set_mesh(self._mesh):
+            return fn(*args)
 
     def _block_table_rows(self, reqs) -> "np.ndarray":  # noqa: F821
         import numpy as np
@@ -1225,6 +1252,7 @@ class InferenceEngine:
             "preemptions": self._preemptions,
             "prefill_compiles": self._program_compiles("prefill"),
             "decode_compiles": self._program_compiles("decode"),
+            "paged_attn": dict(self._paged_attn),
             "kv": self._bm.stats(),
             "prefix_cache": (self._prefix.stats() if self._prefix is not None
                              else {"enabled": False, "cached_blocks": 0,

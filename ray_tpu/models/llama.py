@@ -18,7 +18,10 @@ Three forward paths share parameters:
   logical blocks to physical ones, so the continuous-batching engine
   (`ray_tpu/inference/`) can admit/evict/preempt sequences without ever
   reshaping the cache — one compiled program per (batch, step-width)
-  shape, forever.
+  shape, forever. Writes scatter into the arena in place; reads go
+  through `ops/paged_attention.py`, whose Pallas kernel walks each row's
+  block table and copies only the live blocks (GQA inside, nothing
+  repeated or upcast in HBM).
 """
 
 from __future__ import annotations
@@ -31,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import flash_attention_sharded, mha_reference
+from ray_tpu.ops.paged_attention import paged_attention
 
 
 @dataclass(frozen=True)
@@ -126,8 +130,10 @@ class LlamaBlock(nn.Module):
         cache=(k_arena, v_arena, block_tables, write_mask) with arenas
         [num_blocks, block_size, kv_heads, head_dim]: paged variant —
         writes land at the physical slot the row's block table maps each
-        position to (masked-off tokens go to trash block 0), reads gather
-        the row's logical context back out of the arena.
+        position to (masked-off tokens go to trash block 0), reads are
+        `paged_attention` over the arena as the writes left it: each row
+        sees its logical positions <= the query's, out of the blocks its
+        table maps, and only those below its live length are touched.
 
         lora=(aq, bq, ao, bo, adapter_idx): model-multiplexed low-rank
         LATE-FUSION deltas (ladder-style side adapter). The block reads
@@ -186,7 +192,6 @@ class LlamaBlock(nn.Module):
             with jax.named_scope("paged_attn"):
                 nb, bsz, kvh, _ = k_arena.shape
                 max_blocks = block_tables.shape[1]
-                max_ctx = max_blocks * bsz
                 # Scatter this call's K/V into the arena. Physical slot
                 # of logical position p in row i: block_tables[i, p // bsz]
                 # * bsz + p % bsz. Masked tokens (batch padding, chunk
@@ -206,28 +211,17 @@ class LlamaBlock(nn.Module):
                 v_flat = v_arena.reshape(nb * bsz, kvh, hd)
                 k_flat = k_flat.at[flat].set(kw.reshape(-1, kvh, hd))
                 v_flat = v_flat.at[flat].set(vw.reshape(-1, kvh, hd))
-                # Gather each row's logical context back out of the arena.
-                slot = (block_tables * bsz)[:, :, None] \
-                    + jnp.arange(bsz)[None, None, :]
-                slot = slot.reshape(b, max_ctx)
-                kf = jnp.repeat(k_flat[slot], groups, axis=2)  # [b,ctx,h,d]
-                vf = jnp.repeat(v_flat[slot], groups, axis=2)
-                # Causal over LOGICAL positions: arena slot (j, o) of a row
-                # holds logical position j*bsz+o; unwritten slots sit past
-                # every query's position (or behind trash-padded table
-                # entries) and are masked out.
-                kv_pos = jnp.arange(max_ctx)
-                mask = kv_pos[None, None, :] <= positions[:, :, None]
-                scores = jnp.einsum("bhqd,bkhd->bhqk",
-                                    q.astype(jnp.float32),
-                                    kf.astype(jnp.float32)) / (hd ** 0.5)
-                scores = jnp.where(mask[:, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum("bhqk,bkhd->bhqd", probs,
-                                  vf.astype(jnp.float32)).astype(cfg.dtype)
-                new_cache = (k_flat.reshape(nb, bsz, kvh, hd),
-                             v_flat.reshape(nb, bsz, kvh, hd),
-                             block_tables, write_mask)
+                k_arena = k_flat.reshape(nb, bsz, kvh, hd)
+                v_arena = v_flat.reshape(nb, bsz, kvh, hd)
+                # Read: each row's live blocks straight out of the arena
+                # (ops/paged_attention.py: the Pallas kernel where the
+                # dispatch rule gives it the call, the dense reference
+                # elsewhere). The scatter above comes first, so the call's
+                # own K/V are in the arena it reads.
+                attn = paged_attention(
+                    q.transpose(0, 2, 1, 3), k_arena, v_arena, block_tables,
+                    positions, write_mask).transpose(0, 2, 1, 3)
+                new_cache = (k_arena, v_arena, block_tables, write_mask)
         else:
             k_cache, v_cache = cache                 # [b, max, kvh, d]
             max_len = k_cache.shape[1]

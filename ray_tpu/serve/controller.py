@@ -1279,9 +1279,14 @@ def _try_ping(handle, timeout_s: float) -> tuple:
 
 
 # A live replica's health answer that did not arrive in time, and how many
-# in a row make it a hung replica (a dead actor is replaced at once).
+# in a row make it a hung replica (a dead actor is replaced at once). One
+# native call that keeps the GIL holds every answer back for as long as it
+# runs: reading a 38 MB profiler trace back took over 6 s in a replica
+# that had compiled its programs itself (under 1 s in one that loaded them
+# from the compile cache), and five late answers killed it mid-read
+# (PERF.md, PR 25). Thirty is Ray Serve's own 30 s health-check timeout.
 _SLOW = object()
-_SLOW_CHECKS_TO_REPLACE = 5
+_SLOW_CHECKS_TO_REPLACE = 30
 
 
 def _gather_stats(replicas) -> list:

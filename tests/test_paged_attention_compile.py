@@ -1,0 +1,137 @@
+"""Compile rehearsal for the paged-attention kernel: `decode_paged` at the
+Mistral serve cell's sizes, with the dispatch rule steered to `tpu`,
+lowers and compiles for one described v5e chip WITH the kernel in every
+layer, without the dense path's context-wide tensors, inside the chip's
+memory. The TPU's compiler is installed here and compiles for a chip that
+is described, not attached; nothing runs, so this says nothing about
+times. (`tests/benchmarks/test_bench_compile_rehearsal.py` compiles the
+same programs as the CPU sees them, through the reference.)
+
+The topology is described inside a fixture (never at import: only one
+process at a time may load the TPU's library) and the test skips where it
+cannot be."""
+
+import json
+import os
+import re
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM = 16e9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_mistral_decode_and_prefill_compile_with_the_kernel(one_chip,
+                                                            monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import Llama
+    from ray_tpu.ops import attention
+
+    monkeypatch.syspath_prepend(ROOT)
+    from benchmarks.builders.llama_serve import llama_config
+
+    # The dispatch rule asks jax for the platform, which is the CPU here:
+    # steer it in the test so that the programs compile WITH the kernel.
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "mistral-7b-v0.3-l16-serve.json")) as f:
+        config = json.load(f)
+    eng = config["engine"]
+    cfg = llama_config(config)
+    model = Llama(cfg)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    arena = spec((eng["num_blocks"], eng["block_size"], cfg.n_kv_head,
+                  cfg.head_dim), cfg.dtype)
+    arenas = [(arena, arena) for _ in range(cfg.n_layer)]
+    assert (cfg.n_layer, eng["num_blocks"], eng["block_size"]) == (
+        16, 4097, 16)
+
+    # The engine's two programs (`InferenceEngine._build_programs`): one
+    # paged forward, at [slots, 1] and at [1, chunk].
+    def step_fn(params, arenas, toks, bt, pos, wmask):
+        logits, arenas = model.apply(params, toks, arenas, bt, pos, wmask,
+                                     method=Llama.decode_paged)
+        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), arenas
+
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    cache = 2 * cfg.n_layer * arena.size * arena.dtype.itemsize
+    shapes = ((eng["batch_slots"], 1), (1, eng["prefill_chunk"]))
+    assert shapes == ((16, 1), (1, 512))
+    for b, s in shapes:
+        compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+            params, arenas, spec((b, s), jnp.int32),
+            spec((b, eng["max_blocks_per_seq"]), jnp.int32),
+            spec((b,), jnp.int32), spec((b, s), jnp.bool_)).compile()
+        hlo = compiled.as_text()
+        kernels = [line for line in hlo.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line
+                   and re.search(r"%paged_attention[.\d]* = ", line)]
+        assert len(kernels) == cfg.n_layer, (b, s, len(kernels))
+        # Nothing as wide as the block table is left: neither the GQA
+        # repeat of every row's whole context ([16,4096,8,4,128] at
+        # decode) nor its gather ([65536,8,128]).
+        ctx = eng["max_blocks_per_seq"] * eng["block_size"]
+        groups = cfg.n_head // cfg.n_kv_head
+        for gone in (f"[{b},{ctx},{cfg.n_kv_head},{groups},{cfg.head_dim}]",
+                     f"[{b * ctx},{cfg.n_kv_head},{cfg.head_dim}]",
+                     f"[{b},{ctx},{cfg.n_kv_head},{cfg.head_dim}]"):
+            assert gone not in hlo, (b, s, gone)
+        # The arena is updated in place and read where it lies: weights,
+        # cache and little else.
+        mem = compiled.memory_analysis()
+        need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert weights + cache < need < weights + cache + 0.5e9 < HBM, (
+            b, s, need)
+        assert mem.alias_size_in_bytes >= cache, (b, s)
+
+
+def test_records_say_pallas_for_the_cells_shapes(monkeypatch):
+    """The dispatch rule alone, no compiler: at the cell's shapes on
+    platform `tpu` both passes go to the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention, paged_attention as pa
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    attention.reset_pallas_status()
+    arena = jax.ShapeDtypeStruct((4097, 16, 8, 128), jnp.bfloat16)
+    for b, s in ((16, 1), (1, 512)):
+        q = jax.ShapeDtypeStruct((b, s, 32, 128), jnp.bfloat16)
+        assert pa._dispatch(q, arena)
+    assert pa.paged_calls() == {("paged_decode", "pallas"): 1,
+                                ("paged_prefill", "pallas"): 1}
