@@ -280,8 +280,9 @@ def check_retrace_v2(ctx: FileContext) -> Iterable[Finding]:
 # =====================================================================
 #
 # The inference engine's decode loop budget is one device sync per
-# step: `nxt, self._arenas = self._call(...)` then ONE `np.asarray(nxt)`
-# before the per-request bookkeeping loop reads plain host memory.  A
+# execution: `self._tokens, self._arenas = self._call(...)`, then at
+# harvest ONE `np.asarray(rec.tokens)` before the per-request
+# bookkeeping loop reads plain host memory.  A
 # materializer inside the loop instead blocks on the device once per
 # request per token.  The provenance layer is what keeps this precise:
 # `int(host_copy[slot])` after the hoisted sync is silent, `int(nxt[
@@ -323,7 +324,7 @@ def check_host_sync_in_hot_loop(ctx: FileContext) -> Iterable[Finding]:
 # `donate_argnums` hands the argument's buffer to XLA: after the call
 # the old array is invalid (reading it raises, or worse, returns
 # aliased garbage on some backends).  The safe idiom is the engine's
-# arena lifecycle: `nxt, self._arenas = self._call(..., self._arenas,
+# arena lifecycle: `toks, self._arenas = self._call(..., self._arenas,
 # ...)` — the donated name is rebound from the call's result in the
 # same statement, and the failure path rebuilds the arenas outright.
 

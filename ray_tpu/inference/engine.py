@@ -15,11 +15,24 @@ Two jitted programs serve every request mix, each compiled exactly once:
 - prefill: [1, prefill_chunk] tokens of one sequence (padded chunk),
 - decode:  [batch_slots, 1] — one token for every running slot.
 
+Both thread a device-resident int32[batch_slots] vector, each slot's last
+token, the way they thread the arenas: a final prefill chunk writes its
+token into its slot's row, decode reads its input there and writes its
+output there. So the engine dispatches decode n+1 before it has read the
+tokens of decode n (dispatch-ahead): one execution stays in flight while
+the host reads the one before, does its bookkeeping, runs the callbacks
+and admits. `processed` advances at dispatch; `generated`, the callbacks
+and `_finish` happen at harvest, one execution later. A result that
+arrives for a row that has gone meanwhile (EOS, cancel, preemption) is
+dropped, not emitted.
+
 Speculative decoding (spec_decode_draft_len > 0) swaps the decode step
 for three more fixed-shape programs — draft prefill [1, chunk], propose
 (k+1 scanned draft steps), verify [batch_slots, k+1] — still compiled
 exactly once each; greedy verification makes the emitted tokens
-identical to plain decoding, whatever the draft proposes.
+identical to plain decoding, whatever the draft proposes. A round's
+positions depend on how many drafts the last one accepted, which the
+host must read first, so speculation stays synchronous.
 
 A radix prefix cache (prefix_cache_enabled, continuous scheduling)
 keeps finished sequences' full-block KV prefixes refcounted in the
@@ -50,6 +63,7 @@ scheduling policy.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import logging
 import threading
@@ -78,10 +92,12 @@ WAIT_WORK = "engine.wait_work"          # EngineLoop parked, nothing to do
 ADMIT = "engine.admit"                  # lock wait, gang release, _admit
 PREFILL_HOST = "engine.prefill.host"    # block claim, arrays, block table
 PREFILL_DISPATCH = "engine.prefill.dispatch"   # the jitted call returns
-PREFILL_SYNC = "engine.prefill.sync"    # int(nxt[0]) on a final chunk
+PREFILL_SYNC = "engine.prefill.sync"    # harvest: a final chunk's token
 DECODE_HOST = "engine.decode.host"
 DECODE_DISPATCH = "engine.decode.dispatch"     # spec: draft and verify
-DECODE_SYNC = "engine.decode.sync"      # np.asarray(nxt)
+# Harvest: waiting for the tokens of the execution BEFORE the one just
+# dispatched (spec: of the round just dispatched).
+DECODE_SYNC = "engine.decode.sync"
 DECODE_EMIT = "engine.decode.emit"      # per-row bookkeeping
 CALLBACKS = "engine.callbacks"          # on_token/on_finish, lock released
 PHASES = (WAIT_WORK, ADMIT, PREFILL_HOST, PREFILL_DISPATCH, PREFILL_SYNC,
@@ -133,9 +149,9 @@ class Request:
     preemptions: int = 0
     submitted_at: float = 0.0
     admitted_at: Optional[float] = None    # first batch-slot admission
-    first_token_at: Optional[float] = None   # token computed
+    first_token_at: Optional[float] = None   # token harvested
     # Taken just before this request's first on_token runs: behind
-    # first_token_at by whatever the step still had to do (a decode).
+    # first_token_at by the rest of that harvest.
     first_token_delivered_at: Optional[float] = None
     finished_at: Optional[float] = None
     # Trace context captured at submission: the engine's queue/prefill/
@@ -153,8 +169,11 @@ class Request:
     cached_tokens: int = 0
     # Scheduler-internal:
     slot: Optional[int] = None
-    processed: int = 0                # tokens written into the KV cache
-    cur_token: Optional[int] = None   # next decode input
+    # Tokens whose KV write has been DISPATCHED: it runs ahead of
+    # `generated` by the executions in flight.
+    processed: int = 0
+    inflight: int = 0                 # tokens dispatched, not yet harvested
+    cur_token: Optional[int] = None   # last harvested token (spec input)
     _held_emits: List[tuple] = field(default_factory=list)
     _pinned_node: Any = None          # radix node pinned while scheduled
 
@@ -166,6 +185,22 @@ class Request:
     @property
     def done(self) -> bool:
         return self.state in (FINISHED, FAILED)
+
+    @property
+    def budget_dispatched(self) -> bool:
+        """Its last token is computed or in flight: nothing more to
+        dispatch for it, whatever the tokens turn out to be."""
+        return len(self.generated) + self.inflight >= self.max_new_tokens
+
+
+@dataclass
+class _InFlight:
+    """One dispatched execution whose tokens the host has not read."""
+    decode: bool          # a decode step, or a final prefill chunk
+    tokens: Any           # device int32[batch_slots] after the execution
+    # (request, its slot, its `preemptions` at dispatch): harvest matches
+    # on the request and the count, never on the slot alone.
+    rows: List[tuple]
 
 
 class InferenceEngine:
@@ -295,6 +330,12 @@ class InferenceEngine:
 
             self._adapters = AdapterManager(model.config, cfg.max_adapters,
                                             cfg.lora_rank, mesh=mesh)
+        # Each slot's last token, on the device (module docstring), and
+        # the executions dispatched whose tokens are not read yet, oldest
+        # first. Not donated: an execution's output stays readable after
+        # the next one took it as input.
+        self._tokens = self._fresh_tokens()
+        self._inflight: collections.deque = collections.deque()
         self._slots: List[Optional[Request]] = [None] * cfg.batch_slots
         self._waiting: List[Request] = []     # kept sorted by arrival
         self._live: Dict[str, Request] = {}   # request_id -> live request
@@ -310,8 +351,8 @@ class InferenceEngine:
         # whole at the end of every step so that step_stats() needs no
         # lock and never sees half a step.
         self._clock = PhaseClock(PHASES)
-        self._ledger = {"n": 0, "decode": 0, "prefill": 0, "decode_rows": 0,
-                        "wall_s": 0.0}
+        self._ledger = {"n": 0, "decode": 0, "decode_ahead": 0, "prefill": 0,
+                        "decode_rows": 0, "dropped_rows": 0, "wall_s": 0.0}
         self._publish_steps()
         self._rate_window: List[tuple] = []   # (t, n) recent emissions
         # Which path the paged attention of each program took when it was
@@ -345,45 +386,52 @@ class InferenceEngine:
 
         model = self._model
 
+        # `tokens` is the device-resident last token of every slot. The
+        # chunk writes its token into its slot's row (a chunk that is not
+        # the prompt's last writes one nobody reads: the row decodes only
+        # after the last has overwritten it); decode reads its input there
+        # and leaves the rows it did not run as they were.
+        def chunk_token(tokens, logits, last_idx, slot):
+            nxt = jnp.argmax(jnp.take_along_axis(
+                logits, last_idx[:, None, None], axis=1)[:, 0], axis=-1)
+            return tokens.at[slot].set(nxt.astype(jnp.int32))
+
+        def step_tokens(tokens, logits, wmask):
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return jnp.where(wmask[:, 0], nxt, tokens)
+
         if self._adapters is not None:
             # Multiplexed variants: the adapter banks + per-row index
             # ride as ARGUMENTS (fixed shape/dtype/sharding), so N
             # adapters still mean exactly these two programs — same
             # count as the single-model engine, proven by the compile
             # counters in the multiplex tests and bench_zoo.
-            def prefill_fn(params, arenas, banks, aidx, ids, bt, pos,
-                           wmask, last_idx):
+            def prefill_fn(params, arenas, banks, aidx, tokens, ids, bt,
+                           pos, wmask, last_idx, slot):
                 logits, arenas = model.apply(
                     params, ids, arenas, bt, pos, wmask, banks, aidx,
                     method=Llama.decode_paged)
-                nxt = jnp.argmax(jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1)[:, 0],
-                    axis=-1)
-                return nxt.astype(jnp.int32), arenas
+                return chunk_token(tokens, logits, last_idx, slot), arenas
 
-            def decode_fn(params, arenas, banks, aidx, toks, bt, pos,
+            def decode_fn(params, arenas, banks, aidx, tokens, bt, pos,
                           wmask):
                 logits, arenas = model.apply(
-                    params, toks, arenas, bt, pos, wmask, banks, aidx,
-                    method=Llama.decode_paged)
-                return jnp.argmax(logits[:, -1],
-                                  axis=-1).astype(jnp.int32), arenas
+                    params, tokens[:, None], arenas, bt, pos, wmask, banks,
+                    aidx, method=Llama.decode_paged)
+                return step_tokens(tokens, logits, wmask), arenas
         else:
-            def prefill_fn(params, arenas, ids, bt, pos, wmask, last_idx):
+            def prefill_fn(params, arenas, tokens, ids, bt, pos, wmask,
+                           last_idx, slot):
                 logits, arenas = model.apply(params, ids, arenas, bt, pos,
                                              wmask,
                                              method=Llama.decode_paged)
-                nxt = jnp.argmax(jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1)[:, 0],
-                    axis=-1)
-                return nxt.astype(jnp.int32), arenas
+                return chunk_token(tokens, logits, last_idx, slot), arenas
 
-            def decode_fn(params, arenas, toks, bt, pos, wmask):
-                logits, arenas = model.apply(params, toks, arenas, bt, pos,
-                                             wmask,
+            def decode_fn(params, arenas, tokens, bt, pos, wmask):
+                logits, arenas = model.apply(params, tokens[:, None], arenas,
+                                             bt, pos, wmask,
                                              method=Llama.decode_paged)
-                return jnp.argmax(logits[:, -1],
-                                  axis=-1).astype(jnp.int32), arenas
+                return step_tokens(tokens, logits, wmask), arenas
 
         if self.config.use_jit:
             # Arenas are donated: the update is in place on the device,
@@ -470,6 +518,21 @@ class InferenceEngine:
             except Exception:  # noqa: BLE001 — introspection only
                 pass
         return len(self._shapes[name])
+
+    def _fresh_tokens(self):
+        """The token vector as the programs return it: replicated under a
+        tp mesh, so that the first call's argument and every later one
+        (an output) are one jit cache key."""
+        import jax
+        import jax.numpy as jnp
+
+        shape = (self.config.batch_slots,)
+        if self._mesh is None:
+            return jnp.zeros(shape, jnp.int32)
+        replicated = jax.sharding.NamedSharding(
+            self._mesh, jax.sharding.PartitionSpec())
+        return jax.jit(lambda: jnp.zeros(shape, jnp.int32),
+                       out_shardings=replicated)()
 
     # ---------------------------------------------------------- submission
 
@@ -566,18 +629,22 @@ class InferenceEngine:
     def has_work(self) -> bool:
         with self._lock:
             # Any occupied slot is work: static DONE_HOLD members still
-            # need their gang-release step.
-            return bool(self._waiting) or any(
+            # need their gang-release step. So is an execution whose
+            # tokens nobody has read.
+            return bool(self._waiting) or bool(self._inflight) or any(
                 r is not None for r in self._slots)
 
     # ---------------------------------------------------------------- step
 
     def step(self) -> bool:
-        """One scheduler iteration: admit, one prefill chunk, one decode
-        step. Returns whether any work ran. Callbacks fire after the lock
-        is released (they may hop into an asyncio loop). One thread steps
-        an engine (the EngineLoop's, or a test's): the phase clock and
-        the step ledger are that thread's."""
+        """One scheduler iteration: admit, dispatch one prefill chunk and
+        one decode step, then harvest (read the tokens of, and do the
+        bookkeeping for) every execution but the newest of this step,
+        which stays in flight while the callbacks run and the next step
+        admits and dispatches. Returns whether any work ran. Callbacks
+        fire after the lock is released (they may hop into an asyncio
+        loop). One thread steps an engine (the EngineLoop's, or a
+        test's): the phase clock and the step ledger are that thread's."""
         clock = self._clock
         t0 = time.perf_counter()
         emissions: List[tuple] = []
@@ -586,11 +653,17 @@ class InferenceEngine:
             with self._lock:
                 self._release_static_gang(emissions)
                 self._admit()
-                ran = self._prefill_step(emissions)
+                before = len(self._inflight)
+                ran = self._prefill_step()
                 if self._draft_len > 0:
+                    # A round starts from tokens the host holds.
+                    ran = self._harvest(emissions, keep=0) or ran
                     ran = self._spec_decode_step(emissions) or ran
                 else:
-                    ran = self._decode_step(emissions) or ran
+                    ran = self._decode_step() or ran
+                    # With nothing new to keep the device busy, drain.
+                    keep = min(1, len(self._inflight) - before)
+                    ran = self._harvest(emissions, keep) or ran
             clock.enter(CALLBACKS)
             self._deliver(emissions)
         finally:
@@ -724,6 +797,7 @@ class InferenceEngine:
         victim.slot = None
         victim.state = WAITING
         victim.processed = 0
+        victim.inflight = 0      # their harvest drops them (preemptions)
         victim.cur_token = None
         victim.preemptions += 1
         self._preemptions += 1
@@ -765,7 +839,7 @@ class InferenceEngine:
 
     # ------------------------------------------------------------- prefill
 
-    def _prefill_step(self, emissions) -> bool:
+    def _prefill_step(self) -> bool:
         import numpy as np
 
         cfg = self.config
@@ -786,17 +860,18 @@ class InferenceEngine:
         wmask[0, :chunk] = True
         bt = self._block_table_rows([req])
         args = (ids, bt, np.asarray([req.processed], np.int32), wmask,
-                np.asarray([chunk - 1], np.int32))
+                np.asarray([chunk - 1], np.int32),
+                np.asarray([req.slot], np.int32))
         clock.enter(PREFILL_DISPATCH)
         if self._adapters is not None:
             aidx = np.asarray([req.adapter_row], np.int32)
-            nxt, self._arenas = self._call(
+            self._tokens, self._arenas = self._call(
                 "prefill", self._prefill_fn, self._params, self._arenas,
-                self._adapters.device_banks(), aidx, *args)
+                self._adapters.device_banks(), aidx, self._tokens, *args)
         else:
-            nxt, self._arenas = self._call(
+            self._tokens, self._arenas = self._call(
                 "prefill", self._prefill_fn, self._params, self._arenas,
-                *args)
+                self._tokens, *args)
         if self._draft_len > 0:
             # Keep the draft's KV in lockstep: same chunk, same blocks.
             # Cached-prefix blocks carry draft KV from their original
@@ -808,15 +883,17 @@ class InferenceEngine:
         req.processed += chunk
         self._ledger["prefill"] += 1
         if req.processed >= total:
-            clock.enter(PREFILL_SYNC)
-            token = int(nxt[0])
-            clock.enter(PREFILL_HOST)
-            self._emit_token(req, token, emissions)
+            # Its token is in the slot's row on the device: the row can
+            # decode from this step on, before the host has read it.
+            req.state = DECODE
+            self._track(False, [req])
         return True
 
     # -------------------------------------------------------------- decode
 
-    def _decode_step(self, emissions) -> bool:
+    def _decode_step(self) -> bool:
+        """Dispatch one decode execution for every row that has a token
+        on the device and budget left; reading it is `_harvest`'s."""
         import numpy as np
 
         cfg = self.config
@@ -824,10 +901,12 @@ class InferenceEngine:
         clock.enter(DECODE_HOST)
         active: List[Request] = []
         for req in list(self._scheduled()):
-            if req.state != DECODE:
+            # A static gang member whose last token is in flight holds
+            # its slot and has nothing more to dispatch.
+            if req.state != DECODE or req.budget_dispatched:
                 continue
-            # Writing cur_token at position `processed` needs capacity for
-            # processed + 1 tokens.
+            # Writing the row's token at position `processed` needs
+            # capacity for processed + 1 tokens.
             if self._ensure_blocks(req, req.processed + 1):
                 active.append(req)
         # A later sequence's block claim may have preempted one already
@@ -837,14 +916,12 @@ class InferenceEngine:
         if not active:
             return False
         B = cfg.batch_slots
-        toks = np.zeros((B, 1), np.int32)
         pos = np.zeros(B, np.int32)
         wmask = np.zeros((B, 1), bool)
         rows = [None] * B
         for req in active:
             i = req.slot
             rows[i] = req
-            toks[i, 0] = req.cur_token
             pos[i] = req.processed
             wmask[i, 0] = True
         bt = self._block_table_rows(rows)
@@ -853,22 +930,64 @@ class InferenceEngine:
             aidx = np.zeros(B, np.int32)
             for req in active:
                 aidx[req.slot] = req.adapter_row
-            nxt, self._arenas = self._call(
+            self._tokens, self._arenas = self._call(
                 "decode", self._decode_fn, self._params, self._arenas,
-                self._adapters.device_banks(), aidx, toks, bt, pos, wmask)
+                self._adapters.device_banks(), aidx, self._tokens, bt, pos,
+                wmask)
         else:
-            nxt, self._arenas = self._call(
+            self._tokens, self._arenas = self._call(
                 "decode", self._decode_fn, self._params, self._arenas,
-                toks, bt, pos, wmask)
-        clock.enter(DECODE_SYNC)
-        nxt = np.asarray(nxt)
-        clock.enter(DECODE_EMIT)
+                self._tokens, bt, pos, wmask)
+        clock.enter(DECODE_HOST)
         self._ledger["decode"] += 1
-        self._ledger["decode_rows"] += len(active)
+        self._ledger["decode_ahead"] += any(
+            rec.decode for rec in self._inflight)
         for req in active:
             req.processed += 1
-            self._emit_token(req, int(nxt[req.slot]), emissions)
+        self._track(True, active)
         return True
+
+    def _track(self, decode: bool, reqs: List[Request]) -> None:
+        """Book the execution just dispatched: start its tokens' copy to
+        the host, and let go of the slot of every row whose budget ends
+        with it, so that the next admission does not wait for the
+        harvest. (A static gang member keeps its slot until the gang
+        drains.) The row keeps its blocks until `_finish`."""
+        self._tokens.copy_to_host_async()
+        rows = []
+        for req in reqs:
+            rows.append((req, req.slot, req.preemptions))
+            req.inflight += 1
+            if (req.budget_dispatched
+                    and self.config.scheduling != "static"):
+                self._slots[req.slot] = None
+                req.slot = None
+        self._inflight.append(_InFlight(decode, self._tokens, rows))
+
+    def _harvest(self, emissions, keep: int) -> bool:
+        """Read the tokens of the oldest executions in flight until
+        `keep` are left, and do for each row what the synchronous step
+        did right after its dispatch. A row that left meanwhile (EOS at
+        the harvest before, cancel, preemption, fail_all) is dropped."""
+        import numpy as np
+
+        clock = self._clock
+        harvested = False
+        while len(self._inflight) > keep:
+            rec = self._inflight[0]
+            clock.enter(DECODE_SYNC if rec.decode else PREFILL_SYNC)
+            tokens = np.asarray(rec.tokens).tolist()
+            clock.enter(DECODE_EMIT if rec.decode else PREFILL_HOST)
+            self._inflight.popleft()
+            harvested = True
+            for req, slot, preemptions in rec.rows:
+                if req.state != DECODE or req.preemptions != preemptions:
+                    self._ledger["dropped_rows"] += 1
+                    continue
+                req.inflight -= 1
+                self._ledger["decode_rows"] += rec.decode
+                self._emit_token(req, tokens[slot], emissions)
+        return harvested
 
     def _spec_decode_step(self, emissions) -> bool:
         """Speculative round for every DECODE row: draft proposes k
@@ -1011,7 +1130,6 @@ class InferenceEngine:
     def _emit_token(self, req: Request, token: int, emissions):
         req.generated.append(token)
         req.cur_token = token
-        req.state = DECODE
         self._record_emit(req, ("token", token), emissions)
         if (len(req.generated) >= req.max_new_tokens
                 or (self.config.eos_id is not None
@@ -1097,6 +1215,14 @@ class InferenceEngine:
                 else:
                     self._finish(req, emissions, error=error)
                     failed += 1
+            # Rows that gave up their slot with their last token in flight.
+            for rec in self._inflight:
+                for req, _, _ in rec.rows:
+                    if req.state == DECODE:
+                        self._finish(req, emissions, error=error)
+                        failed += 1
+            self._inflight.clear()
+            self._tokens = self._fresh_tokens()
             for req in self._waiting:
                 req.state = FAILED
                 req.error = error
@@ -1151,10 +1277,9 @@ class InferenceEngine:
         self._record_phase_spans(req)
 
     def _record_deliver_span(self, req: Request):
-        """engine.deliver: first token computed -> its on_token about to
-        run. It overlaps the head of engine.decode: the rest of the step
-        that computed the token (a whole decode step, when one shared the
-        step with the final prefill chunk)."""
+        """engine.deliver: first token harvested -> its on_token about to
+        run. It overlaps the head of engine.decode: the rest of the
+        harvest that read the token."""
         if not _tracing._ENABLED or req.trace_ctx is None:
             return
         _tracing.get_tracer().record_span(

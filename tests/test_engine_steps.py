@@ -195,14 +195,17 @@ def test_step_stats_does_not_wait_for_the_engine_lock(tiny_llama):
 # ----------------------------------------------------- the delivery stamp
 
 
-def test_first_token_is_delivered_after_the_decode_of_its_step(tiny_llama):
+def test_first_token_is_delivered_while_the_decode_of_its_step_runs(
+        tiny_llama):
     engine = _engine(tiny_llama, batch_slots=4, prefill_chunk=16)
     seen = []
     first = engine.add_request(_prompt(5, 1), max_new_tokens=40)
-    while first.state != eng.DECODE:
+    while not first.generated:
         engine.step()
-    # `first` decodes; `second`'s only chunk shares a step with that
-    # decode (and joins it), so its first token waits for the decode.
+    # `first` decodes, one execution in flight. `second`'s only chunk is
+    # dispatched in a step with the decode it joins: the step reads the
+    # decode before and the chunk, delivers `second`'s first token, and
+    # leaves the decode of both rows in flight.
     second = engine.add_request(
         _prompt(9, 50), max_new_tokens=3,
         on_token=lambda r, t: seen.append((time.monotonic(), t)))
@@ -211,15 +214,19 @@ def test_first_token_is_delivered_after_the_decode_of_its_step(tiny_llama):
     after = engine.step_stats()
     assert after["prefill"] - before["prefill"] == 1
     assert after["decode"] - before["decode"] == 1
-    assert after["decode_rows"] - before["decode_rows"] == 2
-    sync_s = after["phase_s"][eng.DECODE_SYNC] \
-        - before["phase_s"][eng.DECODE_SYNC]
-    delay_s = second.first_token_delivered_at - second.first_token_at
-    assert delay_s >= sync_s > 0
-    # Both tokens of that step reached the callback back to back, after
-    # the stamp.
+    assert after["decode_ahead"] - before["decode_ahead"] == 1
+    assert after["decode_rows"] - before["decode_rows"] == 1    # `first`'s
+    assert after["phase_s"][eng.PREFILL_SYNC] \
+        > before["phase_s"][eng.PREFILL_SYNC]
+    assert [t for _, t in seen] == second.generated == second.generated[:1]
+    assert second.inflight == first.inflight == 1
+    assert len(engine._inflight) == 1 and engine._inflight[0].decode
+    assert second.first_token_at <= second.first_token_delivered_at \
+        <= seen[0][0]
+    # The decode it joined is read a step later: its second token.
+    assert engine.step()
     assert [t for _, t in seen] == second.generated[:2]
-    assert second.first_token_delivered_at <= seen[0][0]
+    assert engine.step_stats()["decode_rows"] - after["decode_rows"] == 2
     engine.run_until_idle()
     for req in (first, second):     # with a callback or without
         assert req.first_token_delivered_at >= req.first_token_at
