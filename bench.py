@@ -2093,13 +2093,10 @@ def bench_serve(quick: bool) -> dict:
         serve.shutdown()
 
 
-def _inference_poisson_run(scheduling: str, quick: bool, model=None,
-                           params=None, seed: int = 0) -> dict:
+def _inference_poisson_run(quick: bool, model=None, params=None,
+                           seed: int = 0) -> dict:
     """One Poisson-arrival serving run through the continuous-batching
-    engine. scheduling="continuous" is the iteration-level scheduler;
-    "static" emulates the request-level @serve.batch baseline (gang
-    admission, batch drains at its longest member's speed) through the
-    SAME jitted programs, so the comparison is pure scheduling policy."""
+    engine."""
     import random as _random
     import threading as _threading
 
@@ -2118,12 +2115,9 @@ def _inference_poisson_run(scheduling: str, quick: bool, model=None,
     budgets = [rng.choice(budgets_menu) for _ in range(n)]
 
     cfg = EngineConfig(batch_slots=4, block_size=16, num_blocks=48,
-                       max_blocks_per_seq=8, prefill_chunk=16,
-                       scheduling=scheduling)
+                       max_blocks_per_seq=8, prefill_chunk=16)
     engine = InferenceEngine(cfg, model=model, params=params)
-    # Warm both step programs (one XLA compile each) off the clock: the
-    # measurement compares SCHEDULING, and a 2s compile inside either
-    # run's makespan would wash the policies together.
+    # Warm both step programs (one XLA compile each) off the clock.
     engine.add_request([1, 2, 3], 2, request_id="warmup")
     engine.run_until_idle()
     loop = EngineLoop(engine)
@@ -2332,8 +2326,8 @@ def _inference_spec_run(k: int, quick: bool, model=None, params=None,
 
 
 def bench_inference(quick: bool, smoke: bool = False) -> dict:
-    """Inference engine bench, round 3. Legs: (1) continuous batching vs
-    the static request-batch baseline under Poisson arrivals; (2) radix
+    """Inference engine bench, round 3. Legs: (1) continuous batching
+    under Poisson arrivals; (2) radix
     prefix cache A/B over the same shared-prefix multi-tenant trace with
     per-SLO-class TTFT; (3) speculative-decoding accepted-draft-length
     distributions (honest truncated draft + target-as-draft upper
@@ -2353,18 +2347,8 @@ def bench_inference(quick: bool, smoke: bool = False) -> dict:
 
     out = {}
     if not smoke:
-        cont = _inference_poisson_run("continuous", quick, model=model,
-                                      params=params)
-        stat = _inference_poisson_run("static", quick, model=model,
-                                      params=params)
+        cont = _inference_poisson_run(quick, model=model, params=params)
         out.update({f"inference_cont_{k}": v for k, v in cont.items()})
-        out.update({f"inference_static_{k}": v for k, v in stat.items()})
-        out["inference_tokens_per_sec_speedup"] = (
-            cont["tokens_per_sec"] / stat["tokens_per_sec"]
-            if stat["tokens_per_sec"] else 0.0)
-        out["inference_ttft_p99_improvement"] = (
-            stat["ttft_p99_ms"] / cont["ttft_p99_ms"]
-            if cont["ttft_p99_ms"] else 0.0)
 
     # ---- radix prefix cache A/B on the same shared-prefix trace
     cold = _inference_multitenant_run(False, quick or smoke, model=model,
@@ -2531,17 +2515,19 @@ def _sharded_decode_main(quick: bool) -> dict:
     decode tokens/s at equal parameter count."""
     import jax
 
+    from ray_tpu.inference.api import preset_model
     from ray_tpu.inference.engine import EngineConfig, InferenceEngine
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
     new_tokens = 32 if quick else 96
     n_reqs = 4
+    model, params = preset_model("tiny", 256)
 
     def run_engine(mesh) -> float:
-        cfg = EngineConfig(model_size="tiny", max_model_len=256,
-                           batch_slots=4, num_blocks=64,
+        cfg = EngineConfig(batch_slots=4, num_blocks=64,
                            max_blocks_per_seq=16)
-        engine = InferenceEngine(cfg, mesh=mesh)
+        engine = InferenceEngine(cfg, model=model, params=params,
+                                 mesh=mesh)
         # Warm both programs out of the measurement window.
         engine.add_request([1, 2, 3], max_new_tokens=2)
         engine.run_until_idle()

@@ -272,27 +272,6 @@ _flag("serve_park_max_bytes", int, 8 << 20,
 _flag("serve_park_timeout_s", float, 30.0,
       "Scale-to-zero wait horizon: how long a buffered request waits for "
       "a parked deployment's cold-started replica before failing")
-_flag("prefix_cache_enabled", _parse_bool, True,
-      "Inference engine radix prefix cache: finished sequences donate "
-      "their full-block KV prefixes to a radix tree and new requests "
-      "skip prefill for the longest cached match (continuous scheduling "
-      "only; cached blocks are reclaimed LRU-by-leaf under arena "
-      "pressure before any live sequence is preempted)")
-_flag("spec_decode_draft_len", int, 0,
-      "Speculative decoding draft length k: each decode round proposes "
-      "k tokens with the draft model and verifies k+1 with the target "
-      "in one fixed-shape program (greedy verify — output is identical "
-      "to plain decoding regardless of draft quality). 0 disables")
-_flag("slo_default_class", str, "interactive",
-      "SLO class for requests that do not name one: 'interactive' "
-      "(admission/prefill priority, preferred to survive preemption) or "
-      "'batch' (bulk traffic, first preemption victim)")
-_flag("slo_interactive_reserved_slots", int, 0,
-      "Batch slots the continuous scheduler holds open for "
-      "interactive-class admissions: batch-class requests are only "
-      "admitted while more than this many slots stay free, so a bulk "
-      "flood cannot occupy the whole batch ahead of an interactive "
-      "arrival. 0 disables; capped at batch_slots - 1")
 _flag("job_agent_enabled", _parse_bool, True,
       "Route submitted jobs through the per-node job agents (GCS job "
       "table + driver subprocess on a worker node, checkpointed across "

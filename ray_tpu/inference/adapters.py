@@ -20,8 +20,6 @@ from __future__ import annotations
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-from ray_tpu.models.llama import lora_bank_shapes
-
 
 class AdapterLoadError(ValueError):
     """The adapter cannot become resident (unknown id, or every row is
@@ -32,35 +30,27 @@ class AdapterManager:
     """Residency + banks for one engine. Single-threaded by contract:
     every call happens under the engine lock (submission/step paths)."""
 
-    def __init__(self, model_cfg, max_adapters: int, rank: int,
-                 mesh=None):
+    def __init__(self, model, max_adapters: int, rank: int, mesh=None):
         import numpy as np
 
         if max_adapters < 1:
             raise ValueError("max_adapters must be >= 1 when multiplexing")
         if rank < 1:
             raise ValueError("lora rank must be >= 1")
-        self._cfg = model_cfg
         self.max_adapters = max_adapters
         self.rank = rank
-        self._mesh = mesh
         n_rows = max_adapters + 1   # row 0 = identity (never assigned)
-        import jax.numpy as jnp
-
-        dt = jnp.dtype(model_cfg.dtype)
+        # The model says what its banks look like: layers, one layer's
+        # shapes, their dtype, and their shardings on the mesh (or None).
+        n_layer, shapes, dt, self._shardings = model.adapter_banks(
+            n_rows, rank, mesh)
         self._host: List[Tuple] = [
-            tuple(np.zeros(shape, dtype=dt)
-                  for shape in lora_bank_shapes(model_cfg, n_rows, rank))
-            for _ in range(model_cfg.n_layer)]
+            tuple(np.zeros(shape, dtype=dt) for shape in shapes)
+            for _ in range(n_layer)]
         self._rows: Dict[str, int] = {}        # model_id -> bank row
         self._last_used: Dict[str, float] = {}  # model_id -> monotonic
         self._free_rows = list(range(n_rows - 1, 0, -1))
         self._device_banks = None               # cache, dropped on change
-        self._shardings = None
-        if mesh is not None:
-            from ray_tpu.models.llama import lora_bank_shardings
-
-            self._shardings = lora_bank_shardings(model_cfg, mesh)
         self.loads = 0
         self.evictions = 0
         self.hits = 0
@@ -70,19 +60,16 @@ class AdapterManager:
     def resident(self) -> List[str]:
         return sorted(self._rows)
 
-    def row_of(self, model_id: str) -> Optional[int]:
-        return self._rows.get(model_id)
-
     # ---------------------------------------------------------- residency
 
     def ensure(self, model_id: str,
                loader: Callable[[str], list],
                pinned_rows=()) -> int:
         """Make `model_id` resident and return its bank row. `loader`
-        produces the per-layer (aq, bq, ao, bo) rows on a miss (e.g.
-        `make_adapter_weights` from the adapter's registered seed); LRU
-        evicts the least-recently-used unpinned adapter when the bank is
-        full. Raises AdapterLoadError when nothing can be evicted."""
+        produces the per-layer bank rows on a miss (e.g. derived from
+        the adapter's registered seed); LRU evicts the least-recently-used
+        unpinned adapter when the bank is full. Raises AdapterLoadError
+        when nothing can be evicted."""
         row = self._rows.get(model_id)
         if row is not None:
             self.hits += 1
@@ -112,14 +99,6 @@ class AdapterManager:
         self.loads += 1
         self._device_banks = None
         return row
-
-    def evict(self, model_id: str) -> bool:
-        """Explicit eviction (tests / admin); False when not resident."""
-        if model_id not in self._rows:
-            return False
-        self._evict(model_id)
-        self._device_banks = None
-        return True
 
     def _pick_victim(self, pinned_rows) -> Optional[str]:
         pinned = set(pinned_rows)
@@ -158,10 +137,9 @@ class AdapterManager:
     # -------------------------------------------------------------- banks
 
     def device_banks(self):
-        """Per-layer [(aq, bq, ao, bo)] device arrays for the step
-        programs, cached until residency changes. Placed with the SAME
-        shardings every time (tp: B output dims split with their heads)
-        so a reload is invisible to the jit cache."""
+        """Per-layer bank tuples as device arrays for the step programs,
+        cached until residency changes. Placed with the SAME shardings
+        every time so a reload is invisible to the jit cache."""
         if self._device_banks is None:
             import jax
 

@@ -27,6 +27,22 @@ from ray_tpu import serve
 from ray_tpu.inference.engine import EngineConfig, EngineLoop, InferenceEngine
 
 
+def preset_model(model_size: str = "tiny", max_model_len: int = 256):
+    """(model, params) of a preset named the way `LLMServer` names it:
+    a Llama at `llama_preset`'s widths with randomly initialised weights
+    from key 0, matching the sampler examples (same name, same weights,
+    on every replica and every rank of a gang)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import Llama, llama_preset
+
+    model = Llama(llama_preset(model_size, max_model_len))
+    params = jax.jit(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))()
+    return model, params
+
+
 def _parse(payload: Optional[Dict[str, Any]], default_new: int):
     payload = payload or {}
     ids = [int(t) for t in payload.get("ids", [])] or [0]
@@ -55,8 +71,6 @@ class LLMServer:
                  adapters: Optional[Dict[str, Dict[str, Any]]] = None,
                  max_resident_adapters: int = 0):
         kwargs = dict(engine_config or {})
-        kwargs.setdefault("model_size", model_size)
-        kwargs.setdefault("max_model_len", max_model_len)
         # Model multiplexing: `adapters` registers the replica's servable
         # LoRA models ({model_id: {"seed": int, "rank": r, "scale": s}}).
         # Weights are DERIVED (deterministically, from the seed) on
@@ -86,7 +100,9 @@ class LLMServer:
         # weights as an unsharded replica).
         from ray_tpu import shardgroup
 
-        self._engine = InferenceEngine(self._config,
+        model, params = preset_model(model_size, max_model_len)
+        self._engine = InferenceEngine(self._config, model=model,
+                                       params=params,
                                        mesh=shardgroup.current_mesh())
         if self._adapter_specs:
             self._engine.register_adapter_source(self._load_adapter)

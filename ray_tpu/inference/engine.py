@@ -4,11 +4,28 @@ The serving batch is re-formed every decode step instead of every request:
 finished sequences leave their batch slot immediately, queued requests are
 admitted into freed slots, and long prompts prefill in fixed-size chunks
 interleaved with decode steps so token emission never stalls behind a new
-arrival. K/V lives in a paged arena (`kv_cache.BlockManager` +
-`models/llama.py:decode_paged`); when the arena runs out of blocks the
-engine preempts the lowest-priority sequence — frees its blocks and
-re-queues it for recompute — so the answer to memory pressure is degraded
-latency, never an OOM.
+arrival. K/V lives in a paged cache: `kv_cache.BlockManager` keeps the
+block tables, the model keeps the tensors. When the cache runs out of
+blocks the engine preempts the lowest-priority sequence — frees its blocks
+and re-queues it for recompute — so the answer to memory pressure is
+degraded latency, never an OOM.
+
+The engine knows nothing of the model's family. It is handed `model` and
+`params` and asks the model five things, and nothing else
+(docs/INFERENCE.md, "The model contract"):
+
+- `model.paged_cache(num_blocks, block_size, mesh)`: the paged cache, a
+  pytree the engine donates to every step and never looks inside (and
+  builds anew in `fail_all`);
+- `model.paged_step(params, ids[b, s], cache, block_tables, row_pos,
+  write_mask, adapters) -> (logits[b, s, vocab], cache)`: the one step,
+  where `adapters` is None or (banks, adapter_idx[b]);
+- `model.place_on_mesh(params, mesh) -> (params, tp)`: tensor-parallel
+  placement and the tp degree;
+- `model.early_exit_draft(params) -> (draft_model, draft_params)`: asked
+  only when speculation is on and no draft was injected;
+- `model.adapter_banks(n_rows, rank, mesh)`: the adapter banks' shapes
+  and shardings, asked only by `AdapterManager`.
 
 Two jitted programs serve every request mix, each compiled exactly once:
 
@@ -34,11 +51,11 @@ identical to plain decoding, whatever the draft proposes. A round's
 positions depend on how many drafts the last one accepted, which the
 host must read first, so speculation stays synchronous.
 
-A radix prefix cache (prefix_cache_enabled, continuous scheduling)
-keeps finished sequences' full-block KV prefixes refcounted in the
-arena; a new request adopts its longest cached match and prefills only
-the tail. Cached blocks are reclaimed LRU-by-leaf under pressure before
-any live sequence is preempted.
+A radix prefix cache (prefix_cache_enabled) keeps finished sequences'
+full-block KV prefixes refcounted in the arena; a new request adopts its
+longest cached match and prefills only the tail. Cached blocks are
+reclaimed LRU-by-leaf under pressure before any live sequence is
+preempted.
 
 All shapes are static (batch slots, chunk width, block-table width), so
 the engine's per-step work is argument values, never new programs; the
@@ -53,12 +70,6 @@ stepping thread is always in exactly one named step phase (`PHASES`
 below): the phases feed the step ledger in `stats()["steps"]` and, while
 a profile is being taken, the profiler's own trace
 (docs/OBSERVABILITY.md, "Step phases").
-
-`scheduling="static"` emulates the request-level `@serve.batch` baseline
-(gang admission, batch drains at the speed of its longest member, results
-delivered only when the whole gang finishes) through the same compute
-path — `bench.py:bench_inference` uses it so the comparison is pure
-scheduling policy.
 """
 
 from __future__ import annotations
@@ -83,13 +94,12 @@ PREFILL = "PREFILL"      # in a slot, prompt (+ recomputed tokens) mid-chunk
 DECODE = "DECODE"        # in a slot, emitting one token per step
 FINISHED = "FINISHED"
 FAILED = "FAILED"
-_DONE_HOLD = "DONE_HOLD"  # static mode: finished but holding its gang slot
 
 # Step phases (docs/OBSERVABILITY.md): the engine thread is in exactly one
 # of these at any moment, never two. Not to be confused with the
 # per-request spans engine.queue/prefill/decode/deliver/preempt.
 WAIT_WORK = "engine.wait_work"          # EngineLoop parked, nothing to do
-ADMIT = "engine.admit"                  # lock wait, gang release, _admit
+ADMIT = "engine.admit"                  # lock wait, _admit
 PREFILL_HOST = "engine.prefill.host"    # block claim, arrays, block table
 PREFILL_DISPATCH = "engine.prefill.dispatch"   # the jitted call returns
 PREFILL_SYNC = "engine.prefill.sync"    # harvest: a final chunk's token
@@ -106,8 +116,6 @@ PHASES = (WAIT_WORK, ADMIT, PREFILL_HOST, PREFILL_DISPATCH, PREFILL_SYNC,
 
 @dataclass(frozen=True)
 class EngineConfig:
-    model_size: str = "tiny"        # LlamaConfig preset (tiny/small/7b)
-    max_model_len: int = 256        # positions preset for tiny
     batch_slots: int = 4            # fixed decode batch width
     block_size: int = 16            # KV tokens per block
     num_blocks: int = 64            # arena size (incl. trash block 0)
@@ -115,20 +123,19 @@ class EngineConfig:
     prefill_chunk: int = 16         # prompt tokens per prefill step
     eos_id: Optional[int] = None    # stop token (None = budget only)
     use_jit: bool = True            # False = eager smoke mode
-    scheduling: str = "continuous"  # or "static" (@serve.batch emulation)
     # Model multiplexing (docs/MULTITENANCY.md): >0 hosts that many
     # LoRA-style adapters on this engine — one shared paged arena, the
     # SAME two compiled programs (adapter routing is a per-row index
     # argument), per-replica LRU residency. 0 = classic single model.
     max_adapters: int = 0
     lora_rank: int = 8
-    # Round-3 knobs (docs/INFERENCE.md). None = resolve from the global
-    # flag table at engine construction, so deployments pick them up via
-    # RAY_TPU_* env vars / _system_config without a config plumb-through.
-    prefix_cache_enabled: Optional[bool] = None
-    spec_decode_draft_len: Optional[int] = None
-    slo_default_class: Optional[str] = None
-    slo_interactive_reserved_slots: Optional[int] = None
+    # docs/INFERENCE.md: the radix prefix cache, speculation's draft
+    # length k (0 = off), the class of a request that names none, and
+    # the slots batch-class admissions leave free for interactive ones.
+    prefix_cache_enabled: bool = True
+    spec_decode_draft_len: int = 0
+    slo_default_class: str = "interactive"
+    slo_interactive_reserved_slots: int = 0
 
     @property
     def max_context(self) -> int:
@@ -174,7 +181,6 @@ class Request:
     processed: int = 0
     inflight: int = 0                 # tokens dispatched, not yet harvested
     cur_token: Optional[int] = None   # last harvested token (spec input)
-    _held_emits: List[tuple] = field(default_factory=list)
     _pinned_node: Any = None          # radix node pinned while scheduled
 
     @property
@@ -206,120 +212,62 @@ class _InFlight:
 class InferenceEngine:
     """Synchronous engine core; every public method takes the engine lock.
 
-    `model`/`params` may be injected (tests share one tiny checkpoint with
-    their reference loop); by default the config's Llama preset is built
-    with randomly initialized weights, matching the sampler examples.
+    `model` and `params` are what is served (module docstring: what the
+    engine asks of `model`). With no model the engine serves
+    `api.preset_model()`, `LLMServer`'s default.
     """
 
     def __init__(self, config: EngineConfig, model=None, params=None,
                  mesh=None, draft_model=None, draft_params=None):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.core.config import GLOBAL_CONFIG
-        from ray_tpu.models.llama import (
-            Llama,
-            LlamaConfig,
-            arena_sharding,
-            make_paged_arena,
-            shard_params_tp,
-        )
-
         cfg = config
-        if cfg.scheduling not in ("continuous", "static"):
-            raise ValueError(f"unknown scheduling {cfg.scheduling!r}")
         if cfg.max_blocks_per_seq * cfg.block_size < cfg.prefill_chunk:
             raise ValueError("prefill_chunk exceeds the per-seq context")
-        self.config = cfg
-        # Round-3 knobs: explicit config wins, else the global flag table.
-        self._draft_len = int(
-            cfg.spec_decode_draft_len
-            if cfg.spec_decode_draft_len is not None
-            else GLOBAL_CONFIG.spec_decode_draft_len)
-        self._slo_default = str(
-            cfg.slo_default_class if cfg.slo_default_class is not None
-            else GLOBAL_CONFIG.slo_default_class)
-        if self._slo_default not in ("interactive", "batch"):
+        if cfg.slo_default_class not in ("interactive", "batch"):
             raise ValueError(
-                f"unknown slo_default_class {self._slo_default!r}")
+                f"unknown slo_default_class {cfg.slo_default_class!r}")
+        self.config = cfg
+        self._draft_len = int(cfg.spec_decode_draft_len)
         self._slo_reserved = min(
             cfg.batch_slots - 1,
-            max(0, int(cfg.slo_interactive_reserved_slots
-                       if cfg.slo_interactive_reserved_slots is not None
-                       else GLOBAL_CONFIG.slo_interactive_reserved_slots)))
-        prefix_enabled = (
-            cfg.prefix_cache_enabled if cfg.prefix_cache_enabled is not None
-            else bool(GLOBAL_CONFIG.prefix_cache_enabled))
+            max(0, int(cfg.slo_interactive_reserved_slots)))
         if model is None:
-            mc = {"tiny": LlamaConfig.tiny(seq=cfg.max_model_len),
-                  "small": LlamaConfig.small(),
-                  "7b": LlamaConfig.llama7b()}[cfg.model_size]
-            model = Llama(mc)
-            params = jax.jit(lambda: model.init(
-                jax.random.PRNGKey(0),
-                jnp.zeros((1, 8), jnp.int32)))()
-        # Tensor-parallel serving (docs/SHARDED.md): with a mesh, params
-        # are placed into their tp NamedShardings (heads/mlp/vocab split
-        # over the "tp" axis) and the paged arena shards its kv-head dim
-        # WITH the heads — the jitted step programs below then compile to
+            from ray_tpu.inference.api import preset_model
+
+            model, params = preset_model()
+        # Tensor-parallel serving (docs/SHARDED.md): with a mesh the
+        # model places its params over the "tp" axis and shards its cache
+        # WITH them — the jitted step programs below then compile to
         # partitioned XLA with no code change here (GSPMD does the rest).
         self._mesh = mesh
         self._tp = 1
         if mesh is not None:
-            from ray_tpu.models.llama import _mesh_tp
-
-            self._tp = _mesh_tp(mesh)
-            params = shard_params_tp(model, params, mesh)
+            params, self._tp = model.place_on_mesh(params, mesh)
         self._model = model
         self._params = params
-        self._arena_sharding = (arena_sharding(model.config, mesh)
-                                if mesh is not None else None)
         self._bm = BlockManager(cfg.num_blocks, cfg.block_size)
-        self._arenas = make_paged_arena(model.config, cfg.num_blocks,
-                                        cfg.block_size,
-                                        sharding=self._arena_sharding)
-        # Radix prefix cache (continuous scheduling only: static gangs
-        # hold finished members' blocks for the drain, which fights the
-        # donate-to-cache lifecycle and the baseline it emulates never
-        # had prefix reuse anyway).
+        self._arenas = model.paged_cache(cfg.num_blocks, cfg.block_size, mesh)
         self._prefix: Optional[RadixPrefixCache] = None
-        if prefix_enabled and cfg.scheduling == "continuous":
+        if cfg.prefix_cache_enabled:
             self._prefix = RadixPrefixCache(self._bm)
         # Speculative decoding: the draft shares the target's BLOCK
-        # TABLES (host bookkeeping) but writes its own arenas — same
-        # geometry, so one table addresses both. Default draft: the
-        # TRUNCATED target (its first n_layer//2 blocks plus its embed/
-        # final-norm/lm-head, parameters shared by reference) — an
-        # early-exit draft that agrees with the target on easy tokens
-        # for free. Greedy verify makes the output independent of draft
-        # quality either way; a better draft just accepts more.
+        # TABLES (host bookkeeping) but writes its own cache — same
+        # geometry, so one table addresses both. With none injected the
+        # model gives an early-exit draft. Greedy verify makes the output
+        # independent of draft quality either way; a better draft just
+        # accepts more.
         self._draft_model = None
         self._draft_params = None
         self._draft_arenas = None
-        self._draft_arena_sharding = None
         if self._draft_len > 0:
             if draft_model is None:
-                import dataclasses as _dc
-
-                dcfg = _dc.replace(model.config,
-                                   n_layer=max(1, model.config.n_layer // 2))
-                draft_model = Llama(dcfg)
-                inner = params["params"] if "params" in params else params
-                dp = {k: inner[k]
-                      for k in ("embed", "final_norm", "lm_head")}
-                for i in range(dcfg.n_layer):
-                    dp[f"layer_{i}"] = inner[f"layer_{i}"]
-                draft_params = {"params": dp}
+                draft_model, draft_params = model.early_exit_draft(params)
             if mesh is not None:
-                draft_params = shard_params_tp(draft_model, draft_params,
-                                               mesh)
-                self._draft_arena_sharding = arena_sharding(
-                    draft_model.config, mesh)
+                draft_params, _ = draft_model.place_on_mesh(draft_params,
+                                                            mesh)
             self._draft_model = draft_model
             self._draft_params = draft_params
-            self._draft_arenas = make_paged_arena(
-                draft_model.config, cfg.num_blocks, cfg.block_size,
-                sharding=self._draft_arena_sharding)
+            self._draft_arenas = draft_model.paged_cache(
+                cfg.num_blocks, cfg.block_size, mesh)
         # Model multiplexing: the adapter bank + residency bookkeeping.
         # `adapter_source(model_id) -> per-layer rows` is registered by
         # the deployment (api.py) so a miss loads on demand.
@@ -328,7 +276,7 @@ class InferenceEngine:
         if cfg.max_adapters > 0:
             from ray_tpu.inference.adapters import AdapterManager
 
-            self._adapters = AdapterManager(model.config, cfg.max_adapters,
+            self._adapters = AdapterManager(model, cfg.max_adapters,
                                             cfg.lora_rank, mesh=mesh)
         # Each slot's last token, on the device (module docstring), and
         # the executions dispatched whose tokens are not read yet, oldest
@@ -382,56 +330,32 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.llama import Llama
-
-        model = self._model
+        step = self._model.paged_step
 
         # `tokens` is the device-resident last token of every slot. The
         # chunk writes its token into its slot's row (a chunk that is not
         # the prompt's last writes one nobody reads: the row decodes only
         # after the last has overwritten it); decode reads its input there
         # and leaves the rows it did not run as they were.
-        def chunk_token(tokens, logits, last_idx, slot):
+        #
+        # `adapters` is None or (banks, adapter_idx): None is an empty
+        # pytree to jit, so an engine without adapters compiles programs
+        # with no bank in them, and a multiplexed one takes the banks as
+        # ARGUMENTS (fixed shape/dtype/sharding): N adapters still mean
+        # exactly these programs (docs/MULTITENANCY.md).
+        def prefill_fn(params, arenas, adapters, tokens, ids, bt, pos,
+                       wmask, last_idx, slot):
+            logits, arenas = step(params, ids, arenas, bt, pos, wmask,
+                                  adapters)
             nxt = jnp.argmax(jnp.take_along_axis(
                 logits, last_idx[:, None, None], axis=1)[:, 0], axis=-1)
-            return tokens.at[slot].set(nxt.astype(jnp.int32))
+            return tokens.at[slot].set(nxt.astype(jnp.int32)), arenas
 
-        def step_tokens(tokens, logits, wmask):
+        def decode_fn(params, arenas, adapters, tokens, bt, pos, wmask):
+            logits, arenas = step(params, tokens[:, None], arenas, bt, pos,
+                                  wmask, adapters)
             nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return jnp.where(wmask[:, 0], nxt, tokens)
-
-        if self._adapters is not None:
-            # Multiplexed variants: the adapter banks + per-row index
-            # ride as ARGUMENTS (fixed shape/dtype/sharding), so N
-            # adapters still mean exactly these two programs — same
-            # count as the single-model engine, proven by the compile
-            # counters in the multiplex tests and bench_zoo.
-            def prefill_fn(params, arenas, banks, aidx, tokens, ids, bt,
-                           pos, wmask, last_idx, slot):
-                logits, arenas = model.apply(
-                    params, ids, arenas, bt, pos, wmask, banks, aidx,
-                    method=Llama.decode_paged)
-                return chunk_token(tokens, logits, last_idx, slot), arenas
-
-            def decode_fn(params, arenas, banks, aidx, tokens, bt, pos,
-                          wmask):
-                logits, arenas = model.apply(
-                    params, tokens[:, None], arenas, bt, pos, wmask, banks,
-                    aidx, method=Llama.decode_paged)
-                return step_tokens(tokens, logits, wmask), arenas
-        else:
-            def prefill_fn(params, arenas, tokens, ids, bt, pos, wmask,
-                           last_idx, slot):
-                logits, arenas = model.apply(params, ids, arenas, bt, pos,
-                                             wmask,
-                                             method=Llama.decode_paged)
-                return chunk_token(tokens, logits, last_idx, slot), arenas
-
-            def decode_fn(params, arenas, tokens, bt, pos, wmask):
-                logits, arenas = model.apply(params, tokens[:, None], arenas,
-                                             bt, pos, wmask,
-                                             method=Llama.decode_paged)
-                return step_tokens(tokens, logits, wmask), arenas
+            return jnp.where(wmask[:, 0], nxt, tokens), arenas
 
         if self.config.use_jit:
             # Arenas are donated: the update is in place on the device,
@@ -451,11 +375,11 @@ class InferenceEngine:
         self._propose_fn = None
         self._verify_fn = None
         if self._draft_len > 0:
-            draft = self._draft_model
+            draft_step = self._draft_model.paged_step
 
             def draft_prefill_fn(dparams, darenas, ids, bt, pos, wmask):
-                _, darenas = draft.apply(dparams, ids, darenas, bt, pos,
-                                         wmask, method=Llama.decode_paged)
+                _, darenas = draft_step(dparams, ids, darenas, bt, pos,
+                                        wmask)
                 return darenas
 
             def propose_fn(dparams, darenas, toks, bt, pos, wmask_seq):
@@ -467,9 +391,8 @@ class InferenceEngine:
                 # steps leave the draft KV complete through pos+k.
                 def body(carry, wm):
                     tok, p, arenas = carry
-                    logits, arenas = draft.apply(
-                        dparams, tok, arenas, bt, p, wm,
-                        method=Llama.decode_paged)
+                    logits, arenas = draft_step(dparams, tok, arenas, bt,
+                                                p, wm)
                     nxt = jnp.argmax(logits[:, -1],
                                      axis=-1).astype(jnp.int32)
                     return (nxt[:, None], p + 1, arenas), nxt
@@ -478,21 +401,10 @@ class InferenceEngine:
                     body, (toks, pos, darenas), wmask_seq)
                 return jnp.transpose(props), darenas     # [B, k+1]
 
-            if self._adapters is not None:
-                def verify_fn(params, arenas, banks, aidx, toks, bt, pos,
-                              wmask):
-                    logits, arenas = model.apply(
-                        params, toks, arenas, bt, pos, wmask, banks, aidx,
-                        method=Llama.decode_paged)
-                    return jnp.argmax(logits,
-                                      axis=-1).astype(jnp.int32), arenas
-            else:
-                def verify_fn(params, arenas, toks, bt, pos, wmask):
-                    logits, arenas = model.apply(
-                        params, toks, arenas, bt, pos, wmask,
-                        method=Llama.decode_paged)
-                    return jnp.argmax(logits,
-                                      axis=-1).astype(jnp.int32), arenas
+            def verify_fn(params, arenas, adapters, toks, bt, pos, wmask):
+                logits, arenas = step(params, toks, arenas, bt, pos, wmask,
+                                      adapters)
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32), arenas
 
             if self.config.use_jit:
                 self._draft_prefill_fn = jax.jit(draft_prefill_fn,
@@ -542,12 +454,6 @@ class InferenceEngine:
         registered adapter specs here)."""
         self._adapter_source = fn
 
-    def adapter_stats(self) -> Optional[Dict[str, Any]]:
-        if self._adapters is None:
-            return None
-        with self._lock:
-            return self._adapters.stats()
-
     def _resolve_adapter_locked(self, model_id: Optional[str]) -> int:
         if model_id is None:
             return 0
@@ -574,7 +480,7 @@ class InferenceEngine:
         cfg = self.config
         prompt = [int(t) for t in prompt] or [0]
         max_new_tokens = max(1, int(max_new_tokens))
-        slo = slo_class if slo_class is not None else self._slo_default
+        slo = slo_class if slo_class is not None else cfg.slo_default_class
         if slo not in ("interactive", "batch"):
             raise ValueError(f"unknown slo_class {slo!r} "
                              "(expected 'interactive' or 'batch')")
@@ -618,8 +524,8 @@ class InferenceEngine:
         emissions: List[tuple] = []
         with self._lock:
             req = self._live.get(request_id)
-            if req is None or req.done or req.state == _DONE_HOLD:
-                return False   # gone, or already complete (static hold)
+            if req is None or req.done:
+                return False
             if req.state == WAITING:
                 self._waiting.remove(req)
             self._finish(req, emissions, error="cancelled")
@@ -628,9 +534,7 @@ class InferenceEngine:
 
     def has_work(self) -> bool:
         with self._lock:
-            # Any occupied slot is work: static DONE_HOLD members still
-            # need their gang-release step. So is an execution whose
-            # tokens nobody has read.
+            # An execution whose tokens nobody has read is work too.
             return bool(self._waiting) or bool(self._inflight) or any(
                 r is not None for r in self._slots)
 
@@ -651,7 +555,6 @@ class InferenceEngine:
         try:
             clock.enter(ADMIT)
             with self._lock:
-                self._release_static_gang(emissions)
                 self._admit()
                 before = len(self._inflight)
                 ran = self._prefill_step()
@@ -717,18 +620,13 @@ class InferenceEngine:
 
     def _admit(self):
         cfg = self.config
-        if cfg.scheduling == "static":
-            # Gang admission: only into an EMPTY batch, all at once.
-            if any(r is not None for r in self._slots):
-                return
         while self._waiting:
             free_slots = [i for i, r in enumerate(self._slots) if r is None]
             if not free_slots:
                 return
             req = None
             for cand in self._waiting:   # sorted by (class, arrival)
-                if (cfg.scheduling == "continuous"
-                        and cand.slo_class != "interactive"
+                if (cand.slo_class != "interactive"
                         and len(free_slots) <= self._slo_reserved):
                     # Reserved headroom: batch-class admissions must
                     # leave this many slots open for interactive
@@ -822,15 +720,6 @@ class InferenceEngine:
             if (self._prefix is not None
                     and self._prefix.evict_for(deficit) > 0):
                 continue
-            if self.config.scheduling == "static":
-                # A drained gang member's KV is never read again — reclaim
-                # its blocks before preempting anything still running.
-                holders = [r for r in self._scheduled()
-                           if r.state == _DONE_HOLD
-                           and self._bm.registered(r.request_id)]
-                if holders:
-                    self._bm.free(holders[0].request_id)
-                    continue
             if not self._preempt_one():
                 return False
             if req.state == WAITING:   # preempted itself
@@ -863,15 +752,9 @@ class InferenceEngine:
                 np.asarray([chunk - 1], np.int32),
                 np.asarray([req.slot], np.int32))
         clock.enter(PREFILL_DISPATCH)
-        if self._adapters is not None:
-            aidx = np.asarray([req.adapter_row], np.int32)
-            self._tokens, self._arenas = self._call(
-                "prefill", self._prefill_fn, self._params, self._arenas,
-                self._adapters.device_banks(), aidx, self._tokens, *args)
-        else:
-            self._tokens, self._arenas = self._call(
-                "prefill", self._prefill_fn, self._params, self._arenas,
-                self._tokens, *args)
+        self._tokens, self._arenas = self._call(
+            "prefill", self._prefill_fn, self._params, self._arenas,
+            self._adapter_args([req]), self._tokens, *args)
         if self._draft_len > 0:
             # Keep the draft's KV in lockstep: same chunk, same blocks.
             # Cached-prefix blocks carry draft KV from their original
@@ -901,9 +784,7 @@ class InferenceEngine:
         clock.enter(DECODE_HOST)
         active: List[Request] = []
         for req in list(self._scheduled()):
-            # A static gang member whose last token is in flight holds
-            # its slot and has nothing more to dispatch.
-            if req.state != DECODE or req.budget_dispatched:
+            if req.state != DECODE:
                 continue
             # Writing the row's token at position `processed` needs
             # capacity for processed + 1 tokens.
@@ -926,18 +807,9 @@ class InferenceEngine:
             wmask[i, 0] = True
         bt = self._block_table_rows(rows)
         clock.enter(DECODE_DISPATCH)
-        if self._adapters is not None:
-            aidx = np.zeros(B, np.int32)
-            for req in active:
-                aidx[req.slot] = req.adapter_row
-            self._tokens, self._arenas = self._call(
-                "decode", self._decode_fn, self._params, self._arenas,
-                self._adapters.device_banks(), aidx, self._tokens, bt, pos,
-                wmask)
-        else:
-            self._tokens, self._arenas = self._call(
-                "decode", self._decode_fn, self._params, self._arenas,
-                self._tokens, bt, pos, wmask)
+        self._tokens, self._arenas = self._call(
+            "decode", self._decode_fn, self._params, self._arenas,
+            self._adapter_args(rows), self._tokens, bt, pos, wmask)
         clock.enter(DECODE_HOST)
         self._ledger["decode"] += 1
         self._ledger["decode_ahead"] += any(
@@ -951,15 +823,13 @@ class InferenceEngine:
         """Book the execution just dispatched: start its tokens' copy to
         the host, and let go of the slot of every row whose budget ends
         with it, so that the next admission does not wait for the
-        harvest. (A static gang member keeps its slot until the gang
-        drains.) The row keeps its blocks until `_finish`."""
+        harvest. The row keeps its blocks until `_finish`."""
         self._tokens.copy_to_host_async()
         rows = []
         for req in reqs:
             rows.append((req, req.slot, req.preemptions))
             req.inflight += 1
-            if (req.budget_dispatched
-                    and self.config.scheduling != "static"):
+            if req.budget_dispatched:
                 self._slots[req.slot] = None
                 req.slot = None
         self._inflight.append(_InFlight(decode, self._tokens, rows))
@@ -1049,17 +919,9 @@ class InferenceEngine:
             vtoks[i, 1:] = props[i, :k]
             vmask[i, :allow + 1] = True
         clock.enter(DECODE_DISPATCH)
-        if self._adapters is not None:
-            aidx = np.zeros(B, np.int32)
-            for req, _ in active:
-                aidx[req.slot] = req.adapter_row
-            tgt, self._arenas = self._call(
-                "verify", self._verify_fn, self._params, self._arenas,
-                self._adapters.device_banks(), aidx, vtoks, bt, pos, vmask)
-        else:
-            tgt, self._arenas = self._call(
-                "verify", self._verify_fn, self._params, self._arenas,
-                vtoks, bt, pos, vmask)
+        tgt, self._arenas = self._call(
+            "verify", self._verify_fn, self._params, self._arenas,
+            self._adapter_args(rows), vtoks, bt, pos, vmask)
         clock.enter(DECODE_SYNC)
         tgt = np.asarray(tgt)                   # [B, k+1] target argmaxes
         clock.enter(DECODE_EMIT)
@@ -1115,6 +977,18 @@ class InferenceEngine:
         with jax.set_mesh(self._mesh):
             return fn(*args)
 
+    def _adapter_args(self, rows):
+        """The programs' one `adapters` argument for these batch rows
+        (requests, or None where a row is idle): None, or the banks and
+        each row's bank row."""
+        if self._adapters is None:
+            return None
+        import numpy as np
+
+        aidx = np.asarray([0 if r is None else r.adapter_row for r in rows],
+                          np.int32)
+        return self._adapters.device_banks(), aidx
+
     def _block_table_rows(self, reqs) -> "np.ndarray":  # noqa: F821
         import numpy as np
 
@@ -1130,38 +1004,26 @@ class InferenceEngine:
     def _emit_token(self, req: Request, token: int, emissions):
         req.generated.append(token)
         req.cur_token = token
-        self._record_emit(req, ("token", token), emissions)
+        now = time.monotonic()
+        if req.first_token_at is None:
+            req.first_token_at = now
+        self._tokens_emitted += 1
+        self._rate_window.append((now, 1))
+        # Prune the stale head here, not just in stats(): an unpolled
+        # engine must not grow a tuple per token forever.
+        while self._rate_window and now - self._rate_window[0][0] > 5.0:
+            self._rate_window.pop(0)
+        # Queued even with no callback: delivery is stamped there.
+        emissions.append((req.on_token, req, token))
         if (len(req.generated) >= req.max_new_tokens
                 or (self.config.eos_id is not None
                     and token == self.config.eos_id)):
             self._finish(req, emissions)
 
-    def _record_emit(self, req: Request, event, emissions):
-        """Route one client-visible event. Static mode holds everything
-        back until the gang drains — that IS the baseline's latency."""
-        if self.config.scheduling == "static" and event[0] == "token":
-            req._held_emits.append(event)
-            return
-        self._fire(req, event, emissions)
-
-    def _fire(self, req: Request, event, emissions):
-        kind, payload = event
-        if kind == "token":
-            now = time.monotonic()
-            if req.first_token_at is None:
-                req.first_token_at = now
-            self._tokens_emitted += 1
-            self._rate_window.append((now, 1))
-            # Prune the stale head here, not just in stats(): an unpolled
-            # engine must not grow a tuple per token forever.
-            while self._rate_window and now - self._rate_window[0][0] > 5.0:
-                self._rate_window.pop(0)
-            # Queued even with no callback: delivery is stamped there.
-            emissions.append((req.on_token, req, payload))
-        else:  # finish
-            req.finished_at = time.monotonic()
-            if req.on_finish is not None:
-                emissions.append((req.on_finish, req, None))
+    def _emit_finish(self, req: Request, emissions):
+        req.finished_at = time.monotonic()
+        if req.on_finish is not None:
+            emissions.append((req.on_finish, req, None))
 
     def _finish(self, req: Request, emissions, error: Optional[str] = None):
         req.state = FAILED if error else FINISHED
@@ -1170,14 +1032,6 @@ class InferenceEngine:
             self._failed += 1
         else:
             self._finished += 1
-        if self.config.scheduling == "static" and not error:
-            # Hold the slot (and blocks) until the whole gang drains:
-            # request-level batching runs at the longest member's speed.
-            req.state = _DONE_HOLD
-            return
-        for event in req._held_emits:   # static error: flush, then fail
-            self._fire(req, event, emissions)
-        req._held_emits = []
         # Donate the finished sequence's full-block prefix to the radix
         # cache BEFORE freeing: insert increfs the novel suffix, free
         # decrefs the request's own references, net the cache keeps
@@ -1197,24 +1051,20 @@ class InferenceEngine:
             self._slots[req.slot] = None
             req.slot = None
         self._live.pop(req.request_id, None)
-        self._fire(req, ("finish", None), emissions)
+        self._emit_finish(req, emissions)
         self._record_phase_spans(req)
 
     def fail_all(self, error: str) -> int:
         """Abort every scheduled and waiting request with `error` (the
         EngineLoop's circuit breaker after repeated step failures —
         callers must see the failure, not hang on futures nothing will
-        resolve). Completed static gang members are released as
-        successes. Returns how many requests were failed."""
+        resolve). Returns how many requests were failed."""
         emissions: List[tuple] = []
         failed = 0
         with self._lock:
             for req in list(self._scheduled()):
-                if req.state == _DONE_HOLD:
-                    self._release_hold(req, emissions)
-                else:
-                    self._finish(req, emissions, error=error)
-                    failed += 1
+                self._finish(req, emissions, error=error)
+                failed += 1
             # Rows that gave up their slot with their last token in flight.
             for rec in self._inflight:
                 for req, _, _ in rec.rows:
@@ -1229,52 +1079,25 @@ class InferenceEngine:
                 self._failed += 1
                 failed += 1
                 self._live.pop(req.request_id, None)
-                self._fire(req, ("finish", None), emissions)
+                self._emit_finish(req, emissions)
             self._waiting.clear()
             # Rebuild the arena: a step that failed mid-execution consumed
             # the DONATED buffers without producing replacements, so the
             # old self._arenas may reference deleted arrays — without this
             # every future request would fail on 'Array has been deleted'
             # and the circuit breaker could never actually recover.
-            from ray_tpu.models.llama import make_paged_arena
-
-            self._arenas = make_paged_arena(
-                self._model.config, self.config.num_blocks,
-                self.config.block_size, sharding=self._arena_sharding)
+            cfg = self.config
+            self._arenas = self._model.paged_cache(
+                cfg.num_blocks, cfg.block_size, self._mesh)
             if self._draft_arenas is not None:
-                self._draft_arenas = make_paged_arena(
-                    self._draft_model.config, self.config.num_blocks,
-                    self.config.block_size,
-                    sharding=self._draft_arena_sharding)
+                self._draft_arenas = self._draft_model.paged_cache(
+                    cfg.num_blocks, cfg.block_size, self._mesh)
             # Fresh arenas invalidate every cached block's contents: a
             # warm radix tree pointing at zeroed KV would serve garbage.
             if self._prefix is not None:
                 self._prefix.clear()
         self._deliver(emissions)
         return failed
-
-    def _release_static_gang(self, emissions):
-        if self.config.scheduling != "static":
-            return
-        scheduled = self._scheduled()
-        if not scheduled or any(r.state != _DONE_HOLD for r in scheduled):
-            return
-        for req in scheduled:
-            self._release_hold(req, emissions)
-
-    def _release_hold(self, req: Request, emissions):
-        """Complete a static DONE_HOLD member: flush its held events in
-        order, free its slot and blocks, fire its finish."""
-        req.state = FINISHED
-        self._live.pop(req.request_id, None)
-        for event in req._held_emits:
-            self._fire(req, event, emissions)
-        req._held_emits = []
-        self._bm.free(req.request_id)
-        self._slots[req.slot] = None
-        req.slot = None
-        self._fire(req, ("finish", None), emissions)
-        self._record_phase_spans(req)
 
     def _record_deliver_span(self, req: Request):
         """engine.deliver: first token harvested -> its on_token about to

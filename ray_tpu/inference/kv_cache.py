@@ -1,8 +1,8 @@
 """Paged KV-cache block manager (vLLM/PagedAttention-shaped).
 
 The cache arena is a preallocated pool of fixed-size blocks shared by every
-sequence (`models/llama.py:make_paged_arena` holds the actual K/V tensors);
-this module owns the bookkeeping: which physical blocks belong to which
+sequence (the model's `paged_cache` holds the actual K/V tensors); this
+module owns the bookkeeping: which physical blocks belong to which
 sequence, in logical order, with refcounts so a fork shares its parent's
 blocks copy-on-write. The manager never touches device memory — it hands
 out indices, and the engine's jitted step functions read/write the arena

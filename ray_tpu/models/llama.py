@@ -26,7 +26,7 @@ Three forward paths share parameters:
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -77,6 +77,17 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.n_embd // self.n_head
+
+
+def llama_preset(name: str, seq: int = 256) -> LlamaConfig:
+    """The preset a deployment names (`LLMServer`, `LlamaSampler`):
+    tiny (at `seq` positions), small or 7b."""
+    presets = {"tiny": lambda: LlamaConfig.tiny(seq=seq),
+               "small": LlamaConfig.small, "7b": LlamaConfig.llama7b}
+    if name not in presets:
+        raise ValueError(f"unknown model size {name!r} "
+                         f"(expected one of {sorted(presets)})")
+    return presets[name]()
 
 
 def _dense(features: int, axes: Tuple[str, ...], cfg: LlamaConfig, name: str):
@@ -368,6 +379,54 @@ class Llama(nn.Module):
         x = self.final_norm(x)
         return self.lm_head(x), new_arenas
 
+    # What the serving engine asks of the model it is handed
+    # (docs/INFERENCE.md, "The model contract"): all of it, and each a
+    # call into the functions of this file. `nowrap`: they run on the
+    # unbound module, outside `apply`.
+
+    @nn.nowrap
+    def paged_cache(self, num_blocks: int, block_size: int, mesh=None):
+        """The paged cache, opaque to the engine: `make_paged_arena`,
+        sharded with the kv heads under a tp mesh."""
+        sharding = None if mesh is None else arena_sharding(self.config, mesh)
+        return make_paged_arena(self.config, num_blocks, block_size,
+                                sharding=sharding)
+
+    @nn.nowrap
+    def paged_step(self, params, ids, cache, block_tables, row_pos,
+                   write_mask, adapters=None):
+        """One step, `decode_paged`: (logits [b, s, vocab], cache).
+        `adapters` is None or (banks, adapter_idx)."""
+        return self.apply(params, ids, cache, block_tables, row_pos,
+                          write_mask, *(adapters or ()),
+                          method=Llama.decode_paged)
+
+    @nn.nowrap
+    def place_on_mesh(self, params, mesh):
+        """(params in their tp layout, the mesh's tp degree)."""
+        return shard_params_tp(self, params, mesh), _mesh_tp(mesh)
+
+    @nn.nowrap
+    def early_exit_draft(self, params):
+        """(draft model, its params) for speculation when none was
+        injected: the first n_layer // 2 blocks under this model's own
+        embedding, final norm and head, every leaf shared by reference.
+        It agrees with the target on easy tokens for free."""
+        cfg = replace(self.config, n_layer=max(1, self.config.n_layer // 2))
+        inner = params["params"] if "params" in params else params
+        keep = ("embed", "final_norm", "lm_head",
+                *(f"layer_{i}" for i in range(cfg.n_layer)))
+        return Llama(cfg), {"params": {k: inner[k] for k in keep}}
+
+    @nn.nowrap
+    def adapter_banks(self, n_rows: int, rank: int, mesh=None):
+        """What `AdapterManager` holds: (layers, one layer's bank
+        shapes, their dtype, their shardings on `mesh` or None)."""
+        cfg = self.config
+        return (cfg.n_layer, lora_bank_shapes(cfg, n_rows, rank),
+                jnp.dtype(cfg.dtype),
+                None if mesh is None else lora_bank_shardings(cfg, mesh))
+
 
 # --------------------------------------------------------------------------- #
 # Pipeline stages: the model partitioned by layer for cross-process pp
@@ -521,8 +580,6 @@ def make_paged_arena(cfg: LlamaConfig, num_blocks: int, block_size: int,
         # whole arena to one device — at real tp widths that excess can
         # OOM device 0 at startup even though the sharded steady state
         # fits. One jitted zeros program, executed 2*n_layer times.
-        import jax
-
         zeros = jax.jit(lambda: jnp.zeros(shape, cfg.dtype),
                         out_shardings=sharding)
     return [(zeros(), zeros()) for _ in range(cfg.n_layer)]
@@ -555,11 +612,9 @@ def lora_bank_shardings(cfg: LlamaConfig, mesh):
     (rank/embed dims are tiny or already replicated). Mirrors
     arena_sharding's no-trailing-None discipline so bank reloads can
     never perturb the jit cache key."""
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     validate_tp(cfg, _mesh_tp(mesh))
-    del jax
     rep = NamedSharding(mesh, P())
     return (rep,
             rep,
@@ -581,9 +636,7 @@ def make_adapter_weights(cfg: LlamaConfig, rank: int, seed: int,
     out = []
     for _ in range(cfg.n_layer):
         rows = []
-        for shape in ((cfg.n_embd, rank), (rank, cfg.n_embd),
-                      (cfg.n_head * cfg.head_dim, rank),
-                      (rank, cfg.n_embd)):
+        for _, *shape in lora_bank_shapes(cfg, 1, rank):
             w = rng.standard_normal(shape, dtype=np.float32) * scale
             rows.append((w * 1.0).astype(dt))  # ml_dtypes casts in numpy
         out.append(tuple(rows))

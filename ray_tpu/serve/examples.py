@@ -160,11 +160,9 @@ class LlamaSampler(_SamplerMetrics):
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.llama import Llama, LlamaConfig, make_cache
+        from ray_tpu.models.llama import Llama, llama_preset, make_cache
 
-        cfg = {"tiny": LlamaConfig.tiny(seq=max_seq),
-               "small": LlamaConfig.small(),
-               "7b": LlamaConfig.llama7b()}[model_size]
+        cfg = llama_preset(model_size, max_seq)
         self._cfg = cfg
         self._max_seq = min(max_seq, cfg.n_positions)
         self._default_new = default_new_tokens

@@ -206,19 +206,6 @@ def _case_cancel(tiny_llama, plain):
                             (reqs[2], whole[2])]
 
 
-def _case_static(tiny_llama, plain):
-    """Gang members hold their slot, and their tokens, to the drain."""
-    engine = _engine(tiny_llama, batch_slots=2, scheduling="static")
-    streams = _Streams()
-    mix = [([1, 2], 2), ([3, 4], 9), ([5], 1), ([6, 7, 8], 4)]
-    reqs = [streams.submit(engine, p, m) for p, m in mix]
-    engine.run_until_idle()
-    # Whole gangs, in order: nobody of the second before the first is out.
-    assert streams.finished[:2] == [r.request_id for r in reqs[:2]]
-    return engine, streams, [
-        (r, plain(engine, p, m)) for r, (p, m) in zip(reqs, mix)]
-
-
 def _case_adapter(tiny_llama, plain):
     from ray_tpu.models.llama import make_adapter_weights
 
@@ -261,7 +248,6 @@ CASES = {
     "max_new_tokens_2": lambda t, p: _case_budgets(t, p, 2),
     "preemption": _case_preemption,
     "cancel_between_dispatch_and_harvest": _case_cancel,
-    "static_gang": _case_static,
     "adapter_engine": _case_adapter,
     "prefix_cache_hit": _case_prefix_cache,
 }

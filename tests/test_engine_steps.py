@@ -234,18 +234,6 @@ def test_first_token_is_delivered_while_the_decode_of_its_step_runs(
     engine.check_no_leaks()
 
 
-def test_static_gang_stamps_delivery_at_release(tiny_llama):
-    engine = _engine(tiny_llama, batch_slots=2, scheduling="static")
-    reqs = [engine.add_request(_prompt(4, i), max_new_tokens=m)
-            for i, m in enumerate((2, 6))]
-    engine.run_until_idle()
-    for req in reqs:
-        assert req.first_token_delivered_at >= req.first_token_at
-    # Held until the gang drained: the short one is delivered no sooner
-    # than the long one finished computing.
-    assert reqs[0].first_token_delivered_at >= reqs[1].first_token_at
-
-
 def test_deliver_span_joins_the_request_trace(tiny_llama):
     from ray_tpu.core.config import GLOBAL_CONFIG
     from ray_tpu.observability import tracing

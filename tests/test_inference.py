@@ -455,22 +455,6 @@ def test_fail_all_and_submit_after_stop(tiny_llama):
         loop.submit([1], 2)
 
 
-def test_static_gang_holds_results_until_drain(tiny_llama):
-    """The @serve.batch-shaped baseline: a short request admitted with a
-    long one sees its tokens only when the whole gang drains."""
-    engine = _make_engine(tiny_llama, batch_slots=2, scheduling="static")
-    r_short = engine.add_request([1, 2], max_new_tokens=2,
-                                 request_id="short")
-    r_long = engine.add_request([3, 4], max_new_tokens=16,
-                                request_id="long")
-    r_next = engine.add_request([5], max_new_tokens=2, request_id="next")
-    engine.run_until_idle()
-    assert r_short.state == r_long.state == r_next.state == "FINISHED"
-    assert abs(r_short.first_token_at - r_long.finished_at) < 0.5
-    assert r_next.first_token_at >= r_long.finished_at   # second gang
-    engine.check_no_leaks()
-
-
 # --------------------------------------------------------------------- #
 # Radix prefix cache through the engine
 # --------------------------------------------------------------------- #
@@ -778,27 +762,3 @@ def test_llm_server_streams_over_http(ray_start_regular):
         assert len(body["result"]["ids"]) == 5
     finally:
         serve.shutdown()
-
-
-# --------------------------------------------------------------------- #
-# Continuous vs static under Poisson load (bench-backed; slow)
-# --------------------------------------------------------------------- #
-
-
-@pytest.mark.slow
-def test_continuous_beats_static_under_poisson_load(tiny_llama):
-    """Acceptance: iteration-level scheduling beats gang batching on
-    aggregate tokens/s AND p99 TTFT under mixed-length Poisson arrivals,
-    with zero leaked blocks and zero decode recompiles. ~30s of decode
-    loops: excluded from the tier-1 budget, exercised via bench.py."""
-    import bench
-
-    model, params = tiny_llama
-    cont = bench._inference_poisson_run("continuous", quick=True,
-                                        model=model, params=params)
-    stat = bench._inference_poisson_run("static", quick=True,
-                                        model=model, params=params)
-    assert cont["leaked_blocks"] == 0 and stat["leaked_blocks"] == 0
-    assert cont["decode_recompiles"] == 0
-    assert cont["tokens_per_sec"] > stat["tokens_per_sec"], (cont, stat)
-    assert cont["ttft_p99_ms"] < stat["ttft_p99_ms"], (cont, stat)

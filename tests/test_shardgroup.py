@@ -91,14 +91,18 @@ def test_engine_tp_decode_parity_and_compile_once(multi_device_workers):
     discipline intact on both."""
     import jax
 
+    from ray_tpu.inference.api import preset_model
     from ray_tpu.inference.engine import EngineConfig, InferenceEngine
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
-    cfg = EngineConfig(model_size="tiny", max_model_len=128)
+    cfg = EngineConfig()
+    model, params = preset_model("tiny", 128)
     mesh = build_mesh(MeshSpec({"tp": 2}), devices=jax.devices()[:2])
     outs = {}
-    for name, engine in (("single", InferenceEngine(cfg)),
-                         ("tp2", InferenceEngine(cfg, mesh=mesh))):
+    for name, engine in (
+            ("single", InferenceEngine(cfg, model=model, params=params)),
+            ("tp2", InferenceEngine(cfg, model=model, params=params,
+                                    mesh=mesh))):
         reqs = [engine.add_request([1, 2, 3, 4, 5], max_new_tokens=10),
                 engine.add_request([7, 8, 9], max_new_tokens=8)]
         engine.run_until_idle()
@@ -107,9 +111,8 @@ def test_engine_tp_decode_parity_and_compile_once(multi_device_workers):
         assert_compiles_once(engine.stats(), "prefill_compiles",
                              "decode_compiles", context=name)
     assert outs["single"] == outs["tp2"]
-    # The arena really is sharded on its kv-head dim.
-    engine_tp = InferenceEngine(cfg, mesh=mesh)
-    spec = engine_tp._arenas[0][0].sharding.spec
+    # The tp2 engine's arena really is sharded on its kv-head dim.
+    spec = engine._arenas[0][0].sharding.spec
     assert tuple(spec) == (None, None, "tp")
 
 
