@@ -61,12 +61,138 @@ def mha_reference(q, k, v, causal: bool = True,
 
 
 # --------------------------------------------------------------------------- #
+# The causal tile schedule
+# --------------------------------------------------------------------------- #
+#
+# A grid block is what one DMA brings into VMEM; the score square is counted
+# in (tile_q, tile_k) tiles. Inside a grid block the kernels compute STRIPES:
+# the forward and dQ kernels take tile_q rows at a time against exactly the
+# columns those rows may see, the dK/dV kernel takes tile_k columns against
+# the rows that may see them. A stripe's extent is static: how the diagonal
+# crosses a grid block depends only on `off`, the block's first row minus its
+# first column, which takes a handful of values over the grid; the kernels
+# hold one specialisation of their body for each. Only the tiles the
+# diagonal crosses pay for the mask. Why stripes and not a loop over tiles:
+# every row of a tile pays for its softmax statistics, lane broadcasts and
+# accumulator updates once per tile it is in, and a 256-wide tile is too
+# narrow to carry that (PERF.md section 6, PR 30).
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _masked(s, lo: int, hi: int, shift: int, q_axis: int = 0):
+    """Scores with the causal mask on columns [lo, hi) only. Along
+    `q_axis` run query positions, along the other key positions; the first
+    query position of the piece sits `shift` after its first key position,
+    and a score stays where q_pos >= k_pos."""
+    if lo == hi:
+        return s
+    shape = (s.shape[0], hi - lo)
+    keep = (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+            ) >= -shift
+    parts = [s[:, :lo], jnp.where(keep, s[:, lo:hi], _NEG_INF), s[:, hi:]]
+    parts = [x for x in parts if x.shape[1]]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _eye(rows: int, cols: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _column(row):
+    """[1, n] -> [n, 1] without a transpose: the diagonal of the row's
+    sublane broadcast, summed over lanes."""
+    n = row.shape[1]
+    return jnp.sum(jnp.where(_eye(n, n), jnp.broadcast_to(row, (n, n)), 0.0),
+                   axis=1, keepdims=True)
+
+
+def _store_row(ref, stat):
+    """Write per-row statistics held lane-replicated, stat [n, LANES] with
+    stat[i, :] == x_i, as the row ref[0] = [1, n]: the diagonal of each
+    LANES-row chunk, summed over sublanes."""
+    n = stat.shape[0]
+    for lo in range(0, n, _STATS_LANES):
+        m = min(_STATS_LANES, n - lo)
+        chunk = jnp.where(_eye(m, _STATS_LANES), stat[lo:lo + m], 0.0)
+        ref[0, :, lo:lo + m] = jnp.sum(chunk, axis=0, keepdims=True)[:, :m]
+
+
+def _row_stripes(off, block_q: int, block_k: int, tile_q: int, tile_k: int):
+    """[(row, full, live)]: the tile_q rows from `row` see the block's
+    columns [0, live); columns [0, full) need no mask. `off` None: no
+    diagonal in this block."""
+    out = []
+    for row in range(0, block_q, tile_q):
+        if off is None:
+            out.append((row, block_k, block_k))
+            continue
+        last = off + row + tile_q - 1        # last column the stripe sees
+        live = min(block_k, max(0, (last // tile_k + 1) * tile_k))
+        full = min(live, max(0, (off + row + 1) // tile_k * tile_k))
+        if live:
+            out.append((row, full, live))
+    return out
+
+
+def _col_stripes(off, block_q: int, block_k: int, tile_q: int, tile_k: int):
+    """[(col, first, full)]: the tile_k columns from `col` are seen by the
+    block's rows [first, block_q); rows [full, block_q) need no mask."""
+    out = []
+    for col in range(0, block_k, tile_k):
+        if off is None:
+            out.append((col, 0, 0))
+            continue
+        first = min(block_q, max(0, (col - off) // tile_q * tile_q))
+        full = min(block_q,
+                   max(first, -((off - col - tile_k + 1) // tile_q) * tile_q))
+        if first < block_q:
+            out.append((col, first, full))
+    return out
+
+
+def _by_offset(causal: bool, off, block_q: int, block_k: int, n_q: int,
+               n_k: int, run):
+    """Call `run(static off)` under a guard for every way the diagonal
+    crosses a block of this grid, `run(None)` for the blocks below it (or
+    when there is no mask); blocks above it run nothing."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        return run(None)
+    offs = {i * block_q - j * block_k for i in range(n_q) for j in range(n_k)}
+    if any(o >= block_k - 1 for o in offs):
+        pl.when(off >= block_k - 1)(lambda: run(None))
+    for o in sorted(o for o in offs if -block_q < o < block_k - 1):
+        pl.when(off == o)(functools.partial(run, o))
+
+
+def _tile_counts(seq: int, tile_q: int, tile_k: int, causal: bool) -> tuple:
+    """(tiles in the seq x seq square, tiles the stripes cover), per
+    (batch, head)."""
+    nq, nk = seq // tile_q, seq // tile_k
+    if not causal:
+        return nq * nk, nq * nk
+    return nq * nk, sum(min(((i + 1) * tile_q - 1) // tile_k + 1, nk)
+                        for i in range(nq))
+
+
+# --------------------------------------------------------------------------- #
 # Forward kernel
 # --------------------------------------------------------------------------- #
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                scale: float, causal: bool, block_q: int, block_k: int):
+                scale: float, causal: bool, block_q: int, block_k: int,
+                tile_q: int, tile_k: int, n_q: int, n_k: int):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -78,58 +204,57 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def body():
-        q = q_ref[0].astype(jnp.float32)              # [bq, d]
-        k = k_ref[0].astype(jnp.float32)              # [bk, d]
-        v = v_ref[0].astype(jnp.float32)              # [bk, d]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]                         # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)     # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                        # [bq, bk]
-        correction = jnp.exp(m_prev - m_new)          # [bq, 1]
-        l_new = correction * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    def run(off):
+        for row, full, live in _row_stripes(off, block_q, block_k, tile_q,
+                                            tile_k):
+            rows = pl.ds(row, tile_q)
+            q = q_ref[0, rows, :].astype(jnp.float32)         # [tq, d]
+            k = k_ref[0, :live, :].astype(jnp.float32)        # [live, d]
+            v = v_ref[0, :live, :].astype(jnp.float32)
+            s = _dot(q, k, _NT) * scale                       # [tq, live]
+            if off is not None:
+                s = _masked(s, full, live, off + row - full)
+            m_prev = m_scr[rows, :][:, :1]                    # [tq, 1]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)                            # [tq, live]
+            correction = jnp.exp(m_prev - m_new)              # [tq, 1]
+            l_new = (correction * l_scr[rows, :][:, :1]
+                     + jnp.sum(p, axis=1, keepdims=True))
+            acc_scr[rows, :] = (acc_scr[rows, :] * correction
+                                + _dot(p, v, _NN))
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (tile_q, _STATS_LANES))
+            l_scr[rows, :] = jnp.broadcast_to(l_new, (tile_q, _STATS_LANES))
 
-    if causal:
-        # Skip blocks entirely above the diagonal.
-        @pl.when(ki * block_k <= qi * block_q + (block_q - 1))
-        def _run():
-            body()
-    else:
-        body()
+    _by_offset(causal, qi * block_q - ki * block_k, block_q, block_k, n_q,
+               n_k, run)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        # Row stats kept lane-broadcast: lse is (bh, seq, LANES) in HBM so
-        # its blocks are (8, 128)-tileable on TPU; the backward kernels read
-        # lane 0. Costs seq*LANES*4B per (b,h) — negligible vs the KV cache
-        # and the price of a layout XLA can tile.
-        lse_ref[0] = m_scr[...] + jnp.log(
-            jnp.maximum(l_scr[...], 1e-30))
+        # lse leaves as a row, (bh, 1, seq) in HBM: lane-dense, O(seq), and
+        # the layout both backward kernels read.
+        _store_row(lse_ref, m_scr[...] + jnp.log(
+            jnp.maximum(l_scr[...], 1e-30)))
 
 
+# The two wrappers are jitted on their own so that a model's layers share ONE
+# trace and ONE lowering of each kernel: 24 layers otherwise lower the three
+# kernels to Mosaic 24 times on every process start, compile-cache hit or
+# not (PERF.md section 6, PR 25 and PR 30). `interpret` is an argument so
+# that the trace is keyed by it.
+_STATIC = ("causal", "scale", "block_q", "block_k", "tile_q", "tile_k",
+           "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_forward(q, k, v, causal: bool, scale: float,
-                   block_q: int, block_k: int):
-    """Returns (out [b,h,sq,d], lse [bh, sq, 1]).
-
-    The kernel writes lse lane-broadcast as (bh, sq, LANES) so its blocks
-    are (8,128)-tileable, but only lane 0 is returned — the saved training
-    residual stays O(seq), not O(seq*128); the backward re-broadcasts
-    transiently."""
+                   block_q: int, block_k: int, tile_q: int, tile_k: int,
+                   interpret: bool = False):
+    """Returns (out [b,h,sq,d], lse [bh, 1, sq]): the per-row logsumexp as
+    a row per (batch, head), which is the saved training residual (O(seq))
+    and what the backward kernels read as it is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -142,7 +267,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
     nq = pl.cdiv(seq_q, block_q)
     nk = pl.cdiv(seq_k, block_k)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               tile_q=tile_q, tile_k=tile_k, n_q=nq, n_k=nk)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
@@ -153,12 +279,11 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STATS_LANES),
-                         lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq_q, _STATS_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
@@ -168,10 +293,10 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_fwd",
     )(q3, k3, v3)
-    return out.reshape(batch, heads, seq_q, d), lse[..., :1]
+    return out.reshape(batch, heads, seq_q, d), lse
 
 
 # --------------------------------------------------------------------------- #
@@ -181,7 +306,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_scr, *, scale: float, causal: bool,
-                   block_q: int, block_k: int):
+                   block_q: int, block_k: int, tile_q: int, tile_k: int,
+                   n_q: int, n_k: int):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -191,35 +317,26 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]                        # [bq, 1] (lane 0)
-        delta = delta_ref[0][:, :1]                    # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                           # [bq, bk]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                  # [bq, bk]
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def run(off):
+        for row, full, live in _row_stripes(off, block_q, block_k, tile_q,
+                                            tile_k):
+            rows = pl.ds(row, tile_q)
+            q = q_ref[0, rows, :].astype(jnp.float32)      # [tq, d]
+            do = do_ref[0, rows, :].astype(jnp.float32)
+            k = k_ref[0, :live, :].astype(jnp.float32)     # [live, d]
+            v = v_ref[0, :live, :].astype(jnp.float32)
+            lse = _column(lse_ref[0, :, rows])             # [tq, 1]
+            delta = _column(delta_ref[0, :, rows])
+            s = _dot(q, k, _NT) * scale                    # [tq, live]
+            if off is not None:
+                s = _masked(s, full, live, off + row - full)
+            p = jnp.exp(s - lse)
+            dp = _dot(do, v, _NT)
+            ds = p * (dp - delta) * scale                  # [tq, live]
+            dq_scr[rows, :] += _dot(ds, k, _NN)
 
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + (block_q - 1))
-        def _run():
-            body()
-    else:
-        body()
+    _by_offset(causal, qi * block_q - ki * block_k, block_q, block_k, n_q,
+               n_k, run)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
@@ -228,7 +345,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                    causal: bool, block_q: int, block_k: int):
+                    causal: bool, block_q: int, block_k: int,
+                    tile_q: int, tile_k: int, n_q: int, n_k: int):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
@@ -239,40 +357,29 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]                        # lane 0
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                           # [bq, bk]
-        dv_scr[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),           # p^T @ do -> [bk, d]
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                  # [bq, bk]
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),           # ds^T @ q -> [bk, d]
-            preferred_element_type=jnp.float32)
+    def run(off):
+        # Transposed throughout, s^T = K Q^T: P^T dO and dS^T Q are then
+        # plain products, and lse / delta are wanted as the rows they are.
+        for col, first, full in _col_stripes(off, block_q, block_k, tile_q,
+                                             tile_k):
+            cols = pl.ds(col, tile_k)
+            k = k_ref[0, cols, :].astype(jnp.float32)        # [tk, d]
+            v = v_ref[0, cols, :].astype(jnp.float32)
+            q = q_ref[0, first:, :].astype(jnp.float32)      # [rows, d]
+            do = do_ref[0, first:, :].astype(jnp.float32)
+            lse = lse_ref[0, :, first:]                      # [1, rows]
+            delta = delta_ref[0, :, first:]
+            st = _dot(k, q, _NT) * scale                     # [tk, rows]
+            if off is not None:
+                st = _masked(st, 0, full - first, off + first - col, q_axis=1)
+            pt = jnp.exp(st - lse)
+            dv_scr[cols, :] += _dot(pt, do, _NN)             # [tk, d]
+            dpt = _dot(v, do, _NT)
+            dst = pt * (dpt - delta) * scale                 # [tk, rows]
+            dk_scr[cols, :] += _dot(dst, q, _NN)
 
-    if causal:
-        # Q blocks strictly above the diagonal contribute nothing to this
-        # K block: skip when the last q row < first k row.
-        @pl.when(qi * block_q + (block_q - 1) >= ki * block_k)
-        def _run():
-            body()
-    else:
-        body()
+    _by_offset(causal, qi * block_q - ki * block_k, block_q, block_k, n_q,
+               n_k, run)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finalize():
@@ -280,8 +387,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
-                    block_q: int, block_k: int):
+                    block_q: int, block_k: int, tile_q: int, tile_k: int,
+                    interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -292,19 +401,18 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
     k3 = k.reshape(bh, seq_k, d)
     v3 = v.reshape(bh, seq_k, d)
     do3 = g.reshape(bh, seq_q, d)
-    # delta_i = rowsum(dO * O) (the softmax-jacobian diagonal term),
-    # broadcast over stats lanes like lse. Both broadcasts are transient
-    # kernel inputs, not saved residuals.
+    # delta_i = rowsum(dO * O) (the softmax-jacobian diagonal term), a row
+    # per (batch, head) like lse.
     delta = jnp.sum(do3.astype(jnp.float32)
                     * out.reshape(bh, seq_q, d).astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    delta = jnp.broadcast_to(delta, (bh, seq_q, _STATS_LANES))
-    lse = jnp.broadcast_to(lse, (bh, seq_q, _STATS_LANES))
+                    axis=-1).reshape(bh, 1, seq_q)
     nq = pl.cdiv(seq_q, block_q)
     nk = pl.cdiv(seq_k, block_k)
 
+    tiles = dict(block_q=block_q, block_k=block_k, tile_q=tile_q,
+                 tile_k=tile_k, n_q=nq, n_k=nk)
     dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                  block_q=block_q, block_k=block_k)
+                                  **tiles)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(bh, nq, nk),
@@ -313,10 +421,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STATS_LANES),
-                         lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STATS_LANES),
-                         lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
@@ -324,13 +430,12 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse, delta)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                   causal=causal, block_q=block_q,
-                                   block_k=block_k)
+                                   causal=causal, **tiles)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(bh, nk, nq),
@@ -339,10 +444,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STATS_LANES),
-                         lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STATS_LANES),
-                         lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -357,7 +460,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse, delta)
 
@@ -371,26 +474,46 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
 # --------------------------------------------------------------------------- #
 
 
-def pick_block_sizes(seq: int, d: int) -> tuple:
-    """Block-size heuristic: biggest blocks that fit VMEM comfortably.
-    VMEM budget ~16 MiB; fwd scratch ~ block_q*(2*LANES + d)*4B plus the
-    q/k/v/o blocks. Asymmetric q=512/k=1024 measured fastest on v5e for
-    d<=128 (fewer grid steps on the streamed contraction dim); shrink for
-    bigger heads."""
+def pick_block_sizes(seq: int, d: int, causal: bool = True) -> tuple:
+    """(block_q, block_k, tile_q, tile_k) for a [*, *, seq, d] call: a pure
+    function of the shape and the mask.
+
+    Grid blocks stay as large as VMEM takes comfortably. Measured on one
+    v5e at [8,16,1024,64] bf16 causal (my chip runs, PR 30; us a call, fwd /
+    dq / dkv, same tiles): q blocks of 256 / 512 / 1024 rows against a
+    1024-row k block took 782 / 678 / 563, 500 / 424 / 391, 925 / 717 /
+    631: a grid step costs 0.4-0.9 us, about what a 256 x 256 tile's
+    matmuls take. So at d <= 128 one block covers 1024 positions both ways
+    (with k = 1024 the 512 x 1024 blocks of before PR 30 computed the whole
+    square at seq 1024: the grid-level skip never fired), and the causal
+    skip happens inside a block, on tiles.
+
+    Tiles are what the causal schedule counts in and the finest extent a
+    stripe is cut to (see "The causal tile schedule"). tile_k = 128 lets
+    the dK/dV stripes start at the diagonal to the lane tile. tile_q is the
+    height of a forward / dQ stripe: 128 where every grid block sits on the
+    diagonal (seq within one block: 464 against 563 us forward at
+    [8,16,1024,64], and 36 of 64 tiles run against 40), 256 where most
+    blocks lie below it or there is no mask, and a stripe is as wide as the
+    block ([1,12,8192,64]: 2,203 against 2,304 us; non-causal
+    [8,16,1024,64]: 604 against 706). Wider heads keep the blocks they had
+    (not measured), one tile each."""
     if d <= 128:
-        bq, bk = 512, 1024
+        bq = bk = 1024
+        tq, tk = (128 if causal and seq <= bk else 256), 128
     elif d <= 256:
-        bq, bk = 256, 256
+        bq = bk = tq = tk = 256
     else:
-        bq, bk = 128, 128
+        bq = bk = tq = tk = 128
     while seq % bq and bq > 128:
         bq //= 2
     while seq % bk and bk > 128:
         bk //= 2
-    return bq, bk
+    return bq, bk, min(tq, bq), min(tk, bk)
 
 
-# (pass, path, reason, shape, dtype, block_q, block_k) -> traced calls
+# (pass, path, reason, shape, dtype, block_q, block_k) -> traced calls; the
+# flash passes append (causal, tiles, tiles_live) to theirs
 _CALLS: collections.Counter = collections.Counter()
 _CALLS_LOCK = threading.Lock()
 
@@ -400,12 +523,22 @@ def pallas_status() -> list:
     entry per distinct (pass, shape, dtype, blocks) with `path` "pallas"
     or "reference", the dispatch rule's `reason` for a reference call, and
     the number of traced calls. A caller that asked for flash and needs to
-    know it got flash (chip_smoke.py, bench.py) reads this."""
+    know it got flash (chip_smoke.py, bench.py) reads this.
+
+    Entries of the flash passes (`fwd`, `bwd`) also say `causal`, and on
+    the Pallas path `tiles` and `tiles_live`: the (tile_q, tile_k) tiles in
+    one (batch, head)'s score square and those whose body the kernels run
+    (None for a reference call). `tiles_live / tiles` near 1.0 on a causal
+    call means the causal skip is dead at that shape."""
     with _CALLS_LOCK:
         items = list(_CALLS.items())
-    return [{"pass": p, "path": path, "reason": reason, "shape": list(shape),
-             "dtype": dtype, "block_q": bq, "block_k": bk, "calls": n}
-            for (p, path, reason, shape, dtype, bq, bk), n in items]
+    out = []
+    for (p, path, reason, shape, dtype, bq, bk, *schedule), n in items:
+        out.append({"pass": p, "path": path, "reason": reason,
+                    "shape": list(shape), "dtype": dtype, "block_q": bq,
+                    "block_k": bk, "calls": n,
+                    **dict(zip(("causal", "tiles", "tiles_live"), schedule))})
+    return out
 
 
 def reset_pallas_status() -> None:
@@ -415,11 +548,12 @@ def reset_pallas_status() -> None:
         _CALLS.clear()
 
 
-def _dispatch(pass_: str, q, k, block_q: int, block_k: int) -> bool:
+def _dispatch(pass_: str, q, k, causal: bool, blocks: tuple) -> bool:
     """True when the Pallas kernels take this call. Records the decision."""
     platform = _platform()
     seq_q, d = q.shape[2], q.shape[3]
     seq_k = k.shape[2]
+    block_q, block_k, tile_q, tile_k = blocks
     if _interpret() and platform == "tpu":
         raise RuntimeError(
             "RAY_TPU_PALLAS_INTERPRET=1 is a CPU test switch; on platform "
@@ -436,8 +570,11 @@ def _dispatch(pass_: str, q, k, block_q: int, block_k: int) -> bool:
         reason = "head_dim not a multiple of 64"
     else:
         reason = ""
+    tiles = (None, None) if reason else _tile_counts(seq_q, tile_q, tile_k,
+                                                     causal)
     key = (pass_, "reference" if reason else "pallas", reason,
-           tuple(q.shape), jnp.dtype(q.dtype).name, block_q, block_k)
+           tuple(q.shape), jnp.dtype(q.dtype).name, block_q, block_k,
+           causal, *tiles)
     with _CALLS_LOCK:
         _CALLS[key] += 1
     return not reason
@@ -457,19 +594,24 @@ def flash_attention(q, k, v, causal: bool = True,
     return out
 
 
-def _resolve(q, scale, block_q, block_k):
+def _resolve(q, causal, scale, block_q, block_k):
+    """(scale, (block_q, block_k, tile_q, tile_k)): explicit blocks keep
+    the rule's tiles, cut to the block."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     seq = q.shape[2]
-    if not block_q or not block_k:
-        block_q, block_k = pick_block_sizes(seq, q.shape[-1])
-    return scale, min(block_q, seq), min(block_k, seq)
+    bq, bk, tq, tk = pick_block_sizes(seq, q.shape[-1], causal)
+    if block_q and block_k:
+        bq, bk = block_q, block_k
+    bq, bk = min(bq, seq), min(bk, seq)
+    return scale, (bq, bk, min(tq, bq), min(tk, bk))
 
 
 def _attn_fwd_impl(q, k, v, causal, scale, block_q, block_k):
-    scale, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _dispatch("fwd", q, k, bq, bk):
-        return _flash_forward(q, k, v, causal, scale, bq, bk)
+    scale, blocks = _resolve(q, causal, scale, block_q, block_k)
+    if _dispatch("fwd", q, k, causal, blocks):
+        return _flash_forward(q, k, v, causal, scale, *blocks,
+                              interpret=_interpret())
     return mha_reference(q, k, v, causal=causal, scale=scale), None
 
 
@@ -480,9 +622,10 @@ def _attn_fwd(q, k, v, causal, scale, block_q, block_k):
 
 def _attn_bwd(causal, scale, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
-    scale_v, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _dispatch("bwd", q, k, bq, bk):
-        return _flash_backward(q, k, v, out, lse, g, causal, scale_v, bq, bk)
+    scale_v, blocks = _resolve(q, causal, scale, block_q, block_k)
+    if _dispatch("bwd", q, k, causal, blocks):
+        return _flash_backward(q, k, v, out, lse, g, causal, scale_v,
+                               *blocks, interpret=_interpret())
     _, vjp = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal, scale),
                      q, k, v)
     return vjp(g)

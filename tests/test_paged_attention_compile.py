@@ -135,3 +135,46 @@ def test_records_say_pallas_for_the_cells_shapes(monkeypatch):
         assert pa._dispatch(q, arena)
     assert pa.paged_calls() == {("paged_decode", "pallas"): 1,
                                 ("paged_prefill", "pallas"): 1}
+
+
+@pytest.mark.parametrize("shape, dtype, causal", [
+    ((8, 16, 1024, 64), "bfloat16", True),     # the train cells' local shape
+    ((8, 16, 1024, 64), "bfloat16", False),
+    ((1, 12, 8192, 64), "bfloat16", True),     # chip_smoke's long leg
+    ((2, 4, 512, 64), "float32", True),        # chip_smoke's on-chip parity
+    ((1, 8, 4096, 128), "bfloat16", True),     # a Llama-shaped head
+    ((1, 4, 8192, 128), "float32", True),      # the most VMEM the rule asks
+    ((2, 4, 1024, 256), "bfloat16", True),
+    ((2, 4, 64, 64), "bfloat16", True),        # a block under one lane tile
+])
+def test_flash_kernels_compile_at_the_rules_schedule(one_chip, monkeypatch,
+                                                     shape, dtype, causal):
+    """Forward and backward of `flash_attention` at the blocks and tiles
+    `pick_block_sizes` gives lower and compile for a described v5e with
+    the three kernels in the program. Interpret mode cannot refuse a slice
+    off the tiling or a kernel over its VMEM; the chip's compiler can."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+
+    def fwd_bwd(q, k, v, do):
+        out, vjp = jax.vjp(
+            lambda q, k, v: attention.flash_attention(q, k, v, causal),
+            q, k, v)
+        return out, vjp(do)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    attention.reset_pallas_status()
+    hlo = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
+    # Found the way chip_smoke.py's check_kernels finds them.
+    kernels = [re.search(r"flash_(fwd|bwd_dq|bwd_dkv)", line).group(0)
+               for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert {(e["pass"], e["path"], e["causal"])
+            for e in attention.pallas_status()} == {
+        ("fwd", "pallas", causal), ("bwd", "pallas", causal)}
