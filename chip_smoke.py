@@ -239,8 +239,7 @@ def gpt2_leg(config):
                 (d.memory_stats() or {}).get("bytes_in_use")
                 for d in devices]
             rec["hlo_has_global_attention_operand"] = (
-                f"[{batch_size * cfg.n_head},{seq},"
-                f"{cfg.n_embd // cfg.n_head}]" in hlo)
+                f"[{batch_size},{seq},{3 * cfg.n_embd}]" in hlo)
         rec["finite"] = math.isfinite(first_loss) and math.isfinite(
             rec.get("loss", first_loss))
         out[tag] = rec
@@ -290,14 +289,14 @@ def check_attention(rec, n_layer, local_shape, what):
 
 def check_kernels(rec, n_layer, local_shape, what):
     """The compiled program holds the three Mosaic kernels, once per layer
-    at least, with outputs of the local [b*h, s, d] shape."""
+    at least, with outputs of the local [b, s, h*d] shape."""
     b, h, s, d = local_shape
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         shapes = rec["kernels"].get(name, [])
         check(len(shapes) >= n_layer,
               f"{what}: {len(shapes)} compiled {name} calls for {n_layer} "
               f"layers (found {({k: len(v) for k, v in rec['kernels'].items()})})")
-        check(all(x == f"bf16[{b * h},{s},{d}]" for x in shapes),
+        check(all(x == f"bf16[{b},{s},{h * d}]" for x in shapes),
               f"{what}: {name} compiled at {sorted(set(shapes))}")
 
 

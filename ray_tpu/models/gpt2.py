@@ -22,7 +22,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import flash_attention_sharded
+from ray_tpu.ops.attention import flash_attention_bse_sharded
 
 
 @dataclass(frozen=True)
@@ -81,29 +81,31 @@ class Block(nn.Module):
                          name="ln_1")(x)
         # Column-parallel QKV (tp shards heads), row-parallel output proj.
         qkv = _dense(3 * cfg.n_embd, ("embed", "mlp"), cfg, "c_attn")(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        b, s, _ = q.shape
-
-        def heads(t):
-            t = t.reshape(b, s, cfg.n_head, head_dim)
-            t = nn.with_logical_constraint(t, ("batch", "seq", "heads", None))
-            return t.transpose(0, 2, 1, 3)  # [b, heads, seq, d]
-
-        q, k, v = heads(q), heads(k), heads(v)
-        if cfg.use_ring:
-            from ray_tpu.ops.ring_attention import ring_attention
-
-            attn = ring_attention(q, k, v, axis_name="sp", causal=True)
-        elif cfg.use_flash:
-            attn = flash_attention_sharded(
-                q, k, v, nn.logical_to_mesh_axes(("batch", "heads", None,
-                                                  None)),
+        b, s, _ = qkv.shape
+        if cfg.use_flash and not cfg.use_ring:
+            # The kernels read c_attn's output where it lies and write what
+            # c_proj reads: no split, no [b, heads, s, d] in between.
+            attn = flash_attention_bse_sharded(
+                qkv, head_dim,
+                nn.logical_to_mesh_axes(("batch", None, "heads")),
                 True, None, cfg.flash_block_q, cfg.flash_block_k)
         else:
-            from ray_tpu.ops.attention import mha_reference
+            def heads(t):
+                t = t.reshape(b, s, cfg.n_head, head_dim)
+                t = nn.with_logical_constraint(
+                    t, ("batch", "seq", "heads", None))
+                return t.transpose(0, 2, 1, 3)  # [b, heads, seq, d]
 
-            attn = mha_reference(q, k, v, causal=True)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_embd)
+            q, k, v = (heads(t) for t in jnp.split(qkv, 3, axis=-1))
+            if cfg.use_ring:
+                from ray_tpu.ops.ring_attention import ring_attention
+
+                attn = ring_attention(q, k, v, axis_name="sp", causal=True)
+            else:
+                from ray_tpu.ops.attention import mha_reference
+
+                attn = mha_reference(q, k, v, causal=True)
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_embd)
         attn = _dense(cfg.n_embd, ("mlp", "embed"), cfg, "c_proj")(attn)
         if cfg.dropout:
             attn = nn.Dropout(cfg.dropout)(attn, deterministic=deterministic)

@@ -7,9 +7,21 @@ kernels (dQ with K/V streaming; dK/dV with Q streaming) recomputing
 probabilities from the saved logsumexp — memory stays O(block^2) for
 training too, which is the whole point for long context.
 
-Layout: q,k,v [batch, heads, seq, head_dim]; grids put batch*heads and the
-output-block dim as parallel dimensions and stream the contraction dim as
-the innermost "arbitrary" dim with VMEM scratch accumulators.
+Layout: the kernels read and write [batch, seq, heads*head_dim] arrays, the
+layout the projections beside attention produce and consume, and find a
+head by a BlockSpec COLUMN block: `flash_attention_bse` takes a layer's
+fused [batch, seq, 3*heads*head_dim] projection as three views of one
+array (or q, k, v apart) and returns [batch, seq, heads*head_dim], with no
+split, reshape or transpose around the kernels. A column block is
+max(128, head_dim) lanes: at head_dim 64 two adjacent heads, which the
+bodies tell apart by a lane mask (no operand is ever 64 lanes wide in HBM
+or cut at lane 64 in VMEM). `flash_attention` on [batch, heads, seq,
+head_dim] is the same kernels on the free reshape [batch*heads, seq,
+head_dim], one head a column block: for callers whose q, k, v are already
+per head (ring attention's rotating chunks, GQA after its repeat). Grids
+put batch, the column block and the output-block dim as parallel
+dimensions and stream the contraction dim as the innermost "arbitrary" dim
+with VMEM scratch accumulators.
 
 Dispatch is a rule, not a fallback: on platform `tpu` a call whose shape
 the kernels take goes to the kernels, and a kernel the compiler refuses
@@ -115,15 +127,51 @@ def _column(row):
                    axis=1, keepdims=True)
 
 
-def _store_row(ref, stat):
+def _store_row(ref, h: int, stat):
     """Write per-row statistics held lane-replicated, stat [n, LANES] with
-    stat[i, :] == x_i, as the row ref[0] = [1, n]: the diagonal of each
+    stat[i, :] == x_i, as the row ref[0, h] = [1, n]: the diagonal of each
     LANES-row chunk, summed over sublanes."""
     n = stat.shape[0]
     for lo in range(0, n, _STATS_LANES):
         m = min(_STATS_LANES, n - lo)
         chunk = jnp.where(_eye(m, _STATS_LANES), stat[lo:lo + m], 0.0)
-        ref[0, :, lo:lo + m] = jnp.sum(chunk, axis=0, keepdims=True)[:, :m]
+        ref[0, h, :, lo:lo + m] = jnp.sum(chunk, axis=0,
+                                          keepdims=True)[:, :m]
+
+
+# Heads in a column block. A block of `lanes` lanes holds lanes // d heads
+# side by side. The bodies never slice it at a head's edge: a head's scores
+# are the product of the block with the OTHER heads' lanes of one small
+# operand zeroed (the contraction runs over all the lanes, which costs the
+# 128-deep MXU no more than 64 of them), and a product that writes head
+# columns is taken for the whole block and kept on that head's lanes.
+
+
+def _head_lanes(n: int, lanes: int, d: int, h: int):
+    """bool [n, lanes]: the lanes of the block's head h. Compares, not
+    `lane // d == h`: Mosaic lowers every integer division through a traced
+    helper, 5 ms of each process start apiece (PERF.md section 6, PR 33)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n, lanes), 1)
+    lo, hi = h * d, (h + 1) * d
+    if hi == lanes:
+        return lane >= lo
+    return lane < hi if lo == 0 else (lane >= lo) & (lane < hi)
+
+
+def _only_head(x, d: int, h: int):
+    """x [n, lanes] with every lane outside head h zeroed."""
+    if x.shape[1] == d:
+        return x
+    return jnp.where(_head_lanes(*x.shape, d, h), x, 0.0)
+
+
+def _by_head(parts, n: int, lanes: int, d: int):
+    """[n, lanes] that reads parts[h] ([n, lanes] or [n, 1]) on head h's
+    lanes."""
+    out = parts[-1]
+    for h in range(len(parts) - 2, -1, -1):
+        out = jnp.where(_head_lanes(n, lanes, d, h), parts[h], out)
+    return jnp.broadcast_to(out, (n, lanes))
 
 
 def _row_stripes(off, block_q: int, block_k: int, tile_q: int, tile_k: int):
@@ -188,15 +236,22 @@ def _tile_counts(seq: int, tile_q: int, tile_k: int, causal: bool) -> tuple:
 # --------------------------------------------------------------------------- #
 # Forward kernel
 # --------------------------------------------------------------------------- #
+#
+# Every kernel sees [batch, seq, width] operands through column blocks of
+# `lanes` lanes (grid axis 1) that hold lanes // d heads; lse and delta are
+# rows, [batch, heads, 1, seq], a block of them the rows of the column
+# block's heads.
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                scale: float, causal: bool, block_q: int, block_k: int,
-                tile_q: int, tile_k: int, n_q: int, n_k: int):
+                d: int, scale: float, causal: bool, block_q: int,
+                block_k: int, tile_q: int, tile_k: int, n_q: int, n_k: int):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    lanes = acc_scr.shape[1]
+    heads = range(lanes // d)
 
     @pl.when(ki == 0)
     def _init():
@@ -208,35 +263,81 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         for row, full, live in _row_stripes(off, block_q, block_k, tile_q,
                                             tile_k):
             rows = pl.ds(row, tile_q)
-            q = q_ref[0, rows, :].astype(jnp.float32)         # [tq, d]
-            k = k_ref[0, :live, :].astype(jnp.float32)        # [live, d]
+            q = q_ref[0, rows, :].astype(jnp.float32)         # [tq, lanes]
+            k = k_ref[0, :live, :].astype(jnp.float32)        # [live, lanes]
             v = v_ref[0, :live, :].astype(jnp.float32)
-            s = _dot(q, k, _NT) * scale                       # [tq, live]
-            if off is not None:
-                s = _masked(s, full, live, off + row - full)
-            m_prev = m_scr[rows, :][:, :1]                    # [tq, 1]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(s - m_new)                            # [tq, live]
-            correction = jnp.exp(m_prev - m_new)              # [tq, 1]
-            l_new = (correction * l_scr[rows, :][:, :1]
-                     + jnp.sum(p, axis=1, keepdims=True))
-            acc_scr[rows, :] = (acc_scr[rows, :] * correction
-                                + _dot(p, v, _NN))
-            m_scr[rows, :] = jnp.broadcast_to(m_new, (tile_q, _STATS_LANES))
-            l_scr[rows, :] = jnp.broadcast_to(l_new, (tile_q, _STATS_LANES))
+            corrections, pvs = [], []
+            for h in heads:
+                s = _dot(_only_head(q, d, h), k, _NT) * scale  # [tq, live]
+                if off is not None:
+                    s = _masked(s, full, live, off + row - full)
+                m_prev = m_scr[h, rows, :][:, :1]             # [tq, 1]
+                m_cur = jnp.max(s, axis=1, keepdims=True)
+                m_new = jnp.maximum(m_prev, m_cur)
+                p = jnp.exp(s - m_new)                        # [tq, live]
+                correction = jnp.exp(m_prev - m_new)          # [tq, 1]
+                l_new = (correction * l_scr[h, rows, :][:, :1]
+                         + jnp.sum(p, axis=1, keepdims=True))
+                corrections.append(correction)
+                pvs.append(_dot(p, v, _NN))                   # [tq, lanes]
+                m_scr[h, rows, :] = jnp.broadcast_to(
+                    m_new, (tile_q, _STATS_LANES))
+                l_scr[h, rows, :] = jnp.broadcast_to(
+                    l_new, (tile_q, _STATS_LANES))
+            acc_scr[rows, :] = (
+                acc_scr[rows, :] * _by_head(corrections, tile_q, lanes, d)
+                + _by_head(pvs, tile_q, lanes, d))
 
     _by_offset(causal, qi * block_q - ki * block_k, block_q, block_k, n_q,
                n_k, run)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[:, :1], 1e-30)
+        denom = _by_head([jnp.maximum(l_scr[h][:, :1], 1e-30) for h in heads],
+                         block_q, lanes, d)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        # lse leaves as a row, (bh, 1, seq) in HBM: lane-dense, O(seq), and
-        # the layout both backward kernels read.
-        _store_row(lse_ref, m_scr[...] + jnp.log(
-            jnp.maximum(l_scr[...], 1e-30)))
+        # lse leaves as a row per head: lane-dense, O(seq), and the layout
+        # both backward kernels read.
+        for h in heads:
+            _store_row(lse_ref, h, m_scr[h] + jnp.log(
+                jnp.maximum(l_scr[h], 1e-30)))
+
+
+def _column_block(e: int, d: int) -> int:
+    """Lanes of a column block on operands that hold e // d heads of d
+    lanes side by side: max(128, d), so two heads at d = 64; a single head
+    is its own block whatever its width."""
+    return d if e == d else max(_STATS_LANES, d)
+
+
+def _specs(e: int, d: int, fused: bool, block_q: int, block_k: int,
+           where=lambda b, c, i, j: (b, c, i, j)):
+    """BlockSpecs of a kernel's operands on [batch, seq, width] arrays of
+    e // d heads, by name. `where` takes a grid step to (batch, column
+    block, q block, k block). `fused`: q, k and v are the thirds of ONE
+    [batch, seq, 3e] array, taken as three views of it with the column
+    index moved on by a third."""
+    from jax.experimental import pallas as pl
+
+    lanes = _column_block(e, d)
+    third = e // lanes if fused else 0
+
+    def block(rows, pick, shift=0):
+        def index(*step):
+            b, c, i, j = where(*step)
+            return b, (i, j)[pick], c + shift
+        return pl.BlockSpec((1, rows, lanes), index)
+
+    def stat_index(*step):
+        b, c, i, _ = where(*step)
+        return b, c, 0, i
+
+    return {"q": block(block_q, 0), "k": block(block_k, 1, third),
+            "v": block(block_k, 1, 2 * third),
+            # a [batch, seq, e] array by q blocks (o, dO, dQ) / by k blocks
+            "rows": block(block_q, 0), "cols": block(block_k, 1),
+            "stat": pl.BlockSpec((1, lanes // d, 1, block_q), stat_index),
+            "lanes": lanes}
 
 
 # The two wrappers are jitted on their own so that a model's layers share ONE
@@ -244,59 +345,53 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 # kernels to Mosaic 24 times on every process start, compile-cache hit or
 # not (PERF.md section 6, PR 25 and PR 30). `interpret` is an argument so
 # that the trace is keyed by it.
-_STATIC = ("causal", "scale", "block_q", "block_k", "tile_q", "tile_k",
-           "interpret")
+_STATIC = ("d", "fused", "causal", "scale", "block_q", "block_k", "tile_q",
+           "tile_k", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _flash_forward(q, k, v, causal: bool, scale: float,
+def _flash_forward(q, k, v, d: int, fused: bool, causal: bool, scale: float,
                    block_q: int, block_k: int, tile_q: int, tile_k: int,
                    interpret: bool = False):
-    """Returns (out [b,h,sq,d], lse [bh, 1, sq]): the per-row logsumexp as
-    a row per (batch, head), which is the saved training residual (O(seq))
-    and what the backward kernels read as it is."""
+    """q, k, v [batch, seq, e] (`fused`: the same [batch, seq, 3e] array
+    three times). Returns (out [batch, seq, e], lse [batch, heads, 1,
+    seq]): the per-row logsumexp as a row per (batch, head), which is the
+    saved training residual (O(seq)) and what the backward kernels read as
+    it is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    batch, heads, seq_q, d = q.shape
-    seq_k = k.shape[2]
-    bh = batch * heads
-    q3 = q.reshape(bh, seq_q, d)
-    k3 = k.reshape(bh, seq_k, d)
-    v3 = v.reshape(bh, seq_k, d)
+    batch, seq_q, width = q.shape
+    seq_k = k.shape[1]
+    e = width // 3 if fused else width
     nq = pl.cdiv(seq_q, block_q)
     nk = pl.cdiv(seq_k, block_k)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+    sp = _specs(e, d, fused, block_q, block_k)
+    lanes = sp["lanes"]
+    kernel = functools.partial(_fwd_kernel, d=d, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
                                tile_q=tile_q, tile_k=tile_k, n_q=nq, n_k=nk)
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-        ],
+        grid=(batch, e // lanes, nq, nk),
+        in_specs=[sp["q"], sp["k"], sp["v"]],
+        out_specs=[sp["rows"], sp["stat"]],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
+            jax.ShapeDtypeStruct((batch, seq_q, e), q.dtype),
+            jax.ShapeDtypeStruct((batch, e // d, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((lanes // d, block_q, _STATS_LANES), jnp.float32),
+            pltpu.VMEM((lanes // d, block_q, _STATS_LANES), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
         ),
         interpret=interpret,
         name="flash_fwd",
-    )(q3, k3, v3)
-    return out.reshape(batch, heads, seq_q, d), lse
+    )(q, k, v)
 
 
 # --------------------------------------------------------------------------- #
@@ -304,53 +399,70 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
 # --------------------------------------------------------------------------- #
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, scale: float, causal: bool,
-                   block_q: int, block_k: int, tile_q: int, tile_k: int,
-                   n_q: int, n_k: int):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+                   delta_ref, dq_scr, delta_scr, *, d: int, scale: float,
+                   causal: bool, block_q: int, block_k: int, tile_q: int,
+                   tile_k: int, n_q: int, n_k: int):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    lanes = dq_scr.shape[1]
+    heads = range(lanes // d)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        # delta_i = rowsum(dO * O) over a head's lanes (the softmax
+        # jacobian's diagonal term): kept lane-replicated for this kernel's
+        # columns, and written as a row per head, like lse, for dK/dV.
+        prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        for h in heads:
+            delta = jnp.broadcast_to(
+                jnp.sum(_only_head(prod, d, h), axis=1, keepdims=True),
+                (block_q, _STATS_LANES))
+            delta_scr[h] = delta
+            _store_row(delta_ref, h, delta)
 
     def run(off):
         for row, full, live in _row_stripes(off, block_q, block_k, tile_q,
                                             tile_k):
             rows = pl.ds(row, tile_q)
-            q = q_ref[0, rows, :].astype(jnp.float32)      # [tq, d]
+            q = q_ref[0, rows, :].astype(jnp.float32)      # [tq, lanes]
             do = do_ref[0, rows, :].astype(jnp.float32)
-            k = k_ref[0, :live, :].astype(jnp.float32)     # [live, d]
+            k = k_ref[0, :live, :].astype(jnp.float32)     # [live, lanes]
             v = v_ref[0, :live, :].astype(jnp.float32)
-            lse = _column(lse_ref[0, :, rows])             # [tq, 1]
-            delta = _column(delta_ref[0, :, rows])
-            s = _dot(q, k, _NT) * scale                    # [tq, live]
-            if off is not None:
-                s = _masked(s, full, live, off + row - full)
-            p = jnp.exp(s - lse)
-            dp = _dot(do, v, _NT)
-            ds = p * (dp - delta) * scale                  # [tq, live]
-            dq_scr[rows, :] += _dot(ds, k, _NN)
+            dqs = []
+            for h in heads:
+                lse = _column(lse_ref[0, h, :, rows])      # [tq, 1]
+                delta = delta_scr[h, rows, :][:, :1]
+                s = _dot(_only_head(q, d, h), k, _NT) * scale  # [tq, live]
+                if off is not None:
+                    s = _masked(s, full, live, off + row - full)
+                p = jnp.exp(s - lse)
+                dp = _dot(_only_head(do, d, h), v, _NT)
+                ds = p * (dp - delta) * scale              # [tq, live]
+                dqs.append(_dot(ds, k, _NN))               # [tq, lanes]
+            dq_scr[rows, :] += _by_head(dqs, tile_q, lanes, d)
 
     _by_offset(causal, qi * block_q - ki * block_k, block_q, block_k, n_q,
                n_k, run)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
+                    dk_ref, dv_ref, dk_scr, dv_scr, *, d: int, scale: float,
                     causal: bool, block_q: int, block_k: int,
                     tile_q: int, tile_k: int, n_q: int, n_k: int):
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
+    lanes = dk_scr.shape[1]
+    heads = range(lanes // d)
 
     @pl.when(qi == 0)
     def _init():
@@ -363,110 +475,89 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for col, first, full in _col_stripes(off, block_q, block_k, tile_q,
                                              tile_k):
             cols = pl.ds(col, tile_k)
-            k = k_ref[0, cols, :].astype(jnp.float32)        # [tk, d]
+            k = k_ref[0, cols, :].astype(jnp.float32)        # [tk, lanes]
             v = v_ref[0, cols, :].astype(jnp.float32)
-            q = q_ref[0, first:, :].astype(jnp.float32)      # [rows, d]
+            q = q_ref[0, first:, :].astype(jnp.float32)      # [rows, lanes]
             do = do_ref[0, first:, :].astype(jnp.float32)
-            lse = lse_ref[0, :, first:]                      # [1, rows]
-            delta = delta_ref[0, :, first:]
-            st = _dot(k, q, _NT) * scale                     # [tk, rows]
-            if off is not None:
-                st = _masked(st, 0, full - first, off + first - col, q_axis=1)
-            pt = jnp.exp(st - lse)
-            dv_scr[cols, :] += _dot(pt, do, _NN)             # [tk, d]
-            dpt = _dot(v, do, _NT)
-            dst = pt * (dpt - delta) * scale                 # [tk, rows]
-            dk_scr[cols, :] += _dot(dst, q, _NN)
+            dks, dvs = [], []
+            for h in heads:
+                lse = lse_ref[0, h, :, first:]               # [1, rows]
+                delta = delta_ref[0, h, :, first:]
+                st = _dot(_only_head(k, d, h), q, _NT) * scale  # [tk, rows]
+                if off is not None:
+                    st = _masked(st, 0, full - first, off + first - col,
+                                 q_axis=1)
+                pt = jnp.exp(st - lse)
+                dvs.append(_dot(pt, do, _NN))                # [tk, lanes]
+                dpt = _dot(_only_head(v, d, h), do, _NT)
+                dst = pt * (dpt - delta) * scale             # [tk, rows]
+                dks.append(_dot(dst, q, _NN))
+            dv_scr[cols, :] += _by_head(dvs, tile_k, lanes, d)
+            dk_scr[cols, :] += _by_head(dks, tile_k, lanes, d)
 
     _by_offset(causal, qi * block_q - ki * block_k, block_q, block_k, n_q,
                n_k, run)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(qi == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
-                    block_q: int, block_k: int, tile_q: int, tile_k: int,
-                    interpret: bool = False):
+def _flash_backward(q, k, v, out, lse, g, d: int, fused: bool, causal: bool,
+                    scale: float, block_q: int, block_k: int, tile_q: int,
+                    tile_k: int, interpret: bool = False):
+    """(dq, dk, dv), each [batch, seq, e], of the operands `_flash_forward`
+    took; `out`, `g` [batch, seq, e], `lse` as it returned it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    batch, heads, seq_q, d = q.shape
-    seq_k = k.shape[2]
-    bh = batch * heads
-    q3 = q.reshape(bh, seq_q, d)
-    k3 = k.reshape(bh, seq_k, d)
-    v3 = v.reshape(bh, seq_k, d)
-    do3 = g.reshape(bh, seq_q, d)
-    # delta_i = rowsum(dO * O) (the softmax-jacobian diagonal term), a row
-    # per (batch, head) like lse.
-    delta = jnp.sum(do3.astype(jnp.float32)
-                    * out.reshape(bh, seq_q, d).astype(jnp.float32),
-                    axis=-1).reshape(bh, 1, seq_q)
+    batch, seq_q, e = out.shape
+    seq_k = k.shape[1]
     nq = pl.cdiv(seq_q, block_q)
     nk = pl.cdiv(seq_k, block_k)
+    lanes = _column_block(e, d)
+    stats = jax.ShapeDtypeStruct((batch, e // d, 1, seq_q), jnp.float32)
+    grads = jax.ShapeDtypeStruct((batch, seq_q, e), q.dtype)
+    tiles = dict(d=d, scale=scale, causal=causal, block_q=block_q,
+                 block_k=block_k, tile_q=tile_q, tile_k=tile_k, n_q=nq,
+                 n_k=nk)
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
-    tiles = dict(block_q=block_q, block_k=block_k, tile_q=tile_q,
-                 tile_k=tile_k, n_q=nq, n_k=nk)
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                  **tiles)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+    sp = _specs(e, d, fused, block_q, block_k)
+    dq, delta = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **tiles),
+        grid=(batch, e // lanes, nq, nk),
+        in_specs=[sp["q"], sp["k"], sp["v"], sp["rows"], sp["rows"],
+                  sp["stat"]],
+        out_specs=[sp["rows"], sp["stat"]],
+        out_shape=[grads, stats],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((lanes // d, block_q, _STATS_LANES), jnp.float32)],
+        compiler_params=semantics,
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q3, k3, v3, do3, lse, delta)
+    )(q, k, v, g, out, lse)
 
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                   causal=causal, **tiles)
+    sp = _specs(e, d, fused, block_q, block_k,
+                where=lambda b, c, j, i: (b, c, i, j))
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        functools.partial(_bwd_dkv_kernel, **tiles),
+        grid=(batch, e // lanes, nk, nq),
+        in_specs=[sp["q"], sp["k"], sp["v"], sp["rows"], sp["stat"],
+                  sp["stat"]],
+        out_specs=[sp["cols"], sp["cols"]],
+        out_shape=[grads, grads],
+        scratch_shapes=[pltpu.VMEM((block_k, lanes), jnp.float32),
+                        pltpu.VMEM((block_k, lanes), jnp.float32)],
+        compiler_params=semantics,
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q3, k3, v3, do3, lse, delta)
-
-    shape_q = (batch, heads, seq_q, d)
-    shape_k = (batch, heads, seq_k, d)
-    return (dq.reshape(shape_q), dk.reshape(shape_k), dv.reshape(shape_k))
+    )(q, k, v, g, lse, delta)
+    return dq, dk, dv
 
 
 # --------------------------------------------------------------------------- #
@@ -475,8 +566,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
 
 
 def pick_block_sizes(seq: int, d: int, causal: bool = True) -> tuple:
-    """(block_q, block_k, tile_q, tile_k) for a [*, *, seq, d] call: a pure
-    function of the shape and the mask.
+    """(block_q, block_k, tile_q, tile_k) for a call of heads `d` wide over
+    `seq` positions: a pure function of the shape and the mask.
 
     Grid blocks stay as large as VMEM takes comfortably. Measured on one
     v5e at [8,16,1024,64] bf16 causal (my chip runs, PR 30; us a call, fwd /
@@ -513,9 +604,10 @@ def pick_block_sizes(seq: int, d: int, causal: bool = True) -> tuple:
 
 
 # (pass, path, reason, shape, dtype, block_q, block_k) -> traced calls; the
-# flash passes append (causal, tiles, tiles_live) to theirs
+# flash passes append _FLASH_FIELDS to theirs
 _CALLS: collections.Counter = collections.Counter()
 _CALLS_LOCK = threading.Lock()
+_FLASH_FIELDS = ("causal", "tiles", "tiles_live", "layout", "heads_per_block")
 
 
 def pallas_status() -> list:
@@ -525,19 +617,23 @@ def pallas_status() -> list:
     the number of traced calls. A caller that asked for flash and needs to
     know it got flash (chip_smoke.py, bench.py) reads this.
 
-    Entries of the flash passes (`fwd`, `bwd`) also say `causal`, and on
-    the Pallas path `tiles` and `tiles_live`: the (tile_q, tile_k) tiles in
-    one (batch, head)'s score square and those whose body the kernels run
-    (None for a reference call). `tiles_live / tiles` near 1.0 on a causal
-    call means the causal skip is dead at that shape."""
+    Entries of the flash passes (`fwd`, `bwd`) give `shape` as the call's
+    [batch, heads, seq, head_dim] whatever arrays carried it, and also say
+    `causal`, `layout` ("bse": `flash_attention_bse` on [batch, seq,
+    heads*head_dim] arrays; "bhsd": `flash_attention`), and on the Pallas
+    path `heads_per_block` (heads in one column block of the kernels'
+    operands) and `tiles`, `tiles_live`: the (tile_q, tile_k) tiles in one
+    (batch, head)'s score square and those whose body the kernels run (None
+    for a reference call). `tiles_live / tiles` near 1.0 on a causal call
+    means the causal skip is dead at that shape."""
     with _CALLS_LOCK:
         items = list(_CALLS.items())
     out = []
-    for (p, path, reason, shape, dtype, bq, bk, *schedule), n in items:
+    for (p, path, reason, shape, dtype, bq, bk, *flash), n in items:
         out.append({"pass": p, "path": path, "reason": reason,
                     "shape": list(shape), "dtype": dtype, "block_q": bq,
                     "block_k": bk, "calls": n,
-                    **dict(zip(("causal", "tiles", "tiles_live"), schedule))})
+                    **dict(zip(_FLASH_FIELDS, flash))})
     return out
 
 
@@ -548,11 +644,26 @@ def reset_pallas_status() -> None:
         _CALLS.clear()
 
 
-def _dispatch(pass_: str, q, k, causal: bool, blocks: tuple) -> bool:
+def _operands(qkv) -> tuple:
+    """(q, k, v), or (qkv,) for one fused array."""
+    return tuple(qkv) if isinstance(qkv, (tuple, list)) else (qkv,)
+
+
+def _views(operands) -> tuple:
+    """(q, k, v, fused): a fused array stands for all three of its
+    thirds."""
+    fused = len(operands) == 1
+    return (*(operands * 3 if fused else operands), fused)
+
+
+def _dispatch(pass_: str, operands, d: int, fold: int, causal: bool,
+              blocks: tuple) -> bool:
     """True when the Pallas kernels take this call. Records the decision."""
     platform = _platform()
-    seq_q, d = q.shape[2], q.shape[3]
-    seq_k = k.shape[2]
+    q, k, _, fused = _views(operands)
+    batch, seq_q, seq_k = q.shape[0], q.shape[1], k.shape[1]
+    e = q.shape[2] // 3 if fused else q.shape[2]
+    lanes = _column_block(e, d)
     block_q, block_k, tile_q, tile_k = blocks
     if _interpret() and platform == "tpu":
         raise RuntimeError(
@@ -568,70 +679,138 @@ def _dispatch(pass_: str, q, k, causal: bool, blocks: tuple) -> bool:
         reason = "seq not a multiple of the block"
     elif d % 64:
         reason = "head_dim not a multiple of 64"
+    elif e % lanes or (lanes % _STATS_LANES and (fused or e != lanes)):
+        # e.g. three heads of 64 side by side: the last column block would
+        # be half a lane tile
+        reason = "heads do not fill whole column blocks"
     else:
         reason = ""
-    tiles = (None, None) if reason else _tile_counts(seq_q, tile_q, tile_k,
-                                                     causal)
-    key = (pass_, "reference" if reason else "pallas", reason,
-           tuple(q.shape), jnp.dtype(q.dtype).name, block_q, block_k,
-           causal, *tiles)
+    tiles, tiles_live, heads_per_block = (None, None, None) if reason else (
+        *_tile_counts(seq_q, tile_q, tile_k, causal), lanes // d)
+    shape = (batch // fold, fold, seq_q, d) if fold else (
+        batch, e // d, seq_q, d)
+    key = (pass_, "reference" if reason else "pallas", reason, shape,
+           jnp.dtype(q.dtype).name, block_q, block_k, causal, tiles,
+           tiles_live, "bhsd" if fold else "bse", heads_per_block)
     with _CALLS_LOCK:
         _CALLS[key] += 1
     return not reason
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal: bool = True,
-                    scale: Optional[float] = None,
-                    block_q: int = 0, block_k: int = 0) -> jax.Array:
-    """Blocked attention. q,k,v: [batch, heads, seq, head_dim].
-
-    Dispatches to the Pallas kernels on TPU (shapes permitting; block size 0
-    = auto) and the XLA reference elsewhere. Fully differentiable with a
-    flash backward — training memory stays O(seq * block).
-    """
-    out, _ = _attn_fwd_impl(q, k, v, causal, scale, block_q, block_k)
-    return out
-
-
-def _resolve(q, causal, scale, block_q, block_k):
+def _resolve(seq: int, d: int, causal, scale, block_q, block_k):
     """(scale, (block_q, block_k, tile_q, tile_k)): explicit blocks keep
     the rule's tiles, cut to the block."""
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    seq = q.shape[2]
-    bq, bk, tq, tk = pick_block_sizes(seq, q.shape[-1], causal)
+        scale = 1.0 / math.sqrt(d)
+    bq, bk, tq, tk = pick_block_sizes(seq, d, causal)
     if block_q and block_k:
         bq, bk = block_q, block_k
     bq, bk = min(bq, seq), min(bk, seq)
     return scale, (bq, bk, min(tq, bq), min(tk, bk))
 
 
-def _attn_fwd_impl(q, k, v, causal, scale, block_q, block_k):
-    scale, blocks = _resolve(q, causal, scale, block_q, block_k)
-    if _dispatch("fwd", q, k, causal, blocks):
-        return _flash_forward(q, k, v, causal, scale, *blocks,
+def _reference(operands, d: int, causal: bool, scale):
+    """`mha_reference` on the kernels' operands: [batch, seq, e] in and
+    out."""
+    q, k, v = (operands if len(operands) == 3
+               else jnp.split(operands[0], 3, axis=-1))
+
+    def apart(t):
+        b, s, e = t.shape
+        return t.reshape(b, s, e // d, d).transpose(0, 2, 1, 3)
+
+    out = mha_reference(apart(q), apart(k), apart(v), causal, scale)
+    b, h, s, _ = out.shape
+    return out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _flash(operands, d: int, fold: int, causal: bool, scale, block_q: int,
+           block_k: int):
+    """Attention over [batch, seq, heads*d] arrays. `operands` is (q, k,
+    v), or (qkv,): one [batch, seq, 3*heads*d] array whose thirds they
+    are. `fold`: heads the caller folded into `batch` (`flash_attention`),
+    for the records only."""
+    out, _ = _flash_fwd_impl(operands, d, fold, causal, scale, block_q,
+                             block_k)
+    return out
+
+
+def _flash_fwd_impl(operands, d, fold, causal, scale, block_q, block_k):
+    scale, blocks = _resolve(operands[0].shape[1], d, causal, scale, block_q,
+                             block_k)
+    if _dispatch("fwd", operands, d, fold, causal, blocks):
+        q, k, v, fused = _views(operands)
+        return _flash_forward(q, k, v, d, fused, causal, scale, *blocks,
                               interpret=_interpret())
-    return mha_reference(q, k, v, causal=causal, scale=scale), None
+    return _reference(operands, d, causal, scale), None
 
 
-def _attn_fwd(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _attn_fwd_impl(q, k, v, causal, scale, block_q, block_k)
-    return out, (q, k, v, out, lse)
+def _flash_fwd(operands, d, fold, causal, scale, block_q, block_k):
+    out, lse = _flash_fwd_impl(operands, d, fold, causal, scale, block_q,
+                               block_k)
+    return out, (operands, out, lse)
 
 
-def _attn_bwd(causal, scale, block_q, block_k, residuals, g):
-    q, k, v, out, lse = residuals
-    scale_v, blocks = _resolve(q, causal, scale, block_q, block_k)
-    if _dispatch("bwd", q, k, causal, blocks):
-        return _flash_backward(q, k, v, out, lse, g, causal, scale_v,
-                               *blocks, interpret=_interpret())
-    _, vjp = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal, scale),
-                     q, k, v)
-    return vjp(g)
+def _flash_bwd(d, fold, causal, scale, block_q, block_k, residuals, g):
+    operands, out, lse = residuals
+    scale_v, blocks = _resolve(operands[0].shape[1], d, causal, scale,
+                               block_q, block_k)
+    if not _dispatch("bwd", operands, d, fold, causal, blocks):
+        _, vjp = jax.vjp(lambda ops: _reference(ops, d, causal, scale),
+                         operands)
+        return vjp(g)
+    q, k, v, fused = _views(operands)
+    grads = _flash_backward(q, k, v, out, lse, g, d, fused, causal, scale_v,
+                            *blocks, interpret=_interpret())
+    if not fused:
+        return (grads,)
+    # Three in-place slice updates of ~7 us each in the GPT-2-medium step.
+    # The kernels writing one [batch, seq, 3e] array themselves (dK/dV in a
+    # second turn of each grid step) cost dK/dV 127 us a call: PERF.md
+    # section 6, PR 33.
+    return ((jnp.concatenate(grads, axis=-1),),)
 
 
-flash_attention.defvjp(_attn_fwd, _attn_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention_bse(qkv, head_dim: int, causal: bool = True,
+                        scale: Optional[float] = None,
+                        block_q: int = 0, block_k: int = 0) -> jax.Array:
+    """Blocked attention on the layout the projections around it use.
+    `qkv`: one [batch, seq, 3*heads*head_dim] array holding q, k and v
+    side by side (a fused projection's output, read in place as three
+    views), or a (q, k, v) tuple of [batch, seq, heads*head_dim] arrays.
+    Returns [batch, seq, heads*head_dim]: no split, reshape or transpose
+    for XLA to turn into copies of whole activations.
+
+    Dispatches to the Pallas kernels on TPU (shapes permitting; block size 0
+    = auto) and the XLA reference elsewhere. Fully differentiable with a
+    flash backward — training memory stays O(seq * block).
+    """
+    return _flash(_operands(qkv), head_dim, 0, causal, scale, block_q,
+                  block_k)
+
+
+def flash_attention(q, k, v, causal: bool = True,
+                    scale: Optional[float] = None,
+                    block_q: int = 0, block_k: int = 0) -> jax.Array:
+    """Blocked attention. q,k,v: [batch, heads, seq, head_dim], for callers
+    whose operands are per head already: the same kernels and dispatch rule
+    as `flash_attention_bse`, on [batch*heads, seq, head_dim] (a reshape
+    that moves nothing), every head its own column block."""
+    b, h, s, d = q.shape
+    out = _flash(tuple(t.reshape(b * h, t.shape[2], d) for t in (q, k, v)),
+                 d, h, causal, scale, block_q, block_k)
+    return out.reshape(b, h, s, d)
+
+
+def _sharded(local, operands, spec):
+    if jax.sharding.get_abstract_mesh().empty or not any(spec):
+        return local(*operands)
+    return jax.shard_map(local, in_specs=(spec,) * len(operands),
+                         out_specs=spec, check_vma=False)(*operands)
 
 
 def flash_attention_sharded(q, k, v, spec, causal: bool = True,
@@ -649,7 +828,29 @@ def flash_attention_sharded(q, k, v, spec, causal: bool = True,
     def local(q, k, v):
         return flash_attention(q, k, v, causal, scale, block_q, block_k)
 
-    if jax.sharding.get_abstract_mesh().empty or not any(spec):
-        return local(q, k, v)
-    return jax.shard_map(local, in_specs=(spec, spec, spec), out_specs=spec,
-                         check_vma=False)(q, k, v)
+    return _sharded(local, (q, k, v), spec)
+
+
+def flash_attention_bse_sharded(qkv, head_dim: int, spec,
+                                causal: bool = True,
+                                scale: Optional[float] = None,
+                                block_q: int = 0,
+                                block_k: int = 0) -> jax.Array:
+    """`flash_attention_bse` inside a partitioned jit, as
+    `flash_attention_sharded` is `flash_attention`. `spec` is the
+    PartitionSpec of the [batch, seq, heads*head_dim] arrays: (batch axes,
+    None, heads axis); each device runs the kernels on its [b/dp, s,
+    (h/tp)*d]. Whole heads shard with the last dim only once q, k and v are
+    apart, so where `spec` names a heads axis a fused `qkv` is split first
+    (XLA makes the thirds three outputs of the projection, not copies)."""
+    operands = _operands(qkv)
+    heads_sharded = (not jax.sharding.get_abstract_mesh().empty
+                     and len(spec) > 2 and spec[2])
+    if len(operands) == 1 and heads_sharded:
+        operands = tuple(jnp.split(operands[0], 3, axis=-1))
+
+    def local(*operands):
+        return flash_attention_bse(operands, head_dim, causal, scale,
+                                   block_q, block_k)
+
+    return _sharded(local, operands, spec)

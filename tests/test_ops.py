@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.attention import flash_attention, mha_reference
+from ray_tpu.ops.attention import (flash_attention, flash_attention_bse,
+                                   mha_reference)
 
 
 def _qkv(rng, b=2, h=4, s=128, d=64, dtype=jnp.float32):
@@ -148,6 +149,147 @@ def test_flash_parity_at_the_train_cells_schedule(monkeypatch, causal, dtype):
         np.testing.assert_allclose(a, b, atol=atol, rtol=rtol)
 
 
+def _rows(t):
+    """[b, h, s, d] -> [b, s, h*d], the layout of the projections."""
+    b, h, s, d = t.shape
+    return t.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _bse_and_reference(q, k, v, causal, fused):
+    """(out, dq, dk, dv) of `flash_attention_bse` on the [b, s, h*d] forms
+    of q, k, v (`fused`: on their concatenation, one array read as three
+    views) and of mha_reference, all as [b, s, h*d] float32 arrays."""
+    d = q.shape[-1]
+
+    def bse(q, k, v):
+        q, k, v = _rows(q), _rows(k), _rows(v)
+        qkv = jnp.concatenate([q, k, v], axis=-1) if fused else (q, k, v)
+        return flash_attention_bse(qkv, d, causal)
+
+    def run(fn):
+        def loss(q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
+        out, grads = fn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return [np.asarray(out, np.float32)] + [
+            np.asarray(_rows(g), np.float32) for g in grads]
+
+    return run(bse), run(lambda q, k, v: _rows(
+        mha_reference(q, k, v, causal=causal)))
+
+
+@pytest.mark.parametrize("seq, heads, d, causal, fused", [
+    # d = 64: a 128-lane column block holds two heads
+    (256, 2, 64, True, True),
+    (256, 4, 64, False, False),
+    (1024, 2, 64, True, True),       # the train cells' schedule
+    (1024, 2, 64, False, True),
+    (1024, 2, 64, True, False),
+    (2048, 2, 64, True, True),       # several grid blocks
+    # d = 128: one head a block
+    (256, 2, 128, True, True),
+    (256, 2, 128, False, False),
+    (1024, 1, 128, True, True),
+    (2048, 1, 128, False, True),
+])
+def test_flash_bse_parity(monkeypatch, seq, heads, d, causal, fused):
+    """Forward and the three gradients of the [batch, seq, heads*d] entry
+    against mha_reference: q, k, v apart, and as three views of one fused
+    array."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, k, v = _qkv(jax.random.PRNGKey(21), b=1, h=heads, s=seq, d=d)
+    attention.reset_pallas_status()
+    got, want = _bse_and_reference(q, k, v, causal, fused)
+    for a, b, tol in zip(got, want, [2e-5, 5e-4, 5e-4, 5e-4]):
+        np.testing.assert_allclose(a, b, atol=tol, rtol=tol)
+    assert {(e["pass"], e["path"], e["layout"], e["heads_per_block"],
+             tuple(e["shape"])) for e in attention.pallas_status()} == {
+        (p, "pallas", "bse", max(1, 128 // d), (1, heads, seq, d))
+        for p in ("fwd", "bwd")}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_bhsd_wrapper_runs_the_same_kernels(monkeypatch, dtype):
+    """`flash_attention` on [b, h, s, d] and `flash_attention_bse` on the
+    same values as [b, s, h*d] are one set of kernels: the same numbers,
+    to the order of a sum (a head alone in its block against two heads a
+    block), out and gradients."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, k, v = _qkv(jax.random.PRNGKey(22), b=2, h=2, s=256, d=64,
+                   dtype=jnp.dtype(dtype))
+    attention.reset_pallas_status()
+    bse, _ = _bse_and_reference(q, k, v, True, True)
+    bhsd, _ = _flash_and_reference(q, k, v, True)
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
+    for a, b in zip(bse, [np.asarray(_rows(x)) for x in bhsd]):
+        np.testing.assert_allclose(a, b, atol=tol * np.abs(b).max(), rtol=0)
+    assert {(e["pass"], e["layout"], e["heads_per_block"])
+            for e in attention.pallas_status() if e["path"] == "pallas"} == {
+        ("fwd", "bse", 2), ("bwd", "bse", 2),
+        ("fwd", "bhsd", 1), ("bwd", "bhsd", 1)}
+
+
+def test_flash_bse_takes_the_reference_where_the_rule_says(monkeypatch):
+    """Three heads of 64 do not fill whole 128-lane column blocks: the
+    rule sends the call to the reference and says so; the numbers are
+    mha_reference's either way, as off the chip."""
+    from ray_tpu.ops import attention
+
+    q, k, v = _qkv(jax.random.PRNGKey(23), b=1, h=3, s=128, d=64)
+    attention.reset_pallas_status()
+    cpu, want = _bse_and_reference(q, k, v, True, True)
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    odd, _ = _bse_and_reference(q, k, v, True, True)
+    for a, b, c in zip(cpu, odd, want):
+        np.testing.assert_allclose(a, c, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(b, c, atol=2e-5, rtol=2e-5)
+    assert {(e["path"], e["reason"], e["layout"], e["heads_per_block"])
+            for e in attention.pallas_status()} == {
+        ("reference", "platform cpu", "bse", None),
+        ("reference", "heads do not fill whole column blocks", "bse", None)}
+
+
+@pytest.mark.parametrize("heads_axis", [None, "tp"])
+def test_flash_bse_sharded_matches_the_unsharded_entry(monkeypatch,
+                                                       heads_axis):
+    """`flash_attention_bse_sharded` under a dp x tp mesh: each device
+    runs the kernels on its own [b/dp, s, (h/tp)*d]. With no heads axis
+    the fused array goes in as it is; with one it is split first, since
+    whole heads shard with the last dim only once q, k and v are apart.
+    Out and the gradient of the fused array equal the unsharded entry's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.attention import flash_attention_bse_sharded
+
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, k, v = _qkv(jax.random.PRNGKey(24), b=4, h=4, s=128, d=64)
+    qkv = jnp.concatenate([_rows(q), _rows(k), _rows(v)], axis=-1)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+
+    def loss(fn):
+        return jax.value_and_grad(
+            lambda x: jnp.sum(fn(x).astype(jnp.float32) ** 2))
+
+    want = loss(lambda x: flash_attention_bse(x, 64, True))(qkv)
+    attention.reset_pallas_status()
+    with jax.set_mesh(mesh):
+        got = jax.jit(loss(lambda x: flash_attention_bse_sharded(
+            x, 64, P("dp", None, heads_axis), True)))(
+            jax.device_put(qkv, NamedSharding(mesh, P("dp"))))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4)
+    local_heads = 2 if heads_axis else 4
+    assert {(e["pass"], e["path"], e["layout"], tuple(e["shape"]))
+            for e in attention.pallas_status()} == {
+        (p, "pallas", "bse", (2, local_heads, 128, 64))
+        for p in ("fwd", "bwd")}
+
+
 def test_flash_causal_over_several_grid_blocks_and_tiles(monkeypatch):
     """seq 2048 in 512 x 1024 grid blocks of 256 x 128 tiles: blocks above
     the diagonal run nothing, blocks below it run whole, and each of the
@@ -179,11 +321,13 @@ def test_pallas_status_counts_the_tiles_that_run(monkeypatch):
     flash_attention(q[:, :, :32], k, v, True)   # the rule's reference path
     share = {}
     for e in attention.pallas_status():
-        assert e["shape"][-1] == 64 and e["dtype"] == "bfloat16"
+        assert e["shape"] == [1, 1, e["shape"][2], 64]
+        assert e["dtype"] == "bfloat16" and e["layout"] == "bhsd"
         if e["path"] == "reference":
-            assert (e["causal"], e["tiles"], e["tiles_live"]) == (
-                True, None, None)
+            assert (e["causal"], e["tiles"], e["tiles_live"],
+                    e["heads_per_block"]) == (True, None, None, None)
         else:
+            assert e["heads_per_block"] == 1    # one head, its own block
             share[e["pass"], e["causal"]] = e["tiles_live"] / e["tiles"]
     assert set(share) == {("fwd", True), ("bwd", True), ("fwd", False),
                           ("bwd", False)}

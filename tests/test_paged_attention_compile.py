@@ -178,3 +178,162 @@ def test_flash_kernels_compile_at_the_rules_schedule(one_chip, monkeypatch,
     assert {(e["pass"], e["path"], e["causal"])
             for e in attention.pallas_status()} == {
         ("fwd", "pallas", causal), ("bwd", "pallas", causal)}
+
+
+@pytest.mark.parametrize("shape, d, dtype, causal", [
+    ((8, 1024, 1024), 64, "bfloat16", True),   # the train cells' local shape
+    ((8, 1024, 1024), 64, "bfloat16", False),
+    ((1, 8192, 768), 64, "bfloat16", True),    # GPT-2-small, chip_smoke's long leg
+    ((2, 512, 256), 64, "float32", True),
+    ((1, 4096, 1024), 128, "bfloat16", True),  # one head a column block
+    ((2, 1024, 1024), 256, "bfloat16", True),  # a 256-lane column block
+])
+def test_flash_bse_kernels_compile_on_a_fused_projection(
+        one_chip, monkeypatch, shape, d, dtype, causal):
+    """`flash_attention_bse` between two projections, forward and backward,
+    compiles for a described v5e: the three kernels read the fused
+    [batch, seq, 3e] product as it is (three views of one array) and no
+    `copy` of an activation stands between the products and the kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    b, s, e = shape
+    dt = jnp.dtype(dtype)
+
+    def layer(x, w_in, w_out):
+        qkv = x @ w_in
+        return attention.flash_attention_bse(qkv, d, causal) @ w_out
+
+    def fwd_bwd(x, w_in, w_out, do):
+        out, vjp = jax.vjp(layer, x, w_in, w_out)
+        return out, vjp(do)
+
+    def spec(*dims):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    attention.reset_pallas_status()
+    hlo = jax.jit(fwd_bwd).lower(spec(b, s, e), spec(e, 3 * e), spec(e, e),
+                                 spec(b, s, e)).compile().as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(re.search(r"flash_(fwd|bwd_dq|bwd_dkv)", k).group(0)
+                  for k in kernels) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    for k in kernels:
+        # q, k and v are ONE operand, the product itself, three times
+        first = re.search(r"custom-call\(([^)]*)\)", k).group(1).split(", ")[:3]
+        assert len(set(first)) == 1, k
+    short = {"bfloat16": "bf16", "float32": "f32"}[dt.name]
+    assert not re.search(
+        rf"= {short}\[{b},{s},(?:{e}|{3 * e})\][^ ]* copy\(", hlo)
+    assert {(x["pass"], x["path"], x["layout"], x["heads_per_block"])
+            for x in attention.pallas_status()} == {
+        (p, "pallas", "bse", max(1, 128 // d)) for p in ("fwd", "bwd")}
+
+
+def _gpt2_medium_step(n_layer, spec):
+    """(jitted train step, its abstract arguments): GPT-2-medium at full
+    width, `n_layer` layers, batch 8 x 1024, AdamW, donation, as the train
+    cells build it; `spec(shape, dtype)` makes an argument."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, make_train_step
+
+    cfg = dataclasses.replace(GPT2Config.medium(), n_layer=n_layer)
+    model = GPT2(cfg)
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    opt = optax.adamw(3e-4, weight_decay=0.1)
+    opt_state = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                             jax.eval_shape(opt.init, params))
+    ids = spec((8, 1024), jnp.int32)
+    step = make_train_step(model, opt, donate=True)
+    return step, (params, opt_state, {"input_ids": ids, "labels": ids})
+
+
+ACTIVATION_COPY = re.compile(
+    r"= bf16\[(8,1024,1024|8,16,1024,64|8,1024,16,64)\][^ ]* copy\(")
+
+
+@pytest.mark.slow
+def test_gpt2_medium_step_compiles_without_activation_copies(one_chip,
+                                                             monkeypatch):
+    """The train cells' step at 2 layers compiled for one described v5e:
+    one `flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv` a layer and no `copy`
+    of a whole activation around them (the `[batch, heads, seq, d]` kernels
+    of before PR 33 had 14 a layer: q, k, v split and transposed, the
+    output transposed back, and the same for the gradients). ~15 s;
+    `test_gpt2_step_hands_attention_the_projections_own_arrays` is the
+    twin that runs with the rest."""
+    import jax
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    n_layer = 2
+    step, args = _gpt2_medium_step(
+        n_layer, lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=one_chip))
+    hlo = step.lower(*args).compile().as_text()
+    kernels = [re.search(r"flash_(fwd|bwd_dq|bwd_dkv)", line).group(0)
+               for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kernels) == sorted(
+        ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * n_layer)
+    assert not ACTIVATION_COPY.findall(hlo)
+
+
+def test_gpt2_step_hands_attention_the_projections_own_arrays(monkeypatch):
+    """The same step as traced, no compiler: every layer calls each kernel
+    once, on `c_attn`'s [8, 1024, 3072] output itself (q, k and v are one
+    variable) and nothing between `c_attn` and `c_proj` transposes or
+    splits an activation."""
+    import jax
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    n_layer = 2
+    step, args = _gpt2_medium_step(n_layer, jax.ShapeDtypeStruct)
+    attention.reset_pallas_status()
+    found = {"transposes": [], "kernels": [], "wrappers": []}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.params.get("name")
+            if eqn.primitive.name == "pallas_call":
+                found["kernels"].append((name, eqn.invars[0].aval.shape))
+            elif name in ("_flash_forward", "_flash_backward"):
+                # the jitted wrappers' q, k, v as the layer hands them over
+                found["wrappers"].append(eqn.invars[:3])
+            elif eqn.primitive.name in ("transpose", "split") and \
+                    eqn.invars[0].aval.ndim >= 3:
+                found["transposes"].append(
+                    (eqn.primitive.name, eqn.invars[0].aval.shape))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(lambda *a: step(*a))(*args).jaxpr)
+    assert found["transposes"] == []
+    assert sorted(found["kernels"]) == sorted(
+        [(k, (8, 1024, 3072))
+         for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] * n_layer)
+    assert len(found["wrappers"]) == 2 * n_layer
+    for q, k, v in found["wrappers"]:
+        assert q is k is v and q.aval.shape == (8, 1024, 3072)
+    assert {(e["pass"], e["layout"], e["heads_per_block"], tuple(e["shape"]),
+             e["calls"]) for e in attention.pallas_status()} == {
+        ("fwd", "bse", 2, (8, 16, 1024, 64), n_layer),
+        ("bwd", "bse", 2, (8, 16, 1024, 64), n_layer)}
