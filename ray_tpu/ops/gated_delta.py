@@ -1,0 +1,580 @@
+"""Gated delta rule (Gated DeltaNet's recurrence): chunked Pallas TPU
+kernels, forward AND backward, and the short causal convolution before it.
+
+Per head, with a state `S` in R^{dk x dv} that starts at zero:
+
+    S' = exp(g_t) S_{t-1}            decay, g_t <= 0
+    u_t = beta_t (v_t - S'^T k_t)    the delta rule's correction
+    S_t = S' + k_t u_t^T
+    o_t = S_t^T q_t
+
+`gated_delta_scan` is exactly that, one position at a time under
+`lax.scan`: the definition, the fallback, and what the tests hold the
+kernels to. The kernels compute the same thing a chunk of `CHUNK` = 64
+positions at a time (the family's chunk). Inside a chunk, with G the
+running sum of g from the chunk's start (kept in f32) and S the state at
+its start:
+
+    A[t, j] = beta_t exp(G_t - G_j) k_t.k_j        j < t
+    T = (I + A)^-1                                 unit lower triangular
+    W = T (beta exp(G) * K),  U0 = T (beta * V)
+    U = U0 - W S                                   every u_t of the chunk
+    O = (exp(G) * Q) S + (exp(G_t - G_j) q_t.k_j)[j <= t] U
+    S_end = exp(G_C) S + (exp(G_C - G) * K)^T U
+
+`gdn_chunk_fwd` walks one (batch, value head)'s chunks in order with the
+[dk, dv] state carried in f32 in VMEM; with `save` it also writes the state
+at every chunk's start, which is the residual the backward needs (O(seq/64)
+states, not O(seq)). `gdn_chunk_bwd` walks the chunks in reverse carrying
+dS, recomputes each chunk's T, W, U from its inputs and the saved state, and
+writes dq, dk, dv, dG and dbeta. Matmul operands are bf16 with f32
+accumulation (as the flash kernels), except the triangular inverse, which
+is f32 at `Precision.HIGHEST`; the carried state and every exp() are f32.
+
+The triangular solve is an inverse built from products, because the MXU
+has products and no substitution: 16-row diagonal blocks by the nilpotent
+series (I + D)^-1 = (I - D)(I + D^2)(I + D^4)(I + D^8), then the blocks
+joined the same way one level up ((I + A) = (I + D)(I + N), N strictly
+block-lower, N^4 = 0 at four blocks). Ten 64^3 products a chunk; the
+series over all 64 rows at once would be the same count but sums terms that
+grow like (64 c)^n / n! before they cancel.
+
+Layout: q, k [batch, seq, key_heads*dk], v [batch, seq, value_heads*dv],
+the layout the projections produce; a head is a BlockSpec column block
+(dk = dv = 128 = the lane width). A key head serves `value_heads //
+key_heads` adjacent value heads through the index map: the repeat is never
+materialised, and the caller gets dq, dk per key head. g and beta are
+[batch, seq, value_heads] in f32.
+
+Dispatch is a rule, as in `ops/attention.py`: on platform `tpu` a call the
+kernels take goes to the kernels; every other call runs the scan and is
+recorded with the reason. `gated_delta_status()` lists the path of every
+traced call. RAY_TPU_PALLAS_INTERPRET=1 runs the kernels in the
+interpreter on the CPU (tests).
+"""
+
+from __future__ import annotations
+
+import collections
+import functools
+import threading
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.attention import _interpret, _platform
+
+CHUNK = 64
+_SUB = 16            # rows of a diagonal block of the triangular inverse
+_CHUNKS_PER_STEP = 8  # chunks one grid step walks (512 positions)
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+# --------------------------------------------------------------------------- #
+# The definition: one position at a time
+# --------------------------------------------------------------------------- #
+
+
+def gated_delta_scan(q, k, v, g, beta):
+    """The recurrence of the module docstring under `lax.scan`, in f32.
+    q, k [batch, seq, key_heads, dk], v [batch, seq, value_heads, dv],
+    g, beta [batch, seq, value_heads]. Returns [batch, seq, value_heads,
+    dv] in v's dtype. Differentiable by jax (the scan keeps a state per
+    position: a reference and a fallback, not a training path)."""
+    rep = v.shape[2] // k.shape[2]
+    f32 = jnp.float32
+    qf, kf = (jnp.repeat(t.astype(f32), rep, axis=2) for t in (q, k))
+    vf, gf, bf = v.astype(f32), g.astype(f32), beta.astype(f32)
+
+    def step(state, xs):
+        q_t, k_t, v_t, g_t, b_t = xs                # [b, h, d], [b, h]
+        state = state * jnp.exp(g_t)[..., None, None]
+        pred = jnp.einsum("bhkv,bhk->bhv", state, k_t)
+        u = b_t[..., None] * (v_t - pred)
+        state = state + jnp.einsum("bhk,bhv->bhkv", k_t, u)
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    b, _, h, dv = vf.shape
+    state0 = jnp.zeros((b, h, qf.shape[-1], dv), f32)
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (qf, kf, vf, gf, bf))
+    _, out = jax.lax.scan(step, state0, xs)
+    return jnp.moveaxis(out, 0, 1).astype(v.dtype)
+
+
+def causal_conv1d(x, w):
+    """Depthwise causal convolution, no bias: x [batch, seq, channels], w
+    [channels, width]; y_t = sum_j w[:, j] x_{t - (width-1) + j}, zeros
+    before the sequence's start (the published conv1d's weight [channels,
+    1, width] with left padding width-1)."""
+    width = w.shape[1]
+    seq = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + seq] * w[:, j].astype(x.dtype)
+               for j in range(width))
+
+
+# --------------------------------------------------------------------------- #
+# One chunk's mathematics, on 2D values (kernel bodies and nothing else)
+# --------------------------------------------------------------------------- #
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _dot32(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _iotas(n: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _column(row):
+    """[1, n] -> [n, 1]: the diagonal of the row's sublane broadcast."""
+    n = row.shape[1]
+    r, c = _iotas(n)
+    return jnp.sum(jnp.where(r == c, jnp.broadcast_to(row, (n, n)), 0.0),
+                   axis=1, keepdims=True)
+
+
+def _row(col):
+    """[n, 1] -> [1, n]: the diagonal of the column's lane broadcast."""
+    n = col.shape[0]
+    r, c = _iotas(n)
+    return jnp.sum(jnp.where(r == c, jnp.broadcast_to(col, (n, n)), 0.0),
+                   axis=0, keepdims=True)
+
+
+def _transpose(m):
+    """A square f32 matrix's transpose as eye @ m^T, which the MXU does in
+    the layout it already reads."""
+    n = m.shape[0]
+    r, c = _iotas(n)
+    return _dot32((r == c).astype(jnp.float32), m, _NT)
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for a strictly lower triangular [n, n] f32 `a`, n a
+    multiple of `_SUB`, from products alone (module docstring)."""
+    n = a.shape[0]
+    r, c = _iotas(n)
+    eye = (r == c).astype(jnp.float32)
+    same = (r // _SUB) == (c // _SUB)
+    diag, low = jnp.where(same, a, 0.0), jnp.where(same, 0.0, a)
+
+    def series(m, order):
+        """(I + m)^-1 where m^order = 0."""
+        inv, power, reach = eye - m, m, 2
+        while reach < order:
+            power = _dot32(power, power)
+            inv = _dot32(inv, eye + power)
+            reach *= 2
+        return inv
+
+    inv_diag = series(diag, _SUB)
+    if n == _SUB:
+        return inv_diag
+    return _dot32(series(_dot32(inv_diag, low), n // _SUB), inv_diag)
+
+
+def _chunk_parts(q, k, v, g_row, b_row):
+    """What forward and backward both need of one chunk and that does not
+    depend on the state. q, k, v [C, d] bf16; g_row (the running sum of
+    log decay), b_row [1, C] f32."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    n = q.shape[0]
+    r, c = _iotas(n)
+    g_col, b_col = _column(g_row), _column(b_row)
+    # exp() only of what is <= 0: above the diagonal G_t - G_j > 0 may
+    # overflow, and is not part of the chunk.
+    decay = jnp.exp(jnp.where(r >= c, g_col - g_row, -jnp.inf))
+    e_col = jnp.exp(g_col)
+    kk = _dot(k, k, _NT)
+    a = jnp.where(r > c, b_col * decay * kk, 0.0)
+    t = _unit_lower_inverse(a)
+    kf, vf, qf = k.astype(f32), v.astype(f32), q.astype(f32)
+    kb = (kf * (b_col * e_col)).astype(bf16)
+    vb = (vf * b_col).astype(bf16)
+    tb = t.astype(bf16)
+    last = (c == n - 1)[:1]
+    g_last = jnp.sum(jnp.where(last, g_row, 0.0))    # a scalar
+    kd = (kf * jnp.exp(g_last - g_col)).astype(bf16)
+    qe = (qf * e_col).astype(bf16)
+    p = decay * _dot(q, k, _NT)                      # zero above diagonal
+    return dict(g_col=g_col, b_col=b_col, e_col=e_col, decay=decay, kk=kk,
+                a=a, t=t, tb=tb, kb=kb, vb=vb, kd=kd, qe=qe, p=p,
+                g_last=g_last, w=_dot(tb, kb, _NN), u0=_dot(tb, vb, _NN),
+                strict=r > c, lower=r >= c, last=last)
+
+
+def _chunk_u(parts, sb):
+    """Every u_t of the chunk, bf16, from the bf16 state at its start."""
+    return (parts["u0"] - _dot(parts["w"].astype(jnp.bfloat16), sb, _NN)
+            ).astype(jnp.bfloat16)
+
+
+def _chunk_forward(parts, state):
+    """(o [C, dv] f32, state at the chunk's end [dk, dv] f32)."""
+    sb = state.astype(jnp.bfloat16)
+    u = _chunk_u(parts, sb)
+    o = _dot(parts["qe"], sb, _NN) + _dot(parts["p"].astype(jnp.bfloat16),
+                                          u, _NN)
+    end = jnp.exp(parts["g_last"]) * state + _dot(parts["kd"], u, _TN)
+    return o, end
+
+
+def _chunk_backward(q, k, v, parts, state, do, d_end):
+    """Gradients of one chunk: (dq, dk, dv [C, d] f32, dG_row, db_row
+    [1, C] f32, d_state [dk, dv] f32) from do [C, dv] bf16 and the gradient
+    `d_end` of the state at the chunk's end. The steps follow
+    `_chunk_parts` and `_chunk_forward` backwards, line for line."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    x = parts
+    qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
+    sb = state.astype(bf16)
+    u = _chunk_u(x, sb)
+    wb = x["w"].astype(bf16)
+    pb = x["p"].astype(bf16)
+    e_last = jnp.exp(x["g_last"])
+    deb = d_end.astype(bf16)
+    # end = e_last * state + kd^T u
+    d_state = e_last * d_end
+    du = _dot(x["kd"], deb, _NN)
+    dkd = _dot(u, deb, _NT)
+    kd_term = jnp.sum(dkd * x["kd"].astype(f32), axis=1, keepdims=True)
+    d_last = e_last * jnp.sum(d_end * state) + jnp.sum(kd_term)
+    dk = dkd * jnp.exp(x["g_last"] - x["g_col"])
+    dg_col = -kd_term
+    # o = qe state + p u
+    dqe = _dot(do, sb, _NT)
+    d_state = d_state + _dot(x["qe"], do, _TN)
+    dp = jnp.where(x["lower"], _dot(do, u, _NT), 0.0)
+    du = du + _dot(pb, do, _TN)
+    dq = dqe * x["e_col"]
+    dg_col = dg_col + jnp.sum(dqe * x["qe"].astype(f32), axis=1,
+                              keepdims=True)
+    # p = decay * (q k^T)
+    dqk = (dp * x["decay"]).astype(bf16)
+    dq = dq + _dot(dqk, k, _NN)
+    dk = dk + _dot(dqk, q, _TN)
+    r1 = dp * x["p"]
+    dg_col = dg_col + jnp.sum(r1, axis=1, keepdims=True)
+    dg_row = -jnp.sum(r1, axis=0, keepdims=True)
+    # u = u0 - w state
+    dub = du.astype(bf16)
+    dw = -_dot(dub, sb, _NT)
+    d_state = d_state - _dot(wb, dub, _TN)
+    # w = t kb, u0 = t vb
+    dwb = dw.astype(bf16)
+    dt = _dot(dwb, x["kb"], _NT) + _dot(dub, x["vb"], _NT)
+    dkb = _dot(x["tb"], dwb, _TN)
+    dvb = _dot(x["tb"], dub, _TN)
+    # kb = k * (beta e^G), vb = v * beta
+    dv = dvb * x["b_col"]
+    db_col = jnp.sum(dvb * vf, axis=1, keepdims=True)
+    dk = dk + dkb * (x["b_col"] * x["e_col"])
+    kb_term = jnp.sum(dkb * kf, axis=1, keepdims=True) * x["e_col"]
+    db_col = db_col + kb_term
+    dg_col = dg_col + kb_term * x["b_col"]
+    # t = (I + a)^-1: da = -t^T dt t^T
+    tt = _transpose(x["t"])
+    da = jnp.where(x["strict"], -_dot32(_dot32(tt, dt), tt), 0.0)
+    # a = strict * beta_t * decay * (k k^T)
+    dkk = (da * x["b_col"] * x["decay"]).astype(bf16)
+    dk = dk + _dot(dkk, k, _NN) + _dot(dkk, k, _TN)
+    db_col = db_col + jnp.sum(da * x["decay"] * x["kk"], axis=1,
+                              keepdims=True)
+    r2 = da * x["a"]
+    dg_col = dg_col + jnp.sum(r2, axis=1, keepdims=True)
+    dg_row = dg_row - jnp.sum(r2, axis=0, keepdims=True)
+    dg_row = dg_row + _row(dg_col) + jnp.where(x["last"], d_last, 0.0)
+    return dq, dk, dv, dg_row, _row(db_col), d_state
+
+
+# --------------------------------------------------------------------------- #
+# Kernels
+# --------------------------------------------------------------------------- #
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, chunk: int,
+                steps: int, save: bool):
+    from jax.experimental import pallas as pl
+
+    h_ref, state = rest if save else (None, rest[0])
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state[...] = jnp.zeros_like(state)
+
+    def body(i, carry):
+        rows = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
+        parts = _chunk_parts(q_ref[0, rows, :], k_ref[0, rows, :],
+                             v_ref[0, rows, :], g_ref[0, 0, pl.ds(i, 1), :],
+                             b_ref[0, 0, pl.ds(i, 1), :])
+        start = state[...]
+        if save:
+            h_ref[0, 0, i] = start
+        o, end = _chunk_forward(parts, start)
+        o_ref[0, rows, :] = o.astype(o_ref.dtype)
+        state[...] = end
+        return carry
+
+    jax.lax.fori_loop(0, steps, body, 0)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, h_ref, do_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, db_ref, d_state, *, chunk: int,
+                steps: int):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    def body(j, carry):
+        i = steps - 1 - j
+        rows = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
+        q, k, v = q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :]
+        parts = _chunk_parts(q, k, v, g_ref[0, 0, pl.ds(i, 1), :],
+                             b_ref[0, 0, pl.ds(i, 1), :])
+        dq, dk, dv, dg, db, ds = _chunk_backward(
+            q, k, v, parts, h_ref[0, 0, i], do_ref[0, rows, :], d_state[...])
+        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
+        dg_ref[0, 0, pl.ds(i, 1), :] = dg
+        db_ref[0, 0, pl.ds(i, 1), :] = db
+        d_state[...] = ds
+        return carry
+
+    jax.lax.fori_loop(0, steps, body, 0)
+
+
+def _specs(rep: int, d: int, chunk: int, steps: int, n_blocks: int,
+           reverse: bool):
+    from jax.experimental import pallas as pl
+
+    def at(t):
+        return n_blocks - 1 - t if reverse else t
+
+    rows = steps * chunk
+    return {
+        "key": pl.BlockSpec((1, rows, d), lambda b, h, t: (b, at(t),
+                                                           h // rep)),
+        "value": pl.BlockSpec((1, rows, d), lambda b, h, t: (b, at(t), h)),
+        "gate": pl.BlockSpec((1, 1, steps, chunk),
+                             lambda b, h, t: (b, h, at(t), 0)),
+        "states": pl.BlockSpec((1, 1, steps, d, d),
+                               lambda b, h, t: (b, h, at(t), 0, 0)),
+    }
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "steps", "save",
+                                             "interpret"))
+def _gdn_forward(q, k, v, gcum, beta, chunk: int, steps: int, save: bool,
+                 interpret: bool = False):
+    """q, k [batch, seq, key_heads*d] bf16, v [batch, seq, value_heads*d]
+    bf16, gcum, beta [batch, value_heads, chunks, chunk] f32 (gcum the sum
+    of g from its chunk's start). Returns (o like v, states [batch,
+    value_heads, chunks, d, d] f32 at every chunk's start, or None)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, seq, _ = v.shape
+    heads, chunks = gcum.shape[1], gcum.shape[2]
+    d = v.shape[2] // heads
+    rep = heads // (k.shape[2] // d)
+    n_blocks = chunks // steps
+    sp = _specs(rep, d, chunk, steps, n_blocks, False)
+    out_specs = [sp["value"]]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if save:
+        out_specs.append(sp["states"])
+        out_shape.append(jax.ShapeDtypeStruct((batch, heads, chunks, d, d),
+                                              jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, steps=steps, save=save),
+        grid=(batch, heads, n_blocks),
+        in_specs=[sp["key"], sp["key"], sp["value"], sp["gate"], sp["gate"]],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_chunk_fwd",
+    )(q, k, v, gcum, beta)
+    return (out[0], out[1]) if save else (out[0], None)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "steps", "interpret"))
+def _gdn_backward(q, k, v, gcum, beta, states, do, chunk: int, steps: int,
+                  interpret: bool = False):
+    """Returns (dq, dk [batch, seq, value_heads*d]: per VALUE head, the
+    caller sums the heads a key head served), dv, dgcum, dbeta)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads, chunks = gcum.shape[1], gcum.shape[2]
+    batch = v.shape[0]
+    d = v.shape[2] // heads
+    rep = heads // (k.shape[2] // d)
+    n_blocks = chunks // steps
+    sp = _specs(rep, d, chunk, steps, n_blocks, True)
+    wide = jax.ShapeDtypeStruct(v.shape, v.dtype)
+    gate = jax.ShapeDtypeStruct(gcum.shape, jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk, steps=steps),
+        grid=(batch, heads, n_blocks),
+        in_specs=[sp["key"], sp["key"], sp["value"], sp["gate"], sp["gate"],
+                  sp["states"], sp["value"]],
+        out_specs=[sp["value"], sp["value"], sp["value"], sp["gate"],
+                   sp["gate"]],
+        out_shape=[wide, wide, wide, gate, gate],
+        scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_chunk_bwd",
+    )(q, k, v, gcum, beta, states, do)
+
+
+# --------------------------------------------------------------------------- #
+# Dispatch + custom VJP
+# --------------------------------------------------------------------------- #
+
+# (pass, path, reason, shape, dtype, chunk) -> traced calls
+_CALLS: collections.Counter = collections.Counter()
+_CALLS_LOCK = threading.Lock()
+
+
+def gated_delta_status() -> list:
+    """Which path every traced recurrence call of this process took: one
+    entry per distinct (pass, shape) with `path` "pallas" or "scan", the
+    dispatch rule's `reason` for a scan call, `shape` [batch, value_heads,
+    seq, head_dim], the `chunk` and the number of traced calls."""
+    with _CALLS_LOCK:
+        items = list(_CALLS.items())
+    return [{"pass": p, "path": path, "reason": reason, "shape": list(shape),
+             "dtype": dtype, "chunk": chunk, "calls": n}
+            for (p, path, reason, shape, dtype, chunk), n in items]
+
+
+def reset_gated_delta_status() -> None:
+    with _CALLS_LOCK:
+        _CALLS.clear()
+
+
+def _dispatch(pass_: str, q, k, v) -> bool:
+    """True when the kernels take this call. Records the decision."""
+    platform = _platform()
+    b, s, hv, dv = v.shape
+    if _interpret() and platform == "tpu":
+        raise RuntimeError(
+            "RAY_TPU_PALLAS_INTERPRET=1 is a CPU test switch; on platform "
+            "tpu it would run the interpreter under the kernels' name")
+    if platform != "tpu" and not _interpret():
+        reason = f"platform {platform}"
+    elif q.shape[-1] != 128 or dv != 128:
+        reason = "head_dim is not the lane width (128)"
+    elif hv % k.shape[2]:
+        reason = "value heads not a multiple of key heads"
+    else:
+        reason = ""
+    key = (pass_, "scan" if reason else "pallas", reason, (b, hv, s, dv),
+           jnp.dtype(v.dtype).name, CHUNK)
+    with _CALLS_LOCK:
+        _CALLS[key] += 1
+    return not reason
+
+
+def _padded_len(seq: int) -> int:
+    """Whole chunks, and whole grid steps once there is more than one."""
+    chunks = -(-seq // CHUNK)
+    if chunks > _CHUNKS_PER_STEP:
+        chunks = -(-chunks // _CHUNKS_PER_STEP) * _CHUNKS_PER_STEP
+    return chunks * CHUNK
+
+
+def _kernel_operands(q, k, v, g, beta):
+    """The kernels' layout: heads folded into the last dim, bf16; g summed
+    within its chunk in f32, g and beta as [batch, heads, chunks, chunk].
+    Positions past the sequence's end get k = v = 0, g = 0, beta = 0: they
+    leave the state as it is."""
+    b, s, hv, _ = v.shape
+    pad = _padded_len(s) - s
+
+    def wide(t):
+        t = t.reshape(b, s, -1).astype(jnp.bfloat16)
+        return jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+
+    def gates(t):
+        t = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, pad), (0, 0)))
+        return t.reshape(b, -1, CHUNK, hv).transpose(0, 3, 1, 2)
+
+    gcum = jnp.cumsum(gates(g), axis=-1)
+    steps = min(_CHUNKS_PER_STEP, gcum.shape[2])
+    return (wide(q), wide(k), wide(v), gcum, gates(beta)), steps
+
+
+@jax.custom_vjp
+def gated_delta_rule(q, k, v, g, beta):
+    """The gated delta rule over whole sequences from a zero state.
+    q, k [batch, seq, key_heads, dk] (already L2-normalised and scaled, as
+    the model wants them), v [batch, seq, value_heads, dv], g (log decay,
+    <= 0), beta [batch, seq, value_heads]. Returns [batch, seq,
+    value_heads, dv] in v's dtype."""
+    return _forward(q, k, v, g, beta, False)[0]
+
+
+def _forward(q, k, v, g, beta, save: bool):
+    if not _dispatch("fwd", q, k, v):
+        return gated_delta_scan(q, k, v, g, beta), None
+    operands, steps = _kernel_operands(q, k, v, g, beta)
+    out, states = _gdn_forward(*operands, chunk=CHUNK, steps=steps,
+                               save=save, interpret=_interpret())
+    return out[:, :v.shape[1]].reshape(v.shape).astype(v.dtype), states
+
+
+def _vjp_fwd(q, k, v, g, beta):
+    out, states = _forward(q, k, v, g, beta, True)
+    return out, (q, k, v, g, beta, states)
+
+
+def _vjp_bwd(residuals, do):
+    q, k, v, g, beta, states = residuals
+    if not _dispatch("bwd", q, k, v):
+        _, vjp = jax.vjp(gated_delta_scan, q, k, v, g, beta)
+        return vjp(do)
+    b, s, hv, _ = v.shape
+    hk = k.shape[2]
+    operands, steps = _kernel_operands(q, k, v, g, beta)
+    pad = operands[0].shape[1] - s
+    do_w = jnp.pad(do.reshape(b, s, -1).astype(jnp.bfloat16),
+                   ((0, 0), (0, pad), (0, 0)))
+    dq, dk, dv, dgcum, dbeta = _gdn_backward(
+        *operands, states, do_w, chunk=CHUNK, steps=steps,
+        interpret=_interpret())
+
+    def keys(t, like):
+        t = t[:, :s].reshape(b, s, hk, hv // hk, -1).astype(jnp.float32)
+        return t.sum(axis=3).astype(like.dtype)
+
+    def gates(t, like):
+        t = t.transpose(0, 2, 3, 1).reshape(b, -1, hv)[:, :s]
+        return t.astype(like.dtype)
+
+    # gcum = cumsum(g) within the chunk, so dg_t = sum over j >= t of dG_j
+    dg = jnp.flip(jnp.cumsum(jnp.flip(dgcum, -1), axis=-1), -1)
+    return (keys(dq, q), keys(dk, k),
+            dv[:, :s].reshape(v.shape).astype(v.dtype),
+            gates(dg, g), gates(dbeta, beta))
+
+
+gated_delta_rule.defvjp(_vjp_fwd, _vjp_bwd)

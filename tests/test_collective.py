@@ -282,6 +282,11 @@ def test_barrier_reusable_across_rounds(collective_cluster):
     barrier state is GC'd after each round."""
     crossings = []
     lock = threading.Lock()
+    # `_run_ranks` destroys the group the moment rank 0's fn returns: every
+    # rank must have read the group's record by then, whichever thread the
+    # scheduler runs first (rank 0 used to win, and the others read a
+    # record that was gone).
+    all_read = threading.Barrier(WORLD)
 
     def fn(rank, group):
         for rnd in range(3):
@@ -292,7 +297,10 @@ def test_barrier_reusable_across_rounds(collective_cluster):
             group.barrier()
             with lock:
                 crossings.append(("crossed", rnd, rank))
-        rec = _group_record(collective_cluster)
+        try:
+            rec = _group_record(collective_cluster)
+        finally:
+            all_read.wait(30)
         assert rec["pending_barriers"] == 0, rec
         return True
 

@@ -1,0 +1,266 @@
+"""What Qwen3-Next brought under `ops/`, on the CPU at small sizes: the
+chunked gated delta rule (Pallas kernels in the interpreter, and the scan
+fallback) against the step-by-step scan; the grouped products and the
+held-expert layer against dense arithmetic, dropless under a skewed
+router. The model itself is in `test_qwen3_next_model.py`.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from ray_tpu.ops import gated_delta as gd  # noqa: E402
+from ray_tpu.ops import grouped_matmul as gm  # noqa: E402
+from ray_tpu.ops import held_experts as he  # noqa: E402
+
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+
+
+def recurrence_inputs(seq, key_heads=1, value_heads=2, d=128, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (1, seq, key_heads, d))
+    k = jax.random.normal(ks[1], (1, seq, key_heads, d))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (1, seq, value_heads, d))
+    g = -0.3 * jax.nn.softplus(jax.random.normal(ks[3],
+                                                 (1, seq, value_heads)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, seq, value_heads)))
+    do = jax.random.normal(ks[5], (1, seq, value_heads, d))
+    # what both sides see is what bf16 holds
+    q, k, v, do = (t.astype(jnp.bfloat16).astype(jnp.float32)
+                   for t in (q, k, v, do))
+    return (q, k, v, g, beta), do
+
+
+def close(a, b, rel):
+    scale = float(jnp.max(jnp.abs(b))) + 1e-12
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) <= rel * scale
+
+
+# seq 128: two whole chunks; 100: not a multiple of the chunk; 640: ten
+# chunks, padded to two grid steps of eight
+@pytest.mark.parametrize("seq", [128, 100, 640])
+def test_chunked_kernels_match_the_step_by_step_scan(interpret, seq):
+    args, do = recurrence_inputs(seq)
+    gd.reset_gated_delta_status()
+    want = gd.gated_delta_scan(*args)
+    got = gd.gated_delta_rule(*args)
+    assert close(got, want, 1e-2)
+    grads = jax.grad(lambda *a: jnp.sum(gd.gated_delta_rule(*a) * do),
+                     argnums=range(5))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(gd.gated_delta_scan(*a) * do),
+                     argnums=range(5))(*args)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, wants):
+        # bf16 operands inside the kernels: 2^-8 of the largest entry
+        assert close(a, b, 2e-2), name
+    status = gd.gated_delta_status()
+    assert {c["pass"] for c in status} == {"fwd", "bwd"}
+    assert all(c["path"] == "pallas" and c["chunk"] == 64
+               and c["shape"] == [1, 2, seq, 128] for c in status)
+
+
+@pytest.mark.parametrize("seq", [64, 37])
+def test_the_fallback_is_the_scan_and_says_why(seq):
+    args, do = recurrence_inputs(seq, d=16)
+    gd.reset_gated_delta_status()
+    got = gd.gated_delta_rule(*args)
+    assert close(got, gd.gated_delta_scan(*args), 1e-6)
+    grads = jax.grad(lambda *a: jnp.sum(gd.gated_delta_rule(*a) * do),
+                     argnums=range(5))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(gd.gated_delta_scan(*a) * do),
+                     argnums=range(5))(*args)
+    assert all(close(a, b, 1e-5) for a, b in zip(grads, wants))
+    assert all(c["path"] == "scan" and c["reason"].startswith("platform")
+               for c in gd.gated_delta_status())
+
+
+def test_the_scan_is_the_recurrence_written_out():
+    (q, k, v, g, beta), _ = recurrence_inputs(5, d=8)
+    state = np.zeros((2, 8, 8))
+    for t in range(5):
+        for h in range(2):
+            s = state[h] * np.exp(g[0, t, h])
+            u = beta[0, t, h] * (v[0, t, h] - s.T @ k[0, t, 0])
+            state[h] = s + np.outer(k[0, t, 0], u)
+    out = gd.gated_delta_scan(q, k, v, g, beta)
+    np.testing.assert_allclose(out[0, 4, 1], state[1].T @ q[0, 4, 0],
+                               rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("n", [16, 64])
+def test_unit_lower_inverse_from_products(n):
+    a = np.tril(np.random.default_rng(n).normal(size=(n, n)) * 0.3, -1)
+    got = gd._unit_lower_inverse(jnp.asarray(a, jnp.float32))
+    np.testing.assert_allclose(got, np.linalg.inv(np.eye(n) + a), atol=2e-4)
+
+
+def test_causal_conv1d_is_the_published_left_padded_convolution():
+    rng = np.random.default_rng(0)
+    x, w = rng.normal(size=(2, 9, 3)), rng.normal(size=(3, 4))
+    want = np.zeros_like(x)
+    for t in range(9):
+        for j in range(4):
+            if t - 3 + j >= 0:
+                want[:, t] += w[:, j] * x[:, t - 3 + j]
+    np.testing.assert_allclose(
+        gd.causal_conv1d(jnp.asarray(x), jnp.asarray(w)), want, atol=1e-6)
+
+
+def test_grouped_matmul_and_both_gradients():
+    groups, k, n = 3, 256, 384
+    tile_group = jnp.array([0, 0, 1, 2, 2, 2], jnp.int32)
+    used = jnp.array([5], jnp.int32)          # the sixth tile is the tail
+    m = 6 * gm.TILE
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    lhs = jax.random.normal(ks[0], (m, k)).astype(jnp.bfloat16)
+    lhs = lhs.at[5 * gm.TILE:].set(0)
+    rhs = (0.1 * jax.random.normal(ks[1], (groups, k, n))).astype(
+        jnp.bfloat16).astype(jnp.float32)
+    do = jax.random.normal(ks[2], (m, n)).astype(jnp.bfloat16)
+    live = (jnp.arange(m) < 5 * gm.TILE)[:, None]
+
+    def dense(lhs, rhs):
+        w = rhs[jnp.repeat(tile_group, gm.TILE)]
+        return jnp.einsum("mk,mkn->mn", lhs.astype(jnp.float32), w) * live
+
+    out = gm.grouped_matmul(lhs, rhs, tile_group, used)
+    assert out.dtype == jnp.bfloat16 and close(out, dense(lhs, rhs), 1e-2)
+    assert not np.asarray(out[5 * gm.TILE:]).any()
+    f32 = jnp.float32
+    got = jax.grad(lambda l, r: jnp.sum(
+        gm.grouped_matmul(l, r, tile_group, used).astype(f32)
+        * do.astype(f32)), argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(lambda l, r: jnp.sum(dense(l, r) * do.astype(f32)),
+                    argnums=(0, 1))(lhs, rhs)
+    assert got[1].dtype == jnp.float32       # never through bf16
+    assert close(got[0], want[0].astype(f32), 1e-2)
+    assert close(got[1], want[1], 1e-3)
+
+
+def expert_problem(tokens=300, d=64, width=32, experts=16, skew=0.0):
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(ks[0], (tokens, d))
+    w_router = 0.5 * jax.random.normal(ks[1], (d, experts))
+    if skew:        # one feature every token has, and expert 5 loves
+        x = x.at[:, 0].set(3.0)
+        w_router = w_router.at[0, 5].add(5.0 * skew)
+    w_gate_up = 0.2 * jax.random.normal(ks[2], (experts, d, 2 * width))
+    w_down = 0.2 * jax.random.normal(ks[3], (experts, width, d))
+    return x, w_router, w_gate_up, w_down, jax.random.normal(ks[4],
+                                                             (tokens, d))
+
+
+def dense_experts(x, w_router, w_gate_up, w_down, held, top_k):
+    """Every token through every held expert, masked by its gate."""
+    first, count = held
+    width = w_down.shape[1]
+    _, gates, index = he.route(x, w_router, top_k)
+    bf = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
+    out = jnp.zeros_like(x)
+    for e in range(first, first + count):
+        h = bf(x) @ bf(w_gate_up[e])
+        y = (jax.nn.silu(h[:, :width]) * h[:, width:]) @ bf(w_down[e])
+        out = out + jnp.sum(jnp.where(index == e, gates, 0), -1)[:, None] * y
+    return out
+
+
+def held_part(x, w_router, w_gate_up, w_down, held, top_k):
+    first, count = held
+    _, gates, index = he.route(x, w_router, top_k)
+    return he.held_expert_mlp(
+        x.astype(jnp.bfloat16), gates, index,
+        w_gate_up[first:first + count], w_down[first:first + count], held,
+        w_router.shape[1])
+
+
+# (held, block): one block; a block so small that the later blocks run;
+# every expert held
+@pytest.mark.parametrize("held,block", [((4, 4), 0), ((4, 4), 128),
+                                        ((0, 16), 256)])
+def test_held_experts_match_dense_arithmetic(held, block, monkeypatch):
+    if block:
+        monkeypatch.setattr(he, "default_block", lambda *sizes: block)
+    x, w_router, w_gate_up, w_down, do = expert_problem()
+    args = (x, w_router, w_gate_up, w_down)
+    got, counts = held_part(*args, held, 4)
+    want = dense_experts(*args, held, 4)
+    assert close(got, want, 2e-2)
+    assert int(counts["placed"]) == int(counts["assigned"]) \
+        == int(jnp.sum(counts["load"])) > block
+    grads = jax.grad(lambda *a: jnp.sum(held_part(*a, held, 4)[0] * do),
+                     argnums=range(4))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(dense_experts(*a, held, 4) * do),
+                     argnums=range(4))(*args)
+    for name, a, b in zip(("x", "router", "gate_up", "down"), grads, wants):
+        assert close(a, b, 3e-2), name
+
+
+def test_dropless_when_one_expert_takes_most_tokens():
+    x, w_router, w_gate_up, w_down, _ = expert_problem(skew=1.0)
+    held = (5, 1)
+    _, _, index = he.route(x, w_router, 4)
+    taken = int(jnp.sum(index == 5))
+    assert taken > 0.9 * x.shape[0]          # the skew is real
+    he.reset_held_experts_status()
+    got, counts = held_part(x, w_router, w_gate_up, w_down, held, 4)
+    assert int(counts["load"][0]) == taken
+    assert int(counts["placed"]) == int(counts["assigned"])
+    assert close(got, dense_experts(x, w_router, w_gate_up, w_down, held, 4),
+                 2e-2)
+    (call,) = he.held_experts_status()
+    assert call["held"] == [5, 1] and call["experts"] == 16
+    # the default block is 3 x even routing: this load needs the later ones
+    assert int(counts["assigned"]) > call["block"] and call["blocks"] > 1
+
+
+
+
+@pytest.mark.parametrize("always", [1, 2, 4])
+def test_sum_rows_is_exact_whatever_it_gathers_unasked(always):
+    rng = np.random.default_rng(always)
+    rows = jnp.asarray(rng.normal(size=(40, 8)), jnp.float32)
+    # sorted slots, 40 = "no row"; one token has a row in every slot
+    slots = np.sort(np.where(rng.random((25, 4)) < 0.4,
+                             rng.integers(0, 40, (25, 4)), 40), axis=-1)
+    slots[3] = [1, 5, 7, 9]
+    ext = np.concatenate([np.asarray(rows), np.zeros((1, 8))])
+    got = he._sum_rows(rows, jnp.asarray(slots, jnp.int32), always)
+    np.testing.assert_allclose(got, ext[slots].sum(axis=1), atol=1e-5)
+    # nothing beyond the slots gathered unasked: the same, by the other branch
+    few = np.where(np.arange(4) < always, slots, 40)
+    got = he._sum_rows(rows, jnp.asarray(few, jnp.int32), always)
+    np.testing.assert_allclose(got, ext[few].sum(axis=1), atol=1e-5)
+
+
+def test_slots_always_by_the_rule():
+    # 10 x 32 / 512 = 0.625 held choices a token: one + six slots unasked
+    assert he.slots_always(8192, 10, 32, 512) == 7
+    assert he.slots_always(300, 4, 4, 16) == 4      # never more than top_k
+    assert 1 <= he.slots_always(8, 10, 1, 512) <= 2
+
+
+def test_default_block_and_the_status_line():
+    # 8192 x 10 x 32 / 512 = 5120 assignments at even routing; 3 x that
+    assert he.default_block(8192, 10, 32, 512) == 15360
+    assert he.default_block(64, 4, 1, 16) == 128    # whole tiles
+    assert he.default_block(8, 4, 16, 16) == 128    # never past the worst
+    x, w_router, w_gate_up, w_down, _ = expert_problem()
+    he.reset_held_experts_status()
+    held_part(x, w_router, w_gate_up, w_down, (4, 4), 4)
+    (call,) = he.held_experts_status()
+    # off the TPU, and not asked for: the interpreter, and it says so
+    assert call["path"] == ("pallas" if os.environ.get(
+        "RAY_TPU_PALLAS_INTERPRET") == "1" else "interpret")
+    assert call["block"] == he.default_block(x.shape[0], 4, 4, 16)

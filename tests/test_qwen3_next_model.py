@@ -1,0 +1,195 @@
+"""Qwen3-Next on the CPU at small sizes, seeded weights: the model against
+`benchmarks/reference/qwen3_next_plain.py` on logits, loss and gradients
+(one period, float32: the same mathematics; bfloat16 through the kernels in
+the interpreter: the same within rounding); sixteen shares of one expert
+layer against the uncut layer of the reference; a few steps through
+`make_train_step`.
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks.reference import qwen3_next_plain as plain  # noqa: E402
+from ray_tpu.models.gpt2 import make_train_step  # noqa: E402
+from ray_tpu.models.qwen3_next import (  # noqa: E402
+    Qwen3Next, Qwen3NextConfig, make_loss_fn, published_weights)
+from ray_tpu.ops import gated_delta as gd  # noqa: E402
+
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+
+
+def close(a, b, rel):
+    scale = float(jnp.max(jnp.abs(b))) + 1e-12
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) <= rel * scale
+
+
+def tiny(**kw):
+    return Qwen3NextConfig.tiny(dtype=jnp.float32, **kw)
+
+
+def reference_keys(cfg):
+    out = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
+           if isinstance(getattr(cfg, f.name), (int, float))}
+    return {**out, "router_width": cfg.num_experts}
+
+
+@pytest.fixture(scope="module")
+def both():
+    """The program and the plain reference on two seeded sequences, each
+    under ONE jitted value-and-grad (what every test below reads)."""
+    cfg = tiny(held_experts=(4, 8))
+    model = Qwen3Next(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 96), 0,
+                             cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), ids)
+    # norms start at 0 and 1: move them, so that a swapped one would show
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
+    leaves = [l + (0.05 * jax.random.normal(k, l.shape) if l.ndim == 1
+                   else 0) for l, k in zip(leaves, keys)]
+    params = jax.tree.unflatten(tree, leaves)
+    loss_fn = make_loss_fn(model)
+
+    @jax.jit
+    def program(params, ids):
+        def objective(p):
+            logits, aux = model.apply(p, ids[None], return_aux=True)
+            total, shown = loss_fn(p, {"input_ids": ids[None],
+                                       "labels": ids[None]})
+            return total, (shown, logits[0], aux)
+        return jax.value_and_grad(objective, has_aux=True)(params)
+
+    @jax.jit
+    def reference(w, ids):
+        def objective(w):
+            logits, picks, balance = plain.forward(
+                w, ids, reference_keys(cfg), cfg.held_experts)
+            ce = plain.next_token_loss(logits, ids)
+            return ce + cfg.router_aux_loss_coef * jnp.mean(balance), (
+                ce, logits, picks)
+        return jax.value_and_grad(objective, has_aux=True)(w)
+
+    weights = published_weights(params, cfg)
+    return cfg, [(program(params, row), reference(weights, row))
+                 for row in ids]
+
+
+def test_the_model_matches_the_plain_reference_in_float32(both):
+    cfg, rows = both
+    for ((_, (_, logits, aux)), _), ((_, (_, want, picks)), _) in rows:
+        assert float(jnp.max(jnp.abs(logits - want))) < 1e-4
+        assert bool(jnp.all(jnp.sort(aux["index"], -1)
+                            == jnp.sort(picks, -1)))
+        assert [int(x) for x in aux["placed"]] == [
+            int(x) for x in aux["assigned"]]
+
+
+def test_loss_and_gradients_match_the_plain_reference(both):
+    cfg, rows = both
+    for ((total, (shown, _, _)), grads), ((want, (want_ce, _, _)),
+                                          want_grads) in rows:
+        assert float(total) == pytest.approx(float(want), abs=1e-5)
+        assert float(shown["loss"]) == pytest.approx(float(want_ce),
+                                                     abs=1e-5)
+        assert float(total) > float(shown["loss"])     # the auxiliary loss
+        got_grads = published_weights(grads, cfg)
+        assert set(got_grads) == set(want_grads)
+        for name, want in want_grads.items():
+            assert close(got_grads[name], want, 1e-3), name
+
+
+def test_the_kernel_paths_match_the_reference_in_bfloat16(interpret):
+    # one recurrent and one attention layer, at widths the kernels take
+    cfg = Qwen3NextConfig.tiny(
+        held_experts=(4, 8), linear_key_head_dim=128,
+        linear_value_head_dim=128, linear_num_key_heads=1,
+        linear_num_value_heads=2, head_dim=64, num_hidden_layers=2,
+        full_attention_interval=2)
+    model = Qwen3Next(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 128), 0,
+                             cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), ids)
+    gd.reset_gated_delta_status()
+    logits = jax.jit(model.apply)(params, ids)
+    want, _, _ = jax.jit(lambda w, row: plain.forward(
+        w, row, reference_keys(cfg), cfg.held_experts))(
+        published_weights(params, cfg), ids[0])
+    assert close(logits[0], want, 5e-2)
+    assert {c["path"] for c in gd.gated_delta_status()} == {"pallas"}
+
+
+def test_sixteen_shares_add_up_to_the_uncut_layer():
+    """One expert layer of 32 experts, cut into sixteen shares of two: what
+    the shares give, with the shared expert (which every chip computes
+    alike) counted once, is what the uncut reference gives."""
+    from ray_tpu.models.qwen3_next import SparseMoe
+
+    base = tiny(num_experts=32, held_experts=(0, 32))
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+    x = jax.random.normal(ks[0], (1, 96, base.hidden_size))
+    whole = SparseMoe(base).init(ks[1], x)["params"]
+    unbox = lambda t: getattr(t, "value", t)
+    weights = {
+        "gate": whole["router"],
+        "experts.gate_proj": whole["experts_gate_up"][..., :32],
+        "experts.up_proj": whole["experts_gate_up"][..., 32:],
+        "experts.down_proj": whole["experts_down"],
+        "shared_expert.gate_proj": unbox(whole["shared_gate"]["kernel"]),
+        "shared_expert.up_proj": unbox(whole["shared_up"]["kernel"]),
+        "shared_expert.down_proj": unbox(whole["shared_down"]["kernel"]),
+        "shared_expert_gate": unbox(whole["shared_expert_gate"]["kernel"])}
+    keys = reference_keys(base)
+    uncut, *_ = plain.expert_layer(weights, "", x[0], keys, (0, 32))
+    shared_alone, *_ = plain.expert_layer(weights, "", x[0], keys, (0, 0))
+    total = jnp.zeros_like(uncut)
+    for share in range(16):
+        cfg = dataclasses.replace(base, held_experts=(2 * share, 2))
+        mine = {**whole,
+                "experts_gate_up": whole["experts_gate_up"][
+                    2 * share:2 * share + 2],
+                "experts_down": whole["experts_down"][
+                    2 * share:2 * share + 2]}
+        out, aux = jax.jit(SparseMoe(cfg).apply)({"params": mine}, x)
+        assert int(aux["placed"]) == int(aux["assigned"])
+        total = total + out[0]
+    assert float(jnp.max(jnp.abs(total - 15 * shared_alone - uncut))) < 1e-4
+
+
+def test_trained_through_make_train_step_the_loss_falls():
+    import optax
+
+    cfg = Qwen3NextConfig.tiny(held_experts=(4, 8), remat=True,
+                               num_hidden_layers=2,
+                               full_attention_interval=2)
+    model = Qwen3Next(cfg)
+    ids = (cfg.vocab_size * np.random.default_rng(0).random((2, 64)) ** 3
+           ).astype(np.int32)
+    params = model.init(jax.random.PRNGKey(0), jnp.asarray(ids))
+    opt = optax.adamw(3e-3)
+    step = make_train_step(model, opt, donate=False,
+                           loss_fn=make_loss_fn(model))
+    opt_state = opt.init(params)
+    batch = {"input_ids": jnp.asarray(ids), "labels": jnp.asarray(ids)}
+    losses = []
+    for _ in range(5):
+        params, opt_state, shown = step(params, opt_state, batch)
+        losses.append(float(shown["loss"]))
+    assert losses[-1] < losses[0]
+    # the routed counts are outputs of the step
+    assert shown["moe"]["load"].shape == (2, 8)
+    assert [int(x) for x in shown["moe"]["placed"]] == [
+        int(x) for x in shown["moe"]["assigned"]]
+    assert shown["load_balance"].shape == (2,)

@@ -406,8 +406,15 @@ def test_task_propagation_one_trace(traced_cluster):
     assert pctx["trace_id"] == root.trace_id
     assert cctx["trace_id"] == root.trace_id
     assert pctx["sampled"] and cctx["sampled"]
-    spans = _trace_spans(root.trace_id, {"test.task.root", "task.run"})
-    runs = [s for s in spans if s["name"] == "task.run"]
+    # The two tasks ran in two workers, each flushing on its own 2 s
+    # cadence: the first poll that sees one `task.run` need not see both.
+    deadline = time.time() + 25.0
+    while True:
+        spans = _trace_spans(root.trace_id, {"test.task.root", "task.run"})
+        runs = [s for s in spans if s["name"] == "task.run"]
+        if len(runs) >= 2 or time.time() > deadline:
+            break
+        time.sleep(0.4)
     assert len(runs) >= 2  # parent and child tasks
     # parent edges resolve: the parent task's span hangs off the root
     by_id = {s["span_id"]: s for s in spans}
