@@ -23,13 +23,37 @@ its start:
     S_end = exp(G_C) S + (exp(G_C - G) * K)^T U
 
 `gdn_chunk_fwd` walks one (batch, value head)'s chunks in order with the
-[dk, dv] state carried in f32 in VMEM; with `save` it also writes the state
-at every chunk's start, which is the residual the backward needs (O(seq/64)
-states, not O(seq)). `gdn_chunk_bwd` walks the chunks in reverse carrying
-dS, recomputes each chunk's T, W, U from its inputs and the saved state, and
+[dk, dv] state carried in f32; with `save` it also writes the state at every
+chunk's start, which is the residual the backward needs (O(seq/64) states,
+not O(seq)). `gdn_chunk_bwd` walks the chunks in reverse carrying dS,
+recomputes each chunk's T, W, U from its inputs and the saved state, and
 writes dq, dk, dv, dG and dbeta. Matmul operands are bf16 with f32
 accumulation (as the flash kernels), except the triangular inverse, which
 is f32 at `Precision.HIGHEST`; the carried state and every exp() are f32.
+
+A grid step holds `_CHUNKS_PER_STEP` = 8 chunks (512 positions) and walks
+them in two phases. Nothing above but the lines that name S needs the
+state, and that half is a chain about eleven products deep (K K^T, the
+series, W, U0), so a loop that ran whole chunks one after another waited on
+the depth of one chunk's chain (2.09 us a chunk forward, PERF.md PR 35).
+(1) ABREAST: `_chunk_parts` on [8, 64, d] operands, the chunk index the
+batch dimension of every `dot_general` (lowered once, eight independent
+chains for the scheduler): decay, A, T, W, U0, P, Kd, Qe, exp(G_C). What
+the walk reads of it goes to VMEM scratch: W, Kd, Qe [8, 64, 128] and P
+[8, 64, 64] in bf16, U0 in f32, exp(G_C): 88 KB a chunk, 0.7 MB a step.
+(2) SERIAL: a `fori_loop` with the state as the loop's VALUE holds only the
+products that read it: U = U0 - W S, S_end = exp(G_C) S + Kd^T U, and
+O = Qe S + P U beside them. The carry runs through two products a chunk
+(W S, then Kd^T U), which is why the loop holds no more. The backward has
+three phases: (1) abreast, the same `_chunk_parts` again, and from the
+saved states U, P^T dO and Qe^T dO (`_chunk_backward_before`); scratch: Kd,
+W, exp(G_C), P^T dO [8, 64, 128] f32 and Qe^T dO [8, 128, 128] f32; (2)
+serial in reverse, dU = Kd dS_end + P^T dO and dS = exp(G_C) dS_end +
+Qe^T dO - W^T dU (`_chunk_backward_carry`: again two products deep),
+leaving every chunk's dS_end (f32) and dU (bf16) in scratch, 1.7 MB a step
+in all; (3) abreast again, everything else (`_chunk_backward_after`: dKd,
+dQe, dP, dW, dT, dA through the two `HIGHEST` products, the gate and beta
+sums), which touches no carry.
 
 The triangular solve is an inverse built from products, because the MXU
 has products and no substitution: 16-row diagonal blocks by the nilpotent
@@ -116,55 +140,77 @@ def causal_conv1d(x, w):
 
 
 # --------------------------------------------------------------------------- #
-# One chunk's mathematics, on 2D values (kernel bodies and nothing else)
+# The chunk's mathematics, on one chunk's [C, .] values or on [n, C, .]
+# values of n chunks abreast (kernel bodies and nothing else)
 # --------------------------------------------------------------------------- #
 
 
+def _dims(dims, rank: int):
+    """A 2D product's dimension numbers, or those of the same product of
+    every chunk with the chunk index as the batch dimension."""
+    if rank == 2:
+        return dims
+    ((lhs,), (rhs,)), _ = dims
+    return (((lhs + 1,), (rhs + 1,)), ((0,), (0,)))
+
+
 def _dot(a, b, dims):
-    return jax.lax.dot_general(a, b, dims,
+    return jax.lax.dot_general(a, b, _dims(dims, a.ndim),
                                preferred_element_type=jnp.float32)
 
 
 def _dot32(a, b, dims=_NN):
-    return jax.lax.dot_general(a, b, dims,
+    return jax.lax.dot_general(a, b, _dims(dims, a.ndim),
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
-def _iotas(n: int):
-    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0),
-            jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+def _iotas(shape):
+    """The row and the column index of `shape`'s last two dims."""
+    rank = len(shape)
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, rank - 2),
+            jax.lax.broadcasted_iota(jnp.int32, shape, rank - 1))
+
+
+def _square(t):
+    n = max(t.shape[-2:])
+    return t.shape[:-2] + (n, n)
 
 
 def _column(row):
-    """[1, n] -> [n, 1]: the diagonal of the row's sublane broadcast."""
-    n = row.shape[1]
-    r, c = _iotas(n)
-    return jnp.sum(jnp.where(r == c, jnp.broadcast_to(row, (n, n)), 0.0),
-                   axis=1, keepdims=True)
+    """[.., 1, n] -> [.., n, 1]: the diagonal of the row's sublane
+    broadcast."""
+    r, c = _iotas(_square(row))
+    return jnp.sum(jnp.where(r == c, jnp.broadcast_to(row, r.shape), 0.0),
+                   axis=-1, keepdims=True)
 
 
 def _row(col):
-    """[n, 1] -> [1, n]: the diagonal of the column's lane broadcast."""
-    n = col.shape[0]
-    r, c = _iotas(n)
-    return jnp.sum(jnp.where(r == c, jnp.broadcast_to(col, (n, n)), 0.0),
-                   axis=0, keepdims=True)
+    """[.., n, 1] -> [.., 1, n]: the diagonal of the column's lane
+    broadcast."""
+    r, c = _iotas(_square(col))
+    return jnp.sum(jnp.where(r == c, jnp.broadcast_to(col, r.shape), 0.0),
+                   axis=-2, keepdims=True)
+
+
+def _total(m):
+    """The sum over the last two dims, kept: [.., 1, 1]."""
+    return jnp.sum(jnp.sum(m, axis=-1, keepdims=True), axis=-2,
+                   keepdims=True)
 
 
 def _transpose(m):
     """A square f32 matrix's transpose as eye @ m^T, which the MXU does in
     the layout it already reads."""
-    n = m.shape[0]
-    r, c = _iotas(n)
+    r, c = _iotas(m.shape)
     return _dot32((r == c).astype(jnp.float32), m, _NT)
 
 
 def _unit_lower_inverse(a):
-    """(I + a)^-1 for a strictly lower triangular [n, n] f32 `a`, n a
+    """(I + a)^-1 for a strictly lower triangular [.., n, n] f32 `a`, n a
     multiple of `_SUB`, from products alone (module docstring)."""
-    n = a.shape[0]
-    r, c = _iotas(n)
+    n = a.shape[-1]
+    r, c = _iotas(a.shape)
     eye = (r == c).astype(jnp.float32)
     same = (r // _SUB) == (c // _SUB)
     diag, low = jnp.where(same, a, 0.0), jnp.where(same, 0.0, a)
@@ -185,12 +231,14 @@ def _unit_lower_inverse(a):
 
 
 def _chunk_parts(q, k, v, g_row, b_row):
-    """What forward and backward both need of one chunk and that does not
-    depend on the state. q, k, v [C, d] bf16; g_row (the running sum of
-    log decay), b_row [1, C] f32."""
+    """What forward and backward both need of a chunk and that does not
+    depend on the state. q, k, v [.., C, d] bf16; g_row (the running sum of
+    log decay), b_row [.., 1, C] f32. With a leading dim every product is
+    one batched `dot_general` over the chunks: the body is lowered once
+    and the chunks' chains are independent work."""
     f32, bf16 = jnp.float32, jnp.bfloat16
-    n = q.shape[0]
-    r, c = _iotas(n)
+    n = q.shape[-2]
+    r, c = _iotas(q.shape[:-2] + (n, n))
     g_col, b_col = _column(g_row), _column(b_row)
     # exp() only of what is <= 0: above the diagonal G_t - G_j > 0 may
     # overflow, and is not part of the chunk.
@@ -203,14 +251,16 @@ def _chunk_parts(q, k, v, g_row, b_row):
     kb = (kf * (b_col * e_col)).astype(bf16)
     vb = (vf * b_col).astype(bf16)
     tb = t.astype(bf16)
-    last = (c == n - 1)[:1]
-    g_last = jnp.sum(jnp.where(last, g_row, 0.0))    # a scalar
+    last = (c == n - 1)[..., :1, :]
+    g_last = jnp.sum(jnp.where(last, g_row, 0.0), axis=-1,
+                     keepdims=True)                  # [.., 1, 1]
     kd = (kf * jnp.exp(g_last - g_col)).astype(bf16)
     qe = (qf * e_col).astype(bf16)
     p = decay * _dot(q, k, _NT)                      # zero above diagonal
     return dict(g_col=g_col, b_col=b_col, e_col=e_col, decay=decay, kk=kk,
                 a=a, t=t, tb=tb, kb=kb, vb=vb, kd=kd, qe=qe, p=p,
-                g_last=g_last, w=_dot(tb, kb, _NN), u0=_dot(tb, vb, _NN),
+                g_last=g_last, e_last=jnp.exp(g_last),
+                w=_dot(tb, kb, _NN), u0=_dot(tb, vb, _NN),
                 strict=r > c, lower=r >= c, last=last)
 
 
@@ -221,56 +271,73 @@ def _chunk_u(parts, sb):
 
 
 def _chunk_forward(parts, state):
-    """(o [C, dv] f32, state at the chunk's end [dk, dv] f32)."""
+    """(o [C, dv] f32, state at the chunk's end [dk, dv] f32): the products
+    that read the state. The carry runs through two of them, W S and
+    Kd^T U."""
     sb = state.astype(jnp.bfloat16)
     u = _chunk_u(parts, sb)
     o = _dot(parts["qe"], sb, _NN) + _dot(parts["p"].astype(jnp.bfloat16),
                                           u, _NN)
-    end = jnp.exp(parts["g_last"]) * state + _dot(parts["kd"], u, _TN)
+    end = parts["e_last"] * state + _dot(parts["kd"], u, _TN)
     return o, end
 
 
-def _chunk_backward(q, k, v, parts, state, do, d_end):
-    """Gradients of one chunk: (dq, dk, dv [C, d] f32, dG_row, db_row
-    [1, C] f32, d_state [dk, dv] f32) from do [C, dv] bf16 and the gradient
-    `d_end` of the state at the chunk's end. The steps follow
-    `_chunk_parts` and `_chunk_forward` backwards, line for line."""
+# A chunk's backward in three parts, which together follow `_chunk_parts`
+# and `_chunk_forward` backwards, line for line: what needs no carry and
+# the serial walk reads (`_before`), what the carried dS runs through
+# (`_carry`), and everything else (`_after`).
+
+
+def _chunk_backward_before(parts, state, do):
+    """From the state at the chunk's start and do [C, dv] bf16: u, and
+    the terms of du and d_state that `o = qe state + p u` gives."""
+    sb = state.astype(jnp.bfloat16)
+    pb = parts["p"].astype(jnp.bfloat16)
+    return dict(sb=sb, u=_chunk_u(parts, sb),
+                du_o=_dot(pb, do, _TN), ds_o=_dot(parts["qe"], do, _TN))
+
+
+def _chunk_backward_carry(x, d_end):
+    """(du bf16, d_state f32) from the gradient `d_end` of the state at
+    the chunk's end; x holds the chunk's kd, w (bf16), e_last, du_o, ds_o.
+    The carry runs through two products, Kd dS and W^T dU."""
+    # end = e_last * state + kd^T u;  o = qe state + p u
+    du = _dot(x["kd"], d_end.astype(jnp.bfloat16), _NN) + x["du_o"]
+    # u = u0 - w state
+    dub = du.astype(jnp.bfloat16)
+    d_state = x["e_last"] * d_end + x["ds_o"] - _dot(x["w"], dub, _TN)
+    return dub, d_state
+
+
+def _chunk_backward_after(q, k, v, parts, before, state, do, d_end, dub):
+    """(dq, dk, dv [C, d] f32, dG_row, db_row [1, C] f32) once the walk
+    has left the chunk's `d_end` and `dub`."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     x = parts
     qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
-    sb = state.astype(bf16)
-    u = _chunk_u(x, sb)
-    wb = x["w"].astype(bf16)
-    pb = x["p"].astype(bf16)
-    e_last = jnp.exp(x["g_last"])
+    sb, u = before["sb"], before["u"]
     deb = d_end.astype(bf16)
     # end = e_last * state + kd^T u
-    d_state = e_last * d_end
-    du = _dot(x["kd"], deb, _NN)
     dkd = _dot(u, deb, _NT)
-    kd_term = jnp.sum(dkd * x["kd"].astype(f32), axis=1, keepdims=True)
-    d_last = e_last * jnp.sum(d_end * state) + jnp.sum(kd_term)
+    kd_term = jnp.sum(dkd * x["kd"].astype(f32), axis=-1, keepdims=True)
+    d_last = x["e_last"] * _total(d_end * state) + _total(kd_term)
     dk = dkd * jnp.exp(x["g_last"] - x["g_col"])
     dg_col = -kd_term
     # o = qe state + p u
     dqe = _dot(do, sb, _NT)
-    d_state = d_state + _dot(x["qe"], do, _TN)
     dp = jnp.where(x["lower"], _dot(do, u, _NT), 0.0)
-    du = du + _dot(pb, do, _TN)
     dq = dqe * x["e_col"]
-    dg_col = dg_col + jnp.sum(dqe * x["qe"].astype(f32), axis=1,
+    dg_col = dg_col + jnp.sum(dqe * x["qe"].astype(f32), axis=-1,
                               keepdims=True)
     # p = decay * (q k^T)
     dqk = (dp * x["decay"]).astype(bf16)
     dq = dq + _dot(dqk, k, _NN)
     dk = dk + _dot(dqk, q, _TN)
     r1 = dp * x["p"]
-    dg_col = dg_col + jnp.sum(r1, axis=1, keepdims=True)
-    dg_row = -jnp.sum(r1, axis=0, keepdims=True)
+    dg_col = dg_col + jnp.sum(r1, axis=-1, keepdims=True)
+    dg_row = -jnp.sum(r1, axis=-2, keepdims=True)
     # u = u0 - w state
-    dub = du.astype(bf16)
     dw = -_dot(dub, sb, _NT)
-    d_state = d_state - _dot(wb, dub, _TN)
     # w = t kb, u0 = t vb
     dwb = dw.astype(bf16)
     dt = _dot(dwb, x["kb"], _NT) + _dot(dub, x["vb"], _NT)
@@ -278,9 +345,9 @@ def _chunk_backward(q, k, v, parts, state, do, d_end):
     dvb = _dot(x["tb"], dub, _TN)
     # kb = k * (beta e^G), vb = v * beta
     dv = dvb * x["b_col"]
-    db_col = jnp.sum(dvb * vf, axis=1, keepdims=True)
+    db_col = jnp.sum(dvb * vf, axis=-1, keepdims=True)
     dk = dk + dkb * (x["b_col"] * x["e_col"])
-    kb_term = jnp.sum(dkb * kf, axis=1, keepdims=True) * x["e_col"]
+    kb_term = jnp.sum(dkb * kf, axis=-1, keepdims=True) * x["e_col"]
     db_col = db_col + kb_term
     dg_col = dg_col + kb_term * x["b_col"]
     # t = (I + a)^-1: da = -t^T dt t^T
@@ -289,72 +356,116 @@ def _chunk_backward(q, k, v, parts, state, do, d_end):
     # a = strict * beta_t * decay * (k k^T)
     dkk = (da * x["b_col"] * x["decay"]).astype(bf16)
     dk = dk + _dot(dkk, k, _NN) + _dot(dkk, k, _TN)
-    db_col = db_col + jnp.sum(da * x["decay"] * x["kk"], axis=1,
+    db_col = db_col + jnp.sum(da * x["decay"] * x["kk"], axis=-1,
                               keepdims=True)
     r2 = da * x["a"]
-    dg_col = dg_col + jnp.sum(r2, axis=1, keepdims=True)
-    dg_row = dg_row - jnp.sum(r2, axis=0, keepdims=True)
+    dg_col = dg_col + jnp.sum(r2, axis=-1, keepdims=True)
+    dg_row = dg_row - jnp.sum(r2, axis=-2, keepdims=True)
     dg_row = dg_row + _row(dg_col) + jnp.where(x["last"], d_last, 0.0)
-    return dq, dk, dv, dg_row, _row(db_col), d_state
+    return dq, dk, dv, dg_row, _row(db_col)
 
 
 # --------------------------------------------------------------------------- #
 # Kernels
 # --------------------------------------------------------------------------- #
 
+# What the serial walk reads of a chunk, kept in VMEM scratch between the
+# phases: name -> (trailing shape in units of (chunk, d), dtype); and what
+# the backward's walk leaves of a chunk for the third phase.
+_FWD_KEPT = {"w": ("cd", jnp.bfloat16), "u0": ("cd", jnp.float32),
+             "p": ("cc", jnp.bfloat16), "kd": ("cd", jnp.bfloat16),
+             "qe": ("cd", jnp.bfloat16), "e_last": ("11", jnp.float32)}
+_BWD_KEPT = {"kd": ("cd", jnp.bfloat16), "w": ("cd", jnp.bfloat16),
+             "e_last": ("11", jnp.float32), "du_o": ("cd", jnp.float32),
+             "ds_o": ("dd", jnp.float32)}
+_BWD_LEFT = {"d_end": ("dd", jnp.float32), "dub": ("cd", jnp.bfloat16)}
+
+
+def _scratch(kept, steps: int, chunk: int, d: int):
+    from jax.experimental.pallas import tpu as pltpu
+
+    size = {"c": chunk, "d": d, "1": 1}
+    return [pltpu.VMEM((d, d), jnp.float32)] + [
+        pltpu.VMEM((steps, size[dims[0]], size[dims[1]]), dtype)
+        for dims, dtype in kept.values()]
+
+
+def _abreast(refs, gate_refs, steps: int, chunk: int):
+    """A grid step's operands with the chunk index in front: [steps, chunk,
+    d] of every ref of `refs`, [steps, 1, chunk] of every gate."""
+    return ([ref[0].reshape(steps, chunk, ref.shape[-1]) for ref in refs]
+            + [ref[0, 0][:, None, :] for ref in gate_refs])
+
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, chunk: int,
                 steps: int, save: bool):
     from jax.experimental import pallas as pl
 
-    h_ref, state = rest if save else (None, rest[0])
+    h_ref, state, *kept = rest if save else (None, *rest)
+    kept = dict(zip(_FWD_KEPT, kept))
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         state[...] = jnp.zeros_like(state)
 
-    def body(i, carry):
+    # abreast: every chunk's state-independent part
+    parts = _chunk_parts(*_abreast((q_ref, k_ref, v_ref), (g_ref, b_ref),
+                                   steps, chunk))
+    for name, ref in kept.items():
+        ref[...] = parts[name].astype(ref.dtype)
+
+    # serial: the products that read the state, the state the loop's value
+    def body(i, start):
         rows = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
-        parts = _chunk_parts(q_ref[0, rows, :], k_ref[0, rows, :],
-                             v_ref[0, rows, :], g_ref[0, 0, pl.ds(i, 1), :],
-                             b_ref[0, 0, pl.ds(i, 1), :])
-        start = state[...]
         if save:
             h_ref[0, 0, i] = start
-        o, end = _chunk_forward(parts, start)
+        o, end = _chunk_forward({name: ref[i] for name, ref in kept.items()},
+                                start)
         o_ref[0, rows, :] = o.astype(o_ref.dtype)
-        state[...] = end
-        return carry
+        return end
 
-    jax.lax.fori_loop(0, steps, body, 0)
+    state[...] = jax.lax.fori_loop(0, steps, body, state[...])
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, h_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, d_state, *, chunk: int,
+                dk_ref, dv_ref, dg_ref, db_ref, d_state, *rest, chunk: int,
                 steps: int):
     from jax.experimental import pallas as pl
+
+    kept = dict(zip(_BWD_KEPT, rest))
+    d_ends, dubs = rest[len(kept):]
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         d_state[...] = jnp.zeros_like(d_state)
 
-    def body(j, carry):
-        i = steps - 1 - j
-        rows = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
-        q, k, v = q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :]
-        parts = _chunk_parts(q, k, v, g_ref[0, 0, pl.ds(i, 1), :],
-                             b_ref[0, 0, pl.ds(i, 1), :])
-        dq, dk, dv, dg, db, ds = _chunk_backward(
-            q, k, v, parts, h_ref[0, 0, i], do_ref[0, rows, :], d_state[...])
-        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
-        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
-        dg_ref[0, 0, pl.ds(i, 1), :] = dg
-        db_ref[0, 0, pl.ds(i, 1), :] = db
-        d_state[...] = ds
-        return carry
+    # abreast: the forward's state-independent part again, and u
+    q, k, v, do, g_row, b_row = _abreast((q_ref, k_ref, v_ref, do_ref),
+                                         (g_ref, b_ref), steps, chunk)
+    parts = _chunk_parts(q, k, v, g_row, b_row)
+    states = h_ref[0, 0]
+    before = _chunk_backward_before(parts, states, do)
+    made = {**parts, **before}
+    for name, ref in kept.items():
+        ref[...] = made[name].astype(ref.dtype)
 
-    jax.lax.fori_loop(0, steps, body, 0)
+    # serial, in reverse: what the carried dS runs through
+    def body(j, d_end):
+        i = steps - 1 - j
+        d_ends[i] = d_end
+        dubs[i], start = _chunk_backward_carry(
+            {name: ref[i] for name, ref in kept.items()}, d_end)
+        return start
+
+    d_state[...] = jax.lax.fori_loop(0, steps, body, d_state[...])
+
+    # abreast: everything else
+    dq, dk, dv, dg, db = _chunk_backward_after(
+        q, k, v, parts, before, states, do, d_ends[...], dubs[...])
+    for ref, t in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv)):
+        ref[0] = t.reshape(steps * chunk, -1).astype(ref.dtype)
+    dg_ref[0, 0] = dg.reshape(steps, chunk)
+    db_ref[0, 0] = db.reshape(steps, chunk)
 
 
 def _specs(rep: int, d: int, chunk: int, steps: int, n_blocks: int,
@@ -404,7 +515,7 @@ def _gdn_forward(q, k, v, gcum, beta, chunk: int, steps: int, save: bool,
         grid=(batch, heads, n_blocks),
         in_specs=[sp["key"], sp["key"], sp["value"], sp["gate"], sp["gate"]],
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
+        scratch_shapes=_scratch(_FWD_KEPT, steps, chunk, d),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -437,7 +548,8 @@ def _gdn_backward(q, k, v, gcum, beta, states, do, chunk: int, steps: int,
         out_specs=[sp["value"], sp["value"], sp["value"], sp["gate"],
                    sp["gate"]],
         out_shape=[wide, wide, wide, gate, gate],
-        scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
+        scratch_shapes=_scratch({**_BWD_KEPT, **_BWD_LEFT}, steps, chunk,
+                                d),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -449,7 +561,7 @@ def _gdn_backward(q, k, v, gcum, beta, states, do, chunk: int, steps: int,
 # Dispatch + custom VJP
 # --------------------------------------------------------------------------- #
 
-# (pass, path, reason, shape, dtype, chunk) -> traced calls
+# (pass, path, reason, shape, dtype, chunk, chunks_abreast) -> traced calls
 _CALLS: collections.Counter = collections.Counter()
 _CALLS_LOCK = threading.Lock()
 
@@ -458,12 +570,15 @@ def gated_delta_status() -> list:
     """Which path every traced recurrence call of this process took: one
     entry per distinct (pass, shape) with `path` "pallas" or "scan", the
     dispatch rule's `reason` for a scan call, `shape` [batch, value_heads,
-    seq, head_dim], the `chunk` and the number of traced calls."""
+    seq, head_dim], the `chunk`, `chunks_abreast` (how many chunks'
+    state-independent parts one grid step of the kernels computes side by
+    side; None on the scan path) and the number of traced calls."""
     with _CALLS_LOCK:
         items = list(_CALLS.items())
     return [{"pass": p, "path": path, "reason": reason, "shape": list(shape),
-             "dtype": dtype, "chunk": chunk, "calls": n}
-            for (p, path, reason, shape, dtype, chunk), n in items]
+             "dtype": dtype, "chunk": chunk, "chunks_abreast": abreast,
+             "calls": n}
+            for (p, path, reason, shape, dtype, chunk, abreast), n in items]
 
 
 def reset_gated_delta_status() -> None:
@@ -488,10 +603,16 @@ def _dispatch(pass_: str, q, k, v) -> bool:
     else:
         reason = ""
     key = (pass_, "scan" if reason else "pallas", reason, (b, hv, s, dv),
-           jnp.dtype(v.dtype).name, CHUNK)
+           jnp.dtype(v.dtype).name, CHUNK,
+           None if reason else _steps(_padded_len(s) // CHUNK))
     with _CALLS_LOCK:
         _CALLS[key] += 1
     return not reason
+
+
+def _steps(chunks: int) -> int:
+    """The chunks one grid step holds, and runs abreast."""
+    return min(_CHUNKS_PER_STEP, chunks)
 
 
 def _padded_len(seq: int) -> int:
@@ -519,7 +640,7 @@ def _kernel_operands(q, k, v, g, beta):
         return t.reshape(b, -1, CHUNK, hv).transpose(0, 3, 1, 2)
 
     gcum = jnp.cumsum(gates(g), axis=-1)
-    steps = min(_CHUNKS_PER_STEP, gcum.shape[2])
+    steps = _steps(gcum.shape[2])
     return (wide(q), wide(k), wide(v), gcum, gates(beta)), steps
 
 

@@ -50,8 +50,10 @@ def close(a, b, rel):
 
 
 # seq 128: two whole chunks; 100: not a multiple of the chunk; 640: ten
-# chunks, padded to two grid steps of eight
-@pytest.mark.parametrize("seq", [128, 100, 640])
+# chunks, padded to two grid steps of eight; 1100: eighteen chunks with a
+# ragged end, padded to three grid steps, so the carry crosses two grid
+# steps in both directions. One key head serves two value heads in all.
+@pytest.mark.parametrize("seq", [128, 100, 640, 1100])
 def test_chunked_kernels_match_the_step_by_step_scan(interpret, seq):
     args, do = recurrence_inputs(seq)
     gd.reset_gated_delta_status()
@@ -68,7 +70,45 @@ def test_chunked_kernels_match_the_step_by_step_scan(interpret, seq):
     status = gd.gated_delta_status()
     assert {c["pass"] for c in status} == {"fwd", "bwd"}
     assert all(c["path"] == "pallas" and c["chunk"] == 64
-               and c["shape"] == [1, 2, seq, 128] for c in status)
+               and c["shape"] == [1, 2, seq, 128]
+               and c["chunks_abreast"] == min(8, -(-seq // 64))
+               for c in status)
+
+
+@pytest.mark.parametrize("chunks", [1, 3, 8])
+def test_chunks_abreast_equal_the_chunks_one_by_one(chunks):
+    """`_chunk_parts` on [chunks, 64, d] operands (every product one
+    batched `dot_general`, what a grid step runs) against the same function
+    on each chunk's [64, d] alone."""
+    (q, k, v, g, beta), _ = recurrence_inputs(chunks * gd.CHUNK, 1, 1)
+    q, k, v = (t[0, :, 0].astype(jnp.bfloat16).reshape(chunks, gd.CHUNK, -1)
+               for t in (q, k, v))
+    g_row = jnp.cumsum(g[0, :, 0].reshape(chunks, 1, gd.CHUNK), axis=-1)
+    b_row = beta[0, :, 0].reshape(chunks, 1, gd.CHUNK)
+    abreast = gd._chunk_parts(q, k, v, g_row, b_row)
+    for i in range(chunks):
+        alone = gd._chunk_parts(q[i], k[i], v[i], g_row[i], b_row[i])
+        for name in ("t", "w", "u0", "p", "kd", "qe", "e_last"):
+            assert abreast[name][i].shape == alone[name].shape, name
+            assert close(abreast[name][i], alone[name].astype(jnp.float32),
+                         1e-6), (name, i)
+
+
+# the chunk count under one grid step's eight, eight from there on; the
+# scan has no grid step
+@pytest.mark.parametrize("seq,d,abreast", [(128, 128, 2), (100, 128, 2),
+                                           (512, 128, 8), (8192, 128, 8),
+                                           (128, 16, None)])
+def test_status_says_how_many_chunks_run_abreast(interpret, seq, d, abreast):
+    args, _ = recurrence_inputs(seq, d=d)
+    gd.reset_gated_delta_status()
+    jax.eval_shape(jax.grad(lambda *a: jnp.sum(gd.gated_delta_rule(*a))),
+                   *args)
+    status = gd.gated_delta_status()
+    assert {c["pass"] for c in status} == {"fwd", "bwd"}
+    assert all(c["chunks_abreast"] == abreast
+               and c["path"] == ("pallas" if abreast else "scan")
+               for c in status)
 
 
 @pytest.mark.parametrize("seq", [64, 37])
@@ -83,6 +123,7 @@ def test_the_fallback_is_the_scan_and_says_why(seq):
                      argnums=range(5))(*args)
     assert all(close(a, b, 1e-5) for a, b in zip(grads, wants))
     assert all(c["path"] == "scan" and c["reason"].startswith("platform")
+               and c["chunks_abreast"] is None
                for c in gd.gated_delta_status())
 
 
