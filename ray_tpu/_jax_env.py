@@ -13,6 +13,7 @@ variable's back.
 from __future__ import annotations
 
 import os
+import time
 from typing import Dict
 
 GRANT_ENV = "RAY_TPU_GRANTED_TPU"
@@ -47,7 +48,12 @@ def enable_compilation_cache() -> str:
 
 def device_info() -> Dict[str, object]:
     """What this process computes on, as jax reports it. Initializes the
-    backend; every stats/report path that names a device reads this."""
+    backend; every stats/report path that names a device reads this
+    (so a process that computes without a grant to claim, a CPU replica,
+    has its programs timed from here on: `observability/compile.py`)."""
+    from ray_tpu.observability import compile as _compile
+
+    _compile.install()
     import jax
 
     devices = jax.local_devices()
@@ -67,16 +73,34 @@ def claim_devices() -> Dict[str, object]:
     spawned with a TPU grant must see exactly the granted chips on
     platform `tpu` — anything else (a CPU fallback, a neighbour's chips)
     is an error here, not a slow or wrong number later. Returns
-    `device_info()` plus the cache directory."""
-    cache_dir = enable_compilation_cache()
-    info = device_info()
-    granted = granted_tpu_chips()
-    if granted and (info["platform"] != "tpu"
-                    or info["n_devices"] != granted):
-        raise RuntimeError(
-            f"worker was granted {granted} TPU chip(s) but jax reports "
-            f"{info['n_devices']} local device(s) on platform "
-            f"{info['platform']!r} ({info['device_kind']}); "
-            f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} "
-            f"TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')!r}")
+    `device_info()` plus the cache directory.
+
+    This is also where a process's start-up timeline learns about jax:
+    the call is a lifecycle span (`jax.claim_devices`, with the seconds
+    of the jax import and of the backend coming up as attributes), and
+    from here on every program's trace, lowering and compile is timed
+    (`observability/compile.py`)."""
+    from ray_tpu.observability import compile as _compile
+    from ray_tpu.observability import tracing as _tracing
+
+    with _tracing.get_tracer().lifecycle_span(
+            "jax.claim_devices", always=True) as span:
+        t0 = time.monotonic()
+        _compile.install()          # imports jax
+        t1 = time.monotonic()
+        cache_dir = enable_compilation_cache()
+        info = device_info()        # starts the backend
+        span.set_attr("jax_import_s", round(t1 - t0, 3))
+        span.set_attr("backend_s", round(time.monotonic() - t1, 3))
+        span.set_attr("platform", info["platform"])
+        span.set_attr("n_devices", info["n_devices"])
+        granted = granted_tpu_chips()
+        if granted and (info["platform"] != "tpu"
+                        or info["n_devices"] != granted):
+            raise RuntimeError(
+                f"worker was granted {granted} TPU chip(s) but jax reports "
+                f"{info['n_devices']} local device(s) on platform "
+                f"{info['platform']!r} ({info['device_kind']}); "
+                f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} "
+                f"TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')!r}")
     return {**info, "compilation_cache_dir": cache_dir}

@@ -29,6 +29,7 @@ from ray_tpu.core.common import TPU, TaskSpec
 from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.core.ids import TaskID
 from ray_tpu.core.rpc import ConnectionLost, RpcClient
+from ray_tpu.observability import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -308,8 +309,12 @@ class DirectTaskTransport:
                         desired - n_leases - len(key_reqs)),
                     cap - len(key_reqs) - n_leases)
                 if pending:
+                    # A task of a start-up names it on the request, so
+                    # the raylet puts the wait for this worker on its
+                    # timeline (`raylet.lease`); None on every other.
                     template = (dict(pending[0].resources),
-                                pending[0].runtime_env)
+                                pending[0].runtime_env,
+                                _tracing.spec_startup_ctx(pending[0]))
                 else:
                     template = self._last_template.get(key)
                 if template is None:
@@ -412,7 +417,8 @@ class DirectTaskTransport:
     # ---------------------------------------------------------------- leases
 
     def _request_lease(self, key, resources: Dict[str, float],
-                       runtime_env: Optional[Dict[str, Any]]):
+                       runtime_env: Optional[Dict[str, Any]],
+                       startup: Optional[Tuple[str, str]] = None):
         pseudo = TaskSpec(
             task_id=TaskID.for_task(self._rt.job_id),
             job_id=self._rt.job_id,
@@ -422,6 +428,8 @@ class DirectTaskTransport:
             resources=dict(resources),
             runtime_env=runtime_env,
         )
+        if startup is not None:
+            pseudo.trace_ctx = {"startup": startup}
         req_id = pseudo.task_id.binary()
         with self._lock:
             self._inflight_reqs[req_id] = key

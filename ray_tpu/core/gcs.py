@@ -46,6 +46,7 @@ from ray_tpu.core.rpc import (
 )
 from ray_tpu.exceptions import RaySystemError
 from ray_tpu.jobs import state as _jobstate
+from ray_tpu.observability import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -1497,6 +1498,31 @@ class GcsServer:
             # __ray_restart__ state-restore hook on restarts (count > 0)
             # but never on first creation.
             spec.actor_restart_count = info.num_restarts
+            restarts = info.num_restarts
+        # An actor of a start-up: registered -> ALIVE, with what the
+        # retry loop below spent asleep. The lease, the spawn and the
+        # constructor it causes name this span as their parent.
+        with _tracing.get_tracer().lifecycle_span(
+                "actor.create", ctx=_tracing.spec_startup_ctx(spec),
+                role="gcs", attrs={"actor": actor_id.hex()[:12],
+                                   "class": spec.name,
+                                   "restarts": restarts}) as span:
+            if span.ctx is not None:
+                spec.trace_ctx = dict(spec.trace_ctx, startup=span.ctx)
+            self._schedule_actor_loop(actor_id, spec, span)
+
+    def _schedule_actor_loop(self, actor_id: ActorID, spec, span):
+        retries, slept_s = 0, 0.0
+
+        def nap(seconds: float, why: str):
+            nonlocal retries, slept_s
+            time.sleep(seconds)
+            retries += 1
+            slept_s += seconds
+            span.set_attr("retries", retries)
+            span.set_attr("slept_s", round(slept_s, 3))
+            span.set_attr("waited_for", why)
+
         deadline = time.monotonic() + GLOBAL_CONFIG.worker_lease_timeout_ms / 1000.0 * 10
         while not self._stopped.is_set():
             node_id = self._pick_node_for(spec)
@@ -1505,7 +1531,7 @@ class GcsServer:
                     self._actor_dead(actor_id, "no node with required resources "
                                                f"{spec.resources} became available")
                     return
-                time.sleep(0.2)
+                nap(0.2, "no_node")
                 continue
             try:
                 # Dedicated connection: create_actor blocks for the whole
@@ -1515,7 +1541,7 @@ class GcsServer:
                 with self._lock:
                     info = self.nodes.get(node_id)
                 if info is None or info.state != "ALIVE":
-                    time.sleep(0.2)
+                    nap(0.2, "node_not_alive")
                     continue
                 create_client = RpcClient(
                     info.address, name=f"gcs-create-actor-{actor_id.hex()[:8]}")
@@ -1542,7 +1568,7 @@ class GcsServer:
             except Exception as e:
                 logger.warning("actor %s creation on %s failed: %s",
                                actor_id.hex()[:12], node_id.hex()[:12], e)
-                time.sleep(0.2)
+                nap(0.2, "create_failed")
                 continue
             if resp.get("status") == "ok":
                 with self._lock:
@@ -1560,7 +1586,7 @@ class GcsServer:
                                  error_blob=resp.get("error_blob"))
                 return
             # status == "retry": node couldn't take it (resources raced); loop.
-            time.sleep(0.1)
+            nap(0.1, "raylet_retry")
 
     def _pick_node_for(self, spec) -> Optional[NodeID]:
         """Resource-feasibility + packing score over the cluster view."""

@@ -232,6 +232,10 @@ class WorkerHandle:
     tpu_chips: Tuple[int, ...] = ()
     exit_release: Dict[str, float] = field(default_factory=dict)
     exited: bool = False
+    # (startup_id, parent span id) of the `worker.spawn` span that made
+    # this process: handed to it at registration, so that its
+    # `worker.boot` lies beside the spawn under what caused both.
+    spawn_ctx: Optional[Tuple[Optional[str], str]] = None
     # Resources held for this worker's lifetime (actor workers hold their
     # creation-task resources until death, like the reference's leases).
     held_resources: Dict[str, float] = field(default_factory=dict)
@@ -334,14 +338,16 @@ class WorkerPool:
                               spawn_kind="cold")
         handle.granted_env = env_extra or {}
         handle.tpu_chips = chips
-        spawn_span = _tracing.NOOP_SPAN
-        if _tracing._ENABLED:
-            # Roots its own trace (spawns are demand-driven, not tied to
-            # one request); the kind attr lands once the path is known.
-            spawn_span = _tracing.get_tracer().start_span(
-                "worker.spawn",
-                attrs={"worker": worker_id.hex()[:12],
-                       "node": self._raylet.node_id.hex()[:12]})
+        # A lifecycle span (rare, so always recorded): part of the
+        # start-up whose lease asked for it, if one did; the kind attr
+        # lands once the path is known.
+        spawn_span = _tracing.get_tracer().lifecycle_span(
+            "worker.spawn", always=True, role="raylet",
+            attrs={"worker": worker_id.hex()[:12],
+                   "node": self._raylet.node_id.hex()[:12],
+                   "chips": len(chips)})
+        # The process's boot follows the spawn: a sibling, not a child.
+        handle.spawn_ctx = (spawn_span.startup_id, spawn_span.parent_id)
         with self._lock:
             self._workers[worker_id] = handle
             self._starting += 1
@@ -738,6 +744,9 @@ class Raylet:
         self.node_id = NodeID.from_random()
         self.gcs_address = gcs_address
         self.session_dir = session_dir
+        # This process's lifecycle file goes there too, and the directory
+        # stays known after stop() (the driver reads its last run).
+        _tracing.set_session_dir(session_dir)
         self.session_suffix = session_suffix or f"{os.getpid()}_{self.node_id.hex()[:8]}"
         self.is_head = is_head
         self.server = RpcServer(host=host, port=port, name="raylet")
@@ -1581,9 +1590,21 @@ class Raylet:
         self._dispatch_event.set()
         return {"returned": True}
 
+    @staticmethod
+    def _record_task_lease(qt: QueuedTask):
+        """A task of a start-up (its spec carries one): queued -> granted,
+        on the lifecycle timeline. The per-task `raylet.queue` request
+        span stays what it is; ordinary tasks record nothing here."""
+        startup = _tracing.spec_startup_ctx(qt.spec)
+        if startup is not None:
+            _tracing.get_tracer().record_lifecycle(
+                "raylet.lease", qt.queued_at, time.monotonic(),
+                ctx=startup, role="raylet", attrs={"task": qt.spec.name})
+
     def _grant_lease(self, worker: WorkerHandle, qt: QueuedTask):
         """Worker + resources acquired for a lease request: hand the worker
         to the requester over its push channel."""
+        self._record_task_lease(qt)
         lease_id = os.urandom(16)
         worker.held_resources = dict(qt.spec.resources)
         with self._lock:
@@ -1996,6 +2017,8 @@ class Raylet:
                 _tracing.epoch_of(now), parent_ctx=spec.trace_ctx,
                 attrs={"task": spec.name,
                        "node": self.node_id.hex()[:12]})
+        if not spec.actor_creation:
+            self._record_task_lease(qt)
         with self._lock:
             self._running[spec.task_id.binary()] = (spec, worker)
         self._record_task_event(spec, "RUNNING", worker)
@@ -2032,7 +2055,9 @@ class Raylet:
             # Worker not spawned by us (e.g. driver-embedded runtime): ignore.
             return {"ok": False}
         self._dispatch_event.set()
-        return {"ok": True, "node_id": self.node_id, "session_suffix": self.session_suffix}
+        return {"ok": True, "node_id": self.node_id,
+                "session_suffix": self.session_suffix,
+                "spawn_ctx": handle.spawn_ctx}
 
     def handle_task_done(self, conn: Connection, data: Dict[str, Any]):
         """Worker finished a task: register results, notify submitter, recycle."""
@@ -2327,13 +2352,27 @@ class Raylet:
         refused = self._tpu_grant_error(spec)
         if refused:
             return {"status": "error", "error": refused}
+        # The lease of an actor creation that belongs to a start-up: this
+        # call -> a registered worker has been handed the creation task.
+        # One span an attempt; a refused attempt says what it waited for
+        # and the GCS's `actor.create` counts the retries.
+        with _tracing.get_tracer().lifecycle_span(
+                "raylet.lease", ctx=_tracing.spec_startup_ctx(spec),
+                role="raylet",
+                attrs={"actor": spec.actor_id.hex()[:12]}) as lease:
+            return self._create_actor(conn, spec, lease)
+
+    def _create_actor(self, conn: Connection, spec: TaskSpec, lease):
         placement = spec.placement_resources or spec.resources
         if not self.resources.try_acquire(placement):
+            lease.set_attr("waited_for", "resources")
             return {"status": "retry"}
         env = self._env_for(spec)
         # Reuse an idle pooled worker whose granted env matches (reference
         # worker_pool.h lease matching) — saves a cold start.
         worker = self.pool.pop_idle(env)
+        lease.set_attr("worker_from", "pool" if worker is not None
+                       else "spawn")
         if worker is None:
             try:
                 worker = self.pool.spawn_worker(env_extra=env)
@@ -2342,6 +2381,7 @@ class Raylet:
                             spec.actor_id.hex()[:12], e)
                 self.resources.release(placement)
                 self._evict_idle_chip_holder(env)
+                lease.set_attr("waited_for", "chips_busy")
                 return {"status": "retry"}
         worker.is_actor = True
         worker.actor_id = spec.actor_id
@@ -2404,6 +2444,8 @@ class Raylet:
         with self._lock:
             self._task_submitters[spec.task_id.binary()] = conn
         self._dispatch_to(worker, qt)
+        lease.set_attr("worker_pid", worker.pid)
+        lease.end()   # granted: what follows is the actor's constructor
         init_deadline_s = GLOBAL_CONFIG.worker_lease_timeout_ms / 1000.0 * (
             CHIP_START_DEADLINE_FACTOR if worker.tpu_chips else 1)
         if not pending["event"].wait(init_deadline_s):

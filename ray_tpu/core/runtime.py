@@ -1025,6 +1025,10 @@ class CoreRuntime:
     # -------------------------------------------------------------- actors
 
     def create_actor(self, spec: TaskSpec) -> ActorID:
+        if spec.trace_ctx is None:
+            # As for tasks: the creation joins the submitter's trace, and
+            # the start-up it is part of (observability/tracing.py).
+            spec.trace_ctx = self.child_trace_ctx()
         spec.runtime_env = self._prepare_runtime_env(spec.runtime_env)
         key = spec.actor_id.binary()
         # One RPC, subscription piggybacked (the GCS subscribes this

@@ -153,6 +153,7 @@ class WorkerRuntime(CoreRuntime):
              "direct_address": self.direct_server.address})
         if not resp.get("ok"):
             raise RuntimeError("raylet refused worker registration")
+        return resp
 
     def on_execute_task(self, spec: TaskSpec):
         # Called on the RpcClient reader thread: enqueue only.
@@ -806,6 +807,7 @@ class WorkerRuntime(CoreRuntime):
         # flush the last tasks' events and buffered results synchronously.
         self._flush_direct_replies()
         self._flush_task_events()
+        _tracing.write_lifecycle()
         threading.Thread(target=lambda: (os._exit(0)), daemon=True).start()
 
 
@@ -834,7 +836,28 @@ def forked_main():
     main()
 
 
+def _process_start_monotonic(now: float) -> float:
+    """When this process came to be, on `time.monotonic()`'s line: the
+    kernel's start time of the process (field 22 of /proc/self/stat, in
+    clock ticks since boot, which is where CLOCK_MONOTONIC starts). For a
+    cold worker that is before the interpreter and every import; for a
+    forge fork it is the fork. `now` where that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        born = ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return now
+    # Where the two clocks are not one line (a time namespace, a host
+    # that was suspended) the figure is nonsense: a worker reaches this
+    # line within seconds of being made, never minutes.
+    return born if 0.0 <= now - born < 120.0 else now
+
+
 def main():
+    t_main = time.monotonic()
+    born = _process_start_monotonic(t_main)
+    _tracing.set_role("worker")
     logging.basicConfig(
         level=os.environ.get("RAY_TPU_LOG_LEVEL", "INFO"),
         format=(f"%(asctime)s [worker pid={os.getpid()}] "
@@ -873,7 +896,14 @@ def main():
         # _event_lock (mid-buffer) or the RPC send lock (mid-call) right
         # now — flushing inline would self-deadlock and the worker would
         # never exit.
-        t = threading.Thread(target=runtime._flush_task_events, daemon=True)
+        # The lifecycle file goes the same way (its ring has a lock too):
+        # a process stopped after its start-up leaves its last spans and
+        # compile counters behind.
+        def _last_words():
+            _tracing.write_lifecycle()
+            runtime._flush_task_events()
+
+        t = threading.Thread(target=_last_words, daemon=True)
         t.start()
         t.join(timeout=0.5)
         os._exit(0)
@@ -903,7 +933,15 @@ def main():
     import ray_tpu
 
     ray_tpu._global_runtime = runtime
-    runtime.register()
+    reply = runtime.register()
+    # The process's first instant -> registered with its raylet. Rare (a
+    # process does it once), so recorded in any case; the raylet's reply
+    # names the `worker.spawn` that caused it.
+    _tracing.get_tracer().record_lifecycle(
+        "worker.boot", born, time.monotonic(), always=True,
+        ctx=reply.get("spawn_ctx"),
+        attrs={"worker": runtime.worker_id.hex()[:12],
+               "import_s": round(t_main - born, 3)})
     try:
         runtime.main_loop()
     except KeyboardInterrupt:

@@ -895,7 +895,8 @@ class WorkerForge:
         Raises ForgeUnavailable (caller falls back to cold spawn)."""
         from ray_tpu.observability import tracing as _tracing
 
-        with _tracing.get_tracer().start_span("forge.fork") as span:
+        with _tracing.get_tracer().lifecycle_span(
+                "forge.fork", always=True, role="raylet") as span:
             reply = self._call({"c": "spawn", "env": env_delta, "cwd": cwd,
                                 "log": log_path})
             span.set_attr("pid", reply.get("pid"))

@@ -11,6 +11,10 @@ the timeline workflow. Quick tour::
 Exports land in the GCS and are served by the dashboard
 (`/api/traces/<trace_id>`, `/api/timeline`) or the CLI
 (`python -m ray_tpu.observability timeline`).
+
+The start-up timeline (lifecycle spans, always on) is read from the
+session directory: `startup_report()`, `python -m ray_tpu.observability
+startup`; `compile_watch()` gives this process's compile counters.
 """
 
 from ray_tpu.observability.tracing import (  # noqa: F401
@@ -30,9 +34,16 @@ from ray_tpu.observability.export import (  # noqa: F401
     chrome_trace_events,
     span_tree,
 )
+from ray_tpu.observability.compile import compile_watch  # noqa: F401
+from ray_tpu.observability.startup import (  # noqa: F401
+    format_waterfall,
+    startup_report,
+)
 
 __all__ = [
     "FlightRecorder", "NOOP_SPAN", "Span", "Tracer", "capture",
-    "chrome_trace_events", "current_ctx", "enabled", "format_traceparent",
-    "get_tracer", "parse_traceparent", "refresh_from_config", "span_tree",
+    "chrome_trace_events", "compile_watch", "current_ctx", "enabled",
+    "format_traceparent", "format_waterfall", "get_tracer",
+    "parse_traceparent", "refresh_from_config", "span_tree",
+    "startup_report",
 ]

@@ -30,6 +30,7 @@ import time
 from dataclasses import replace as _dc_replace
 from typing import Any, Callable, Dict, Optional, Union
 
+from ray_tpu.observability import tracing as _tracing
 from ray_tpu.serve.batching import batch
 from ray_tpu.serve.config import AutoscalingConfig, DeploymentConfig
 from ray_tpu.serve.handle import DeploymentHandle, _drop_process_router
@@ -171,15 +172,17 @@ def _get_or_create_controller(create: bool = True):
     except Exception:  # noqa: BLE001 — not started yet
         if not create:
             raise
-    controller = ray_tpu.remote(ServeController).options(
-        name=CONTROLLER_NAME, namespace=SERVE_NAMESPACE,
-        lifetime="detached", max_concurrency=64, num_cpus=0.1,
-    ).remote()
-    # Crash recovery (reference controller.py:75): a checkpoint in the
-    # GCS KV means a previous controller died — rebuild its state and
-    # re-adopt surviving named replicas before reconciling.
-    ray_tpu.get(controller.restore.remote(), timeout=60.0)
-    controller.reconcile_forever.remote()
+    # Asked for -> answering (lifecycle: only inside a start-up).
+    with _tracing.get_tracer().lifecycle_span("serve.controller.start"):
+        controller = ray_tpu.remote(ServeController).options(
+            name=CONTROLLER_NAME, namespace=SERVE_NAMESPACE,
+            lifetime="detached", max_concurrency=64, num_cpus=0.1,
+        ).remote()
+        # Crash recovery (reference controller.py:75): a checkpoint in the
+        # GCS KV means a previous controller died — rebuild its state and
+        # re-adopt surviving named replicas before reconciling.
+        ray_tpu.get(controller.restore.remote(), timeout=60.0)
+        controller.reconcile_forever.remote()
     return controller
 
 
@@ -210,10 +213,13 @@ def _ensure_proxy_actor(name: str, cls, host: str, port: int) -> int:
     try:
         proxy = ray_tpu.get_actor(name, namespace=SERVE_NAMESPACE)
     except Exception:  # noqa: BLE001
-        proxy = ray_tpu.remote(cls).options(
-            name=name, namespace=SERVE_NAMESPACE,
-            lifetime="detached", max_concurrency=256, num_cpus=0.1,
-        ).remote(host, port)
+        with _tracing.get_tracer().lifecycle_span(
+                "serve.proxy.start", attrs={"proxy": name}):
+            proxy = ray_tpu.remote(cls).options(
+                name=name, namespace=SERVE_NAMESPACE,
+                lifetime="detached", max_concurrency=256, num_cpus=0.1,
+            ).remote(host, port)
+            return ray_tpu.get(proxy.ready.remote(), timeout=60.0)
     return ray_tpu.get(proxy.ready.remote(), timeout=60.0)
 
 
@@ -328,8 +334,22 @@ def run(app: Union[Application, Deployment], *, _blocking: bool = False,
 
     if isinstance(app, Deployment):
         app = app.bind()
+    order = _graph_order(app)
+    # The start-up's root: entered -> returned. Everything it causes (the
+    # controller, each deployment's replicas, their workers) shares its
+    # startup_id; `python -m ray_tpu.observability startup` reads it.
+    with _tracing.get_tracer().lifecycle_span(
+            "serve.run", root=True,
+            attrs={"deployments": [a.deployment.name for a in order]}):
+        return _run(app, order, http, http_host, http_port, timeout_s)
+
+
+def _run(app: Application, order: list, http: bool, http_host: str,
+         http_port: int, timeout_s: float) -> DeploymentHandle:
+    import ray_tpu
+
     controller = _get_or_create_controller()
-    for a in _graph_order(app):
+    for a in order:
         dep = a.deployment
         sub_args = _sub_handles(tuple(a.init_args))
         sub_kwargs = _sub_handles(dict(a.init_kwargs))

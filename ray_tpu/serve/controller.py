@@ -26,6 +26,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.chaos.deadline import TransitionWatch
 from ray_tpu.core.common import CHIP_START_DEADLINE_FACTOR
+from ray_tpu.observability import tracing as _tracing
 from ray_tpu.serve.config import (
     REPLICA_RUNNING,
     REPLICA_STARTING,
@@ -58,6 +59,14 @@ class _ReplicaInfo:
         self.state = REPLICA_STARTING
         self.last_ongoing = 0
         self.started_at = time.time()
+        # The start-up this replica belongs to, if any, and what its
+        # STARTING phase cost the reconcile loop: recorded as the
+        # `serve.replica.start` lifecycle span when it turns RUNNING.
+        self.startup_ctx = None
+        self.started_mono = time.monotonic()
+        self.first_ok_mono: Optional[float] = None
+        self.polls = 0
+        self.slept_mark = 0.0
         # Last user_config version pushed to this replica (0 = never).
         self.user_config_version = 0
         # Placement, reported by the replica's ping: published in the
@@ -106,8 +115,20 @@ class _DeploymentInfo:
         # hysteresis), when the in-flight cold start began, and the last
         # measured cold-start latency (wake -> first RUNNING replica).
         self.last_wake_at = 0.0
-        self.cold_start_t0: Optional[float] = None
         self.last_cold_start_ms: Optional[float] = None
+        # Lifecycle: the start-up (a `serve.run()`, or a wake from zero)
+        # this deployment's replicas are being started for, as the
+        # (startup_id, span id) of its `serve.deploy` span, what caused
+        # it (None for a wake: the span is a root) and when it began, on
+        # `time.monotonic()`. All None once the target is RUNNING.
+        self.deploy_ctx = None
+        self.deploy_parent = None
+        self.deploy_t0: Optional[float] = None
+
+    @property
+    def waking(self) -> bool:
+        """A scale-to-zero cold start is in flight."""
+        return self.deploy_ctx is not None and self.deploy_parent is None
 
 
 class ServeController:
@@ -121,6 +142,7 @@ class ServeController:
     ANTI_ENTROPY_SHARDS = 16
 
     def __init__(self):
+        _tracing.set_role("controller")
         self._deployments: Dict[str, _DeploymentInfo] = {}
         self._version = 0
         self._routing_table: Dict[str, Any] = {}
@@ -145,6 +167,10 @@ class ServeController:
         self._dirty: set = set()
         self._active: set = set()
         self._parked_cursor = 0
+        # Seconds this loop has spent in its own sleep between ticks: a
+        # STARTING replica is looked at once a tick, and its
+        # `serve.replica.start` span says how much of its wait was this.
+        self._slept_s = 0.0
         self._reconcile_stats: Dict[str, Any] = {
             "ticks": 0, "last_tick_ms": 0.0, "last_scanned": 0,
             "last_parked_skipped": 0, "deployments": 0}
@@ -434,6 +460,16 @@ class ServeController:
                     self._stop_replica(rep)
                 info.replicas = []
             info.ckpt_blob = None   # cls/args/config may all have moved
+        startup = _tracing.startup_ctx()
+        if startup is not None:
+            # Deployed from inside a start-up: the reconcile loop (another
+            # task of this loop) starts the replicas, so what it records
+            # and submits is parented by hand. The span's id is minted
+            # now and the span recorded when the target is RUNNING.
+            info = self._deployments[name]
+            info.deploy_ctx = (startup[0], _tracing._rand_hex(8))
+            info.deploy_parent = startup
+            info.deploy_t0 = time.monotonic()
         # Config-only updates (route_prefix, max_concurrent_queries) must
         # reach routers even when the replica set doesn't change.
         self._dirty.add(name)
@@ -499,25 +535,39 @@ class ServeController:
                     deployments=len(self._deployments))
 
     async def wait_ready(self, name: str, timeout_s: float = 60.0) -> bool:
-        deadline = time.time() + timeout_s
-        while time.time() < deadline:
-            info = self._deployments.get(name)
-            if info is not None:
-                running = sum(1 for r in info.replicas
-                              if r.state == REPLICA_RUNNING)
-                # Autoscaled deployments are ready at one replica; fixed
-                # deployments wait for the full target; scale-to-zero
-                # (min_replicas=0) deployments deploy parked — ready with
-                # zero replicas, the first request cold-starts one.
-                auto = info.config.autoscaling
-                if auto is not None:
-                    need = 0 if auto.min_replicas == 0 else 1
-                else:
-                    need = info.target
-                if running >= need:
-                    return True
-            await asyncio.sleep(0.05)
-        return False
+        polls, slept_s, ready = 0, 0.0, False
+        with _tracing.get_tracer().lifecycle_span(
+                "serve.wait_ready", attrs={"deployment": name},
+                flush=True) as span:
+            deadline = time.time() + timeout_s
+            while time.time() < deadline:
+                polls += 1
+                if self._is_ready(name):
+                    ready = True
+                    break
+                t0 = time.monotonic()
+                await asyncio.sleep(0.05)
+                slept_s += time.monotonic() - t0
+            span.set_attr("polls", polls)
+            span.set_attr("slept_s", round(slept_s, 3))
+        return ready
+
+    def _is_ready(self, name: str) -> bool:
+        info = self._deployments.get(name)
+        if info is None:
+            return False
+        running = sum(1 for r in info.replicas
+                      if r.state == REPLICA_RUNNING)
+        # Autoscaled deployments are ready at one replica; fixed
+        # deployments wait for the full target; scale-to-zero
+        # (min_replicas=0) deployments deploy parked — ready with
+        # zero replicas, the first request cold-starts one.
+        auto = info.config.autoscaling
+        if auto is not None:
+            need = 0 if auto.min_replicas == 0 else 1
+        else:
+            need = info.target
+        return running >= need
 
     async def wake_deployment(self, name: str) -> bool:
         """Scale-to-zero wake: a router saw a request for a parked
@@ -539,8 +589,14 @@ class ServeController:
         self._dirty.add(name)
         self._active.add(name)
         if not info.replicas:
-            if info.cold_start_t0 is None:
-                info.cold_start_t0 = time.time()
+            if info.deploy_ctx is None:
+                # A start-up of its own (no `serve.run()` is waiting):
+                # its `serve.deploy` span is the root, and the cold-start
+                # figure in status() is that span's length.
+                info.deploy_t0 = time.monotonic()
+                info.deploy_ctx = (_tracing._rand_hex(8),
+                                   _tracing._rand_hex(8))
+                info.deploy_parent = None
             logger.info("serve: waking %s (scale-to-zero cold start)", name)
             info.replicas.append(self._start_replica(name, info))
             self._checkpoint()
@@ -613,6 +669,9 @@ class ServeController:
     # ----------------------------------------------------------- reconcile
 
     async def reconcile_forever(self, period_s: float = 0.1) -> None:
+        # Submitted from the `serve.run()` that created this controller;
+        # the loop outlives it, and parents what it starts by hand.
+        _tracing.leave_startup()
         proxy_tick = 0.0
         while not self._shutdown:
             try:
@@ -626,7 +685,9 @@ class ServeController:
                     await self._reconcile_proxies()
                 except Exception:  # noqa: BLE001
                     logger.exception("serve proxy reconcile error")
+            t0 = time.monotonic()
             await asyncio.sleep(period_s)
+            self._slept_s += time.monotonic() - t0
 
     # ------------------------------------------------------ proxy management
 
@@ -720,8 +781,7 @@ class ServeController:
         (scale-to-zero at zero replicas, target 0, no cold start in
         flight) deployments have nothing time-driven to do — wake/deploy
         /delete all dirty them explicitly."""
-        return bool(info.replicas or info.target > 0
-                    or info.cold_start_t0 is not None)
+        return bool(info.replicas or info.target > 0 or info.waking)
 
     def _scan_set(self) -> Tuple[list, int]:
         """Names to reconcile this tick: every active deployment, every
@@ -814,7 +874,10 @@ class ServeController:
                     if r.state == REPLICA_STARTING]:
             state, node = await loop.run_in_executor(
                 None, functools.partial(_try_ping_replica, rep, 0.05))
+            rep.polls += 1
             if state == "ok":
+                if rep.first_ok_mono is None:
+                    rep.first_ok_mono = time.monotonic()
                 if node:
                     rep.node_hex = node
                 # Deliver the current user_config BEFORE the replica
@@ -830,13 +893,7 @@ class ServeController:
                         loop, info, rep):
                     rep.state = REPLICA_RUNNING
                     changed = True
-                    if info.cold_start_t0 is not None:
-                        info.last_cold_start_ms = round(
-                            (time.time() - info.cold_start_t0) * 1e3, 1)
-                        info.cold_start_t0 = None
-                        logger.info(
-                            "serve: %s cold start served in %.0fms",
-                            name, info.last_cold_start_ms)
+                    self._record_replica_start(name, rep)
             startup_timeout_s = info.config.replica_startup_timeout_s * (
                 CHIP_START_DEADLINE_FACTOR
                 if info.config.ray_actor_options.get("num_tpus") else 1)
@@ -849,6 +906,8 @@ class ServeController:
                 self._stop_replica(rep, graceful=False)
                 info.replicas.remove(rep)
                 changed = True
+        if info.deploy_ctx is not None:
+            self._record_deploy(name, info)
 
         # 1.5 Weight/config broadcast: push the current user_config to
         # RUNNING replicas behind on it (a live update bumped the
@@ -1046,6 +1105,47 @@ class ServeController:
 
     # ------------------------------------------------------------- helpers
 
+    def _record_replica_start(self, name: str, rep: _ReplicaInfo) -> None:
+        """`serve.replica.start`: actor submitted -> RUNNING, with the
+        first `ok` ping on the way and what the reconcile loop's polling
+        added (it looks once a tick: `slept_s` is the ticks' own sleep)."""
+        if rep.startup_ctx is None:
+            return
+        now = time.monotonic()
+        info = self._deployments.get(name)
+        _tracing.get_tracer().record_lifecycle(
+            "serve.replica.start", rep.started_mono, now,
+            ctx=(rep.startup_ctx[0],
+                 info.deploy_ctx[1] if info is not None
+                 and info.deploy_ctx is not None else None),
+            span_id=rep.startup_ctx[1],
+            attrs={"replica": rep.replica_id, "polls": rep.polls,
+                   "slept_s": round(self._slept_s - rep.slept_mark, 3),
+                   "first_ok_s": round((rep.first_ok_mono or now)
+                                       - rep.started_mono, 3),
+                   "running_s": round(now - rep.started_mono, 3)})
+
+    def _record_deploy(self, name: str, info: _DeploymentInfo) -> None:
+        """`serve.deploy`: accepted (or woken from zero) -> the replicas
+        it waits for RUNNING. Recorded once, then the deployment is no
+        longer part of a start-up. A wake's cold-start figure in
+        status() is this span's length: one stopwatch."""
+        if not self._is_ready(name) or (info.waking and not any(
+                r.state == REPLICA_RUNNING for r in info.replicas)):
+            return      # a wake is served by its first RUNNING replica
+        now = time.monotonic()
+        if info.waking:
+            info.last_cold_start_ms = round((now - info.deploy_t0) * 1e3, 1)
+            logger.info("serve: %s cold start served in %.0fms", name,
+                        info.last_cold_start_ms)
+        _tracing.get_tracer().record_lifecycle(
+            "serve.deploy", info.deploy_t0, now,
+            ctx=info.deploy_parent or (info.deploy_ctx[0], None),
+            span_id=info.deploy_ctx[1], flush=True,
+            attrs={"deployment": name, "replicas": len(info.replicas),
+                   "from_zero": info.waking})
+        info.deploy_ctx = info.deploy_parent = info.deploy_t0 = None
+
     def _start_replica(self, name: str, info: _DeploymentInfo):
         import ray_tpu
         from ray_tpu.serve.replica import Replica
@@ -1060,11 +1160,23 @@ class ServeController:
         opts["name"] = f"SERVE_REPLICA::{replica_id}"
         opts["namespace"] = SERVE_NAMESPACE
         actor_cls = ray_tpu.remote(Replica)
-        handle = actor_cls.options(**opts).remote(
-            name, info.user_cls, info.init_args, info.init_kwargs,
-            replica_id)
+        rep_ctx = None
+        if info.deploy_ctx is not None:
+            # Part of a start-up: the replica's `serve.replica.start`
+            # span id is minted now, so that the actor's creation (GCS,
+            # raylet, worker, constructor) names it as its cause.
+            rep_ctx = (info.deploy_ctx[0], _tracing._rand_hex(8))
+        token = _tracing._startup_cv.set(rep_ctx)
+        try:
+            handle = actor_cls.options(**opts).remote(
+                name, info.user_cls, info.init_args, info.init_kwargs,
+                replica_id)
+        finally:
+            _tracing._startup_cv.reset(token)
         logger.info("serve: starting replica %s", replica_id)
-        return _ReplicaInfo(handle, replica_id)
+        rep = _ReplicaInfo(handle, replica_id)
+        rep.startup_ctx, rep.slept_mark = rep_ctx, self._slept_s
+        return rep
 
     def _start_replica_group(self, name: str, info: _DeploymentInfo):
         """One logical replica = one gang: shard_spec.world_size rank

@@ -37,6 +37,7 @@ logger = logging.getLogger(__name__)
 
 class HTTPProxy:
     def __init__(self, host: str = "127.0.0.1", port: int = 8000):
+        _tracing.set_role("proxy")
         self._host = host
         self._port = port
         self._runner = None

@@ -47,20 +47,31 @@ class Replica:
             self._shard_ctx = shardgroup.activate(shard_ctx)
         from ray_tpu import _jax_env
 
-        t0 = time.time()
-        if _jax_env.granted_tpu_chips():
-            # A replica that holds chips: compile cache on before the
-            # deployment's first program, and start-up fails unless jax
-            # shows exactly the granted chips (never a silent CPU replica).
-            logger.info("replica %s of %s on %s (backend up in %.1fs)",
-                        replica_id, deployment_name,
-                        _jax_env.claim_devices(), time.time() - t0)
-        self._user = user_cls(*init_args, **(init_kwargs or {}))
-        # Against the raylet's actor-creation deadline
-        # (worker_lease_timeout_ms): a constructor that compiles on a
-        # cold cache spends most of it here.
-        logger.info("replica %s of %s constructed in %.1fs", replica_id,
-                    deployment_name, time.time() - t0)
+        # The constructor as a lifecycle span (rare, so always recorded;
+        # part of a start-up when a `serve.run()` or a scale-up caused
+        # this actor). The two log lines read its stamps: one stopwatch.
+        _tracing.set_role("replica")
+        tracer = _tracing.get_tracer()
+        with tracer.lifecycle_span(
+                "serve.replica.ctor", always=True, flush=True,
+                attrs={"replica": replica_id,
+                       "deployment": deployment_name}) as ctor:
+            if _jax_env.granted_tpu_chips():
+                # A replica that holds chips: compile cache on before the
+                # deployment's first program, and start-up fails unless
+                # jax shows exactly the granted chips (never a silent CPU
+                # replica).
+                devices = _jax_env.claim_devices()
+                logger.info("replica %s of %s on %s (backend up in %.1fs)",
+                            replica_id, deployment_name, devices,
+                            time.monotonic() - ctor.start)
+            with tracer.lifecycle_span("user.ctor", always=True):
+                self._user = user_cls(*init_args, **(init_kwargs or {}))
+            # Against the raylet's actor-creation deadline
+            # (worker_lease_timeout_ms): a constructor that compiles on a
+            # cold cache spends most of it here.
+            logger.info("replica %s of %s constructed in %.1fs", replica_id,
+                        deployment_name, time.monotonic() - ctor.start)
         self._asgi_app = self._resolve_asgi_app(user_cls)
         self._ongoing = 0
         self._processed = 0

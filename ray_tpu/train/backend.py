@@ -68,10 +68,23 @@ def _set_platform(platform: str):
 
 
 def _init_jax_distributed(coordinator: str, world: int, rank: int):
+    from ray_tpu.observability import tracing
     from ray_tpu.parallel.distributed import initialize_distributed
 
-    initialize_distributed(coordinator, world, rank)
+    with tracing.get_tracer().lifecycle_span(
+            "jax.distributed", attrs={"rank": rank, "world": world}):
+        initialize_distributed(coordinator, world, rank)
     return True
+
+
+def _claim_devices_on(rank: int):
+    """`claim_devices` on one rank, as that rank's part of the backend's
+    start (the lifecycle span says which rank was the slow one)."""
+    from ray_tpu.observability import tracing
+
+    with tracing.get_tracer().lifecycle_span(
+            "train.backend.on_start", attrs={"rank": rank}):
+        return claim_devices()
 
 
 def _mesh_builder_for(spec: Optional[MeshSpec]):
@@ -115,7 +128,11 @@ class JaxBackend(Backend):
         # holds a TPU grant fails here unless jax shows it exactly the
         # granted chips. After the process group forms: this starts the
         # backend, which jax.distributed must precede.
-        devices = worker_group.execute(claim_devices)
+        import ray_tpu
+
+        devices = ray_tpu.get([
+            w.execute.remote(_claim_devices_on, rank)
+            for rank, w in enumerate(worker_group.workers)])
         logger.info("train workers on %s", devices)
 
     def mesh_builder(self, backend_config: JaxConfig):

@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 import ray_tpu
 from ray_tpu.core import serialization
 from ray_tpu.exceptions import RayActorError
+from ray_tpu.observability import tracing as _tracing
 from ray_tpu.train.backend import Backend, BackendConfig
 from ray_tpu.train.config import ScalingConfig
 from ray_tpu.train.worker_group import WorkerGroup
@@ -24,6 +25,10 @@ logger = logging.getLogger(__name__)
 
 class TrainingFailedError(RuntimeError):
     pass
+
+
+def _alive() -> bool:
+    return True
 
 
 class BackendExecutor:
@@ -50,16 +55,27 @@ class BackendExecutor:
         self.latest_checkpoint = None
         # (restart_count, world_size) history for observability/benches.
         self.restarts: List[Dict[str, Any]] = []
+        # The trainer's `train.startup` lifecycle root, closed here when
+        # every rank has been handed its train function.
+        self.startup_span = _tracing.NOOP_SPAN
 
     def start(self):
         sc = self.scaling_config
-        self.worker_group = WorkerGroup(
-            num_workers=sc.num_workers,
-            resources_per_worker=sc.worker_resources(),
-            placement_strategy=sc.placement_strategy,
-            use_placement_group=sc.num_workers > 1,
-        )
-        self.backend.on_start(self.worker_group, self.backend_config)
+        tracer = _tracing.get_tracer()
+        with tracer.lifecycle_span("train.executor.start",
+                                   attrs={"workers": sc.num_workers}):
+            self.worker_group = WorkerGroup(
+                num_workers=sc.num_workers,
+                resources_per_worker=sc.worker_resources(),
+                placement_strategy=sc.placement_strategy,
+                use_placement_group=sc.num_workers > 1,
+            )
+            # Every worker actor alive before the span closes, so that
+            # the backend's start below is not charged their creation.
+            self.worker_group.execute(_alive)
+        with tracer.lifecycle_span("train.backend.on_start",
+                                   attrs={"workers": sc.num_workers}):
+            self.backend.on_start(self.worker_group, self.backend_config)
 
     def run(self, train_fn: Callable, config: Dict[str, Any],
             checkpoint=None, datasets_per_worker: Optional[List[Dict]] = None,
@@ -133,6 +149,7 @@ class BackendExecutor:
                 train_fn, config, checkpoint, mesh_builder, ds,
                 experiment_name, run_nonce))
         ray_tpu.get(start_refs)
+        self.startup_span.end()     # every rank is in its train function
         done = [False] * len(wg.workers)
         while not all(done):
             refs = [w.next_result.remote()

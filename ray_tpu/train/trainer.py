@@ -15,6 +15,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu.observability import tracing as _tracing
 from ray_tpu.train.backend import (Backend, BackendConfig, JaxConfig,
                                    TorchConfig)
 from ray_tpu.train.backend_executor import BackendExecutor, TrainingFailedError
@@ -126,6 +127,15 @@ class DataParallelTrainer(BaseTrainer):
         return per_worker
 
     def training_loop(self) -> Result:
+        # The start-up's root: from here until every rank's wrapper is
+        # about to call the train function (the executor closes it; the
+        # reader takes the latest rank's own `train.loop.enter` mark).
+        sc = self.scaling_config
+        startup = _tracing.get_tracer().lifecycle_span(
+            "train.startup", root=True,
+            attrs={"workers": sc.num_workers,
+                   "chips": sc.num_workers * int(
+                       sc.worker_resources().get("TPU", 0))})
         run_config = self.run_config
         storage = run_config.resolved_storage_path()
         name = run_config.name or f"{type(self).__name__}_{int(time.time())}"
@@ -140,7 +150,12 @@ class DataParallelTrainer(BaseTrainer):
         executor = BackendExecutor(
             self.backend_config, self.scaling_config,
             max_failures=run_config.failure_config.max_failures)
-        executor.start()
+        executor.startup_span = startup
+        try:
+            executor.start()
+        except BaseException as e:  # noqa: BLE001 — recorded, re-raised
+            executor.startup_span.end(error=f"{type(e).__name__}: {e}")
+            raise
         history: List[Dict[str, Any]] = []
         last_metrics: Dict[str, Any] = {}
         last_ckpt: Optional[Checkpoint] = None
@@ -172,6 +187,9 @@ class DataParallelTrainer(BaseTrainer):
         except (TrainingFailedError, Exception) as e:  # noqa: BLE001
             error = e
         finally:
+            executor.startup_span.end(
+                error=None if error is None
+                else f"{type(error).__name__}: {error}")
             executor.shutdown()
         return Result(metrics=last_metrics, checkpoint=last_ckpt,
                       best_checkpoint=manager.best_checkpoint(),

@@ -108,6 +108,16 @@ class TrainWorker:
                 session.finished.set()
 
         self._thread = threading.Thread(target=run, name="train-loop", daemon=True)
+        # This rank's end of the start-up (`train.startup` ends at the
+        # latest rank's mark), and the write-out of this process's
+        # lifecycle file. Down here, and imported here, so that no line
+        # of run() above moves: its frames are on the call stack the
+        # train step is traced under, and a Pallas program's cache key
+        # holds them (PERF.md section 6, PR 23).
+        from ray_tpu.observability import tracing as _tracing
+
+        _tracing.get_tracer().lifecycle_mark(
+            "train.loop.enter", attrs={"rank": self.rank})
         self._thread.start()
         return True
 

@@ -248,6 +248,7 @@ class MetricsPusher:
     def flush(self):
         from ray_tpu.observability import tracing
 
+        tracing.write_lifecycle_if_unwritten()
         spans, dropped = tracing.drain_for_flush()
         try:
             snap = GLOBAL_REGISTRY.snapshot()
