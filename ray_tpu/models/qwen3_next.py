@@ -56,6 +56,25 @@ from ray_tpu.ops.held_experts import (held_expert_mlp, load_balance_loss,
                                       route)
 
 
+# What a rematerialised layer keeps between its forward and its backward,
+# by the names the kernels' `custom_vjp`s and the expert layer give what
+# they make; everything else of a layer (projections, convolution, norms,
+# gates, the shared expert, the gathers) is made again in the backward.
+# Chosen by one rule (ISSUE 38): the step's arguments + temporaries stay
+# under 15.9 GB of a v5e's 16.9, names dropped from the cheap end by
+# milliseconds a GB. All seven fit: kept, the temporaries FALL (6.83 ->
+# 6.27 GB; PERF.md section 4 has the table). At [1, 8192], a layer, with
+# the ms a step of `train_qwen3next_8k_ep16share` that no longer run twice:
+#   gdn_out 67 MB + gdn_states 268 MB   x3 layers   13.7 ms (gdn_chunk_fwd)
+#   flash_out 67 MB + flash_lse 0.5 MB  x1          13.2 ms (flash_fwd)
+#   moe_plan 19 MB                      x4          4.2 ms (top-10 sort 2.4)
+#   moe_h 40 MB                         x4          2.1 ms (moe_gmm gate|up)
+#   moe_y 80 MB                         x4          1.8 ms (moe_gmm down)
+KEPT_BY_REMAT = ("gdn_out", "gdn_states", "flash_out", "flash_lse",
+                 "moe_plan", "moe_h", "moe_y")
+_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_BY_REMAT)
+
+
 @dataclasses.dataclass(frozen=True)
 class Qwen3NextConfig:
     vocab_size: int = 151936
@@ -79,6 +98,8 @@ class Qwen3NextConfig:
     rms_norm_eps: float = 1e-6
     router_aux_loss_coef: float = 0.001
     held_experts: Tuple[int, int] = (0, 512)   # (first, count) held here
+    # jax.checkpoint each layer: the backward makes a layer's forward again
+    # except what `KEPT_BY_REMAT` names (no kernel's forward runs twice)
     remat: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -283,7 +304,8 @@ class Qwen3Next(nn.Module):
                      param_dtype=cfg.param_dtype,
                      embedding_init=nn.initializers.normal(0.02),
                      name="embed_tokens")(input_ids)
-        layer = nn.remat(Qwen3NextLayer) if cfg.remat else Qwen3NextLayer
+        layer = (nn.remat(Qwen3NextLayer, policy=_KEEP) if cfg.remat
+                 else Qwen3NextLayer)
         auxes = []
         for i in range(cfg.num_hidden_layers):
             x, aux = layer(cfg, cfg.is_attention(i), name=f"layers_{i}")(x)

@@ -29,6 +29,16 @@ fails the caller's compile. Calls the rule sends to the XLA reference
 (another platform, a shape the kernels do not take) are recorded with the
 reason; `pallas_status()` lists the path of every traced call.
 
+Under a remat: the backward reads the operands, `out` and the logsumexp,
+and only the forward kernel can make the last two. `_flash_fwd` gives them
+the names `flash_out` and `flash_lse` (`jax.ad_checkpoint.checkpoint_name`).
+A caller's `jax.checkpoint` whose policy keeps both
+(`save_only_these_names`) makes the operands again from its own input and
+does NOT run `flash_fwd` a second time; one that keeps neither runs it
+twice, as every policy-less checkpoint does. A name is the identity
+anywhere else: outside such a checkpoint a call compiles to the program it
+compiled to without the names.
+
 Set RAY_TPU_PALLAS_INTERPRET=1 to run the kernels in interpreter mode on
 CPU (used by tests to cover kernel logic without a chip). It is a CPU
 switch and is refused on platform `tpu`.
@@ -45,6 +55,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
 _STATS_LANES = 128  # TPU lane width: stats scratch is (block_q, 128)
@@ -749,6 +760,12 @@ def _flash_fwd_impl(operands, d, fold, causal, scale, block_q, block_k):
 def _flash_fwd(operands, d, fold, causal, scale, block_q, block_k):
     out, lse = _flash_fwd_impl(operands, d, fold, causal, scale, block_q,
                                block_k)
+    # The two residuals only the kernel can make, by name: a caller's
+    # `jax.checkpoint` whose policy keeps both has no forward call to
+    # repeat in its backward (module docstring, "Under a remat").
+    out = checkpoint_name(out, "flash_out")
+    if lse is not None:
+        lse = checkpoint_name(lse, "flash_lse")
     return out, (operands, out, lse)
 
 

@@ -73,7 +73,19 @@ materialised, and the caller gets dq, dk per key head. g and beta are
 Dispatch is a rule, as in `ops/attention.py`: on platform `tpu` a call the
 kernels take goes to the kernels; every other call runs the scan and is
 recorded with the reason. `gated_delta_status()` lists the path of every
-traced call. RAY_TPU_PALLAS_INTERPRET=1 runs the kernels in the
+traced call.
+
+Under a remat: `gated_delta_rule` is a `custom_vjp` whose backward reads
+q, k, v, g, beta and the chunk-start states. Its forward rule gives what
+only the kernel can make the names `gdn_out` and `gdn_states`
+(`jax.ad_checkpoint.checkpoint_name`). A caller's `jax.checkpoint` whose
+policy keeps both (`save_only_these_names`; at [1, 8192] x 32 heads of 128
+that is 67 MB + 268 MB a layer) makes the five operands again from its own
+input and runs `gdn_chunk_fwd` ONCE a layer; a policy-less checkpoint runs
+it twice (once for the output alone, once more in its backward for the
+output and the states). A name is the identity anywhere else. On the scan
+path there are no states to keep and the backward differentiates the scan
+anew whatever is kept. RAY_TPU_PALLAS_INTERPRET=1 runs the kernels in the
 interpreter on the CPU (tests).
 """
 
@@ -85,6 +97,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import _interpret, _platform
 
@@ -665,6 +678,12 @@ def _forward(q, k, v, g, beta, save: bool):
 
 def _vjp_fwd(q, k, v, g, beta):
     out, states = _forward(q, k, v, g, beta, True)
+    # What only the kernel can make, by name: kept both, a surrounding
+    # checkpoint's backward has no forward call to repeat (module
+    # docstring, "Under a remat"). The scan path has no states.
+    out = checkpoint_name(out, "gdn_out")
+    if states is not None:
+        states = checkpoint_name(states, "gdn_states")
     return out, (q, k, v, g, beta, states)
 
 

@@ -28,6 +28,19 @@ other. The slots of a token are sorted held-first; the first few
 hold and the rest only if some token has a row there, which is rare, so a
 step's time does not move with the luck of its batch.
 
+Under a remat: what is dear to make here carries a name
+(`jax.ad_checkpoint.checkpoint_name`) that a caller's `jax.checkpoint` can
+keep by policy (`save_only_these_names`): `moe_plan`, the router's logits
+and its top-k, the two sorts' results and the first block's six layout
+arrays (integers but for the logits and the top-k's values: ~19 MB a layer
+at 8,192 tokens over 512 experts); `moe_h` and `moe_y`, the first block's
+two grouped products. Kept all three, the backward runs no sort, no
+`searchsorted` and no forward `moe_gmm` again; it still makes the gathers,
+the activation and the softmax again, which are cheap. The later blocks
+carry no names: a policy reaches through their `cond`, `scan` and inner
+checkpoint and would keep every trip's rows. A name is the identity
+anywhere else.
+
 `held_experts_status()` lists the traced calls (path, held range, tokens,
 top-k, block, blocks); the loads are outputs of the call (`counts`), so that a
 train step returns them and nothing syncs to read them.
@@ -42,6 +55,8 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.grouped_matmul import (TILE, grouped_matmul,
                                         grouped_matmul_path)
@@ -182,16 +197,41 @@ def _tokens_bwd(always, residuals, d_out):
 _tokens_from_rows.defvjp(_tokens_fwd, _tokens_bwd)
 
 
+def _named(made, name: str):
+    """Every array of `made` under `name`, for a surrounding remat's policy
+    (module docstring, "Under a remat")."""
+    return jax.tree.map(lambda a: checkpoint_name(a, name), made)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def _top_k(probs, k: int):
+    """`lax.top_k` whose tangent rule reads the index under its NAME: jax's
+    own rule reads the index it made inside the rule, which no name outside
+    reaches, so a remat that kept the named copy would still sort again."""
+    return tuple(jax.lax.top_k(probs, k))
+
+
+@_top_k.defjvp
+def _top_k_jvp(k, primals, tangents):
+    top, index = _named(tuple(jax.lax.top_k(primals[0], k)), "moe_plan")
+    d_top = jnp.take_along_axis(tangents[0], index, axis=-1)
+    return (top, index), (d_top, np.zeros(index.shape, jax.dtypes.float0))
+
+
 def route(x, w_router, top_k: int):
     """(probs [T, E] f32 over ALL experts, gates [T, k] f32 renormalised to
     sum to one, index [T, k] int32). Float32 at `Precision.HIGHEST`: a
-    bf16 product here moves which experts a token gets."""
-    logits = jax.lax.dot_general(
+    bf16 product here moves which experts a token gets. Named for a
+    surrounding remat: the logits (the softmax's own rule reads what IT
+    made, so a kept `probs` would save nothing) and both results of the
+    top-k (`_top_k`); the softmax and the renormalisation are made again
+    from them."""
+    logits = _named(jax.lax.dot_general(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
         (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32), "moe_plan")
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, index = jax.lax.top_k(probs, top_k)
+    top_p, index = _top_k(probs, top_k)
     return probs, top_p / jnp.sum(top_p, axis=-1, keepdims=True), index
 
 
@@ -260,25 +300,30 @@ def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
     iota = jnp.arange(n_slots, dtype=jnp.int32)
     _, order = jax.lax.sort((key, iota), num_keys=1)     # sorted -> slot
     _, position = jax.lax.sort((order, iota), num_keys=1)  # slot -> sorted
+    order, position = _named((order, position), "moe_plan")
     loads = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
     starts = jnp.cumsum(loads) - loads
     assigned = jnp.sum(loads)
 
-    def one_block(lo):
-        tile_group, n_used, row_token, row_slot, slot_row, slots = \
+    def one_block(lo, name=lambda made, _: made):
+        tile_group, n_used, row_token, row_slot, slot_row, slots = name(
             _block_layout(key, order, position, starts, loads, lo, block,
-                          count, top_k)
+                          count, top_k), "moe_plan")
         rows = _rows_from_tokens(x, row_token, slots, always)
-        h = grouped_matmul(rows, w_gate_up, tile_group, n_used)
+        h = name(grouped_matmul(rows, w_gate_up, tile_group, n_used),
+                 "moe_h")
         act = (jax.nn.silu(h[:, :width].astype(jnp.float32))
                * h[:, width:].astype(jnp.float32)).astype(x.dtype)
-        y = grouped_matmul(act, w_down, tile_group, n_used)
+        y = name(grouped_matmul(act, w_down, tile_group, n_used), "moe_y")
         placed = jnp.sum(row_slot < n_slots, dtype=jnp.int32)
         return _tokens_from_rows(y, gates, row_slot, slot_row, slots,
                                  always), placed
 
-    out, placed = one_block(jnp.int32(0))
+    # The first block's layout and products go by name; the later blocks'
+    # do not (a policy reaches through the `cond`, the `scan` and its
+    # checkpoint, and would keep every trip's).
+    out, placed = one_block(jnp.int32(0), _named)
     if blocks > 1:
         zero = (jnp.zeros_like(out), jnp.int32(0))
 

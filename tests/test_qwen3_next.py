@@ -248,6 +248,50 @@ def test_held_experts_match_dense_arithmetic(held, block, monkeypatch):
         assert close(a, b, 3e-2), name
 
 
+# Under a checkpoint whose policy keeps the expert layer's names, only the
+# FIRST block's products and layout are kept: a policy reaches through the
+# later blocks' `cond`, `scan` and inner checkpoint, and names there would
+# keep every trip's rows (at the cell's sizes 200 + 400 MB a layer).
+@pytest.mark.parametrize("block,blocks", [(0, 2), (128, 10)])
+def test_a_policy_keeps_the_first_block_by_name_and_no_later_one(
+        block, blocks, monkeypatch):
+    from jax._src.ad_checkpoint import saved_residuals
+
+    if block:
+        monkeypatch.setattr(he, "default_block", lambda *sizes: block)
+    x, w_router, w_gate_up, w_down, do = expert_problem()
+    keep = jax.checkpoint_policies.save_only_these_names(
+        "moe_plan", "moe_h", "moe_y")
+
+    def part(*args):
+        return jnp.sum(held_part(*args, (4, 4), 4)[0] * do)
+
+    he.reset_held_experts_status()
+    kept = saved_residuals(jax.checkpoint(part, policy=keep), x, w_router,
+                           w_gate_up, w_down)
+    (call,) = he.held_experts_status()
+    assert call["blocks"] == blocks
+    rows = call["block"] + 4 * he.TILE
+    made = [(aval.dtype.name, aval.shape) for aval, why in kept
+            if "argument" not in why]
+    width, d = w_down.shape[1], x.shape[1]
+    # logits, h, y (of one shape here) and the layout's two row tables:
+    # once each
+    assert 2 * width == d
+    for want, times in ((("float32", (300, 16)), 1),
+                        (("bfloat16", (rows, d)), 2),
+                        (("int32", (rows,)), 2)):
+        assert made.count(want) == times, (want, made)
+    assert not [s for _, s in made if len(s) > 2], made   # no trip-stacked
+    assert not [why for _, why in kept if "output of cond" in why]
+    # and the gradients are those of the layer without a checkpoint
+    args = (x, w_router, w_gate_up, w_down)
+    got = jax.grad(jax.checkpoint(part, policy=keep), argnums=range(4))(*args)
+    want = jax.grad(part, argnums=range(4))(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_dropless_when_one_expert_takes_most_tokens():
     x, w_router, w_gate_up, w_down, _ = expert_problem(skew=1.0)
     held = (5, 1)

@@ -22,7 +22,9 @@ if ROOT not in sys.path:
 from benchmarks.reference import qwen3_next_plain as plain  # noqa: E402
 from ray_tpu.models.gpt2 import make_train_step  # noqa: E402
 from ray_tpu.models.qwen3_next import (  # noqa: E402
-    Qwen3Next, Qwen3NextConfig, make_loss_fn, published_weights)
+    KEPT_BY_REMAT, Qwen3Next, Qwen3NextConfig, make_loss_fn,
+    published_weights)
+from ray_tpu.ops import attention  # noqa: E402
 from ray_tpu.ops import gated_delta as gd  # noqa: E402
 
 
@@ -193,3 +195,172 @@ def test_trained_through_make_train_step_the_loss_falls():
     assert [int(x) for x in shown["moe"]["placed"]] == [
         int(x) for x in shown["moe"]["assigned"]]
     assert shown["load_balance"].shape == (2,)
+
+
+# --------------------------------------------------------------------------- #
+# What a rematerialised layer keeps (`KEPT_BY_REMAT`): one recurrent and one
+# softmax layer at widths the kernels take, the kernels in the interpreter.
+# --------------------------------------------------------------------------- #
+
+FORWARD_KERNELS = ("gdn_chunk_fwd", "flash_fwd", "moe_gmm")
+ROUTING = ("top_k", "sort")     # primitives of the plan, counted as kernels
+BACKWARD_KERNELS = ("gdn_chunk_bwd", "flash_bwd_dq", "flash_bwd_dkv",
+                    "moe_gmm_dlhs", "moe_gmm_drhs")
+
+
+def kernel_calls(jaxpr, found=None):
+    """`pallas_call` equations by kernel name and the `ROUTING` primitives
+    by their own, inner jaxprs included."""
+    import collections
+
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        elif eqn.primitive.name in ROUTING:
+            found[eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    kernel_calls(inner, found)
+    return found
+
+
+def two_kernel_layers(remat, dtype=jnp.bfloat16):
+    cfg = Qwen3NextConfig.tiny(
+        held_experts=(4, 8), linear_key_head_dim=128,
+        linear_value_head_dim=128, linear_num_key_heads=1,
+        linear_num_value_heads=2, head_dim=64, num_hidden_layers=2,
+        full_attention_interval=2, remat=remat, dtype=dtype)
+    model = Qwen3Next(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 128), 0,
+                             cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), ids)
+    loss_fn = make_loss_fn(model)
+    return params, lambda p: loss_fn(p, {"input_ids": ids,
+                                         "labels": ids})[0]
+
+
+@pytest.fixture(scope="module")
+def remat_and_not():
+    """{remat: (kernel calls in the gradient's jaxpr, loss, gradients,
+    saved residuals)}. The values in float32 activations: XLA's CPU
+    backend keeps a bf16 elementwise chain in f32 inside a fusion, and a
+    remat moves the fusions' borders, so bf16 gradients differ by an ulp
+    with or without this PR; the kernels round their operands to bf16
+    themselves either way."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    was = os.environ.get("RAY_TPU_PALLAS_INTERPRET")
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"
+    try:
+        out = {}
+        for remat in (False, True):
+            params, loss = two_kernel_layers(remat)
+            calls = kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+            kept = saved_residuals(loss, params)
+            params, loss = two_kernel_layers(remat, jnp.float32)
+            out[remat] = (calls, *jax.jit(jax.value_and_grad(loss))(params),
+                          kept)
+        return out
+    finally:
+        if was is None:
+            del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+        else:
+            os.environ["RAY_TPU_PALLAS_INTERPRET"] = was
+
+
+@pytest.mark.parametrize("kernel",
+                         FORWARD_KERNELS + BACKWARD_KERNELS + ROUTING)
+def test_remat_runs_no_kernel_more_often_than_no_remat(remat_and_not, kernel):
+    # the parent ran every forward kernel, the top-k and the plan's sorts
+    # twice a layer under remat
+    plain, kept = remat_and_not[False][0], remat_and_not[True][0]
+    assert set(plain) == set(FORWARD_KERNELS + BACKWARD_KERNELS + ROUTING)
+    assert kept[kernel] == plain[kernel] > 0
+
+
+def test_remat_changes_no_bit_of_loss_or_gradients(remat_and_not):
+    (_, loss, grads, _), (_, kept_loss, kept_grads, _) = (
+        remat_and_not[False], remat_and_not[True])
+    assert float(loss) == float(kept_loss)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(kept_grads)):
+        assert bool(jnp.all(a == b)), jax.tree_util.keystr(path)
+
+
+# name -> (dtype, shape) of what goes by it in `two_kernel_layers`: tokens
+# 128, one key head / two value heads of 128 (2 chunks), 4 heads of 64,
+# 8 held experts of width 32 in a block of 512 + 8 x 128 rows
+KEPT_ARRAYS = {
+    "gdn_out": [("bfloat16", (1, 128, 2, 128))],
+    "gdn_states": [("float32", (1, 2, 2, 128, 128))],
+    "flash_out": [("bfloat16", (1, 128, 256))],
+    "flash_lse": [("float32", (1, 4, 1, 128))],
+    "moe_plan": [("float32", (128, 16)), ("float32", (128, 4)),
+                 ("int32", (128, 4)), ("int32", (12,)), ("int32", (1,)),
+                 ("int32", (1536,))],
+    "moe_h": [("bfloat16", (1536, 64))],
+    "moe_y": [("bfloat16", (1536, 64))],
+}
+
+
+@pytest.mark.parametrize("name", KEPT_BY_REMAT)
+def test_a_rematerialised_layer_keeps_what_goes_by(remat_and_not, name):
+    kept = remat_and_not[True][3]
+    made = [(aval.dtype.name, aval.shape) for aval, _ in kept]
+    for want in KEPT_ARRAYS[name]:
+        assert want in made, (name, want)
+    # jax says "named" of a kept array that nothing else of the forward
+    # reads, and "reduce_precision" (the identity it puts on the others)
+    assert any(f"named '{name}'" in why or "reduce_precision" in why
+               for aval, why in kept
+               if (aval.dtype.name, aval.shape) in KEPT_ARRAYS[name])
+
+
+def test_a_rematerialised_layer_keeps_no_projection_or_convolution(
+        remat_and_not):
+    def wide(kept):
+        # in_proj_qkvz's output is 768 wide here (12,288 in the cell) and
+        # the convolution's f32 output 512 (8,192); nothing else is
+        return [(aval.str_short(), why) for aval, why in kept
+                if aval.shape and aval.shape[-1] in (768, 512)
+                and "argument" not in why]
+
+    assert wide(remat_and_not[False][3])        # kept where nothing is remat
+    assert not wide(remat_and_not[True][3])
+
+
+def test_the_names_change_nothing_of_a_gpt2_train_step(interpret,
+                                                       monkeypatch):
+    """GPT-2 trains with `remat=False`: a name is the identity there. The
+    step's jaxpr holds the same kernels and its outputs the same bits as
+    with `checkpoint_name` taken out of `ops/attention.py`."""
+    import optax
+
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config
+
+    cfg = GPT2Config(vocab_size=256, n_positions=128, n_embd=128, n_layer=2,
+                     n_head=2, use_flash=True)
+    model = GPT2(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 256)
+    params = model.init(jax.random.PRNGKey(0), ids)
+    opt = optax.adamw(1e-3)
+    opt_state = opt.init(params)
+    batch = {"input_ids": ids, "labels": ids}
+
+    def step_of():
+        step = make_train_step(model, opt, donate=False)
+        calls = kernel_calls(jax.make_jaxpr(step)(params, opt_state,
+                                                  batch).jaxpr)
+        return calls, step(params, opt_state, batch)
+
+    named_calls, named = step_of()
+    monkeypatch.setattr(attention, "checkpoint_name", lambda made, _: made)
+    bare_calls, bare = step_of()
+    assert named_calls == bare_calls == {
+        "flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+    for a, b in zip(jax.tree.leaves(named), jax.tree.leaves(bare)):
+        assert bool(jnp.all(a == b))
