@@ -21,6 +21,7 @@ requests (not just in-flight RPCs) and scales replicas on real backlog.
 from __future__ import annotations
 
 import asyncio
+import collections
 from typing import Any, Dict, Optional
 
 from ray_tpu import serve
@@ -52,6 +53,33 @@ def _parse(payload: Optional[Dict[str, Any]], default_new: int):
     return (ids, max_new,
             (str(model_id) if model_id is not None else None),
             (str(slo) if slo is not None else None))
+
+
+class _LoopMailbox:
+    """Hands items from the engine thread to asyncio queues of one loop.
+    One wake-up of the loop serves every item posted before it ran: a
+    decode step posts a token for every row, and a
+    `call_soon_threadsafe` each wrote the loop's self-pipe, and gave the
+    GIL up to it, once a token (64 times a step at 64 slots)."""
+
+    def __init__(self, loop):
+        self.loop = loop
+        self._items: collections.deque = collections.deque()
+        self._waking = False
+
+    def post(self, queue: asyncio.Queue, item) -> None:
+        # Append before the test: a drain that has not cleared the flag
+        # yet has not started popping either, so it will see this item.
+        self._items.append((queue, item))
+        if not self._waking:
+            self._waking = True
+            self.loop.call_soon_threadsafe(self._drain)
+
+    def _drain(self) -> None:
+        self._waking = False
+        while self._items:
+            queue, item = self._items.popleft()
+            queue.put_nowait(item)
 
 
 @serve.deployment(max_concurrent_queries=64)
@@ -170,12 +198,17 @@ class LLMServer:
         ids, max_new, model_id, slo = _parse(payload, self._default_new)
         loop = asyncio.get_running_loop()
         queue: asyncio.Queue = asyncio.Queue()
+        # One mailbox a replica (its streams share the replica's loop);
+        # made here because a subclass may not run this class's __init__.
+        mailbox = getattr(self, "_mailbox", None)
+        if mailbox is None or mailbox.loop is not loop:
+            mailbox = self._mailbox = _LoopMailbox(loop)
 
         def on_token(req, token):
-            loop.call_soon_threadsafe(queue.put_nowait, ("token", token))
+            mailbox.post(queue, ("token", token))
 
         def on_finish(req):
-            loop.call_soon_threadsafe(queue.put_nowait, ("end", req))
+            mailbox.post(queue, ("end", req))
 
         req = self._loop.submit(ids, max_new, on_token=on_token,
                                 on_finish=on_finish, model_id=model_id,

@@ -14,12 +14,24 @@ The engine knows nothing of the model's family. It is handed `model` and
 `params` and asks the model five things, and nothing else
 (docs/INFERENCE.md, "The model contract"):
 
-- `model.paged_cache(num_blocks, block_size, mesh)`: the paged cache, a
-  pytree the engine donates to every step and never looks inside (and
-  builds anew in `fail_all`);
+- `model.paged_cache(num_blocks, block_size, mesh, batch_slots)`: the
+  paged cache, a pytree the engine donates to every step and never looks
+  inside (and builds anew in `fail_all`). It may hold state per batch
+  SLOT beside the paged blocks, which is why it is told their number;
 - `model.paged_step(params, ids[b, s], cache, block_tables, row_pos,
-  write_mask, adapters) -> (logits[b, s, vocab], cache)`: the one step,
-  where `adapters` is None or (banks, adapter_idx[b]);
+  write_mask, adapters, slots, last_idx) -> (logits, cache)`: the one
+  step, where `adapters` is None or (banks, adapter_idx[b]); `slots` is
+  each row's batch slot (None: row i is slot i, as in decode) and
+  `last_idx` the one position a row whose logits are read (None: every
+  position, logits[b, s, vocab]; else logits[b, vocab]). A slot is the
+  address of a row's per-slot state, `write_mask` its hold (a row with
+  no live position keeps its state), and a live row whose first position
+  is 0 starts from zero state. With them go two attributes:
+  `model.prefix_restores`, whether a prefix of blocks alone restores a
+  sequence (where not, nothing is adopted from or donated to the radix
+  prefix cache, and speculation is refused: its rejected positions
+  would need a rollback), and `model.slot_state_bytes`, what a slot
+  holds beside the blocks (for `stats()`);
 - `model.place_on_mesh(params, mesh) -> (params, tp)`: tensor-parallel
   placement and the tp degree;
 - `model.early_exit_draft(params) -> (draft_model, draft_params)`: asked
@@ -245,9 +257,21 @@ class InferenceEngine:
         self._model = model
         self._params = params
         self._bm = BlockManager(cfg.num_blocks, cfg.block_size)
-        self._arenas = model.paged_cache(cfg.num_blocks, cfg.block_size, mesh)
+        self._arenas = self._fresh_cache(model)
+        # Blocks alone do not bring back a sequence whose model keeps
+        # state per slot: nothing is adopted, so nothing is kept either
+        # (blocks nobody may adopt only fill the arena), and a rejected
+        # draft's positions could not be rolled back.
+        self._prefix_restores = bool(model.prefix_restores)
+        self._slot_state_bytes = int(model.slot_state_bytes)
+        if not self._prefix_restores and self._draft_len > 0:
+            raise ValueError(
+                "spec_decode_draft_len > 0 needs a model whose cache a "
+                "prefix of blocks restores: a rejected draft's positions "
+                "are overwritten in the paged blocks, and per-slot state "
+                "has no rollback")
         self._prefix: Optional[RadixPrefixCache] = None
-        if cfg.prefix_cache_enabled:
+        if cfg.prefix_cache_enabled and self._prefix_restores:
             self._prefix = RadixPrefixCache(self._bm)
         # Speculative decoding: the draft shares the target's BLOCK
         # TABLES (host bookkeeping) but writes its own cache — same
@@ -266,8 +290,7 @@ class InferenceEngine:
                                                             mesh)
             self._draft_model = draft_model
             self._draft_params = draft_params
-            self._draft_arenas = draft_model.paged_cache(
-                cfg.num_blocks, cfg.block_size, mesh)
+            self._draft_arenas = self._fresh_cache(draft_model)
         # Model multiplexing: the adapter bank + residency bookkeeping.
         # `adapter_source(model_id) -> per-layer rows` is registered by
         # the deployment (api.py) so a miss loads on demand.
@@ -295,6 +318,8 @@ class InferenceEngine:
         self._finished = 0
         self._failed = 0
         self._preemptions = 0
+        self._state_resets = 0             # sequences begun from zero state
+        self._prefix_refused = 0           # admissions that may adopt nothing
         # The step ledger: written by the stepping thread alone, published
         # whole at the end of every step so that step_stats() needs no
         # lock and never sees half a step.
@@ -345,10 +370,10 @@ class InferenceEngine:
         # exactly these programs (docs/MULTITENANCY.md).
         def prefill_fn(params, arenas, adapters, tokens, ids, bt, pos,
                        wmask, last_idx, slot):
+            # Logits only where they are read: one position of the chunk.
             logits, arenas = step(params, ids, arenas, bt, pos, wmask,
-                                  adapters)
-            nxt = jnp.argmax(jnp.take_along_axis(
-                logits, last_idx[:, None, None], axis=1)[:, 0], axis=-1)
+                                  adapters, slot, last_idx)
+            nxt = jnp.argmax(logits, axis=-1)
             return tokens.at[slot].set(nxt.astype(jnp.int32)), arenas
 
         def decode_fn(params, arenas, adapters, tokens, bt, pos, wmask):
@@ -430,6 +455,11 @@ class InferenceEngine:
             except Exception:  # noqa: BLE001 — introspection only
                 pass
         return len(self._shapes[name])
+
+    def _fresh_cache(self, model):
+        cfg = self.config
+        return model.paged_cache(cfg.num_blocks, cfg.block_size, self._mesh,
+                                 cfg.batch_slots)
 
     def _fresh_tokens(self):
         """The token vector as the programs return it: replicated under a
@@ -643,6 +673,8 @@ class InferenceEngine:
             # first emitted token needs fresh logits.
             matched_tokens = 0
             pin_node = None
+            if cfg.prefix_cache_enabled and not self._prefix_restores:
+                self._prefix_refused += 1
             if self._prefix is not None:
                 stream = req.prompt + req.generated
                 cap = (len(stream) - 1) // cfg.block_size * cfg.block_size
@@ -763,6 +795,8 @@ class InferenceEngine:
                 "draft_prefill", self._draft_prefill_fn,
                 self._draft_params, self._draft_arenas, *args[:4])
         clock.enter(PREFILL_HOST)
+        self._state_resets += (req.processed == 0
+                               and self._slot_state_bytes > 0)
         req.processed += chunk
         self._ledger["prefill"] += 1
         if req.processed >= total:
@@ -1086,12 +1120,9 @@ class InferenceEngine:
             # old self._arenas may reference deleted arrays — without this
             # every future request would fail on 'Array has been deleted'
             # and the circuit breaker could never actually recover.
-            cfg = self.config
-            self._arenas = self._model.paged_cache(
-                cfg.num_blocks, cfg.block_size, self._mesh)
+            self._arenas = self._fresh_cache(self._model)
             if self._draft_arenas is not None:
-                self._draft_arenas = self._draft_model.paged_cache(
-                    cfg.num_blocks, cfg.block_size, self._mesh)
+                self._draft_arenas = self._fresh_cache(self._draft_model)
             # Fresh arenas invalidate every cached block's contents: a
             # warm radix tree pointing at zeroed KV would serve garbage.
             if self._prefix is not None:
@@ -1202,6 +1233,15 @@ class InferenceEngine:
             "decode_compiles": self._program_compiles("decode"),
             "paged_attn": dict(self._paged_attn),
             "kv": self._bm.stats(),
+            # What the model keeps per batch slot beside the paged blocks
+            # (recurrent state): nothing for a model without any.
+            "state": {
+                "slots": (self.config.batch_slots
+                          if self._slot_state_bytes else 0),
+                "bytes": self._slot_state_bytes * self.config.batch_slots,
+                "resets": self._state_resets,
+                "prefix_adoptions_refused": self._prefix_refused,
+            },
             "prefix_cache": (self._prefix.stats() if self._prefix is not None
                              else {"enabled": False, "cached_blocks": 0,
                                    "hit_rate": 0.0, "hit_tokens": 0}),

@@ -129,6 +129,49 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+def paged_write_and_attend(q, k, v, k_arena, v_arena, block_tables,
+                           positions, write_mask):
+    """Scatter this call's K/V ([b, kv_heads, s, d], after RoPE) into the
+    paged arenas and attend q [b, heads, s, d] over them. Returns (attn
+    [b, heads, s, d], k_arena, v_arena)."""
+    hd = q.shape[-1]
+    # Named for the profiler: device ops of the paged path carry
+    # `paged_attn` in their op_name (PERF.md, Open questions).
+    with jax.named_scope("paged_attn"):
+        nb, bsz, kvh, _ = k_arena.shape
+        max_blocks = block_tables.shape[1]
+        # Scatter this call's K/V into the arena. Physical slot
+        # of logical position p in row i: block_tables[i, p // bsz]
+        # * bsz + p % bsz. Masked tokens (batch padding, chunk
+        # padding) are pointed at physical block 0 — reserved as a
+        # trash block the manager never allocates — so one
+        # fixed-shape scatter handles every mix of active/idle
+        # slots without recompiling.
+        kw = k.transpose(0, 2, 1, 3).astype(
+            k_arena.dtype)                        # [b,s,kvh,d]
+        vw = v.transpose(0, 2, 1, 3).astype(v_arena.dtype)
+        blk = jnp.clip(positions // bsz, 0, max_blocks - 1)
+        phys = jnp.take_along_axis(block_tables, blk,
+                                   axis=1)        # [b, s]
+        phys = jnp.where(write_mask, phys, 0)
+        flat = (phys * bsz + positions % bsz).reshape(-1)
+        k_flat = k_arena.reshape(nb * bsz, kvh, hd)
+        v_flat = v_arena.reshape(nb * bsz, kvh, hd)
+        k_flat = k_flat.at[flat].set(kw.reshape(-1, kvh, hd))
+        v_flat = v_flat.at[flat].set(vw.reshape(-1, kvh, hd))
+        k_arena = k_flat.reshape(nb, bsz, kvh, hd)
+        v_arena = v_flat.reshape(nb, bsz, kvh, hd)
+        # Read: each row's live blocks straight out of the arena
+        # (ops/paged_attention.py: the Pallas kernel where the
+        # dispatch rule gives it the call, the dense reference
+        # elsewhere). The scatter above comes first, so the call's
+        # own K/V are in the arena it reads.
+        attn = paged_attention(
+            q.transpose(0, 2, 1, 3), k_arena, v_arena, block_tables,
+            positions, write_mask).transpose(0, 2, 1, 3)
+    return attn, k_arena, v_arena
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
 
@@ -198,41 +241,10 @@ class LlamaBlock(nn.Module):
             new_cache = None
         elif len(cache) == 4:
             k_arena, v_arena, block_tables, write_mask = cache
-            # Named for the profiler: device ops of the paged path carry
-            # `paged_attn` in their op_name (PERF.md, Open questions).
-            with jax.named_scope("paged_attn"):
-                nb, bsz, kvh, _ = k_arena.shape
-                max_blocks = block_tables.shape[1]
-                # Scatter this call's K/V into the arena. Physical slot
-                # of logical position p in row i: block_tables[i, p // bsz]
-                # * bsz + p % bsz. Masked tokens (batch padding, chunk
-                # padding) are pointed at physical block 0 — reserved as a
-                # trash block the manager never allocates — so one
-                # fixed-shape scatter handles every mix of active/idle
-                # slots without recompiling.
-                kw = k.transpose(0, 2, 1, 3).astype(
-                    k_arena.dtype)                        # [b,s,kvh,d]
-                vw = v.transpose(0, 2, 1, 3).astype(v_arena.dtype)
-                blk = jnp.clip(positions // bsz, 0, max_blocks - 1)
-                phys = jnp.take_along_axis(block_tables, blk,
-                                           axis=1)        # [b, s]
-                phys = jnp.where(write_mask, phys, 0)
-                flat = (phys * bsz + positions % bsz).reshape(-1)
-                k_flat = k_arena.reshape(nb * bsz, kvh, hd)
-                v_flat = v_arena.reshape(nb * bsz, kvh, hd)
-                k_flat = k_flat.at[flat].set(kw.reshape(-1, kvh, hd))
-                v_flat = v_flat.at[flat].set(vw.reshape(-1, kvh, hd))
-                k_arena = k_flat.reshape(nb, bsz, kvh, hd)
-                v_arena = v_flat.reshape(nb, bsz, kvh, hd)
-                # Read: each row's live blocks straight out of the arena
-                # (ops/paged_attention.py: the Pallas kernel where the
-                # dispatch rule gives it the call, the dense reference
-                # elsewhere). The scatter above comes first, so the call's
-                # own K/V are in the arena it reads.
-                attn = paged_attention(
-                    q.transpose(0, 2, 1, 3), k_arena, v_arena, block_tables,
-                    positions, write_mask).transpose(0, 2, 1, 3)
-                new_cache = (k_arena, v_arena, block_tables, write_mask)
+            attn, k_arena, v_arena = paged_write_and_attend(
+                q, k, v, k_arena, v_arena, block_tables, positions,
+                write_mask)
+            new_cache = (k_arena, v_arena, block_tables, write_mask)
         else:
             k_cache, v_cache = cache                 # [b, max, kvh, d]
             max_len = k_cache.shape[1]
@@ -334,7 +346,8 @@ class Llama(nn.Module):
         return self.lm_head(x), new_cache
 
     def decode_paged(self, input_ids, arenas, block_tables, row_pos,
-                     write_mask, lora_banks=None, adapter_idx=None):
+                     write_mask, lora_banks=None, adapter_idx=None,
+                     last_idx=None):
         """Step-shaped paged decode: the continuous-batching engine's
         entry point. `input_ids` [b, s] are each row's next s tokens
         (s = 1 for decode steps, s = chunk for chunked prefill),
@@ -355,7 +368,11 @@ class Llama(nn.Module):
         writes stay base-model-pure, so cached prefix blocks are
         shareable across adapters exactly. The banks are fixed-shape
         arguments, so N adapters still compile the SAME two programs
-        and adapter churn is pure data movement."""
+        and adapter churn is pure data movement.
+
+        `last_idx` [b] asks for logits at one position a row only: the
+        hidden state is gathered there BEFORE the final norm and the
+        head, which then run on [b, embd] (returns logits [b, vocab])."""
         cfg = self.config
         b, s = input_ids.shape
         x = self.embed.astype(cfg.dtype)[input_ids]
@@ -376,6 +393,8 @@ class Llama(nn.Module):
             new_arenas.append((layer_cache[0], layer_cache[1]))
         if side_sum is not None:
             x = x + side_sum.astype(x.dtype)
+        if last_idx is not None:
+            x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
         x = self.final_norm(x)
         return self.lm_head(x), new_arenas
 
@@ -384,21 +403,32 @@ class Llama(nn.Module):
     # call into the functions of this file. `nowrap`: they run on the
     # unbound module, outside `apply`.
 
+    # A prefix of KV blocks alone restores a sequence (so the radix
+    # prefix cache and speculation's no-rollback hold), and a slot holds
+    # nothing of its own.
+    prefix_restores = True
+    slot_state_bytes = 0
+
     @nn.nowrap
-    def paged_cache(self, num_blocks: int, block_size: int, mesh=None):
+    def paged_cache(self, num_blocks: int, block_size: int, mesh=None,
+                    batch_slots: Optional[int] = None):
         """The paged cache, opaque to the engine: `make_paged_arena`,
-        sharded with the kv heads under a tp mesh."""
+        sharded with the kv heads under a tp mesh. Nothing in it is per
+        slot, so `batch_slots` is not used."""
         sharding = None if mesh is None else arena_sharding(self.config, mesh)
         return make_paged_arena(self.config, num_blocks, block_size,
                                 sharding=sharding)
 
     @nn.nowrap
     def paged_step(self, params, ids, cache, block_tables, row_pos,
-                   write_mask, adapters=None):
-        """One step, `decode_paged`: (logits [b, s, vocab], cache).
-        `adapters` is None or (banks, adapter_idx)."""
+                   write_mask, adapters=None, slots=None, last_idx=None):
+        """One step, `decode_paged`: (logits [b, s, vocab], cache), or
+        logits [b, vocab] at `last_idx` [b] where it is given.
+        `adapters` is None or (banks, adapter_idx). `slots` (each row's
+        batch slot) is not used: the block tables say everything."""
+        banks, adapter_idx = adapters or (None, None)
         return self.apply(params, ids, cache, block_tables, row_pos,
-                          write_mask, *(adapters or ()),
+                          write_mask, banks, adapter_idx, last_idx,
                           method=Llama.decode_paged)
 
     @nn.nowrap

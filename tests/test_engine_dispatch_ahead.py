@@ -76,14 +76,20 @@ def plain(tiny_llama):
             bt[0] = np.arange(1, width + 1)
             row_pos = np.zeros(rows, np.int32)
             row_pos[0] = pos
-            lora = ()
+            lora = (None, None)
             if adapter_row is not None:
                 aidx = np.zeros(rows, np.int32)
                 aidx[0] = adapter_row
                 lora = (engine._adapters.device_banks(), aidx)
+            if live == 1:
+                logits, new = apply(params, toks, arenas, bt, row_pos, wmask,
+                                    *lora)
+                return int(jnp.argmax(logits[0, 0])), new
+            # A chunk's logits where the engine reads them: one position,
+            # gathered before the final norm and the head.
             logits, new = apply(params, toks, arenas, bt, row_pos, wmask,
-                                *lora)
-            return int(jnp.argmax(logits[0, len(ids) - 1])), new
+                                *lora, np.asarray([len(ids) - 1], np.int32))
+            return int(jnp.argmax(logits[0])), new
 
         chunk = cfg.prefill_chunk
         for at in range(0, len(prompt), chunk):

@@ -32,7 +32,11 @@ class BagModel:
                 "head": rng.standard_normal(
                     (self.width, self.vocab)).astype(np.float32)}
 
-    def paged_cache(self, num_blocks, block_size, mesh=None):
+    prefix_restores = True      # its cache is the paged blocks alone
+    slot_state_bytes = 0
+
+    def paged_cache(self, num_blocks, block_size, mesh=None,
+                    batch_slots=None):
         import jax.numpy as jnp
 
         assert mesh is None
@@ -41,7 +45,7 @@ class BagModel:
                 "steps": jnp.zeros((), jnp.int32)}
 
     def paged_step(self, params, ids, cache, block_tables, row_pos,
-                   write_mask, adapters=None):
+                   write_mask, adapters=None, slots=None, last_idx=None):
         import jax.numpy as jnp
 
         assert adapters is None
@@ -58,7 +62,11 @@ class BagModel:
         live = jnp.arange(seen.shape[1])[None, None, :] <= pos[:, :, None]
         ctx = jnp.einsum("bsk,bkw->bsw", live.astype(jnp.float32), seen) \
             / (pos[:, :, None] + 1)
-        logits = jnp.tanh(x + ctx) @ jnp.asarray(params["head"])
+        hidden = jnp.tanh(x + ctx)
+        if last_idx is not None:       # logits where they are read only
+            hidden = jnp.take_along_axis(
+                hidden, last_idx[:, None, None], axis=1)[:, 0]
+        logits = hidden @ jnp.asarray(params["head"])
         return logits, {"mem": mem, "steps": cache["steps"] + 1}
 
 
@@ -237,10 +245,11 @@ def _direct_programs(model):
 
     def prefill_fn(params, arenas, tokens, ids, bt, pos, wmask, last_idx,
                    slot):
+        # Logits at the one position that is read (no banks, no index).
         logits, arenas = model.apply(params, ids, arenas, bt, pos, wmask,
+                                     None, None, last_idx,
                                      method=Llama.decode_paged)
-        nxt = jnp.argmax(jnp.take_along_axis(
-            logits, last_idx[:, None, None], axis=1)[:, 0], axis=-1)
+        nxt = jnp.argmax(logits, axis=-1)
         return tokens.at[slot].set(nxt.astype(jnp.int32)), arenas
 
     def decode_fn(params, arenas, tokens, bt, pos, wmask):
