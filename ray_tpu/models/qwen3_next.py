@@ -51,27 +51,33 @@ import jax.numpy as jnp
 from ray_tpu.models.gpt2 import next_token_loss
 from ray_tpu.models.llama import _dense
 from ray_tpu.ops.attention import flash_attention_bse
-from ray_tpu.ops.gated_delta import causal_conv1d, gated_delta_rule
+from ray_tpu.ops.gated_delta import (gated_delta_rule, gdn_gate,
+                                     gdn_prep)
 from ray_tpu.ops.held_experts import (held_expert_mlp, load_balance_loss,
                                       route)
 
 
 # What a rematerialised layer keeps between its forward and its backward,
 # by the names the kernels' `custom_vjp`s and the expert layer give what
-# they make; everything else of a layer (projections, convolution, norms,
-# gates, the shared expert, the gathers) is made again in the backward.
+# they make; everything else of a layer (the small projections, the norms,
+# the shared expert, the gathers) is made again in the backward.
 # Chosen by one rule (ISSUE 38): the step's arguments + temporaries stay
 # under 15.9 GB of a v5e's 16.9, names dropped from the cheap end by
-# milliseconds a GB. All seven fit: kept, the temporaries FALL (6.83 ->
-# 6.27 GB; PERF.md section 4 has the table). At [1, 8192], a layer, with
-# the ms a step of `train_qwen3next_8k_ep16share` that no longer run twice:
+# milliseconds a GB. All ten fit: 7.508 + 7.228 = 14.74 GB (PERF.md section
+# 4 has the table). At [1, 8192], a layer, with the ms a step of
+# `train_qwen3next_8k_ep16share` that no longer run twice:
 #   gdn_out 67 MB + gdn_states 268 MB   x3 layers   13.7 ms (gdn_chunk_fwd)
 #   flash_out 67 MB + flash_lse 0.5 MB  x1          13.2 ms (flash_fwd)
 #   moe_plan 19 MB                      x4          4.2 ms (top-10 sort 2.4)
 #   moe_h 40 MB                         x4          2.1 ms (moe_gmm gate|up)
 #   moe_y 80 MB                         x4          1.8 ms (moe_gmm down)
+#   gdn_in 201 MB                       x3          6.6 ms (the in_proj_qkvz
+#       product: `gdn_prep`'s and `gdn_gate`'s one large residual, bf16)
+#   gdn_qkv 134 MB + gdn_gated 67 MB    x3          1.4 ms (gdn_prep_fwd,
+#       gdn_gate_fwd; jax's rounding pass on gdn_gated stays, 0.16 a layer)
 KEPT_BY_REMAT = ("gdn_out", "gdn_states", "flash_out", "flash_lse",
-                 "moe_plan", "moe_h", "moe_y")
+                 "moe_plan", "moe_h", "moe_y", "gdn_in", "gdn_qkv",
+                 "gdn_gated")
 _KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_BY_REMAT)
 
 
@@ -175,28 +181,16 @@ class GatedDeltaNet(nn.Module):
                              jnp.float32)
         norm_w = self.param("norm", nn.initializers.ones, (dv,), jnp.float32)
         with jax.named_scope("gdn_conv"):
-            mixed = jax.nn.silu(causal_conv1d(
-                qkvz[..., :2 * key_w + val_w].astype(jnp.float32), conv_w))
-        z = qkvz[..., 2 * key_w + val_w:].reshape(b, s, hv, dv)
-        q = mixed[..., :key_w].reshape(b, s, hk, dk)
-        k = mixed[..., key_w:2 * key_w].reshape(b, s, hk, dk)
-        v = mixed[..., 2 * key_w:].reshape(b, s, hv, dv)
-
-        def unit(t):
-            return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
-                                     + 1e-6)
-
-        q = (unit(q) * dk ** -0.5).astype(cfg.dtype)
-        k = unit(k).astype(cfg.dtype)
+            q, k, v, z_in = gdn_prep(qkvz, conv_w, dk)
         beta = jax.nn.sigmoid(ba[..., :hv].astype(jnp.float32))
         g = -jnp.exp(a_log) * jax.nn.softplus(
             ba[..., hv:].astype(jnp.float32) + dt_bias)
-        o = gated_delta_rule(q, k, v.astype(cfg.dtype), g, beta)
+        o = gated_delta_rule(q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
+                             v.reshape(b, s, hv, dv), g, beta)
         with jax.named_scope("gdn_gate"):
-            o = norm_w * _rms(o, cfg.rms_norm_eps) * jax.nn.silu(
-                z.astype(jnp.float32))
-        return _dense(cfg.hidden_size, ("mlp", "embed"), cfg, "out_proj")(
-            o.astype(cfg.dtype).reshape(b, s, val_w))
+            o = gdn_gate(o.reshape(b, s, val_w), z_in, norm_w,
+                         cfg.rms_norm_eps)
+        return _dense(cfg.hidden_size, ("mlp", "embed"), cfg, "out_proj")(o)
 
 
 class GatedAttention(nn.Module):

@@ -1,5 +1,7 @@
 """Gated delta rule (Gated DeltaNet's recurrence): chunked Pallas TPU
-kernels, forward AND backward, and the short causal convolution before it.
+kernels, forward AND backward, and its elementwise neighbours as two fused
+ops with their own backward: `gdn_prep` (the short causal convolution, SiLU
+and the L2 norms before it) and `gdn_gate` (the gated norm after it).
 
 Per head, with a state `S` in R^{dk x dv} that starts at zero:
 
@@ -75,6 +77,43 @@ kernels take goes to the kernels; every other call runs the scan and is
 recorded with the reason. `gated_delta_status()` lists the path of every
 traced call.
 
+Beside the recurrence: a Gated DeltaNet layer projects to qkvz [batch,
+seq, q | k | v | z] in bf16, and between that product and the kernels above
+stands a chain of elementwise f32 work that jax's own rules turn into five
+passes over f32 [batch, seq, channels] arrays through HBM, forward, again in
+a rematerialised forward and a third time in the backward (30 ms of a 310 ms
+step at [1, 8192, 12288], PERF.md PR 42). `gdn_prep(qkvz, conv_w, head_dim)
+-> q, k, v, z_in` and `gdn_gate(o, z_in, norm_w, eps)` are that chain as
+`custom_vjp`s with bf16 at their borders and f32 only inside a block in
+VMEM; their backward reads the SAME bf16 input and makes the chain again in
+VMEM, so no f32 intermediate exists in HBM and the one large residual is
+the projection itself. A grid step is one head's [rows, 128] block (`_ROWS`
+= 1024 positions, the head's dims on the lanes; a sequence shorter than
+that takes as many adjacent heads a block as keep it a block's worth of
+work), read from qkvz IN PLACE by
+a column offset in the BlockSpec (no slice, no convert); the convolution's
+`width - 1` rows before the block come through a second view of the same
+array, the 16-row tile that ends where the block starts, and its transpose
+in the backward reads d(conv out) of the rows AFTER the block, so the
+backward makes the chain for 8 positions more from a third view. `conv_w`'s
+gradient [channels, width] and `norm_w`'s [128] are summed in f32 in an
+output block the sequence axis revisits. `gdn_prep` is one `pallas_call` a
+kind of head (q, k: L2 norm over the lanes, q times head_dim^-0.5; v: none)
+writing q, k [batch, seq, key_w], v [batch, seq, val_w]: the layout above,
+nothing between it and the recurrence. Its fourth output is the projection
+itself, for `gdn_gate` to read z from (the LAST val_w columns, in place):
+handed on like that, d z comes back as the cotangent of that output, inside
+a [batch, seq, columns] buffer of which `gdn_gate_bwd` wrote only the z
+columns and which `gdn_prep_bwd` takes as its own output
+(`input_output_aliases`) and completes: the projection's gradient is
+written once, by the two backwards, and never summed or concatenated. The
+same rule as above decides: platform `tpu`, heads of 128 lanes, the
+sequence whole blocks (one block of whole 16-row tiles under 1024
+positions), else the chain of jax primitives (`_prep_chain`,
+`_gate_chain`: the definition, the fallback, the tests' reference),
+recorded as passes `prep_fwd`, `prep_bwd`, `gate_fwd`, `gate_bwd` with path
+`pallas` or `xla` beside the recurrence's `fwd` and `bwd`.
+
 Under a remat: `gated_delta_rule` is a `custom_vjp` whose backward reads
 q, k, v, g, beta and the chunk-start states. Its forward rule gives what
 only the kernel can make the names `gdn_out` and `gdn_states`
@@ -83,10 +122,17 @@ policy keeps both (`save_only_these_names`; at [1, 8192] x 32 heads of 128
 that is 67 MB + 268 MB a layer) makes the five operands again from its own
 input and runs `gdn_chunk_fwd` ONCE a layer; a policy-less checkpoint runs
 it twice (once for the output alone, once more in its backward for the
-output and the states). A name is the identity anywhere else. On the scan
-path there are no states to keep and the backward differentiates the scan
-anew whatever is kept. RAY_TPU_PALLAS_INTERPRET=1 runs the kernels in the
-interpreter on the CPU (tests).
+output and the states). The neighbours' residuals have names too: `gdn_in`
+(the bf16 projection, 201 MB a layer: kept, the backward makes neither the
+product nor anything of the chain's f32 again), `gdn_qkv` (q, k, v as the
+recurrence's backward reads them, 134 MB: kept, `gdn_prep_fwd` runs once),
+`gdn_gated` (`gdn_gate`'s output, 67 MB, which the next product's backward
+reads: kept, `gdn_gate_fwd` runs once); `gdn_gate`'s own copy of o goes by
+`gdn_out` (`_vjp_fwd` says why a name sits on a residual and not on an
+output). A name is the identity anywhere else. On the scan path there are
+no states to keep and the backward differentiates the scan anew whatever
+is kept. RAY_TPU_PALLAS_INTERPRET=1 runs the kernels in the interpreter on
+the CPU (tests).
 """
 
 from __future__ import annotations
@@ -571,6 +617,355 @@ def _gdn_backward(q, k, v, gcum, beta, states, do, chunk: int, steps: int,
 
 
 # --------------------------------------------------------------------------- #
+# The recurrence's elementwise neighbours: `gdn_prep` before it, `gdn_gate`
+# after it (module docstring, "Beside the recurrence")
+# --------------------------------------------------------------------------- #
+
+_ROWS = 1024  # positions a grid step of the elementwise kernels holds
+_HALO = 16    # rows of the view that brings a neighbouring block's edge
+_EDGE = 8     # of which the kernels read the nearest (an f32 tile)
+
+
+def _unit(t):
+    return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+
+def _widths(qkvz, conv_w):
+    """(key_w, val_w) of a projection [.., 2 key_w + 2 val_w] whose first
+    2 key_w + val_w columns the convolution's weight covers."""
+    return _split(qkvz.shape[-1], conv_w.shape[0])
+
+
+def _split(columns: int, channels: int):
+    val_w = columns - channels
+    return (channels - val_w) // 2, val_w
+
+
+def _prep_chain(qkvz, conv_w, head_dim: int):
+    """`gdn_prep` as jax primitives: the definition, the fallback, and what
+    the tests hold the kernels to. Returns q, k, v."""
+    b, s, _ = qkvz.shape
+    key_w, val_w = _widths(qkvz, conv_w)
+    mixed = jax.nn.silu(causal_conv1d(
+        qkvz[..., :2 * key_w + val_w].astype(jnp.float32), conv_w))
+    q = mixed[..., :key_w].reshape(b, s, -1, head_dim)
+    k = mixed[..., key_w:2 * key_w].reshape(b, s, -1, head_dim)
+    q = (_unit(q) * head_dim ** -0.5).astype(qkvz.dtype)
+    k = _unit(k).astype(qkvz.dtype)
+    return (q.reshape(b, s, key_w), k.reshape(b, s, key_w),
+            mixed[..., 2 * key_w:].astype(qkvz.dtype))
+
+
+def _gate_chain(o, qkvz, norm_w, eps: float):
+    """`gdn_gate` as jax primitives (definition, fallback, reference)."""
+    b, s, val_w = o.shape
+    d = norm_w.shape[0]
+    of = o.astype(jnp.float32).reshape(b, s, -1, d)
+    z = qkvz[..., qkvz.shape[-1] - val_w:].reshape(of.shape)
+    rms = of * jax.lax.rsqrt(jnp.mean(jnp.square(of), axis=-1,
+                                      keepdims=True) + eps)
+    out = norm_w * rms * jax.nn.silu(z.astype(jnp.float32))
+    return out.astype(o.dtype).reshape(b, s, val_w)
+
+
+# Kernel bodies. A block is [rows, 128 h]: positions on the sublanes, the
+# dims of h adjacent heads on the lanes. h is 1 where the sequence fills a
+# block's `_ROWS`; a shorter sequence takes as many heads a block as keep
+# rows x h within `_ROWS` (`_heads_a_block`), so that a grid step stays a
+# block's worth of work.
+
+
+def _head_sum(x):
+    """The sum over each head's 128 lanes of x [n, 128 h], in place of the
+    head's lanes (broadcasts against x)."""
+    if x.shape[-1] == 128:
+        return jnp.sum(x, axis=-1, keepdims=True)
+    return jnp.concatenate(
+        [jnp.broadcast_to(jnp.sum(x[:, i:i + 128], axis=-1, keepdims=True),
+                          (x.shape[0], 128))
+         for i in range(0, x.shape[-1], 128)], axis=-1)
+
+
+def _conv(ext, w, n: int):
+    """c_t = sum_j w[j] x_{t-(width-1)+j} for the n positions that follow
+    the first `_EDGE` rows of ext (f32); w [width, 128]."""
+    width = w.shape[0]
+    first = _EDGE - (width - 1)
+    return sum(w[j:j + 1] * ext[first + j:first + j + n]
+               for j in range(width))
+
+
+def _edge(ref, there, part):
+    """The `_EDGE` rows of a neighbouring block's view nearest this block,
+    in f32; zeros where the sequence has no such neighbour."""
+    return jnp.where(there, ref[0].astype(jnp.float32)[part], 0.0)
+
+
+_BEFORE, _AFTER = slice(_HALO - _EDGE, _HALO), slice(0, _EDGE)
+
+
+def _prep_fwd_kernel(x_ref, before_ref, w_ref, o_ref, *, scale):
+    from jax.experimental import pallas as pl
+
+    rows = x_ref.shape[1]
+    ext = jnp.concatenate(
+        [_edge(before_ref, pl.program_id(2) > 0, _BEFORE),
+         x_ref[0].astype(jnp.float32)], axis=0)
+    c = _conv(ext, w_ref[...], rows)
+    a = c * jax.nn.sigmoid(c)
+    if scale is not None:       # a q or k head: the L2 norm over its lanes
+        a = a * (jax.lax.rsqrt(_head_sum(a * a) + 1e-6) * scale)
+    o_ref[0] = a.astype(o_ref.dtype)
+
+
+def _prep_bwd_kernel(x_ref, before_ref, after_ref, w_ref, dn_ref,
+                     dn_after_ref, _, dx_ref, dw_ref, *, scale):
+    """d(input) of one block and its term of d(conv_w). The convolution's
+    transpose reads d(conv out) of the `width - 1` positions AFTER the
+    block, so the chain is made again for `_EDGE` positions more."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    t, rows = pl.program_id(2), x_ref.shape[1]
+    more = t < pl.num_programs(2) - 1
+    w = w_ref[...]
+    width = w.shape[0]
+    ext = jnp.concatenate([_edge(before_ref, t > 0, _BEFORE),
+                           x_ref[0].astype(f32),
+                           _edge(after_ref, more, _AFTER)], axis=0)
+    dn = jnp.concatenate([dn_ref[0].astype(f32),
+                          _edge(dn_after_ref, more, _AFTER)], axis=0)
+    c = _conv(ext, w, rows + _EDGE)
+    sig = jax.nn.sigmoid(c)
+    if scale is None:
+        da = dn
+    else:                       # n = scale a r, r = (a.a + 1e-6)^-1/2
+        a = c * sig
+        r = jax.lax.rsqrt(_head_sum(a * a) + 1e-6)
+        da = (scale * r) * dn - a * (scale * r * r * r * _head_sum(a * dn))
+    dc = da * (sig * (1.0 + c * (1.0 - sig)))
+    dx = sum(w[j:j + 1] * dc[width - 1 - j:width - 1 - j + rows]
+             for j in range(width))
+    dx_ref[0] = dx.astype(dx_ref.dtype)
+    first = _EDGE - (width - 1)
+    dw = jnp.concatenate(
+        [jnp.sum(dc[:rows] * ext[first + j:first + j + rows], axis=0,
+                 keepdims=True) for j in range(width)], axis=0)
+
+    @pl.when(t == 0)
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    dw_ref[0] += dw
+
+
+def _gate_fwd_kernel(o_ref, z_ref, w_ref, out_ref, *, eps: float):
+    o, z = o_ref[0].astype(jnp.float32), z_ref[0].astype(jnp.float32)
+    r = jax.lax.rsqrt(_head_sum(o * o) / 128 + eps)
+    out_ref[0] = (w_ref[...] * (o * r) * (z * jax.nn.sigmoid(z))).astype(
+        out_ref.dtype)
+
+
+def _gate_bwd_kernel(o_ref, z_ref, w_ref, dg_ref, do_ref, dz_ref, dw_ref, *,
+                     eps: float):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    o, z, dg = (ref[0].astype(f32) for ref in (o_ref, z_ref, dg_ref))
+    w = w_ref[...]
+    r = jax.lax.rsqrt(_head_sum(o * o) / 128 + eps)
+    sig = jax.nn.sigmoid(z)
+    n, s = o * r, z * sig
+    dgn = dg * n
+    dz_ref[0] = (dgn * w * (sig * (1.0 + z * (1.0 - sig)))).astype(
+        dz_ref.dtype)
+    dn = dg * w * s             # n = o r, r = (mean(o o) + eps)^-1/2
+    mean = _head_sum(o * dn) / 128
+    do_ref[0] = (r * dn - o * (r * r * r * mean)).astype(do_ref.dtype)
+    dw = jnp.sum(dgn * s, axis=0, keepdims=True)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    dw_ref[0, 0] += dw
+
+
+def _heads_a_block(rows: int, *columns: int) -> int:
+    """The heads one block holds: as many as keep rows x heads within
+    `_ROWS` and divide every one of `columns` (widths and first columns)."""
+    heads = max(1, _ROWS // rows)
+    while any(c % (128 * heads) for c in columns):
+        heads -= 1
+    return heads
+
+
+def _block_specs(rows: int, n_blocks: int, lanes: int = 128, first: int = 0,
+                 taps: int = 1):
+    """A [rows, lanes] block of a [batch, seq, columns] array whose heads
+    start at column `first`, the `_HALO`-row views of the same array that
+    end where the block starts and start where it ends, and the block's
+    columns of a [taps, columns] weight."""
+    from jax.experimental import pallas as pl
+
+    per, at = rows // _HALO, first // lanes
+    return {
+        "taps": pl.BlockSpec((taps, lanes), lambda b, h, t: (0, at + h)),
+        "block": pl.BlockSpec((1, rows, lanes),
+                              lambda b, h, t: (b, t, at + h)),
+        "before": pl.BlockSpec(
+            (1, _HALO, lanes),
+            lambda b, h, t: (b, jnp.maximum(t * per - 1, 0), at + h)),
+        "after": pl.BlockSpec(
+            (1, _HALO, lanes),
+            lambda b, h, t: (b, jnp.minimum(t + 1, n_blocks - 1) * per,
+                             at + h)),
+    }
+
+
+def _every_block(lanes: int):
+    """The [1, lanes] norm weight (tiled over a block's heads) that every
+    block of `gdn_gate` shares."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((1, lanes), lambda b, h, t: (0, 0))
+
+
+def _elementwise_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _kinds(key_w: int, val_w: int, head_dim: int):
+    """q, k, v: (first column of the kind's heads in the projection, their
+    width, what the L2 norm is scaled by: None for v, which has none)."""
+    return ((0, key_w, head_dim ** -0.5), (key_w, key_w, 1.0),
+            (2 * key_w, val_w, None))
+
+
+@functools.partial(jax.jit, static_argnames=("head_dim", "rows",
+                                             "interpret"))
+def _gdn_prep_forward(qkvz, conv_w, head_dim: int, rows: int,
+                      interpret: bool = False):
+    """q, k [batch, seq, key_w], v [batch, seq, val_w] in qkvz's dtype:
+    one call a kind of head, each reading its columns of qkvz in place."""
+    from jax.experimental import pallas as pl
+
+    batch, seq, _ = qkvz.shape
+    n_blocks = seq // rows
+    taps = conv_w.T                                   # [width, channels]
+    out = []
+    for first, width, scale in _kinds(
+            *_split(qkvz.shape[-1], conv_w.shape[0]), head_dim):
+        lanes = 128 * _heads_a_block(rows, width, first)
+        at = _block_specs(rows, n_blocks, lanes, first, taps.shape[0])
+        out.append(pl.pallas_call(
+            functools.partial(_prep_fwd_kernel, scale=scale),
+            grid=(batch, width // lanes, n_blocks),
+            in_specs=[at["block"], at["before"], at["taps"]],
+            out_specs=_block_specs(rows, n_blocks, lanes)["block"],
+            out_shape=jax.ShapeDtypeStruct((batch, seq, width), qkvz.dtype),
+            compiler_params=_elementwise_params(),
+            interpret=interpret, name="gdn_prep_fwd",
+        )(qkvz, qkvz, taps))
+    return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnames=("head_dim", "rows",
+                                             "interpret"))
+def _gdn_prep_backward(qkvz, conv_w, dq, dk, dv, d_in, head_dim: int,
+                       rows: int, interpret: bool = False):
+    """(d qkvz, d conv_w). `d_in` [batch, seq, columns] is the cotangent
+    of the projection handed on to `gdn_gate`, whose backward wrote its z
+    columns: every call below writes its own columns INTO that buffer."""
+    from jax.experimental import pallas as pl
+
+    batch, seq, _ = qkvz.shape
+    n_blocks = seq // rows
+    taps = conv_w.T
+    d_outs, d_taps = (dq, dk, dv), []
+    for kind, (first, width, scale) in enumerate(_kinds(
+            *_split(qkvz.shape[-1], conv_w.shape[0]), head_dim)):
+        dn = d_outs[kind]
+        lanes = 128 * _heads_a_block(rows, width, first)
+        at = _block_specs(rows, n_blocks, lanes, first, taps.shape[0])
+        here = _block_specs(rows, n_blocks, lanes)
+        d_in, d_tap = pl.pallas_call(
+            functools.partial(_prep_bwd_kernel, scale=scale),
+            grid=(batch, width // lanes, n_blocks),
+            in_specs=[at["block"], at["before"], at["after"], at["taps"],
+                      here["block"], here["after"],
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[at["block"],
+                       pl.BlockSpec((1, taps.shape[0], lanes),
+                                    lambda b, h, t: (b, 0, h))],
+            out_shape=[jax.ShapeDtypeStruct(d_in.shape, d_in.dtype),
+                       jax.ShapeDtypeStruct((batch, taps.shape[0], width),
+                                            jnp.float32)],
+            input_output_aliases={6: 0},
+            compiler_params=_elementwise_params(),
+            interpret=interpret, name="gdn_prep_bwd",
+        )(qkvz, qkvz, qkvz, taps, dn, dn, d_in)
+        d_taps.append(d_tap)
+    d_w = jnp.concatenate(d_taps, axis=-1).sum(axis=0).T
+    return d_in, d_w.astype(conv_w.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "rows", "interpret"))
+def _gdn_gate_forward(o, qkvz, norm_w, eps: float, rows: int,
+                      interpret: bool = False):
+    from jax.experimental import pallas as pl
+
+    batch, seq, val_w = o.shape
+    n_blocks = seq // rows
+    first = qkvz.shape[-1] - val_w
+    heads = _heads_a_block(rows, val_w, first)
+    here = _block_specs(rows, n_blocks, 128 * heads)
+    z = _block_specs(rows, n_blocks, 128 * heads, first)
+    return pl.pallas_call(
+        functools.partial(_gate_fwd_kernel, eps=eps),
+        grid=(batch, val_w // (128 * heads), n_blocks),
+        in_specs=[here["block"], z["block"], _every_block(128 * heads)],
+        out_specs=here["block"],
+        out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
+        compiler_params=_elementwise_params(),
+        interpret=interpret, name="gdn_gate_fwd",
+    )(o, qkvz, jnp.tile(norm_w.astype(jnp.float32), heads)[None])
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "rows", "interpret"))
+def _gdn_gate_backward(o, qkvz, norm_w, dg, eps: float, rows: int,
+                       interpret: bool = False):
+    """(do, d qkvz with ONLY its z columns written, d norm_w)."""
+    from jax.experimental import pallas as pl
+
+    batch, seq, val_w = o.shape
+    n_blocks = seq // rows
+    first = qkvz.shape[-1] - val_w
+    heads = _heads_a_block(rows, val_w, first)
+    lanes = 128 * heads
+    here = _block_specs(rows, n_blocks, lanes)["block"]
+    z = _block_specs(rows, n_blocks, lanes, first)
+    do, d_in, d_w = pl.pallas_call(
+        functools.partial(_gate_bwd_kernel, eps=eps),
+        grid=(batch, val_w // lanes, n_blocks),
+        in_specs=[here, z["block"], _every_block(lanes), here],
+        out_specs=[here, z["block"],
+                   pl.BlockSpec((1, 1, 1, lanes),
+                                lambda b, h, t: (b, h, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype),
+                   jax.ShapeDtypeStruct(qkvz.shape, qkvz.dtype),
+                   jax.ShapeDtypeStruct((batch, val_w // lanes, 1, lanes),
+                                        jnp.float32)],
+        compiler_params=_elementwise_params(),
+        interpret=interpret, name="gdn_gate_bwd",
+    )(o, qkvz, jnp.tile(norm_w.astype(jnp.float32), heads)[None], dg)
+    return do, d_in, d_w.reshape(-1, 128).sum(axis=0).astype(norm_w.dtype)
+
+
+# --------------------------------------------------------------------------- #
 # Dispatch + custom VJP
 # --------------------------------------------------------------------------- #
 
@@ -580,12 +975,17 @@ _CALLS_LOCK = threading.Lock()
 
 
 def gated_delta_status() -> list:
-    """Which path every traced recurrence call of this process took: one
-    entry per distinct (pass, shape) with `path` "pallas" or "scan", the
-    dispatch rule's `reason` for a scan call, `shape` [batch, value_heads,
-    seq, head_dim], the `chunk`, `chunks_abreast` (how many chunks'
-    state-independent parts one grid step of the kernels computes side by
-    side; None on the scan path) and the number of traced calls."""
+    """Which path every traced call of this process took: one entry per
+    distinct (pass, shape). The recurrence's passes `fwd` and `bwd`: `path`
+    "pallas" or "scan", the dispatch rule's `reason` for a scan call,
+    `shape` [batch, value_heads, seq, head_dim], the `chunk`,
+    `chunks_abreast` (how many chunks' state-independent parts one grid
+    step of the kernels computes side by side; None on the scan path) and
+    the number of traced calls. Its neighbours' passes `prep_fwd`,
+    `prep_bwd`, `gate_fwd`, `gate_bwd`: `path` "pallas" or "xla" (the chain
+    of jax primitives) with the `reason`, `shape` [batch, seq, columns] of
+    the array the op reads, `chunk` the positions of a block (None on the
+    xla path), `chunks_abreast` None."""
     with _CALLS_LOCK:
         items = list(_CALLS.items())
     return [{"pass": p, "path": path, "reason": reason, "shape": list(shape),
@@ -599,22 +999,27 @@ def reset_gated_delta_status() -> None:
         _CALLS.clear()
 
 
-def _dispatch(pass_: str, q, k, v) -> bool:
-    """True when the kernels take this call. Records the decision."""
+def _off_platform() -> str:
+    """Why no kernel of this file runs here, whatever the shapes: "" on
+    platform `tpu` and under the interpreter switch on any other."""
     platform = _platform()
-    b, s, hv, dv = v.shape
     if _interpret() and platform == "tpu":
         raise RuntimeError(
             "RAY_TPU_PALLAS_INTERPRET=1 is a CPU test switch; on platform "
             "tpu it would run the interpreter under the kernels' name")
-    if platform != "tpu" and not _interpret():
-        reason = f"platform {platform}"
+    return "" if platform == "tpu" or _interpret() else f"platform {platform}"
+
+
+def _dispatch(pass_: str, q, k, v) -> bool:
+    """True when the kernels take this call. Records the decision."""
+    b, s, hv, dv = v.shape
+    reason = _off_platform()
+    if reason:
+        pass
     elif q.shape[-1] != 128 or dv != 128:
         reason = "head_dim is not the lane width (128)"
     elif hv % k.shape[2]:
         reason = "value heads not a multiple of key heads"
-    else:
-        reason = ""
     key = (pass_, "scan" if reason else "pallas", reason, (b, hv, s, dv),
            jnp.dtype(v.dtype).name, CHUNK,
            None if reason else _steps(_padded_len(s) // CHUNK))
@@ -684,6 +1089,12 @@ def _vjp_fwd(q, k, v, g, beta):
     out = checkpoint_name(out, "gdn_out")
     if states is not None:
         states = checkpoint_name(states, "gdn_states")
+    # The operands `gdn_prep` made, by name too, on copies that ONLY the
+    # backward reads: jax rounds every kept array the forward also reads
+    # through an identity (`reduce_precision`, against XLA's excess
+    # precision inside fusions), and between two kernels, which round where
+    # they write, that identity is a pass over the array through HBM.
+    q, k, v = (checkpoint_name(t, "gdn_qkv") for t in (q, k, v))
     return out, (q, k, v, g, beta, states)
 
 
@@ -718,3 +1129,124 @@ def _vjp_bwd(residuals, do):
 
 
 gated_delta_rule.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def _dispatch_beside(pass_: str, x, head_dim: int, widths,
+                     taps: int = 1) -> int:
+    """The rows of a block when the elementwise kernels take this call on
+    x [batch, seq, columns], else 0. Records the decision beside the
+    recurrence's (`gated_delta_status()`)."""
+    b, s, columns = x.shape
+    rows = min(_ROWS, s)
+    reason = _off_platform()
+    if reason:
+        pass
+    elif head_dim != 128:
+        reason = "head_dim is not the lane width (128)"
+    elif any(w % 128 for w in widths):
+        reason = "heads do not fill whole 128-lane column blocks"
+    elif s % rows or rows % _HALO:
+        reason = (f"sequence is not whole blocks of {_ROWS} rows (or one "
+                  f"block of whole {_HALO}-row tiles)")
+    elif taps - 1 > _EDGE:
+        reason = f"convolution reaches over more than {_EDGE} positions"
+    key = (pass_, "xla" if reason else "pallas", reason, (b, s, columns),
+           jnp.dtype(x.dtype).name, None if reason else rows, None)
+    with _CALLS_LOCK:
+        _CALLS[key] += 1
+    return 0 if reason else rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def gdn_prep(qkvz, conv_w, head_dim: int):
+    """What stands between a Gated DeltaNet layer's projection and its
+    recurrence, fused: of qkvz [batch, seq, 2 key_w + 2 val_w] (columns
+    [q | k | v | z]) the first 2 key_w + val_w columns go through the causal
+    depthwise convolution conv_w [channels, width] and SiLU in f32; q and
+    k are L2-normalised per head of `head_dim` (q also times head_dim^-0.5).
+    Returns (q, k [batch, seq, key_w], v [batch, seq, val_w], z_in) in
+    qkvz's dtype: the layout the recurrence kernels read. `z_in` is the
+    projection itself, for `gdn_gate` and nothing else: only its z columns
+    may be read (the cotangent of its other columns is dropped), and in
+    exchange d z arrives here inside the buffer this op's backward
+    completes, instead of as a second [batch, seq, columns] array to add."""
+    return (*_prep(qkvz, conv_w, head_dim), qkvz)
+
+
+def _prep(qkvz, conv_w, head_dim: int):
+    rows = _dispatch_beside("prep_fwd", qkvz, head_dim,
+                            _widths(qkvz, conv_w), conv_w.shape[1])
+    if not rows:
+        return _prep_chain(qkvz, conv_w, head_dim)
+    return _gdn_prep_forward(qkvz, conv_w, head_dim=head_dim, rows=rows,
+                             interpret=_interpret())
+
+
+def _prep_vjp_fwd(qkvz, conv_w, head_dim: int):
+    # The one large residual, by name, and the forward reads the NAMED
+    # array: kept, a surrounding checkpoint's backward makes neither the
+    # projection nor (with `gdn_qkv`) this op's outputs again. Its
+    # producer is XLA's product, which takes jax's identity into its own
+    # fusion (`_vjp_fwd` has the other case).
+    qkvz = checkpoint_name(qkvz, "gdn_in")
+    outs = _prep(qkvz, conv_w, head_dim)
+    return (*outs, qkvz), (qkvz, conv_w)
+
+
+def _prep_vjp_bwd(head_dim: int, residuals, cotangents):
+    qkvz, conv_w = residuals
+    dq, dk, dv, d_in = cotangents
+    rows = _dispatch_beside("prep_bwd", qkvz, head_dim,
+                            _widths(qkvz, conv_w), conv_w.shape[1])
+    if rows:
+        return _gdn_prep_backward(qkvz, conv_w, dq, dk, dv, d_in,
+                                  head_dim=head_dim, rows=rows,
+                                  interpret=_interpret())
+    _, vjp = jax.vjp(lambda x, w: _prep_chain(x, w, head_dim), qkvz, conv_w)
+    dx, dw = vjp((dq, dk, dv))
+    channels = conv_w.shape[0]
+    return jnp.concatenate([dx[..., :channels], d_in[..., channels:]],
+                           axis=-1), dw
+
+
+gdn_prep.defvjp(_prep_vjp_fwd, _prep_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def gdn_gate(o, z_in, norm_w, eps: float):
+    """The gated norm after the recurrence, fused: per head of
+    norm_w.shape[0] dims, norm_w * o * rsqrt(mean(o^2) + eps) * silu(z) in
+    f32, z the LAST o.shape[-1] columns of z_in (`gdn_prep`'s fourth
+    output), read in place. o [batch, seq, val_w]; returns the same shape
+    and dtype."""
+    return _gate(o, z_in, norm_w, eps)
+
+
+def _gate(o, z_in, norm_w, eps: float):
+    rows = _dispatch_beside("gate_fwd", o, norm_w.shape[0],
+                            (o.shape[-1], z_in.shape[-1]))
+    if not rows:
+        return _gate_chain(o, z_in, norm_w, eps)
+    return _gdn_gate_forward(o, z_in, norm_w, eps=eps, rows=rows,
+                             interpret=_interpret())
+
+
+def _gate_vjp_fwd(o, z_in, norm_w, eps: float):
+    out = checkpoint_name(_gate(o, z_in, norm_w, eps), "gdn_gated")
+    # o comes out of a kernel and goes into one: its name on a copy that
+    # only the backward reads (`_vjp_fwd`)
+    return out, (checkpoint_name(o, "gdn_out"), z_in, norm_w)
+
+
+def _gate_vjp_bwd(eps: float, residuals, dg):
+    o, z_in, norm_w = residuals
+    rows = _dispatch_beside("gate_bwd", o, norm_w.shape[0],
+                            (o.shape[-1], z_in.shape[-1]))
+    if rows:
+        return _gdn_gate_backward(o, z_in, norm_w, dg, eps=eps, rows=rows,
+                                  interpret=_interpret())
+    _, vjp = jax.vjp(lambda *a: _gate_chain(*a, eps), o, z_in, norm_w)
+    return vjp(dg)
+
+
+gdn_gate.defvjp(_gate_vjp_fwd, _gate_vjp_bwd)

@@ -1,6 +1,8 @@
 """What Qwen3-Next brought under `ops/`, on the CPU at small sizes: the
 chunked gated delta rule (Pallas kernels in the interpreter, and the scan
-fallback) against the step-by-step scan; the grouped products and the
+fallback) against the step-by-step scan; its fused elementwise neighbours
+(`gdn_prep`, `gdn_gate`) against the chain of jax primitives they replaced;
+the grouped products and the
 held-expert layer against dense arithmetic, dropless under a skewed
 router. The model itself is in `test_qwen3_next_model.py`.
 """
@@ -157,6 +159,213 @@ def test_causal_conv1d_is_the_published_left_padded_convolution():
                 want[:, t] += w[:, j] * x[:, t - 3 + j]
     np.testing.assert_allclose(
         gd.causal_conv1d(jnp.asarray(x), jnp.asarray(w)), want, atol=1e-6)
+
+
+# --------------------------------------------------------------------------- #
+# The recurrence's elementwise neighbours: `gdn_prep` and `gdn_gate`
+# --------------------------------------------------------------------------- #
+
+
+def beside_inputs(seq, key_w=128, val_w=256, d=128, dtype=jnp.bfloat16):
+    """A projection [q | k | v | z] of one key head and two value heads,
+    the convolution's and the norm's weights, the recurrence's output and a
+    cotangent for everything the two ops return."""
+    ks = jax.random.split(jax.random.PRNGKey(seq), 9)
+    rand = lambda k, w: jax.random.normal(k, (1, seq, w)).astype(dtype)
+    return dict(
+        qkvz=rand(ks[0], 2 * key_w + 2 * val_w),
+        conv_w=jax.random.uniform(ks[1], (2 * key_w + val_w, 4),
+                                  jnp.float32, -0.5, 0.5),
+        norm_w=1.0 + 0.1 * jax.random.normal(ks[2], (d,)),
+        o=rand(ks[3], val_w),
+        cts=(rand(ks[4], key_w), rand(ks[5], key_w), rand(ks[6], val_w)),
+        dg=rand(ks[7], val_w), d=d, channels=2 * key_w + val_w)
+
+
+def dotted(outs, cts):
+    return sum(jnp.sum(a.astype(jnp.float32) * c.astype(jnp.float32))
+               for a, c in zip(outs, cts))
+
+
+def old_mixer_chain(qkvz, conv_w, norm_w, o, d, eps=1e-6):
+    """`GatedDeltaNet.__call__` around its recurrence as it stood before
+    the fused ops, line for line (q, k, v, gated)."""
+    b, s, _ = qkvz.shape
+    val_w = qkvz.shape[-1] - conv_w.shape[0]
+    key_w = (conv_w.shape[0] - val_w) // 2
+    mixed = jax.nn.silu(gd.causal_conv1d(
+        qkvz[..., :2 * key_w + val_w].astype(jnp.float32), conv_w))
+    z = qkvz[..., 2 * key_w + val_w:].reshape(b, s, -1, d)
+    q = mixed[..., :key_w].reshape(b, s, -1, d)
+    k = mixed[..., key_w:2 * key_w].reshape(b, s, -1, d)
+    v = mixed[..., 2 * key_w:].reshape(b, s, -1, d)
+
+    def unit(t):
+        return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+    q = (unit(q) * d ** -0.5).astype(qkvz.dtype)
+    k = unit(k).astype(qkvz.dtype)
+    v = v.astype(qkvz.dtype)
+    of = o.reshape(b, s, -1, d).astype(jnp.float32)
+    rms = of * jax.lax.rsqrt(jnp.mean(jnp.square(of), axis=-1, keepdims=True)
+                             + eps)
+    gated = norm_w * rms * jax.nn.silu(z.astype(jnp.float32))
+    return (q.reshape(b, s, key_w), k.reshape(b, s, key_w),
+            v.reshape(b, s, val_w),
+            gated.astype(qkvz.dtype).reshape(b, s, val_w))
+
+
+# seq 128: one block of fewer rows than the kernels' own 1024; 1024: one
+# whole block; 3072: three, so a block has a neighbour on both sides and
+# the convolution's three rows cross a border forward, its transpose's
+# three backward.
+@pytest.mark.parametrize("seq", [128, 1024, 3072])
+def test_gdn_prep_matches_the_chain_it_fuses(interpret, seq):
+    x = beside_inputs(seq)
+    gd.reset_gated_delta_status()
+    got = gd.gdn_prep(x["qkvz"], x["conv_w"], x["d"])
+    want = gd._prep_chain(x["qkvz"], x["conv_w"], x["d"])
+    assert got[3] is x["qkvz"]
+    for name, a, b in zip("qkv", got, want):
+        # bf16 out of f32 arithmetic in another order: an ulp of bf16
+        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
+        assert close(a, b.astype(jnp.float32), 4e-3), name
+    grads = jax.grad(lambda a, w: dotted(gd.gdn_prep(a, w, x["d"]),
+                                         x["cts"]), (0, 1))(
+        x["qkvz"], x["conv_w"])
+    wants = jax.grad(lambda a, w: dotted(gd._prep_chain(a, w, x["d"]),
+                                         x["cts"]), (0, 1))(
+        x["qkvz"], x["conv_w"])
+    assert grads[0].dtype == jnp.bfloat16
+    assert grads[1].dtype == jnp.float32 and grads[1].shape == (512, 4)
+    assert close(grads[0], wants[0].astype(jnp.float32), 4e-3)
+    assert close(grads[1], wants[1], 1e-4)      # summed in f32 in-kernel
+    # nothing of z reaches q, k, v
+    assert not np.asarray(grads[0][..., x["channels"]:]).any()
+    assert all(c["path"] == "pallas" and c["chunk"] == min(seq, 1024)
+               and c["shape"] == [1, seq, 768]
+               for c in gd.gated_delta_status())
+
+
+@pytest.mark.parametrize("seq", [128, 1024, 3072])
+def test_gdn_gate_matches_the_chain_it_fuses(interpret, seq):
+    x = beside_inputs(seq)
+    args = (x["o"], x["qkvz"], x["norm_w"])
+    gd.reset_gated_delta_status()
+    got = gd.gdn_gate(*args, 1e-6)
+    want = gd._gate_chain(*args, 1e-6)
+    assert got.dtype == jnp.bfloat16
+    assert close(got, want.astype(jnp.float32), 4e-3)
+    grads = jax.grad(lambda *a: dotted([gd.gdn_gate(*a, 1e-6)], [x["dg"]]),
+                     (0, 1, 2))(*args)
+    wants = jax.grad(lambda *a: dotted([gd._gate_chain(*a, 1e-6)],
+                                       [x["dg"]]), (0, 1, 2))(*args)
+    assert grads[0].dtype == grads[1].dtype == jnp.bfloat16
+    assert close(grads[0], wants[0].astype(jnp.float32), 4e-3)
+    # d z lies in the projection's LAST columns; the others are not the
+    # gate's to write (`gdn_prep`'s backward fills them)
+    z = slice(x["channels"], None)
+    assert close(grads[1][..., z], wants[1][..., z].astype(jnp.float32),
+                 4e-3)
+    assert grads[2].dtype == jnp.float32 and close(grads[2], wants[2], 1e-4)
+    assert {c["pass"] for c in gd.gated_delta_status()} == {"gate_fwd",
+                                                             "gate_bwd"}
+    assert all(c["path"] == "pallas" for c in gd.gated_delta_status())
+
+
+# the two ops as the model chains them (v stands for the recurrence's
+# output): d z, written by the gate's backward, arrives inside the buffer
+# `gdn_prep`'s backward completes
+@pytest.mark.parametrize("seq", [128, 2048])
+def test_both_ops_give_the_projection_one_whole_gradient(interpret, seq):
+    x = beside_inputs(seq)
+
+    def through(prep, gate):
+        def loss(qkvz, conv_w, norm_w):
+            q, k, v, z_in = prep(qkvz, conv_w)
+            return dotted((q, k, gate(v, z_in, norm_w)),
+                          (*x["cts"][:2], x["dg"]))
+        return jax.grad(loss, (0, 1, 2))(x["qkvz"], x["conv_w"], x["norm_w"])
+
+    got = through(lambda a, w: gd.gdn_prep(a, w, x["d"]),
+                  lambda o, z, w: gd.gdn_gate(o, z, w, 1e-6))
+    want = through(lambda a, w: (*gd._prep_chain(a, w, x["d"]), a),
+                   lambda o, z, w: gd._gate_chain(o, z, w, 1e-6))
+    assert got[0].shape == x["qkvz"].shape
+    for a, b, rel in zip(got, want, (4e-3, 1e-3, 1e-3)):
+        assert close(a, b.astype(jnp.float32), rel)
+
+
+# 100: no whole 16-row tile; 1536: more than a block and not whole blocks
+@pytest.mark.parametrize("seq", [100, 1536])
+def test_a_sequence_of_part_blocks_falls_back_and_says_why(interpret, seq):
+    x = beside_inputs(seq)
+    gd.reset_gated_delta_status()
+    q, k, v, z_in = gd.gdn_prep(x["qkvz"], x["conv_w"], x["d"])
+    gated = gd.gdn_gate(x["o"], z_in, x["norm_w"], 1e-6)
+    old = old_mixer_chain(x["qkvz"], x["conv_w"], x["norm_w"], x["o"],
+                          x["d"])
+    for a, b in zip((q, k, v, gated), old):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b))
+    status = gd.gated_delta_status()
+    assert {c["pass"] for c in status} == {"prep_fwd", "gate_fwd"}
+    assert all(c["path"] == "xla" and "whole blocks" in c["reason"]
+               and c["chunk"] is None for c in status)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_the_fallback_equals_the_old_code_bit_for_bit(dtype):
+    """Off the TPU, and for heads that are not 128 wide, the ops ARE the
+    chain of jax primitives the model held before them: values and every
+    gradient, to the bit, heads of 16 as in `Qwen3NextConfig.tiny`."""
+    x = beside_inputs(96, key_w=32, val_w=64, d=16, dtype=dtype)
+
+    def new(qkvz, conv_w, norm_w, o):
+        q, k, v, z_in = gd.gdn_prep(qkvz, conv_w, x["d"])
+        return q, k, v, gd.gdn_gate(o, z_in, norm_w, 1e-6)
+
+    def old(qkvz, conv_w, norm_w, o):
+        return old_mixer_chain(qkvz, conv_w, norm_w, o, x["d"])
+
+    args = (x["qkvz"], x["conv_w"], x["norm_w"], x["o"])
+    cts = (*x["cts"], x["dg"])
+    gd.reset_gated_delta_status()
+    for a, b in zip(new(*args), old(*args)):
+        assert a.dtype == b.dtype == dtype and bool(jnp.all(a == b))
+    grads = jax.grad(lambda *a: dotted(new(*a), cts), range(4))(*args)
+    wants = jax.grad(lambda *a: dotted(old(*a), cts), range(4))(*args)
+    for name, a, b in zip(("qkvz", "conv_w", "norm_w", "o"), grads, wants):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b)), name
+    status = gd.gated_delta_status()
+    assert {c["pass"] for c in status} == {"prep_fwd", "prep_bwd",
+                                           "gate_fwd", "gate_bwd"}
+    assert all(c["path"] == "xla" and c["reason"].startswith("platform")
+               and c["calls"] >= 1 for c in status)
+
+
+@pytest.mark.parametrize("d,key_w,reason", [
+    (16, 32, "head_dim is not the lane width"),
+    (128, 128, "")])
+def test_status_lists_the_four_passes_with_path_and_reason(interpret, d,
+                                                           key_w, reason):
+    x = beside_inputs(128, key_w=key_w, val_w=2 * key_w, d=d)
+    gd.reset_gated_delta_status()
+
+    def loss(qkvz, conv_w, norm_w):
+        q, k, v, z_in = gd.gdn_prep(qkvz, conv_w, d)
+        return dotted((q, k, gd.gdn_gate(v, z_in, norm_w, 1e-6)),
+                      (*x["cts"][:2], x["dg"]))
+
+    jax.eval_shape(jax.grad(loss, (0, 1, 2)), x["qkvz"], x["conv_w"],
+                   x["norm_w"])
+    status = {c["pass"]: c for c in gd.gated_delta_status()}
+    assert sorted(status) == ["gate_bwd", "gate_fwd", "prep_bwd",
+                              "prep_fwd"]
+    for c in status.values():
+        assert c["path"] == ("xla" if reason else "pallas")
+        assert c["reason"].startswith(reason) and c["calls"] == 1
+        assert c["chunks_abreast"] is None
 
 
 def test_grouped_matmul_and_both_gradients():

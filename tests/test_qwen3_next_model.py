@@ -202,10 +202,16 @@ def test_trained_through_make_train_step_the_loss_falls():
 # softmax layer at widths the kernels take, the kernels in the interpreter.
 # --------------------------------------------------------------------------- #
 
-FORWARD_KERNELS = ("gdn_chunk_fwd", "flash_fwd", "moe_gmm")
+FORWARD_KERNELS = ("gdn_chunk_fwd", "flash_fwd", "moe_gmm", "gdn_prep_fwd",
+                   "gdn_gate_fwd")
 ROUTING = ("top_k", "sort")     # primitives of the plan, counted as kernels
 BACKWARD_KERNELS = ("gdn_chunk_bwd", "flash_bwd_dq", "flash_bwd_dkv",
-                    "moe_gmm_dlhs", "moe_gmm_drhs")
+                    "moe_gmm_dlhs", "moe_gmm_drhs", "gdn_prep_bwd",
+                    "gdn_gate_bwd")
+# calls a recurrent layer: the elementwise kernels beside the recurrence
+# run once a kind of head (q, k, v), the gate's once
+CALLS_A_LAYER = {"gdn_prep_fwd": 3, "gdn_prep_bwd": 3, "gdn_gate_fwd": 1,
+                 "gdn_gate_bwd": 1, "gdn_chunk_fwd": 1, "gdn_chunk_bwd": 1}
 
 
 def kernel_calls(jaxpr, found=None):
@@ -280,6 +286,8 @@ def test_remat_runs_no_kernel_more_often_than_no_remat(remat_and_not, kernel):
     plain, kept = remat_and_not[False][0], remat_and_not[True][0]
     assert set(plain) == set(FORWARD_KERNELS + BACKWARD_KERNELS + ROUTING)
     assert kept[kernel] == plain[kernel] > 0
+    # one recurrent layer: no forward beside the recurrence runs twice
+    assert kept[kernel] == CALLS_A_LAYER.get(kernel, kept[kernel])
 
 
 def test_remat_changes_no_bit_of_loss_or_gradients(remat_and_not):
@@ -295,7 +303,13 @@ def test_remat_changes_no_bit_of_loss_or_gradients(remat_and_not):
 # 128, one key head / two value heads of 128 (2 chunks), 4 heads of 64,
 # 8 held experts of width 32 in a block of 512 + 8 x 128 rows
 KEPT_ARRAYS = {
-    "gdn_out": [("bfloat16", (1, 128, 2, 128))],
+    # the projection [q 128 | k 128 | v 256 | z 256], ONCE: `gdn_prep`'s
+    # and `gdn_gate`'s one large residual
+    "gdn_in": [("bfloat16", (1, 128, 768))],
+    "gdn_qkv": [("bfloat16", (1, 128, 1, 128)),
+                ("bfloat16", (1, 128, 2, 128))],
+    "gdn_gated": [("bfloat16", (1, 128, 256))],
+    "gdn_out": [("bfloat16", (1, 128, 256))],
     "gdn_states": [("float32", (1, 2, 2, 128, 128))],
     "flash_out": [("bfloat16", (1, 128, 256))],
     "flash_lse": [("float32", (1, 4, 1, 128))],
@@ -320,17 +334,31 @@ def test_a_rematerialised_layer_keeps_what_goes_by(remat_and_not, name):
                if (aval.dtype.name, aval.shape) in KEPT_ARRAYS[name])
 
 
-def test_a_rematerialised_layer_keeps_no_projection_or_convolution(
+def test_a_rematerialised_layer_keeps_the_bf16_projection_once_and_no_f32_convolution_output(
         remat_and_not):
     def wide(kept):
         # in_proj_qkvz's output is 768 wide here (12,288 in the cell) and
-        # the convolution's f32 output 512 (8,192); nothing else is
-        return [(aval.str_short(), why) for aval, why in kept
-                if aval.shape and aval.shape[-1] in (768, 512)
+        # the convolution's output 512 (8,192); nothing else is
+        return [(aval.dtype.name, aval.shape, why) for aval, why in kept
+                if aval.ndim == 3 and aval.shape[-1] in (768, 512)
                 and "argument" not in why]
 
-    assert wide(remat_and_not[False][3])        # kept where nothing is remat
-    assert not wide(remat_and_not[True][3])
+    # with or without remat the fused ops' residual is the projection as
+    # the product made it: bf16, once, and no f32 array of the chain
+    # (jax's own rules kept the convolution's and SiLU's f32 outputs)
+    for remat in (False, True):
+        kept = wide(remat_and_not[remat][3])
+        assert kept and {(dtype, shape) for dtype, shape, _ in kept} == {
+            ("bfloat16", (1, 128, 768))}, kept
+    # once: `gdn_prep`'s residual and `gdn_gate`'s are one named array
+    assert len(wide(remat_and_not[True][3])) == 1
+    # nor a head's f32 q, k, v or z, [1, 128, heads, 128], which the norms'
+    # and the gate's rules kept
+    for remat in (False, True):
+        for aval, why in remat_and_not[remat][3]:
+            assert not (aval.dtype == jnp.float32 and aval.ndim == 4
+                        and aval.shape[:2] == (1, 128)
+                        and aval.shape[-1] == 128), (aval, why)
 
 
 def test_the_names_change_nothing_of_a_gpt2_train_step(interpret,
