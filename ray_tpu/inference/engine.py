@@ -31,7 +31,13 @@ The engine knows nothing of the model's family. It is handed `model` and
   sequence (where not, nothing is adopted from or donated to the radix
   prefix cache, and speculation is refused: its rejected positions
   would need a rollback), and `model.slot_state_bytes`, what a slot
-  holds beside the blocks (for `stats()`);
+  holds beside the blocks (for `stats()`). A third, `pageless_context`,
+  is answered only by a model whose cache has NO paged part (per-slot
+  state and nothing else): the positions a sequence may reach. The
+  engine then hands out no block (`kv_cache.NoBlocks`: admission is by
+  free slots alone and nothing is ever preempted for blocks), gives the
+  step programs a block table zero blocks wide, and bounds a request by
+  that context and not by `max_blocks_per_seq`;
 - `model.place_on_mesh(params, mesh) -> (params, tp)`: tensor-parallel
   placement and the tp degree;
 - `model.early_exit_draft(params) -> (draft_model, draft_params)`: asked
@@ -94,7 +100,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
-from ray_tpu.inference.kv_cache import BlockManager, RadixPrefixCache
+from ray_tpu.inference.kv_cache import (BlockManager, NoBlocks,
+                                        RadixPrefixCache)
 from ray_tpu.observability import tracing as _tracing
 from ray_tpu.observability.phases import PhaseClock
 
@@ -232,8 +239,6 @@ class InferenceEngine:
     def __init__(self, config: EngineConfig, model=None, params=None,
                  mesh=None, draft_model=None, draft_params=None):
         cfg = config
-        if cfg.max_blocks_per_seq * cfg.block_size < cfg.prefill_chunk:
-            raise ValueError("prefill_chunk exceeds the per-seq context")
         if cfg.slo_default_class not in ("interactive", "batch"):
             raise ValueError(
                 f"unknown slo_default_class {cfg.slo_default_class!r}")
@@ -256,8 +261,27 @@ class InferenceEngine:
             params, self._tp = model.place_on_mesh(params, mesh)
         self._model = model
         self._params = params
-        self._bm = BlockManager(cfg.num_blocks, cfg.block_size)
+        # A model whose cache has no paged part says so with the positions
+        # a sequence may reach (`pageless_context`): no block is handed
+        # out or counted against admission, and the block table the step
+        # programs are given is zero blocks wide.
+        pageless = getattr(model, "pageless_context", None)
+        if pageless is None:
+            self._bm = BlockManager(cfg.num_blocks, cfg.block_size)
+            self._table_width = cfg.max_blocks_per_seq
+            self._max_context = cfg.max_context
+        else:
+            self._bm = NoBlocks(cfg.block_size)
+            self._table_width = 0
+            self._max_context = int(pageless)
+        if self._max_context < cfg.prefill_chunk:
+            raise ValueError("prefill_chunk exceeds the per-seq context")
         self._arenas = self._fresh_cache(model)
+        import jax
+
+        self._kv_bytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self._arenas)
+        ) - cfg.batch_slots * int(model.slot_state_bytes)
         # Blocks alone do not bring back a sequence whose model keeps
         # state per slot: nothing is adopted, so nothing is kept either
         # (blocks nobody may adopt only fill the arena), and a rejected
@@ -458,8 +482,8 @@ class InferenceEngine:
 
     def _fresh_cache(self, model):
         cfg = self.config
-        return model.paged_cache(cfg.num_blocks, cfg.block_size, self._mesh,
-                                 cfg.batch_slots)
+        return model.paged_cache(self._bm.num_blocks, cfg.block_size,
+                                 self._mesh, cfg.batch_slots)
 
     def _fresh_tokens(self):
         """The token vector as the programs return it: replicated under a
@@ -515,7 +539,11 @@ class InferenceEngine:
             raise ValueError(f"unknown slo_class {slo!r} "
                              "(expected 'interactive' or 'batch')")
         total = len(prompt) + max_new_tokens
-        if total > cfg.max_context or not self._bm.fits(total):
+        if total > self._max_context or not self._bm.fits(total):
+            if not self._table_width:
+                raise ValueError(
+                    f"request needs {total} token slots; the model's "
+                    f"context is {self._max_context} positions")
             raise ValueError(
                 f"request needs {total} token slots; engine caps at "
                 f"{min(cfg.max_context, self._bm.capacity * cfg.block_size)}"
@@ -919,7 +947,7 @@ class InferenceEngine:
             # Rows near the context limit shorten their round: writes
             # never pass max_context (the block table has no slots
             # there; a clipped write would corrupt the last block).
-            allow = max(0, min(k, cfg.max_context - req.processed - 1))
+            allow = max(0, min(k, self._max_context - req.processed - 1))
             if self._ensure_blocks(req, req.processed + allow + 1):
                 active.append((req, allow))
         active = [(r, a) for r, a in active
@@ -1026,8 +1054,9 @@ class InferenceEngine:
     def _block_table_rows(self, reqs) -> "np.ndarray":  # noqa: F821
         import numpy as np
 
-        cfg = self.config
-        bt = np.zeros((len(reqs), cfg.max_blocks_per_seq), np.int32)
+        bt = np.zeros((len(reqs), self._table_width), np.int32)
+        if not self._table_width:
+            return bt
         for i, req in enumerate(reqs):
             if req is None or req.done or req.state == WAITING:
                 continue
@@ -1232,7 +1261,9 @@ class InferenceEngine:
             "prefill_compiles": self._program_compiles("prefill"),
             "decode_compiles": self._program_compiles("decode"),
             "paged_attn": dict(self._paged_attn),
-            "kv": self._bm.stats(),
+            # `bytes`: what the cache holds beside the per-slot state
+            # (the paged arenas; 0 for a cache with no paged part).
+            "kv": {**self._bm.stats(), "bytes": self._kv_bytes},
             # What the model keeps per batch slot beside the paged blocks
             # (recurrent state): nothing for a model without any.
             "state": {

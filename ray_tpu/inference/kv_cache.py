@@ -186,6 +186,26 @@ class BlockManager:
         }
 
 
+class NoBlocks(BlockManager):
+    """The block manager of a cache with no paged part (a model whose
+    cache is per-slot state and nothing else): it has no blocks, a
+    sequence of any length needs none, and so `ensure` and `fits` never
+    refuse. Every table is empty; the engine's block table is zero blocks
+    wide."""
+
+    def __init__(self, block_size: int):
+        super().__init__(2, block_size)     # the smallest it builds
+        self.num_blocks = 0
+        self._free.clear()
+
+    @property
+    def capacity(self) -> int:
+        return 0
+
+    def blocks_for_tokens(self, num_tokens: int) -> int:
+        return 0
+
+
 # --------------------------------------------------------------------------- #
 # Radix prefix cache: shared-prefix KV reuse at block granularity
 # --------------------------------------------------------------------------- #

@@ -204,6 +204,93 @@ def test_the_engine_serves_a_model_that_is_no_llama(bag, case):
     assert int(engine._arenas["steps"]) == ran or case.startswith("fail_all")
 
 
+# ------------------------------- a model whose cache has no paged part
+
+
+class RunningMeanModel:
+    """No blocks at all: its cache is the sum of a slot's embeddings so
+    far, and a token's logits come from that sum over its position + 1.
+    `pageless_context` says so (docs/INFERENCE.md, finding (e)): the
+    engine is to ask for 0 blocks, hand a table 0 blocks wide, admit by
+    slots alone and bound a request by this context."""
+
+    vocab, width = 64, 8
+    prefix_restores = False
+    pageless_context = 40
+    slot_state_bytes = 4 * width
+
+    def init(self, seed):
+        rng = np.random.default_rng(seed)
+        return {"embed": rng.standard_normal(
+                    (self.vocab, self.width)).astype(np.float32),
+                "head": rng.standard_normal(
+                    (self.width, self.vocab)).astype(np.float32)}
+
+    def paged_cache(self, num_blocks, block_size, mesh=None,
+                    batch_slots=None):
+        import jax.numpy as jnp
+
+        assert num_blocks == 0 and mesh is None
+        return {"sum": jnp.zeros((batch_slots, self.width), jnp.float32)}
+
+    def paged_step(self, params, ids, cache, block_tables, row_pos,
+                   write_mask, adapters=None, slots=None, last_idx=None):
+        import jax.numpy as jnp
+
+        assert adapters is None and block_tables.shape[1] == 0
+        rows = jnp.arange(ids.shape[0]) if slots is None else slots
+        # hold before reset: a row with no live position keeps its sum
+        fresh = write_mask[:, 0] & (row_pos == 0)
+        start = jnp.where(fresh[:, None], 0.0, cache["sum"][rows])
+        x = jnp.asarray(params["embed"])[ids] * write_mask[..., None]
+        run = start[:, None, :] + jnp.cumsum(x, axis=1)
+        pos = row_pos[:, None] + jnp.arange(ids.shape[1])[None, :]
+        hidden = jnp.tanh(run / (pos[..., None] + 1.0))
+        if last_idx is not None:
+            hidden = jnp.take_along_axis(
+                hidden, last_idx[:, None, None], axis=1)[:, 0]
+        return hidden @ jnp.asarray(params["head"]), {
+            "sum": cache["sum"].at[rows].set(run[:, -1])}
+
+
+def _running_mean_tokens(params, prompt, n):
+    ids, out = list(prompt), []
+    for _ in range(n):
+        mean = params["embed"][ids].sum(0) / len(ids)
+        out.append(int(np.argmax(np.tanh(mean) @ params["head"])))
+        ids.append(out[-1])
+    return out
+
+
+def test_the_engine_serves_a_model_with_no_paged_part():
+    """More requests than slots and far more tokens than `num_blocks` x
+    `block_size` would hold: nothing is refused for blocks, nothing is
+    preempted, and a slot's next owner starts from zero."""
+    model = RunningMeanModel()
+    params = model.init(11)
+    engine = InferenceEngine(
+        EngineConfig(batch_slots=2, block_size=4, num_blocks=2,
+                     max_blocks_per_seq=1, prefill_chunk=8),
+        model=model, params=params)
+    assert list(engine._arenas) == ["sum"]
+    mix = [(_prompt(5, 3), 9), (_prompt(19, 11), 6), (_prompt(8, 29), 1),
+           (_prompt(13, 41), 12), (_prompt(2, 17), 7)]
+    reqs = [engine.add_request(p, m) for p, m in mix]
+    engine.run_until_idle()
+    for req, (prompt, n) in zip(reqs, mix):
+        assert req.state == "FINISHED"
+        assert req.generated == _running_mean_tokens(params, prompt, n)
+    engine.check_no_leaks()
+    stats = engine.stats()
+    assert stats["preemptions"] == 0
+    assert stats["prefill_compiles"] == stats["decode_compiles"] == 1
+    assert stats["kv"]["num_blocks"] == 0 and stats["kv"]["bytes"] == 0
+    assert stats["state"] == {"slots": 2, "bytes": 2 * 4 * 8, "resets": 5,
+                              "prefix_adoptions_refused": 5}
+    with pytest.raises(ValueError, match="context is 40 positions"):
+        engine.add_request(_prompt(30, 1), 11)
+
+
 # -------------------------------------------------- nothing under models/
 
 INFERENCE = os.path.join(os.path.dirname(ray_tpu.__file__), "inference")
