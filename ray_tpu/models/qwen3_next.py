@@ -213,14 +213,12 @@ class GatedAttention(nn.Module):
             k.reshape(b, s, kv, d))
         q = rope_first_dims(q, rotary, cfg.rope_theta).astype(cfg.dtype)
         k = rope_first_dims(k, rotary, cfg.rope_theta).astype(cfg.dtype)
-        # Each KV head serves h // kv query heads: repeated to the kernels'
-        # [batch, seq, heads*d] (as `models/llama.py` repeats; 64 MB a
-        # sequence each at 8k; the kernels have no GQA index map yet).
-        k = jnp.repeat(k, h // kv, axis=2).reshape(b, s, h * d)
-        v = jnp.repeat(v.reshape(b, s, kv, d), h // kv, axis=2).reshape(
-            b, s, h * d)
-        attn = flash_attention_bse((q.reshape(b, s, h * d), k, v), d,
-                                   causal=True)
+        # Each KV head serves h // kv query heads: k and v go in as they are,
+        # [batch, seq, kv*d], and the kernels read a KV head in place for
+        # its group (at d < 128, the CPU tests' widths, the entry repeats).
+        attn = flash_attention_bse(
+            (q.reshape(b, s, h * d), k.reshape(b, s, kv * d), v), d,
+            causal=True)
         gated = attn.astype(jnp.float32) * jax.nn.sigmoid(
             gate.astype(jnp.float32))
         return _dense(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(

@@ -18,7 +18,11 @@ bodies tell apart by a lane mask (no operand is ever 64 lanes wide in HBM
 or cut at lane 64 in VMEM). `flash_attention` on [batch, heads, seq,
 head_dim] is the same kernels on the free reshape [batch*heads, seq,
 head_dim], one head a column block: for callers whose q, k, v are already
-per head (ring attention's rotating chunks, GQA after its repeat). Grids
+per head (ring attention's rotating chunks, GQA after its repeat).
+`flash_attention_bse` also takes k and v of FEWER heads than q (grouped
+queries): where a column block is one head, the query head in column block
+c reads column block c // group of k and v, and dK / dV are summed over
+the group in VMEM and written once at the KV heads' width. Grids
 put batch, the column block and the output-block dim as parallel
 dimensions and stream the contraction dim as the innermost "arbitrary" dim
 with VMEM scratch accumulators.
@@ -321,21 +325,26 @@ def _column_block(e: int, d: int) -> int:
     return d if e == d else max(_STATS_LANES, d)
 
 
-def _specs(e: int, d: int, fused: bool, block_q: int, block_k: int,
-           where=lambda b, c, i, j: (b, c, i, j)):
+def _specs(e: int, d: int, fused: bool, block_q: int, block_k: int, where,
+           group: int = 1):
     """BlockSpecs of a kernel's operands on [batch, seq, width] arrays of
-    e // d heads, by name. `where` takes a grid step to (batch, column
+    e // d query heads, by name. `where` takes a grid step to (batch, column
     block, q block, k block). `fused`: q, k and v are the thirds of ONE
     [batch, seq, 3e] array, taken as three views of it with the column
-    index moved on by a third."""
+    index moved on by a third. `group` > 1: k and v are [batch, seq,
+    e // group] arrays of one KV head a column block, and the query head in
+    column block c reads (and "kv" writes) column block c // group of them:
+    a KV head is read in place by its whole group, never repeated."""
     from jax.experimental import pallas as pl
 
     lanes = _column_block(e, d)
     third = e // lanes if fused else 0
 
-    def block(rows, pick, shift=0):
+    def block(rows, pick, shift=0, share=1):
         def index(*step):
             b, c, i, j = where(*step)
+            if share > 1:
+                c = jax.lax.div(c, share)
             return b, (i, j)[pick], c + shift
         return pl.BlockSpec((1, rows, lanes), index)
 
@@ -343,12 +352,31 @@ def _specs(e: int, d: int, fused: bool, block_q: int, block_k: int,
         b, c, i, _ = where(*step)
         return b, c, 0, i
 
-    return {"q": block(block_q, 0), "k": block(block_k, 1, third),
-            "v": block(block_k, 1, 2 * third),
-            # a [batch, seq, e] array by q blocks (o, dO, dQ) / by k blocks
-            "rows": block(block_q, 0), "cols": block(block_k, 1),
+    return {"q": block(block_q, 0), "k": block(block_k, 1, third, group),
+            "v": block(block_k, 1, 2 * third, group),
+            # a [batch, seq, e] array by q blocks (o, dO, dQ); dK and dV,
+            # [batch, seq, e // group], by k blocks
+            "rows": block(block_q, 0), "kv": block(block_k, 1, 0, group),
             "stat": pl.BlockSpec((1, lanes // d, 1, block_q), stat_index),
             "lanes": lanes}
+
+
+def _live(causal: bool, block_q: int, block_k: int, n_q: int, n_k: int,
+          streams_q: bool = False):
+    """Takes the (q block, k block) of a grid step to the blocks its
+    operands are read at: its own, except that a step above the diagonal
+    (whose body runs nothing) names the streamed operand's nearest live
+    block again, the one the pipeline holds already, so that no block is
+    fetched for it (at [1,16,8192,256] a dead step's 1 MB of k and v cost
+    the forward 0.45 ms of 4.63: my chip runs, PR 44). `streams_q`: the q
+    blocks are the streamed axis (dK/dV); else the k blocks are."""
+    if not causal or n_q == n_k == 1:
+        return lambda i, j: (i, j)
+    if streams_q:
+        return lambda i, j: (
+            jnp.maximum(i, jax.lax.div(j * block_k, block_q)), j)
+    return lambda i, j: (
+        i, jnp.minimum(j, jax.lax.div((i + 1) * block_q - 1, block_k)))
 
 
 # The two wrappers are jitted on their own so that a model's layers share ONE
@@ -364,11 +392,11 @@ _STATIC = ("d", "fused", "causal", "scale", "block_q", "block_k", "tile_q",
 def _flash_forward(q, k, v, d: int, fused: bool, causal: bool, scale: float,
                    block_q: int, block_k: int, tile_q: int, tile_k: int,
                    interpret: bool = False):
-    """q, k, v [batch, seq, e] (`fused`: the same [batch, seq, 3e] array
-    three times). Returns (out [batch, seq, e], lse [batch, heads, 1,
-    seq]): the per-row logsumexp as a row per (batch, head), which is the
-    saved training residual (O(seq)) and what the backward kernels read as
-    it is."""
+    """q [batch, seq, e], k and v [batch, seq, e // group] (`fused`: the
+    same [batch, seq, 3e] array three times). Returns (out [batch, seq,
+    e], lse [batch, heads, 1, seq]): the per-row logsumexp as a row per
+    (batch, head), which is the saved training residual (O(seq)) and what
+    the backward kernels read as it is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -377,7 +405,10 @@ def _flash_forward(q, k, v, d: int, fused: bool, causal: bool, scale: float,
     e = width // 3 if fused else width
     nq = pl.cdiv(seq_q, block_q)
     nk = pl.cdiv(seq_k, block_k)
-    sp = _specs(e, d, fused, block_q, block_k)
+    live = _live(causal, block_q, block_k, nq, nk)
+    sp = _specs(e, d, fused, block_q, block_k,
+                lambda b, c, i, j: (b, c, *live(i, j)),
+                group=1 if fused else e // k.shape[2])
     lanes = sp["lanes"]
     kernel = functools.partial(_fwd_kernel, d=d, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
@@ -467,15 +498,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, d: int, scale: float,
                     causal: bool, block_q: int, block_k: int,
-                    tile_q: int, tile_k: int, n_q: int, n_k: int):
+                    tile_q: int, tile_k: int, n_q: int, n_k: int,
+                    group: int = 1):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    # The streamed axis: the q blocks, and around them the `group` query
+    # heads that read this KV head, summed into the one dK / dV it has.
+    turn = pl.program_id(3)
+    qi = turn if group == 1 else jax.lax.rem(turn, n_q)
     lanes = dk_scr.shape[1]
     heads = range(lanes // d)
 
-    @pl.when(qi == 0)
+    @pl.when(turn == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -509,7 +544,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _by_offset(causal, qi * block_q - ki * block_k, block_q, block_k, n_q,
                n_k, run)
 
-    @pl.when(qi == pl.num_programs(3) - 1)
+    @pl.when(turn == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -519,8 +554,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_backward(q, k, v, out, lse, g, d: int, fused: bool, causal: bool,
                     scale: float, block_q: int, block_k: int, tile_q: int,
                     tile_k: int, interpret: bool = False):
-    """(dq, dk, dv), each [batch, seq, e], of the operands `_flash_forward`
-    took; `out`, `g` [batch, seq, e], `lse` as it returned it."""
+    """(dq, dk, dv), each the shape of its operand, of the operands
+    `_flash_forward` took; `out`, `g` [batch, seq, e], `lse` as it returned
+    it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -529,22 +565,24 @@ def _flash_backward(q, k, v, out, lse, g, d: int, fused: bool, causal: bool,
     nq = pl.cdiv(seq_q, block_q)
     nk = pl.cdiv(seq_k, block_k)
     lanes = _column_block(e, d)
+    group = 1 if fused else e // k.shape[2]
     stats = jax.ShapeDtypeStruct((batch, e // d, 1, seq_q), jnp.float32)
-    grads = jax.ShapeDtypeStruct((batch, seq_q, e), q.dtype)
     tiles = dict(d=d, scale=scale, causal=causal, block_q=block_q,
                  block_k=block_k, tile_q=tile_q, tile_k=tile_k, n_q=nq,
                  n_k=nk)
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
-    sp = _specs(e, d, fused, block_q, block_k)
+    live = _live(causal, block_q, block_k, nq, nk)
+    sp = _specs(e, d, fused, block_q, block_k,
+                lambda b, c, i, j: (b, c, *live(i, j)), group=group)
     dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **tiles),
         grid=(batch, e // lanes, nq, nk),
         in_specs=[sp["q"], sp["k"], sp["v"], sp["rows"], sp["rows"],
                   sp["stat"]],
         out_specs=[sp["rows"], sp["stat"]],
-        out_shape=[grads, stats],
+        out_shape=[jax.ShapeDtypeStruct((batch, seq_q, e), q.dtype), stats],
         scratch_shapes=[
             pltpu.VMEM((block_q, lanes), jnp.float32),
             pltpu.VMEM((lanes // d, block_q, _STATS_LANES), jnp.float32)],
@@ -553,14 +591,26 @@ def _flash_backward(q, k, v, out, lse, g, d: int, fused: bool, causal: bool,
         name="flash_bwd_dq",
     )(q, k, v, g, out, lse)
 
-    sp = _specs(e, d, fused, block_q, block_k,
-                where=lambda b, c, j, i: (b, c, i, j))
+    # dK / dV: grid axis 1 counts the column blocks of k and v, and the
+    # streamed axis takes a KV head's `group` query heads in turn, each
+    # over all its q blocks, so that the group's sum is made in the VMEM
+    # accumulators and written once at the KV heads' width.
+    live = _live(causal, block_q, block_k, nq, nk, streams_q=True)
+    if group == 1:
+        def where(b, c, j, i):
+            return (b, c, *live(i, j))
+    else:
+        def where(b, c, j, turn):
+            return (b, c * group + jax.lax.div(turn, nq),
+                    *live(jax.lax.rem(turn, nq), j))
+    sp = _specs(e, d, fused, block_q, block_k, where, group)
+    grads = jax.ShapeDtypeStruct((batch, seq_k, e // group), k.dtype)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **tiles),
-        grid=(batch, e // lanes, nk, nq),
+        functools.partial(_bwd_dkv_kernel, **tiles, group=group),
+        grid=(batch, e // lanes // group, nk, group * nq),
         in_specs=[sp["q"], sp["k"], sp["v"], sp["rows"], sp["stat"],
                   sp["stat"]],
-        out_specs=[sp["cols"], sp["cols"]],
+        out_specs=[sp["kv"], sp["kv"]],
         out_shape=[grads, grads],
         scratch_shapes=[pltpu.VMEM((block_k, lanes), jnp.float32),
                         pltpu.VMEM((block_k, lanes), jnp.float32)],
@@ -598,13 +648,41 @@ def pick_block_sizes(seq: int, d: int, causal: bool = True) -> tuple:
     [8,16,1024,64], and 36 of 64 tiles run against 40), 256 where most
     blocks lie below it or there is no mask, and a stripe is as wide as the
     block ([1,12,8192,64]: 2,203 against 2,304 us; non-causal
-    [8,16,1024,64]: 604 against 706). Wider heads keep the blocks they had
-    (not measured), one tile each."""
+    [8,16,1024,64]: 604 against 706).
+
+    Heads of 256 take the same 1024 x 1024 block with the causal stripes
+    inside it, in tiles of 256 x 256. Measured on one v5e at
+    [1,16,8192,256] bf16 causal, the kernels alone (my chip runs, PR 44; us
+    a call, fwd / dq / dkv; k and v repeated 8x for 16 query heads unless
+    it says 2 KV heads):
+
+        (bq, bk, tq, tk)           fwd     dq    dkv
+        256, 256, 256, 256      13,918 10,667 11,833   (PR 34 to PR 43)
+        512, 512, 256, 256       6,840  6,887  8,076
+        512, 1024, 256, 128      5,485  6,522  7,236
+        1024, 512, 256, 256      5,995  5,794  7,711
+        1024, 1024, 128, 128     5,298  5,575  6,831
+        1024, 1024, 256, 128     4,640  5,600  6,913
+        1024, 1024, 256, 256     4,642  5,607  6,871
+        1024, 1024, 512, 256     4,476  5,721  7,050
+        the same at 256 / 256, 2 KV heads read in place, dK / dV summed
+        over the group in VMEM     4,632  5,595  6,696
+        and no fetch for a step above the diagonal (`_live`): the rule
+                                   4,185  5,155  6,077
+
+    A 256 x 256 block paid a grid step (and the statistics' and the
+    accumulator's round trip through VMEM) for 0.34 us of matmuls; 2048 x
+    1024 wants 19.9 MB of VMEM against the compiler's 16. dK / dV written
+    a query head and summed by XLA took 6,886 us and 0.38 ms of XLA ops
+    more. Measured at that one shape: seq 1024 and the non-causal call
+    take its tiles unmeasured, and heads of 512 keep the one-tile 128 x
+    128 blocks they had (no cell runs them, not measured)."""
     if d <= 128:
         bq = bk = 1024
         tq, tk = (128 if causal and seq <= bk else 256), 128
     elif d <= 256:
-        bq = bk = tq = tk = 256
+        bq = bk = 1024
+        tq = tk = 256
     else:
         bq = bk = tq = tk = 128
     while seq % bq and bq > 128:
@@ -618,7 +696,8 @@ def pick_block_sizes(seq: int, d: int, causal: bool = True) -> tuple:
 # flash passes append _FLASH_FIELDS to theirs
 _CALLS: collections.Counter = collections.Counter()
 _CALLS_LOCK = threading.Lock()
-_FLASH_FIELDS = ("causal", "tiles", "tiles_live", "layout", "heads_per_block")
+_FLASH_FIELDS = ("causal", "tiles", "tiles_live", "layout", "heads_per_block",
+                 "kv_heads")
 
 
 def pallas_status() -> list:
@@ -630,8 +709,10 @@ def pallas_status() -> list:
 
     Entries of the flash passes (`fwd`, `bwd`) give `shape` as the call's
     [batch, heads, seq, head_dim] whatever arrays carried it, and also say
-    `causal`, `layout` ("bse": `flash_attention_bse` on [batch, seq,
-    heads*head_dim] arrays; "bhsd": `flash_attention`), and on the Pallas
+    `causal`, `kv_heads` (the heads k and v hold: fewer than the shape's
+    where a group of query heads reads one KV head in place), `layout`
+    ("bse": `flash_attention_bse` on [batch, seq, heads*head_dim] arrays;
+    "bhsd": `flash_attention`), and on the Pallas
     path `heads_per_block` (heads in one column block of the kernels'
     operands) and `tiles`, `tiles_live`: the (tile_q, tile_k) tiles in one
     (batch, head)'s score square and those whose body the kernels run (None
@@ -700,9 +781,10 @@ def _dispatch(pass_: str, operands, d: int, fold: int, causal: bool,
         *_tile_counts(seq_q, tile_q, tile_k, causal), lanes // d)
     shape = (batch // fold, fold, seq_q, d) if fold else (
         batch, e // d, seq_q, d)
+    kv_heads = shape[1] if fused or fold else k.shape[2] // d
     key = (pass_, "reference" if reason else "pallas", reason, shape,
            jnp.dtype(q.dtype).name, block_q, block_k, causal, tiles,
-           tiles_live, "bhsd" if fold else "bse", heads_per_block)
+           tiles_live, "bhsd" if fold else "bse", heads_per_block, kv_heads)
     with _CALLS_LOCK:
         _CALLS[key] += 1
     return not reason
@@ -722,15 +804,18 @@ def _resolve(seq: int, d: int, causal, scale, block_q, block_k):
 
 def _reference(operands, d: int, causal: bool, scale):
     """`mha_reference` on the kernels' operands: [batch, seq, e] in and
-    out."""
+    out, a KV head repeated for the query heads that share it."""
     q, k, v = (operands if len(operands) == 3
                else jnp.split(operands[0], 3, axis=-1))
+    group = q.shape[2] // k.shape[2]
 
-    def apart(t):
+    def apart(t, repeat=1):
         b, s, e = t.shape
-        return t.reshape(b, s, e // d, d).transpose(0, 2, 1, 3)
+        t = t.reshape(b, s, e // d, d).transpose(0, 2, 1, 3)
+        return t if repeat == 1 else jnp.repeat(t, repeat, axis=1)
 
-    out = mha_reference(apart(q), apart(k), apart(v), causal, scale)
+    out = mha_reference(apart(q), apart(k, group), apart(v, group), causal,
+                        scale)
     b, h, s, _ = out.shape
     return out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
@@ -739,8 +824,9 @@ def _reference(operands, d: int, causal: bool, scale):
 def _flash(operands, d: int, fold: int, causal: bool, scale, block_q: int,
            block_k: int):
     """Attention over [batch, seq, heads*d] arrays. `operands` is (q, k,
-    v), or (qkv,): one [batch, seq, 3*heads*d] array whose thirds they
-    are. `fold`: heads the caller folded into `batch` (`flash_attention`),
+    v), k and v [batch, seq, kv_heads*d] with kv_heads dividing heads, or
+    (qkv,): one [batch, seq, 3*heads*d] array whose thirds they are.
+    `fold`: heads the caller folded into `batch` (`flash_attention`),
     for the records only."""
     out, _ = _flash_fwd_impl(operands, d, fold, causal, scale, block_q,
                              block_k)
@@ -802,12 +888,27 @@ def flash_attention_bse(qkv, head_dim: int, causal: bool = True,
     Returns [batch, seq, heads*head_dim]: no split, reshape or transpose
     for XLA to turn into copies of whole activations.
 
+    Grouped queries: k and v may be [batch, seq, kv_heads*head_dim] as they
+    leave their projections, every KV head serving heads // kv_heads
+    adjacent query heads. Where a column block is one head (head_dim >=
+    128) the kernels read a KV head in place for its whole group and write
+    ONE dK / dV a KV head, the group's sum made in VMEM; where a block
+    holds two heads (head_dim 64) k and v are repeated here, as a caller
+    would. Which of the two is the shape's choice, not the caller's.
+
     Dispatches to the Pallas kernels on TPU (shapes permitting; block size 0
     = auto) and the XLA reference elsewhere. Fully differentiable with a
     flash backward — training memory stays O(seq * block).
     """
-    return _flash(_operands(qkv), head_dim, 0, causal, scale, block_q,
-                  block_k)
+    operands = _operands(qkv)
+    if len(operands) == 3 and head_dim < _STATS_LANES:
+        q, k, v = operands
+        group = q.shape[2] // k.shape[2]
+        if group > 1:
+            operands = (q, *(jnp.repeat(
+                t.reshape(*t.shape[:2], -1, head_dim), group,
+                axis=2).reshape(q.shape) for t in (k, v)))
+    return _flash(operands, head_dim, 0, causal, scale, block_q, block_k)
 
 
 def flash_attention(q, k, v, causal: bool = True,

@@ -155,16 +155,26 @@ def _rows(t):
     return t.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
-def _bse_and_reference(q, k, v, causal, fused):
+def _bse_and_reference(q, k, v, causal, fused, blocks=0):
     """(out, dq, dk, dv) of `flash_attention_bse` on the [b, s, h*d] forms
     of q, k, v (`fused`: on their concatenation, one array read as three
-    views) and of mha_reference, all as [b, s, h*d] float32 arrays."""
+    views; `blocks`: explicit grid blocks) and of mha_reference, all as
+    [b, s, h*d] float32 arrays. k and v may hold fewer heads than q: the
+    entry takes them as they are, the reference their repeat, and dk, dv
+    come back at the width k and v went in at."""
     d = q.shape[-1]
+    group = q.shape[1] // k.shape[1]
 
     def bse(q, k, v):
         q, k, v = _rows(q), _rows(k), _rows(v)
         qkv = jnp.concatenate([q, k, v], axis=-1) if fused else (q, k, v)
-        return flash_attention_bse(qkv, d, causal)
+        return flash_attention_bse(qkv, d, causal, block_q=blocks,
+                                   block_k=blocks)
+
+    def reference(q, k, v):
+        if group > 1:
+            k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+        return _rows(mha_reference(q, k, v, causal=causal))
 
     def run(fn):
         def loss(q, k, v):
@@ -173,8 +183,7 @@ def _bse_and_reference(q, k, v, causal, fused):
         return [np.asarray(out, np.float32)] + [
             np.asarray(_rows(g), np.float32) for g in grads]
 
-    return run(bse), run(lambda q, k, v: _rows(
-        mha_reference(q, k, v, causal=causal)))
+    return run(bse), run(reference)
 
 
 @pytest.mark.parametrize("seq, heads, d, causal, fused", [
@@ -207,6 +216,35 @@ def test_flash_bse_parity(monkeypatch, seq, heads, d, causal, fused):
              tuple(e["shape"])) for e in attention.pallas_status()} == {
         (p, "pallas", "bse", max(1, 128 // d), (1, heads, seq, d))
         for p in ("fwd", "bwd")}
+
+
+@pytest.mark.parametrize("seq, heads, kv, d, blocks", [
+    (1024, 16, 2, 256, 0),      # the Qwen3-Next layer's heads, one block
+    (512, 16, 2, 256, 256),     # 2 x 2 blocks: dead steps, a group's turns
+    (512, 4, 2, 128, 256),
+    (256, 4, 2, 64, 0),         # two heads a column block: the entry repeats
+])
+def test_flash_bse_grouped_queries_read_a_kv_head_in_place(
+        monkeypatch, seq, heads, kv, d, blocks):
+    """k and v at [batch, seq, kv_heads*d]: value and the three gradients
+    against mha_reference on repeated k and v, with dK / dV compared at
+    the width they went in at (the group's sum is the kernel's own where a
+    column block is one head, autodiff's of the entry's repeat at d = 64)."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, _, _ = _qkv(jax.random.PRNGKey(25), b=1, h=heads, s=seq, d=d)
+    _, k, v = _qkv(jax.random.PRNGKey(26), b=1, h=kv, s=seq, d=d)
+    attention.reset_pallas_status()
+    got, want = _bse_and_reference(q, k, v, True, False, blocks)
+    assert got[2].shape == got[3].shape == (1, seq, kv * d)
+    for a, b, tol in zip(got, want, [2e-5, 5e-4, 5e-4, 5e-4]):
+        np.testing.assert_allclose(a, b, atol=tol, rtol=tol)
+    in_place = d >= 128
+    assert {(e["pass"], e["path"], tuple(e["shape"]), e["kv_heads"],
+             e["heads_per_block"]) for e in attention.pallas_status()} == {
+        (p, "pallas", (1, heads, seq, d), kv if in_place else heads,
+         max(1, 128 // d)) for p in ("fwd", "bwd")}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -345,14 +383,16 @@ def test_pallas_status_counts_the_tiles_that_run(monkeypatch):
     (4096, 128, True, (1024, 1024, 256, 128)),
     (8192, 64, True, (1024, 1024, 256, 128)),
     (8192, 128, False, (1024, 1024, 256, 128)),
-    # wider heads keep their small blocks, one tile each
-    (1024, 256, True, (256, 256, 256, 256)),
-    (4096, 256, True, (256, 256, 256, 256)),
-    (8192, 256, False, (256, 256, 256, 256)),
+    # d = 256: the same block in tiles of 256 (measured at 8192 causal,
+    # PR 44); d = 512 keeps its one-tile blocks (not measured)
+    (1024, 256, True, (1024, 1024, 256, 256)),
+    (4096, 256, True, (1024, 1024, 256, 256)),
+    (8192, 256, False, (1024, 1024, 256, 256)),
     (1024, 512, True, (128, 128, 128, 128)),
     # a sequence the big block does not divide gets the largest that does
     (1536, 64, True, (512, 512, 256, 128)),
     (384, 64, True, (128, 128, 128, 128)),
+    (1536, 256, True, (512, 512, 256, 256)),
 ])
 def test_pick_block_sizes(seq, d, causal, want):
     from ray_tpu.ops.attention import _tile_counts, pick_block_sizes

@@ -235,6 +235,66 @@ def test_flash_bse_kernels_compile_on_a_fused_projection(
         (p, "pallas", "bse", max(1, 128 // d)) for p in ("fwd", "bwd")}
 
 
+def test_flash_bse_reads_a_kv_head_in_place_for_its_group(one_chip,
+                                                          monkeypatch):
+    """The Qwen3-Next cell's softmax layer, [1, 8192, 16 x 256] queries on
+    2 KV heads, between its projections, forward and backward, at the
+    blocks the rule gives (1024 x 1024: the most VMEM any flash call
+    asks): ONE execution of each kernel (the per-layer readers multiply
+    the required work by the executions they find), k and v go in at
+    [1, 8192, 512] as the projections make them, dK / dV come out at that
+    width, and no array of the query heads' width is copied, broadcast or
+    summed to make or unmake a repeat."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    b, s, hidden, h, kv, d = 1, 8192, 2048, 16, 2, 256
+
+    def layer(x, w_q, w_k, w_v, w_out):
+        out = attention.flash_attention_bse(
+            (x @ w_q, x @ w_k, x @ w_v), d, True)
+        return out @ w_out
+
+    def fwd_bwd(x, w_q, w_k, w_v, w_out, do):
+        out, vjp = jax.vjp(layer, x, w_q, w_k, w_v, w_out)
+        return out, vjp(do)
+
+    def spec(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    attention.reset_pallas_status()
+    hlo = jax.jit(fwd_bwd).lower(
+        spec(b, s, hidden), spec(hidden, h * d), spec(hidden, kv * d),
+        spec(hidden, kv * d), spec(h * d, hidden),
+        spec(b, s, hidden)).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = {re.search(r"flash_(fwd|bwd_dq|bwd_dkv)", line).group(0): line
+               for line in calls}
+    assert len(calls) == 3 and sorted(kernels) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    narrow, wide = f"bf16[{b},{s},{kv * d}]", f"bf16[{b},{s},{h * d}]"
+    for name, line in kernels.items():
+        # q, k, v as the kernel takes them: k and v at the KV heads' width
+        took = re.findall(r"bf16\[[0-9,]*\]", line.split(
+            "operand_layout_constraints=")[1])[:3]
+        assert took == [wide, narrow, narrow], (name, took)
+    # and dK, dV leave their kernel at that width, the group's sum inside
+    assert re.findall(r"bf16\[[0-9,]*\]", kernels["flash_bwd_dkv"].split(
+        " custom-call(")[0]) == [narrow, narrow]
+    assert not re.search(
+        rf"= {re.escape(wide)}[^ ]* (copy|broadcast|reduce)\(", hlo)
+    assert {(x["pass"], x["path"], tuple(x["shape"]), x["kv_heads"],
+             x["block_q"], x["block_k"], x["tiles_live"], x["tiles"])
+            for x in attention.pallas_status()} == {
+        (p, "pallas", (b, h, s, d), kv, 1024, 1024, 528, 1024)
+        for p in ("fwd", "bwd")}
+
+
 def _gpt2_medium_step(n_layer, spec):
     """(jitted train step, its abstract arguments): GPT-2-medium at full
     width, `n_layer` layers, batch 8 x 1024, AdamW, donation, as the train

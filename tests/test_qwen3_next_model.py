@@ -361,11 +361,35 @@ def test_a_rematerialised_layer_keeps_the_bf16_projection_once_and_no_f32_convol
                         and aval.shape[-1] == 128), (aval, why)
 
 
+def how_cut(jaxpr, found=None):
+    """Every `pallas_call` of a jaxpr, inner jaxprs included, as (kernel
+    name, grid, the text of each operand's index map): how a call is cut
+    into grid steps and which block of each operand a step reads."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            mapping = eqn.params["grid_mapping"]
+            found.append((eqn.params["name"], tuple(mapping.grid), tuple(
+                str(b.index_map_jaxpr) for b in mapping.block_mappings)))
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    how_cut(inner, found)
+    return found
+
+
 def test_the_names_change_nothing_of_a_gpt2_train_step(interpret,
                                                        monkeypatch):
     """GPT-2 trains with `remat=False`: a name is the identity there. The
     step's jaxpr holds the same kernels and its outputs the same bits as
-    with `checkpoint_name` taken out of `ops/attention.py`."""
+    with `checkpoint_name` taken out of `ops/attention.py`. Nor does what
+    PR 44 gave long sequences and grouped queries reach it: one block a
+    (batch, column block), q, k and v three views of one array, every
+    index map the grid step's own indices and a constant shift (no
+    division by a group, no nearest live block), and with `_live` taken
+    out the same cut and the same bits."""
     import optax
 
     from ray_tpu.models.gpt2 import GPT2, GPT2Config
@@ -381,14 +405,62 @@ def test_the_names_change_nothing_of_a_gpt2_train_step(interpret,
 
     def step_of():
         step = make_train_step(model, opt, donate=False)
-        calls = kernel_calls(jax.make_jaxpr(step)(params, opt_state,
-                                                  batch).jaxpr)
-        return calls, step(params, opt_state, batch)
+        jaxpr = jax.make_jaxpr(step)(params, opt_state, batch).jaxpr
+        return (kernel_calls(jaxpr), how_cut(jaxpr),
+                step(params, opt_state, batch))
 
-    named_calls, named = step_of()
+    named_calls, named_cut, named = step_of()
     monkeypatch.setattr(attention, "checkpoint_name", lambda made, _: made)
-    bare_calls, bare = step_of()
+    bare_calls, bare_cut, bare = step_of()
+    monkeypatch.setattr(attention, "_live",
+                        lambda *a, **kw: lambda i, j: (i, j))
+    jax.clear_caches()       # `_live` is read inside the jitted wrappers
+    _, uncut, plain_step = step_of()
     assert named_calls == bare_calls == {
         "flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
-    for a, b in zip(jax.tree.leaves(named), jax.tree.leaves(bare)):
-        assert bool(jnp.all(a == b))
+    assert named_cut == bare_cut == uncut
+    for name, grid, maps in named_cut:
+        assert grid == (2, 1, 1, 1), name      # two heads of 64 a block
+        assert not any(op in text for text in maps
+                       for op in ("div", "rem", "min", "max")), (name, maps)
+    for other in (bare, plain_step):
+        for a, b in zip(jax.tree.leaves(named), jax.tree.leaves(other)):
+            assert bool(jnp.all(a == b))
+
+
+def test_the_attention_layer_reads_a_kv_head_as_it_read_its_repeat(
+        interpret, monkeypatch):
+    """`GatedAttention` hands the kernels k and v as `k_proj` / `v_proj`
+    make them. At a head width where a column block is one head (4 query
+    on 2 KV heads of 128) the layer's output and every gradient equal the
+    layer that repeats k and v first, to the order of a sum."""
+    from ray_tpu.models import qwen3_next
+
+    cfg = tiny(head_dim=128, hidden_size=128)
+    layer = qwen3_next.GatedAttention(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 256, cfg.hidden_size))
+    params = layer.init(jax.random.PRNGKey(0), x)
+
+    def value_and_grads():
+        attention.reset_pallas_status()
+        out, vjp = jax.vjp(layer.apply, params, x)
+        made = [out, *jax.tree.leaves(vjp(jnp.cos(out)))]
+        return made, {(e["pass"], e["path"], e["kv_heads"])
+                      for e in attention.pallas_status()}
+
+    got, status = value_and_grads()
+    assert status == {("fwd", "pallas", 2), ("bwd", "pallas", 2)}
+    entry = attention.flash_attention_bse
+
+    def repeated(qkv, d, causal=True):
+        q, k, v = qkv
+        group = q.shape[-1] // k.shape[-1]
+        k, v = (jnp.repeat(t.reshape(*t.shape[:2], -1, d), group,
+                           axis=2).reshape(q.shape) for t in (k, v))
+        return entry((q, k, v), d, causal)
+
+    monkeypatch.setattr(qwen3_next, "flash_attention_bse", repeated)
+    want, status = value_and_grads()
+    assert status == {("fwd", "pallas", 4), ("bwd", "pallas", 4)}
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and close(a, b, 1e-5)
