@@ -12,9 +12,13 @@ variable's back.
 
 from __future__ import annotations
 
+import errno
+import glob
 import os
 import time
-from typing import Dict
+from typing import Dict, List
+
+from ray_tpu.core.procutil import CHIP_GONE_BY_S
 
 GRANT_ENV = "RAY_TPU_GRANTED_TPU"
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -67,6 +71,97 @@ def granted_tpu_chips() -> int:
     return int(os.environ.get(GRANT_ENV) or 0)
 
 
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def tpu_device_nodes(dev_root: str = "/dev",
+                     sys_root: str = "/sys") -> List[str]:
+    """The device node of every local TPU chip, found without importing
+    jax, in the runtime's chip order (`TPU_VISIBLE_CHIPS=2` opened
+    `/dev/vfio/2` on a host whose PCI order is 2, 3, 1, 0: the node's
+    number, not the bus's).
+
+    A chip is a device node: `/dev/accel<N>` under the accel driver, or
+    under vfio the `/dev/vfio/<group>` node of a Google PCI function's
+    IOMMU group. Neither half alone is right: the PCI bus lists every
+    function of the board even when the VM was handed one group (a
+    one-chip v5e machine shows four), and `/dev/vfio/*` also matches the
+    `vfio` control node.
+    """
+    accels = glob.glob(os.path.join(dev_root, "accel[0-9]*"))
+    if accels:
+        return sorted(accels, key=lambda p: int(p.rpartition("accel")[2]))
+    groups = []
+    for dev in glob.glob(os.path.join(sys_root, "bus/pci/devices/*")):
+        try:
+            with open(os.path.join(dev, "vendor")) as f:
+                vendor = f.read().strip()
+            group = os.path.basename(
+                os.readlink(os.path.join(dev, "iommu_group")))
+        except OSError:
+            continue  # unreadable entry, or a function outside the IOMMU
+        if vendor == _GOOGLE_PCI_VENDOR and os.path.exists(
+                os.path.join(dev_root, "vfio", group)):
+            groups.append(int(group))
+    return [os.path.join(dev_root, "vfio", str(g)) for g in sorted(groups)]
+
+
+def _open_node(node: str) -> None:
+    os.close(os.open(node, os.O_RDWR))
+
+
+def _holder_of(node: str) -> str:
+    """Who has `node` open, as far as `/proc/*/fd` shows."""
+    for fd in glob.glob("/proc/[0-9]*/fd/*"):
+        try:
+            if os.readlink(fd) == node:
+                pid = fd.split("/")[2]
+                with open(f"/proc/{pid}/cmdline", "rb") as f:
+                    cmd = f.read().replace(b"\0", b" ").decode()[:120]
+                return f"held by pid {pid} ({cmd.strip()})"
+        except OSError:
+            continue  # gone while we looked, or not ours to read
+    return ("no process shows it among its open files: one that was "
+            "killed and is not reaped yet is still closing it")
+
+
+def wait_for_granted_chips(dev_root: str = "/dev",
+                           sys_root: str = "/sys") -> float:
+    """Before the backend starts: see that the device nodes this
+    process's grant lets it open CAN be opened. A chip has one holder at a
+    time, and the holder before (a worker that was stopped, a program that
+    was killed) keeps it until the kernel has closed its files, seconds
+    after it died; the runtime fails outright on such a chip. On EBUSY,
+    and on nothing else, wait and look again, for as long as the other end
+    waits for a chip-holder to be reaped. Returns the seconds waited: 0.0
+    in every ordinary start, and without a grant, which opens nothing."""
+    if not granted_tpu_chips():
+        return 0.0
+    nodes = tpu_device_nodes(dev_root, sys_root)
+    visible = os.environ.get("TPU_VISIBLE_CHIPS")
+    if visible:
+        nodes = [nodes[int(i)] for i in visible.split(",")
+                 if int(i) < len(nodes)]
+    t0 = time.monotonic()
+    deadline = t0 + CHIP_GONE_BY_S
+    busy = False
+    for node in nodes:
+        while True:
+            try:
+                _open_node(node)
+                break
+            except OSError as e:
+                if e.errno != errno.EBUSY:
+                    raise
+                busy = True
+                if time.monotonic() >= deadline:
+                    raise RuntimeError(
+                        f"TPU device node {node} is still busy after "
+                        f"{CHIP_GONE_BY_S:.0f} s: {_holder_of(node)}") from e
+                time.sleep(0.1)
+    return time.monotonic() - t0 if busy else 0.0
+
+
 def claim_devices() -> Dict[str, object]:
     """Start-up of a process that is about to compute with jax: enable
     the compile cache, then hold the process to its grant. A worker
@@ -77,7 +172,8 @@ def claim_devices() -> Dict[str, object]:
 
     This is also where a process's start-up timeline learns about jax:
     the call is a lifecycle span (`jax.claim_devices`, with the seconds
-    of the jax import and of the backend coming up as attributes), and
+    of the jax import, of the wait for a chip that was still being let
+    go, and of the backend coming up as attributes), and
     from here on every program's trace, lowering and compile is timed
     (`observability/compile.py`)."""
     from ray_tpu.observability import compile as _compile
@@ -89,9 +185,12 @@ def claim_devices() -> Dict[str, object]:
         _compile.install()          # imports jax
         t1 = time.monotonic()
         cache_dir = enable_compilation_cache()
-        info = device_info()        # starts the backend
+        chip_wait_s = wait_for_granted_chips()
+        span.set_attr("chip_wait_s", round(chip_wait_s, 3))
+        info = device_info()        # starts the backend, once
         span.set_attr("jax_import_s", round(t1 - t0, 3))
-        span.set_attr("backend_s", round(time.monotonic() - t1, 3))
+        span.set_attr("backend_s",
+                      round(time.monotonic() - t1 - chip_wait_s, 3))
         span.set_attr("platform", info["platform"])
         span.set_attr("n_devices", info["n_devices"])
         granted = granted_tpu_chips()

@@ -8,12 +8,12 @@ adds more raylets (in-process or subprocess) for multi-node simulation.
 
 from __future__ import annotations
 
-import glob
 import logging
 import os
 import time
 from typing import Dict, Optional
 
+from ray_tpu._jax_env import tpu_device_nodes
 from ray_tpu.core.common import CPU, TPU
 from ray_tpu.core.gcs import GcsServer
 from ray_tpu.core.raylet import Raylet
@@ -28,39 +28,14 @@ def default_session_dir() -> str:
     return path
 
 
-_GOOGLE_PCI_VENDOR = "0x1ae0"
-
-
 def detect_tpu_chips(dev_root: str = "/dev", sys_root: str = "/sys") -> int:
     """Local TPU chips this host can open, counted without importing jax
-    (the raylet process must never start a backend).
-
-    A chip is a device node: `/dev/accel<N>` under the accel driver, or
-    under vfio the `/dev/vfio/<group>` node of a Google PCI function's
-    IOMMU group. Neither half alone is right: the PCI bus lists every
-    function of the board even when the VM was handed one group (a
-    one-chip v5e machine shows four), and `/dev/vfio/*` also matches the
-    `vfio` control node.
-    """
+    (the raylet process must never start a backend): its device nodes
+    (`_jax_env.tpu_device_nodes`), unless `RAY_TPU_NUM_TPUS` says."""
     env = os.environ.get("RAY_TPU_NUM_TPUS")
     if env:
         return int(env)
-    accels = glob.glob(os.path.join(dev_root, "accel[0-9]*"))
-    if accels:
-        return len(accels)
-    chips = 0
-    for dev in glob.glob(os.path.join(sys_root, "bus/pci/devices/*")):
-        try:
-            with open(os.path.join(dev, "vendor")) as f:
-                vendor = f.read().strip()
-            group = os.path.basename(
-                os.readlink(os.path.join(dev, "iommu_group")))
-        except OSError:
-            continue  # unreadable entry, or a function outside the IOMMU
-        if vendor == _GOOGLE_PCI_VENDOR and os.path.exists(
-                os.path.join(dev_root, "vfio", group)):
-            chips += 1
-    return chips
+    return len(tpu_device_nodes(dev_root, sys_root))
 
 
 class Node:

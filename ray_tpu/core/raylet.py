@@ -44,7 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import msgpack
 
-from ray_tpu.core import serialization
+from ray_tpu.core import procutil, serialization
 from ray_tpu._jax_env import GRANT_ENV
 from ray_tpu.core.common import (
     CHIP_START_DEADLINE_FACTOR,
@@ -555,27 +555,16 @@ class WorkerPool:
             return self._workers.get(worker_id)
 
     def kill_all(self):
+        """Signal every worker, then see each one reaped: the waits
+        overlap, and stop() returns with no worker process left."""
         with self._lock:
-            handles = list(self._workers.values())
+            handles = [h for h in self._workers.values()
+                       if h.proc is not None and h.proc.poll() is None]
+        signalled = time.monotonic()
         for h in handles:
-            if h.proc is not None and h.proc.poll() is None:
-                try:
-                    h.proc.terminate()
-                except OSError:
-                    pass  # already reaped
-        deadline = time.monotonic() + 3
+            self._raylet._terminate(h)
         for h in handles:
-            if h.proc is not None:
-                try:
-                    h.proc.wait(timeout=max(0.1, deadline - time.monotonic()))
-                except subprocess.TimeoutExpired:
-                    # Not gone on SIGTERM: kill, and see it gone, so that
-                    # stop() returns with no worker process left.
-                    try:
-                        h.proc.kill()
-                        h.proc.wait(timeout=2.0)
-                    except (OSError, subprocess.TimeoutExpired):
-                        pass  # exited between wait and kill
+            self._raylet._see_reaped(h, grace_s=3.0, term_sent_at=signalled)
 
 
 # --------------------------------------------------------------------------- #
@@ -1085,6 +1074,33 @@ class Raylet:
             except OSError:
                 pass  # already reaped
 
+    @staticmethod
+    def _see_reaped(handle: WorkerHandle, grace_s: float,
+                    term_sent_at: float) -> None:
+        """The other half of `_terminate`: SIGKILL the worker if it
+        outlives `grace_s` from its SIGTERM, and wait until it is reaped.
+        A worker that was granted chips keeps them open until then, so it
+        is waited for as long as closing chips can take, and the wait is
+        on the start-up timeline (`worker.exit`) for the next holder to
+        be read against; any other worker gets the usual few seconds."""
+        gone_by_s = procutil.CHIP_GONE_BY_S if handle.tpu_chips \
+            else procutil.GONE_BY_S
+        stopped = procutil.stop_process(handle.proc, grace_s, gone_by_s,
+                                        term_sent_at)
+        if handle.tpu_chips:
+            _tracing.get_tracer().record_lifecycle(
+                "worker.exit", term_sent_at, term_sent_at + stopped.wait_s,
+                always=True, role="raylet", flush=True,
+                attrs={"pid": handle.pid,
+                       "tpu_chips": len(handle.tpu_chips),
+                       "signal": stopped.signal,
+                       "wait_s": round(stopped.wait_s, 3),
+                       "reaped": stopped.reaped})
+        if not stopped.reaped:
+            logger.warning(procutil.unreaped(
+                f"worker with {len(handle.tpu_chips)} TPU chip(s)",
+                handle.pid, gone_by_s))
+
     def _recycle(self, worker: WorkerHandle):
         """A worker finished what it was leased for. It goes back to the
         idle pool unless it holds chips: such a process keeps them open
@@ -1454,10 +1470,7 @@ class Raylet:
                     # Told to go and still here (SIGTERM is only seen
                     # between bytecodes; a thread deep in native code can
                     # hold it off): its chips stay closed until it exits.
-                    try:
-                        h.proc.kill()
-                    except OSError:
-                        pass  # exited between poll and kill
+                    self._see_reaped(h, grace_s=5.0, term_sent_at=h.died_at)
             # Long-dead handles leave the pool after a grace window so
             # worker churn cannot grow it without bound.
             self.pool.prune_dead()

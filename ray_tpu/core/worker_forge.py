@@ -68,6 +68,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import msgpack
 
+from ray_tpu.core import procutil
+
 logger = logging.getLogger(__name__)
 
 _HDR = struct.Struct("<I")
@@ -564,17 +566,9 @@ class _SharedTemplate:
             proc = self.proc
         if proc is None or proc.poll() is not None:
             return
-        try:
-            proc.terminate()
-            proc.wait(timeout=2.0)
-        except subprocess.TimeoutExpired:
-            try:
-                proc.kill()
-                proc.wait(timeout=1.0)
-            except (OSError, subprocess.TimeoutExpired):
-                pass
-        except OSError:
-            pass
+        if not procutil.stop_process(proc, grace_s=2.0).reaped:
+            logger.warning(procutil.unreaped("forge template", proc.pid,
+                                             procutil.GONE_BY_S))
 
 
 _templates_lock = threading.Lock()

@@ -14,8 +14,8 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.core import procutil
 from ray_tpu.job_submission import JobStatus
-from ray_tpu.jobs import procutil
 
 
 class JobManager:
@@ -52,7 +52,7 @@ class JobManager:
         runner.start()
         return sid
 
-    # Kill-handshake hygiene lives in jobs/procutil.py now, shared with
+    # Kill-handshake hygiene lives in core/procutil.py now, shared with
     # the per-node job agent; these shims keep the existing call sites
     # (and the direct unit tests against them) stable.
     _kill_group = staticmethod(procutil.kill_group)

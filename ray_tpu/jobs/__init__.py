@@ -3,7 +3,7 @@
 Sits between the core runtime and clients: submitted jobs live in a
 GCS-owned, checkpointed job table; per-node agents (`jobs/agent.py`,
 hosted inside each raylet) launch driver subprocesses with kill-handshake
-hygiene (`jobs/procutil.py`) and stream logs back; the raylet dispatch
+hygiene (`core/procutil.py`) and stream logs back; the raylet dispatch
 loop applies per-job fairness and rate quotas (`jobs/tenancy.py`) so a
 batch job's task storm and serve traffic share one admission model.
 
@@ -13,7 +13,6 @@ docs/JOBS.md for the submission API, the runtime_env contract,
 detached-actor lifetimes, and cleanup guarantees.
 """
 
-from ray_tpu.jobs import procutil  # noqa: F401
 from ray_tpu.jobs.agent import JobAgent  # noqa: F401
 from ray_tpu.jobs.state import (  # noqa: F401
     FAILED, RUNNING, STOPPED, SUBMITTED, SUCCEEDED, TERMINAL,

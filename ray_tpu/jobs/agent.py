@@ -10,7 +10,7 @@ Contract with the GCS (which owns the job table):
 
 - `run_job(sid, entrypoint, runtime_env)` spawns the entrypoint with the
   PR-4 kill-handshake hygiene (`start_new_session=True`, group-liveness
-  escalation from jobs/procutil.py) and returns immediately; a runner
+  escalation from core/procutil.py) and returns immediately; a runner
   thread then reports `job_started` {sid, pid}, streams stdout/stderr
   lines to `job_log_append` in batched flushes (LogStreamer cadence:
   0.25 s flush tick, bounded batch with a dropped counter — a driver
@@ -41,7 +41,7 @@ import time
 import zipfile
 from typing import Any, Callable, Dict, List, Optional
 
-from ray_tpu.jobs import procutil
+from ray_tpu.core import procutil
 
 logger = logging.getLogger(__name__)
 
