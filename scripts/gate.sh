@@ -4,7 +4,8 @@
 # kernel that could not lower on TPU — this script makes that impossible.)
 #
 # Usage: scripts/gate.sh [--full]
-#   default: full pytest + quick bench + 8-device multichip dryrun
+#   default: raylint + full pytest (slow tests too) + 8-device multichip
+#            dryrun + one benchmark cell rehearsed on the CPU
 #   --full:  additionally runs chip_smoke.py (needs a TPU; a few minutes)
 
 set -uo pipefail
@@ -52,61 +53,15 @@ step "raylint (incremental + suppression audit)" bash -c '
   fi
 '
 step "pytest tests/" python -m pytest tests/ -q
-# Seeded chaos smoke: ONE node kill under light serve load, deterministic
-# seed, <60s — zero hangs + bounded recovery asserted (exit nonzero on
-# either). The full bench_chaos (Poisson serve + training loop under the
-# whole schedule) stays a bench-only run.
-step "chaos smoke (seeded, 1 node kill)" \
-  env JAX_PLATFORMS=cpu python bench.py --chaos-smoke
-# Ingest smoke: one seeded node kill MID-SHUFFLE (the node holding the
-# most blocks), <60s — the epoch must complete with recomputed blocks
-# >= 1 (the fault destroyed state the pipeline needed) and bounded by
-# the victim's resident count, HangWatchdog-clean, zero unsealed
-# buffers (exit nonzero on any hang/unbounded-recompute/leak).
-step "ingest smoke (seeded node kill mid-shuffle)" \
-  env JAX_PLATFORMS=cpu python bench.py --ingest-smoke
-# Inference smoke: prefix-cache A/B over one seeded shared-prefix trace
-# plus spec-decode quick runs, <60s — hard asserts on ZERO recompiles
-# (prefill/decode/draft/propose/verify), ZERO leaked blocks on every
-# arm, a nonzero radix hit rate, and the target-as-draft acceptance
-# upper bound (exit nonzero on any invariant breach).
-step "inference smoke (prefix cache + spec decode)" \
-  env JAX_PLATFORMS=cpu python bench.py --inference-smoke
-# Query smoke: sort/groupby/join through the windowed shuffle on a
-# 3-node cluster, <60s — row-identity verified inline, the driver's sort
-# footprint bounded by the key sample, and the locality-routing A/B must
-# show the routed arm moving strictly fewer cross-node bytes (socket
-# path forced; exit nonzero on any invariant breach).
-step "query smoke (exchange operators + locality A/B)" \
-  env JAX_PLATFORMS=cpu python bench.py --query-smoke
-# Job-tier smoke: cold vs forge-template submit->first-task (warm must
-# be >=2x faster), 3 concurrent tenant jobs with distinct runtime envs
-# on one cluster, then the cleanup invariants — zero orphan job
-# processes via /proc cmdline scan (driver mark + cold-worker argv
-# diff) and num_unsealed 0 (exit nonzero on any breach).
-step "jobs smoke (submission plane + env forge + tenants)" \
-  env JAX_PLATFORMS=cpu python bench.py --jobs-smoke
-# Sharded smoke: pp=2 pipeline parity + seeded kill-a-stage resume, <60s —
-# hard asserts on step-for-step BITWISE parity with pp=1 (zero per-step
-# recompiles via compile counters), the 1F1B bubble fraction strictly
-# below the sequential schedule's, an ingest-fed run with bounded
-# stall_frac, and a checkpoint-gated stage kill whose elastic resharded
-# resume is bitwise-equal to the unkilled run at the same step (exit
-# nonzero on any invariant breach). Makespan speedup stays a soft flag
-# (`sharded_regressed`) — on small hosts XLA intra-op threading hands the
-# sequential schedule every core per op, so wall-clock is noise-bound.
-step "sharded smoke (pp=2 parity + kill-a-stage resume)" \
-  env JAX_PLATFORMS=cpu python bench.py --sharded-smoke
-# 100-node envelope smoke: placement at width + one seeded node kill with
-# AUTOSCALER-driven replacement, bounded — zero hangs, zero lost tasks,
-# lease-cache invalidation asserted (no stale-lease double execution).
-step "envelope100 smoke (100 nodes, autoscaled kill)" \
-  env JAX_PLATFORMS=cpu python bench.py --envelope100-smoke
 step "multichip dryrun (8 virtual devices)" \
   env JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python __graft_entry__.py 8
-
-step "bench.py --quick" python bench.py --quick
+# One cell of the benchmark end to end at rehearsal sizes, on the CPU: the
+# harness, a builder, the check against the reference and the result line.
+# It prints no metric value; numbers come from a chip (PERF.md §1).
+step "benchmark rehearsal (one cell, CPU, no numbers)" \
+  python3 benchmarks/run.py --workload train_gpt2m_1chip --seed 1 \
+  --seconds 2 --rehearsal
 if [[ "${1:-}" == "--full" ]]; then
   # The train and serve main paths on the chip: it asserts by itself that
   # every attention call compiled to the Pallas kernels, that they agree

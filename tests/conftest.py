@@ -123,8 +123,29 @@ def assert_compiles_once(source, *counters, context=None):
         assert source.get(key) == 1, (context, key, source)
 
 
+def pids_with_mark(mark: str):
+    """Pids whose /proc cmdline carries `mark`. The job leak tests put
+    the mark INSIDE the `python -c` source so it lands in the
+    grandchild's argv — a shell-comment mark dies with the sh wrapper
+    and the scan would pass vacuously. (A zombie has an empty cmdline,
+    so a killed-but-unreaped process cannot false-positive.)"""
+    pids = []
+    for pid in os.listdir("/proc"):
+        if not pid.isdigit():
+            continue
+        try:
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                cmdline = f.read()
+        except OSError:
+            continue  # exited while scanning
+        if mark.encode() in cmdline:
+            pids.append(pid)
+    return pids
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
-        "slow: long-running perf comparisons excluded from the tier-1 "
-        "budget (run explicitly or via bench.py)")
+        "slow: tests over ~10 s (multi-node recovery, long compiles), "
+        "excluded from the tier-1 budget; `pytest tests/` with no -m, as "
+        "scripts/gate.sh calls it, runs them")

@@ -307,6 +307,70 @@ def test_node_kill_chaos_with_task_load():
         cluster.shutdown()
 
 
+@pytest.mark.slow  # multi-node cluster + recovery: >10s under load
+def test_node_kill_chaos_with_serve_load():
+    """One seeded node kill while a two-replica deployment takes a
+    steady trickle of requests through its handle: the fault recovers
+    within the deadline, the executed log is the schedule, no request's
+    result parks forever, and most requests are answered."""
+    from ray_tpu import serve
+
+    ray_tpu.shutdown()
+    cluster = Cluster(initialize_head=True, head_node_args={"num_cpus": 3})
+    node_args = {"num_cpus": 2, "resources": {"churn": 2}}
+    try:
+        for _ in range(2):
+            cluster.add_node(**node_args)
+        cluster.wait_for_nodes()
+        cluster.connect()
+
+        @serve.deployment(num_replicas=2, max_concurrent_queries=32)
+        class Echo:
+            def __call__(self, payload):
+                return payload
+
+        handle = serve.run(Echo.bind())
+        assert ray_tpu.get([handle.remote(i) for i in range(8)],
+                           timeout=60) == list(range(8))
+
+        sched = ChaosSchedule(seed=20260804, kinds=("node_kill",),
+                              period_s=1.5, count=1, jitter=0.25)
+        runner = ChaosRunner(
+            cluster, sched,
+            {"node_kill": NodeKillInjector(cluster, replace=True,
+                                           node_args=node_args)},
+            recovery_deadline_s=45)
+        ok = err = 0
+        with HangWatchdog(limit_s=60) as wd:
+            with runner:
+                refs = []
+                for i in range(50):           # ~3.5 s: spans the kill
+                    time.sleep(0.07)
+                    try:
+                        refs.append(handle.remote(i))
+                    except Exception:  # noqa: BLE001 — routed into a
+                        err += 1       # replica that died mid-churn
+                for ref in refs:
+                    try:
+                        with wd.track("serve-result"):
+                            ray_tpu.get(ref, timeout=30)
+                        ok += 1
+                    except Exception:  # noqa: BLE001 — replica died mid-call
+                        err += 1
+                assert runner.wait(timeout=90)
+        runner.assert_recovered()
+        wd.assert_no_hangs()
+        assert runner.executed_signatures == sched.signatures()
+        assert runner.faults_injected == 1
+        assert ok + err == 50 and err < 25, (ok, err)
+    finally:
+        try:
+            serve.shutdown()
+        except Exception:  # noqa: BLE001 — the controller may have died
+            pass
+        cluster.shutdown()
+
+
 def test_train_gang_elastic_restart_resumes_from_checkpoint():
     """Kill a train worker mid-run: the gang aborts and restarts as a
     unit on a fresh placement group, and the loop RESUMES from the last
@@ -489,6 +553,26 @@ def test_multiplexed_replica_kill_reloads_adapters_no_leaks():
 # ---------------------------------------- task fast path in the victim set
 
 
+def _assert_marked_dups_are_retries(mark_file, n_tasks):
+    """Every one of the `n_tasks` tasks named `marked` left its
+    side-channel execution mark, and duplicate executions are
+    owner-accounted retries, never a stale-lease double push."""
+    counts: dict = {}
+    with open(mark_file) as f:
+        for line in f:
+            if line.strip():
+                counts[int(line)] = counts.get(int(line), 0) + 1
+    assert all(i in counts for i in range(n_tasks)), "a task never executed"
+    rt = ray_tpu._require_runtime()
+    retries = sum(rec.attempts for rec in rt._tasks.values()
+                  if rec.spec is not None
+                  and rec.spec.name.endswith("marked"))
+    dup = sum(c - 1 for c in counts.values() if c > 1)
+    assert dup <= retries, (
+        f"{dup} duplicate executions but only {retries} owner "
+        "retries: a stale lease double-pushed")
+
+
 @pytest.mark.slow
 def test_node_kill_invalidates_lease_cache():
     """Node death mid-push: every lease cached against the dead node's
@@ -541,24 +625,78 @@ def test_node_kill_invalidates_lease_cache():
             for leases in d._leases.values():
                 for lease in leases:
                     assert not lease.closed
-        # Duplicate executions are owner-accounted retries, never a
-        # stale-lease double push.
-        counts: dict = {}
-        with open(mark_file) as f:
-            for line in f:
-                if line.strip():
-                    idx = int(line)
-                    counts[idx] = counts.get(idx, 0) + 1
-        rt = ray_tpu._require_runtime()
-        retries = sum(rec.attempts for rec in rt._tasks.values()
-                      if rec.spec is not None
-                      and rec.spec.name.endswith("marked"))
-        dup = sum(c - 1 for c in counts.values()
-                  if c > 1)
-        assert dup <= retries, (
-            f"{dup} duplicate executions but only {retries} owner "
-            "retries: a stale lease double-pushed")
+        _assert_marked_dups_are_retries(mark_file, 60)
     finally:
+        cluster.shutdown()
+
+
+@pytest.mark.slow  # multi-node cluster + autoscaler relaunch: >10s under load
+def test_node_kill_replaced_by_autoscaler_floor():
+    """A node of the autoscaler's managed fleet is crashed under task
+    load and NOTHING in the test adds a node back: the fault counts as
+    recovered only once the autoscaler's `min_workers` floor has
+    relaunched one. Every task resolves to its own result, and a task
+    that ran twice is covered by an owner-side retry."""
+    import os
+    import tempfile
+
+    from ray_tpu.autoscaler import (
+        AutoscalerConfig,
+        LocalNodeProvider,
+        StandardAutoscaler,
+    )
+
+    ray_tpu.shutdown()
+    cluster = Cluster(initialize_head=True, head_node_args={"num_cpus": 1})
+    mark_file = os.path.join(tempfile.mkdtemp(), "floor_marks")
+    provider = LocalNodeProvider(cluster)
+    autoscaler = StandardAutoscaler(
+        cluster.gcs_address, provider,
+        AutoscalerConfig(min_workers=2, max_workers=2,
+                         node_resources={"CPU": 2},
+                         idle_timeout_s=3600.0, update_period_s=0.5))
+    try:
+        autoscaler.update()    # fill the floor now; the loop keeps it
+        autoscaler.start()
+        cluster.wait_for_nodes(timeout=60)
+        cluster.connect()
+        launches = autoscaler.num_launches
+        assert launches == 2
+
+        @ray_tpu.remote
+        def marked(path, idx):
+            time.sleep(0.05)
+            with open(path, "a") as f:
+                f.write(f"{idx}\n")
+            return idx
+
+        # Plain CPU tasks: the head and the surviving node can take what
+        # the victim drops while the floor is being refilled (a task that
+        # only the managed fleet could run has nowhere to go until then).
+        opts = {"max_retries": 8}
+        ray_tpu.get([marked.options(**opts).remote(mark_file, -1 - i)
+                     for i in range(8)], timeout=60)    # warm leases
+        sched = ChaosSchedule(seed=12, kinds=("node_kill",), period_s=1.0,
+                              count=1, jitter=0.2)
+        runner = ChaosRunner(
+            cluster, sched,
+            {"node_kill": NodeKillInjector(cluster, provider=provider)},
+            recovery_deadline_s=45)
+        with HangWatchdog(limit_s=120) as wd:
+            with runner:
+                refs = [marked.options(**opts).remote(mark_file, i)
+                        for i in range(80)]
+                results = ray_tpu.get(refs, timeout=120)
+                assert runner.wait(timeout=90)
+        runner.assert_recovered()
+        wd.assert_no_hangs()
+        assert results == list(range(80)), "task lost under node kill"
+        assert runner.faults_injected == 1
+        assert autoscaler.num_launches == launches + 1
+        assert len(provider.non_terminated_nodes()) == 2
+        _assert_marked_dups_are_retries(mark_file, 80)
+    finally:
+        autoscaler.stop()
         cluster.shutdown()
 
 

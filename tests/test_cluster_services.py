@@ -12,6 +12,7 @@ import time
 import pytest
 
 import ray_tpu
+from conftest import pids_with_mark
 
 
 # --------------------------------------------------------------------------- #
@@ -166,28 +167,6 @@ def test_job_stop_and_failure_status(ray_start_regular):
     client.close()
 
 
-def _leaked_pids(mark: str):
-    """Pids whose /proc cmdline carries `mark`. The job-manager leak
-    tests put the mark INSIDE the `python -c` source so it lands in the
-    grandchild's argv — a shell-comment mark dies with the sh wrapper
-    and the scan would pass vacuously. (A zombie has an empty cmdline,
-    so a killed-but-unreaped process cannot false-positive.)"""
-    import os
-
-    pids = []
-    for pid in os.listdir("/proc"):
-        if not pid.isdigit():
-            continue
-        try:
-            with open(f"/proc/{pid}/cmdline", "rb") as f:
-                cmdline = f.read()
-        except OSError:
-            continue  # exited while scanning
-        if mark.encode() in cmdline:
-            pids.append(pid)
-    return pids
-
-
 def test_job_manager_shutdown_kills_inflight_spawn(tmp_path):
     """shutdown() racing submit() must never orphan an entrypoint: a job
     still PENDING (spawn in flight on the runner thread) is marked
@@ -216,13 +195,13 @@ def test_job_manager_shutdown_kills_inflight_spawn(tmp_path):
     while time.monotonic() < deadline:
         details = [jm.details(s) for s in sids]
         if all(d["status"] == JobStatus.STOPPED and d["end_time"]
-               for d in details) and not _leaked_pids(mark):
+               for d in details) and not pids_with_mark(mark):
             break
         time.sleep(0.2)
     details = [jm.details(s) for s in sids]
     assert all(d["status"] == JobStatus.STOPPED for d in details), details
     assert all(d["end_time"] for d in details), details
-    assert _leaked_pids(mark) == []
+    assert pids_with_mark(mark) == []
 
 
 def test_job_manager_shutdown_waits_for_kill_delivery(tmp_path):
@@ -253,7 +232,7 @@ def test_job_manager_shutdown_waits_for_kill_delivery(tmp_path):
     jm.shutdown()
     # No grace window here: by the time shutdown() returns, the group
     # must be dead and reaped (killer joined), not merely signaled.
-    leaked = _leaked_pids(mark)
+    leaked = pids_with_mark(mark)
     assert leaked == [], f"entrypoint outlived shutdown(): {leaked}"
 
 
@@ -301,13 +280,13 @@ def test_job_manager_stop_escalates_past_sigterm_trap(tmp_path):
     while time.monotonic() < deadline:
         d = jm.details(sid)
         if d["status"] == JobStatus.STOPPED and d["end_time"] \
-                and not _leaked_pids(mark):
+                and not pids_with_mark(mark):
             break
         time.sleep(0.2)
     d = jm.details(sid)
     assert d["status"] == JobStatus.STOPPED, d
     assert d["end_time"], "runner never unparked: SIGKILL escalation missing"
-    assert _leaked_pids(mark) == [], "TERM-trapping driver outlived the SIGKILL"
+    assert pids_with_mark(mark) == [], "TERM-trapping driver outlived the SIGKILL"
 
 
 # --------------------------------------------------------------------------- #

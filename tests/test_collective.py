@@ -4,7 +4,7 @@ the object-transfer plane, GCS group membership, rank-attributed aborts.
 Most tests drive ranks as THREADS over an in-process multi-node Cluster
 (RayletTransport — full GCS control plane + chunked transfer plane, no
 worker processes); the runtime-transport path is covered with real rank
-actors, and the legacy star path through a real rendezvous actor.
+actors, joined through the reference's name (`ray_tpu.util.collective`).
 """
 
 import threading
@@ -20,7 +20,6 @@ from ray_tpu.collective import CollectiveGroup, RayletTransport
 from ray_tpu.collective.buffer import PackedTree, tree_index
 from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.exceptions import CollectiveError
-from ray_tpu.util.collective import _RendezvousActor, StarCollectiveGroup
 
 CHUNK = 256 * 1024
 STALL_S = 10.0
@@ -176,7 +175,7 @@ def test_reducescatter_remainder_raises(collective_cluster):
     results, errors = _run_ranks(collective_cluster, fn)
     assert not any(errors), errors
     assert all(results)
-    # The same validation, directly on the helper the star path shares.
+    # The same validation, directly on the helper.
     with pytest.raises(ValueError, match="not divisible"):
         tree_index({"x": np.ones((5, 2))}, rank=0, world=4)
 
@@ -316,43 +315,20 @@ def test_barrier_reusable_across_rounds(collective_cluster):
             f"straggler arrived: {crossings}")
 
 
-def test_rendezvous_actor_slots_drain_unit():
-    """Regression for the unbounded `_results`/`_events` growth: after
-    every member fetched a key, its slot is deleted."""
-    actor = _RendezvousActor(world_size=2)
-    for i in range(5):
-        key = f"ar:{i}"
-        actor.contribute(key, 0, 1.0, "sum")
-        actor.contribute(key, 1, 2.0, "sum")
-        assert actor.fetch(key, timeout=5) == actor.fetch(key, timeout=5) == 3.0
-    assert actor._results == {}
-    assert actor._events == {}
-    assert actor._fetches == {}
-    assert actor._round == {}
-
-
 # --------------------------------------------------------------------------- #
-# Runtime transport (real rank actors) + star path
+# Runtime transport (real rank actors)
 # --------------------------------------------------------------------------- #
 
 
 class _RankActor:
-    def __init__(self, rank, world, group_name="actors", backend="ring"):
+    def __init__(self, rank, world, group_name="actors"):
         from ray_tpu.util.collective import init_collective_group
 
-        self.group = init_collective_group(
-            world, rank, group_name=group_name, backend=backend)
+        self.group = init_collective_group(world, rank,
+                                           group_name=group_name)
 
     def allreduce_value(self, value):
         return self.group.allreduce(value)
-
-    def allreduce_size(self, n_bytes):
-        import numpy as _np
-
-        value = _np.full(max(1, n_bytes // 4), float(self.group.rank + 1),
-                         dtype=_np.float32)
-        self.group.allreduce(value)
-        return True
 
 
 def test_runtime_transport_actors_and_death(collective_cluster):
@@ -376,65 +352,3 @@ def test_runtime_transport_actors_and_death(collective_cluster):
     ray_tpu.kill(a1)
     with pytest.raises(CollectiveError, match="rank 1"):
         ray_tpu.get(pending, timeout=60)
-
-
-def test_star_attach_validates_world_size(collective_cluster):
-    """get_if_exists on a namesake rendezvous actor with a different
-    world_size must raise instead of deadlocking every op."""
-    cluster = collective_cluster
-    cluster.connect()
-    group = StarCollectiveGroup("star_ws", 2, 0)
-    try:
-        with pytest.raises(ValueError, match="world_size=2"):
-            StarCollectiveGroup("star_ws", 3, 1)
-    finally:
-        group.destroy()
-
-
-@pytest.mark.slow
-def test_ring_beats_star_under_modeled_links(collective_cluster):
-    """The perf story: a large allreduce between rank actors pinned one
-    per node beats the single-actor star rendezvous under a modeled
-    per-host link bandwidth (`_chunk_serve_bw_bps` serializes each node's
-    chunk egress). The star funnels O(W x bytes) through the hub's one
-    link — args in, one result object out per caller — while the ring
-    moves 2(W-1)/W x bytes per link, spread over every node."""
-    cluster = collective_cluster
-    cluster.connect()
-    GLOBAL_CONFIG._overrides.update({
-        "object_transfer_chunk_bytes": 2 << 20,
-        "object_transfer_refetch_location_chunks": 2,
-    })
-    mb = 64
-    actor_cls = ray_tpu.remote(_RankActor)
-
-    def measure(backend):
-        # num_cpus=1 on 1-CPU nodes: exactly one rank actor per node.
-        ranks = [actor_cls.options(num_cpus=1).remote(
-            r, WORLD, group_name=f"perf_{backend}", backend=backend)
-            for r in range(WORLD)]
-        # Warm-up op outside the timed window (worker spawn, connections);
-        # payloads are created rank-locally, like real gradients.
-        ray_tpu.get([a.allreduce_size.remote(1024) for a in ranks],
-                    timeout=120)
-        for raylet in cluster.raylets:
-            raylet._chunk_serve_bw_bps = 25e6
-        try:
-            t0 = time.perf_counter()
-            ray_tpu.get([a.allreduce_size.remote(mb << 20) for a in ranks],
-                        timeout=300)
-            return time.perf_counter() - t0
-        finally:
-            for raylet in cluster.raylets:
-                raylet._chunk_serve_bw_bps = 0.0
-            for a in ranks:
-                ray_tpu.kill(a)
-
-    star_s = measure("star")
-    ring_s = measure("ring")
-    # bench.py measures ~2.2x at this size (and is the acceptance gate);
-    # the 1.33x floor here absorbs CI jitter. Marked slow: ~30s of
-    # modeled-link sleeps is bench territory, not tier-1 budget.
-    assert ring_s < star_s * 0.75, (
-        f"ring ({ring_s:.2f}s) should beat the star actor "
-        f"({star_s:.2f}s) on a {mb} MiB allreduce over 25 MB/s links")

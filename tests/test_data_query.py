@@ -449,6 +449,85 @@ def test_split_coordinator_locality_never_starves(ray_start_shared):
     assert handed == 4
 
 
+class _ShardConsumer:
+    def take_blocks(self, shard, routing, n_blocks):
+        # The knob is resolved on the consumer's side (this process).
+        DataContext.get_current().locality_routing = bool(routing)
+        rows = 0
+        batches = shard.iter_batches(batch_size=2000)   # one block each
+        for _ in range(n_blocks):
+            rows += len(next(batches)["data"])
+        stats = shard.ingest_stats()
+        return rows, stats["locality_hits"], stats["locality_misses"]
+
+
+def test_locality_routing_moves_fewer_cross_node_bytes():
+    """Eight blocks, four on each of two nodes and interleaved in the
+    handout order; a consumer on each node takes four, one after the
+    other. Routed, each is handed the blocks its own node holds and the
+    raylets serve nothing over the socket; unrouted, the handout is
+    FIFO, half of each consumer's blocks live on the other node, and
+    they cross (the same-host attach is off here, or it would hide the
+    bytes being counted)."""
+    from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.data.dataset import Dataset
+
+    ray_tpu.shutdown()
+    saved = dict(GLOBAL_CONFIG._overrides)
+    GLOBAL_CONFIG._overrides["object_transfer_same_host_attach"] = False
+    cluster = Cluster(initialize_head=True, head_node_args={"num_cpus": 1})
+    for i in range(2):
+        cluster.add_node(num_cpus=2, resources={f"pin{i}": 1})
+    cluster.wait_for_nodes()
+    cluster.connect()
+    try:
+        # 512 KiB blocks: real store residency with directory entries
+        # (inline blocks live nowhere and cannot be routed to).
+        @ray_tpu.remote(num_cpus=1)
+        def make_block(tag):
+            return {"data": np.full((2000, 32), float(tag))}
+
+        refs = [make_block.options(resources={f"pin{i}": 0.01}).remote(
+            2 * j + i) for j in range(4) for i in range(2)]
+        ready, _ = ray_tpu.wait(refs, num_returns=len(refs), timeout=120)
+        assert len(ready) == len(refs)
+        consumer = ray_tpu.remote(num_cpus=1)(_ShardConsumer)
+
+        def run_arm(routing):
+            # A materialized dataset: the refs handed out ARE the pinned
+            # blocks, not the outputs of a pass over them.
+            ds = Dataset([(None, (r,)) for r in refs])
+            ds._materialized_refs = refs
+            shards = rd.DataIterator(ds).iter_shards(2, prefetch=0)
+            served0 = sum(r._chunk_bytes_served for r in cluster.raylets)
+            hits = 0
+            for i, shard in enumerate(shards):
+                actor = consumer.options(resources={f"pin{i}": 1}).remote()
+                try:
+                    rows, hit, miss = ray_tpu.get(
+                        actor.take_blocks.remote(shard, routing, 4),
+                        timeout=120)
+                finally:
+                    ray_tpu.kill(actor)
+                assert rows == 4 * 2000 and hit + miss == 4
+                hits += hit
+            served = sum(r._chunk_bytes_served
+                         for r in cluster.raylets) - served0
+            return served, hits
+
+        bytes_on, hits_on = run_arm(routing=True)
+        bytes_off, hits_off = run_arm(routing=False)
+        assert hits_on == 8
+        assert hits_off == 0          # routing off advertises no node
+        assert bytes_on < bytes_off, (bytes_on, bytes_off)
+        for r in cluster.raylets:
+            assert r.store.stats()["num_unsealed"] == 0
+    finally:
+        cluster.shutdown()
+        GLOBAL_CONFIG._overrides.clear()
+        GLOBAL_CONFIG._overrides.update(saved)
+
+
 # --------------------------------------------------------------------------- #
 # Same-host sealed-segment attach
 # --------------------------------------------------------------------------- #
@@ -569,8 +648,7 @@ def test_attach_declines_when_link_model_armed(attach_cluster):
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.slow  # multi-node cluster + recovery: >10s under load; the
-# envelope bench's query leg hard-gates the same scenario at scale
+@pytest.mark.slow  # multi-node cluster + recovery: >10s under load
 def test_sort_survives_node_kill_mid_exchange():
     """Kill the busiest worker node mid-sort (blocks past the 100 KiB
     inline threshold, so real store state dies with it). The epoch must

@@ -64,34 +64,47 @@ def test_full_shuffle_epoch_spills_not_oom():
         ray_tpu.shutdown()
 
 
-@pytest.mark.slow  # multi-node cluster + recovery: >10s under load; the
-# gate's `bench.py --ingest-smoke` hard-gates the same scenario
+@pytest.mark.slow  # multi-node cluster + recovery: >10s under load
 def test_node_death_mid_shuffle_recomputes_bounded():
-    """Chaos: kill a node mid-shuffle. The epoch completes, recomputed
-    blocks are bounded by the dead node's resident blocks (never a
-    whole-pipeline restart), and nothing hangs."""
+    """Chaos: kill a node mid-shuffle. The epoch completes, the kill
+    destroyed blocks the pipeline still needed (recomputed >= 1),
+    recomputed blocks are bounded by the dead node's resident blocks
+    (never a whole-pipeline restart), and nothing hangs."""
     import ray_tpu
     from ray_tpu.chaos import HangWatchdog
     from ray_tpu.cluster_utils import Cluster
+    from ray_tpu.data.context import DataContext
     from ray_tpu.data.streaming.lineage import core_reconstructions
 
     ray_tpu.shutdown()
     cluster = Cluster(initialize_head=True, head_node_args={"num_cpus": 2})
+    # Pipeline tasks pin to the killable nodes through the churn
+    # resource: the head is never the victim, so a kill must hit blocks
+    # the pipeline holds for the recompute bound to mean something.
     for _ in range(2):
-        cluster.add_node(num_cpus=2)
+        cluster.add_node(num_cpus=2, resources={"churn": 2})
     cluster.wait_for_nodes()
     cluster.connect()
+    ctx = DataContext.get_current()
+    old_in_flight = ctx.max_tasks_in_flight_per_op
+    # Two reduces in flight: the kill lands while most partitions still
+    # need their buckets, not after a fast exchange has finished.
+    ctx.max_tasks_in_flight_per_op = 2
     try:
-        n_parts = 8
-        ds = rd.range_tensor(4000, shape=(40,), parallelism=n_parts) \
+        # Few fat partitions: inputs ~1 MiB, buckets ~128 KiB, so every
+        # block clears the 100 KiB inline threshold and lives in a node's
+        # store (inline blocks live in the GCS and survive any kill).
+        n_rows, n_parts = 16_000, 8
+        ds = rd.range_tensor(n_rows, shape=(64,), parallelism=n_parts) \
+            .with_resources(resources={"churn": 0.25}) \
             .random_shuffle(seed=9)
         base = core_reconstructions()
         rows = 0
         killed = {}
-        with HangWatchdog(limit_s=60.0) as wd:
-            for i, batch in enumerate(ds.iter_batches(batch_size=250)):
+        with HangWatchdog(limit_s=90.0) as wd:
+            for batch in ds.iter_batches(batch_size=512):
                 rows += len(batch["data"])
-                if i == 1 and not killed:
+                if not killed:
                     # Kill the worker node holding the most blocks so the
                     # fault actually destroys state the pipeline needs.
                     victim = max(
@@ -100,22 +113,22 @@ def test_node_death_mid_shuffle_recomputes_bounded():
                     killed["resident"] = \
                         victim.store.stats()["num_objects"]
                     cluster.crash_node(victim)
+                    cluster.add_node(num_cpus=2, resources={"churn": 2})
         wd.assert_no_hangs()
-        assert rows == 4000
+        assert rows == n_rows
         recomputed = (core_reconstructions() - base) \
-            + ds._lineage.recomputed_blocks if ds._lineage else 0
-        total_blocks = killed["resident"] if killed else 0
+            + (ds._lineage.recomputed_blocks if ds._lineage else 0)
+        assert recomputed >= 1, "the kill destroyed nothing the shuffle used"
         # Bounded: no more re-executions than the victim held blocks
-        # (map buckets + reduce outputs), and certainly not a restart of
-        # every task in the pipeline.
-        assert recomputed <= max(total_blocks, 1) + n_parts, \
+        # (map buckets + reduce outputs) plus one resubmission per output
+        # partition, and certainly not a restart of every task.
+        assert recomputed <= max(killed["resident"], 1) + n_parts, \
             (recomputed, killed)
         for raylet in cluster.raylets:
             assert raylet.store.stats()["num_unsealed"] == 0
     finally:
+        ctx.max_tasks_in_flight_per_op = old_in_flight
         try:
             cluster.shutdown()
         except Exception:  # noqa: BLE001 — nodes already churned
             pass
-
-

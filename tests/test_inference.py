@@ -580,10 +580,10 @@ def test_spec_decode_lossless_and_compiles_once(tiny_llama):
 
 def test_spec_decode_target_draft_accepts_everything(tiny_llama):
     """Upper bound: with the target itself as draft every proposal is
-    accepted, so n tokens cost ceil(n / (k+1)) verify rounds."""
+    accepted, so n tokens cost ceil(n / (k+1)) verify rounds, and a draft
+    handed in compiles its three programs once like the built-in one."""
     model, params = tiny_llama
-    engine = _make_engine(tiny_llama, use_jit=False,
-                          spec_decode_draft_len=3,
+    engine = _make_engine(tiny_llama, spec_decode_draft_len=3,
                           draft_model=model, draft_params=params)
     r = engine.add_request([1, 2, 3, 4], max_new_tokens=8)
     engine.run_until_idle()
@@ -592,6 +592,8 @@ def test_spec_decode_target_draft_accepts_everything(tiny_llama):
     assert sd["accept_rate"] == 1.0
     assert sd["rounds"] == 2                       # 8 tokens, k+1 = 4 each
     assert sd["accepted_hist"][3] == 2
+    assert_compiles_once(sd, "draft_prefill_compiles", "propose_compiles",
+                         "verify_compiles")
     engine.check_no_leaks()
 
 

@@ -9,10 +9,12 @@ runtime_env job tests, adapted to the agent-based submission plane
 import os
 import sys
 import time
+import uuid
 
 import pytest
 
 import ray_tpu
+from conftest import pids_with_mark
 from ray_tpu.job_submission import JobStatus, JobSubmissionClient
 
 
@@ -75,14 +77,18 @@ def test_submit_bad_tenant_rejected(ray_start_regular):
 
 
 def test_concurrent_jobs_with_distinct_envs(ray_start_regular):
-    """Acceptance: N concurrent jobs with different runtime envs share
-    one cluster; each sees only its own env (worker isolation by job)."""
+    """Acceptance: N concurrent jobs with different runtime envs and
+    tenant tiers share one cluster; each sees only its own env (worker
+    isolation by job), and once they finish no driver process (or
+    descendant carrying the mark) is left and nothing is unsealed."""
     client = _client()
+    mark = "jobsenv_" + uuid.uuid4().hex[:12]
     sids = []
-    for i in range(3):
+    for i, tier in enumerate(["gold", "silver", "bronze"]):
         sids.append(client.submit_job(
             entrypoint=(
                 f"{sys.executable} -c \""
+                f"_MARK = '{mark}'\n"
                 "import os, ray_tpu; ray_tpu.init()\n"
                 "@ray_tpu.remote\n"
                 "def who():\n"
@@ -90,13 +96,21 @@ def test_concurrent_jobs_with_distinct_envs(ray_start_regular):
                 "got = ray_tpu.get([who.remote() for _ in range(4)])\n"
                 "print('COLORS=' + ','.join(sorted(set(got))))\n"
                 "ray_tpu.shutdown()\""),
-            runtime_env={"env_vars": {"JOB_COLOR": f"color-{i}"}}))
+            runtime_env={"env_vars": {"JOB_COLOR": f"color-{i}"}},
+            tenant={"name": f"jobsenv-{tier}", "tier": tier}))
     for i, sid in enumerate(sids):
         status = _wait_terminal(client, sid)
         logs = client.get_job_logs(sid)
         assert status == JobStatus.SUCCEEDED, \
             f"job {i} status={status} logs={logs[-800:]}"
         assert f"COLORS=color-{i}" in logs, logs[-800:]
+    store = ray_tpu._global_node.raylet.store
+    deadline = time.monotonic() + 20
+    while time.monotonic() < deadline and (
+            pids_with_mark(mark) or store.stats()["num_unsealed"]):
+        time.sleep(0.2)
+    assert pids_with_mark(mark) == [], "a job's process outlived its job"
+    assert store.stats()["num_unsealed"] == 0
     client.close()
 
 
