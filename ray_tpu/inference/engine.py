@@ -369,6 +369,12 @@ class InferenceEngine:
         from ray_tpu._jax_env import device_info
 
         self._device = device_info()
+        # Counters a model keeps ON THE DEVICE in its cache (finding (f) of
+        # docs/INFERENCE.md): copied out under the lock when stats() is
+        # asked, read on the host once the copy has landed, never waited
+        # for by a step.
+        self._counters_pending = None
+        self._counter_stats: Dict[str, Any] = {}
         self._build_programs()
         self._last_stats = self._stats_locked()
         self._last_stats_at = time.monotonic()
@@ -1210,18 +1216,49 @@ class InferenceEngine:
         (stats with a 1s timeout) must not read that as a dead replica —
         fall back to the last snapshot instead of parking, and say how
         old it is (`snapshot_age_s`, 0.0 when fresh). `steps` is fresh
-        either way."""
+        either way.
+
+        A model that keeps counters in its cache (`cache_counters`) has
+        them copied out here and read once the copy has landed: what they
+        say is as of an earlier call, and they are cumulative, so a late
+        read loses nothing."""
         if self._lock.acquire(timeout=0.2):
             try:
                 self._last_stats = self._stats_locked()
                 self._last_stats_at = time.monotonic()
+                self._copy_counters_locked()
             finally:
                 self._lock.release()
             age = 0.0
         else:
             age = time.monotonic() - self._last_stats_at
-        return {**self._last_stats, "snapshot_age_s": age,
-                "steps": self.step_stats()}
+        self._read_counters()
+        return {**self._last_stats, **self._counter_stats,
+                "snapshot_age_s": age, "steps": self.step_stats()}
+
+    def _copy_counters_locked(self) -> None:
+        """Dispatch a copy of the model's device counters (a few KB). Under
+        the lock the cache is not mid-donation; the copy is ordered behind
+        the execution in flight and outlives the next donation. One copy
+        is outstanding at a time."""
+        peek = getattr(self._model, "cache_counters", None)
+        if peek is None or self._counters_pending is not None:
+            return
+        import jax
+        import jax.numpy as jnp
+
+        self._counters_pending = jax.tree.map(jnp.copy, peek(self._arenas))
+
+    def _read_counters(self) -> None:
+        import jax
+
+        pending = self._counters_pending
+        if pending is None or not all(
+                leaf.is_ready() for leaf in jax.tree.leaves(pending)):
+            return
+        self._counters_pending = None
+        self._counter_stats = self._model.counter_stats(
+            jax.device_get(pending))
 
     def step_stats(self) -> Dict[str, Any]:
         """The step ledger as of the end of the last step: cumulative

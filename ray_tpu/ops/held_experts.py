@@ -41,9 +41,20 @@ carry no names: a policy reaches through their `cond`, `scan` and inner
 checkpoint and would keep every trip's rows. A name is the identity
 anywhere else.
 
+Two router rules, both over ALL experts in float32 at `Precision.HIGHEST`:
+`route` (softmax, top-k of the probabilities, gates renormalised to one) and
+`route_sigmoid` (sigmoid scores, top-k of `scores + bias` where the bias is
+a selection bias that no gate sees, gates = the chosen scores renormalised,
+times a scaling factor).
+
 `held_experts_status()` lists the traced calls (path, held range, tokens,
-top-k, block, blocks); the loads are outputs of the call (`counts`), so that a
-train step returns them and nothing syncs to read them.
+top-k, block, blocks); the loads are outputs of the call (`counts`), so that
+nothing syncs to read them: a train step returns them beside the loss; a
+SERVED step (`models/deepseek_v3.py`) adds them into counters it keeps on
+the device in the cache pytree it is donated, and the host reads the sums
+when `stats()` is asked, not a step. A served step also routes its idle
+rows (batch and chunk padding) to expert number `experts`, which no chip
+holds: they get no row and count for nothing.
 """
 
 from __future__ import annotations
@@ -235,6 +246,24 @@ def route(x, w_router, top_k: int):
     return probs, top_p / jnp.sum(top_p, axis=-1, keepdims=True), index
 
 
+def route_sigmoid(x, w_router, bias, top_k: int, scaling: float):
+    """The second published rule (`scoring_func` sigmoid, `topk_method`
+    noaux_tc with one group, `norm_topk_prob`): (scores [T, E] f32 over ALL
+    experts, gates [T, k] f32, index [T, k] int32). The selection is the
+    top-k of `scores + bias`; the gates are the chosen SCORES, without the
+    bias, renormalised (+1e-20) and times `scaling`. Float32 at
+    `Precision.HIGHEST` like `route`, whose lowering this leaves alone."""
+    logits = jax.lax.dot_general(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    _, index = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, index, axis=-1)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return scores, gates * scaling, index.astype(jnp.int32)
+
+
 def _block_layout(key, order, position, starts, loads, lo, block: int,
                   held: int, top_k: int):
     """Where block [lo, lo + block) of the sorted assignments puts its
@@ -284,6 +313,9 @@ def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
     the blocks really computed (== assigned: nothing is dropped; reported,
     not assumed)}."""
     first_held, count = held
+    if not (0 <= first_held and count >= 1
+            and first_held + count <= experts):
+        raise ValueError(f"held {held} does not lie inside {experts} experts")
     tokens, top_k = index.shape
     width = w_gate_up.shape[2] // 2
     block = default_block(tokens, top_k, count, experts)

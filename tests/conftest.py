@@ -149,3 +149,31 @@ def pytest_configure(config):
         "slow: tests over ~10 s (multi-node recovery, long compiles), "
         "excluded from the tier-1 budget; `pytest tests/` with no -m, as "
         "scripts/gate.sh calls it, runs them")
+
+
+# Two tests under tests/benchmarks assert where the benchmark STOOD when
+# they were written, and the benchmark's own growth falsifies them: they
+# are the benchmark's files, which only a `benchmark` PR may edit, so the
+# PR that adds the eighth cell (PR 50) marks them here, and
+# tests/benchmarks/test_bench_kanana2.py asserts what of them still holds
+# (the manifest validates, the Brumby cell reports what it reported, a
+# four-chip cell beyond the quarter is refused). Strict: the day a
+# `benchmark` PR rewords them, they pass and this entry must go.
+OUTGROWN = {
+    "tests/benchmarks/test_bench_brumby.py::"
+    "test_the_manifest_is_clean_and_gained_what_the_issue_names":
+        "asserts that the Brumby cell, its configuration and its four "
+        "metrics are the LAST entries of BENCHMARK.json; a later cell is "
+        "appended after them",
+    "tests/benchmarks/test_bench_manifest.py::"
+    "test_validate_refuses[a second four-chip cell of four-<lambda>]":
+        "with eight cells a second four-chip cell is inside the quarter "
+        "that `manifest.validate` allows",
+}
+
+
+def pytest_collection_modifyitems(config, items):
+    for item in items:
+        reason = OUTGROWN.get(item.nodeid)
+        if reason is not None:
+            item.add_marker(pytest.mark.xfail(reason=reason, strict=True))

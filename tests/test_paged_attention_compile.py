@@ -118,6 +118,90 @@ def test_mistral_decode_and_prefill_compile_with_the_kernel(one_chip,
         assert mem.alias_size_in_bytes >= cache, (b, s)
 
 
+def test_kanana2_decode_and_prefill_compile_with_the_latent_kernels(
+        one_chip, monkeypatch):
+    """The engine's two programs over `DeepseekV3.paged_step` at the
+    Kanana-2 cell's sizes: `latent_decode` / `latent_prefill` in every
+    layer and `moe_gmm` twice in every expert layer, nothing as wide as a
+    row's whole context left, the arenas and the routing record updated in
+    place, weights + arena + little else inside the chip's memory (the cell's
+    84%)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.deepseek_v3 import DeepseekV3, DeepseekV3Config
+    from ray_tpu.ops import attention, grouped_matmul
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.setattr(grouped_matmul, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "kanana-2-30b-a3b-l8-serve.json")) as f:
+        config = json.load(f)
+    eng = config["engine"]
+    cfg = DeepseekV3Config.from_published(config)
+    model = DeepseekV3(cfg)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def specs(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+    params = specs(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = specs(jax.eval_shape(lambda: model.paged_cache(
+        eng["num_blocks"], eng["block_size"], None, eng["batch_slots"])))
+    assert cache["latent"][0].shape == (2560, 128, 640)
+    assert cache["routing"].shape == (12, 2560 * 128)
+    weights, arena = nbytes(params), nbytes(cache)
+    # 3.355 GB of latent rows + 15.7 MB of routing record + the counters
+    assert 10.13e9 < weights < 10.15e9 and 3.37e9 < arena < 3.38e9
+    assert nbytes(cache["latent"]) == 3_355_443_200
+    slots, width, chunk = (eng["batch_slots"], eng["max_blocks_per_seq"],
+                           eng["prefill_chunk"])
+
+    def decode_fn(params, cache, tokens, bt, pos, wmask):
+        logits, cache = model.paged_step(params, tokens[:, None], cache, bt,
+                                         pos, wmask)
+        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
+
+    def prefill_fn(params, cache, ids, bt, pos, wmask, last_idx, slot):
+        logits, cache = model.paged_step(params, ids, cache, bt, pos, wmask,
+                                         None, slot, last_idx)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+    programs = {
+        "latent_decode": (decode_fn, slots, (
+            spec((slots,), jnp.int32), spec((slots, width), jnp.int32),
+            spec((slots,), jnp.int32), spec((slots, 1), jnp.bool_))),
+        "latent_prefill": (prefill_fn, 1, (
+            spec((1, chunk), jnp.int32), spec((1, width), jnp.int32),
+            spec((1,), jnp.int32), spec((1, chunk), jnp.bool_),
+            spec((1,), jnp.int32), spec((1,), jnp.int32)))}
+    ctx = width * eng["block_size"]
+    for kernel, (fn, rows, args) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, *args).compile()
+        hlo = compiled.as_text()
+        calls = [line for line in hlo.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert sum(bool(re.search(rf"%{kernel}[.\d]* = ", c))
+                   for c in calls) == cfg.num_hidden_layers, kernel
+        assert sum(bool(re.search(r"%moe_gmm[.\d]* = ", c))
+                   for c in calls) == 2 * cfg.n_moe_layers, kernel
+        assert f"[{rows},{ctx},{cfg.latent_page_width}]" not in hlo, kernel
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= nbytes(cache["latent"]) \
+            + nbytes(cache["routing"]), kernel
+        need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert weights + arena < need < weights + arena + 0.3e9 < HBM, (
+            kernel, need)
+        assert need > 0.25 * HBM
+
+
 def test_records_say_pallas_for_the_cells_shapes(monkeypatch):
     """The dispatch rule alone, no compiler: at the cell's shapes on
     platform `tpu` both passes go to the kernel."""
