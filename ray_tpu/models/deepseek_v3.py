@@ -45,7 +45,7 @@ directly: the same function under one fixed permutation of the r lanes
 applied to q and k alike, which no score sees. The cache holds k_r
 half-split. `published_weights` hands the columns back interleaved.
 
-Counters: the expert layers' loads (`held_expert_mlp`'s `counts`) are
+Counters: the expert layers' loads (`held_expert_forward`'s `counts`) are
 added into `cache["moe"]` on the device, decode steps and prefill chunks
 apart, and read when `stats()` is asked (`cache_counters`,
 `counter_stats`: finding (f) of docs/INFERENCE.md). Idle rows (batch and
@@ -365,7 +365,7 @@ def routed_experts(cfg, lp, n, live, held=None):
             [index.astype(jnp.float32), gates], axis=-1).T
     first, count = held or (0, experts)
     with jax.named_scope("moe_experts"):
-        y, counts = moe.held_expert_mlp(
+        y, counts = moe.held_expert_forward(
             n, gates, index, lp["w_gate_up"][first:first + count],
             lp["w_down"][first:first + count], (first, count), experts)
     return y, counts, routing
@@ -404,6 +404,7 @@ def _count(moe_counters, kind: int, per_layer):
     add = {"steps": jnp.int32(1),
            "assigned": jnp.stack([c["assigned"] for c in per_layer]),
            "placed": jnp.stack([c["placed"] for c in per_layer]),
+           "tiles": jnp.stack([c["tiles"] for c in per_layer]),
            "drew": jnp.sum(load > 0, axis=1, dtype=jnp.int32),
            "max_over_mean": jnp.max(load, axis=1).astype(jnp.float32) / mean,
            "load": load}
@@ -443,6 +444,7 @@ class DeepseekV3:
             "moe": {"steps": jnp.zeros((2,), i32),
                     "assigned": jnp.zeros((2, layers), i32),
                     "placed": jnp.zeros((2, layers), i32),
+                    "tiles": jnp.zeros((2, layers), i32),
                     "drew": jnp.zeros((2, layers), i32),
                     "max_over_mean": jnp.zeros((2, layers), jnp.float32),
                     "load": jnp.zeros((2, layers, experts), i32)}}
@@ -502,7 +504,7 @@ class DeepseekV3:
         for k, kind in enumerate(KINDS):
             steps = int(host["steps"][k])
             sums = {name: float(np.sum(host[name][k]))
-                    for name in ("assigned", "placed", "drew",
+                    for name in ("assigned", "placed", "tiles", "drew",
                                  "max_over_mean")}
             calls = max(1, steps * cfg.n_moe_layers)
             out[kind] = {

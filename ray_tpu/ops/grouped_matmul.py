@@ -4,15 +4,24 @@ experts a chip holds, forward, data gradient and weight gradient.
     out[rows of tile i] = lhs[rows of tile i] @ rhs[tile_group[i]]
 
 `lhs` [m, k] holds the rows of every group back to back, each group padded
-to whole tiles of `TILE` = 128 rows (zero rows), so that a row tile belongs
-to ONE group and the kernels are plain tiled products whose weight block is
-chosen by a scalar-prefetched table; no tile straddles two groups, so
-nothing is masked. `rhs` is [groups, k, n]. Consecutive tiles of one group
-keep the weight block's index, so a group's weights are read once a column
-block whatever its load. Every group owns at least one tile (the caller's
-layout, `held_experts.py`): the weight gradient writes each group's block
-exactly once, zeros for a group nothing was routed to, and needs no
-zero-filled buffer to accumulate into.
+to whole row tiles (zero rows), so that a row tile belongs to ONE group and
+the kernels are plain tiled products whose weight block is chosen by a
+scalar-prefetched table; no tile straddles two groups, so nothing is
+masked. `rhs` is [groups, k, n]. Consecutive tiles of one group keep the
+weight block's index, so a group's weights are read once a column block
+whatever its load.
+
+Which rule belongs to which path. TRAINED (`grouped_matmul`, a
+`custom_vjp` over all three kernels): tiles of `TILE` = 128 rows, and every
+group owns at least one (the caller's layout, `held_experts.py`): the
+weight gradient writes each group's block exactly once, zeros for a group
+nothing was routed to, and needs no zero-filled buffer to accumulate into.
+FORWARD ONLY (`grouped_matmul_forward`, `moe_gmm` alone): the row tile is
+the caller's (a multiple of 16, bf16's sublane tile), `tile_group` names
+only the groups that own a tile and the buffer's tail repeats the last of
+them, so a group nothing was routed to is never read. That layout would
+leave `moe_gmm_drhs`'s blocks of the absent groups unwritten, which is why
+the forward product has no gradient and says so when asked.
 
 Three kernels with stable names the device trace finds: `moe_gmm` (lhs @
 rhs[g]), `moe_gmm_dlhs` (dout @ rhs[g]^T) and `moe_gmm_drhs` (lhs_g^T @
@@ -54,6 +63,19 @@ def _block(n: int, want: int) -> int:
     return b
 
 
+def _column_block(k: int, n: int, tile: int) -> int:
+    """Columns of `rhs` a grid step: 512 under a 128-row tile, and as many
+    more as the row tile is narrower (the out block keeps its 64K elements)
+    while the weight block stays under 8 MB. A 16-row tile takes an
+    expert's whole [2048, 1536] or [768, 2048] block in one contiguous
+    copy, its rows are fetched once and not once a column block, and a
+    call has a third or a quarter of the grid steps (measured on the
+    layer alone, PERF.md section 6, PR 51: 4.5% of a decode step's layer,
+    10.6% of a chunk's)."""
+    room = max(512, (8 << 20) // (2 * k) // 128 * 128)
+    return _block(n, min(512 * TILE // tile, room))
+
+
 def _gmm_kernel(groups_ref, used_ref, lhs_ref, rhs_ref, out_ref, *, dims):
     from jax.experimental import pallas as pl
 
@@ -71,17 +93,19 @@ def _gmm_kernel(groups_ref, used_ref, lhs_ref, rhs_ref, out_ref, *, dims):
         out_ref[...] = jnp.zeros_like(out_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("transpose_rhs", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("transpose_rhs", "interpret", "tile"))
 def _gmm(lhs, rhs, tile_group, n_used, transpose_rhs: bool = False,
-         interpret: bool = False):
+         interpret: bool = False, tile: int = TILE):
     """lhs [m, k] @ rhs[g] ([groups, k, n], or [groups, n, k] with
-    `transpose_rhs`) -> [m, n] in lhs's dtype."""
+    `transpose_rhs`) -> [m, n] in lhs's dtype, over row tiles of `tile`
+    rows (a multiple of 16, bf16's sublane tile)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tn = _block(n, 512)
+    tn = _column_block(k, n, tile)
     if transpose_rhs:
         rhs_spec = pl.BlockSpec((1, tn, k), lambda j, i, g, u: (g[i], j, 0))
     else:
@@ -89,10 +113,10 @@ def _gmm(lhs, rhs, tile_group, n_used, transpose_rhs: bool = False,
     return pl.pallas_call(
         functools.partial(_gmm_kernel, dims=_NT if transpose_rhs else _NN),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(n // tn, m // TILE),
-            in_specs=[pl.BlockSpec((TILE, k), lambda j, i, g, u: (i, 0)),
+            num_scalar_prefetch=2, grid=(n // tn, m // tile),
+            in_specs=[pl.BlockSpec((tile, k), lambda j, i, g, u: (i, 0)),
                       rhs_spec],
-            out_specs=pl.BlockSpec((TILE, tn), lambda j, i, g, u: (i, j))),
+            out_specs=pl.BlockSpec((tile, tn), lambda j, i, g, u: (i, j))),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -187,6 +211,17 @@ def grouped_matmul(lhs, rhs, tile_group, n_used):
     the kernels' own, there is no second path to dispatch to."""
     return _gmm(lhs, rhs.astype(lhs.dtype), tile_group, n_used,
                 interpret=_interpret_here())
+
+
+def grouped_matmul_forward(lhs, rhs, tile_group, n_used, tile: int):
+    """`grouped_matmul`'s product for a step that is never differentiated:
+    `moe_gmm` alone, over row tiles of `tile` rows, and `tile_group` names
+    only the groups that own a tile (a group without one is never read).
+    No `custom_vjp`: `moe_gmm_drhs` would leave an absent group's block
+    unwritten, and a bare `pallas_call` with scalar prefetch refuses to be
+    differentiated."""
+    return _gmm(lhs, rhs.astype(lhs.dtype), tile_group, n_used,
+                interpret=_interpret_here(), tile=tile)
 
 
 def _fwd(lhs, rhs, tile_group, n_used):

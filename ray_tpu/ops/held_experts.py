@@ -16,9 +16,9 @@ exact). The worst case, every choice of every token held, is `ceil(T *
 min(k, held) / block)` blocks.
 
 Inside a block the assignments are sorted by expert, each expert's rows
-padded to whole 128-row tiles (`grouped_matmul`'s layout), the tokens'
-activations gathered into the rows, two grouped products run (gate|up
-fused, then down), and the rows summed back to their tokens. Both
+padded to whole row tiles (`grouped_matmul`'s layout; 128 rows trained),
+the tokens' activations gathered into the rows, two grouped products run
+(gate|up fused, then down), and the rows summed back to their tokens. Both
 directions of that plumbing are GATHERS, forward and backward (XLA's
 scatter on TPU is a serial loop over rows): rows <- tokens is `x[token of
 row]`, tokens <- rows is a sum over the token's k slots of `y[row of
@@ -47,9 +47,25 @@ Two router rules, both over ALL experts in float32 at `Precision.HIGHEST`:
 a selection bias that no gate sees, gates = the chosen scores renormalised,
 times a scaling factor).
 
+Two entries over one body, because their layouts' needs conflict and
+nothing in the operands tells a differentiated call from a plain one:
+`held_expert_mlp` is the TRAINED path (128-row tiles, every held expert
+owning at least one so that `moe_gmm_drhs` writes each expert's gradient
+block exactly once, the remat names above, both gradients; the right tile
+where an expert draws 160 rows). `held_expert_forward` is a SERVED step's:
+never differentiated (`jax.grad` through it raises), no names, and a layout
+of the rows the call HAS: the row tile is `serve_tile`'s by the call's
+static shapes (16 rows while even routing gives an expert less than that),
+an expert that drew no row owns NO tile, so `moe_gmm`'s weight index map
+never reaches it, and a block's buffer is `block + min(held, assignments) *
+tile` rows. At 32 rows x 6 choices over 128 held experts that is 2,304 rows
+and the ~64 experts that drew a row, where the trained layout has 16,640
+and reads all 128.
+
 `held_experts_status()` lists the traced calls (path, held range, tokens,
-top-k, block, blocks); the loads are outputs of the call (`counts`), so that
-nothing syncs to read them: a train step returns them beside the loss; a
+top-k, block, blocks, tile, rows, forward_only); the loads are outputs of
+the call (`counts`), so that nothing syncs to read them: a train step
+returns them beside the loss; a
 SERVED step (`models/deepseek_v3.py`) adds them into counters it keeps on
 the device in the cache pytree it is donated, and the host reads the sums
 when `stats()` is asked, not a step. A served step also routes its idle
@@ -70,6 +86,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.grouped_matmul import (TILE, grouped_matmul,
+                                        grouped_matmul_forward,
                                         grouped_matmul_path)
 
 _CALLS: collections.Counter = collections.Counter()
@@ -77,18 +94,22 @@ _CALLS_LOCK = threading.Lock()
 
 
 def held_experts_status() -> list:
-    """One entry per distinct traced call of `held_expert_mlp`: `path`
-    ("pallas", or "interpret" where the grouped products fell to the
-    interpreter unasked), `held` [first, count], `experts` (the router's
-    width), `tokens`, `top_k`, `block` (assignments a block), `blocks` (the
-    worst case's count; the first always runs), `tile`, and the number of
-    traced calls."""
+    """One entry per distinct traced call of `held_expert_mlp` or
+    `held_expert_forward`: `path` ("pallas", or "interpret" where the
+    grouped products fell to the interpreter unasked), `held` [first,
+    count], `experts` (the router's width), `tokens`, `top_k`, `block`
+    (assignments a block), `blocks` (the worst case's count; the first
+    always runs), `tile` (rows a row tile: 128 trained, `serve_tile`'s
+    served), `rows` (a block's static buffer), `forward_only`, and the
+    number of traced calls."""
     with _CALLS_LOCK:
         items = list(_CALLS.items())
     return [{"path": path, "held": list(held), "experts": experts,
              "tokens": tokens, "top_k": k, "block": block, "blocks": blocks,
-             "tile": TILE, "calls": n}
-            for (path, held, experts, tokens, k, block, blocks), n in items]
+             "tile": tile, "rows": rows, "forward_only": forward_only,
+             "calls": n}
+            for (path, held, experts, tokens, k, block, blocks, tile, rows,
+                 forward_only), n in items]
 
 
 def reset_held_experts_status() -> None:
@@ -107,6 +128,18 @@ def default_block(tokens: int, top_k: int, held: int, experts: int) -> int:
     even = tokens * top_k * held / experts
     worst = tokens * min(top_k, held)
     return min(-(-int(3 * even) // TILE) * TILE, -(-worst // TILE) * TILE)
+
+
+def serve_tile(tokens: int, top_k: int, experts: int) -> int:
+    """Rows a row tile of a FORWARD-ONLY call: bf16's smallest (16) while
+    even routing gives an expert less than one such tile, the trained
+    path's 128 from there on. A served step's experts draw a row or two
+    each (32 rows x 6 over 128: 1.5; a 256-token chunk: 12), and a tile is
+    what an expert with one row pays for. Measured on the layer alone at
+    those two shapes (PERF.md section 6, PR 51): 16 / 32 / 64 / 128 rows a
+    tile take 1.01 / 1.14 / 1.61 / 2.27 ms a decode step's layer and 1.75 /
+    1.79 / 2.13 / 2.86 ms a chunk's."""
+    return 16 if tokens * top_k < 16 * experts else TILE
 
 
 def _lookup(table, index):
@@ -214,6 +247,12 @@ def _named(made, name: str):
     return jax.tree.map(lambda a: checkpoint_name(a, name), made)
 
 
+def _unnamed(made, name: str):
+    """No name: a later block's arrays, and all of a forward-only call's."""
+    del name
+    return made
+
+
 @functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
 def _top_k(probs, k: int):
     """`lax.top_k` whose tangent rule reads the index under its NAME: jax's
@@ -265,26 +304,36 @@ def route_sigmoid(x, w_router, bias, top_k: int, scaling: float):
 
 
 def _block_layout(key, order, position, starts, loads, lo, block: int,
-                  held: int, top_k: int):
+                  held: int, top_k: int, tile: int, rows: int,
+                  forward_only: bool):
     """Where block [lo, lo + block) of the sorted assignments puts its
-    rows. Returns (tile_group, n_used, row_token, row_slot, slot_row,
+    rows: a buffer of `rows` in tiles of `tile`. Trained, every group owns
+    at least one tile (the weight gradient writes each group's block
+    exactly once); `forward_only`, a group without a row owns none,
+    `tile_group` names only the groups that drew one and the buffer's tail
+    repeats the last of them, so that no weight block is fetched for
+    nothing. Returns (tile_group, n_used, row_token, row_slot, slot_row,
     slots)."""
     n_slots = key.shape[0]
     tokens = n_slots // top_k
-    rows = block + held * TILE
     first = jnp.clip(starts - lo, 0, block)          # in-block start, per e
     mine = jnp.clip(starts + loads - lo, 0, block) - first
-    tiles = jnp.maximum(-(-mine // TILE), 1)         # every group >= 1 tile
+    tiles = -(-mine // tile)
+    last = held - 1
+    if forward_only:                                 # the last that drew
+        last = jnp.max(jnp.where(tiles > 0, jnp.arange(held), 0))
+    else:
+        tiles = jnp.maximum(tiles, 1)                # every group >= 1 tile
     tile_end = jnp.cumsum(tiles)
     tile_start = tile_end - tiles
     tile_group = jnp.minimum(
-        jnp.searchsorted(tile_end, jnp.arange(rows // TILE), side="right"),
-        held - 1).astype(jnp.int32)
+        jnp.searchsorted(tile_end, jnp.arange(rows // tile), side="right"),
+        last).astype(jnp.int32)
     n_used = tile_end[-1:].astype(jnp.int32)
     # rows -> assignments
     r = jnp.arange(rows)
-    e = tile_group[r // TILE]
-    rank = r - _lookup(tile_start, e) * TILE
+    e = tile_group[r // tile]
+    rank = r - _lookup(tile_start, e) * tile
     real = rank < _lookup(mine, e)
     at = jnp.clip(lo + _lookup(first, e) + rank, 0, n_slots - 1)
     slot = jnp.where(real, order[at], n_slots)
@@ -293,7 +342,7 @@ def _block_layout(key, order, position, starts, loads, lo, block: int,
     # assignments -> rows
     rank = position - lo - _lookup(first, jnp.minimum(key, held - 1))
     inside = (key < held) & (position >= lo) & (position < lo + block)
-    row = _lookup(tile_start, jnp.minimum(key, held - 1)) * TILE + rank
+    row = _lookup(tile_start, jnp.minimum(key, held - 1)) * tile + rank
     slot_row = jnp.where(inside, row, rows).reshape(tokens, top_k).astype(
         jnp.int32)
     return (tile_group, n_used, row_token, row_slot, slot_row,
@@ -301,7 +350,9 @@ def _block_layout(key, order, position, starts, loads, lo, block: int,
 
 
 def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
-    """The held experts' part of a routed SwiGLU layer.
+    """The held experts' part of a routed SwiGLU layer, TRAINED: 128-row
+    tiles, every held expert owning one (module docstring), the names a
+    surrounding remat keeps, both gradients.
 
     x [T, D] (bf16), gates [T, k] f32 and index [T, k] int32 from `route`
     (over all `experts`), w_gate_up [count, D, 2F] (an expert's gate
@@ -312,6 +363,25 @@ def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
     each held expert received, "assigned": their sum, "placed": the rows
     the blocks really computed (== assigned: nothing is dropped; reported,
     not assumed)}."""
+    return _held_experts(x, gates, index, w_gate_up, w_down, held, experts,
+                         forward_only=False)
+
+
+def held_expert_forward(x, gates, index, w_gate_up, w_down, held,
+                        experts: int):
+    """`held_expert_mlp` for a step that is never differentiated (a served
+    step): the same arguments, the same sums, and counts gains "tiles", the
+    row tiles the products ran. Its layout is the rows the call HAS: the
+    row tile is `serve_tile`'s by the call's static shapes, an expert that
+    drew no row owns no tile (its weights are never read), and the buffer
+    is `block + min(held, assignments) * tile` rows. No remat names; the
+    products are `moe_gmm` alone, so `jax.grad` through it raises."""
+    return _held_experts(x, gates, index, w_gate_up, w_down, held, experts,
+                         forward_only=True)
+
+
+def _held_experts(x, gates, index, w_gate_up, w_down, held, experts: int,
+                  forward_only: bool):
     first_held, count = held
     if not (0 <= first_held and count >= 1
             and first_held + count <= experts):
@@ -321,9 +391,20 @@ def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
     block = default_block(tokens, top_k, count, experts)
     always = slots_always(tokens, top_k, count, experts)
     blocks = -(-tokens * min(top_k, count) // block)
+    # A block's buffer: its assignments and a tile of padding for every
+    # expert that can own one there (trained: each of them; forward only:
+    # no more than there are assignments).
+    if forward_only:
+        tile = serve_tile(tokens, top_k, experts)
+        buffer = block + min(count, tokens * min(top_k, count)) * tile
+        product = functools.partial(grouped_matmul_forward, tile=tile)
+        named = _unnamed
+    else:
+        tile, buffer = TILE, block + count * TILE
+        product, named = grouped_matmul, _named
     with _CALLS_LOCK:
         _CALLS[(grouped_matmul_path(), (first_held, count), experts, tokens,
-                top_k, block, blocks)] += 1
+                top_k, block, blocks, tile, buffer, forward_only)] += 1
 
     local = index - first_held
     key = jnp.where((local >= 0) & (local < count), local,
@@ -332,32 +413,34 @@ def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
     iota = jnp.arange(n_slots, dtype=jnp.int32)
     _, order = jax.lax.sort((key, iota), num_keys=1)     # sorted -> slot
     _, position = jax.lax.sort((order, iota), num_keys=1)  # slot -> sorted
-    order, position = _named((order, position), "moe_plan")
+    order, position = named((order, position), "moe_plan")
     loads = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
     starts = jnp.cumsum(loads) - loads
     assigned = jnp.sum(loads)
 
-    def one_block(lo, name=lambda made, _: made):
+    def one_block(lo, name=_unnamed):
         tile_group, n_used, row_token, row_slot, slot_row, slots = name(
             _block_layout(key, order, position, starts, loads, lo, block,
-                          count, top_k), "moe_plan")
+                          count, top_k, tile, buffer, forward_only),
+            "moe_plan")
         rows = _rows_from_tokens(x, row_token, slots, always)
-        h = name(grouped_matmul(rows, w_gate_up, tile_group, n_used),
-                 "moe_h")
+        h = name(product(rows, w_gate_up, tile_group, n_used), "moe_h")
         act = (jax.nn.silu(h[:, :width].astype(jnp.float32))
                * h[:, width:].astype(jnp.float32)).astype(x.dtype)
-        y = name(grouped_matmul(act, w_down, tile_group, n_used), "moe_y")
-        placed = jnp.sum(row_slot < n_slots, dtype=jnp.int32)
+        y = name(product(act, w_down, tile_group, n_used), "moe_y")
+        tally = {"placed": jnp.sum(row_slot < n_slots, dtype=jnp.int32)}
+        if forward_only:
+            tally["tiles"] = n_used[0]
         return _tokens_from_rows(y, gates, row_slot, slot_row, slots,
-                                 always), placed
+                                 always), tally
 
     # The first block's layout and products go by name; the later blocks'
     # do not (a policy reaches through the `cond`, the `scan` and its
     # checkpoint, and would keep every trip's).
-    out, placed = one_block(jnp.int32(0), _named)
+    out, tally = one_block(jnp.int32(0), named)
     if blocks > 1:
-        zero = (jnp.zeros_like(out), jnp.int32(0))
+        zero = (jnp.zeros_like(out), {k: jnp.int32(0) for k in tally})
 
         def later_blocks():
             @jax.checkpoint
@@ -371,8 +454,8 @@ def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
                                 jnp.arange(1, blocks, dtype=jnp.int32))[0]
 
         more = jax.lax.cond(assigned > block, later_blocks, lambda: zero)
-        out, placed = out + more[0], placed + more[1]
-    return out, {"load": loads, "assigned": assigned, "placed": placed}
+        out, tally = jax.tree.map(jnp.add, (out, tally), more)
+    return out, {"load": loads, "assigned": assigned, **tally}
 
 
 def load_balance_loss(probs, index, experts: int):

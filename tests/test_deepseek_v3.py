@@ -310,9 +310,19 @@ def test_the_expert_layers_counters_reach_stats(tiny, served):
     assert first["prefill"]["steps"] == 1 + 1 + 3
     assert first["decode"]["assigned"] == (8 + 7 + 5) * per_token
     for kind in ("decode", "prefill"):
+        assert {"steps", "assigned", "placed", "tiles", "drew",
+                "max_over_mean", "assignments_per_step",
+                "experts_drawn_per_step", "load_max_over_mean"} \
+            <= set(first[kind])
         assert first[kind]["placed"] == first[kind]["assigned"]
         assert 1.0 <= first[kind]["load_max_over_mean"] <= 8.0
         assert 0 < first[kind]["experts_drawn_per_step"] <= 8
+        # an expert that drew a row ran a tile, and one that drew none did
+        # not: at most a tile for each assignment
+        assert first[kind]["drew"] <= first[kind]["tiles"] \
+            <= first[kind]["assigned"]
+    # a decode step's experts draw a row or two: one 16-row tile each
+    assert first["decode"]["tiles"] == first["decode"]["drew"]
     assert sum(first["load"]) == first["decode"]["assigned"] \
         + first["prefill"]["assigned"]
     # after fail_all: only what the rebuilt cache saw

@@ -193,6 +193,14 @@ def test_kanana2_decode_and_prefill_compile_with_the_latent_kernels(
         assert sum(bool(re.search(r"%moe_gmm[.\d]* = ", c))
                    for c in calls) == 2 * cfg.n_moe_layers, kernel
         assert f"[{rows},{ctx},{cfg.latent_page_width}]" not in hlo, kernel
+        # the forward-only layout: a block's buffer of 16-row tiles for the
+        # experts that can draw a row, not a 128-row tile for each of 128
+        tokens = rows * args[0].shape[-1] if kernel == "latent_prefill" \
+            else rows
+        buffer, trained = {32: (2304, 16640), 256: (3584, 17920)}[tokens]
+        assert all(f"bf16[{buffer}," in c for c in calls
+                   if re.search(r"%moe_gmm[.\d]* = ", c)), kernel
+        assert f"[{trained}" not in hlo, kernel
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= nbytes(cache["latent"]) \
             + nbytes(cache["routing"]), kernel

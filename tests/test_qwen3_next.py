@@ -368,26 +368,41 @@ def test_status_lists_the_four_passes_with_path_and_reason(interpret, d,
         assert c["chunks_abreast"] is None
 
 
-def test_grouped_matmul_and_both_gradients():
-    groups, k, n = 3, 256, 384
-    tile_group = jnp.array([0, 0, 1, 2, 2, 2], jnp.int32)
+# (row tile, forward only): the trained product and both its gradients; the
+# served step's product at the smallest bf16 tile and at the trained one,
+# where two of five groups own NO tile and the tail repeats the last that
+# does: their weights are NaN, and nothing reads them.
+@pytest.mark.parametrize("tile,forward_only", [(128, False), (16, True),
+                                               (128, True)])
+def test_grouped_matmul_and_both_gradients(tile, forward_only):
+    k, n = 256, 384
+    groups = 5 if forward_only else 3
+    tile_group = jnp.array([0, 0, 2, 4, 4, 4] if forward_only
+                           else [0, 0, 1, 2, 2, 2], jnp.int32)
     used = jnp.array([5], jnp.int32)          # the sixth tile is the tail
-    m = 6 * gm.TILE
+    m = 6 * tile
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     lhs = jax.random.normal(ks[0], (m, k)).astype(jnp.bfloat16)
-    lhs = lhs.at[5 * gm.TILE:].set(0)
+    lhs = lhs.at[5 * tile:].set(0)
     rhs = (0.1 * jax.random.normal(ks[1], (groups, k, n))).astype(
         jnp.bfloat16).astype(jnp.float32)
     do = jax.random.normal(ks[2], (m, n)).astype(jnp.bfloat16)
-    live = (jnp.arange(m) < 5 * gm.TILE)[:, None]
+    live = (jnp.arange(m) < 5 * tile)[:, None]
 
     def dense(lhs, rhs):
-        w = rhs[jnp.repeat(tile_group, gm.TILE)]
+        w = rhs[jnp.repeat(tile_group, tile)]
         return jnp.einsum("mk,mkn->mn", lhs.astype(jnp.float32), w) * live
 
-    out = gm.grouped_matmul(lhs, rhs, tile_group, used)
+    if forward_only:
+        absent = rhs.at[jnp.array([1, 3])].set(jnp.nan)
+        out = gm.grouped_matmul_forward(lhs, absent, tile_group, used, tile)
+    else:
+        assert tile == gm.TILE
+        out = gm.grouped_matmul(lhs, rhs, tile_group, used)
     assert out.dtype == jnp.bfloat16 and close(out, dense(lhs, rhs), 1e-2)
-    assert not np.asarray(out[5 * gm.TILE:]).any()
+    assert not np.asarray(out[5 * tile:]).any()
+    if forward_only:
+        return
     f32 = jnp.float32
     got = jax.grad(lambda l, r: jnp.sum(
         gm.grouped_matmul(l, r, tile_group, used).astype(f32)
@@ -412,11 +427,10 @@ def expert_problem(tokens=300, d=64, width=32, experts=16, skew=0.0):
                                                              (tokens, d))
 
 
-def dense_experts(x, w_router, w_gate_up, w_down, held, top_k):
+def dense_routed(x, gates, index, w_gate_up, w_down, held):
     """Every token through every held expert, masked by its gate."""
     first, count = held
     width = w_down.shape[1]
-    _, gates, index = he.route(x, w_router, top_k)
     bf = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
     out = jnp.zeros_like(x)
     for e in range(first, first + count):
@@ -424,6 +438,11 @@ def dense_experts(x, w_router, w_gate_up, w_down, held, top_k):
         y = (jax.nn.silu(h[:, :width]) * h[:, width:]) @ bf(w_down[e])
         out = out + jnp.sum(jnp.where(index == e, gates, 0), -1)[:, None] * y
     return out
+
+
+def dense_experts(x, w_router, w_gate_up, w_down, held, top_k):
+    _, gates, index = he.route(x, w_router, top_k)
+    return dense_routed(x, gates, index, w_gate_up, w_down, held)
 
 
 def held_part(x, w_router, w_gate_up, w_down, held, top_k):
@@ -435,13 +454,32 @@ def held_part(x, w_router, w_gate_up, w_down, held, top_k):
         w_router.shape[1])
 
 
+SERVED_TOKENS = {"decode": 32, "chunk": 256}     # the Kanana-2 cell's shapes
+
+
+def served_routing(index, experts, routing):
+    """The router's choice bent to a served step's hard cases: `few`, nine
+    experts of 128 draw every row and the tail of the rows is idle;
+    `one`, expert 5 draws every row; `idle`, every row is padding."""
+    if routing == "few":
+        index = jnp.array([3, 40, 41, 42, 90, 100, 101, 126, 127])[index % 9]
+        return index.at[-index.shape[0] // 3:].set(experts)
+    return jnp.full_like(index, 5 if routing == "one" else experts)
+
+
 # (held, block): one block; a block so small that the later blocks run;
-# every expert held
-@pytest.mark.parametrize("held,block", [((4, 4), 0), ((4, 4), 128),
-                                        ((0, 16), 256)])
-def test_held_experts_match_dense_arithmetic(held, block, monkeypatch):
+# every expert held. (program, routing): the forward-only entry at the
+# served cell's two shapes, 6 choices over 128 experts at small widths.
+@pytest.mark.parametrize("held,block,served", [
+    ((4, 4), 0, None), ((4, 4), 128, None), ((0, 16), 256, None),
+    *(((0, 128), 0, (program, routing)) for program in SERVED_TOKENS
+      for routing in ("few", "one", "idle"))])
+def test_held_experts_match_dense_arithmetic(held, block, served,
+                                             monkeypatch):
     if block:
         monkeypatch.setattr(he, "default_block", lambda *sizes: block)
+    if served:
+        return served_matches_dense_and_trained(*served)
     x, w_router, w_gate_up, w_down, do = expert_problem()
     args = (x, w_router, w_gate_up, w_down)
     got, counts = held_part(*args, held, 4)
@@ -455,6 +493,51 @@ def test_held_experts_match_dense_arithmetic(held, block, monkeypatch):
                      argnums=range(4))(*args)
     for name, a, b in zip(("x", "router", "gate_up", "down"), grads, wants):
         assert close(a, b, 3e-2), name
+
+
+def served_matches_dense_and_trained(program, routing):
+    experts, top_k, tokens = 128, 6, SERVED_TOKENS[program]
+    x, w_router, w_gate_up, w_down, _ = expert_problem(
+        tokens=tokens, d=32, width=16, experts=experts)
+    _, gates, index = he.route(x, w_router, top_k)
+    index = served_routing(index, experts, routing)
+    call = (x.astype(jnp.bfloat16), gates, index, w_gate_up, w_down,
+            (0, experts), experts)
+    he.reset_held_experts_status()
+    got, counts = he.held_expert_forward(*call)
+    (line,) = he.held_experts_status()
+    trained, trained_counts = he.held_expert_mlp(*call)
+    want = dense_routed(x, gates, index, w_gate_up, w_down, (0, experts))
+    # the same sums in the same order as the trained path's forward
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(trained))
+    assert close(got, want, 2e-2) if routing != "idle" \
+        else not np.asarray(got).any()
+    live = int(jnp.sum(index < experts))
+    drew = int(jnp.sum(counts["load"] > 0))
+    assert int(counts["placed"]) == int(counts["assigned"]) == live \
+        == int(trained_counts["placed"])
+    assert drew == {"few": 9, "one": 1, "idle": 0}[routing]
+    # an expert without a row has no tile; one with rows has what they fill
+    assert int(counts["tiles"]) == int(jnp.sum(-(-counts["load"] // 16)))
+    assert drew <= int(counts["tiles"]) <= line["rows"] // line["tile"]
+    assert "tiles" not in trained_counts
+    assert (line["tile"], line["blocks"], line["forward_only"]) \
+        == (16, 1, True)
+
+
+def test_the_forward_only_entry_has_no_gradient():
+    """A served step never differentiates; a caller that tries is told so
+    and is not handed a weight gradient with unwritten blocks."""
+    x, w_router, w_gate_up, w_down, _ = expert_problem(tokens=32)
+    _, gates, index = he.route(x, w_router, 4)
+
+    def part(x, w_gate_up):
+        return jnp.sum(he.held_expert_forward(
+            x, gates, index, w_gate_up, w_down, (0, 16), 16)[0])
+
+    for argnum in (0, 1):
+        with pytest.raises(NotImplementedError):
+            jax.grad(part, argnums=argnum)(x.astype(jnp.bfloat16), w_gate_up)
 
 
 # Under a checkpoint whose policy keeps the expert layer's names, only the
@@ -545,16 +628,48 @@ def test_slots_always_by_the_rule():
     assert 1 <= he.slots_always(8, 10, 1, 512) <= 2
 
 
-def test_default_block_and_the_status_line():
+# The status line of a traced call, by path: the fifth cell's trained step
+# (8,192 tokens, top-10, 32 held of 512) reads what it read before the
+# served path had a layout of its own; the Kanana-2 cell's decode step and
+# prefill chunk read the served rule's tile and rows.
+@pytest.mark.parametrize("entry,tokens,top_k,held,experts,want", [
+    ("held_expert_mlp", 8192, 10, (0, 32), 512,
+     dict(tile=128, block=15360, blocks=6, rows=15360 + 32 * 128)),
+    ("held_expert_mlp", 32, 6, (0, 128), 128,
+     dict(tile=128, block=256, blocks=1, rows=16640)),
+    ("held_expert_forward", 32, 6, (0, 128), 128,
+     dict(tile=16, block=256, blocks=1, rows=2304)),
+    ("held_expert_forward", 256, 6, (0, 128), 128,
+     dict(tile=16, block=1536, blocks=1, rows=3584)),
+    # 8 held of 128: fewer owners than assignments, two blocks
+    ("held_expert_forward", 256, 6, (8, 8), 128,
+     dict(tile=16, block=384, blocks=4, rows=384 + 8 * 16)),
+    # an expert's even share reaches a tile: the trained path's 128
+    ("held_expert_forward", 512, 4, (0, 16), 16,
+     dict(tile=128, block=2048, blocks=1, rows=2048 + 16 * 128))])
+def test_default_block_and_the_status_line(entry, tokens, top_k, held,
+                                           experts, want):
     # 8192 x 10 x 32 / 512 = 5120 assignments at even routing; 3 x that
     assert he.default_block(8192, 10, 32, 512) == 15360
     assert he.default_block(64, 4, 1, 16) == 128    # whole tiles
     assert he.default_block(8, 4, 16, 16) == 128    # never past the worst
-    x, w_router, w_gate_up, w_down, _ = expert_problem()
+    assert he.serve_tile(32, 6, 128) == he.serve_tile(256, 6, 128) == 16
+    assert he.serve_tile(512, 4, 16) == gm.TILE == 128
+    d, width, count = 64, 32, held[1]
+    shape = jax.ShapeDtypeStruct
     he.reset_held_experts_status()
-    held_part(x, w_router, w_gate_up, w_down, (4, 4), 4)
+    jax.eval_shape(                       # traced, and nothing run
+        lambda *a: getattr(he, entry)(*a, held, experts),
+        shape((tokens, d), jnp.bfloat16), shape((tokens, top_k), jnp.float32),
+        shape((tokens, top_k), jnp.int32),
+        shape((count, d, 2 * width), jnp.float32),
+        shape((count, width, d), jnp.float32))
     (call,) = he.held_experts_status()
     # off the TPU, and not asked for: the interpreter, and it says so
     assert call["path"] == ("pallas" if os.environ.get(
         "RAY_TPU_PALLAS_INTERPRET") == "1" else "interpret")
-    assert call["block"] == he.default_block(x.shape[0], 4, 4, 16)
+    assert call["block"] == he.default_block(tokens, top_k, count, experts)
+    assert {k: call[k] for k in want} == want
+    assert call["forward_only"] == (entry == "held_expert_forward")
+    assert (call["held"], call["experts"], call["tokens"], call["top_k"],
+            call["calls"]) == (list(held), experts, tokens, top_k, 1)
