@@ -57,13 +57,13 @@ in all; (3) abreast again, everything else (`_chunk_backward_after`: dKd,
 dQe, dP, dW, dT, dA through the two `HIGHEST` products, the gate and beta
 sums), which touches no carry.
 
-The triangular solve is an inverse built from products, because the MXU
-has products and no substitution: 16-row diagonal blocks by the nilpotent
-series (I + D)^-1 = (I - D)(I + D^2)(I + D^4)(I + D^8), then the blocks
-joined the same way one level up ((I + A) = (I + D)(I + N), N strictly
-block-lower, N^4 = 0 at four blocks). Ten 64^3 products a chunk; the
-series over all 64 rows at once would be the same count but sums terms that
-grow like (64 c)^n / n! before they cancel.
+The triangular solve is an inverse built from products (the MXU has no
+substitution): 16-row diagonal blocks by the nilpotent series (I + D)^-1 =
+(I - D)(I + D^2)(I + D^4)(I + D^8), then the blocks joined the same way one
+level up ((I + A) = (I + D)(I + N), N^4 = 0; over all 64 rows at once terms
+grow like (64 c)^n / n! before they cancel). Ten products a chunk, two
+chunks to a product ([64, 128] @ a block-diagonal [128, 128]; the series'
+six on [16, 128] rows): only exact zeros differ, so bit-equal to [64, 64]s.
 
 Layout: q, k [batch, seq, key_heads*dk], v [batch, seq, value_heads*dv],
 the layout the projections produce; a head is a BlockSpec column block
@@ -258,35 +258,114 @@ def _total(m):
                    keepdims=True)
 
 
-def _transpose(m):
-    """A square f32 matrix's transpose as eye @ m^T, which the MXU does in
-    the layout it already reads."""
-    r, c = _iotas(m.shape)
-    return _dot32((r == c).astype(jnp.float32), m, _NT)
+# The `HIGHEST` products of the triangular inverse, laid on the 128 x 128
+# MXU. A [64, 64] f32 product fills a quarter of it and pushes 64 rows, so
+# (1) where `_chunk_parts` holds several chunks they go TWO CHUNKS A
+# PRODUCT: the left operands side by side on the lanes (`_pair`, [n, 2n]),
+# the right operands as the two diagonal blocks of a [2n, 2n] (`_blocks`):
+# [x1 | x2] @ diag(y1, y2) = [x1 y1 | x2 y2]; and (2) the series of the
+# `_SUB`-row diagonal blocks, block diagonal itself, runs on the blocks side
+# by side, [_SUB, width] rows against all of them as diagonal blocks: a
+# quarter of the rows pushed. Both are the same sums with exact zeros added
+# or left out: bit-equal to the plain [n, n] products, chunk by chunk.
+
+
+def _chunks_a_product(abreast: int) -> int:
+    """The chunks one MXU product of the triangular inverse holds: two, side
+    by side, wherever a grid step runs more than one chunk abreast."""
+    return 2 if abreast > 1 else 1
+
+
+def _pair(mats):
+    """Each [chunks, n, n] of `mats` as [pairs, n, 2n], chunk i and chunk
+    pairs + i side by side on the lanes (zeros beside the last chunk of an
+    odd count), and the way back; a bare [n, n] or one chunk stays as it
+    is."""
+    chunks = mats[0].shape[0] if mats[0].ndim == 3 else 1
+    if _chunks_a_product(chunks) == 1:
+        return mats, lambda w: w
+    pairs = -(-chunks // 2)
+
+    def pair(x):
+        if chunks % 2:
+            x = jnp.concatenate([x, jnp.zeros_like(x[:1])], axis=0)
+        return jnp.concatenate([x[:pairs], x[pairs:]], axis=-1)
+
+    def unpair(w):
+        n = w.shape[-2]
+        return jnp.concatenate([w[..., :n], w[..., n:]], axis=0)[:chunks]
+
+    return [pair(x) for x in mats], unpair
+
+
+def _block_of(index, m: int):
+    """index // m of an iota (>= 0, so `lax.div` is the floor; `//` and `%`
+    lower through a sign correction a use, and this file's masks are many:
+    2 s of a start-up in `jax.lower`)."""
+    return jax.lax.div(index, jnp.int32(m))
+
+
+def _blocks(w):
+    """[.., m, width] -> [.., width, width]: w's [m, m] blocks, side by
+    side on the lanes, as the diagonal blocks of a square, zeros off them
+    (so x @ _blocks(y) is every block of x times its block of y)."""
+    m, width = w.shape[-2:]
+    if m == width:
+        return w
+    r, c = _iotas(w.shape[:-2] + (width, width))
+    return jnp.where(_block_of(r, m) == _block_of(c, m),
+                     jnp.concatenate([w] * (width // m), axis=-2), 0.0)
+
+
+def _block_eye(shape):
+    """The identity in every [m, m] block of a [.., m, width]."""
+    r, c = _iotas(shape)
+    return (r == jax.lax.rem(c, jnp.int32(shape[-2]))).astype(jnp.float32)
+
+
+def _block_dot32(x, y):
+    return _dot32(x, _blocks(y))
+
+
+def _nilpotent_inverse(m, order: int):
+    """(I + m)^-1 = (I - m)(I + m^2)(I + m^4).. block by block, where every
+    [rows, rows] block of m [.., rows, width] has m^order = 0."""
+    eye = _block_eye(m.shape)
+    inv, power, reach = eye - m, m, 2
+    while reach < order:
+        power = _block_dot32(power, power)
+        inv = _block_dot32(inv, eye + power)
+        reach *= 2
+    return inv
 
 
 def _unit_lower_inverse(a):
     """(I + a)^-1 for a strictly lower triangular [.., n, n] f32 `a`, n a
     multiple of `_SUB`, from products alone (module docstring)."""
     n = a.shape[-1]
+    (a,), unpair = _pair([a])
     r, c = _iotas(a.shape)
-    eye = (r == c).astype(jnp.float32)
-    same = (r // _SUB) == (c // _SUB)
+    same = _block_of(r, _SUB) == _block_of(jax.lax.rem(c, jnp.int32(n)), _SUB)
     diag, low = jnp.where(same, a, 0.0), jnp.where(same, 0.0, a)
+    rows = [diag[..., i:i + _SUB, :] for i in range(0, n, _SUB)]
+    inv = _nilpotent_inverse(sum(rows[1:], rows[0]), _SUB)
+    if n > _SUB:
+        inv_diag = jnp.where(same, jnp.concatenate([inv] * len(rows),
+                                                   axis=-2), 0.0)
+        inv = _block_dot32(
+            _nilpotent_inverse(_block_dot32(inv_diag, low), n // _SUB),
+            inv_diag)
+    return unpair(inv)
 
-    def series(m, order):
-        """(I + m)^-1 where m^order = 0."""
-        inv, power, reach = eye - m, m, 2
-        while reach < order:
-            power = _dot32(power, power)
-            inv = _dot32(inv, eye + power)
-            reach *= 2
-        return inv
 
-    inv_diag = series(diag, _SUB)
-    if n == _SUB:
-        return inv_diag
-    return _dot32(series(_dot32(inv_diag, low), n // _SUB), inv_diag)
+def _inverse_cotangent(t, dt):
+    """da = -t^T dt t^T, what t = (I + a)^-1 hands `a` of its own cotangent
+    ([.., n, n] f32 both): t^T as eye @ t^T, which the MXU does in the
+    layout it already reads, then two products, laid out as the inverse's
+    own."""
+    (t, dt), unpair = _pair([t, dt])
+    tt = _dot32(_block_eye(t.shape), _blocks(t), _NT)
+    return unpair(-_block_dot32(_block_dot32(tt, dt), tt))
 
 
 def _chunk_parts(q, k, v, g_row, b_row):
@@ -410,8 +489,7 @@ def _chunk_backward_after(q, k, v, parts, before, state, do, d_end, dub):
     db_col = db_col + kb_term
     dg_col = dg_col + kb_term * x["b_col"]
     # t = (I + a)^-1: da = -t^T dt t^T
-    tt = _transpose(x["t"])
-    da = jnp.where(x["strict"], -_dot32(_dot32(tt, dt), tt), 0.0)
+    da = jnp.where(x["strict"], _inverse_cotangent(x["t"], dt), 0.0)
     # a = strict * beta_t * decay * (k k^T)
     dkk = (da * x["b_col"] * x["decay"]).astype(bf16)
     dk = dk + _dot(dkk, k, _NN) + _dot(dkk, k, _TN)
@@ -969,7 +1047,8 @@ def _gdn_gate_backward(o, qkvz, norm_w, dg, eps: float, rows: int,
 # Dispatch + custom VJP
 # --------------------------------------------------------------------------- #
 
-# (pass, path, reason, shape, dtype, chunk, chunks_abreast) -> traced calls
+# (pass, path, reason, shape, dtype, chunk, chunks_abreast,
+#  chunks_a_product) -> traced calls
 _CALLS: collections.Counter = collections.Counter()
 _CALLS_LOCK = threading.Lock()
 
@@ -980,18 +1059,22 @@ def gated_delta_status() -> list:
     "pallas" or "scan", the dispatch rule's `reason` for a scan call,
     `shape` [batch, value_heads, seq, head_dim], the `chunk`,
     `chunks_abreast` (how many chunks' state-independent parts one grid
-    step of the kernels computes side by side; None on the scan path) and
-    the number of traced calls. Its neighbours' passes `prep_fwd`,
-    `prep_bwd`, `gate_fwd`, `gate_bwd`: `path` "pallas" or "xla" (the chain
-    of jax primitives) with the `reason`, `shape` [batch, seq, columns] of
-    the array the op reads, `chunk` the positions of a block (None on the
-    xla path), `chunks_abreast` None."""
+    step of the kernels computes side by side; None on the scan path),
+    `chunks_a_product` (how many of them one MXU product of the triangular
+    inverse holds: 2 wherever more than one runs abreast, 1 for a single
+    chunk, None on the scan path) and the number of traced calls. Its
+    neighbours' passes `prep_fwd`, `prep_bwd`, `gate_fwd`, `gate_bwd`:
+    `path` "pallas" or "xla" (the chain of jax primitives) with the
+    `reason`, `shape` [batch, seq, columns] of the array the op reads,
+    `chunk` the positions of a block (None on the xla path),
+    `chunks_abreast` and `chunks_a_product` None."""
     with _CALLS_LOCK:
         items = list(_CALLS.items())
     return [{"pass": p, "path": path, "reason": reason, "shape": list(shape),
              "dtype": dtype, "chunk": chunk, "chunks_abreast": abreast,
-             "calls": n}
-            for (p, path, reason, shape, dtype, chunk, abreast), n in items]
+             "chunks_a_product": a_product, "calls": n}
+            for (p, path, reason, shape, dtype, chunk, abreast,
+                 a_product), n in items]
 
 
 def reset_gated_delta_status() -> None:
@@ -1020,9 +1103,10 @@ def _dispatch(pass_: str, q, k, v) -> bool:
         reason = "head_dim is not the lane width (128)"
     elif hv % k.shape[2]:
         reason = "value heads not a multiple of key heads"
+    abreast = None if reason else _steps(_padded_len(s) // CHUNK)
     key = (pass_, "scan" if reason else "pallas", reason, (b, hv, s, dv),
-           jnp.dtype(v.dtype).name, CHUNK,
-           None if reason else _steps(_padded_len(s) // CHUNK))
+           jnp.dtype(v.dtype).name, CHUNK, abreast,
+           abreast and _chunks_a_product(abreast))
     with _CALLS_LOCK:
         _CALLS[key] += 1
     return not reason
@@ -1151,7 +1235,7 @@ def _dispatch_beside(pass_: str, x, head_dim: int, widths,
     elif taps - 1 > _EDGE:
         reason = f"convolution reaches over more than {_EDGE} positions"
     key = (pass_, "xla" if reason else "pallas", reason, (b, s, columns),
-           jnp.dtype(x.dtype).name, None if reason else rows, None)
+           jnp.dtype(x.dtype).name, None if reason else rows, None, None)
     with _CALLS_LOCK:
         _CALLS[key] += 1
     return 0 if reason else rows

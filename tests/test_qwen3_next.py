@@ -51,11 +51,13 @@ def close(a, b, rel):
     return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) <= rel * scale
 
 
-# seq 128: two whole chunks; 100: not a multiple of the chunk; 640: ten
-# chunks, padded to two grid steps of eight; 1100: eighteen chunks with a
-# ragged end, padded to three grid steps, so the carry crosses two grid
-# steps in both directions. One key head serves two value heads in all.
-@pytest.mark.parametrize("seq", [128, 100, 640, 1100])
+# seq 128: two whole chunks; 100: not a multiple of the chunk; 192: three
+# chunks, an odd count abreast, so the inverse's last pair is a chunk and
+# zeros; 640: ten chunks, padded to two grid steps of eight; 1100: eighteen
+# chunks with a ragged end, padded to three grid steps, so the carry crosses
+# two grid steps in both directions. One key head serves two value heads in
+# all.
+@pytest.mark.parametrize("seq", [128, 100, 192, 640, 1100])
 def test_chunked_kernels_match_the_step_by_step_scan(interpret, seq):
     args, do = recurrence_inputs(seq)
     gd.reset_gated_delta_status()
@@ -74,14 +76,16 @@ def test_chunked_kernels_match_the_step_by_step_scan(interpret, seq):
     assert all(c["path"] == "pallas" and c["chunk"] == 64
                and c["shape"] == [1, 2, seq, 128]
                and c["chunks_abreast"] == min(8, -(-seq // 64))
+               and c["chunks_a_product"] == 2
                for c in status)
 
 
-@pytest.mark.parametrize("chunks", [1, 3, 8])
+@pytest.mark.parametrize("chunks", [1, 2, 3, 8])
 def test_chunks_abreast_equal_the_chunks_one_by_one(chunks):
     """`_chunk_parts` on [chunks, 64, d] operands (every product one
     batched `dot_general`, what a grid step runs) against the same function
-    on each chunk's [64, d] alone."""
+    on each chunk's [64, d] alone. The inverse, two chunks to a product
+    there, is the same to the bit."""
     (q, k, v, g, beta), _ = recurrence_inputs(chunks * gd.CHUNK, 1, 1)
     q, k, v = (t[0, :, 0].astype(jnp.bfloat16).reshape(chunks, gd.CHUNK, -1)
                for t in (q, k, v))
@@ -94,14 +98,17 @@ def test_chunks_abreast_equal_the_chunks_one_by_one(chunks):
             assert abreast[name][i].shape == alone[name].shape, name
             assert close(abreast[name][i], alone[name].astype(jnp.float32),
                          1e-6), (name, i)
+        assert np.array_equal(abreast["t"][i], alone["t"]), i
 
 
-# the chunk count under one grid step's eight, eight from there on; the
-# scan has no grid step
-@pytest.mark.parametrize("seq,d,abreast", [(128, 128, 2), (100, 128, 2),
-                                           (512, 128, 8), (8192, 128, 8),
-                                           (128, 16, None)])
-def test_status_says_how_many_chunks_run_abreast(interpret, seq, d, abreast):
+# the chunk count under one grid step's eight, eight from there on, and of
+# those the inverse's products hold two each (an odd count's last beside
+# zeros) unless there is one; the scan has no grid step
+@pytest.mark.parametrize("seq,d,abreast,a_product", [
+    (128, 128, 2, 2), (100, 128, 2, 2), (512, 128, 8, 2), (8192, 128, 8, 2),
+    (192, 128, 3, 2), (64, 128, 1, 1), (128, 16, None, None)])
+def test_status_says_how_many_chunks_run_abreast(interpret, seq, d, abreast,
+                                                 a_product):
     args, _ = recurrence_inputs(seq, d=d)
     gd.reset_gated_delta_status()
     jax.eval_shape(jax.grad(lambda *a: jnp.sum(gd.gated_delta_rule(*a))),
@@ -109,6 +116,7 @@ def test_status_says_how_many_chunks_run_abreast(interpret, seq, d, abreast):
     status = gd.gated_delta_status()
     assert {c["pass"] for c in status} == {"fwd", "bwd"}
     assert all(c["chunks_abreast"] == abreast
+               and c["chunks_a_product"] == a_product
                and c["path"] == ("pallas" if abreast else "scan")
                for c in status)
 
@@ -126,6 +134,7 @@ def test_the_fallback_is_the_scan_and_says_why(seq):
     assert all(close(a, b, 1e-5) for a, b in zip(grads, wants))
     assert all(c["path"] == "scan" and c["reason"].startswith("platform")
                and c["chunks_abreast"] is None
+               and c["chunks_a_product"] is None
                for c in gd.gated_delta_status())
 
 
@@ -147,6 +156,59 @@ def test_unit_lower_inverse_from_products(n):
     a = np.tril(np.random.default_rng(n).normal(size=(n, n)) * 0.3, -1)
     got = gd._unit_lower_inverse(jnp.asarray(a, jnp.float32))
     np.testing.assert_allclose(got, np.linalg.inv(np.eye(n) + a), atol=2e-4)
+
+
+def strictly_lower(chunks, seed):
+    a = np.random.default_rng(seed).normal(size=(chunks, 64, 64)) * 0.3
+    return jnp.asarray(np.tril(a, -1), jnp.float32)
+
+
+def ten_plain_products(a):
+    """(I + a)^-1 of one [64, 64] as the module docstring defines it: ten
+    [64, 64] x [64, 64] `HIGHEST` products, nothing side by side."""
+    eye = jnp.eye(64, dtype=jnp.float32)
+    same = jnp.kron(eye[:4, :4], jnp.ones((16, 16))) > 0
+    diag, low = jnp.where(same, a, 0.0), jnp.where(same, 0.0, a)
+
+    def series(m, order):
+        inv, power, reach = eye - m, m, 2
+        while reach < order:
+            power = gd._dot32(power, power)
+            inv = gd._dot32(inv, eye + power)
+            reach *= 2
+        return inv
+
+    inv_diag = series(diag, 16)
+    return gd._dot32(series(gd._dot32(inv_diag, low), 4), inv_diag)
+
+
+# one chunk goes as a bare [64, 64] does; two and eight go in pairs, [64,
+# 128] against a block-diagonal [128, 128]; three leave one beside zeros
+@pytest.mark.parametrize("chunks", [1, 2, 3, 8])
+def test_the_inverse_of_chunks_abreast_is_each_chunks_own_to_the_bit(chunks):
+    a = strictly_lower(chunks, chunks)
+    got = gd._unit_lower_inverse(a)
+    assert got.shape == a.shape
+    for i in range(chunks):
+        assert np.array_equal(got[i], gd._unit_lower_inverse(a[i])), i
+        assert np.array_equal(got[i], ten_plain_products(a[i])), i
+        np.testing.assert_allclose(got[i], np.linalg.inv(np.eye(64) + a[i]),
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("chunks", [2, 3, 8])
+def test_the_inverses_cotangent_in_pairs_is_the_three_products(chunks):
+    """da = -t^T dt t^T two chunks to a product against the transpose and
+    the two [64, 64] products written out for each chunk: exact."""
+    t = gd._unit_lower_inverse(strictly_lower(chunks, 7))
+    dt = jnp.asarray(np.random.default_rng(chunks).normal(
+        size=(chunks, 64, 64)), jnp.float32)
+    got = gd._inverse_cotangent(t, dt)
+    assert got.shape == t.shape
+    for i in range(chunks):
+        tt = gd._dot32(jnp.eye(64, dtype=jnp.float32), t[i], gd._NT)
+        assert np.array_equal(tt, t[i].T)
+        assert np.array_equal(got[i], -gd._dot32(gd._dot32(tt, dt[i]), tt)), i
 
 
 def test_causal_conv1d_is_the_published_left_padded_convolution():
