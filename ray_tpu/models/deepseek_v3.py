@@ -49,8 +49,13 @@ Counters: the expert layers' loads (`held_expert_forward`'s `counts`) are
 added into `cache["moe"]` on the device, decode steps and prefill chunks
 apart, and read when `stats()` is asked (`cache_counters`,
 `counter_stats`: finding (f) of docs/INFERENCE.md). Idle rows (batch and
-chunk padding) are routed to no expert and count for nothing. Beside the
-latent rows the cache keeps the ROUTING RECORD, `cache["routing"]` f32
+chunk padding) are routed to no expert and count for nothing. Beside them
+`cache["latent_walk"]` counts what the attention kernel's tile rule walks
+(`ops/latent_attention.py tile_walk`, asked once a step: every layer's call
+has the step's positions): grid steps, those that copy anything, the KV
+chunks they copy and of those the ones that run the masked body, decode
+steps and chunks apart; `stats()["latent_walk"]`. Beside the latent rows
+the cache keeps the ROUTING RECORD, `cache["routing"]` f32
 [2k, blocks x block]: the FIRST expert layer's chosen experts (k rows, as
 floats) and their gates (k rows) of every cached token, written by the step
 that routed it at the token's own cache location, so that what a served
@@ -75,7 +80,8 @@ import numpy as np
 
 from ray_tpu.models.falcon_h1 import _normal, _rms_norm, _RowsOfTransposed
 from ray_tpu.ops import held_experts as moe
-from ray_tpu.ops.latent_attention import latent_attention
+from ray_tpu.ops.latent_attention import (WALK_COUNTS, latent_attention,
+                                          tile_walk)
 
 _LANES = 128
 KINDS = ("decode", "prefill")
@@ -447,7 +453,8 @@ class DeepseekV3:
                     "tiles": jnp.zeros((2, layers), i32),
                     "drew": jnp.zeros((2, layers), i32),
                     "max_over_mean": jnp.zeros((2, layers), jnp.float32),
-                    "load": jnp.zeros((2, layers, experts), i32)}}
+                    "load": jnp.zeros((2, layers, experts), i32)},
+            "latent_walk": {k: jnp.zeros((2,), i32) for k in WALK_COUNTS}}
 
     def paged_step(self, params, ids, cache, block_tables, row_pos,
                    write_mask, adapters=None, slots=None, last_idx=None):
@@ -459,8 +466,8 @@ class DeepseekV3:
         cfg = self.config
         s = ids.shape[1]
         positions = row_pos[:, None] + jnp.arange(s)[None, :]
-        flat = cache_locations(block_tables, positions, write_mask,
-                               cache["latent"][0].shape[1])
+        bsz = cache["latent"][0].shape[1]
+        flat = cache_locations(block_tables, positions, write_mask, bsz)
         x = params["embed"][ids]
         arenas, counts, record = [], [], cache["routing"]
         for lp, arena in zip(params["layers"], cache["latent"]):
@@ -478,26 +485,39 @@ class DeepseekV3:
         x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.dot(x.astype(cfg.dtype), params["lm_head"],
                          preferred_element_type=jnp.float32)
+        kind = 0 if s == 1 else 1
         counters = cache["moe"]
         if counts:
-            counters = _count(counters, 0 if s == 1 else 1, counts)
+            counters = _count(counters, kind, counts)
+        walked = tile_walk(
+            positions, write_mask, heads=cfg.num_attention_heads,
+            block_size=bsz, max_ctx=block_tables.shape[1] * bsz,
+            dtype=cfg.dtype)[2]
+        walk = {k: v.at[kind].add(walked[k])
+                for k, v in cache["latent_walk"].items()}
         return logits, {"latent": arenas, "routing": record,
-                        "moe": counters}
+                        "moe": counters, "latent_walk": walk}
 
     # ------------------------------------------------- counters (finding f)
 
     def cache_counters(self, cache):
         """The part of the cache the host may read when `stats()` is
         asked: small device arrays, cumulative since the cache was made."""
-        return cache["moe"]
+        return {"moe": cache["moe"], "latent_walk": cache["latent_walk"]}
 
     def counter_stats(self, host) -> Dict[str, Any]:
-        """`stats()["moe"]` from a host copy of `cache_counters`:
-        cumulative sums over the expert layers for decode steps and prefill
-        chunks apart (the difference of two reads is a window's), the
-        per-step per-layer means over the cache's life, and every expert's
-        cumulative load."""
+        """`stats()["moe"]` and `stats()["latent_walk"]` from a host copy
+        of `cache_counters`: cumulative sums over the expert layers for
+        decode steps and prefill chunks apart (the difference of two reads
+        is a window's), the per-step per-layer means over the cache's life,
+        and every expert's cumulative load; and what an attention call of
+        each kind of step walked (`WALK_COUNTS`: the same for each of a
+        step's layers, counted once a step), cumulative."""
         cfg = self.config
+        walk = {kind: {name: int(host["latent_walk"][name][k])
+                       for name in WALK_COUNTS}
+                for k, kind in enumerate(KINDS)}
+        host = host["moe"]
         out: Dict[str, Any] = {"layers": cfg.n_moe_layers,
                                "experts": cfg.n_routed_experts,
                                "top_k": cfg.num_experts_per_tok}
@@ -513,7 +533,7 @@ class DeepseekV3:
                 "experts_drawn_per_step": sums["drew"] / calls,
                 "load_max_over_mean": sums["max_over_mean"] / calls}
         out["load"] = [int(v) for v in np.sum(host["load"], axis=(0, 1))]
-        return {"moe": out}
+        return {"moe": out, "latent_walk": walk}
 
     # ---------------------------------------------------------- the rest
 

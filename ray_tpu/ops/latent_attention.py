@@ -21,9 +21,25 @@ the pages the table maps below the row's live length, straight out of the
 arena in HBM into a double-buffered VMEM scratch, once a (row, query tile),
 and runs an online softmax in float32 over them. The query rows of a tile
 are (token, head) pairs, token-major: a decode step's tile is a slot's 32
-heads, a prefill chunk's tiles are 16 tokens x 32 heads each. Scores are
+heads, a prefill chunk's tiles are 32 tokens x 32 heads each. Scores are
 one product of depth `width`, probabilities go into P x V in the arena's
 dtype (bf16 on the chip) with float32 accumulation.
+
+What a tile walks is `tile_walk`'s to say (the TILE RULE), from its LIVE
+queries alone (`write_mask`): up to its last live position + 1, in chunks
+of 1,024 tokens under a decode tile and 512 under a prefill tile.
+- A tile with no live query (a chunk's padding past the question, an idle
+  slot) copies nothing, multiplies nothing and writes zeros.
+- Chunks that lie whole at or below the tile's FIRST live position hold no
+  key that some live query may not see and no row past the walk's end: they
+  run the loop's body with no mask on scores or probabilities and no select
+  over the page buffer. That is the same arithmetic in the same order
+  (`where(True, x, .)` is x; outputs on live queries are bit-equal to the
+  all-masked loop's on the chip); only the chunks that reach the tile's
+  own queries, and the walk's last, run the masked body.
+- A grid step's first chunk is started under the last chunk of the grid
+  step before it that walked (the grid is sequential; the next table row
+  is already in SMEM), so only the call's very first copy is exposed.
 
 `latent_attention_reference` is the `jax.numpy` definition: the fallback,
 and the CPU tests' yardstick. Dispatch is `ops/attention.py`'s rule;
@@ -47,12 +63,19 @@ from ray_tpu.ops import attention as _attn
 from ray_tpu.ops.attention import _NEG_INF
 
 _LANES = 128
-_MAX_Q_ROWS = 512
-# Tokens copied and multiplied a loop iteration: a decode tile (a slot's
-# heads, few rows) is bound by the loop's fixed costs and wants long
-# chunks; a prefill tile of 512 rows by its products.
-_CHUNK_TOKENS_FEW_ROWS = 512
-_CHUNK_TOKENS = 256
+# Query rows a grid step and tokens copied and multiplied a loop iteration,
+# taken on the chip at 32 heads behind ~8k cached tokens (PERF.md section 6,
+# PR 55). A decode tile (a slot's heads, few rows) is bound by the pages'
+# bytes and the loop's fixed costs: a longer chunk has fewer of those, and
+# past 1,024 what the walk's last chunk multiplies for nothing costs more
+# than they (512: 0.565 ms a call, 1,024: 0.504, 2,048: 0.520). A prefill
+# tile is bound by its products: 1,024 rows feed the MXU better than 512 and
+# a 512-token chunk rescales the accumulator half as often as 256 (a full
+# chunk 1.33 -> 0.99 ms); 2,048 rows are as fast a row and skip less of a
+# half-filled chunk.
+_MAX_Q_ROWS = 1024
+_CHUNK_TOKENS_FEW_ROWS = 1024
+_CHUNK_TOKENS = 512
 _FEW_ROWS = 128
 _VMEM_LIMIT = 64 * 1024 * 1024
 PASSES = ("paged_latent_decode", "paged_latent_prefill")
@@ -84,21 +107,79 @@ def latent_attention_reference(q, arena, block_tables, positions, *,
 # --------------------------------------------------------------------------- #
 
 
-def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems,
+# Rows of the walk array (scalar prefetch, one column a grid step).
+_HI, _PLAIN, _BEFORE, _NEXT = range(4)
+WALK_COUNTS = ("tiles", "tiles_walked", "kv_chunks", "kv_chunks_masked")
+
+
+def tile_walk(positions, write_mask, *, heads: int, block_size: int,
+              max_ctx: int, dtype):
+    """THE TILE RULE: what the kernel walks for queries at `positions`
+    [b, s] of which `write_mask` marks the live ones. Returns
+
+      q_pos [b, tiles * rows] int32: a query row's position, -1 for a
+        masked query and for the padding of the last tile;
+      walk [4, b * tiles] int32, a column a grid step in grid order (row-
+        major (row, tile)): `_HI` the walk's end, the tile's last LIVE
+        position + 1 (0: nothing is copied and zeros are written);
+        `_PLAIN` the leading chunks that lie whole at or below the tile's
+        FIRST live position (and below `_HI`), which no mask can touch;
+        `_BEFORE` the chunks of all earlier grid steps (its parity is the
+        buffer a step's first chunk lands in, 0 says nobody started it);
+        `_NEXT` the next grid step that walks anything, -1 for none;
+      counts, four int32 scalars (`WALK_COUNTS`): grid steps, those that
+        walk, the chunks they copy and multiply, and of those the ones
+        that run the masked body.
+
+    The wrapper builds the kernel's scalars from it and a model counts a
+    step's walk with it: one definition."""
+    b, s = positions.shape
+    rows, pages = _tiles(s * heads, block_size, dtype)
+    chunk = pages * block_size
+    n_rows = s * heads
+    tiles = -(-n_rows // rows)
+    # Row t * heads + h of a slot is query token t, head h.
+    q_pos = jnp.repeat(jnp.where(write_mask, positions, -1).astype(jnp.int32),
+                       heads, axis=1)
+    q_pos = jnp.pad(q_pos, ((0, 0), (0, tiles * rows - n_rows)),
+                    constant_values=-1)
+    by_tile = q_pos.reshape(b * tiles, rows)
+    hi = jnp.clip(by_tile.max(axis=-1) + 1, 0, max_ctx)
+    first = jnp.where(by_tile < 0, max_ctx, by_tile).min(axis=-1)
+    plain = jnp.minimum(first + 1, hi) // chunk
+    chunks = (hi + chunk - 1) // chunk
+    before = jnp.cumsum(chunks) - chunks
+    step = jnp.arange(b * tiles, dtype=jnp.int32)
+    later = jnp.where(chunks > 0, step, b * tiles)
+    nxt = jnp.flip(jax.lax.cummin(jnp.flip(later)))        # at or after
+    nxt = jnp.concatenate([nxt[1:], jnp.full((1,), b * tiles, jnp.int32)])
+    nxt = jnp.where(nxt < b * tiles, nxt, -1)
+    walk = jnp.stack([hi, plain, before, nxt]).astype(jnp.int32)
+    counts = (jnp.int32(b * tiles), jnp.sum(chunks > 0, dtype=jnp.int32),
+              jnp.sum(chunks, dtype=jnp.int32),
+              jnp.sum(chunks - plain, dtype=jnp.int32))
+    return q_pos, walk, dict(zip(WALK_COUNTS, counts))
+
+
+def _kernel(walk_ref, bt_ref, q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems,
             m_scr, l_scr, acc_scr, *, scale: float, latent: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     row = pl.program_id(0)
-    tile = pl.program_id(1)
+    step = row * pl.num_programs(1) + pl.program_id(1)
     _, pages, block_size, width = kv_buf.shape
     chunk = pages * block_size
-    hi = hi_ref[row, tile]
+    hi = walk_ref[_HI, step]
+    n_plain = walk_ref[_PLAIN, step]
+    before = walk_ref[_BEFORE, step]
+    nxt = walk_ref[_NEXT, step]
     n_chunks = (hi + chunk - 1) // chunk
 
-    def for_live_pages(c, slot, do):
-        """`do(copy)` for every live page of chunk c into `slot`: ONE copy
-        a page, which serves the scores and the values."""
+    def for_live_pages(row, hi, c, slot, do):
+        """`do(copy)` for every live page of chunk c of a walk of `row` to
+        `hi` into `slot`: ONE copy a page, which serves the scores and the
+        values."""
         def body(p, carry):
             phys = bt_ref[row, c * pages + p]
             do(pltpu.make_async_copy(kv_hbm.at[phys], kv_buf.at[slot, p],
@@ -109,40 +190,54 @@ def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems,
             // block_size
         jax.lax.fori_loop(0, live, body, 0)
 
+    def start(copy):
+        copy.start()
+
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(n_chunks > 0)
+    # The first grid step that walks starts its own first chunk; every
+    # later one finds it started under its predecessor's last chunk.
+    @pl.when((n_chunks > 0) & (before == 0))
     def _():
-        for_live_pages(0, 0, lambda copy: copy.start())
+        for_live_pages(row, hi, 0, 0, start)
 
-    def body(c, carry):
-        slot = c % 2
+    def chunk_step(c, masked: bool):
+        slot = (before + c) % 2
 
         @pl.when(c + 1 < n_chunks)
         def _():
-            for_live_pages(c + 1, 1 - slot, lambda copy: copy.start())
+            for_live_pages(row, hi, c + 1, 1 - slot, start)
 
-        for_live_pages(c, slot, lambda copy: copy.wait())
-        q_pos = qpos_ref[0]                                  # [rows, 1]
-        rows = q_pos.shape[0]
-        k_pos = c * chunk + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, chunk), 1)
-        mask = (k_pos <= q_pos) & (k_pos < hi)
-        # Rows of the buffer at or past `hi` hold whatever was there; they
-        # are the VALUES too, so they are zeroed, or 0 x NaN gets in.
+        @pl.when((c + 1 == n_chunks) & (nxt >= 0))
+        def _():
+            for_live_pages(nxt // pl.num_programs(1), walk_ref[_HI, nxt], 0,
+                           1 - slot, start)
+
+        for_live_pages(row, hi, c, slot, lambda copy: copy.wait())
         kv = kv_buf[slot].reshape(chunk, width)
-        kv_live = c * chunk + jax.lax.broadcasted_iota(
-            jnp.int32, (chunk, width), 0) < hi
-        kv = jnp.where(kv_live, kv, jnp.zeros_like(kv))
+        if masked:
+            q_pos = qpos_ref[0]                              # [rows, 1]
+            k_pos = c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (q_pos.shape[0], chunk), 1)
+            mask = (k_pos <= q_pos) & (k_pos < hi)
+            # Rows of the buffer at or past `hi` hold whatever was there;
+            # they are the VALUES too, so they are zeroed, or 0 x NaN gets
+            # in.
+            kv_live = c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (chunk, width), 0) < hi
+            kv = jnp.where(kv_live, kv, jnp.zeros_like(kv))
         s = jax.lax.dot_general(
             q_ref[0], kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [rows, chunk]
-        s = jnp.where(mask, s, _NEG_INF)
+        if masked:
+            s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(mask, p, 0.0)
         correction = jnp.exp(m_prev - m_new)
         l_new = correction * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
@@ -150,12 +245,19 @@ def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems,
             preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-        return carry
 
-    jax.lax.fori_loop(0, n_chunks, body, 0)
+    # Chunks whole below every live query of the tile (and so below `hi`)
+    # need no mask: `where(True, x, .)` is x, the same arithmetic in the
+    # same order. Only the chunks that reach the tile's queries, and the
+    # walk's last, can hold a key some query may not see or a row past
+    # `hi`.
+    jax.lax.fori_loop(0, n_plain, lambda c, _: chunk_step(c, False), None)
+    jax.lax.fori_loop(n_plain, n_chunks, lambda c, _: chunk_step(c, True),
+                      None)
 
-    # A row with nothing live (an idle slot, a padded query) has l = 0 and
-    # a zero accumulator: it writes zeros, never 0/0.
+    # A row with nothing live (an idle slot, a padded query, a tile whose
+    # queries are all masked) has l = 0 and a zero accumulator: it writes
+    # zeros, never 0/0.
     denom = jnp.maximum(l_scr[:, :1], 1e-30)
     o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
@@ -170,8 +272,8 @@ def _tiles(n_rows: int, block_size: int, dtype) -> tuple:
 
 @functools.partial(jax.jit,
                    static_argnames=("latent", "scale", "interpret"))
-def _latent_attention_pallas(q, arena, block_tables, positions, lengths, *,
-                             latent: int, scale: float,
+def _latent_attention_pallas(q, arena, block_tables, positions, write_mask,
+                             *, latent: int, scale: float,
                              interpret: bool = False):
     # Jitted on its own so that a model's layers share one trace and one
     # lowering of the kernel (ops/paged_attention.py says what that saved).
@@ -180,18 +282,14 @@ def _latent_attention_pallas(q, arena, block_tables, positions, lengths, *,
 
     b, s, heads, width = q.shape
     nb, bsz, _ = arena.shape
-    max_ctx = block_tables.shape[1] * bsz
     n_rows = s * heads
     rows, pages = _tiles(n_rows, bsz, q.dtype)
-    n_tiles = -(-n_rows // rows)
-    pad = n_tiles * rows - n_rows
-    # Row t * heads + h of a slot is query token t, head h.
-    qr = jnp.pad(q.reshape(b, n_rows, width), ((0, 0), (0, pad), (0, 0)))
-    q_pos = jnp.pad(jnp.repeat(positions.astype(jnp.int32), heads, axis=1),
-                    ((0, 0), (0, pad)), constant_values=-1)
-    hi = jnp.minimum(q_pos.reshape(b, n_tiles, rows).max(axis=-1) + 1,
-                     lengths[:, None])
-    hi = jnp.clip(hi, 0, max_ctx).astype(jnp.int32)
+    q_pos, walk, _ = tile_walk(
+        positions, write_mask, heads=heads, block_size=bsz,
+        max_ctx=block_tables.shape[1] * bsz, dtype=q.dtype)
+    n_tiles = q_pos.shape[1] // rows
+    qr = jnp.pad(q.reshape(b, n_rows, width),
+                 ((0, 0), (0, n_tiles * rows - n_rows), (0, 0)))
 
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, latent=latent),
@@ -220,7 +318,7 @@ def _latent_attention_pallas(q, arena, block_tables, positions, lengths, *,
         # Two stable names the device trace finds: a decode step's calls
         # are bound by the pages' bytes, a prefill chunk's by its products.
         name=KERNELS[0] if s == 1 else KERNELS[1],
-    )(hi, block_tables.astype(jnp.int32), qr, q_pos[..., None], arena)
+    )(walk, block_tables.astype(jnp.int32), qr, q_pos[..., None], arena)
     return out[:, :n_rows].reshape(b, s, heads, latent)
 
 
@@ -279,9 +377,10 @@ def latent_attention(q, arena, block_tables, positions, write_mask=None, *,
     Returns o_lat [b, s, heads, latent] in q's dtype.
 
     `write_mask` [b, s] marks the queries whose output is used; the kernel
-    reads a row's pages only up to its last such query (an idle slot reads
-    nothing and gets zeros; a masked query's output is finite and
-    otherwise unspecified, on either path)."""
+    reads a row's pages only up to its last such query, a query tile at a
+    time (`tile_walk`): an idle slot reads nothing and gets zeros; a masked
+    query's output is finite and otherwise unspecified, on either path, and
+    on the kernel's zero where its whole tile is masked."""
     width = arena.shape[-1]
     if q.shape[-1] > width:
         raise ValueError(f"q is {q.shape[-1]} wide, a page {width}")
@@ -289,9 +388,8 @@ def latent_attention(q, arena, block_tables, positions, write_mask=None, *,
     if write_mask is None:
         write_mask = jnp.ones(positions.shape, bool)
     if _dispatch(q, arena, latent):
-        live = jnp.where(write_mask, positions + 1, 0).max(axis=1)
         return _latent_attention_pallas(
-            q, arena, block_tables, positions, live, latent=latent,
+            q, arena, block_tables, positions, write_mask, latent=latent,
             scale=float(scale), interpret=_attn._interpret())
     return latent_attention_reference(q, arena, block_tables, positions,
                                       latent=latent, scale=scale)

@@ -329,6 +329,50 @@ def test_the_expert_layers_counters_reach_stats(tiny, served):
     assert final["prefill"]["assigned"] == (38 + 18) * per_token
 
 
+def test_the_latent_walk_reaches_stats(tiny):
+    """`stats()["latent_walk"]`: what the kernel's tile rule walks a step,
+    decode steps and chunks apart. Four heads and chunks of 512 positions
+    are two tiles of 256 tokens a chunk, KV chunks of 512 tokens under
+    them and of 1,024 under a decode step's tile; a prompt of 1,100 tokens
+    is two full chunks and one of 76, whose second tile holds no live
+    query."""
+    from ray_tpu.ops import latent_attention as la
+
+    model, params, _ = tiny
+    engine = InferenceEngine(
+        EngineConfig(batch_slots=2, block_size=16, num_blocks=80,
+                     max_blocks_per_seq=72, prefill_chunk=512),
+        model=model, params=params)
+    req = engine.add_request(prompt(1100, 50), 4)
+    engine.run_until_idle()
+    assert req.state == "FINISHED", req.error
+    walk = settled_stats(engine)["latent_walk"]
+    # by hand. Chunks at 0, 512, 1024: a tile of queries at p .. p + 255
+    # walks ceil((p + 256) / 512) chunks, those whole below p unmasked;
+    # the last chunk's first tile stops at 1,100 and its second is skipped.
+    assert walk["prefill"] == {"tiles": 6, "tiles_walked": 5,
+                               "kv_chunks": 1 + 1 + 2 + 2 + 3,
+                               "kv_chunks_masked": 5}
+    # three decode steps at positions 1,100-1,102, two slots of which one
+    # is live: two 1,024-token chunks, the first whole below the query
+    assert walk["decode"] == {"tiles": 6, "tiles_walked": 3,
+                              "kv_chunks": 6, "kv_chunks_masked": 3}
+    # and the rule that counted is the one the kernel's wrapper walks by
+    assert dsv3.tile_walk is la.tile_walk
+    cfg = model.config
+    rule = dict(heads=cfg.num_attention_heads, block_size=16,
+                max_ctx=72 * 16, dtype=cfg.dtype)
+    total = dict.fromkeys(la.WALK_COUNTS, 0)
+    for start, live in ((0, 512), (512, 512), (1024, 76)):
+        counts = la.tile_walk(start + jnp.arange(512)[None],
+                              jnp.arange(512)[None] < live, **rule)[2]
+        total = {k: total[k] + int(counts[k]) for k in total}
+    assert total == walk["prefill"]
+    counts = la.tile_walk(jnp.array([[1101], [0]]),
+                          jnp.array([[True], [False]]), **rule)[2]
+    assert {k: 3 * int(v) for k, v in counts.items()} == walk["decode"]
+
+
 def test_published_keys_make_the_configuration():
     import json
 

@@ -10,7 +10,7 @@ in the definition's, so a read past the length shows as a NaN."""
 import numpy as np
 import pytest
 
-LATENT, ROPE, WIDTH, HEADS, BS = 128, 32, 256, 4, 16
+LATENT, ROPE, WIDTH, HEADS, BS = 128, 32, 256, 8, 16
 SCALE = 0.11
 
 
@@ -19,11 +19,13 @@ def interpret(monkeypatch):
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
 
 
-def _case(s, lens, max_blocks, dtype, seed=0, share=None):
+def _case(s, lens, max_blocks, dtype, seed=0, share=None, queries=None,
+          heads=HEADS):
     """An arena with shuffled physical blocks and trash-padded table
     tails. `lens[i]` is row i's live length AFTER this call (0: an idle
-    row); its last min(s, len) positions are this call's queries. `share`
-    = (i, j): row j's first blocks ARE row i's (a shared prefix)."""
+    row); its last `queries[i]` (min(s, len) by default) positions are this
+    call's live queries, the chunk's other positions follow them, masked.
+    `share` = (i, j): row j's first blocks ARE row i's (a shared prefix)."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(seed)
@@ -48,10 +50,10 @@ def _case(s, lens, max_blocks, dtype, seed=0, share=None):
         bt[i, :len(blocks)] = blocks
         p = np.arange(n)
         live[blocks[p // BS], p % BS] = True
-        q_n = min(s, n)
+        q_n = min(s, n) if queries is None else queries[i]
         pos[i] = n - q_n + np.arange(s)
         wmask[i, :q_n] = True
-    q = rng.standard_normal((b, s, HEADS, LATENT + ROPE)).astype(np.float32)
+    q = rng.standard_normal((b, s, heads, LATENT + ROPE)).astype(np.float32)
     mask = live[:, :, None]
     return (jnp.asarray(q, dtype),
             jnp.asarray(np.where(mask, arena, np.nan), dtype),
@@ -61,7 +63,7 @@ def _case(s, lens, max_blocks, dtype, seed=0, share=None):
 
 # Ragged lengths in one batch: 1, one ending on a block edge, one in the
 # middle of a block, one filling the whole table, an idle row.
-MAX_BLOCKS = 34           # 544 positions: more than one chunk at any s
+MAX_BLOCKS = 70           # 1,120 positions: more than one chunk at any s
 LENS = (1, 2 * BS, 5 * BS + 3, MAX_BLOCKS * BS, 0, 37)
 
 
@@ -86,7 +88,7 @@ def _both(q, nan_arena, arena, bt, pos, wmask):
 @pytest.mark.parametrize("s", [1, 5, 160])
 def test_kernel_matches_its_definition(interpret, s, dtype, tol):
     """Ragged lengths, a masked (idle) row, padded chunk positions; at s =
-    160 a row's 640 query rows are two grid steps."""
+    160 a row's 1,280 query rows are two grid steps."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.latent_attention import (latent_attention_status,
@@ -101,6 +103,130 @@ def test_kernel_matches_its_definition(interpret, s, dtype, tol):
             if r["shape"][:2] == [len(LENS), s] and r["dtype"] == dtype]
     assert took and all(r["path"] == "pallas" for r in took)
     assert {r["pass"] for r in took} == {PASSES[0] if s == 1 else PASSES[1]}
+
+
+def _walk(case, heads=HEADS):
+    """The tile rule's `walk` array for a case, as numpy."""
+    from ray_tpu.ops.latent_attention import tile_walk
+
+    q, _, arena, bt, pos, wmask = case
+    return np.asarray(tile_walk(
+        pos, wmask, heads=heads, block_size=BS,
+        max_ctx=bt.shape[1] * BS, dtype=q.dtype)[1])
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+def test_a_wholly_masked_tile_walks_nothing(interpret, dtype, tol):
+    """A chunk of 160 positions at 32 heads is five tiles of 32 tokens; of
+    rows with 1, 32, 33 and 160 live queries behind a prefix, the tiles
+    that hold no live query copy nothing (whatever their masked queries'
+    positions would reach is NaN, or trash) and write exactly zero; the
+    half-masked ones match the definition on their live queries."""
+    import jax.numpy as jnp
+
+    live = (1, 32, 33, 160)
+    lens = tuple(300 + n for n in live)
+    case = _case(160, lens, MAX_BLOCKS, jnp.dtype(dtype), seed=3,
+                 queries=live, heads=32)
+    walk = _walk(case, heads=32).reshape(4, len(live), 5)
+    assert [int((row > 0).sum()) for row in walk[0]] == [1, 1, 2, 5]
+    out, ref, wmask = _both(*case)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[wmask], ref[wmask], atol=tol, rtol=tol)
+    for i, n in enumerate(live):
+        assert (out[i, -(-n // 32) * 32:] == 0).all()
+
+
+# (live length after the call, live queries): the split loop's edges. At s
+# = 1 a chunk is 1,024 tokens (a tile of few rows), at s = 160 it is 512
+# and a tile 128 tokens; `hi` and `plain` are what the tile rule must say,
+# a (row, tile) at a time.
+EDGES = {
+    1: dict(
+        rows=[(1024, 1), (1025, 1), (1023, 1), (1500, 1), (2048, 1), (0, 0)],
+        hi=[1024, 1025, 1023, 1500, 2048, 0],
+        # hi on a chunk edge: no masked chunk at all; one past it: the
+        # query's own position opens a chunk of one live row
+        plain=[1, 1, 0, 1, 2, 0]),
+    160: dict(
+        rows=[(1024, 160), (1025, 160), (1023, 160), (672, 160), (512, 1),
+              (512, 160), (1400, 17), (0, 0)],
+        hi=[992, 1024, 993, 1025, 991, 1023, 640, 672, 512, 0, 480, 512,
+            1400, 0, 0, 0],
+        # (672, 160): its first live position IS a chunk edge; (512, 1): a
+        # walk of exactly one chunk, and that one plain
+        plain=[1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 0, 0, 2, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("s", sorted(EDGES))
+def test_the_split_loops_edges(interpret, s, dtype, tol):
+    """Chunks whole below a tile's first live query run the body with no
+    mask, the others today's: the seam between them at every place it can
+    fall, against the definition; the arena past each row's length is NaN."""
+    import jax.numpy as jnp
+
+    edges = EDGES[s]
+    lens, queries = zip(*edges["rows"])
+    case = _case(s, lens, 128, jnp.dtype(dtype), seed=11 + s,
+                 queries=queries)
+    walk = _walk(case)
+    assert walk[0].tolist() == edges["hi"]
+    assert walk[1].tolist() == edges["plain"]
+    out, ref, wmask = _both(*case)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[wmask], ref[wmask], atol=tol, rtol=tol)
+    assert (out[-1] == 0).all()
+
+
+@pytest.mark.parametrize("s", [1, 160])
+def test_the_first_copy_is_handed_over_idle_steps(interpret, s):
+    """Six slots of which the 1st, 3rd and last are idle: a grid step's
+    last chunk starts the first chunk of the NEXT step that walks (idle
+    ones read nothing: NaN arena), the first live step starts its own, and
+    the last live one starts none."""
+    import jax.numpy as jnp
+
+    lens = (0, 40, 0, 1100, 37, 0)
+    case = _case(s, lens, MAX_BLOCKS, jnp.float32, seed=5)
+    hi, _, before, nxt = _walk(case).reshape(4, len(lens), -1)
+    live = np.flatnonzero(hi.reshape(-1))
+    steps = hi.size
+    assert [int(i) for i in live // hi.shape[1]] == sorted(
+        {1, 3, 4} if s == 1 else [1, 3, 3, 4])
+    # each step names the live step after it, the last live one nobody
+    want = [next((int(j) for j in live if j > g), -1) for g in range(steps)]
+    assert nxt.reshape(-1).tolist() == want and want[live[-1]] == -1
+    assert before.reshape(-1)[live[0]] == 0
+    assert (before.reshape(-1)[live[1:]] > 0).all()
+    out, ref, wmask = _both(*case)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[wmask], ref[wmask], atol=2e-5, rtol=2e-5)
+    for idle in (0, 2, 5):
+        assert (out[idle] == 0).all()
+
+
+def test_the_wrapper_walks_what_the_rule_says(interpret, monkeypatch):
+    """One definition: the kernel's scalars come from `tile_walk`, the
+    function a model counts a step's walk with."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import latent_attention as la
+
+    seen = []
+
+    def spy(positions, write_mask, **kw):
+        seen.append((positions.shape, kw["heads"], kw["block_size"]))
+        return rule(positions, write_mask, **kw)
+
+    rule = la.tile_walk
+    monkeypatch.setattr(la, "tile_walk", spy)
+    # a shape no other test traces, so the wrapper is traced here
+    case = _case(3, (20, 7), 3, jnp.float32, seed=9)
+    out, ref, wmask = _both(*case)
+    np.testing.assert_allclose(out[wmask], ref[wmask], atol=2e-5, rtol=2e-5)
+    assert seen == [((2, 3), HEADS, BS)]
 
 
 def test_two_slots_share_pages(interpret):
