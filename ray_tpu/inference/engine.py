@@ -11,8 +11,8 @@ and re-queues it for recompute — so the answer to memory pressure is
 degraded latency, never an OOM.
 
 The engine knows nothing of the model's family. It is handed `model` and
-`params` and asks the model five things, and nothing else
-(docs/INFERENCE.md, "The model contract"):
+`params` and asks the model five things (and a sixth of a model that has
+it), and nothing else (docs/INFERENCE.md, "The model contract"):
 
 - `model.paged_cache(num_blocks, block_size, mesh, batch_slots)`: the
   paged cache, a pytree the engine donates to every step and never looks
@@ -38,6 +38,14 @@ The engine knows nothing of the model's family. It is handed `model` and
   free slots alone and nothing is ever preempted for blocks), gives the
   step programs a block table zero blocks wide, and bounds a request by
   that context and not by `max_blocks_per_seq`;
+- optionally `model.paged_step_with_chunk(params, tokens[b, 1],
+  chunk_ids[1, c], cache, block_tables, row_pos, write_mask, chunk_bt,
+  chunk_pos, chunk_wmask, chunk_slot, last_idx) -> (logits [b, vocab],
+  the chunk's logits at last_idx [1, vocab], cache)`: a decode step and
+  one sequence's prefill chunk as ONE execution, which reads the weights
+  once for both. The engine looks for it once, when it builds its
+  programs. A model without it is never asked again and keeps the two
+  programs below;
 - `model.place_on_mesh(params, mesh) -> (params, tp)`: tensor-parallel
   placement and the tp degree;
 - `model.early_exit_draft(params) -> (draft_model, draft_params)`: asked
@@ -50,16 +58,23 @@ Two jitted programs serve every request mix, each compiled exactly once:
 - prefill: [1, prefill_chunk] tokens of one sequence (padded chunk),
 - decode:  [batch_slots, 1] — one token for every running slot.
 
-Both thread a device-resident int32[batch_slots] vector, each slot's last
-token, the way they thread the arenas: a final prefill chunk writes its
-token into its slot's row, decode reads its input there and writes its
-output there. So the engine dispatches decode n+1 before it has read the
-tokens of decode n (dispatch-ahead): one execution stays in flight while
-the host reads the one before, does its bookkeeping, runs the callbacks
-and admits. `processed` advances at dispatch; `generated`, the callbacks
-and `_finish` happen at harvest, one execution later. A result that
-arrives for a row that has gone meanwhile (EOS, cancel, preemption) is
-dropped, not emitted.
+A model that offers the fused step gets a third, a decode step with a
+chunk aboard: where a step has a chunk to run AND a row decoding, the one
+execution takes the place of the prefill execution and the decode
+execution after it (not under speculation, nor with adapter banks). A
+chunk that finds no row decoding runs alone as before; no admission waits
+for company. The row whose prompt ends aboard decodes from the next step.
+
+All thread a device-resident int32[batch_slots] vector, each slot's last
+token, the way they thread the arenas: a final prefill chunk (alone or
+aboard) writes its token into its slot's row, decode reads its input
+there and writes its output there. So the engine dispatches decode n+1
+before it has read the tokens of decode n (dispatch-ahead): one execution
+stays in flight while the host reads the one before, does its
+bookkeeping, runs the callbacks and admits. `processed` advances at
+dispatch; `generated`, the callbacks and `_finish` happen at harvest, one
+execution later. A result that arrives for a row that has gone meanwhile
+(EOS, cancel, preemption) is dropped, not emitted.
 
 Speculative decoding (spec_decode_draft_len > 0) swaps the decode step
 for three more fixed-shape programs — draft prefill [1, chunk], propose
@@ -226,6 +241,9 @@ class _InFlight:
     # (request, its slot, its `preemptions` at dispatch): harvest matches
     # on the request and the count, never on the slot alone.
     rows: List[tuple]
+    # Of a decode step's rows the one that is no decode row: the request
+    # whose final chunk rode aboard, and whose first token this is.
+    first: Optional[Request] = None
 
 
 class InferenceEngine:
@@ -349,15 +367,16 @@ class InferenceEngine:
         # lock and never sees half a step.
         self._clock = PhaseClock(PHASES)
         self._ledger = {"n": 0, "decode": 0, "decode_ahead": 0, "prefill": 0,
-                        "decode_rows": 0, "dropped_rows": 0, "wall_s": 0.0}
+                        "chunks_aboard": 0, "decode_rows": 0,
+                        "dropped_rows": 0, "wall_s": 0.0}
         self._publish_steps()
         self._rate_window: List[tuple] = []   # (t, n) recent emissions
         # Which path the paged attention of each program took when it was
         # traced (ops/paged_attention.py's dispatch records).
         self._paged_attn = {"decode": "not traced", "prefill": "not traced"}
         self._shapes = {"prefill": set(), "decode": set(),
-                        "draft_prefill": set(), "propose": set(),
-                        "verify": set()}
+                        "decode_with_chunk": set(), "draft_prefill": set(),
+                        "propose": set(), "verify": set()}
         # Spec-decode accounting: accepted-length histogram [0..k] per
         # verify round (index a = rounds that accepted exactly a drafts).
         self._spec_rounds = 0
@@ -421,6 +440,34 @@ class InferenceEngine:
             self._prefill_fn = prefill_fn
             self._decode_fn = decode_fn
 
+        # A decode step with a chunk aboard, where the model has one (and
+        # the engine neither speculates nor holds adapter banks, which the
+        # fused step does not take): `decode_fn` and `prefill_fn` in one
+        # execution. It is a decode step to whoever counts them, and a
+        # device trace shows it under that name (`jit_decode_fn`, like the
+        # program above); in the engine's own books it is a program of its
+        # own, compiled once.
+        self._decode_with_chunk_fn = None
+        fused = getattr(self._model, "paged_step_with_chunk", None)
+        if (fused is not None and self._draft_len == 0
+                and self._adapters is None):
+            def decode_with_chunk_fn(params, arenas, tokens, bt, pos, wmask,
+                                     ids, chunk_bt, chunk_pos, chunk_wmask,
+                                     last_idx, slot):
+                logits, chunk_logits, arenas = fused(
+                    params, tokens[:, None], ids, arenas, bt, pos, wmask,
+                    chunk_bt, chunk_pos, chunk_wmask, slot, last_idx)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                first = jnp.argmax(chunk_logits, axis=-1).astype(jnp.int32)
+                return jnp.where(wmask[:, 0], nxt, tokens).at[slot].set(
+                    first), arenas
+
+            decode_with_chunk_fn.__name__ = decode_fn.__name__
+            if self.config.use_jit:
+                decode_with_chunk_fn = jax.jit(decode_with_chunk_fn,
+                                               donate_argnums=(1,))
+            self._decode_with_chunk_fn = decode_with_chunk_fn
+
         # Speculative decoding adds exactly three more fixed-shape
         # programs, each compiled once: draft prefill [1, chunk] (keeps
         # the draft's KV in lockstep with the target's), propose (k+1
@@ -473,6 +520,7 @@ class InferenceEngine:
 
     def _program_compiles(self, name: str) -> int:
         fn = {"prefill": self._prefill_fn, "decode": self._decode_fn,
+              "decode_with_chunk": self._decode_with_chunk_fn,
               "draft_prefill": self._draft_prefill_fn,
               "propose": self._propose_fn,
               "verify": self._verify_fn}[name]
@@ -606,8 +654,9 @@ class InferenceEngine:
 
     def step(self) -> bool:
         """One scheduler iteration: admit, dispatch one prefill chunk and
-        one decode step, then harvest (read the tokens of, and do the
-        bookkeeping for) every execution but the newest of this step,
+        one decode step (as one execution where the model has a fused step
+        and rows are decoding), then harvest (read the tokens of, and do
+        the bookkeeping for) every execution but the newest of this step,
         which stays in flight while the callbacks run and the next step
         admits and dispatches. Returns whether any work ran. Callbacks
         fire after the lock is released (they may hop into an asyncio
@@ -621,13 +670,21 @@ class InferenceEngine:
             with self._lock:
                 self._admit()
                 before = len(self._inflight)
-                ran = self._prefill_step()
+                chunk = self._next_chunk()
                 if self._draft_len > 0:
+                    ran = self._prefill_step(chunk)
                     # A round starts from tokens the host holds.
                     ran = self._harvest(emissions, keep=0) or ran
                     ran = self._spec_decode_step(emissions) or ran
                 else:
-                    ran = self._decode_step() or ran
+                    # The chunk rides in the decode step where it can;
+                    # else it runs alone and the decode step after it.
+                    ran = (self._decode_with_chunk_fn is not None
+                           and chunk is not None
+                           and self._decode_step(chunk))
+                    if not ran:
+                        ran = self._prefill_step(chunk)
+                        ran = self._decode_step() or ran
                     # With nothing new to keep the device busy, drain.
                     keep = min(1, len(self._inflight) - before)
                     ran = self._harvest(emissions, keep) or ran
@@ -794,29 +851,38 @@ class InferenceEngine:
 
     # ------------------------------------------------------------- prefill
 
-    def _prefill_step(self) -> bool:
+    def _next_chunk(self) -> Optional[tuple]:
+        """The step's prefill chunk, its blocks claimed and its arrays
+        made: (the request, its tokens in the chunk, the programs'
+        arguments), or None when no sequence is prefilling."""
         import numpy as np
 
         cfg = self.config
         cands = [r for r in self._scheduled() if r.state == PREFILL]
         if not cands:
-            return False
-        clock = self._clock
-        clock.enter(PREFILL_HOST)
+            return None
+        self._clock.enter(PREFILL_HOST)
         req = min(cands, key=self._prio)   # interactive first, then oldest
-        total = req.total_to_prefill
-        chunk = min(cfg.prefill_chunk, total - req.processed)
+        chunk = min(cfg.prefill_chunk, req.total_to_prefill - req.processed)
         if not self._ensure_blocks(req, req.processed + chunk):
-            return False
+            return None
         stream = req.prompt + req.generated
         ids = np.zeros((1, cfg.prefill_chunk), np.int32)
         ids[0, :chunk] = stream[req.processed:req.processed + chunk]
         wmask = np.zeros((1, cfg.prefill_chunk), bool)
         wmask[0, :chunk] = True
         bt = self._block_table_rows([req])
-        args = (ids, bt, np.asarray([req.processed], np.int32), wmask,
-                np.asarray([chunk - 1], np.int32),
-                np.asarray([req.slot], np.int32))
+        return req, chunk, (
+            ids, bt, np.asarray([req.processed], np.int32), wmask,
+            np.asarray([chunk - 1], np.int32),
+            np.asarray([req.slot], np.int32))
+
+    def _prefill_step(self, chunk: Optional[tuple]) -> bool:
+        """Dispatch `_next_chunk`'s chunk as an execution of its own."""
+        if chunk is None or chunk[0].state != PREFILL:
+            return False        # nothing, or a decode row's claim took it
+        req, _, args = chunk
+        clock = self._clock
         clock.enter(PREFILL_DISPATCH)
         self._tokens, self._arenas = self._call(
             "prefill", self._prefill_fn, self._params, self._arenas,
@@ -829,22 +895,33 @@ class InferenceEngine:
                 "draft_prefill", self._draft_prefill_fn,
                 self._draft_params, self._draft_arenas, *args[:4])
         clock.enter(PREFILL_HOST)
-        self._state_resets += (req.processed == 0
-                               and self._slot_state_bytes > 0)
-        req.processed += chunk
         self._ledger["prefill"] += 1
-        if req.processed >= total:
+        if self._chunk_dispatched(chunk):
             # Its token is in the slot's row on the device: the row can
             # decode from this step on, before the host has read it.
-            req.state = DECODE
             self._track(False, [req])
+        return True
+
+    def _chunk_dispatched(self, chunk: tuple) -> bool:
+        """Book a chunk's tokens as written. True when they were the last
+        of the prompt: the request decodes from here."""
+        req, n, _ = chunk
+        self._state_resets += (req.processed == 0
+                               and self._slot_state_bytes > 0)
+        req.processed += n
+        if req.processed < req.total_to_prefill:
+            return False
+        req.state = DECODE
         return True
 
     # -------------------------------------------------------------- decode
 
-    def _decode_step(self) -> bool:
+    def _decode_step(self, chunk: Optional[tuple] = None) -> bool:
         """Dispatch one decode execution for every row that has a token
-        on the device and budget left; reading it is `_harvest`'s."""
+        on the device and budget left; reading it is `_harvest`'s. With
+        `_next_chunk`'s chunk it goes aboard (the fused program), unless a
+        row's block claim preempted its request, and where no row decodes
+        nothing is dispatched: the chunk is left to run alone."""
         import numpy as np
 
         cfg = self.config
@@ -864,6 +941,8 @@ class InferenceEngine:
                   and r.slot is not None]
         if not active:
             return False
+        if chunk is not None and chunk[0].state != PREFILL:
+            chunk = None
         B = cfg.batch_slots
         pos = np.zeros(B, np.int32)
         wmask = np.zeros((B, 1), bool)
@@ -875,23 +954,39 @@ class InferenceEngine:
             wmask[i, 0] = True
         bt = self._block_table_rows(rows)
         clock.enter(DECODE_DISPATCH)
-        self._tokens, self._arenas = self._call(
-            "decode", self._decode_fn, self._params, self._arenas,
-            self._adapter_args(rows), self._tokens, bt, pos, wmask)
+        if chunk is None:
+            self._tokens, self._arenas = self._call(
+                "decode", self._decode_fn, self._params, self._arenas,
+                self._adapter_args(rows), self._tokens, bt, pos, wmask)
+        else:
+            self._tokens, self._arenas = self._call(
+                "decode_with_chunk", self._decode_with_chunk_fn,
+                self._params, self._arenas, self._tokens, bt, pos, wmask,
+                *chunk[2])
         clock.enter(DECODE_HOST)
         self._ledger["decode"] += 1
         self._ledger["decode_ahead"] += any(
             rec.decode for rec in self._inflight)
         for req in active:
             req.processed += 1
-        self._track(True, active)
+        first = None
+        if chunk is not None:
+            self._ledger["chunks_aboard"] += 1
+            if self._chunk_dispatched(chunk):
+                # Its first token comes with the rows' tokens, and it
+                # decodes from the next step.
+                first = chunk[0]
+                active = active + [first]
+        self._track(True, active, first)
         return True
 
-    def _track(self, decode: bool, reqs: List[Request]) -> None:
+    def _track(self, decode: bool, reqs: List[Request],
+               first: Optional[Request] = None) -> None:
         """Book the execution just dispatched: start its tokens' copy to
         the host, and let go of the slot of every row whose budget ends
         with it, so that the next admission does not wait for the
-        harvest. The row keeps its blocks until `_finish`."""
+        harvest. The row keeps its blocks until `_finish`. `first` is the
+        request among them whose final chunk rode aboard a decode step."""
         self._tokens.copy_to_host_async()
         rows = []
         for req in reqs:
@@ -900,7 +995,7 @@ class InferenceEngine:
             if req.budget_dispatched:
                 self._slots[req.slot] = None
                 req.slot = None
-        self._inflight.append(_InFlight(decode, self._tokens, rows))
+        self._inflight.append(_InFlight(decode, self._tokens, rows, first))
 
     def _harvest(self, emissions, keep: int) -> bool:
         """Read the tokens of the oldest executions in flight until
@@ -923,7 +1018,8 @@ class InferenceEngine:
                     self._ledger["dropped_rows"] += 1
                     continue
                 req.inflight -= 1
-                self._ledger["decode_rows"] += rec.decode
+                self._ledger["decode_rows"] += (rec.decode
+                                                and req is not rec.first)
                 self._emit_token(req, tokens[slot], emissions)
         return harvested
 
@@ -1297,6 +1393,8 @@ class InferenceEngine:
             "preemptions": self._preemptions,
             "prefill_compiles": self._program_compiles("prefill"),
             "decode_compiles": self._program_compiles("decode"),
+            "decode_with_chunk_compiles":
+                self._program_compiles("decode_with_chunk"),
             "paged_attn": dict(self._paged_attn),
             # `bytes`: what the cache holds beside the per-slot state
             # (the paged arenas; 0 for a cache with no paged part).

@@ -22,7 +22,9 @@ of a sub-block, eps `rms_norm_eps`; residuals pre-norm):
     logits = W_head RMSNorm(x)                              untied head
 
 What is run is the ABSORBED form, for every step (a decode step and a
-prefill chunk alike; `ops/latent_attention.py`): with W_kvb split a head
+prefill chunk alike, and the two as ONE execution where the engine has a
+chunk to run while rows decode: `paged_step_with_chunk`, the contract's
+optional fused step; `ops/latent_attention.py`): with W_kvb split a head
 into W^K [n, L] and W^V [L, v],
 
     q_lat = q_nope W^K;  score = ([q_lat | q_rope] . [c | k_r]) / sqrt(n + r)
@@ -46,10 +48,12 @@ applied to q and k alike, which no score sees. The cache holds k_r
 half-split. `published_weights` hands the columns back interleaved.
 
 Counters: the expert layers' loads (`held_expert_forward`'s `counts`) are
-added into `cache["moe"]` on the device, decode steps and prefill chunks
-apart, and read when `stats()` is asked (`cache_counters`,
-`counter_stats`: finding (f) of docs/INFERENCE.md). Idle rows (batch and
-chunk padding) are routed to no expert and count for nothing. Beside them
+added into `cache["moe"]` on the device, decode steps and steps that held
+a chunk apart (a chunk alone, or a decode step with a chunk aboard,
+counted WHOLE: its experts are read once for all its rows), and read when
+`stats()` is asked (`cache_counters`, `counter_stats`: finding (f) of
+docs/INFERENCE.md). Idle rows (batch and chunk padding) are routed to no
+expert and count for nothing. Beside them
 `cache["latent_walk"]` counts what the attention kernel's tile rule walks
 (`ops/latent_attention.py tile_walk`, asked once a step: every layer's call
 has the step's positions): grid steps, those that copy anything, the KV
@@ -331,7 +335,24 @@ def cache_locations(block_tables, positions, write_mask, block_size: int):
     return (phys * block_size + positions % block_size).reshape(-1)
 
 
-def _attention(cfg, lp, h, arena, block_tables, positions, write_mask, flat):
+def _a_group_at_a_time(groups, fn, *arrays):
+    """`fn(block_tables, *arrays)` for each row group. One group: the
+    arrays are its own [b, s, ..]. Several: they are [1, T, ..], the
+    groups' rows laid end to end, and are cut apart for `fn` and its
+    results laid back the same way."""
+    if len(groups) == 1:
+        return fn(groups[0][0], *arrays)
+    outs, at = [], 0
+    for block_tables, s in groups:
+        b = block_tables.shape[0]
+        out = fn(block_tables, *(a[0, at:at + b * s].reshape(
+            (b, s) + a.shape[2:]) for a in arrays))
+        outs.append(out.reshape((1, b * s) + out.shape[2:]))
+        at += b * s
+    return jnp.concatenate(outs, axis=1)
+
+
+def _attention(cfg, lp, h, arena, groups, positions, write_mask, flat):
     b, s, _ = h.shape
     nb, bsz, width = arena.shape
     with jax.named_scope("mla_q"):
@@ -341,9 +362,15 @@ def _attention(cfg, lp, h, arena, block_tables, positions, write_mask, flat):
         arena = arena.reshape(nb * bsz, width).at[flat].set(
             rows.reshape(-1, width)).reshape(nb, bsz, width)
     with jax.named_scope("latent_attn"):
-        o_lat = latent_attention(
-            q, arena, block_tables, positions, write_mask,
-            latent=cfg.kv_lora_rank, scale=1.0 / math.sqrt(cfg.qk_head_dim))
+        # Every group's rows are in the arena by now; each group is one
+        # call of the kernel at its own shape.
+        o_lat = _a_group_at_a_time(
+            groups, lambda block_tables, q, positions, live:
+            latent_attention(
+                q, arena, block_tables, positions, live,
+                latent=cfg.kv_lora_rank,
+                scale=1.0 / math.sqrt(cfg.qk_head_dim)),
+            q, positions, write_mask)
     with jax.named_scope("mla_out"):
         o = jnp.einsum("bshl,hlv->bshv", o_lat, lp["w_uv"],
                        preferred_element_type=jnp.float32).astype(cfg.dtype)
@@ -377,12 +404,15 @@ def routed_experts(cfg, lp, n, live, held=None):
     return y, counts, routing
 
 
-def _block(cfg, lp, x, arena, block_tables, positions, write_mask, flat):
-    """One block: (x after it, the arena, an expert layer's (counts,
-    routing) or None)."""
+def _block(cfg, lp, x, arena, groups, positions, write_mask, flat):
+    """One block on x [b, s, hidden] of one row group, or [1, T, hidden] of
+    several laid end to end (`groups`: each one's (block tables, s)): (x
+    after it, the arena, an expert layer's (counts, routing) or None).
+    Everything but the attention is a token's own and reads its weights
+    once for all T."""
     dt = cfg.dtype
     h = _rms_norm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dt)
-    out, arena = _attention(cfg, lp, h, arena, block_tables, positions,
+    out, arena = _attention(cfg, lp, h, arena, groups, positions,
                             write_mask, flat)
     x = x + out
     n = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(dt)
@@ -463,16 +493,67 @@ class DeepseekV3:
         the cache). `slots` is not looked at: nothing is kept per slot."""
         if adapters is not None:
             raise ValueError("DeepseekV3 has no adapter banks")
+
+        def read(x):
+            if last_idx is None:
+                return x
+            return jnp.take_along_axis(x, last_idx[:, None, None],
+                                       axis=1)[:, 0]
+
+        return self._step(
+            params, cache, [(ids, block_tables, row_pos, write_mask)], read)
+
+    def paged_step_with_chunk(self, params, tokens, chunk_ids, cache,
+                              block_tables, row_pos, write_mask, chunk_bt,
+                              chunk_pos, chunk_wmask, chunk_slot, last_idx):
+        """A decode step with one sequence's prefill chunk aboard (the
+        contract's optional answer): `paged_step` of tokens [b, 1] and
+        `paged_step` of chunk_ids [1, c] at `last_idx` [1] as ONE
+        execution, in which every weight is read once for the b + c rows
+        and only the attention is two calls. The engine masks the chunk's
+        own slot among the decode rows. Returns (the decode rows' logits
+        [b, vocab], the chunk's [1, vocab], the cache). `chunk_slot` is not
+        looked at: nothing is kept per slot."""
+        b = tokens.shape[0]
+        logits, cache = self._step(
+            params, cache,
+            [(tokens, block_tables, row_pos, write_mask),
+             (chunk_ids, chunk_bt, chunk_pos, chunk_wmask)],
+            lambda x: jnp.concatenate([x[0, :b], x[0, b + last_idx]]))
+        return logits[:b], logits[b:], cache
+
+    def _step(self, params, cache, groups, read):
+        """The one body of a step over ROW GROUPS, each (ids [b, s],
+        block_tables, row_pos, write_mask): (the logits of the rows `read`
+        picks from the last block's x, the cache). One group is a decode
+        step or a prefill chunk, x [b, s, hidden]. Several are laid end to
+        end, x [1, T, hidden], through everything that is a token's own
+        (the embedding, the norms, the products, the expert layer: T rows
+        against one read of the weights) and cut apart for the attention
+        alone (`_a_group_at_a_time`). Counted whole under kind 1, "a step
+        that held a chunk", where any group is longer than one token."""
         cfg = self.config
-        s = ids.shape[1]
-        positions = row_pos[:, None] + jnp.arange(s)[None, :]
         bsz = cache["latent"][0].shape[1]
-        flat = cache_locations(block_tables, positions, write_mask, bsz)
+        placed = []              # a group's (positions [b, s], flat [b * s])
+        for ids, block_tables, row_pos, write_mask in groups:
+            positions = row_pos[:, None] + jnp.arange(ids.shape[1])[None, :]
+            placed.append((positions, cache_locations(
+                block_tables, positions, write_mask, bsz)))
+        if len(groups) > 1:
+            ids, positions, write_mask = (
+                jnp.concatenate([a.reshape(1, -1) for a in arrays], axis=1)
+                for arrays in ([g[0] for g in groups],
+                               [p for p, _ in placed],
+                               [g[3] for g in groups]))
+            flat = jnp.concatenate([f for _, f in placed])
+        else:
+            (ids, _, _, write_mask), (positions, flat) = groups[0], placed[0]
+        shapes = [(g[1], g[0].shape[1]) for g in groups]
         x = params["embed"][ids]
         arenas, counts, record = [], [], cache["routing"]
         for lp, arena in zip(params["layers"], cache["latent"]):
-            x, arena, routed = _block(cfg, lp, x, arena, block_tables,
-                                      positions, write_mask, flat)
+            x, arena, routed = _block(cfg, lp, x, arena, shapes, positions,
+                                      write_mask, flat)
             arenas.append(arena)
             if routed is None:
                 continue
@@ -480,21 +561,19 @@ class DeepseekV3:
                 with jax.named_scope("moe_record"):
                     record = record.at[:, flat].set(routed[1])
             counts.append(routed[0])
-        if last_idx is not None:
-            x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-        x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = _rms_norm(read(x), params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.dot(x.astype(cfg.dtype), params["lm_head"],
                          preferred_element_type=jnp.float32)
-        kind = 0 if s == 1 else 1
+        kind = int(any(s > 1 for _, s in shapes))
         counters = cache["moe"]
         if counts:
             counters = _count(counters, kind, counts)
-        walked = tile_walk(
-            positions, write_mask, heads=cfg.num_attention_heads,
-            block_size=bsz, max_ctx=block_tables.shape[1] * bsz,
-            dtype=cfg.dtype)[2]
-        walk = {k: v.at[kind].add(walked[k])
-                for k, v in cache["latent_walk"].items()}
+        walk = cache["latent_walk"]
+        for (_, block_tables, _, live), (at, _) in zip(groups, placed):
+            walked = tile_walk(
+                at, live, heads=cfg.num_attention_heads, block_size=bsz,
+                max_ctx=block_tables.shape[1] * bsz, dtype=cfg.dtype)[2]
+            walk = {k: v.at[kind].add(walked[k]) for k, v in walk.items()}
         return logits, {"latent": arenas, "routing": record,
                         "moe": counters, "latent_walk": walk}
 

@@ -1,0 +1,156 @@
+"""The engine's step programs ALONE at the Kanana-2 cell's shapes, on the
+chip: ms an execution of `decode_fn` (31 of 32 slots live at contexts of
+8,300-8,800), of `prefill_fn` (one chunk of 256 positions of which 160 are
+live behind 8,192 cached tokens) and of the fused program that runs both as
+one (a decode step with the chunk aboard, `models/deepseek_v3.py
+paged_step_with_chunk`), and the experts a layer each draws. ROADMAP caveat
+9: time a program alone before predicting what an engine gains from it.
+
+    chiprun -- python3 scripts/time_serve_steps.py
+
+One JSON line on stdout, the same in `chiprun_out/serve_steps.json`. The
+model is the cell's configuration (`benchmarks/configs/<--config>.json`)
+with seeded weights and an arena of seeded rows; the programs are the
+engine's own jitted objects, called as `_decode_step` calls them.
+`--rehearsal` under RAY_TPU_PALLAS_INTERPRET=1 runs a tiny configuration
+on the CPU (times of the interpreter: no device number).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from ray_tpu.inference.engine import (EngineConfig,  # noqa: E402
+                                      InferenceEngine)
+from ray_tpu.models.deepseek_v3 import (DeepseekV3,  # noqa: E402
+                                        DeepseekV3Config)
+
+
+def build(args):
+    if args.rehearsal:
+        model = DeepseekV3(DeepseekV3Config.tiny())
+        engine_cfg = dict(batch_slots=3, block_size=16, num_blocks=40,
+                          max_blocks_per_seq=12, prefill_chunk=16)
+        prefix, live = 128, 10
+    else:
+        with open(os.path.join(ROOT, "benchmarks", "configs",
+                               args.config + ".json")) as f:
+            cfg = json.load(f)
+        model = DeepseekV3(DeepseekV3Config.from_published(
+            cfg, dtype=jnp.bfloat16))
+        engine_cfg, prefix, live = cfg["engine"], 8192, args.live
+    params = model.init(jax.random.PRNGKey(args.seed % (2 ** 31)))
+    engine = InferenceEngine(
+        EngineConfig(prefix_cache_enabled=False, **engine_cfg), model=model,
+        params=params)
+    return model, engine, prefix, live
+
+
+def arguments(engine, prefix: int, live: int, seed: int):
+    """(decode's, the chunk's) arguments: every slot its own shuffled
+    blocks, the last slot the chunk's (dead among the decode rows)."""
+    cfg = engine.config
+    rng = np.random.default_rng(seed)
+    slots, width, bsz = cfg.batch_slots, cfg.max_blocks_per_seq, \
+        cfg.block_size
+    reach = width * bsz
+    tables = 1 + rng.permutation(cfg.num_blocks - 1)[:slots * width].reshape(
+        slots, width).astype(np.int32)
+    pos = rng.integers(min(prefix + 108, reach - 2),
+                       min(prefix + 609, reach), slots).astype(np.int32)
+    wmask = np.ones((slots, 1), bool)
+    wmask[-1] = False
+    chunk = cfg.prefill_chunk
+    vocab = engine._model.config.vocab_size
+    ids = rng.integers(1, vocab, (1, chunk)).astype(np.int32)
+    chunk_args = (ids, tables[-1:], np.asarray([prefix], np.int32),
+                  np.arange(chunk)[None] < live,
+                  np.asarray([live - 1], np.int32),
+                  np.asarray([slots - 1], np.int32))
+    return (tables, pos, wmask), chunk_args
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--config", default="kanana-2-30b-a3b-l8-serve")
+    parser.add_argument("--seed", type=int, default=2718281829)
+    parser.add_argument("--live", type=int, default=160,
+                        help="the chunk's live positions")
+    parser.add_argument("--calls", type=int, default=30)
+    parser.add_argument("--rehearsal", action="store_true")
+    args = parser.parse_args()
+    model, engine, prefix, live = build(args)
+    # Seeded rows in place of the zeros, an arena at a time into its own
+    # buffer: weights and arenas fill the chip.
+    fill = jax.jit(lambda a, k: (0.5 * jax.random.normal(
+        k, a.shape, jnp.float32)).astype(a.dtype), donate_argnums=0)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(
+        engine._arenas["latent"]))
+    engine._arenas["latent"] = [
+        fill(a, k) for k, a in zip(keys, engine._arenas["latent"])]
+    decode_args, chunk_args = arguments(engine, prefix, live, args.seed)
+    vocab = model.config.vocab_size
+    engine._tokens = jnp.asarray(np.random.default_rng(args.seed).integers(
+        1, vocab, engine.config.batch_slots), jnp.int32)
+    head = lambda: (engine._params, engine._arenas)  # noqa: E731
+    programs = {
+        "decode": lambda: engine._decode_fn(
+            *head(), None, engine._tokens, *decode_args),
+        "prefill": lambda: engine._prefill_fn(
+            *head(), None, engine._tokens, *chunk_args),
+        "decode_with_chunk": lambda: engine._decode_with_chunk_fn(
+            *head(), engine._tokens, *decode_args, *chunk_args)}
+    if args.rehearsal:
+        args.calls = 2
+
+    def counters():
+        return model.counter_stats(jax.device_get(
+            model.cache_counters(engine._arenas)))["moe"]
+
+    device = jax.devices()[0]
+    line = {"seed": args.seed, "live": live, "calls": args.calls,
+            "platform": device.platform, "device_kind": device.device_kind,
+            "ms": {}, "experts_drawn_a_layer": {}, "compile_s": {}}
+    for name, run in programs.items():
+        start = time.perf_counter()
+        engine._tokens, engine._arenas = run()
+        jax.block_until_ready(engine._tokens)
+        line["compile_s"][name] = time.perf_counter() - start
+        before, best = counters(), float("inf")
+        for _ in range(3):
+            start = time.perf_counter()
+            for _ in range(args.calls):
+                # the tokens are not fed back: every call the same rows
+                _, engine._arenas = run()
+            jax.block_until_ready(engine._arenas["moe"]["steps"])
+            best = min(best, (time.perf_counter() - start) / args.calls)
+        after = counters()
+        line["ms"][name] = best * 1e3
+        drew = sum(after[k]["drew"] - before[k]["drew"]
+                   for k in ("decode", "prefill"))
+        line["experts_drawn_a_layer"][name] = drew / (
+            3 * args.calls * after["layers"])
+    ms = line["ms"]
+    line["saved_ms"] = ms["decode"] + ms["prefill"] - ms["decode_with_chunk"]
+    print(json.dumps(line), flush=True)
+    out = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "serve_steps.json"), "w") as f:
+        json.dump(line, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

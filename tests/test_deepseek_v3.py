@@ -31,9 +31,8 @@ from ray_tpu.ops import held_experts as moe  # noqa: E402
 TOL = 5e-6
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    cfg = DeepseekV3Config.tiny()
+def _model(**overrides):
+    cfg = DeepseekV3Config.tiny(**overrides)
     model = DeepseekV3(cfg)
     params = model.init(jax.random.PRNGKey(1))
     keys = iter(jax.random.split(jax.random.PRNGKey(2), 64))
@@ -46,6 +45,18 @@ def tiny():
               "layers": [jitter(lp) for lp in params["layers"]]}
     pub = {k: v for k, v in dataclasses.asdict(cfg).items() if k != "dtype"}
     return model, params, pub
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return _model()
+
+
+@pytest.fixture(scope="module")
+def two_layers():
+    """One dense layer and one of experts: half of `tiny`'s time to
+    compile, for the tests that build engines of their own."""
+    return _model(num_hidden_layers=2)
 
 
 def reference_logits(tiny, ids, **kwargs):
@@ -304,11 +315,20 @@ def test_the_expert_layers_counters_reach_stats(tiny, served):
     first, final = served["after_interleaved"]["moe"], served["final"]["moe"]
     assert first["layers"] == 2 and first["experts"] == 8
     per_token = cfg.num_experts_per_tok * cfg.n_moe_layers
-    # prefill: the three prompts' tokens; decode: every token but a
-    # request's first (which its last chunk gives) passes through once
-    assert first["prefill"]["assigned"] == (5 + 3 + 40) * per_token
+    # "prefill" is every step that held a chunk, one a chunk: the three
+    # prompts' tokens and, since a chunk rides in the decode step where
+    # rows decode, those rows' too. "decode" is the plain decode steps.
+    # Every token but a request's first (which its last chunk gives)
+    # passes through as a decode row once.
     assert first["prefill"]["steps"] == 1 + 1 + 3
-    assert first["decode"]["assigned"] == (8 + 7 + 5) * per_token
+    steps = served["after_interleaved"]["steps"]
+    assert steps["chunks_aboard"] + steps["prefill"] == 1 + 1 + 3
+    assert first["decode"]["steps"] == steps["decode"] \
+        - steps["chunks_aboard"]
+    assert steps["chunks_aboard"] >= 3     # the long prompt's, at least
+    assert first["prefill"]["assigned"] >= (5 + 3 + 40) * per_token
+    assert first["prefill"]["assigned"] + first["decode"]["assigned"] \
+        == (5 + 3 + 40 + 8 + 7 + 5) * per_token
     for kind in ("decode", "prefill"):
         assert {"steps", "assigned", "placed", "tiles", "drew",
                 "max_over_mean", "assignments_per_step",
@@ -326,7 +346,8 @@ def test_the_expert_layers_counters_reach_stats(tiny, served):
     assert sum(first["load"]) == first["decode"]["assigned"] \
         + first["prefill"]["assigned"]
     # after fail_all: only what the rebuilt cache saw
-    assert final["prefill"]["assigned"] == (38 + 18) * per_token
+    assert final["prefill"]["assigned"] + final["decode"]["assigned"] \
+        == (38 + 18 + 3 + 5) * per_token
 
 
 def test_the_latent_walk_reaches_stats(tiny):
@@ -371,6 +392,195 @@ def test_the_latent_walk_reaches_stats(tiny):
     counts = la.tile_walk(jnp.array([[1101], [0]]),
                           jnp.array([[True], [False]]), **rule)[2]
     assert {k: 3 * int(v) for k, v in counts.items()} == walk["decode"]
+
+
+# --------------------------------------------------------------------------- #
+# a chunk aboard the decode step
+# --------------------------------------------------------------------------- #
+
+
+class _NoFusedStep:
+    """The model with its fused step hidden: an engine over it keeps the
+    two programs."""
+
+    def __init__(self, model):
+        self._model = model
+
+    def __getattr__(self, name):
+        if name == "paged_step_with_chunk":
+            raise AttributeError(name)
+        return getattr(self._model, name)
+
+
+def test_the_fused_step_is_the_two_steps(tiny):
+    """`paged_step_with_chunk` against `paged_step` called twice, chunk
+    first as the engine's two programs run: the logits and the whole
+    cache, with a dead slot among the decode rows (the chunk's own), a
+    padded chunk over a prefix of its own and counters that book the fused
+    step whole as a step that held a chunk."""
+    model, params, _ = tiny
+    slots, chunk, bsz, width = 3, 16, 16, 4
+    cache = model.paged_cache(1 + (slots + 1) * width, bsz)
+    tables = 1 + jnp.arange((slots + 1) * width, dtype=jnp.int32).reshape(
+        slots + 1, width)
+    ids = jax.random.randint(jax.random.PRNGKey(7), (1, chunk), 1, 96)
+    # what came before: rows of the latent's size, whatever they hold
+    keys = jax.random.split(jax.random.PRNGKey(8), len(cache["latent"]))
+    cache["latent"] = [jax.random.normal(k, a.shape, a.dtype)
+                       for k, a in zip(keys, cache["latent"])]
+    tokens = jnp.array([[5], [9], [0]], jnp.int32)
+    pos, wmask = jnp.array([21, 37, 0]), jnp.array([[True], [True], [False]])
+    chunk_ids = jnp.where(jnp.arange(chunk)[None] < 11, ids, 0)
+    chunk_live = jnp.arange(chunk)[None] < 11
+    chunk_pos, last = jnp.array([16]), jnp.array([10])
+    chunk_slot = jnp.array([2], jnp.int32)
+
+    @jax.jit
+    def twice(cache):
+        chunk_logits, cache = model.paged_step(
+            params, chunk_ids, cache, tables[3:], chunk_pos, chunk_live,
+            None, chunk_slot, last)
+        logits, cache = model.paged_step(params, tokens, cache, tables[:3],
+                                         pos, wmask)
+        return logits[:, -1], chunk_logits, cache
+
+    @jax.jit
+    def fused(cache):
+        return model.paged_step_with_chunk(
+            params, tokens, chunk_ids, cache, tables[:3], pos, wmask,
+            tables[3:], chunk_pos, chunk_live, chunk_slot, last)
+
+    want, got = twice(cache), fused(cache)
+    assert got[0].shape == (slots, 96) and got[1].shape == (1, 96)
+    for a, b in zip(want[:2], got[:2]):
+        assert float(jnp.abs(a[:2] - b[:2]).max()) <= TOL
+    assert float(jnp.abs(want[0]).max()) > 1e-2
+    # the cache: latent rows and the routing record everywhere but the
+    # trash block, where both write their dead rows
+    for a, b in zip(want[2]["latent"], got[2]["latent"]):
+        assert float(jnp.abs(a[1:] - b[1:]).max()) <= TOL
+    np.testing.assert_allclose(want[2]["routing"][:, bsz:],
+                               got[2]["routing"][:, bsz:], atol=TOL)
+    # the counters: the same loads, under "a step that held a chunk"
+    for name in ("assigned", "placed", "load"):
+        a, b = want[2]["moe"][name], got[2]["moe"][name]
+        np.testing.assert_array_equal(a.sum(0), b.sum(0))
+        np.testing.assert_array_equal(b[0], cache["moe"][name][0])
+    assert [int(v) for v in got[2]["moe"]["steps"]
+            - cache["moe"]["steps"]] == [0, 1]
+    for name in dsv3.WALK_COUNTS:
+        a, b = want[2]["latent_walk"][name], got[2]["latent_walk"][name]
+        assert int(a.sum()) == int(b.sum())
+        assert int(b[0]) == int(cache["latent_walk"][name][0])
+
+
+def _cached_rows(engine, req):
+    """(first-layer latent rows, routing-record columns) of the request's
+    whole blocks, found again through the radix cache it donated them to."""
+    bsz = engine.config.block_size
+    stream = req.prompt + req.generated
+    blocks, _ = engine._prefix.match(stream[:len(stream) // bsz * bsz])
+    assert len(blocks) == min(req.processed, len(stream)) // bsz
+    at = (np.asarray(blocks)[:, None] * bsz + np.arange(bsz)).reshape(-1)
+    arena = np.asarray(engine._arenas["latent"][0])
+    return arena.reshape(-1, arena.shape[-1])[at], \
+        np.asarray(engine._arenas["routing"])[:, at]
+
+
+def test_a_chunk_aboard_changes_nothing_that_is_served(two_layers):
+    """The same engine over the model and over the model with its fused
+    step hidden: the same greedy tokens for a mix whose chunks land while
+    others decode (prompts of one to three chunks, a prefix adoption), and
+    the same rows left in the first layer's arena and the routing
+    record."""
+    model, params, _ = two_layers
+
+    def serve(model):
+        engine = InferenceEngine(
+            EngineConfig(batch_slots=3, block_size=16, num_blocks=40,
+                         max_blocks_per_seq=6, prefill_chunk=16),
+            model=model, params=params)
+        doc = prompt(32, 4)
+        engine.add_request(doc, 1)
+        engine.run_until_idle()
+        mix = [(prompt(5, 1), 12), (prompt(40, 3), 9), (prompt(3, 2), 14),
+               (doc + prompt(20, 6), 7), (prompt(17, 7), 6)]
+        reqs = [engine.add_request(p, n) for p, n in mix]
+        engine.run_until_idle()
+        engine.check_no_leaks()
+        assert reqs[3].cached_tokens == 32
+        return engine, reqs
+
+    (fused, got), (plain_engine, want) = serve(model), serve(
+        _NoFusedStep(model))
+    for a, b in zip(got, want):
+        assert a.state == b.state == "FINISHED", (a.error, b.error)
+        assert a.generated == b.generated
+        for x, y in zip(_cached_rows(fused, a),
+                        _cached_rows(plain_engine, b)):
+            assert x.shape == y.shape and x.size and np.abs(x).max() > 0.1
+            np.testing.assert_allclose(x, y, atol=TOL)
+    assert len({tuple(r.generated[:4]) for r in got}) == len(got)
+    steps, plain_steps = fused.step_stats(), plain_engine.step_stats()
+    # 1 + 3 + 1 + 2 + 2 chunks of the mix: all but the first, which found
+    # no row decoding, rode; the document's two ran alone before them
+    assert (steps["prefill"], steps["chunks_aboard"]) == (3, 8)
+    assert (plain_steps["prefill"], plain_steps["chunks_aboard"]) == (11, 0)
+    stats = fused.stats()
+    assert stats["prefill_compiles"] == stats["decode_compiles"] \
+        == stats["decode_with_chunk_compiles"] == 1
+    assert plain_engine.stats()["decode_with_chunk_compiles"] == 0
+    assert set(stats["paged_attn"]) == {"decode", "prefill"}
+
+
+def _case_a_row_takes_the_chunks_blocks(engine_of):
+    """Three blocks: the decoding row's claim of a second finds none and
+    preempts the request whose chunk was about to ride; the step is a
+    plain decode step."""
+    engine = engine_of(batch_slots=2, block_size=4, num_blocks=4,
+                       max_blocks_per_seq=3, prefill_chunk=8)
+    return engine, [engine.add_request(prompt(3, 1), 6),
+                    engine.add_request(prompt(10, 2), 2)], 1, 0
+
+
+def _case_the_chunk_takes_a_rows_blocks(engine_of):
+    """The third chunk of an interactive request (two rode) claims its
+    blocks from the batch request that decodes: it runs alone."""
+    engine = engine_of(batch_slots=2, block_size=4, num_blocks=5,
+                       max_blocks_per_seq=4, prefill_chunk=4)
+    first = engine.add_request(prompt(3, 1), 9, slo_class="batch")
+    for _ in range(2):
+        engine.step()
+    return engine, [first, engine.add_request(prompt(11, 2), 3)], 0, 2
+
+
+PREEMPTIONS = {"a_row_takes_the_chunks_blocks":
+               _case_a_row_takes_the_chunks_blocks,
+               "the_chunk_takes_a_rows_blocks":
+               _case_the_chunk_takes_a_rows_blocks}
+
+
+@pytest.mark.parametrize("case", sorted(PREEMPTIONS))
+def test_a_preemption_inside_a_fused_steps_claims_leaks_nothing(two_layers,
+                                                                case):
+    model, params, _ = two_layers
+
+    def engine_of(**cfg):
+        return InferenceEngine(EngineConfig(prefix_cache_enabled=False, **cfg),
+                               model=model, params=params)
+
+    engine, reqs, victim, rode = PREEMPTIONS[case](engine_of)
+    engine.run_until_idle()
+    assert engine.step_stats()["chunks_aboard"] == rode
+    assert reqs[victim].preemptions >= 1
+    assert reqs[1 - victim].preemptions == 0
+    for req in reqs:
+        assert req.state == "FINISHED", req.error
+        assert len(req.generated) == req.max_new_tokens
+        assert gaps_of(two_layers, req) <= TOL
+    assert not engine.has_work() and not engine._inflight
+    engine.check_no_leaks()
+    assert engine.stats()["kv"]["blocks_in_use"] == 0
 
 
 def test_published_keys_make_the_configuration():

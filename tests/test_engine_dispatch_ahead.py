@@ -4,6 +4,7 @@ what is computed may change: every request receives the tokens, in the
 order, of a plain one-row-at-a-time greedy loop over `Llama.decode_paged`,
 whatever leaves the batch while a step is in flight."""
 
+import functools
 import random
 import sys
 import threading
@@ -295,17 +296,19 @@ class _Spy:
 
 
 def _spy_on_programs(engine, log):
-    """Wrap both programs: log ("call", n) per decode execution, hand
-    out spied token outputs, take them back as the next input."""
+    """Wrap the programs: log ("call", n) per decode execution (with a
+    chunk aboard or without), hand out spied token outputs, take them back
+    as the next input."""
     decode_fn, prefill_fn = engine._decode_fn, engine._prefill_fn
+    with_chunk_fn = engine._decode_with_chunk_fn
 
     def bare(args):
         return [a.tokens if isinstance(a, _Spy) else a for a in args]
 
-    def decode(*args):
+    def decode(*args, fn=decode_fn):
         n = 1 + sum(1 for kind, _ in log if kind == "call")
         log.append(("call", n))
-        tokens, arenas = decode_fn(*bare(args))
+        tokens, arenas = fn(*bare(args))
         return _Spy(tokens, n, log), arenas
 
     def prefill(*args):
@@ -313,6 +316,9 @@ def _spy_on_programs(engine, log):
         return _Spy(tokens, "prefill", log), arenas
 
     engine._decode_fn, engine._prefill_fn = decode, prefill
+    if with_chunk_fn is not None:
+        engine._decode_with_chunk_fn = functools.partial(decode,
+                                                         fn=with_chunk_fn)
 
 
 def test_next_decode_is_dispatched_before_the_last_one_is_read(tiny_llama,
@@ -335,6 +341,72 @@ def test_next_decode_is_dispatched_before_the_last_one_is_read(tiny_llama,
     assert steps["decode_ahead"] == steps["decode"] - 1
     assert steps["dropped_rows"] == 0
     _idle_and_clean(engine)
+
+
+def test_a_chunk_aboard_keeps_one_execution_in_flight():
+    """A model that offers the fused step (the contract test's toy): the
+    row whose prompt ends aboard a decode step has its first token read a
+    step later, with the rows' tokens and once, and decodes from the step
+    after the one it rode in, which is dispatched before that read."""
+    from test_engine_model_contract import BagModel, RidingBagModel
+
+    def serve(model, spy):
+        engine = InferenceEngine(
+            EngineConfig(batch_slots=3, block_size=4, num_blocks=32,
+                         max_blocks_per_seq=8, prefill_chunk=8,
+                         prefix_cache_enabled=False),
+            model=model, params=model.init(7))
+        log = []
+        if spy:
+            _spy_on_programs(engine, log)
+        streams = _Streams()
+        first = streams.submit(engine, [t % 60 + 1 for t in _prompt(5, 10)],
+                               14)
+        while not first.generated:
+            engine.step()
+        rider = streams.submit(engine, [t % 60 + 1 for t in _prompt(11, 30)],
+                               6)
+        return engine, log, streams, first, rider
+
+    engine, log, streams, first, rider = serve(RidingBagModel(), spy=True)
+    assert engine.step()              # the first of its two chunks rides
+    assert rider.state == eng.PREFILL and rider.inflight == 0
+    before = engine.step_stats()
+    assert engine.step()              # the second: its prompt ends aboard
+    after = engine.step_stats()
+    assert after["chunks_aboard"] - before["chunks_aboard"] == 1
+    assert after["decode"] - before["decode"] == 1
+    assert after["prefill"] == before["prefill"] == 1
+    assert rider.state == eng.DECODE and rider.processed == 11
+    assert rider.inflight == 1 and not rider.generated
+    assert len(engine._inflight) == 1 and engine._inflight[0].decode
+    assert engine._inflight[0].first is rider
+    rode_in = max(n for kind, n in log if kind == "call")
+    assert ("read", rode_in) not in log
+    assert engine.step()              # it decodes; then its first is read
+    assert rider.processed == 12 and rider.inflight == 1
+    assert len(rider.generated) == 1 == len(streams.tokens[rider.request_id])
+    assert log.index(("call", rode_in + 1)) < log.index(("read", rode_in))
+    engine.run_until_idle()
+    calls = [n for kind, n in log if kind == "call"]
+    for n in calls[:-1]:
+        assert log.index(("call", n + 1)) < log.index(("read", n)), (n, log)
+    steps = engine.step_stats()
+    assert steps["decode_ahead"] == steps["decode"] - 1 == len(calls) - 1
+    assert steps["dropped_rows"] == 0
+    assert steps["decode_rows"] == (14 - 1) + (6 - 1)
+    _idle_and_clean(engine)
+    # The tokens, and their order at the clients, are those of the two
+    # programs apart.
+    plain_engine, _, plain_streams, *want = serve(BagModel(), spy=False)
+    plain_engine.run_until_idle()
+    assert plain_engine.step_stats()["chunks_aboard"] == 0
+    for got, req in zip((first, rider), want):
+        assert streams.tokens[got.request_id] == got.generated \
+            == req.generated == plain_streams.tokens[req.request_id]
+        assert len(got.generated) == got.max_new_tokens
+    assert sorted(streams.finished) == sorted(
+        r.request_id for r in (first, rider))
 
 
 def test_speculation_stays_synchronous(tiny_llama, plain):

@@ -70,9 +70,26 @@ class BagModel:
         return logits, {"mem": mem, "steps": cache["steps"] + 1}
 
 
-@pytest.fixture(scope="module")
-def bag():
-    model = BagModel()
+class RidingBagModel(BagModel):
+    """`BagModel` with the contract's optional sixth answer, spelled as the
+    two steps it stands for: the engine lets a prefill chunk ride in the
+    decode step of a model that has it, and only of such a model."""
+
+    def paged_step_with_chunk(self, params, tokens, chunk_ids, cache,
+                              block_tables, row_pos, write_mask, chunk_bt,
+                              chunk_pos, chunk_wmask, chunk_slot, last_idx):
+        chunk_logits, cache = self.paged_step(
+            params, chunk_ids, cache, chunk_bt, chunk_pos, chunk_wmask, None,
+            chunk_slot, last_idx)
+        logits, cache = self.paged_step(params, tokens, cache, block_tables,
+                                        row_pos, write_mask)
+        return logits[:, -1], chunk_logits, cache
+
+
+@pytest.fixture(scope="module", params=[BagModel, RidingBagModel],
+                ids=["two_programs", "a_fused_step_too"])
+def bag(request):
+    model = request.param()
     return model, model.init(7)
 
 
@@ -130,7 +147,12 @@ def _case_chunked_prefill_and_decode(bag):
            (_prompt(13, 41), 12), (_prompt(2, 17), 7)]
     reqs = [engine.add_request(p, m) for p, m in mix]
     engine.run_until_idle()
-    assert engine.step_stats()["prefill"] == 1 + 3 + 1 + 2 + 1
+    steps = engine.step_stats()
+    assert steps["prefill"] + steps["chunks_aboard"] == 1 + 3 + 1 + 2 + 1
+    # The first chunk finds no row decoding; the others ride where they
+    # may.
+    assert steps["chunks_aboard"] == (
+        7 if isinstance(bag[0], RidingBagModel) else 0)
     return engine, list(zip(reqs, mix))
 
 
@@ -197,11 +219,20 @@ def test_the_engine_serves_a_model_that_is_no_llama(bag, case):
     engine.check_no_leaks()
     stats = engine.stats()
     assert stats["prefill_compiles"] == stats["decode_compiles"] == 1
-    # Every execution of both programs went through the one cache (the
-    # rebuilt one has missed those before `fail_all`).
+    # Every execution of the programs went through the one cache (the
+    # rebuilt one has missed those before `fail_all`); a fused execution
+    # is this model's two steps.
     steps = engine.step_stats()
-    ran = steps["prefill"] + steps["decode"]
+    ran = steps["prefill"] + steps["decode"] + steps["chunks_aboard"]
     assert int(engine._arenas["steps"]) == ran or case.startswith("fail_all")
+    # The engine asked for the fused step once, when it was built: a model
+    # without one has no third program and never reaches that branch.
+    if isinstance(bag[0], RidingBagModel):
+        assert stats["decode_with_chunk_compiles"] <= 1
+    else:
+        assert engine._decode_with_chunk_fn is None
+        assert steps["chunks_aboard"] == 0 \
+            == stats["decode_with_chunk_compiles"]
 
 
 # ------------------------------- a model whose cache has no paged part
@@ -358,6 +389,7 @@ def test_without_adapters_the_one_spelling_lowers_to_the_old_text(
                      max_blocks_per_seq=16, prefill_chunk=8),
         model=model, params=params)
     cfg = engine.config
+    assert engine._decode_with_chunk_fn is None     # `Llama` offers none
     b, s = {"decode": (cfg.batch_slots, 1),
             "prefill": (1, cfg.prefill_chunk)}[program]
     bt = np.zeros((b, cfg.max_blocks_per_seq), np.int32)

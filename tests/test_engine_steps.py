@@ -119,6 +119,70 @@ def test_ledger_counts_what_the_requests_imply(tiny_llama):
     engine.check_no_leaks()
 
 
+@pytest.fixture(scope="module")
+def tiny_deepseek():
+    """A model that offers the fused step (docs/INFERENCE.md, "The model
+    contract"): two layers, one of experts."""
+    import jax
+
+    from ray_tpu.models.deepseek_v3 import DeepseekV3, DeepseekV3Config
+
+    model = DeepseekV3(DeepseekV3Config.tiny(num_hidden_layers=2))
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def test_ledger_counts_the_chunks_that_rode_and_those_that_ran_alone(
+        tiny_deepseek):
+    engine = _engine(tiny_deepseek, batch_slots=3, block_size=16,
+                     prefill_chunk=16)
+    calls = {"decode": 0, "decode_with_chunk": 0, "prefill": 0}
+
+    def counting(name):
+        fn = getattr(engine, f"_{name}_fn")
+
+        def call(*args):
+            calls[name] += 1
+            return fn(*args)
+
+        setattr(engine, f"_{name}_fn", call)
+
+    for name in calls:
+        counting(name)
+    # Its chunk finds no row decoding: alone. Then it decodes, and every
+    # chunk of the two that arrive (three and one) rides in its steps.
+    first = engine.add_request(_prompt(5, 1), max_new_tokens=12)
+    assert engine.step()
+    steps = engine.step_stats()
+    assert (steps["prefill"], steps["chunks_aboard"], steps["decode"]) \
+        == (1, 0, 1)
+    budgets = (12, 4, 5)
+    reqs = [first, engine.add_request(_prompt(40, 9), max_new_tokens=4),
+            engine.add_request(_prompt(3, 60), max_new_tokens=5)]
+    engine.run_until_idle()
+    assert all(r.state == eng.FINISHED for r in reqs)
+    steps = engine.stats()["steps"]
+    assert (steps["prefill"], steps["chunks_aboard"]) == (1, 4)
+    assert calls["prefill"] == 1 and calls["decode_with_chunk"] == 4
+    # A fused execution is a decode step with a chunk aboard, and no
+    # prefill execution.
+    assert steps["decode"] == calls["decode"] + calls["decode_with_chunk"]
+    assert steps["decode_ahead"] == steps["decode"] - 1
+    # A request's first token comes from its last chunk, aboard or alone,
+    # the rest from decode rows.
+    assert steps["decode_rows"] == sum(m - 1 for m in budgets)
+    assert steps["dropped_rows"] == 0
+    # With nobody decoding a chunk runs alone again.
+    last = engine.add_request(_prompt(20, 5), max_new_tokens=2)
+    engine.run_until_idle()
+    steps = engine.step_stats()
+    assert (steps["prefill"], steps["chunks_aboard"]) == (3, 4)
+    assert len(last.generated) == 2
+    stats = engine.stats()
+    assert stats["prefill_compiles"] == stats["decode_compiles"] \
+        == stats["decode_with_chunk_compiles"] == 1
+    engine.check_no_leaks()
+
+
 def test_ledger_counts_a_speculative_round_as_a_decode(tiny_llama):
     engine = _engine(tiny_llama, batch_slots=2, spec_decode_draft_len=3)
     req = engine.add_request(_prompt(6, 3), max_new_tokens=9)
