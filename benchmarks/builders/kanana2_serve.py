@@ -4,6 +4,19 @@ subclasses `LLMServer`'s class (by way of `llama_serve`'s, whose benchmark
 reads it inherits) and differs only in handing `InferenceEngine` a
 `DeepseekV3` and its seeded parameters.
 
+WHAT `--seed` MOVES, AND WHAT IT DOES NOT (PR 59). `--seed` draws every
+weight but the routers', the 32 documents, the questions' ids and the
+check's five requests. It does NOT draw the expert layers' `router` and
+`router_bias`: those come from the configuration's `router_seed` folded
+with the layer's index (`pin_router`), as the requests' SHAPES come from the
+traffic's `shape_seed`. Since PR 51 a served step reads only the experts
+that drew a row, so its time follows the router: ~1% of `serve_out_tok_s`
+for each expert more that a layer's decode step draws, 62.9-67.3 a layer a
+step over some thirty seeds' routers, which spread six seeds by 1.4-3.1%
+against a bound of 1% while a seed run again repeated to 0.04-0.10%
+(PERF.md sections 2 and 7). The reference is handed the served parameters,
+so it sees the same router, and the check compares what it compared.
+
 Requests go over HTTP through the proxy, streamed. What `llama_serve.run`
 does after the warm-up (the mix, the trace, the verdict on the window) is
 repeated here because that function cannot be handed another deployment,
@@ -187,6 +200,49 @@ def model_config(cfg: Dict[str, Any]):
 
     return DeepseekV3Config.from_published(
         cfg, dtype=jnp.dtype(cfg["param_dtype"]))
+
+
+def pin_router(params, router_seed: int):
+    """`params` with every expert layer's `router` and `router_bias` drawn
+    anew from `router_seed` folded with the layer's index: what
+    `DeepseekV3.init` gives them (normal of std 0.02 through float32 into
+    the leaf's dtype; normal of std `BIAS_STD` in float32) at the leaf's
+    shape, dtype and placement, in one jitted call on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.deepseek_v3 import BIAS_STD
+
+    held = {i: (lp["router"], lp["router_bias"])
+            for i, lp in enumerate(params["layers"]) if "router" in lp}
+
+    def draw():
+        out = {}
+        for i, (w, bias) in held.items():
+            kw, kb = jax.random.split(jax.random.fold_in(
+                jax.random.PRNGKey(int(router_seed)), i))
+            out[i] = (
+                (jax.random.normal(kw, w.shape, jnp.float32)
+                 * 0.02).astype(w.dtype),
+                jax.random.normal(kb, bias.shape, jnp.float32) * BIAS_STD)
+        return out
+
+    # A leaf that `init` placed keeps its placement; one it left to the
+    # default device stays UNCOMMITTED like its neighbours (a committed
+    # input commits a program's outputs, and the arenas that come back
+    # committed are another program to `jax.jit`: a second compile).
+    drawn = jax.block_until_ready(jax.tree.map(
+        lambda new, leaf: jax.device_put(new, leaf.sharding)
+        if leaf.committed else new, jax.jit(draw)(), held))
+    return {**params, "layers": [
+        {**lp, "router": drawn[i][0], "router_bias": drawn[i][1]}
+        if i in drawn else lp for i, lp in enumerate(params["layers"])]}
+
+
+def seeded_params(model, seed: int, router_seed: int):
+    """The cell's weights: `--seed`'s, with the configuration's router
+    (module docstring). The controls make theirs here too."""
+    return pin_router(init_params(model, seed), router_seed)
 
 
 # --------------------------------------------------------------------------- #
@@ -521,7 +577,7 @@ class _BenchKanana2(_BenchLLM):
     reads from `llama_serve._BenchLLM`."""
 
     def __init__(self, model_cfg: Dict[str, Any],
-                 engine_cfg: Dict[str, Any], seed: int):
+                 engine_cfg: Dict[str, Any], seed: int, router_seed: int):
         import jax
         import jax.numpy as jnp
 
@@ -539,7 +595,7 @@ class _BenchKanana2(_BenchLLM):
         self._model_cfg = model_cfg
         model = DeepseekV3(model_config(model_cfg))
         t0 = time.monotonic()
-        params = init_params(model, seed)
+        params = seeded_params(model, seed, router_seed)
         self._spans["init_s"] = time.monotonic() - t0
         t0 = time.monotonic()
         self._engine = InferenceEngine(self._config, model=model,
@@ -672,7 +728,8 @@ def run(ctx) -> Dict[str, Any]:
         raise ValueError("kanana2_serve offers closed-loop mixes only")
     spans = {"serve_run_called": time.monotonic()}
     handle = serve.run(_deployment(ctx.rehearsal).bind(
-        model_cfg, engine_cfg, ctx.seed), timeout_s=900.0)
+        model_cfg, engine_cfg, ctx.seed, int(cfg["router_seed"])),
+        timeout_s=900.0)
     spans["serve_run_returned"] = time.monotonic()
     url = f"http://127.0.0.1:{serve.http_port()}/"
 
@@ -817,7 +874,10 @@ def run(ctx) -> Dict[str, Any]:
     if at_zero:
         a, b = at_zero["steps"], at_end["steps"]
         window_steps = {
-            **{k: b[k] - a[k] for k in ("n", "decode", "prefill", "wall_s",
+            # (a decode execution that carried a chunk counts in `decode`
+            # AND in `chunks_aboard`)
+            **{k: b[k] - a[k] for k in ("n", "decode", "prefill",
+                                        "chunks_aboard", "wall_s",
                                         "wait_work_s")},
             "phase_s": {k: round(b["phase_s"][k] - a["phase_s"][k], 4)
                         for k in b["phase_s"]}}
@@ -866,6 +926,7 @@ def run(ctx) -> Dict[str, Any]:
                 r["prompt_len"] + r["max_new_tokens"] / 2
                 for r in records) if records else None,
             **{f"window_{k}": v for k, v in window.items()},
+            "window_steps": window_steps,
         },
         "client": {"out_tok_s": quiet["tokens_in_window"] / quiet_s,
                    "prefill_tok_s": prefilled / quiet_s},

@@ -5,8 +5,9 @@
 
 `benchmarks/builders/kanana2_serve.py` holds the system to six limits;
 this shows what they are there to refuse. In ONE process that holds the
-chip (no cluster, no HTTP, no window) it makes the cell's weights from the
-seed once, and for the system as it is and for each control builds the
+chip (no cluster, no HTTP, no window) it makes the cell's weights once, as
+the builder makes them (`seeded_params`: the seed's, with the
+configuration's router), and for the system as it is and for each control builds the
 cell's engine, caches document 0 through it, drives the builder's five
 check requests (`adopter` adopts the document, `leaver` leaves, `reuser` is
 admitted when it has), and puts what came out through the builder's own
@@ -97,7 +98,7 @@ def main(argv=None) -> int:
     model_cfg = {k: cfg[k] for k in b.MODEL_KEYS}
     mc = b.model_config(cfg)
     model = dsv3.DeepseekV3(mc)
-    params = b.init_params(model, args.seed)
+    params = b.seeded_params(model, args.seed, int(cfg["router_seed"]))
     doc = b.documents(traffic, args.seed, int(cfg["vocab_size"]))[0]
     check = b.check_requests(cfg, args.seed, doc)
     true_rows, true_route = dsv3.latent_rows, moe.route_sigmoid

@@ -76,10 +76,25 @@ def latent_roofline_pct(facts, kernel: str) -> Optional[float]:
     return _roofline_pct(facts, need, calls, seconds)
 
 
+def _fused_share(facts) -> float:
+    """The share of the window's decode executions that carried a prefill
+    chunk, by the engine's own ledger (`window_steps`: `decode` counts a
+    fused execution too, `chunks_aboard` those alone). The trace cannot
+    tell: both programs are `jit_decode_fn`. 0 where the program has no
+    fused step."""
+    steps = _window(facts, "steps") or {}
+    return steps.get("chunks_aboard", 0) / steps["decode"] \
+        if steps.get("decode") else 0.0
+
+
 def serve_moe_gmm_roofline_pct(facts) -> Optional[float]:
-    """The grouped products' required work in the traced steps, by the
-    window's own counters (assignments and experts that drew a row, a
-    decode step and a prefill chunk apart), over their device time."""
+    """The grouped products' required work in the traced executions, each
+    counted ONCE under the kind whose counters hold its rows (the window's
+    own: assignments and experts that drew a row an execution), over their
+    device time. The model books a decode step that carried a chunk whole
+    under `prefill`, so that kind's work is times the traced chunks alone
+    plus the traced decode executions' fused share, and the `decode`
+    kind's times the rest."""
     trace, moe = facts.get("trace"), _window(facts, "moe")
     if not trace or not moe or not is_kanana2(facts):
         return None
@@ -88,8 +103,9 @@ def serve_moe_gmm_roofline_pct(facts) -> Optional[float]:
     if not seconds or not (dec or pre):
         return None
     cfg = facts["config"]
+    fused = dec * _fused_share(facts)
     flops = nbytes = 0.0
-    for runs, kind in ((dec, "decode"), (pre, "prefill")):
+    for runs, kind in ((dec - fused, "decode"), (pre + fused, "prefill")):
         need = peaks_kanana2.moe_gmm_required(
             cfg, moe[kind]["assignments_per_step"],
             moe[kind]["experts_drawn_per_step"])
@@ -141,10 +157,15 @@ def serve_mfu_pct(facts) -> Optional[float]:
 
 
 def serve_membw_pct(facts) -> Optional[float]:
-    """The steps' required bytes a second over the chip's HBM bandwidth:
-    decode steps a second (client tokens a second over the live rows a
-    step) and prefill chunks a second (one a request), each times
-    `peaks_kanana2.step_bytes` at the window's counts."""
+    """The executions' required bytes a second over the chip's HBM
+    bandwidth, each execution counted ONCE (`peaks_kanana2.step_bytes` at
+    the window's counts). Executions that hold a chunk a second: one a
+    request (client prefill tokens a second over the mean question); the
+    rows of one are the `prefill` kind's (the question's, and where the
+    chunk rode in a decode step that step's live rows too: its weights are
+    read once for both). Plain decode steps a second: the client tokens a
+    second that no such execution's decode rows emitted, over the live
+    rows of a `decode`-kind step."""
     rates, moe = _rates(facts), _window(facts, "moe")
     if rates is None or not moe or not moe["decode"]["steps"]:
         return None
@@ -155,11 +176,17 @@ def serve_membw_pct(facts) -> Optional[float]:
     question = (traffic["prompt"]["min"] + traffic["prompt"]["max"]) / 2.0
     if not rows:
         return None
-    per_s = (out / rows) * peaks_kanana2.step_bytes(
+    # decode rows aboard an execution that holds a chunk (mean; 0 where
+    # every chunk ran alone)
+    aboard = max(0.0, moe["prefill"]["assignments_per_step"] / top_k
+                 - question)
+    chunks_s = pre / question
+    per_s = ((out - chunks_s * aboard) / rows) * peaks_kanana2.step_bytes(
         cfg, rows, rows * context, moe["decode"]["experts_drawn_per_step"],
         rows) \
-        + (pre / question) * peaks_kanana2.step_bytes(
-            cfg, question, float(traffic["document_len"]) + question,
-            moe["prefill"]["experts_drawn_per_step"], 1.0)
+        + chunks_s * peaks_kanana2.step_bytes(
+            cfg, question + aboard,
+            float(traffic["document_len"]) + question + aboard * context,
+            moe["prefill"]["experts_drawn_per_step"], 1.0 + aboard)
     return 100.0 * per_s / peaks.peaks_for(
         facts["device"]["kind"])["hbm_bytes_per_s"]

@@ -1,6 +1,7 @@
 """The share of the chip's HBM bandwidth that the steps' REQUIRED bytes
-(weights once a step, drawn experts, live latent pages, the head) take:
-the bound that binds a decode step here."""
+(weights once an execution, drawn experts, live latent pages, the head)
+take: the bound that binds a decode step here. A decode step with a chunk
+aboard is one execution."""
 from benchmarks.layer_metrics._kanana2 import serve_membw_pct
 
 

@@ -31,7 +31,11 @@ a DECODED token the head (2 V e); a prefilled token does not pay the head
 A step's required bytes (`step_bytes`): every parameter outside the routed
 experts and the embedding once, the routed experts that drew a row once,
 the embedding's rows of the step's tokens, the head where a position is
-read, and the latent pages of every live row's context once a layer.
+read, and the latent pages of every live row's context once a layer. A
+step is ONE execution: a decode step with a prefill chunk aboard (PR 58)
+is one step over the decode rows and the chunk's rows together, its
+weights and the union of the experts they drew charged once, not a decode
+step and a chunk with weights each.
 """
 
 from __future__ import annotations
@@ -151,10 +155,10 @@ def serve_flops_per_token(cfg: Dict[str, Any], decoded: bool,
 def step_bytes(cfg: Dict[str, Any], tokens: float, cached_tokens: float,
                experts_drawn: float, head_rows: float,
                itemsize: int = 2) -> float:
-    """Required bytes of one step over `tokens` live tokens whose rows see
-    `cached_tokens` cached tokens in all (each row's context once a
-    layer), with `experts_drawn` experts drawing a row a layer (mean);
-    the head is read where `head_rows` > 0."""
+    """Required bytes of one step (one execution: module docstring) over
+    `tokens` live tokens whose rows see `cached_tokens` cached tokens in
+    all (each row's context once a layer), with `experts_drawn` experts
+    drawing a row a layer (mean); the head is read where `head_rows` > 0."""
     e = int(cfg["hidden_size"])
     dense, moe = layer_counts(cfg)
     weights = (dense + moe) * attention_params(cfg) \

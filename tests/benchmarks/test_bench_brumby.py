@@ -39,17 +39,22 @@ def files():
             mf.traffic_of(cell))
 
 
-def test_the_manifest_is_clean_and_gained_what_the_issue_names(files):
+def test_the_manifest_is_clean_and_holds_what_the_issue_names_in_order(
+        files):
+    """The cell, its configuration and its four metrics are present and in
+    the order the issue gave them (later cells are appended after them)."""
     manifest, cell, _, _ = files
     assert mf.validate(manifest, _paths.ROOT) == []
     assert mf.check_budget(manifest) is None
-    assert cell == manifest["workloads"][-1] and cell["chips"] == 1
-    assert manifest["configs"][-1]["name"] == cell["config"]
+    assert cell in manifest["workloads"] and cell["chips"] == 1
+    assert cell["config"] in [c["name"] for c in manifest["configs"]]
     mine = [m["name"] for m in manifest["per_layer"]
             if m.get("workloads") == [CELL]]
     assert mine == ["retention.share_pct", "retention_step_roofline",
                     "retention_chunk_fwd_roofline", "serve.mfu_pct.brumby"]
-    assert [m["name"] for m in manifest["per_layer"][-4:]] == mine
+    at = [i for i, m in enumerate(manifest["per_layer"])
+          if m["name"] in mine]
+    assert at == list(range(at[0], at[0] + 4))       # side by side
     reported = {m["name"] for kind in ("end_to_end", "per_layer")
                 for m in mf.metrics_of(manifest, CELL, kind)}
     assert reported == set(mine) | {

@@ -1,7 +1,9 @@
 """The Kanana-2 configuration and its cell: the manifest with an eighth
 cell, the file against the catalog's config, the traffic's documents and
-pool, the required-work arithmetic hand-worked, the readers on synthetic
-facts, the cell's labelled CPU rehearsal end to end.
+pool, the router that `--seed` does not move, the required-work arithmetic
+hand-worked, the readers on synthetic facts (a window of plain steps, and
+one in which chunks ride in decode steps), the cell's labelled CPU
+rehearsal end to end.
 (`benchmarks/kanana2_controls.py --rehearsal` is run by hand: six more
 engine builds beside the rehearsal's would make this the heaviest file of
 the suite.)"""
@@ -39,7 +41,8 @@ PUBLISHED = {
 MINE = ["mla.share_pct", "latent_decode_roofline", "latent_prefill_roofline",
         "moe.expert_share_pct.serve", "serve_moe_gmm_roofline",
         "moe.load_max_over_mean.serve", "prefix.hit_pct",
-        "serve.mfu_pct.kanana2", "serve.membw_pct.kanana2"]
+        "serve.mfu_pct.kanana2", "serve.membw_pct.kanana2",
+        "engine.chunk_aboard_pct"]
 BATCH_SERVED = {
     "serve_out_tok_s", "setup_s", "setup.deploy_s.serve", "setup.compile_s",
     "engine.decode_step_ms.batch", "engine.prefill_step_ms.batch",
@@ -62,6 +65,10 @@ def reported(manifest, cell_name):
             for m in mf.metrics_of(manifest, cell_name, kind)}
 
 
+def metric_of(manifest, name):
+    return next(m for m in manifest["per_layer"] if m["name"] == name)
+
+
 def test_the_manifest_is_clean_and_gained_what_the_issue_names(files):
     manifest, cell, _, _ = files
     assert mf.validate(manifest, _paths.ROOT) == []
@@ -77,12 +84,23 @@ def test_the_manifest_is_clean_and_gained_what_the_issue_names(files):
     assert mine == MINE
     assert all(m["moves"] == "serve_out_tok_s"
                for m in manifest["per_layer"] if m["name"] in MINE)
-    assert reported(manifest, CELL) == set(MINE) | BATCH_SERVED
+    # every chunk of this cell's window rides in a decode step (PR 58):
+    # no `jit_prefill_fn` runs there, and the metric is not listed for it
+    assert reported(manifest, CELL) == set(MINE) | BATCH_SERVED - {
+        "engine.prefill_step_ms.batch"}
+    assert metric_of(manifest, "engine.prefill_step_ms.batch")[
+        "workloads"] == ["serve_falconh1_batchgen",
+                         "serve_brumby14b_batchgen"]
+    assert metric_of(manifest, "engine.chunk_aboard_pct") == {
+        "name": "engine.chunk_aboard_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "engine scheduler",
+        "moves": "serve_out_tok_s", "workloads": [CELL]}
 
 
 def test_what_two_outgrown_tests_still_hold(files):
-    """tests/conftest.py `OUTGROWN`: the halves of those two tests that a
-    later cell does not falsify."""
+    """What two tests of the benchmark at PR 43 held before PR 59 reworded
+    them (`test_bench_brumby.py`, `test_bench_manifest.py`), on this
+    cell's side."""
     manifest = files[0]
     assert reported(manifest, "serve_brumby14b_batchgen") == {
         "retention.share_pct", "retention_step_roofline",
@@ -111,6 +129,10 @@ def test_every_published_key_stands_or_is_listed_as_reduced(files):
         "kanana2_serve", "deepseek_v3_plain")
     assert "six pipeline stages" in config["deployment"]
     assert "640" in config["engine_notes"]
+    # the router's seed is the configuration's, fixed by ISSUE 59
+    assert config["router_seed"] == 20261003
+    assert "router_seed" in config["assumed"] \
+        and "router_seed" in config["engine_notes"]
 
 
 def test_the_traffic_is_the_issues(files):
@@ -187,6 +209,121 @@ def test_every_requests_first_ids_are_its_documents(files):
         cached = len(r["ids"]) + r["max_new_tokens"] - 1
         assert cached % block == 1 and len(r["ids"]) // block \
             == cached // block - 1, who
+
+
+def _leaves(params):
+    import jax
+
+    return {jax.tree_util.keystr(path): leaf for path, leaf
+            in jax.tree_util.tree_leaves_with_path(params)}
+
+
+def test_the_seed_moves_every_weight_but_the_routers(files):
+    """`--seed` draws the other weights; `router_seed` the expert layers'
+    `router` and `router_bias`, at `DeepseekV3.init`'s shapes, dtypes and
+    spreads."""
+    import numpy as np
+
+    from benchmarks.builders import kanana2_serve as b
+    from ray_tpu.models.deepseek_v3 import DeepseekV3
+
+    config = mf.apply_rehearsal(files[2])
+    model = DeepseekV3(b.model_config(config))
+    plain = _leaves(b.init_params(model, 3000000019))
+    one = _leaves(b.seeded_params(model, 3000000019, config["router_seed"]))
+    two = _leaves(b.seeded_params(model, 7, config["router_seed"]))
+    other = _leaves(b.seeded_params(model, 7, config["router_seed"] + 1))
+    routers = sorted(k for k in one if "router" in k)
+    assert len(routers) == 2 * (config["num_hidden_layers"]
+                                - config["first_k_dense_replace"])
+    assert one.keys() == two.keys() == plain.keys()
+    for key, leaf in one.items():
+        assert (leaf.shape, leaf.dtype, leaf.sharding, leaf.committed) == (
+            plain[key].shape, plain[key].dtype, plain[key].sharding,
+            plain[key].committed), key
+        same = np.array_equal(np.asarray(leaf), np.asarray(two[key]))
+        if key in routers:
+            assert same, key                 # bit-equal across --seed
+            assert not np.array_equal(np.asarray(leaf),
+                                      np.asarray(plain[key])), key
+            assert not np.array_equal(np.asarray(leaf),
+                                      np.asarray(other[key])), key
+        elif "norm" not in key:              # norms are one on every seed
+            assert not same, key
+            assert np.array_equal(np.asarray(leaf),
+                                  np.asarray(plain[key])), key
+    # no two layers share a router
+    first, second = [np.asarray(one[k]) for k in routers
+                     if k.endswith("['router']")][:2]
+    assert not np.array_equal(first, second)
+
+
+def test_the_pinned_router_has_inits_spread():
+    """At the cell's own shapes: normal of std 0.02 in bfloat16 and of
+    std `BIAS_STD` in float32, as `DeepseekV3.init` draws them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.builders import kanana2_serve as b
+    from ray_tpu.models.deepseek_v3 import BIAS_STD
+    from ray_tpu.models.falcon_h1 import _normal
+
+    layer = {"router": jnp.zeros((2048, 128), jnp.bfloat16),
+             "router_bias": jnp.zeros((128,), jnp.float32),
+             "wq": jnp.ones((4, 4), jnp.bfloat16)}
+    placed = {**layer, "router": jax.device_put(layer["router"],
+                                                 jax.devices()[0])}
+    params = {"embed": jnp.ones((8, 4)), "layers": [
+        {"wq": jnp.ones((4, 4), jnp.bfloat16)}, dict(layer), placed]}
+    pinned = b.pin_router(params, 20261003)
+    # a leaf keeps its placement, and one left to the default device stays
+    # uncommitted (a committed input would commit the engine's arenas and
+    # compile its programs a second time)
+    assert [lp["router"].committed for lp in pinned["layers"][1:]] == [
+        False, True]
+    assert not pinned["layers"][2]["router_bias"].committed
+    assert pinned["embed"] is params["embed"]
+    assert pinned["layers"][0] is params["layers"][0]
+    assert pinned["layers"][1]["wq"] is layer["wq"]
+    inits = np.asarray(_normal(jax.random.PRNGKey(1), (2048, 128),
+                               jnp.bfloat16), np.float32)
+    for lp in pinned["layers"][1:]:
+        w = np.asarray(lp["router"], np.float32)
+        bias = np.asarray(lp["router_bias"])
+        assert (lp["router"].dtype, lp["router_bias"].dtype) == (
+            jnp.bfloat16, jnp.float32)
+        assert (w.shape, bias.shape) == ((2048, 128), (128,))
+        assert w.std() == pytest.approx(0.02, rel=0.01)
+        assert w.std() == pytest.approx(inits.std(), rel=0.01)
+        assert abs(w.mean()) < 2e-4 and abs(np.abs(w).max() - 0.09) < 0.03
+        assert bias.std() == pytest.approx(BIAS_STD, rel=0.25)
+    assert not np.array_equal(np.asarray(pinned["layers"][1]["router"]),
+                              np.asarray(pinned["layers"][2]["router"]))
+    again = b.pin_router(params, 20261003)
+    assert np.array_equal(np.asarray(again["layers"][2]["router_bias"]),
+                          np.asarray(pinned["layers"][2]["router_bias"]))
+
+
+def test_the_controls_make_their_weights_as_the_builder_does(monkeypatch):
+    """`kanana2_controls.py` goes through the builder's `seeded_params`
+    with the configuration's `router_seed`: its faults route by the
+    router the cell times."""
+    from benchmarks import kanana2_controls
+    from benchmarks.builders import kanana2_serve as b
+
+    class Reached(Exception):
+        pass
+
+    def seeded_params(model, seed, router_seed):
+        raise Reached(seed, router_seed)
+
+    for key in ("JAX_PLATFORMS", "RAY_TPU_PALLAS_INTERPRET"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))   # main() sets them
+    monkeypatch.setattr(b, "seeded_params", seeded_params)
+    with pytest.raises(Reached) as reached:
+        kanana2_controls.main(["--seed", "2718281829", "--rehearsal"])
+    assert reached.value.args == (2718281829, 20261003)
 
 
 def test_required_work_hand_worked(files):
@@ -312,6 +449,89 @@ def test_readers_on_synthetic_facts(files):
         {**facts, "device": {"platform": "cpu", "kind": "cpu"}}) is None
 
 
+def test_readers_count_a_fused_execution_once(files):
+    """A window in which chunks ride in decode steps (PR 58): the model
+    books such an execution whole under `prefill`, the engine's ledger
+    counts it in `decode` and in `chunks_aboard`, and the trace calls it
+    `jit_decode_fn`."""
+    _, _, config, traffic = files
+    moe = {"layers": 7, "experts": 128,
+           # a plain decode step: 31 live rows
+           "decode": {"steps": 2600, "assignments_per_step": 186.0,
+                      "experts_drawn_per_step": 64.0,
+                      "load_max_over_mean": 3.1},
+           # the 360 fused executions of 31 + 160 rows beside the 40 chunks
+           # of 160 that ran alone: 160 + 0.9 x 31 rows a `prefill` step
+           "prefill": {"steps": 400, "assignments_per_step": 6 * 187.9,
+                       "experts_drawn_per_step": 90.0,
+                       "load_max_over_mean": 1.9}}
+    steps = {"n": 3040, "decode": 2960, "prefill": 40, "chunks_aboard": 360,
+             "wall_s": 39.0, "wait_work_s": 0.5}
+    facts = {
+        "client": {"out_tok_s": 2300.0, "prefill_tok_s": 1440.0},
+        "device": {"platform": "tpu", "kind": "TPU v5 lite"},
+        "config": config, "traffic": traffic,
+        "counters": {"batch_slots": 32, "tokens_emitted_in_trace": 9_000,
+                     "first_tokens_in_trace": 36, "mean_context": 8480.0,
+                     "window_moe": moe, "window_steps": steps},
+        "trace": {"busy_s": 3.9, "window_s": 4.0,
+                  "modules": {"jit_decode_fn": [296, 3.8],
+                              "jit_prefill_fn": [4, 0.07]},
+                  "ops": {"moe_gmm.3 | bf16[2304,1536] custom-call":
+                          [4200, 1.8]}}}
+    read = lambda name, f=facts: mf.reader_of(name)(f)
+    assert read("engine.chunk_aboard_pct") == pytest.approx(90.0)
+    # 296 traced decode executions, 360 / 2960 of them fused: 36 go to the
+    # `prefill` kind beside the 4 chunks alone, 260 stay plain
+    need = {k: pk.moe_gmm_required(config, moe[k]["assignments_per_step"],
+                                   moe[k]["experts_drawn_per_step"])
+            for k in ("decode", "prefill")}
+    once = 7 * (260 * need["decode"]["bytes"] + 40 * need["prefill"]["bytes"])
+    assert read("serve_moe_gmm_roofline") == pytest.approx(
+        100 * once / 819e9 / 1.8)
+    # as read before PR 59: 296 plain steps and the 4 chunks, the fused
+    # steps' extra rows and experts uncounted, so LOWER
+    before = 7 * (296 * need["decode"]["bytes"] + 4 * need["prefill"]["bytes"])
+    assert before < once
+    # the bytes: 9 chunk-holding executions a second, each ONE step over
+    # 160 + 27.9 rows, and the decode rows aboard them taken out of the
+    # plain steps' count
+    chunks_s, aboard = 1440 / 160, 27.9
+    per_s = (2300 - chunks_s * aboard) / 31 * pk.step_bytes(
+        config, 31, 31 * 8480.0, 64.0, 31) + chunks_s * pk.step_bytes(
+        config, 187.9, 8352.0 + aboard * 8480.0, 90.0, 28.9)
+    assert read("serve.membw_pct.kanana2") == pytest.approx(
+        100 * per_s / 819e9)
+    # as read before PR 59: every request a chunk with weights of its own
+    # beside decode steps that emit every token, so HIGHER
+    charged_twice = 2300 / 31 * pk.step_bytes(
+        config, 31, 31 * 8480.0, 64.0, 31) + chunks_s * pk.step_bytes(
+        config, 160.0, 8352.0, 90.0, 1.0)
+    assert per_s < charged_twice
+    for name in ("serve_moe_gmm_roofline", "serve.membw_pct.kanana2"):
+        assert 0 < read(name) < 100, name
+    # no chunk rode: the chunk share is 0 of what ran, and a program
+    # without the fused step (no such key in its ledger) reports nothing
+    alone = {**steps, "decode": 2600, "prefill": 400, "chunks_aboard": 0}
+    counters = facts["counters"]
+    assert read("engine.chunk_aboard_pct", {**facts, "counters": {
+        **counters, "window_steps": alone}}) == 0.0
+    del alone["chunks_aboard"]
+    for window in (alone, {}, {**steps, "prefill": 0, "chunks_aboard": 0}):
+        assert read("engine.chunk_aboard_pct", {**facts, "counters": {
+            **counters, "window_steps": window}}) is None
+    # ... and both repaired readers read such a window as before PR 58
+    plain = {**facts, "counters": {**counters, "window_steps": alone,
+                                   "window_moe": {**moe, "prefill": {
+                                       **moe["prefill"],
+                                       "assignments_per_step": 960.0}}}}
+    assert read("serve_moe_gmm_roofline", plain) == pytest.approx(
+        100 * 7 * (296 * need["decode"]["bytes"] + 4 * pk.moe_gmm_required(
+            config, 960.0, 90.0)["bytes"]) / 819e9 / 1.8)
+    assert read("serve.membw_pct.kanana2", plain) == pytest.approx(
+        100 * charged_twice / 819e9)
+
+
 def test_the_cells_rehearsal_runs_end_to_end():
     env = {**os.environ, "PYTHONPATH": _paths.ROOT}
     done = subprocess.run(
@@ -327,8 +547,10 @@ def test_the_cells_rehearsal_runs_end_to_end():
     assert last["rehearsal"] is True and last["correct"] is True, lines[-2:]
     assert last["attempted"] > 0 and last["failed"] == 0
     assert {"setup.compile_s", "setup.deploy_s.serve", "startup.backend_s",
-            "compile.cold_s", "prefix.hit_pct",
+            "compile.cold_s", "prefix.hit_pct", "engine.chunk_aboard_pct",
             "moe.load_max_over_mean.serve"} <= set(last["metrics_reported"])
+    listed = set(last["metrics_reported"]) | set(last["metrics_left_out"])
+    assert "engine.prefill_step_ms.batch" not in listed
     assert "metrics" not in last and last["device"]["platform"] == "cpu"
     run = next(x for x in lines if x.get("builder") == "kanana2_serve")
     stats = run["engine_stats"]
@@ -343,6 +565,11 @@ def test_the_cells_rehearsal_runs_end_to_end():
     assert stats["prefix_cache"]["enabled"] is True
     assert stats["prefix_cache"]["cached_blocks"] >= 4 * 8
     assert run["fillers"] == 8
+    # the window's chunks rode in decode steps (all but the few admitted
+    # to an idle engine), and the ledger's `decode` counts those
+    # executions too
+    steps = run["window_steps"]
+    assert steps["decode"] >= steps["chunks_aboard"] > 10 * steps["prefill"]
     window = run["window"]
     assert window["prefix"]["hits"] == window["prefix"]["lookups"] > 0
     assert window["prefix"]["lookup_hit_tokens"] \

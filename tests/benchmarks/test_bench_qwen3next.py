@@ -210,7 +210,9 @@ def test_the_cells_rehearsal_runs_end_to_end():
     env = {**os.environ, "PYTHONPATH": _paths.ROOT}
     done = subprocess.run(
         [sys.executable, os.path.join(_paths.ROOT, "benchmarks", "run.py"),
-         "--workload", CELL, "--seed", "3000000019", "--seconds", "2",
+         # 4 s, not 2: the verdict asks the loss to fall 0.3 inside the
+         # window, ~20 steps, and a loaded host fit 14 and 15 in 2 s
+         "--workload", CELL, "--seed", "3000000019", "--seconds", "4",
          "--trace", "1", "--rehearsal"],
         capture_output=True, text=True, cwd=_paths.ROOT, env=env,
         timeout=600)
