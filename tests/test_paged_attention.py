@@ -90,6 +90,41 @@ def test_kernel_matches_reference(interpret, s, groups):
     assert (out[LENS.index(0)] == 0).all()
 
 
+@pytest.mark.parametrize("s", [1, 32])
+def test_sixteen_kv_heads_with_one_query_row_each(interpret, s):
+    """The looped model's shape (`models/ouro.py`): plain multi-head
+    attention, `groups` = 1 at 16 KV heads, so every product of the kernel
+    is ONE query row a token against a head's chunk, the 16 heads taken
+    out of a page by 8 paired strided loads. And its tables: a pass's
+    pages lie a whole number of `num_blocks` up one arena, which to the
+    kernel is a table like any other."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import (paged_attention,
+                                             paged_attention_reference)
+
+    lens, max_blocks = (1, 2 * BS, 5 * BS + 3, 0, 37), 6
+    q, arenas, bt, pos, wmask = _case(s, 16, 1, lens, max_blocks,
+                                      jnp.bfloat16, seed=160 + s)
+    # the arena of a model with two passes: pass 1's pages are these, one
+    # `num_blocks` up; pass 0's range holds NaN throughout
+    nb = arenas["k"].shape[0]
+    up = {name: jnp.concatenate([jnp.full_like(a, jnp.nan), a])
+          for name, a in arenas.items()}
+    out = jax.jit(paged_attention)(q, up["k_nan"], up["v_nan"], bt + nb,
+                                   pos, wmask)
+    ref = paged_attention_reference(q, arenas["k"], arenas["v"], bt, pos)
+    out, ref = (np.asarray(a, np.float32) for a in (out, ref))
+    assert np.isfinite(out).all()
+    used = np.asarray(wmask)
+    np.testing.assert_allclose(out[used], ref[used], atol=2e-2, rtol=2e-2)
+    assert np.abs(out[used] - ref[used]).mean() < 1e-3
+    assert (out[lens.index(0)] == 0).all()
+    records = [r for r in _paged_records() if r["shape"][2] == 16]
+    assert records and all(r["path"] == "pallas" for r in records)
+
+
 def test_kernel_float32_arena_is_near_exact(interpret):
     import jax
     import jax.numpy as jnp
