@@ -374,6 +374,9 @@ class InferenceEngine:
         # Which path the paged attention of each program took when it was
         # traced (ops/paged_attention.py's dispatch records).
         self._paged_attn = {"decode": "not traced", "prefill": "not traced"}
+        # ... and which tile of the kernel its shape was given (the records'
+        # `tile`; empty for a reference call and for the latent kernel).
+        self._paged_tile = dict(self._paged_attn)
         self._shapes = {"prefill": set(), "decode": set(),
                         "decode_with_chunk": set(), "draft_prefill": set(),
                         "propose": set(), "verify": set()}
@@ -1121,12 +1124,14 @@ class InferenceEngine:
         from ray_tpu.ops.paged_attention import paged_calls
 
         self._shapes[name].add(shape)
-        before = paged_calls()
+        before = paged_calls(), paged_calls("tile")
         out = self._under_mesh(fn, args)
-        took = {key[1] for key, n in paged_calls().items()
-                if n > before.get(key, 0)}
-        if name in self._paged_attn and took:
-            self._paged_attn[name] = " | ".join(sorted(took))
+        for was, field, said in ((before[0], "path", self._paged_attn),
+                                 (before[1], "tile", self._paged_tile)):
+            took = {key[1] for key, n in paged_calls(field).items()
+                    if n > was.get(key, 0)}
+            if name in said and took:
+                said[name] = " | ".join(sorted(took))
         return out
 
     def _under_mesh(self, fn, args):
@@ -1396,6 +1401,7 @@ class InferenceEngine:
             "decode_with_chunk_compiles":
                 self._program_compiles("decode_with_chunk"),
             "paged_attn": dict(self._paged_attn),
+            "paged_attn_tile": dict(self._paged_tile),
             # `bytes`: what the cache holds beside the per-slot state
             # (the paged arenas; 0 for a cache with no paged part).
             "kv": {**self._bm.stats(), "bytes": self._kv_bytes},

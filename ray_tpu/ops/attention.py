@@ -693,7 +693,8 @@ def pick_block_sizes(seq: int, d: int, causal: bool = True) -> tuple:
 
 
 # (pass, path, reason, shape, dtype, block_q, block_k) -> traced calls; the
-# flash passes append _FLASH_FIELDS to theirs
+# flash passes append _FLASH_FIELDS to theirs, `paged_decode` and
+# `paged_prefill` their `tile`
 _CALLS: collections.Counter = collections.Counter()
 _CALLS_LOCK = threading.Lock()
 _FLASH_FIELDS = ("causal", "tiles", "tiles_live", "layout", "heads_per_block",
@@ -717,7 +718,11 @@ def pallas_status() -> list:
     operands) and `tiles`, `tiles_live`: the (tile_q, tile_k) tiles in one
     (batch, head)'s score square and those whose body the kernels run (None
     for a reference call). `tiles_live / tiles` near 1.0 on a causal call
-    means the causal skip is dead at that shape."""
+    means the causal skip is dead at that shape.
+
+    Entries of the paged kernel (`paged_decode`, `paged_prefill`) say
+    `tile`: which of the kernel's two tiles the call's shape was given
+    (`ops/paged_attention.py`; empty for a reference call)."""
     with _CALLS_LOCK:
         items = list(_CALLS.items())
     out = []
@@ -725,7 +730,8 @@ def pallas_status() -> list:
         out.append({"pass": p, "path": path, "reason": reason,
                     "shape": list(shape), "dtype": dtype, "block_q": bq,
                     "block_k": bk, "calls": n,
-                    **dict(zip(_FLASH_FIELDS, flash))})
+                    **dict(zip(("tile",) if p.startswith("paged_")
+                               else _FLASH_FIELDS, flash))})
     return out
 
 

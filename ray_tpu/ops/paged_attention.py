@@ -20,10 +20,32 @@ token's [kv_heads, head_dim]), so the 2-D view that is a bitcast in
 row-major memory costs a copy of the whole arena there. A page
 `arena[block]` is `block_size` whole tiles, contiguous, and one DMA; in
 VMEM, KV head h of a chunk is every kv_heads-th row of the chunk seen as
-[chunk * kv_heads, head_dim], a strided load (`heads`, below). One grid
+[chunk * kv_heads, head_dim], a strided load (`_heads`, below). One grid
 step serves one (row, query tile); the KV chunks are an in-kernel loop
 whose trip count is the tile's own bound, so a chunk past the live length
 costs neither a copy nor a grid step.
+
+The kernel has two tiles, and which one a call gets is its SHAPE's to say
+(`_tiles`: query tokens x groups, never a flag or a model's name):
+
+- MANY ROWS (more than 128 query rows a KV head: a prefill chunk's tiles of
+  up to 512) is bound by its products: a grid step walks its own chunks of
+  256 tokens, the next chunk's copies in flight under this chunk's
+  products, head by head.
+- FEW ROWS (up to 128: every decode step, 1 to 5 query rows a KV head in
+  the serve cells, and a speculative round's verify) is bound by its pages'
+  bytes and by what each copy and each loop iteration costs beside them
+  (`_few_rows_kernel`): (a) a row's first chunk is started under the LAST
+  chunk of the row before it that had anything live (the grid is
+  sequential, scratch persists across grid steps, every row's table and
+  length are in SMEM), so only the call's very first copy is exposed; an
+  idle row starts nothing, waits for nothing and hands the chain on; (b)
+  the products run over sub-blocks of 512 tokens up to the row's live
+  length, inside chunks of 1,024; (c) the KV heads of a sub-block are the
+  batch dimension of ONE `dot_general` for the scores and one for P x V,
+  and the f32 statistics, probabilities and result hold 8 query rows a
+  tile, not the 16 of the bf16 operand; a chunk's copies are waited for in
+  powers of two of pages, not page by page.
 
 Arithmetic is the reference's in another order of summation: scores are
 q . k of the arena's values accumulated in f32, mask, softmax and the
@@ -50,19 +72,36 @@ from ray_tpu.ops import attention as _attn
 from ray_tpu.ops.attention import _NEG_INF
 
 _LANES = 128
-# The most query rows (query tokens x groups) one grid step holds, and the
-# tokens copied and multiplied per loop iteration. Measured on the v5e at
-# Mistral widths (PERF.md, PR 25): a decode tile (16 rows) is bound by the
-# loop's fixed costs and wants long chunks (512: 92 us a call at ~350 live
-# tokens a row, 497 at 4,096; 256: 107 and 772), a prefill tile of 512 rows
-# by its matmuls and what the mask wastes of them (256: 72 and 394 us at 512
-# and 3,072 positions; 512: 89 and 412). 512 tokens x 8 heads x 128 of bf16
-# K and V, double-buffered, are 4 MB of VMEM; 512 rows of f32 statistics
-# and accumulator for 8 KV heads 6 MB.
+# The most query rows (query tokens x groups) one grid step holds, the
+# tokens a chunk of pages holds and the tokens a few-rows tile multiplies at
+# a time. Measured on the v5e with the kernel alone
+# (`scripts/time_paged_kernels.py`; PERF.md section 5, PR 61; us a call).
+# A few-rows tile at the ninth cell's shape (8 rows x 16 KV heads x 1 query
+# row, 284 tokens a row / contexts U(64,576) / 576), Mistral's (16 rows x 8
+# x 4, ~350 / ~900) and Falcon-H1's (64 rows x 4 x 5, ~290), parent first:
+#   head by head, whole 512-token chunks      70.4  84.1 128.9  79.6 135.5 182.0
+#   + the next row's first chunk in flight    50.8  59.0  98.7  58.3 123.5 150.3
+#   + live 128-token sub-blocks, 8-row f32    63.1  74.8 120.5  79.5 188.5 200.4
+#   + heads batched, chunks of 1,024          27.9  40.8  54.0  46.2 109.0 146.8
+#   + waits in powers of two of pages         27.9  40.8  54.0  43.9 102.3 138.3
+#   + sub-blocks of 512 (the tree)            28.1  41.0  55.0  34.4  82.1  95.4
+# Sub-blocks of 128 under a dynamic bound cost more than the dead tokens
+# they skip wherever a row has more than one (256: 27.9 39.5 53.7 36.0 84.2
+# 113.5; 1,024, a whole chunk: 44.2 48.2 55.2 43.6 81.9 115.6): an
+# iteration's fixed cost, not its width, is what a few-rows tile pays. Head
+# by head they lose to whole chunks outright (71.9 85.0 131.8 92.9 219.1
+# 214.3 with 16-row tiles). Chunks of 2,048 read as 1,024 do, chunks of 512
+# make a 576-token row wait for a second one (66.7). A many-rows
+# tile of 512 rows is bound by its matmuls and what the mask wastes of them
+# (PR 25, at Mistral's widths: chunks of 256 72 and 394 us at 512 and 3,072
+# positions; 512: 89 and 412), and none of the above moved it (18.9 / 90.3 /
+# 35.5 us a chunk of the three models before and after). 1,024 tokens x 16
+# heads x 128 of bf16 K and V, double-buffered, are 16 MB of VMEM.
 _MAX_Q_ROWS = 512
-_CHUNK_TOKENS_FEW_ROWS = 512        # tiles of up to _FEW_ROWS query rows
+_CHUNK_TOKENS_FEW_ROWS = 1024      # tiles of up to _FEW_ROWS query rows
 _CHUNK_TOKENS = 256
 _FEW_ROWS = 128
+_SUB_TOKENS = 512                  # ... multiply this many at a time
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -100,10 +139,89 @@ def paged_attention_reference(q, k_arena, v_arena, block_tables, positions):
 # --------------------------------------------------------------------------- #
 
 
-def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *, scale: float):
+def _live_pages(buf, hi, c):
+    """The live pages of chunk c of a walk to `hi`: a prefix of the chunk."""
+    pages, block_size = buf.shape[1:3]
+    chunk = pages * block_size
+    return (jnp.minimum(hi - c * chunk, chunk) + block_size - 1) \
+        // block_size
+
+
+def _for_live_pages(bt_ref, hbm, bufs, sems, row, hi, c, slot, do: str):
+    """`do` ("start" or "wait") the K and the V copy of every live page of
+    chunk c of a walk of `row` to `hi` into `slot`: a loop with a dynamic
+    bound.
+    Unrolled and guarded page by page, tracing and lowering the copies was
+    most of what a process paid for the kernel at start-up (PERF.md, PR
+    25)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pages = bufs[0].shape[1]
+
+    def body(p, carry):
+        phys = bt_ref[row, c * pages + p]
+        for i, (arena, buf) in enumerate(zip(hbm, bufs)):
+            getattr(pltpu.make_async_copy(arena.at[phys], buf.at[slot, p],
+                                          sems.at[i, slot]), do)()
+        return carry
+
+    jax.lax.fori_loop(0, _live_pages(bufs[0], hi, c), body, 0)
+
+
+def _wait_live_pages(bufs, sems, hi, c, slot):
+    """Wait for every copy `_for_live_pages` started for chunk c into
+    `slot`, a power of two of pages at a time: a DMA semaphore counts what
+    has landed, whichever copies brought it, so the waits for 1, 2, 4, ...
+    pages that add up to the chunk's live pages take the place of one wait
+    a page (no copy is made: the descriptor only says how much)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    live = _live_pages(bufs[0], hi, c)
+    n = 1
+    while n <= bufs[0].shape[1]:
+        @pl.when((live & n) != 0)
+        def _(n=n):
+            for i, buf in enumerate(bufs):
+                landed = buf.at[slot, pl.ds(0, n)]
+                pltpu.make_async_copy(landed, landed, sems.at[i, slot]).wait()
+        n *= 2
+
+
+def _heads(view, live=None):
+    """The pages `view` [pages, block, kv_heads, d] of a chunk buffer as one
+    exact f32 [tokens, d] per KV head. A token's heads are the sublanes of
+    one tile, so head h is every kv_heads-th row of the pages seen as
+    [tokens * kv_heads, d]: a strided load. 16-bit rows come packed in
+    pairs, two heads to a 32-bit word, and are taken apart by shift and
+    mask (the upper half of an f32 IS the bf16), as jax's
+    ragged_paged_attention kernel reads its pages. `live` [tokens, d]
+    marks the rows to keep; the others come back as zeros."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pages, block_size, kv_heads, head_dim = view.shape
+    flat = view.reshape(pages * block_size * kv_heads, head_dim)
+
+    def kept(x):
+        return x if live is None else jnp.where(live, x, jnp.zeros_like(x))
+
+    if view.dtype == jnp.float32:
+        return [kept(flat[h::kv_heads, :]) for h in range(kv_heads)]
+    words = flat.bitcast(jnp.uint32)
+    out = []
+    for pair in range(kv_heads // 2):
+        w = kept(words[pair::kv_heads // 2, :])
+        out.append(pltpu.bitcast(w << 16, jnp.float32))
+        out.append(pltpu.bitcast(w & jnp.uint32(0xFFFF0000), jnp.float32))
+    return out
+
+
+def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *, scale: float):
+    """A tile of many query rows (a prefill chunk's): bound by its products.
+    One grid step walks its own chunks, the next one's copy in flight under
+    this one's products, and multiplies every chunk whole."""
+    from jax.experimental import pallas as pl
 
     row = pl.program_id(0)
     tile = pl.program_id(1)
@@ -114,49 +232,9 @@ def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
     hi = hi_ref[row, tile]
     n_chunks = (hi + chunk - 1) // chunk
 
-    def for_live_pages(c, slot, do):
-        """`do(copy)` for the K and the V copy of every live page of chunk
-        c into `slot`. The live pages of a chunk are a prefix of it, so
-        this is a loop with a dynamic bound: unrolled and guarded page by
-        page, tracing and lowering the copies was most of what a process
-        paid for the kernel at start-up (PERF.md, PR 25)."""
-        def body(p, carry):
-            phys = bt_ref[row, c * pages + p]
-            do(pltpu.make_async_copy(k_hbm.at[phys], k_buf.at[slot, p],
-                                     sems.at[0, slot]))
-            do(pltpu.make_async_copy(v_hbm.at[phys], v_buf.at[slot, p],
-                                     sems.at[1, slot]))
-            return carry
-
-        live = (jnp.minimum(hi - c * chunk, chunk) + block_size - 1) \
-            // block_size
-        jax.lax.fori_loop(0, live, body, 0)
-
-    def heads(buf, slot):
-        """The chunk in `slot` as one exact f32 [chunk, d] per KV head.
-        A token's heads are the sublanes of one tile, so head h is every
-        kv_heads-th row of the chunk seen as [chunk * kv_heads, d]: a
-        strided load. 16-bit rows come packed in pairs, two heads to a
-        32-bit word, and are taken apart by shift and mask (the upper
-        half of an f32 IS the bf16), as jax's ragged_paged_attention
-        kernel reads its pages."""
-        flat = buf.at[slot].reshape(chunk * kv_heads, head_dim)
-        if buf.dtype == jnp.float32:
-            return [flat[h::kv_heads, :] for h in range(kv_heads)]
-        words = flat.bitcast(jnp.uint32)
-        out = []
-        for pair in range(kv_heads // 2):
-            w = words[pair::kv_heads // 2, :]
-            out.append(pltpu.bitcast(w << 16, jnp.float32))
-            out.append(pltpu.bitcast(w & jnp.uint32(0xFFFF0000),
-                                     jnp.float32))
-        return out
-
-    def start(c, slot):
-        for_live_pages(c, slot, lambda copy: copy.start())
-
-    def wait(c, slot):
-        for_live_pages(c, slot, lambda copy: copy.wait())
+    def copies(c, slot, do):
+        _for_live_pages(bt_ref, (k_hbm, v_hbm), (k_buf, v_buf), sems, row,
+                        hi, c, slot, do)
 
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -164,16 +242,16 @@ def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(n_chunks > 0)
     def _():
-        start(0, 0)
+        copies(0, 0, "start")
 
     def body(c, carry):
         slot = c % 2
 
         @pl.when(c + 1 < n_chunks)
         def _():
-            start(c + 1, 1 - slot)
+            copies(c + 1, 1 - slot, "start")
 
-        wait(c, slot)
+        copies(c, slot, "wait")
         q_pos = qpos_ref[0]                                  # [rows, 1]
         rows = q_pos.shape[0]
         k_pos = c * chunk + jax.lax.broadcasted_iota(
@@ -184,9 +262,8 @@ def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
         # through the scores, V has to be zeroed, or 0 x NaN gets in.
         v_live = c * chunk + jax.lax.broadcasted_iota(
             jnp.int32, (chunk, head_dim), 0) < hi
-        for h, (k, v) in enumerate(zip(heads(k_buf, slot),
-                                       heads(v_buf, slot))):
-            v = jnp.where(v_live, v, 0.0)
+        for h, (k, v) in enumerate(zip(_heads(k_buf.at[slot]),
+                                       _heads(v_buf.at[slot], v_live))):
             s = jax.lax.dot_general(
                 q_ref[0, h], k.astype(q_ref.dtype), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [rows, chunk]
@@ -213,12 +290,132 @@ def _kernel(hi_ref, bt_ref, q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
         o_ref[0, h] = (acc_scr[h] / denom).astype(o_ref.dtype)
 
 
+# Rows of the few-rows tile's walk array (scalar prefetch, a column a row).
+_HI, _BEFORE, _NEXT = range(3)
+
+
+def _walk(hi, chunk: int):
+    """[3, b] int32 for a call of one tile a row: `_HI` a row's live length
+    (0: an idle row, which copies and multiplies nothing); `_BEFORE` the
+    chunks of all earlier rows (its parity is the buffer slot the row's
+    first chunk lands in, and 0 says nobody has started it); `_NEXT` the
+    next row with anything live, -1 for none."""
+    b = hi.shape[0]
+    chunks = (hi + chunk - 1) // chunk
+    before = jnp.cumsum(chunks) - chunks
+    row = jnp.arange(b, dtype=jnp.int32)
+    later = jnp.where(chunks > 0, row, b)
+    nxt = jnp.flip(jax.lax.cummin(jnp.flip(later)))          # at or after
+    nxt = jnp.concatenate([nxt[1:], jnp.full((1,), b, jnp.int32)])
+    return jnp.stack([hi, before, jnp.where(nxt < b, nxt, -1)]).astype(
+        jnp.int32)
+
+
+def _few_rows_kernel(walk_ref, bt_ref, q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
+                     k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *,
+                     scale: float, sub_pages: int):
+    """A tile of few query rows (a decode step's, a speculative round's):
+    bound by its pages' bytes and by what every loop iteration costs beside
+    them. Its first chunk is started under the last chunk of the row before
+    (the grid is sequential, scratch persists, every row's table is in SMEM
+    already), and it multiplies a chunk in sub-blocks of `sub_pages` pages
+    up to the row's live length and no further."""
+    from jax.experimental import pallas as pl
+
+    row = pl.program_id(0)
+    _, pages, block_size, _, head_dim = k_buf.shape
+    chunk = pages * block_size
+    sub = sub_pages * block_size
+    hi = walk_ref[_HI, row]
+    before = walk_ref[_BEFORE, row]
+    nxt = walk_ref[_NEXT, row]
+    n_chunks = (hi + chunk - 1) // chunk
+    rows = m_scr.shape[1]              # whole f32 tiles of the query rows
+
+    def start(row, hi, c, slot):
+        _for_live_pages(bt_ref, (k_hbm, v_hbm), (k_buf, v_buf), sems, row,
+                        hi, c, slot, "start")
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # The first row that walks starts its own first chunk; every later one
+    # finds it started under its predecessor's last chunk.
+    @pl.when((n_chunks > 0) & (before == 0))
+    def _():
+        start(row, hi, 0, 0)
+
+    q = q_ref[0]                                     # [kv_heads, ., d]
+    q_pos = qpos_ref[0][:rows]                       # [rows, 1]
+    batched = (((2,), (2,)), ((0,), (0,)))
+
+    def chunk_step(c, carry):
+        slot = (before + c) % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(row, hi, c + 1, 1 - slot)
+
+        @pl.when((c + 1 == n_chunks) & (nxt >= 0))
+        def _():
+            start(nxt, walk_ref[_HI, nxt], 0, 1 - slot)
+
+        _wait_live_pages((k_buf, v_buf), sems, hi, c, slot)
+
+        def sub_step(j, carry):
+            first = c * chunk + j * sub
+            k_pos = first + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, sub), 1)
+            mask = ((k_pos <= q_pos) & (k_pos < hi))[None]
+            # Rows of the buffer at or past `hi` hold whatever was there
+            # (the tail of the last live page, another row's pages): K is
+            # masked through the scores, V has to be zeroed, or 0 x NaN
+            # gets in.
+            v_live = first + jax.lax.broadcasted_iota(
+                jnp.int32, (sub, head_dim), 0) < hi
+            at = pl.ds(j * sub_pages, sub_pages)
+            k = jnp.stack(_heads(k_buf.at[slot, at]))    # [kv_heads, sub, d]
+            v = jnp.stack(_heads(v_buf.at[slot, at], v_live))
+            s = jax.lax.dot_general(
+                q, k.astype(q.dtype), batched,
+                preferred_element_type=jnp.float32)[:, :rows] * scale
+            s = jnp.where(mask, s, _NEG_INF)             # [kv_heads, rows, sub]
+            m_prev = m_scr[...][..., :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            correction = jnp.exp(m_prev - m_new)
+            l_new = correction * l_scr[...][..., :1] + jnp.sum(
+                p, axis=2, keepdims=True)
+            acc_scr[...] = acc_scr[...] * correction + jax.lax.dot_general(
+                p, v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+            return carry
+
+        live = jnp.minimum(hi - c * chunk, chunk)
+        jax.lax.fori_loop(0, (live + sub - 1) // sub, sub_step, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk_step, 0)
+
+    # A row with nothing live (an idle slot, a padded query) has l = 0 and
+    # a zero accumulator: it writes zeros, never 0/0.
+    denom = jnp.maximum(l_scr[...][..., :1], 1e-30)
+    o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+
+
 def _tiles(n_rows: int, block_size: int, dtype) -> tuple:
-    """(query rows a grid step, pages a KV chunk) for this call's shape."""
+    """(query rows a grid step, pages a KV chunk, pages a sub-block) for
+    this call's shape; no sub-blocks (0) says the many-rows tile."""
     sublanes = 32 // jnp.dtype(dtype).itemsize     # rows of one packed tile
     rows = min(_MAX_Q_ROWS, -(-n_rows // sublanes) * sublanes)
-    chunk = _CHUNK_TOKENS_FEW_ROWS if rows <= _FEW_ROWS else _CHUNK_TOKENS
-    return rows, max(1, chunk // block_size)
+    if rows > _FEW_ROWS:
+        return rows, max(1, _CHUNK_TOKENS // block_size), 0
+    sub = max(1, _SUB_TOKENS // block_size)
+    return rows, max(1, _CHUNK_TOKENS_FEW_ROWS // (sub * block_size)) * sub, \
+        sub
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -237,7 +434,7 @@ def _paged_attention_pallas(q, k_arena, v_arena, block_tables, positions,
     groups = n_head // kvh
     max_ctx = block_tables.shape[1] * bsz
     n_rows = s * groups
-    rows, pages = _tiles(n_rows, bsz, q.dtype)
+    rows, pages, sub_pages = _tiles(n_rows, bsz, q.dtype)
     n_tiles = -(-n_rows // rows)
     pad = n_tiles * rows - n_rows
     # The query heads of one KV head become rows of one operand: row
@@ -250,12 +447,21 @@ def _paged_attention_pallas(q, k_arena, v_arena, block_tables, positions,
     hi = jnp.minimum(q_pos.reshape(b, n_tiles, rows).max(axis=-1) + 1,
                      lengths[:, None])
     hi = jnp.clip(hi, 0, max_ctx).astype(jnp.int32)
+    if sub_pages:
+        # One tile a row. The statistics, the probabilities and the result
+        # are f32: their tile is 8 rows, so up to 8 query rows of a 16-row
+        # bf16 operand cost one tile of them, not two.
+        out_rows, out_dtype = -(-n_rows // 8) * 8, jnp.float32
+        kernel = functools.partial(_few_rows_kernel, sub_pages=sub_pages)
+        scalars = _walk(hi[:, 0], pages * bsz)
+    else:
+        out_rows, out_dtype, kernel, scalars = rows, q.dtype, _kernel, hi
 
     def q_map(i, t, *_):
         return (i, 0, t, 0)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / math.sqrt(hd)),
+        functools.partial(kernel, scale=1.0 / math.sqrt(hd)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_tiles),
@@ -265,24 +471,25 @@ def _paged_attention_pallas(q, k_arena, v_arena, block_tables, positions,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, kvh, rows, hd), q_map),
+            out_specs=pl.BlockSpec((1, kvh, out_rows, hd), q_map),
             scratch_shapes=[
                 pltpu.VMEM((2, pages, bsz, kvh, hd), k_arena.dtype),
                 pltpu.VMEM((2, pages, bsz, kvh, hd), v_arena.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((kvh, rows, _LANES), jnp.float32),
-                pltpu.VMEM((kvh, rows, _LANES), jnp.float32),
-                pltpu.VMEM((kvh, rows, hd), jnp.float32),
+                pltpu.VMEM((kvh, out_rows, _LANES), jnp.float32),
+                pltpu.VMEM((kvh, out_rows, _LANES), jnp.float32),
+                pltpu.VMEM((kvh, out_rows, hd), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, kvh, n_tiles * out_rows, hd), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="paged_attention",
-    )(hi, block_tables.astype(jnp.int32), qg, q_pos[..., None], k_arena,
+    )(scalars, block_tables.astype(jnp.int32), qg, q_pos[..., None], k_arena,
       v_arena)
-    out = out[:, :, :n_rows].reshape(b, kvh, s, groups, hd)
+    out = out[:, :, :n_rows].astype(q.dtype).reshape(b, kvh, s, groups, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(b, s, n_head, hd)
 
 
@@ -327,25 +534,36 @@ def _dispatch(q, k_arena) -> bool:
         reason = "kv_heads do not fill the arena's tiles"
     else:
         reason = ""
-    rows, pages = _tiles(s * (n_head // kvh), bsz, q.dtype)
+    rows, pages, sub_pages = _tiles(s * (n_head // kvh), bsz, q.dtype)
+    if reason:
+        tile = ""
+    elif sub_pages:
+        tile = (f"few rows: the next row's first chunk in flight, products "
+                f"over live sub-blocks of {sub_pages * bsz}, heads batched")
+    else:
+        tile = "many rows: whole chunks, head by head"
     key = ("paged_decode" if s == 1 else "paged_prefill",
            "reference" if reason else "pallas", reason, tuple(q.shape),
-           jnp.dtype(q.dtype).name, rows, pages * bsz)
+           jnp.dtype(q.dtype).name, rows, pages * bsz, tile)
     with _attn._CALLS_LOCK:
         _attn._CALLS[key] += 1
     return not reason
 
 
-def paged_calls() -> dict:
+def paged_calls(field: str = "path") -> dict:
     """Traced paged-attention calls of this process so far: ((pass,
-    "pallas" | "reference: <reason>") -> count), out of `pallas_status()`.
-    The difference of two reads says which path a trace in between took
-    (`InferenceEngine.stats()["paged_attn"]`)."""
+    "pallas" | "reference: <reason>") -> count), out of `pallas_status()`;
+    with `field` "tile", ((pass, the kernel's tile for the call's shape)
+    -> count). The difference of two reads says which path a trace in
+    between took and which tile (`InferenceEngine.stats()["paged_attn"]`,
+    `["paged_attn_tile"]`)."""
     out: dict = {}
     for r in _attn.pallas_status():
         if r["pass"].startswith("paged_"):
-            path = r["path"] + (f": {r['reason']}" if r["reason"] else "")
-            key = (r["pass"], path)
+            said = r.get(field) or ""
+            if field == "path" and r["reason"]:
+                said += f": {r['reason']}"
+            key = (r["pass"], said)
             out[key] = out.get(key, 0) + r["calls"]
     return out
 

@@ -64,7 +64,7 @@ MAX_BLOCKS = 34           # 544 positions: more than one KV chunk at any s
 LENS = (1, 2 * BS, 5 * BS + 3, MAX_BLOCKS * BS, 0, 37)
 
 
-@pytest.mark.parametrize("groups", [1, 4])
+@pytest.mark.parametrize("groups", [1, 4, 5])
 @pytest.mark.parametrize("s", [1, 5, 32])
 def test_kernel_matches_reference(interpret, s, groups):
     import jax
@@ -73,8 +73,10 @@ def test_kernel_matches_reference(interpret, s, groups):
     from ray_tpu.ops.paged_attention import (paged_attention,
                                              paged_attention_reference)
 
-    q, arenas, bt, pos, wmask = _case(s, 2, groups, LENS, MAX_BLOCKS,
-                                      jnp.bfloat16, seed=s * 10 + groups)
+    # 2 KV heads, and Falcon-H1's 5 query heads a KV head over its 4
+    q, arenas, bt, pos, wmask = _case(s, 4 if groups == 5 else 2, groups,
+                                      LENS, MAX_BLOCKS, jnp.bfloat16,
+                                      seed=s * 10 + groups)
     out = jax.jit(paged_attention)(q, arenas["k_nan"], arenas["v_nan"], bt,
                                    pos, wmask)
     ref = paged_attention_reference(q, arenas["k"], arenas["v"], bt, pos)
@@ -125,14 +127,95 @@ def test_sixteen_kv_heads_with_one_query_row_each(interpret, s):
     assert records and all(r["path"] == "pallas" for r in records)
 
 
-def test_kernel_float32_arena_is_near_exact(interpret):
+def _chunk_and_sub():
+    from ray_tpu.ops import paged_attention as pa
+
+    return pa._CHUNK_TOKENS_FEW_ROWS, pa._SUB_TOKENS
+
+
+def _row_patterns():
+    """name -> the rows' live lengths: what the few-rows tile's chain of
+    copies (a row's first chunk started under the row before) and its
+    sub-blocks under a dynamic bound can get wrong."""
+    chunk, sub = _chunk_and_sub()
+    return {
+        "live_idle_live": (40, 0, 300),
+        "idle_first": (0, 0, 77, 200),
+        "idle_last": (130, 19, 0),
+        "all_idle": (0, 0, 0),
+        "one_chunk": (chunk, 5, chunk),
+        "chunk_plus_one": (chunk + 1, 5, chunk + 1),
+        "one_sub_block": (sub, sub, 3, sub + 1),
+        "one_token": (1, 1, 1),
+        "two_chunks_then_one": (chunk + 90, 20, 2 * chunk, 100),
+        "one_then_two_chunks": (100, 2 * chunk, 0, 20, chunk + 90),
+    }
+
+
+@pytest.mark.parametrize("kvh,groups,s", [
+    (16, 1, 1), (2, 4, 1), (4, 5, 1), (4, 1, 5), (2, 4, 5)])
+@pytest.mark.parametrize("pattern", [
+    "live_idle_live", "idle_first", "idle_last", "all_idle", "one_chunk",
+    "chunk_plus_one", "one_sub_block", "one_token", "two_chunks_then_one",
+    "one_then_two_chunks"])
+def test_few_rows_tile_over_row_patterns(interpret, pattern, kvh, groups, s):
+    """One to twenty query rows a KV head (the looped model's 1, Mistral's
+    4, Falcon-H1's 5, a speculative round's 5 and 20) over rows that are
+    live, idle, one token, one sub-block, one chunk, a chunk and a token,
+    two chunks: every page the table does not map and the dead tail of
+    every last live page hold NaN, so a stale buffer row, a copy that
+    landed in the wrong slot or a sub-block past the live length shows."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.paged_attention import (paged_attention,
                                              paged_attention_reference)
 
-    q, arenas, bt, pos, wmask = _case(3, 2, 2, LENS, MAX_BLOCKS, jnp.float32)
+    lens = _row_patterns()[pattern]
+    chunk, _ = _chunk_and_sub()
+    q, arenas, bt, pos, wmask = _case(
+        s, kvh, groups, lens, 2 * chunk // BS + 2, jnp.bfloat16,
+        seed=len(pattern) * 100 + kvh * groups + s)
+    out = jax.jit(paged_attention)(q, arenas["k_nan"], arenas["v_nan"], bt,
+                                   pos, wmask)
+    ref = paged_attention_reference(q, arenas["k"], arenas["v"], bt, pos)
+    out, ref = (np.asarray(a, np.float32) for a in (out, ref))
+    assert np.isfinite(out).all()
+    used = np.asarray(wmask)
+    np.testing.assert_allclose(out[used], ref[used], atol=2e-2, rtol=2e-2)
+    if used.any():
+        assert np.abs(out[used] - ref[used]).mean() < 1e-3
+    for i, n in enumerate(lens):
+        if not n:
+            assert (out[i] == 0).all()
+    (rec,) = [r for r in _paged_records() if r["shape"] == list(q.shape)]
+    assert rec["path"] == "pallas" and rec["tile"].startswith("few rows")
+
+
+def test_walk_chains_the_rows_that_walk():
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import _walk
+
+    walk = np.asarray(_walk(jnp.asarray([0, 5, 0, 600, 513, 0], jnp.int32),
+                            512))
+    assert walk.tolist() == [[0, 5, 0, 600, 513, 0],      # live length
+                             [0, 0, 1, 1, 3, 5],          # chunks before
+                             [1, 3, 3, 4, -1, -1]]        # next that walks
+    assert np.asarray(_walk(jnp.zeros((3,), jnp.int32), 512))[2].tolist() \
+        == [-1, -1, -1]
+
+
+@pytest.mark.parametrize("s,kvh,groups", [(3, 2, 2), (1, 4, 1), (1, 4, 5)])
+def test_kernel_float32_arena_is_near_exact(interpret, s, kvh, groups):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import (paged_attention,
+                                             paged_attention_reference)
+
+    q, arenas, bt, pos, wmask = _case(s, kvh, groups, LENS, MAX_BLOCKS,
+                                      jnp.float32)
     out = jax.jit(paged_attention)(q, arenas["k_nan"], arenas["v_nan"], bt,
                                    pos, wmask)
     ref = paged_attention_reference(q, arenas["k"], arenas["v"], bt, pos)
@@ -152,6 +235,34 @@ def test_without_write_mask_every_query_is_used(interpret):
     ref = paged_attention_reference(q, arenas["k"], arenas["v"], bt, pos)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=2e-2)
+
+
+def test_timing_script_rehearses(interpret, tmp_path, capsys):
+    """`scripts/time_paged_kernels.py --rehearsal`: the cells' decode
+    shapes cut small, through the interpreter, against the reference; no
+    device number comes out of a CPU."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "time_paged_kernels",
+        os.path.join(root, "scripts", "time_paged_kernels.py"))
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    assert script.main(["--rehearsal", "--out", str(tmp_path)]) == 0
+    (line,) = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+               if x.startswith("{")]
+    with open(tmp_path / "paged_kernels.json") as f:
+        assert json.load(f) == [line]
+    assert line["tree"] == "this" and line["platform"] == "cpu"
+    assert set(line["us"]) == set(script.REHEARSAL)
+    assert all(v is None for key in ("us", "us_op", "roofline_pct")
+               for v in line[key].values())
+    assert max(line["max_abs_diff"].values()) <= script.TOLERANCE
+    assert {(p[0], p[1]) for p in line["paths"]} == {
+        ("paged_decode", "pallas"), ("paged_prefill", "pallas")}
 
 
 # --------------------------------------------------------------------------- #
@@ -187,6 +298,11 @@ def _shapes(s=1, hd=HD, bs=BS, kvh=2, dtype="bfloat16"):
      "kv_heads do not fill the arena's tiles"),
     (True, {"dtype": "float16"}, "paged_decode", "reference",
      "q and arena not both bfloat16 or both float32"),
+    # the tile is the shape's: 2 query heads a KV head x 64 tokens are the
+    # last few-rows tile, one token more the first many-rows tile
+    (True, {"s": 64}, "paged_prefill", "pallas", ""),
+    (True, {"s": 65}, "paged_prefill", "pallas", ""),
+    (True, {"dtype": "float32"}, "paged_decode", "pallas", ""),
 ])
 def test_dispatch_records(monkeypatch, switch, kwargs, want_pass, want_path,
                           want_reason):
@@ -208,6 +324,13 @@ def test_dispatch_records(monkeypatch, switch, kwargs, want_pass, want_path,
     assert rec["dtype"] == kwargs.get("dtype", "bfloat16")
     label = want_path + (f": {want_reason}" if want_reason else "")
     assert paged_calls() == {(want_pass, label): 1}
+    # Which tile of the kernel the call's shape was given: said by the
+    # record, and nothing for a call the kernel did not take.
+    want_tile = "" if want_path == "reference" else \
+        "many rows" if kwargs.get("s", 1) * 2 > 128 else "few rows"
+    assert rec["tile"].startswith(want_tile) and bool(rec["tile"]) == bool(
+        want_tile)
+    assert paged_calls("tile") == {(want_pass, rec["tile"]): 1}
 
 
 def test_interpret_switch_is_refused_on_tpu(interpret, monkeypatch):
@@ -288,6 +411,9 @@ def test_engine_tokens_identical_kernel_and_reference(wide_llama,
     assert stats["steps"]["prefill"] > len(got)          # chunked prefill
     assert_compiles_once(stats, "prefill_compiles", "decode_compiles")
     assert stats["paged_attn"] == {"decode": "pallas", "prefill": "pallas"}
+    assert all(tile.startswith("few rows")
+               for tile in stats["paged_attn_tile"].values())
+    assert ref_stats["paged_attn_tile"] == {"decode": "", "prefill": ""}
     assert ref_stats["paged_attn"] == {
         "decode": "reference: platform cpu",
         "prefill": "reference: platform cpu"}
