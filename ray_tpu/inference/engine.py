@@ -11,8 +11,9 @@ and re-queues it for recompute — so the answer to memory pressure is
 degraded latency, never an OOM.
 
 The engine knows nothing of the model's family. It is handed `model` and
-`params` and asks the model five things (and a sixth of a model that has
-it), and nothing else (docs/INFERENCE.md, "The model contract"):
+`params` and asks the model five things (and a sixth or a seventh of a
+model that has it), and nothing else (docs/INFERENCE.md, "The model
+contract"):
 
 - `model.paged_cache(num_blocks, block_size, mesh, batch_slots)`: the
   paged cache, a pytree the engine donates to every step and never looks
@@ -46,6 +47,13 @@ it), and nothing else (docs/INFERENCE.md, "The model contract"):
   once for both. The engine looks for it once, when it builds its
   programs. A model without it is never asked again and keeps the two
   programs below;
+- optionally `model.decode_block`: the model's unit of work is a BLOCK
+  of `length` positions that several passes denoise (its `mask_id`, its
+  `schedule` of positions a pass commits, its `select` rule, which runs
+  on the device). Looked for once, when the programs are built; the
+  engine then decodes by blocks (`_build_block_programs`: programs and a
+  decode path of their own, docs/INFERENCE.md finding (i)), and a model
+  without it is never asked again;
 - `model.place_on_mesh(params, mesh) -> (params, tp)`: tensor-parallel
   placement and the tp degree;
 - `model.early_exit_draft(params) -> (draft_model, draft_params)`: asked
@@ -216,11 +224,22 @@ class Request:
     inflight: int = 0                 # tokens dispatched, not yet harvested
     cur_token: Optional[int] = None   # last harvested token (spec input)
     _pinned_node: Any = None          # radix node pinned while scheduled
+    # A model that decodes by blocks (`_build_block_programs`): the positions of a
+    # block (1: a token a step), the block under way as the host knows it,
+    # blocks committed and row-passes run, and, where asked for
+    # (`add_request(record_passes=True)`), every pass's buffer.
+    block: int = 1
+    cur_block: Any = None
+    blocks: int = 0
+    passes: int = 0
+    pass_log: Optional[List[Dict]] = None
 
     @property
     def total_to_prefill(self) -> int:
-        # Recompute after preemption replays prompt + already-generated.
-        return len(self.prompt) + len(self.generated)
+        # Recompute after preemption replays prompt + already-generated;
+        # whole blocks of them (the tail opens the next block, decoded).
+        return (len(self.prompt) + len(self.generated)) // self.block \
+            * self.block
 
     @property
     def done(self) -> bool:
@@ -244,6 +263,21 @@ class _InFlight:
     # Of a decode step's rows the one that is no decode row: the request
     # whose final chunk rode aboard, and whose first token this is.
     first: Optional[Request] = None
+    # Of a block step, beside each of `rows`: (the masked positions it
+    # entered with, its block's first position, the buffer a block just
+    # begun started from or None, the given tokens and the tokens to emit
+    # of a commit pass or None of a denoise pass).
+    passes: Optional[List[tuple]] = None
+
+
+@dataclass
+class _Block:
+    """A row's block under way, as the host knows it without reading the
+    device: prompt tokens it opened with, positions still masked once the
+    passes dispatched so far have run, denoise passes dispatched."""
+    given: int
+    masks: int
+    t: int = 0
 
 
 class InferenceEngine:
@@ -347,6 +381,8 @@ class InferenceEngine:
         # the executions dispatched whose tokens are not read yet, oldest
         # first. Not donated: an execution's output stays readable after
         # the next one took it as input.
+        self._token_shape = (cfg.batch_slots,)
+        self._token_block = 1       # positions a decode step gives a row
         self._tokens = self._fresh_tokens()
         self._inflight: collections.deque = collections.deque()
         self._slots: List[Optional[Request]] = [None] * cfg.batch_slots
@@ -408,6 +444,12 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         step = self._model.paged_step
+        # A model that decodes by BLOCKS says so once, here, and gets
+        # programs and a decode path of its own (`_build_block_programs`);
+        # nothing below, and no step of any other model, asks again.
+        self._block = getattr(self._model, "decode_block", None)
+        if self._block is not None:
+            return self._build_block_programs(step)
 
         # `tokens` is the device-resident last token of every slot. The
         # chunk writes its token into its slot's row (a chunk that is not
@@ -521,6 +563,235 @@ class InferenceEngine:
                 self._propose_fn = propose_fn
                 self._verify_fn = verify_fn
 
+    # ------------------------------------------------- decoding by blocks
+
+    def _build_block_programs(self, step):
+        """The programs and the decode path of a model whose unit of work
+        is a BLOCK of `length` positions denoised over several passes
+        (`model.decode_block`; docs/INFERENCE.md finding (i)).
+
+        The device-resident buffer is int32[batch_slots, length]: each
+        slot's block under way, an id where a position is committed or
+        given and -1 where it is still masked (which positions are masked
+        is kept beside the ids, so that a given token that happens to be
+        the mask id stays as given). One block program serves a denoise
+        pass and a commit pass alike, rows of one batch at different
+        passes: it starts the blocks the host says are new (`fresh`,
+        `start`), feeds the mask id where the buffer is masked, runs the
+        model's step over [batch_slots, length], and commits what the
+        model's selection rule picks of the `n` positions the host's
+        schedule gives each row (0 for a row with no mask left: its commit
+        pass, whose keys and values stay because the host then advances
+        `processed`). Prefill writes keys and values only: no logits are
+        read (no next-token shift), the head is dead code to the compiler.
+
+        The path is chosen HERE, once: `_decode_step`, `_harvest` and
+        `_chunk_dispatched` are rebound on this engine, and the one-token
+        methods of the class are never entered."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg, block = self.config, self._block
+        length = int(block.length)
+        if cfg.prefill_chunk % length or cfg.block_size % length:
+            raise ValueError(
+                f"prefill_chunk {cfg.prefill_chunk} and block_size "
+                f"{cfg.block_size} must be multiples of the model's block of "
+                f"{length} positions: a chunk covers whole blocks, and a "
+                f"page boundary is a point a prefix restores a sequence at")
+        if self._draft_len > 0:
+            raise ValueError(
+                "spec_decode_draft_len > 0 with a model that decodes by "
+                "blocks: a block's passes are its own speculation")
+        if self._adapters is not None:
+            raise ValueError("a model that decodes by blocks takes no "
+                             "adapter banks")
+        mask_id, select = int(block.mask_id), block.select
+
+        def prefill_fn(params, arenas, adapters, tokens, ids, bt, pos,
+                       wmask, last_idx, slot):
+            _, arenas = step(params, ids, arenas, bt, pos, wmask, adapters,
+                             slot, last_idx)
+            return tokens, arenas
+
+        def decode_fn(params, arenas, adapters, tokens, bt, pos, wmask,
+                      fresh, start, n):
+            buf = jnp.where(fresh[:, None], start, tokens)
+            masked = buf < 0
+            logits, arenas = step(params, jnp.where(masked, mask_id, buf),
+                                  arenas, bt, pos, wmask, adapters)
+            x0, chosen = select(logits, masked & wmask, n)
+            buf = jnp.where(chosen, x0, buf)
+            return jnp.where(wmask, buf, tokens), arenas
+
+        if cfg.use_jit:
+            prefill_fn = jax.jit(prefill_fn, donate_argnums=(1,))
+            decode_fn = jax.jit(decode_fn, donate_argnums=(1,))
+        self._prefill_fn, self._decode_fn = prefill_fn, decode_fn
+        self._decode_with_chunk_fn = None
+        self._draft_prefill_fn = self._propose_fn = self._verify_fn = None
+        self._token_block = length
+        self._token_shape = (cfg.batch_slots, length)
+        self._tokens = self._fresh_tokens()
+        self._decode_step = self._block_decode_step
+        self._harvest = self._block_harvest
+        self._chunk_dispatched = self._block_chunk_dispatched
+        # Row-passes and what they did (stats()["diffusion"]), counted at
+        # the harvest, for the rows that were still there.
+        self._diffusion = {
+            "block_length": length, "schedule": list(block.schedule),
+            "rule": "static" if block.threshold is None else "dynamic",
+            "blocks_committed": 0, "denoise_passes": 0, "commit_passes": 0,
+            "tokens_committed": 0, "given_tokens": 0, "truncated_tokens": 0,
+            # index a: denoise row-passes that committed exactly a
+            "committed_hist": [0] * (length + 1)}
+
+    def _block_chunk_dispatched(self, chunk: tuple) -> bool:
+        """Book a chunk's tokens as written. Never True: the last chunk
+        leaves no token on the device, the request's blocks begin with the
+        next block step."""
+        req, n, _ = chunk
+        req.processed += n
+        if req.processed >= req.total_to_prefill:
+            req.state = DECODE
+        return False
+
+    def _block_decode_step(self, chunk: Optional[tuple] = None) -> bool:
+        """Dispatch one block execution: every row that is decoding takes
+        the next pass of its block. The host knows which without reading
+        the device: a block starts with its masks counted (the prompt's
+        tail is given), a denoise pass commits what the schedule says, and
+        a row with none left takes its commit pass, whose dispatch books
+        the block's positions as processed and its tokens as in flight."""
+        import numpy as np
+
+        cfg, block, clock = self.config, self._block, self._clock
+        length = block.length
+        clock.enter(DECODE_HOST)
+        active: List[Request] = []
+        for req in list(self._scheduled()):
+            if req.state == DECODE and self._ensure_blocks(
+                    req, req.processed + length):
+                active.append(req)
+        active = [r for r in active if r.state == DECODE
+                  and r.slot is not None]
+        if not active:
+            return False
+        B = cfg.batch_slots
+        pos = np.zeros(B, np.int32)
+        wmask = np.zeros((B, length), bool)
+        fresh = np.zeros(B, bool)
+        start = np.full((B, length), -1, np.int32)
+        n = np.zeros(B, np.int32)
+        rows = [None] * B
+        passes = []
+        for req in active:
+            i = req.slot
+            rows[i] = req
+            pos[i] = req.processed
+            wmask[i] = True
+            began = None
+            if req.cur_block is None:
+                # The tokens past the whole blocks open this block as
+                # given: a prompt's tail, or after a preemption the tail
+                # of what was generated.
+                tail = (req.prompt + req.generated)[req.processed:]
+                req.cur_block = _Block(len(tail), length - len(tail))
+                fresh[i] = True
+                start[i, :len(tail)] = tail
+                began = start[i].tolist()
+            cur = req.cur_block
+            before, emit = cur.masks, None
+            if before:
+                n[i] = min(block.schedule[cur.t], before)
+                cur.masks -= int(n[i])
+                cur.t += 1
+            else:
+                emit = min(length - cur.given, req.max_new_tokens
+                           - len(req.generated) - req.inflight)
+                req.inflight += emit
+                req.cur_block = None
+            passes.append((before, int(pos[i]), began,
+                           None if emit is None else (cur.given, emit)))
+        bt = self._block_table_rows(rows)
+        clock.enter(DECODE_DISPATCH)
+        self._tokens, self._arenas = self._call(
+            "decode", self._decode_fn, self._params, self._arenas, None,
+            self._tokens, bt, pos, wmask, fresh, start, n)
+        clock.enter(DECODE_HOST)
+        self._ledger["decode"] += 1
+        self._ledger["decode_ahead"] += bool(self._inflight)
+        self._tokens.copy_to_host_async()
+        tracked = []
+        for req, (_, _, _, commit) in zip(active, passes):
+            tracked.append((req, req.slot, req.preemptions))
+            if commit is None:
+                continue
+            req.processed += length
+            if req.budget_dispatched:
+                # Its last block is in flight: the slot is the next
+                # admission's; the blocks stay until `_finish`.
+                self._slots[req.slot] = None
+                req.slot = None
+        self._inflight.append(_InFlight(True, self._tokens, tracked,
+                                        passes=passes))
+        return True
+
+    def _block_harvest(self, emissions, keep: int) -> bool:
+        """Read the buffers of the oldest block executions in flight until
+        `keep` are left: a commit pass's row hands its block's tokens to
+        its request (cut at the budget, and at EOS), a denoise pass's is
+        counted. Under the dynamic rule how many positions a pass commits
+        is the device's to say, so nothing stays in flight: the host reads
+        each row's masks left before it dispatches the next pass."""
+        import numpy as np
+
+        clock, book = self._clock, self._diffusion
+        dynamic = self._block.threshold is not None
+        if dynamic:
+            keep = 0
+        harvested = False
+        while len(self._inflight) > keep:
+            rec = self._inflight[0]
+            clock.enter(DECODE_SYNC)
+            view = np.asarray(rec.tokens)
+            clock.enter(DECODE_EMIT)
+            self._inflight.popleft()
+            harvested = True
+            left = (view < 0).sum(axis=1).tolist()
+            for (req, slot, preemptions), (before, at, began, commit) in zip(
+                    rec.rows, rec.passes):
+                if req.state != DECODE or req.preemptions != preemptions:
+                    self._ledger["dropped_rows"] += 1
+                    continue
+                self._ledger["decode_rows"] += 1
+                req.passes += 1
+                if req.pass_log is not None:
+                    req.pass_log.append({
+                        "start": at, "left": view[slot].tolist(),
+                        "entered": began if began is not None
+                        else req.pass_log[-1]["left"]})
+                if commit is None:
+                    took = before - left[slot]
+                    book["denoise_passes"] += 1
+                    book["tokens_committed"] += took
+                    book["committed_hist"][took] += 1
+                    if dynamic and req.cur_block is not None:
+                        req.cur_block.masks = left[slot]
+                    continue
+                given, emit = commit
+                book["commit_passes"] += 1
+                book["blocks_committed"] += 1
+                book["given_tokens"] += given
+                book["truncated_tokens"] += self._token_block - given - emit
+                req.blocks += 1
+                req.inflight -= emit
+                for token in view[slot, given:given + emit].tolist():
+                    if req.done:
+                        break               # EOS inside the block
+                    self._emit_token(req, token, emissions)
+        return harvested
+
     def _program_compiles(self, name: str) -> int:
         fn = {"prefill": self._prefill_fn, "decode": self._decode_fn,
               "decode_with_chunk": self._decode_with_chunk_fn,
@@ -549,7 +820,7 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
 
-        shape = (self.config.batch_slots,)
+        shape = self._token_shape
         if self._mesh is None:
             return jnp.zeros(shape, jnp.int32)
         replicated = jax.sharding.NamedSharding(
@@ -587,7 +858,8 @@ class InferenceEngine:
                     on_finish: Optional[Callable] = None,
                     request_id: Optional[str] = None,
                     model_id: Optional[str] = None,
-                    slo_class: Optional[str] = None) -> Request:
+                    slo_class: Optional[str] = None,
+                    record_passes: bool = False) -> Request:
         cfg = self.config
         prompt = [int(t) for t in prompt] or [0]
         max_new_tokens = max(1, int(max_new_tokens))
@@ -595,7 +867,9 @@ class InferenceEngine:
         if slo not in ("interactive", "batch"):
             raise ValueError(f"unknown slo_class {slo!r} "
                              "(expected 'interactive' or 'batch')")
-        total = len(prompt) + max_new_tokens
+        # ... in whole blocks, where the model decodes by blocks
+        total = -(-(len(prompt) + max_new_tokens) // self._token_block) \
+            * self._token_block
         if total > self._max_context or not self._bm.fits(total):
             if not self._table_width:
                 raise ValueError(
@@ -624,7 +898,8 @@ class InferenceEngine:
                 submitted_at=time.monotonic(),
                 trace_ctx=_tracing.capture(),
                 model_id=model_id, adapter_row=adapter_row,
-                slo_class=slo)
+                slo_class=slo, block=self._token_block,
+                pass_log=[] if record_passes and self._block else None)
             self._live[rid] = req
             # Queue order is (class, arrival): interactive ahead of
             # batch, FIFO within a class.
@@ -795,8 +1070,11 @@ class InferenceEngine:
                     return
             self._waiting.remove(req)
             req.slot = free_slots[0]
-            req.state = PREFILL
             req.processed = matched_tokens
+            # (a prompt shorter than a block, or one whose whole blocks
+            # were all adopted, has nothing to prefill: blocks only)
+            req.state = PREFILL if matched_tokens < req.total_to_prefill \
+                else DECODE
             req.cached_tokens += matched_tokens
             if req.admitted_at is None:
                 req.admitted_at = time.monotonic()
@@ -823,6 +1101,7 @@ class InferenceEngine:
         victim.processed = 0
         victim.inflight = 0      # their harvest drops them (preemptions)
         victim.cur_token = None
+        victim.cur_block = None
         victim.preemptions += 1
         self._preemptions += 1
         if _tracing._ENABLED:
@@ -1307,7 +1586,9 @@ class InferenceEngine:
                 "engine.decode", eo(req.first_token_at), eo(end),
                 parent_ctx=req.trace_ctx,
                 attrs=dict(attrs, tokens=len(req.generated),
-                           preemptions=req.preemptions))
+                           preemptions=req.preemptions,
+                           **({"blocks": req.blocks, "passes": req.passes}
+                              if req.passes else {})))
 
     # --------------------------------------------------------------- stats
 
@@ -1442,6 +1723,9 @@ class InferenceEngine:
             },
             **({"adapters": self._adapters.stats()}
                if self._adapters is not None else {}),
+            **({"diffusion": {**self._diffusion, "committed_hist": list(
+                self._diffusion["committed_hist"])}}
+               if self._block is not None else {}),
         }
 
     def check_no_leaks(self):

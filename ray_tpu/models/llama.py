@@ -130,10 +130,13 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
 
 
 def paged_write_and_attend(q, k, v, k_arena, v_arena, block_tables,
-                           positions, write_mask):
+                           positions, write_mask, sees=None):
     """Scatter this call's K/V ([b, kv_heads, s, d], after RoPE) into the
     paged arenas and attend q [b, heads, s, d] over them. Returns (attn
-    [b, heads, s, d], k_arena, v_arena)."""
+    [b, heads, s, d], k_arena, v_arena). `sees` [b, s] is the last logical
+    position each query attends to, where that is not its own (a model
+    whose mask is not causal token by token: `models/sdar.py`); the scatter
+    goes by `positions` either way."""
     hd = q.shape[-1]
     # Named for the profiler: device ops of the paged path carry
     # `paged_attn` in their op_name (PERF.md, Open questions).
@@ -168,7 +171,8 @@ def paged_write_and_attend(q, k, v, k_arena, v_arena, block_tables,
         # own K/V are in the arena it reads.
         attn = paged_attention(
             q.transpose(0, 2, 1, 3), k_arena, v_arena, block_tables,
-            positions, write_mask).transpose(0, 2, 1, 3)
+            positions if sees is None else sees,
+            write_mask).transpose(0, 2, 1, 3)
     return attn, k_arena, v_arena
 
 
