@@ -53,7 +53,14 @@ contract"):
   on the device). Looked for once, when the programs are built; the
   engine then decodes by blocks (`_build_block_programs`: programs and a
   decode path of their own, docs/INFERENCE.md finding (i)), and a model
-  without it is never asked again;
+  without it is never asked again. The block program's rows are TWO
+  blocks wide: a row whose block has no mask left takes its commit pass
+  (the block once more from its final ids, whose keys and values stay)
+  and its next block's first denoise pass in one execution, unless that
+  was its last block or the next finds no page; `paged_step` is then
+  handed `read_from` [b], where in its row the block begins whose logits
+  are read (logits[b, length, vocab]). A row-pass is one block of one
+  row through one execution, and the engine's books count row-passes;
 - `model.place_on_mesh(params, mesh) -> (params, tp)`: tensor-parallel
   placement and the tp degree;
 - `model.early_exit_draft(params) -> (draft_model, draft_params)`: asked
@@ -263,11 +270,17 @@ class _InFlight:
     # Of a decode step's rows the one that is no decode row: the request
     # whose final chunk rode aboard, and whose first token this is.
     first: Optional[Request] = None
-    # Of a block step, beside each of `rows`: (the masked positions it
-    # entered with, its block's first position, the buffer a block just
-    # begun started from or None, the given tokens and the tokens to emit
-    # of a commit pass or None of a denoise pass).
+    # Of a block step, whose `rows` are ROW-PASSES (a row whose commit
+    # pass has a denoise pass aboard is there twice, the commit first),
+    # beside each: (the masked positions it entered with, its block's
+    # first position, the buffer a block just begun started from or None,
+    # the given tokens and the tokens to emit of a commit pass or None of
+    # a denoise pass, whether a commit pass has the next block's first
+    # denoise pass aboard). `before` is the buffer the execution was
+    # given: a commit aboard's block, which the execution's own buffer no
+    # longer holds.
     passes: Optional[List[tuple]] = None
+    before: Any = None
 
 
 @dataclass
@@ -278,6 +291,32 @@ class _Block:
     given: int
     masks: int
     t: int = 0
+
+
+def block_decode_fn(step, block):
+    """The block program of a model that decodes by blocks, unjitted
+    (`InferenceEngine._build_block_programs` says what it is; a test
+    compiles it for a described chip at a cell's sizes): `step` the model's
+    `paged_step`, `block` its `decode_block`."""
+    import jax.numpy as jnp
+
+    length, mask_id, select = int(block.length), int(block.mask_id), \
+        block.select
+
+    def decode_fn(params, arenas, adapters, tokens, bt, pos, wmask, fresh,
+                  start, n):
+        aboard = wmask[:, length]               # [b]: both halves are live
+        first = jnp.where(fresh[:, None], start, tokens)
+        ids = jnp.concatenate([first, start], axis=1)
+        logits, arenas = step(
+            params, jnp.where(ids < 0, mask_id, ids), arenas, bt, pos, wmask,
+            adapters, read_from=jnp.where(aboard, length, 0))
+        buf = jnp.where(aboard[:, None], start, first)
+        x0, chosen = select(logits, (buf < 0) & wmask[:, :length], n)
+        buf = jnp.where(chosen, x0, buf)
+        return jnp.where(wmask[:, :length], buf, tokens), arenas
+
+    return decode_fn
 
 
 class InferenceEngine:
@@ -574,22 +613,40 @@ class InferenceEngine:
         slot's block under way, an id where a position is committed or
         given and -1 where it is still masked (which positions are masked
         is kept beside the ids, so that a given token that happens to be
-        the mask id stays as given). One block program serves a denoise
-        pass and a commit pass alike, rows of one batch at different
-        passes: it starts the blocks the host says are new (`fresh`,
-        `start`), feeds the mask id where the buffer is masked, runs the
-        model's step over [batch_slots, length], and commits what the
+        the mask id stays as given). A ROW-PASS is one block of one row
+        through one execution: a denoise pass, which commits what the
         model's selection rule picks of the `n` positions the host's
-        schedule gives each row (0 for a row with no mask left: its commit
-        pass, whose keys and values stay because the host then advances
-        `processed`). Prefill writes keys and values only: no logits are
-        read (no next-token shift), the head is dead code to the compiler.
+        schedule gives the row, or the commit pass of a block with no mask
+        left, which chooses nothing and whose keys and values stay because
+        the host then advances `processed`. Every book (`decode_rows`,
+        `stats()["diffusion"]`, `Request.passes`, `pass_log`) counts
+        row-passes.
+
+        ONE block program serves them all, rows of one batch at different
+        passes, and it is [batch_slots, 2 x length] wide, because a row
+        whose block has no mask left takes its commit pass AND its next
+        block's first denoise pass in the same execution (a commit ABOARD:
+        two row-passes, one execution; four executions a block of four
+        where the schedule alone has five). Such a row's first half is the
+        buffer (the block's final ids) and its second the next block, all
+        masks (`start`); the model's step scatters the call's keys and
+        values before each layer attends, so the second half reads what a
+        commit pass of its own would have left. The program tells such a
+        row by its write mask (both halves live), reads logits at the half
+        that denoises (`paged_step`'s `read_from`) and leaves that half in
+        the buffer. Every other row-pass runs in the first half with the
+        second dead: the passes of a block after its first, a request's
+        first block after its prefill (`fresh`, `start`: the prompt's tail
+        is given), and the commit pass that does NOT ride: the request's
+        last block, or one whose next block found no page (nobody is
+        preempted for a page a pass early). Prefill writes keys and values
+        only: no logits are read (no next-token shift), the head is dead
+        code to the compiler.
 
         The path is chosen HERE, once: `_decode_step`, `_harvest` and
         `_chunk_dispatched` are rebound on this engine, and the one-token
         methods of the class are never entered."""
         import jax
-        import jax.numpy as jnp
 
         cfg, block = self.config, self._block
         length = int(block.length)
@@ -606,7 +663,6 @@ class InferenceEngine:
         if self._adapters is not None:
             raise ValueError("a model that decodes by blocks takes no "
                              "adapter banks")
-        mask_id, select = int(block.mask_id), block.select
 
         def prefill_fn(params, arenas, adapters, tokens, ids, bt, pos,
                        wmask, last_idx, slot):
@@ -614,15 +670,7 @@ class InferenceEngine:
                              slot, last_idx)
             return tokens, arenas
 
-        def decode_fn(params, arenas, adapters, tokens, bt, pos, wmask,
-                      fresh, start, n):
-            buf = jnp.where(fresh[:, None], start, tokens)
-            masked = buf < 0
-            logits, arenas = step(params, jnp.where(masked, mask_id, buf),
-                                  arenas, bt, pos, wmask, adapters)
-            x0, chosen = select(logits, masked & wmask, n)
-            buf = jnp.where(chosen, x0, buf)
-            return jnp.where(wmask, buf, tokens), arenas
+        decode_fn = block_decode_fn(step, block)
 
         if cfg.use_jit:
             prefill_fn = jax.jit(prefill_fn, donate_argnums=(1,))
@@ -642,6 +690,9 @@ class InferenceEngine:
             "block_length": length, "schedule": list(block.schedule),
             "rule": "static" if block.threshold is None else "dynamic",
             "blocks_committed": 0, "denoise_passes": 0, "commit_passes": 0,
+            # commit row-passes that rode with the next block's first
+            # denoise pass
+            "commits_aboard": 0,
             "tokens_committed": 0, "given_tokens": 0, "truncated_tokens": 0,
             # index a: denoise row-passes that committed exactly a
             "committed_hist": [0] * (length + 1)}
@@ -658,11 +709,14 @@ class InferenceEngine:
 
     def _block_decode_step(self, chunk: Optional[tuple] = None) -> bool:
         """Dispatch one block execution: every row that is decoding takes
-        the next pass of its block. The host knows which without reading
-        the device: a block starts with its masks counted (the prompt's
-        tail is given), a denoise pass commits what the schedule says, and
-        a row with none left takes its commit pass, whose dispatch books
-        the block's positions as processed and its tokens as in flight."""
+        the next row-pass of its block (`_build_block_programs`). The host
+        knows which without reading the device: a block starts with its
+        masks counted (the prompt's tail is given), a denoise pass commits
+        what the schedule says, and a row with none left takes its commit
+        pass, whose dispatch books the block's positions as processed and
+        its tokens as in flight, and with it, unless that was the
+        request's last block or the next one finds no page, the next
+        block's first denoise pass."""
         import numpy as np
 
         cfg, block, clock = self.config, self._block, self._clock
@@ -679,68 +733,79 @@ class InferenceEngine:
             return False
         B = cfg.batch_slots
         pos = np.zeros(B, np.int32)
-        wmask = np.zeros((B, length), bool)
+        wmask = np.zeros((B, 2 * length), bool)
         fresh = np.zeros(B, bool)
         start = np.full((B, length), -1, np.int32)
         n = np.zeros(B, np.int32)
         rows = [None] * B
-        passes = []
+        # a row-pass each: a row whose commit has a denoise pass aboard is
+        # two, the commit first
+        tracked, passes, leaving = [], [], []
         for req in active:
-            i = req.slot
+            i, at = req.slot, req.processed
+            row = (req, i, req.preemptions)
             rows[i] = req
-            pos[i] = req.processed
-            wmask[i] = True
+            pos[i] = at
+            wmask[i, :length] = True
             began = None
             if req.cur_block is None:
                 # The tokens past the whole blocks open this block as
                 # given: a prompt's tail, or after a preemption the tail
                 # of what was generated.
-                tail = (req.prompt + req.generated)[req.processed:]
+                tail = (req.prompt + req.generated)[at:]
                 req.cur_block = _Block(len(tail), length - len(tail))
                 fresh[i] = True
                 start[i, :len(tail)] = tail
                 began = start[i].tolist()
             cur = req.cur_block
-            before, emit = cur.masks, None
-            if before:
-                n[i] = min(block.schedule[cur.t], before)
-                cur.masks -= int(n[i])
-                cur.t += 1
-            else:
+            if not cur.masks:
                 emit = min(length - cur.given, req.max_new_tokens
                            - len(req.generated) - req.inflight)
                 req.inflight += emit
+                req.processed += length
                 req.cur_block = None
-            passes.append((before, int(pos[i]), began,
-                           None if emit is None else (cur.given, emit)))
+                aboard = not req.budget_dispatched and self._ensure_blocks(
+                    req, req.processed + length, preempt=False)
+                tracked.append(row)
+                passes.append((0, at, None, (cur.given, emit), aboard))
+                if not aboard:
+                    if req.budget_dispatched:
+                        leaving.append(req)
+                    continue
+                at += length
+                wmask[i] = True
+                cur = req.cur_block = _Block(0, length)
+                began = [-1] * length
+            before = cur.masks
+            n[i] = min(block.schedule[cur.t], before)
+            cur.masks -= int(n[i])
+            cur.t += 1
+            tracked.append(row)
+            passes.append((before, at, began, None, False))
         bt = self._block_table_rows(rows)
         clock.enter(DECODE_DISPATCH)
+        was = self._tokens
         self._tokens, self._arenas = self._call(
             "decode", self._decode_fn, self._params, self._arenas, None,
-            self._tokens, bt, pos, wmask, fresh, start, n)
+            was, bt, pos, wmask, fresh, start, n)
         clock.enter(DECODE_HOST)
         self._ledger["decode"] += 1
         self._ledger["decode_ahead"] += bool(self._inflight)
         self._tokens.copy_to_host_async()
-        tracked = []
-        for req, (_, _, _, commit) in zip(active, passes):
-            tracked.append((req, req.slot, req.preemptions))
-            if commit is None:
-                continue
-            req.processed += length
-            if req.budget_dispatched:
-                # Its last block is in flight: the slot is the next
-                # admission's; the blocks stay until `_finish`.
-                self._slots[req.slot] = None
-                req.slot = None
+        for req in leaving:
+            # Its last block is in flight: the slot is the next
+            # admission's; the blocks stay until `_finish`.
+            self._slots[req.slot] = None
+            req.slot = None
         self._inflight.append(_InFlight(True, self._tokens, tracked,
-                                        passes=passes))
+                                        passes=passes, before=was))
         return True
 
     def _block_harvest(self, emissions, keep: int) -> bool:
         """Read the buffers of the oldest block executions in flight until
-        `keep` are left: a commit pass's row hands its block's tokens to
-        its request (cut at the budget, and at EOS), a denoise pass's is
+        `keep` are left, a row-pass at a time: a commit pass hands its
+        block's tokens to its request (cut at the budget, and at EOS, which
+        also drops the denoise pass that rode with it), a denoise pass is
         counted. Under the dynamic rule how many positions a pass commits
         is the device's to say, so nothing stays in flight: the host reads
         each row's masks left before it dispatches the next pass."""
@@ -759,16 +824,23 @@ class InferenceEngine:
             self._inflight.popleft()
             harvested = True
             left = (view < 0).sum(axis=1).tolist()
-            for (req, slot, preemptions), (before, at, began, commit) in zip(
-                    rec.rows, rec.passes):
+            # A commit aboard ran on the buffer as the execution found it
+            # (read at the harvest before this one); what the execution
+            # left there is the next block.
+            given_view = np.asarray(rec.before) if any(
+                p[4] for p in rec.passes) else None
+            for (req, slot, preemptions), (before, at, began, commit,
+                                           aboard) in zip(rec.rows,
+                                                          rec.passes):
                 if req.state != DECODE or req.preemptions != preemptions:
                     self._ledger["dropped_rows"] += 1
                     continue
                 self._ledger["decode_rows"] += 1
                 req.passes += 1
+                final = (given_view if aboard else view)[slot]
                 if req.pass_log is not None:
                     req.pass_log.append({
-                        "start": at, "left": view[slot].tolist(),
+                        "start": at, "left": final.tolist(),
                         "entered": began if began is not None
                         else req.pass_log[-1]["left"]})
                 if commit is None:
@@ -781,12 +853,13 @@ class InferenceEngine:
                     continue
                 given, emit = commit
                 book["commit_passes"] += 1
+                book["commits_aboard"] += aboard
                 book["blocks_committed"] += 1
                 book["given_tokens"] += given
                 book["truncated_tokens"] += self._token_block - given - emit
                 req.blocks += 1
                 req.inflight -= emit
-                for token in view[slot, given:given + emit].tolist():
+                for token in final[given:given + emit].tolist():
                     if req.done:
                         break               # EOS inside the block
                     self._emit_token(req, token, emissions)
@@ -1114,10 +1187,13 @@ class InferenceEngine:
         self._waiting.sort(key=self._prio)
         return True
 
-    def _ensure_blocks(self, req: Request, num_tokens: int) -> bool:
+    def _ensure_blocks(self, req: Request, num_tokens: int,
+                       preempt: bool = True) -> bool:
         """Grow req's block table — reclaiming cold cached prefixes
         first, then preempting victims — until it fits. False when req
-        itself was preempted (caller must drop it)."""
+        itself was preempted (caller must drop it), or, with `preempt`
+        off, when only a preemption would have made room (nobody is
+        preempted and req's table stays as it was)."""
         while not self._bm.ensure(req.request_id, num_tokens):
             deficit = (self._bm.blocks_for_tokens(num_tokens)
                        - len(self._bm.block_table(req.request_id))
@@ -1125,7 +1201,7 @@ class InferenceEngine:
             if (self._prefix is not None
                     and self._prefix.evict_for(deficit) > 0):
                 continue
-            if not self._preempt_one():
+            if not preempt or not self._preempt_one():
                 return False
             if req.state == WAITING:   # preempted itself
                 return False

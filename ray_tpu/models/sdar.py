@@ -39,7 +39,14 @@ confidence passes `confidence_threshold` where those are more); when none is
 masked a COMMIT pass runs the block once more from its final tokens and its
 keys and values stay. `decode_block` tells the engine that, and the engine
 runs the passes: the model's step is the same `paged_step` for a chunk of
-prompt, a denoise pass and a commit pass.
+prompt, a denoise pass and a commit pass. A commit pass chooses nothing, so
+the engine lets it RIDE with the next block's first denoise pass: one step
+over both blocks of the row (`paged_step`'s `read_from`), which is the two
+steps apart because every layer scatters the call's keys and values before
+it attends and the later block sees the earlier one under vis(p). It does
+not ride where the block is the request's last or the next has no page; a
+ROW-PASS is one block of one row through one step, five a block either
+way, and the engine's books count row-passes.
 
 What the published config does not hold (`assumed` in the benchmark's
 configuration file): the q/k norms, the absent shift, L, the steps, the
@@ -163,7 +170,10 @@ class SDARConfig:
 @dataclass(frozen=True)
 class BlockDecode:
     """The contract's `decode_block`: what the engine needs to run a row's
-    block through its passes (module docstring, GENERATION)."""
+    block through its passes (module docstring, GENERATION). The engine's
+    block program is two blocks wide (a commit pass rides with the next
+    block's first denoise pass) and hands `paged_step` `read_from`; `select`
+    sees the one block whose logits were read."""
     length: int                  # L: positions a block, tokens a commit
     mask_id: int                 # the id a masked position is fed as
     schedule: Tuple[int, ...]    # positions denoise pass t commits
@@ -272,11 +282,13 @@ def published_weights(cfg: SDARConfig, params) -> Tuple[Dict[str, Any], Any]:
 # --------------------------------------------------------------------------- #
 
 
-def routed_experts(cfg: SDARConfig, lp, n, live):
+def routed_experts(cfg: SDARConfig, lp, n, live, live_tokens=None):
     """The expert layer on n [T, hidden] (normed): (y [T, hidden] f32,
     counts, routing). `live` [T] marks the rows that are real tokens; the
-    others are routed to no expert. `routing` f32 [2k, T] is what the
-    experts were HANDED: the chosen experts above their gates."""
+    others are routed to no expert, and `live_tokens` is how many the
+    caller expects of them (None: all; `held_expert_forward`). `routing`
+    f32 [2k, T] is what the experts were HANDED: the chosen experts above
+    their gates."""
     experts = cfg.num_experts
     with jax.named_scope("moe_route"):
         probs, gates, index = moe.route(n, lp["router"],
@@ -289,12 +301,12 @@ def routed_experts(cfg: SDARConfig, lp, n, live):
     with jax.named_scope("moe_experts"):
         y, counts = moe.held_expert_forward(
             n, gates, index, lp["w_gate_up"], lp["w_down"], (0, experts),
-            experts)
+            experts, live_tokens)
     return y, counts, routing
 
 
 def _layer(cfg: SDARConfig, lp, x, k_arena, v_arena, tables, positions, sees,
-           rotary, write_mask):
+           rotary, write_mask, live_tokens=None):
     """One layer on the residual stream x [b, s, hidden] (f32): (x, k_arena,
     v_arena, the expert layer's counts, its routing)."""
     dt, eps = cfg.dtype, cfg.rms_norm_eps
@@ -317,7 +329,7 @@ def _layer(cfg: SDARConfig, lp, x, k_arena, v_arena, tables, positions, sees,
         x = x + _product(attn, lp["wo"])
     n = _rms_norm(x, lp["mlp_norm"], eps).astype(dt)
     y, counts, routing = routed_experts(cfg, lp, n.reshape(b * s, e),
-                                        write_mask.reshape(-1))
+                                        write_mask.reshape(-1), live_tokens)
     return x + y.reshape(b, s, e), k_arena, v_arena, counts, routing
 
 
@@ -382,17 +394,33 @@ class SDAR:
                             "assigned", "tiles", "drew", "max_load")}}}
 
     def paged_step(self, params, ids, cache, block_tables, row_pos,
-                   write_mask, adapters=None, slots=None, last_idx=None):
+                   write_mask, adapters=None, slots=None, last_idx=None,
+                   read_from=None):
         """One step: ids [b, s] at positions row_pos[b] + arange(s), each
         seeing its whole diffusion block and all before it. Returns (logits
         [b, s, vocab] of the tokens AT those positions, or [b, vocab] at
-        `last_idx` [b]; the cache). `slots` is not looked at: nothing is
-        kept per slot."""
+        `last_idx` [b], or [b, block_length, vocab] of the block that
+        begins `read_from` [b] positions into its row; the cache). `slots`
+        is not looked at: nothing is kept per slot.
+
+        `read_from` is the engine's block program's (the contract's
+        `decode_block`): its rows are TWO blocks wide, a block whose keys
+        and values become final beside the next one's first denoise pass
+        (GENERATION above), and the head runs where logits are read. Every
+        layer scatters the call's keys and values before it attends, so the
+        later block reads the earlier one's final keys and values, as after
+        a commit pass of its own. A row's two blocks are rarely both live,
+        which the expert layer's tile is told (`routed_experts`)."""
         if adapters is not None:
             raise ValueError("SDAR has no adapter banks")
         cfg = self.config
         length = cfg.block_length
-        s = ids.shape[1]
+        b, s = ids.shape
+        # positions expected live of a block program's b x 2 x length: a
+        # block a row, and both in one of a block's `len(schedule)`
+        # executions (the commit aboard)
+        live_tokens = None if read_from is None else \
+            b * length + -(-b * length // len(cfg.schedule))
         positions = row_pos[:, None] + jnp.arange(s)[None, :]
         # ... as far as the call's live positions go (a chunk or a block
         # step covers whole blocks: the bound then changes nothing)
@@ -408,7 +436,7 @@ class SDAR:
         for lp, (k_arena, v_arena) in zip(params["layers"], cache["kv"]):
             x, k_arena, v_arena, count, routing = _layer(
                 cfg, lp, x, k_arena, v_arena, block_tables, positions, sees,
-                rotary, write_mask)
+                rotary, write_mask, live_tokens)
             kv.append((k_arena, v_arena))
             if not counts:                         # the first layer
                 with jax.named_scope("moe_record"):
@@ -416,12 +444,16 @@ class SDAR:
             counts.append(count)
         if last_idx is not None:
             x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+        elif read_from is not None:
+            x = jnp.take_along_axis(x, (read_from[:, None] + jnp.arange(
+                length)[None, :])[:, :, None], axis=1)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
             logits = _product(x.astype(cfg.dtype), params["lm_head"])
-        # a block step is `block_length` wide, a prefill chunk wider
+        # a prefill chunk reads logits at one position (the engine's reads
+        # none of them); a block step reads a block's, whatever its width
         return logits, {"kv": kv, "routing": record, "moe": _count(
-            cache["moe"], int(s != length), counts)}
+            cache["moe"], int(last_idx is not None), counts)}
 
     # ------------------------------------------------- counters (finding f)
 

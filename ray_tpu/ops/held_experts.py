@@ -79,6 +79,7 @@ import collections
 import functools
 import math
 import threading
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -368,20 +369,27 @@ def held_expert_mlp(x, gates, index, w_gate_up, w_down, held, experts: int):
 
 
 def held_expert_forward(x, gates, index, w_gate_up, w_down, held,
-                        experts: int):
+                        experts: int, live_tokens: Optional[int] = None):
     """`held_expert_mlp` for a step that is never differentiated (a served
     step): the same arguments, the same sums, and counts gains "tiles", the
     row tiles the products ran. Its layout is the rows the call HAS: the
     row tile is `serve_tile`'s by the call's static shapes, an expert that
     drew no row owns no tile (its weights are never read), and the buffer
     is `block + min(held, assignments) * tile` rows. No remat names; the
-    products are `moe_gmm` alone, so `jax.grad` through it raises."""
+    products are `moe_gmm` alone, so `jax.grad` through it raises.
+
+    `live_tokens` is how many of the T rows the caller expects to be real
+    tokens where it can say so statically (None: all of them): `serve_tile`
+    is asked with it, since an idle row draws no expert and what an expert
+    draws is what its tile should fit (a block step of `models/sdar.py`
+    holds 256 rows of which its schedule keeps ~160 live). The sums do not
+    depend on it."""
     return _held_experts(x, gates, index, w_gate_up, w_down, held, experts,
-                         forward_only=True)
+                         forward_only=True, live_tokens=live_tokens)
 
 
 def _held_experts(x, gates, index, w_gate_up, w_down, held, experts: int,
-                  forward_only: bool):
+                  forward_only: bool, live_tokens: Optional[int] = None):
     first_held, count = held
     if not (0 <= first_held and count >= 1
             and first_held + count <= experts):
@@ -395,7 +403,8 @@ def _held_experts(x, gates, index, w_gate_up, w_down, held, experts: int,
     # expert that can own one there (trained: each of them; forward only:
     # no more than there are assignments).
     if forward_only:
-        tile = serve_tile(tokens, top_k, experts)
+        tile = serve_tile(tokens if live_tokens is None
+                          else min(tokens, int(live_tokens)), top_k, experts)
         buffer = block + min(count, tokens * min(top_k, count)) * tile
         product = functools.partial(grouped_matmul_forward, tile=tile)
         named = _unnamed

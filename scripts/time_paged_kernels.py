@@ -17,6 +17,12 @@ The decode shapes (one query token a row; block tables of shuffled pages of
               near 350 and near 900, the two Mistral cells';
   falconh1.*  64 rows x 20 heads over 4 KV heads, a table of 64: near 290;
 
+the block step of a model that decodes by blocks (`models/sdar.py`; 32 rows
+x 32 heads over 4 KV heads, a table of 32, ~420 tokens a row, each query
+seeing to its block's end): `sdar.block64`, two blocks of four positions a
+row (64 query rows a KV head: the step since PR 63) beside `sdar.block32`,
+one block a row (32: the step before it);
+
 a speculative round's verify call at Mistral's widths (`mistral.spec5`: five
 query tokens a row, 20 query rows a KV head) and one prefill chunk each (`*.chunk`: 256 / 512 / 256 positions behind 256
 cached tokens), which the decode tile's changes must not move.
@@ -57,7 +63,8 @@ HBM_BYTES_PER_S = 819e9   # one v5e chip (benchmarks/peaks.py)
 TOLERANCE = 2e-2          # bf16 outputs of order one
 
 # name -> (rows, heads, kv_heads, table width, query tokens a row,
-#          (lowest, highest) context after the call)
+#          (lowest, highest) context after the call[, the positions of a
+#          diffusion block: a query sees to its block's end])
 SHAPES = {
     "ouro.u64_576": (8, 16, 16, 36, 1, (64, 576)),
     "ouro.128": (8, 16, 16, 36, 1, (128, 128)),
@@ -68,6 +75,8 @@ SHAPES = {
     "mistral.900": (16, 32, 8, 256, 1, (800, 1000)),
     "falconh1.290": (64, 20, 4, 64, 1, (200, 380)),
     "mistral.spec5": (16, 32, 8, 256, 5, (250, 450)),
+    "sdar.block32": (32, 32, 4, 32, 4, (332, 508), 4),
+    "sdar.block64": (32, 32, 4, 32, 8, (332, 508), 4),
     "ouro.chunk": (1, 16, 16, 36, 256, (512, 512)),
     "mistral.chunk": (1, 32, 8, 256, 512, (768, 768)),
     "falconh1.chunk": (1, 20, 4, 64, 256, (512, 512)),
@@ -76,6 +85,7 @@ REHEARSAL = {
     "ouro.u64_576": (3, 16, 16, 6, 1, (1, 96)),
     "mistral.350": (2, 8, 2, 40, 1, (500, 640)),
     "falconh1.290": (3, 10, 2, 6, 1, (20, 90)),
+    "sdar.block64": (3, 8, 2, 6, 8, (40, 92), 4),
     "ouro.chunk": (1, 16, 16, 6, 32, (64, 64)),
 }
 
@@ -94,9 +104,11 @@ def case(shape, seed: int):
     """(q, k_arena, v_arena, block_tables, positions, write_mask, live
     tokens): every row its own shuffled physical pages (page 0 the trash
     block the tables' tails point at)."""
-    b, heads, kvh, width, s, (lo, hi) = shape
+    b, heads, kvh, width, s, (lo, hi) = shape[:6]
+    length = shape[6] if len(shape) > 6 else 1
     rng = np.random.default_rng(seed)
-    context = rng.integers(lo, hi + 1, b)
+    # (whole diffusion blocks, where the model has them)
+    context = rng.integers(lo, hi + 1, b) // length * length
     tables = np.zeros((b, width), np.int32)
     held = -(-context // BLOCK)
     pages = 1 + rng.permutation(int(held.sum()))
@@ -107,6 +119,7 @@ def case(shape, seed: int):
     k, v = (jax.random.normal(key, arena, jnp.bfloat16) for key in keys[:2])
     q = jax.random.normal(keys[2], (b, s, heads, HD), jnp.bfloat16)
     positions = (context[:, None] - s + np.arange(s)[None]).astype(np.int32)
+    positions = (positions // length + 1) * length - 1
     return (q, k, v, jnp.asarray(tables), jnp.asarray(positions),
             jnp.ones((b, s), bool), int(context.sum()))
 

@@ -151,24 +151,48 @@ def pytest_configure(config):
         "scripts/gate.sh calls it, runs them")
 
 
-# Two tests under tests/benchmarks assert where the benchmark STOOD when
-# they were written, and the benchmark's own growth falsifies them: they
-# are the benchmark's files, which only a `benchmark` PR may edit, so the
-# PR that adds the eighth cell (PR 50) marks them here, and
-# tests/benchmarks/test_bench_kanana2.py asserts what of them still holds
-# (the manifest validates, the Brumby cell reports what it reported, a
-# four-chip cell beyond the quarter is refused). Strict: the day a
-# `benchmark` PR rewords them, they pass and this entry must go.
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a DESCRIBED v5e host, for compile rehearsals: the TPU's
+    compiler is installed here and compiles for a chip that is not
+    attached. Described inside the fixture, never at import (only one
+    process at a time may load the TPU's library); the test skips where it
+    cannot be. (The files under tests/benchmarks keep copies of their own:
+    only a `benchmark` PR may edit them.)"""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# A test under tests/benchmarks that asserts where the PROGRAM stood when it
+# was written, and that the program has outgrown: it is the benchmark's
+# file, which only a `benchmark` PR may edit, so the PR that outgrows it
+# enters it here and holds every assertion of it that still stands in a
+# twin outside tests/benchmarks. Strict: the day a `benchmark` PR rewords
+# it, it passes and its entry must go (as PR 59's rewording took the two
+# entries of PR 50 with it).
 OUTGROWN = {
-    "tests/benchmarks/test_bench_brumby.py::"
-    "test_the_manifest_is_clean_and_gained_what_the_issue_names":
-        "asserts that the Brumby cell, its configuration and its four "
-        "metrics are the LAST entries of BENCHMARK.json; a later cell is "
-        "appended after them",
-    "tests/benchmarks/test_bench_manifest.py::"
-    "test_validate_refuses[a second four-chip cell of four-<lambda>]":
-        "with eight cells a second four-chip cell is inside the quarter "
-        "that `manifest.validate` allows",
+    "tests/benchmarks/test_bench_sdar.py::"
+    "test_the_cells_rehearsal_runs_end_to_end":
+        "pins the block step's paged call at (4, 4, 8, 128), one block a "
+        "row; since PR 63 a row is two blocks wide, (4, 8, 8, 128): "
+        "tests/test_sdar_rehearsal.py holds every other assertion of it",
 }
 
 

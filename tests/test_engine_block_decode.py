@@ -61,14 +61,22 @@ MIX = ((7, 6), (16, 9), (21, 4), (3, 8), (18, 7))
 def test_one_compile_a_program_rows_at_different_passes_dispatch_ahead(tiny):
     engine = _engine(tiny)
     reqs = [engine.add_request(_ids(n, n), k) for n, k in MIX]
-    mixed = 0
+    mixed = aboard = 0
     while engine.has_work():
         engine.step()
         for rec in engine._inflight:
-            kinds = {commit is None for _, _, _, commit in rec.passes}
+            kinds = {commit is None for _, _, _, commit, _ in rec.passes}
             mixed += len(kinds) == 2
+            # a commit aboard: its row is there twice, the commit first
+            for (a, b), (one, two) in zip(
+                    zip(rec.rows, rec.rows[1:]),
+                    zip(rec.passes, rec.passes[1:])):
+                if one[4]:
+                    assert a == b and one[3] is not None and two[3] is None
+                    assert two[1] == one[1] + 4 and two[2] == [-1] * 4
+                    aboard += 1
     # a commit pass and a denoise pass rode in one execution
-    assert mixed > 0
+    assert mixed > 0 and aboard > 0
     for req, (n, k) in zip(reqs, MIX):
         assert req.state == eng.FINISHED
         assert req.generated == tiny[2](_ids(n, n), k)
@@ -81,12 +89,119 @@ def test_one_compile_a_program_rows_at_different_passes_dispatch_ahead(tiny):
         + book["commit_passes"]
     assert book["commit_passes"] == book["blocks_committed"] == sum(
         r.blocks for r in reqs)
+    # every commit but a request's last had the next block's first denoise
+    # pass aboard: nothing was short of a page
+    assert book["commits_aboard"] == book["commit_passes"] - len(reqs)
+    assert sum(r.passes for r in reqs) == steps["decode_rows"]
     assert book["tokens_committed"] + book["given_tokens"] == \
         4 * book["blocks_committed"]
     emitted = sum(k for _, k in MIX)
     assert 4 * book["blocks_committed"] - book["given_tokens"] \
         - book["truncated_tokens"] == emitted == stats["tokens_emitted"]
     assert book["committed_hist"] == [0, book["denoise_passes"], 0, 0, 0]
+    engine.check_no_leaks()
+
+
+@pytest.mark.parametrize("tail", [0, 1, 2, 3])
+def test_a_block_is_four_executions_and_five_row_passes(tiny, tail):
+    """A request alone: its first block denoises alone (the prompt's tail
+    given), every later block's first denoise pass rides with the commit
+    pass before it, and the last commit runs alone: 4 B + 1 executions for
+    B blocks of a prompt of whole blocks (5 B unfused). The books and the
+    pass log count ROW-passes: five a block, a commit before the next
+    block's denoise, and the tokens are the plain loop's."""
+    engine = _engine(tiny)
+    prompt, blocks = _ids(12 + tail, 80 + tail), 5
+    new = 4 * blocks - tail
+    req = engine.add_request(prompt, new, record_passes=True)
+    engine.run_until_idle()
+    assert req.generated == tiny[2](prompt, new)
+    stats = engine.stats()
+    steps, book = stats["steps"], stats["diffusion"]
+    assert steps["decode"] == 4 * blocks + 1 - tail
+    assert steps["decode_rows"] == req.passes == 5 * blocks - tail
+    assert (book["denoise_passes"], book["commit_passes"],
+            book["commits_aboard"]) == (4 * blocks - tail, blocks,
+                                        blocks - 1)
+    assert stats["prefill_compiles"] == stats["decode_compiles"] == 1
+    log = req.pass_log
+    assert len(log) == 5 * blocks - tail
+    assert [r["start"] for r in log] == sorted(r["start"] for r in log)
+    for b in range(blocks):
+        mine = [r for r in log if r["start"] == 12 + 4 * b]
+        assert len(mine) == 5 - (tail if b == 0 else 0)
+        # its denoise passes leave one mask less each, then its commit
+        assert [r["left"].count(-1) for r in mine] == \
+            list(range(len(mine) - 2, -1, -1)) + [0]
+        assert mine[-1]["entered"] == mine[-1]["left"] == mine[-2]["left"]
+        assert mine[0]["entered"] == (prompt[12:] + [-1] * 4)[:4] if b == 0 \
+            else mine[0]["entered"] == [-1] * 4
+    stream = prompt + req.generated
+    assert [t for r in log if -1 not in r["entered"]
+            for t in r["left"]] == stream[12:]
+    engine.check_no_leaks()
+
+
+def test_no_page_for_the_next_block_is_a_plain_commit(tiny):
+    """A page holds two blocks. With the pool hogged, the commit of a
+    page's first block still has the next block's denoise aboard (it needs
+    no page), the commit of its second runs alone, and nobody is preempted
+    for it."""
+    engine = _engine(tiny, prefix_cache_enabled=False)
+    bm = engine._bm
+    prompt = _ids(16, 90)
+    req = engine.add_request(prompt, 24)
+    while not (bm.registered(req.request_id)
+               and len(bm.block_table(req.request_id)) == 3):
+        engine.step()
+    bm.register("hog")
+    assert bm.ensure("hog", bm.num_free() * 8) and bm.num_free() == 0
+    alone = 0
+    while engine.has_work():
+        engine.step()
+        last = engine._inflight[-1].passes if engine._inflight else []
+        if any(p[3] is not None and not p[4] for p in last) and \
+                bm.registered("hog"):
+            alone += 1
+            bm.free("hog")
+    assert alone == 1 and req.preemptions == 0
+    assert req.generated == tiny[2](prompt, 24)
+    stats = engine.stats()
+    book = stats["diffusion"]
+    # six blocks: the last commit and the one short of a page ran alone
+    assert (book["commit_passes"], book["commits_aboard"]) == (6, 4)
+    assert stats["steps"]["decode"] == 4 * 6 + 1 + 1
+    engine.check_no_leaks()
+
+
+@pytest.mark.parametrize("how", ["preempt", "cancel"])
+def test_preemption_and_cancel_with_a_commit_aboard_in_flight(tiny, how):
+    """The youngest request goes while the execution that holds its commit
+    and the denoise pass aboard is in flight: both row-passes are dropped.
+    Preempted, it is recomputed from its final tokens."""
+    engine = _engine(tiny)
+    prompts = [_ids(12, 100), _ids(16, 101)]
+    reqs = [engine.add_request(p, 16) for p in prompts]
+    victim = reqs[1]
+
+    def aboard_in_flight():
+        return any(row[0] is victim and p[4] for rec in engine._inflight
+                   for row, p in zip(rec.rows, rec.passes))
+
+    while not aboard_in_flight():
+        engine.step()
+    dropped = engine.stats()["steps"]["dropped_rows"]
+    if how == "preempt":
+        assert engine._preempt_one() and victim.state == eng.WAITING
+    else:
+        assert engine.cancel(victim.request_id)
+    engine.run_until_idle()
+    assert engine.stats()["steps"]["dropped_rows"] >= dropped + 2
+    assert reqs[0].generated == tiny[2](prompts[0], 16)
+    if how == "preempt":
+        assert victim.generated == tiny[2](prompts[1], 16)
+    else:
+        assert victim.state == eng.FAILED
     engine.check_no_leaks()
 
 
@@ -143,12 +258,21 @@ def test_eos_inside_a_block_ends_the_request_there(tiny):
     at = next(i for i, t in enumerate(full)
               if full.index(t) == i and (10 + i) % 4 != 3)
     engine = _engine(tiny, eos_id=full[at])
-    req = engine.add_request(prompt, 12)
+    req = engine.add_request(prompt, 12, record_passes=True)
     other = engine.add_request(_ids(8, 8), 12)
     engine.run_until_idle()
     assert req.generated == full[:at + 1]
     assert other.state == eng.FINISHED
     engine.check_no_leaks()
+    # Where the block was not the request's last, its commit had the next
+    # block's first denoise pass aboard: that row-pass is dropped with the
+    # request (and the pass dispatched after it), never logged.
+    last = req.pass_log[-1]
+    assert -1 not in last["left"] and last["entered"] == last["left"]
+    assert last["start"] == (10 + at) // 4 * 4
+    if last["start"] + 4 < 10 + 12:
+        assert engine.stats()["diffusion"]["commits_aboard"] >= 1
+        assert engine.stats()["steps"]["dropped_rows"] >= 1
 
 
 def test_cancel_and_fail_all_in_mid_block(tiny):

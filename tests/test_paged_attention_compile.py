@@ -7,9 +7,9 @@ is described, not attached; nothing runs, so this says nothing about
 times. (`tests/benchmarks/test_bench_compile_rehearsal.py` compiles the
 same programs as the CPU sees them, through the reference.)
 
-The topology is described inside a fixture (never at import: only one
-process at a time may load the TPU's library) and the test skips where it
-cannot be."""
+The topology is described inside a fixture (`conftest.one_chip`; never at
+import: only one process at a time may load the TPU's library) and the test
+skips where it cannot be."""
 
 import json
 import os
@@ -19,29 +19,6 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 16e9
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — whatever keeps it from loading
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # A compile for a described chip is written to the persistent cache
-    # but cannot be read back without one: keep these out of it.
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
 
 
 def test_mistral_decode_and_prefill_compile_with_the_kernel(one_chip,

@@ -50,6 +50,19 @@ def test_forward_is_the_reference_under_the_block_mask(tiny):
     assert extra["experts"].shape == (2, 24, cfg.num_experts_per_tok)
 
 
+def _block_step_cache(model, params, prompt, width=6, bs=8):
+    """A cache with `prompt` (whole blocks) prefilled, and its one table."""
+    cache = model.paged_cache(1 + width, bs)
+    tables = jnp.arange(1, width + 1, dtype=jnp.int32)[None]
+    chunk = np.zeros((1, 24), np.int32)
+    chunk[0, :len(prompt)] = prompt
+    _, cache = model.paged_step(
+        params, jnp.asarray(chunk), cache, tables, jnp.zeros((1,), jnp.int32),
+        jnp.asarray(np.arange(24)[None] < len(prompt)), last_idx=jnp.zeros(
+            (1,), jnp.int32))
+    return cache, tables
+
+
 def test_block_steps_through_the_cache_are_the_reference(tiny):
     """A prefill of whole blocks, then a block's passes as the engine runs
     them: at every pass the logits at the block's positions, the
@@ -60,14 +73,8 @@ def test_block_steps_through_the_cache_are_the_reference(tiny):
     prompt = _ids(18, 3)
     whole = len(prompt) // length * length
     bs, width = 8, 6
-    cache = model.paged_cache(1 + width, bs)
-    tables = jnp.arange(1, width + 1, dtype=jnp.int32)[None]
-    chunk = np.zeros((1, 24), np.int32)
-    chunk[0, :whole] = prompt[:whole]
-    _, cache = model.paged_step(
-        params, jnp.asarray(chunk), cache, tables, jnp.zeros((1,), jnp.int32),
-        jnp.asarray(np.arange(24)[None] < whole), last_idx=jnp.zeros(
-            (1,), jnp.int32))
+    cache, tables = _block_step_cache(model, params, prompt[:whole], width,
+                                      bs)
     select = model.decode_block.select
     seq, buf = list(prompt[:whole]), prompt[whole:] + [-1] * (
         length - len(prompt) + whole)
@@ -105,6 +112,103 @@ def test_block_steps_through_the_cache_are_the_reference(tiny):
             assert int(x0[0, p]) == int(x0_ref[p])
             buf[p] = int(x0[0, p])
     assert t == buf.count(-1) + length - (len(prompt) - whole)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-6),
+                                        (jnp.bfloat16, 3e-2)])
+def test_a_commit_aboard_is_the_commit_step_then_the_denoise_step(dtype,
+                                                                  atol):
+    """One step over TWO blocks of a row, the first final and the second
+    all masks (`read_from`: the engine's block program), leaves the keys,
+    values and routing record, and gives the logits at the second block,
+    of the commit step followed by the denoise step at one block each:
+    every layer scatters before it attends. Both are block steps to the
+    counters, a prefill chunk is told by its `last_idx`."""
+    cfg = sdar.SDARConfig.tiny(dtype=dtype)
+    model = sdar.SDAR(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    length, mask_id = cfg.block_length, cfg.mask_token_id
+    prompt, final = _ids(16, 4), _ids(length, 5)
+    at = jnp.asarray([len(prompt)], jnp.int32)
+    masks = [mask_id] * length
+    # apart: the commit pass of the block, then the next block's denoise
+    cache, tables = _block_step_cache(model, params, prompt)
+    one = jnp.ones((1, length), bool)
+    _, cache = model.paged_step(params, jnp.asarray([final], jnp.int32),
+                                cache, tables, at, one)
+    want, apart = model.paged_step(params, jnp.asarray([masks], jnp.int32),
+                                   cache, tables, at + length, one)
+    # aboard: both in one step (a second row idle, a third a plain pass)
+    cache, tables = _block_step_cache(model, params, prompt)
+    got, aboard = model.paged_step(
+        params, jnp.asarray([final + masks], jnp.int32), cache, tables, at,
+        jnp.ones((1, 2 * length), bool),
+        read_from=jnp.asarray([length], jnp.int32))
+    assert got.shape == want.shape == (1, length, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol)
+    for (k_a, v_a), (k_b, v_b) in zip(aboard["kv"], apart["kv"]):
+        np.testing.assert_allclose(np.asarray(k_a[1:], np.float32),
+                                   np.asarray(k_b[1:], np.float32), atol=atol)
+        np.testing.assert_allclose(np.asarray(v_a[1:], np.float32),
+                                   np.asarray(v_b[1:], np.float32), atol=atol)
+    # the record of every cached token (block 0 is the trash block)
+    bs, k = aboard["kv"][0][0].shape[1], cfg.num_experts_per_tok
+    record_a, record_b = (np.asarray(c["routing"])[:, bs:]
+                          for c in (aboard, apart))
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(record_a[:k], record_b[:k])
+    np.testing.assert_allclose(record_a[k:], record_b[k:], atol=atol)
+    # [decode, prefill]: one block step for two, the chunk apart
+    assert np.asarray(aboard["moe"]["steps"]).tolist() == [1, 1]
+    assert np.asarray(apart["moe"]["steps"]).tolist() == [2, 1]
+    assert int(np.sum(aboard["moe"]["assigned"][0])) == int(np.sum(
+        apart["moe"]["assigned"][0])) == 2 * length * k * 2
+    # a row with its second half dead reads the first (a plain pass)
+    cache, tables = _block_step_cache(model, params, prompt)
+    live = jnp.asarray(np.arange(2 * length)[None] < length)
+    plain_logits, half = model.paged_step(
+        params, jnp.asarray([final + masks], jnp.int32), cache, tables, at,
+        live, read_from=jnp.zeros((1,), jnp.int32))
+    cache, tables = _block_step_cache(model, params, prompt)
+    alone, whole = model.paged_step(
+        params, jnp.asarray([final], jnp.int32), cache, tables, at, one)
+    np.testing.assert_allclose(np.asarray(plain_logits, np.float32),
+                               np.asarray(alone, np.float32), atol=atol)
+    np.testing.assert_allclose(
+        np.asarray(half["kv"][-1][0][1:], np.float32),
+        np.asarray(whole["kv"][-1][0][1:], np.float32), atol=atol)
+
+
+def test_the_expert_layers_tile_at_the_block_steps_shape():
+    """At the served widths: 32 rows of two blocks are 256 x 8 = 16 x 128
+    assignments, where `serve_tile`'s rule by shape flips to a chunk's
+    128-row tiles; the block step says what its schedule keeps live (160)
+    and gets 16. A chunk and a one-block step keep what they had."""
+    from ray_tpu.ops import held_experts
+
+    cfg = sdar.SDARConfig(num_hidden_layers=1)
+    model = sdar.SDAR(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.paged_cache(65, 16))
+    i32 = jnp.int32
+
+    def tile_of(b, s, **kwargs):
+        held_experts.reset_held_experts_status()
+        jax.eval_shape(
+            lambda p, c: model.paged_step(
+                p, jnp.zeros((b, s), i32), c, jnp.zeros((b, 4), i32),
+                jnp.zeros((b,), i32), jnp.ones((b, s), bool), **kwargs),
+            params, cache)
+        (call,) = held_experts.held_experts_status()
+        held_experts.reset_held_experts_status()
+        return call["tokens"], call["top_k"], call["tile"]
+
+    assert tile_of(32, 8, read_from=jnp.zeros((32,), i32)) == (256, 8, 16)
+    assert tile_of(32, 4) == (128, 8, 16)
+    assert tile_of(1, 256, last_idx=jnp.zeros((1,), i32)) == (256, 8, 128)
+    assert held_experts.serve_tile(256, 8, 128) == 128
+    assert held_experts.serve_tile(160, 8, 128) == 16
 
 
 def test_block_select_rules():
@@ -174,8 +278,13 @@ def test_dynamic_rule_commits_several_positions_a_pass():
     assert book["rule"] == "dynamic"
     assert sum(book["committed_hist"][2:]) > 0
     assert book["denoise_passes"] < book["tokens_committed"]
-    # synchronous: nothing is dispatched ahead of a read (finding (i))
+    # synchronous: nothing is dispatched ahead of a read (finding (i)),
+    # and a block the host has read as done commits with the next block's
+    # first denoise pass aboard all the same
     assert engine.stats()["steps"]["decode_ahead"] == 0
+    assert book["commits_aboard"] == book["commit_passes"] - len(reqs) > 0
+    assert engine.stats()["steps"]["decode"] < book["denoise_passes"] \
+        + book["commit_passes"]
     for req, prompt in zip(reqs, prompts):
         want, _ = plain.block_diffusion_generate(top, layer, prompt,
                                                  as_dict(cfg), 10)
@@ -254,3 +363,72 @@ def test_block_mask_through_the_paged_kernel(interpret, s, groups, start,
     # a query sees its block's LATER positions: not the causal answer
     causal = pa.paged_attention(q, k_arena, v_arena, tables, positions)
     assert float(jnp.max(jnp.abs(causal - got))) > 1e-3
+
+
+# --------------------------------------------------------------------------- #
+# the engine's block program compiled for a described v5e at the cell's sizes
+# --------------------------------------------------------------------------- #
+
+
+def test_the_block_program_compiles_for_one_v5e_chip_and_fits(
+        one_chip, monkeypatch):
+    """`engine.block_decode_fn`, the program `_build_block_programs` jits,
+    at the tenth cell's sizes (32 slots, two blocks of four positions a
+    row, depth 8): the kernels and not the interpreter, the paged call in
+    the few-rows tile at 64 query rows a KV head, the expert layers in
+    16-row tiles over 256 rows, the head on one block a row, the cache
+    updated in place, arguments and temporaries what the one-block program
+    took (11.5 GB)."""
+    from ray_tpu.inference.engine import block_decode_fn
+    from ray_tpu.ops import attention, grouped_matmul, held_experts
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.setattr(grouped_matmul, "_platform", lambda: "tpu")
+    with attention._CALLS_LOCK:
+        before = dict(attention._CALLS)
+        attention._CALLS.clear()
+    held_experts.reset_held_experts_status()
+    model = sdar.SDAR(sdar.SDARConfig(num_hidden_layers=8))
+    slots, width, length, layers = 32, 32, 4, 8
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = shaped(jax.eval_shape(lambda: model.paged_cache(1025, 16)))
+    i32, flag = jnp.int32, jnp.bool_
+    args = (spec((slots, length), i32), spec((slots, width), i32),
+            spec((slots,), i32), spec((slots, 2 * length), flag),
+            spec((slots,), flag), spec((slots, length), i32),
+            spec((slots,), i32))
+    fn = block_decode_fn(model.paged_step, model.decode_block)
+    try:
+        lowered = jax.jit(
+            lambda p, c, *rest: fn(p, c, None, *rest),
+            donate_argnums=(1,)).lower(params, cache, *args)
+        # the head and the selection on one block a row
+        assert f"tensor<{slots}x{length}x151936xf32>" in lowered.as_text()
+        assert f"x{2 * length}x151936x" not in lowered.as_text()
+        compiled = lowered.compile()
+        assert compiled.as_text().count("tpu_custom_call") == 3 * layers
+        mem = compiled.memory_analysis()
+        nbytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(cache))
+        assert mem.alias_size_in_bytes >= nbytes - 8
+        assert mem.temp_size_in_bytes < 0.2e9, mem
+        assert 11.4e9 < mem.argument_size_in_bytes \
+            + mem.temp_size_in_bytes < 11.6e9
+        calls = {(tuple(c["shape"]), c["path"], c["tile"][:9]): c["calls"]
+                 for c in attention.pallas_status()}
+        assert calls == {((32, 8, 32, 128), "pallas", "few rows:"): layers}
+        held = {(h["tokens"], h["top_k"], h["tile"], h["path"]): h["calls"]
+                for h in held_experts.held_experts_status()}
+        assert held == {(256, 8, 16, "pallas"): layers}
+    finally:
+        with attention._CALLS_LOCK:
+            attention._CALLS.clear()
+            attention._CALLS.update(before)
+        held_experts.reset_held_experts_status()
