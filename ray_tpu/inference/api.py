@@ -5,7 +5,7 @@ replica (the replica's actor owns the chip; the engine thread owns the
 jitted step programs). Two entry points:
 
 - `__call__` / `generate`: complete the whole generation, return
-  ``{"ids": [...]}`` — wire-compatible with the `LlamaSampler` example.
+  ``{"ids": [...]}``.
 - `stream`: an async generator yielding one event per produced token;
   the existing replica/handle/proxy stream plumbing carries them to
   Python callers (``handle.options(stream=True)``) and HTTP clients
@@ -31,8 +31,8 @@ from ray_tpu.inference.engine import EngineConfig, EngineLoop, InferenceEngine
 def preset_model(model_size: str = "tiny", max_model_len: int = 256):
     """(model, params) of a preset named the way `LLMServer` names it:
     a Llama at `llama_preset`'s widths with randomly initialised weights
-    from key 0, matching the sampler examples (same name, same weights,
-    on every replica and every rank of a gang)."""
+    from key 0 (same name, same weights, on every replica and every rank
+    of a gang)."""
     import jax
     import jax.numpy as jnp
 

@@ -10,63 +10,16 @@ blocks the engine preempts the lowest-priority sequence — frees its blocks
 and re-queues it for recompute — so the answer to memory pressure is
 degraded latency, never an OOM.
 
-The engine knows nothing of the model's family. It is handed `model` and
-`params` and asks the model five things (and a sixth or a seventh of a
-model that has it), and nothing else (docs/INFERENCE.md, "The model
-contract"):
-
-- `model.paged_cache(num_blocks, block_size, mesh, batch_slots)`: the
-  paged cache, a pytree the engine donates to every step and never looks
-  inside (and builds anew in `fail_all`). It may hold state per batch
-  SLOT beside the paged blocks, which is why it is told their number;
-- `model.paged_step(params, ids[b, s], cache, block_tables, row_pos,
-  write_mask, adapters, slots, last_idx) -> (logits, cache)`: the one
-  step, where `adapters` is None or (banks, adapter_idx[b]); `slots` is
-  each row's batch slot (None: row i is slot i, as in decode) and
-  `last_idx` the one position a row whose logits are read (None: every
-  position, logits[b, s, vocab]; else logits[b, vocab]). A slot is the
-  address of a row's per-slot state, `write_mask` its hold (a row with
-  no live position keeps its state), and a live row whose first position
-  is 0 starts from zero state. With them go two attributes:
-  `model.prefix_restores`, whether a prefix of blocks alone restores a
-  sequence (where not, nothing is adopted from or donated to the radix
-  prefix cache, and speculation is refused: its rejected positions
-  would need a rollback), and `model.slot_state_bytes`, what a slot
-  holds beside the blocks (for `stats()`). A third, `pageless_context`,
-  is answered only by a model whose cache has NO paged part (per-slot
-  state and nothing else): the positions a sequence may reach. The
-  engine then hands out no block (`kv_cache.NoBlocks`: admission is by
-  free slots alone and nothing is ever preempted for blocks), gives the
-  step programs a block table zero blocks wide, and bounds a request by
-  that context and not by `max_blocks_per_seq`;
-- optionally `model.paged_step_with_chunk(params, tokens[b, 1],
-  chunk_ids[1, c], cache, block_tables, row_pos, write_mask, chunk_bt,
-  chunk_pos, chunk_wmask, chunk_slot, last_idx) -> (logits [b, vocab],
-  the chunk's logits at last_idx [1, vocab], cache)`: a decode step and
-  one sequence's prefill chunk as ONE execution, which reads the weights
-  once for both. The engine looks for it once, when it builds its
-  programs. A model without it is never asked again and keeps the two
-  programs below;
-- optionally `model.decode_block`: the model's unit of work is a BLOCK
-  of `length` positions that several passes denoise (its `mask_id`, its
-  `schedule` of positions a pass commits, its `select` rule, which runs
-  on the device). Looked for once, when the programs are built; the
-  engine then decodes by blocks (`_build_block_programs`: programs and a
-  decode path of their own, docs/INFERENCE.md finding (i)), and a model
-  without it is never asked again. The block program's rows are TWO
-  blocks wide: a row whose block has no mask left takes its commit pass
-  (the block once more from its final ids, whose keys and values stay)
-  and its next block's first denoise pass in one execution, unless that
-  was its last block or the next finds no page; `paged_step` is then
-  handed `read_from` [b], where in its row the block begins whose logits
-  are read (logits[b, length, vocab]). A row-pass is one block of one
-  row through one execution, and the engine's books count row-passes;
-- `model.place_on_mesh(params, mesh) -> (params, tp)`: tensor-parallel
-  placement and the tp degree;
-- `model.early_exit_draft(params) -> (draft_model, draft_params)`: asked
-  only when speculation is on and no draft was injected;
-- `model.adapter_banks(n_rows, rank, mesh)`: the adapter banks' shapes
-  and shardings, asked only by `AdapterManager`.
+The engine knows nothing of the model's family and imports none. It is
+handed `model` and `params`, and what it asks of the model is stated once,
+as code: `ray_tpu.models._served.PagedModel` (`paged_cache`, `paged_step`,
+the attributes `prefix_restores`, `slot_state_bytes`, `pageless_context`,
+the optional `paged_step_with_chunk`, `decode_block` and `cache_counters`,
+and `place_on_mesh`, `early_exit_draft`, `adapter_banks`). The engine reads
+each of them directly: a model derives from `PagedModel`, whose defaults
+say "the model does not offer it", so a misspelt answer is an error and not
+a silent no (docs/INFERENCE.md, "The model contract", has the reasons and
+the findings).
 
 Two jitted programs serve every request mix, each compiled exactly once:
 
@@ -356,7 +309,7 @@ class InferenceEngine:
         # a sequence may reach (`pageless_context`): no block is handed
         # out or counted against admission, and the block table the step
         # programs are given is zero blocks wide.
-        pageless = getattr(model, "pageless_context", None)
+        pageless = model.pageless_context
         if pageless is None:
             self._bm = BlockManager(cfg.num_blocks, cfg.block_size)
             self._table_width = cfg.max_blocks_per_seq
@@ -486,7 +439,7 @@ class InferenceEngine:
         # A model that decodes by BLOCKS says so once, here, and gets
         # programs and a decode path of its own (`_build_block_programs`);
         # nothing below, and no step of any other model, asks again.
-        self._block = getattr(self._model, "decode_block", None)
+        self._block = self._model.decode_block
         if self._block is not None:
             return self._build_block_programs(step)
 
@@ -532,7 +485,7 @@ class InferenceEngine:
         # program above); in the engine's own books it is a program of its
         # own, compiled once.
         self._decode_with_chunk_fn = None
-        fused = getattr(self._model, "paged_step_with_chunk", None)
+        fused = self._model.paged_step_with_chunk
         if (fused is not None and self._draft_len == 0
                 and self._adapters is None):
             def decode_with_chunk_fn(params, arenas, tokens, bt, pos, wmask,
@@ -1699,7 +1652,7 @@ class InferenceEngine:
         the lock the cache is not mid-donation; the copy is ordered behind
         the execution in flight and outlives the next donation. One copy
         is outstanding at a time."""
-        peek = getattr(self._model, "cache_counters", None)
+        peek = self._model.cache_counters
         if peek is None or self._counters_pending is not None:
             return
         import jax

@@ -54,8 +54,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.falcon_h1 import _normal, _rms_norm, _RowsOfTransposed
-from ray_tpu.models.llama import apply_rope
+from ray_tpu.models._nn import (RowsOfTransposed, apply_rope, normal,
+                                rms_norm)
+from ray_tpu.models._served import PagedModel
 from ray_tpu.ops import power_retention as retention
 
 
@@ -117,7 +118,7 @@ def init_params(cfg: BrumbyConfig, key) -> Dict[str, Any]:
     e, dt = cfg.hidden_size, cfg.dtype
     qd, kvd = (cfg.num_attention_heads * cfg.head_dim,
                cfg.num_key_value_heads * cfg.head_dim)
-    draw = jax.jit(_normal, static_argnums=(1, 2, 3))
+    draw = jax.jit(normal, static_argnums=(1, 2, 3))
     keys = iter(jax.random.split(key, 2 + 9 * cfg.num_hidden_layers))
     params = {"embed": draw(next(keys), (cfg.vocab_size, e), dt),
               "lm_head": draw(next(keys), (e, cfg.vocab_size), dt),
@@ -149,7 +150,7 @@ def published_weights(params) -> Tuple[Dict[str, Any], Any]:
     The program fuses nothing, so the map is names and a transpose."""
     top = {"model.embed_tokens.weight": params["embed"],
            "model.norm.weight": params["final_norm"],
-           "lm_head.weight": _RowsOfTransposed(params["lm_head"])}
+           "lm_head.weight": RowsOfTransposed(params["lm_head"])}
     products = {"wq": "self_attn.q_proj", "wk": "self_attn.k_proj",
                 "wv": "self_attn.v_proj", "wg": "self_attn.g_proj",
                 "wo": "self_attn.o_proj", "w_gate": "mlp.gate_proj",
@@ -186,7 +187,7 @@ def _retention_layer(cfg, lp, h, state, sums, positions, slots, fresh, live):
                  cfg.head_dim)
 
     def heads(t, n, norm):
-        t = _rms_norm(t.reshape(b, s, n, d), norm, cfg.rms_norm_eps)
+        t = rms_norm(t.reshape(b, s, n, d), norm, cfg.rms_norm_eps)
         t = apply_rope(t.transpose(0, 2, 1, 3), positions, cfg.rope_theta)
         return t.transpose(0, 2, 1, 3).astype(cfg.dtype)
 
@@ -214,20 +215,21 @@ def _retention_layer(cfg, lp, h, state, sums, positions, slots, fresh, live):
 
 def _block(cfg, lp, x, state, sums, positions, slots, fresh, live):
     dt = cfg.dtype
-    h = _rms_norm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dt)
+    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dt)
     out, state, sums = _retention_layer(cfg, lp, h, state, sums, positions,
                                         slots, fresh, live)
     x = x + out
     with jax.named_scope("mlp"):
-        n = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(dt)
+        n = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(dt)
         x = x + (jax.nn.silu(n @ lp["w_gate"]) * (n @ lp["w_up"])) \
             @ lp["w_down"]
     return x, state, sums
 
 
-class Brumby:
-    """The model the engine is handed: its configuration and the answers
-    of the model contract. Parameters are a plain pytree (`init_params`)."""
+class Brumby(PagedModel):
+    """The model the engine is handed: its configuration and what of the
+    model contract differs from `PagedModel`'s defaults. Parameters are a
+    plain pytree (`init_params`)."""
 
     # There are no blocks: nothing a prefix could restore.
     prefix_restores = False
@@ -296,7 +298,7 @@ class Brumby:
             sums.append(total)
         if last_idx is not None:
             x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-        x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.dot(x.astype(cfg.dtype), params["lm_head"],
                          preferred_element_type=jnp.float32)
         return logits, {"state": states, "sums": sums}
@@ -307,27 +309,3 @@ class Brumby:
         no live position keeps its state whatever its position says (the
         engine hands idle rows position 0)."""
         return write_mask[:, 0] & (row_pos == 0), write_mask
-
-    def forward(self, params, ids):
-        """Logits [b, s, vocab] of whole sequences from position 0: one
-        `paged_step` over a cache of its own (tests, offline scoring)."""
-        b, s = ids.shape
-        logits, _ = self.paged_step(
-            params, ids, self.paged_cache(0, 1, None, b),
-            jnp.zeros((b, 0), jnp.int32), jnp.zeros((b,), jnp.int32),
-            jnp.ones((b, s), bool), None, jnp.arange(b, dtype=jnp.int32))
-        return logits
-
-    def place_on_mesh(self, params, mesh):
-        """tp = 1 only: a state shared by a GQA group is not sharded."""
-        axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        if int(axes.get("tp", 1)) != 1:
-            raise ValueError("Brumby serves at tp = 1 only")
-        return params, 1
-
-    def early_exit_draft(self, params):
-        raise ValueError("Brumby has no draft: speculation needs a "
-                         "rollback of per-slot state")
-
-    def adapter_banks(self, n_rows: int, rank: int, mesh=None):
-        raise ValueError("Brumby has no adapter banks")

@@ -82,7 +82,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.falcon_h1 import _normal, _rms_norm, _RowsOfTransposed
+# kanana2_controls.py replaces this module's _rms_norm (and latent_rows).
+from ray_tpu.models._nn import (RowsOfTransposed, cache_locations, normal,
+                                rms_norm as _rms_norm)
+from ray_tpu.models._served import PagedModel
 from ray_tpu.ops import held_experts as moe
 from ray_tpu.ops.latent_attention import (WALK_COUNTS, latent_attention,
                                           tile_walk)
@@ -189,7 +192,7 @@ def init_params(cfg: DeepseekV3Config, key) -> Dict[str, Any]:
     n, r, v, lat = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                     cfg.v_head_dim, cfg.kv_lora_rank)
     experts, f = cfg.n_routed_experts, cfg.moe_intermediate_size
-    draw = jax.jit(_normal, static_argnums=(1, 2, 3))
+    draw = jax.jit(normal, static_argnums=(1, 2, 3))
     keys = iter(jax.random.split(key, 2 + 12 * cfg.num_hidden_layers))
     params = {"embed": draw(next(keys), (cfg.vocab_size, e), dt),
               "lm_head": draw(next(keys), (e, cfg.vocab_size), dt),
@@ -243,7 +246,7 @@ def published_weights(cfg: DeepseekV3Config, params
     back = np.argsort(rope_interleave_order(r))   # published lane -> ours
     top = {"model.embed_tokens.weight": params["embed"],
            "model.norm.weight": params["final_norm"],
-           "lm_head.weight": _RowsOfTransposed(params["lm_head"])}
+           "lm_head.weight": RowsOfTransposed(params["lm_head"])}
 
     def layer(i: int) -> Dict[str, Any]:
         lp = params["layers"][i]
@@ -323,16 +326,6 @@ def absorbed_query(cfg, lp, h, positions):
     q_lat = jnp.einsum("bshn,hnl->bshl", q[..., :n].astype(cfg.dtype),
                        lp["w_uk"], preferred_element_type=f32)
     return jnp.concatenate([q_lat, q_rope], axis=-1).astype(cfg.dtype)
-
-
-def cache_locations(block_tables, positions, write_mask, block_size: int):
-    """Where each token's row lies in an arena taken as [blocks x block,
-    ..]: [b * s]. Masked tokens (batch and chunk padding) land in trash
-    block 0."""
-    blk = jnp.clip(positions // block_size, 0, block_tables.shape[1] - 1)
-    phys = jnp.where(write_mask,
-                     jnp.take_along_axis(block_tables, blk, axis=1), 0)
-    return (phys * block_size + positions % block_size).reshape(-1)
 
 
 def _a_group_at_a_time(groups, fn, *arrays):
@@ -447,13 +440,11 @@ def _count(moe_counters, kind: int, per_layer):
     return {k: v.at[kind].add(add[k]) for k, v in moe_counters.items()}
 
 
-class DeepseekV3:
-    """The model the engine is handed: its configuration and the answers
-    of the model contract. Parameters are a plain pytree (`init_params`)."""
-
-    # A prefix of latent blocks alone restores a sequence; no slot state.
-    prefix_restores = True
-    slot_state_bytes = 0
+class DeepseekV3(PagedModel):
+    """The model the engine is handed: its configuration and what of the
+    model contract differs from `PagedModel`'s defaults (a prefix of latent
+    blocks alone restores a sequence; no slot state). Parameters are a
+    plain pytree (`init_params`)."""
 
     def __init__(self, config: DeepseekV3Config):
         self.config = config
@@ -613,31 +604,3 @@ class DeepseekV3:
                 "load_max_over_mean": sums["max_over_mean"] / calls}
         out["load"] = [int(v) for v in np.sum(host["load"], axis=(0, 1))]
         return {"moe": out, "latent_walk": walk}
-
-    # ---------------------------------------------------------- the rest
-
-    def forward(self, params, ids):
-        """Logits [b, s, vocab] of whole sequences from position 0: one
-        `paged_step` over a cache of its own, 16-token blocks (tests,
-        offline scoring)."""
-        b, s = ids.shape
-        per_row = -(-s // 16)
-        tables = 1 + jnp.arange(b * per_row, dtype=jnp.int32).reshape(
-            b, per_row)
-        logits, _ = self.paged_step(
-            params, ids, self.paged_cache(1 + b * per_row, 16), tables,
-            jnp.zeros((b,), jnp.int32), jnp.ones((b, s), bool))
-        return logits
-
-    def place_on_mesh(self, params, mesh):
-        """tp = 1 only: one latent page serves every head of a slot."""
-        axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        if int(axes.get("tp", 1)) != 1:
-            raise ValueError("DeepseekV3 serves at tp = 1 only")
-        return params, 1
-
-    def early_exit_draft(self, params):
-        raise ValueError("DeepseekV3 has no draft")
-
-    def adapter_banks(self, n_rows: int, rank: int, mesh=None):
-        raise ValueError("DeepseekV3 has no adapter banks")

@@ -18,7 +18,7 @@ The equations, each scalar a key of the published config:
 `Attn`: `num_attention_heads` query / `num_key_value_heads` KV heads of
 `head_dim`, k = key_multiplier * W_k u, rotary over the whole head
 (rotate-half, `rope_theta`, no scaling), causal softmax at head_dim^-0.5,
-no bias. K/V live in the paged arenas (`models/llama.py
+no bias. K/V live in the paged arenas (`models/_nn.py
 paged_write_and_attend`, `ops/paged_attention.py`).
 
 `SSM` (Mamba-2; `mamba_d_ssm` = heads x `mamba_d_head`, which overrides
@@ -64,7 +64,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import apply_rope, paged_write_and_attend
+# tests/benchmarks/test_bench_kanana2.py imports `_normal` from here.
+from ray_tpu.models._nn import (RowsOfTransposed, apply_rope,
+                                normal as _normal, paged_write_and_attend,
+                                rms_norm)
+from ray_tpu.models._served import PagedModel
 from ray_tpu.ops import ssd
 
 
@@ -149,25 +153,6 @@ def mup_vector(cfg: FalconH1Config) -> jnp.ndarray:
 # Parameters
 # --------------------------------------------------------------------------- #
 
-# A product's leaf is drawn in blocks of at most this many elements, so that
-# the f32 draw of a [261120, 5120] table is never whole in memory.
-_DRAW_BLOCK = 1 << 26
-
-
-def _normal(key, shape, dtype, std: float = 0.02):
-    rows = shape[0]
-    blocks = max(1, -(-math.prod(shape) // _DRAW_BLOCK))
-    while rows % blocks:
-        blocks += 1
-    block = (rows // blocks,) + tuple(shape[1:])
-
-    def draw(i):
-        return (jax.random.normal(jax.random.fold_in(key, i), block,
-                                  jnp.float32) * std).astype(dtype)
-
-    return jax.lax.map(draw, jnp.arange(blocks)).reshape(shape)
-
-
 def init_params(cfg: FalconH1Config, key) -> Dict[str, Any]:
     """Seeded parameters: products normal(std 0.02) in `cfg.dtype`, norms
     one, the convolution's weight normal(std 0.02) and its bias zero, and
@@ -211,25 +196,13 @@ def init_params(cfg: FalconH1Config, key) -> Dict[str, Any]:
     return params
 
 
-class _RowsOfTransposed:
-    """`a.T` read in row blocks (`t[r0:r1]`), never whole: the head is
-    2.7 GB and its transpose would be a second copy."""
-
-    def __init__(self, a):
-        self._a = a
-        self.shape = a.shape[::-1]
-
-    def __getitem__(self, rows: slice):
-        return self._a[:, rows].T
-
-
 def published_weights(params) -> Tuple[Dict[str, Any], Any]:
     """(the top-level tensors, a function layer index -> that layer's
     tensors) under the published names and layouts: products [out, in],
     `conv1d.weight` [channels, 1, width]."""
     top = {"model.embed_tokens.weight": params["embed"],
            "model.final_layernorm.weight": params["final_norm"],
-           "lm_head.weight": _RowsOfTransposed(params["lm_head"])}
+           "lm_head.weight": RowsOfTransposed(params["lm_head"])}
     names = {"wq": "self_attn.q_proj", "wk": "self_attn.k_proj",
              "wv": "self_attn.v_proj", "wo": "self_attn.o_proj",
              "in_proj": "mamba.in_proj", "out_proj": "mamba.out_proj",
@@ -256,17 +229,6 @@ def published_weights(params) -> Tuple[Dict[str, Any], Any]:
 # --------------------------------------------------------------------------- #
 # The block
 # --------------------------------------------------------------------------- #
-
-
-def _rms_norm(x, weight, eps: float, groups: int = 1):
-    """RMSNorm in f32 over the last dim, or over each of `groups` equal
-    parts of it; returns f32."""
-    xf = x.astype(jnp.float32)
-    shape = xf.shape
-    xg = xf.reshape(shape[:-1] + (groups, shape[-1] // groups))
-    xg = xg * jax.lax.rsqrt(jnp.mean(jnp.square(xg), axis=-1, keepdims=True)
-                            + eps)
-    return xg.reshape(shape) * weight.astype(jnp.float32)
 
 
 def _attention(cfg, lp, u, kv, block_tables, positions, write_mask):
@@ -328,7 +290,7 @@ def _mixer(cfg, lp, u, state, tail, slots, fresh, live):
                 y, state = ssd.ssd_chunk_fwd(x, dt, a, bm, cm, lp["D"], state,
                                              slots, fresh, live)
         y = y.reshape(b, s, d_ssm) * jax.nn.silu(z)
-        y = _rms_norm(y, lp["ssm_norm"], cfg.rms_norm_eps, groups=g)
+        y = rms_norm(y, lp["ssm_norm"], cfg.rms_norm_eps, groups=g)
         return y.astype(cfg.dtype) @ lp["out_proj"], state, tail
 
 
@@ -340,24 +302,24 @@ def _block(cfg, lp, x, cache, block_tables, positions, write_mask, slots,
     def scaled(t, m):
         return t if m == 1 else t * jnp.asarray(m, dt)
 
-    h = _rms_norm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dt)
+    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dt)
     attn, kv = _attention(cfg, lp, scaled(h, cfg.attention_in_multiplier),
                           kv, block_tables, positions, write_mask)
     mix, state, tail = _mixer(cfg, lp, scaled(h, cfg.ssm_in_multiplier),
                               state, tail, slots, fresh, live)
     x = x + scaled(attn, cfg.attention_out_multiplier) \
         + scaled(mix, cfg.ssm_out_multiplier)
-    h2 = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(dt)
+    h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(dt)
     gate = jax.nn.silu(scaled(h2 @ lp["w_gate"], cfg.mlp_multipliers[0]))
     x = x + scaled(((h2 @ lp["w_up"]) * gate) @ lp["w_down"],
                    cfg.mlp_multipliers[1])
     return x, (kv, state, tail)
 
 
-class FalconH1:
-    """The model the engine is handed: its configuration and the five
-    answers of the model contract. Parameters are a plain pytree
-    (`init_params`)."""
+class FalconH1(PagedModel):
+    """The model the engine is handed: its configuration and what of the
+    model contract differs from `PagedModel`'s defaults. Parameters are a
+    plain pytree (`init_params`)."""
 
     # A prefix of KV blocks alone does not restore a sequence: the
     # recurrent state at its end is not in them.
@@ -431,7 +393,7 @@ class FalconH1:
             tails.append(tail)
         if last_idx is not None:
             x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-        x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.dot(x.astype(cfg.dtype), params["lm_head"],
                          preferred_element_type=jnp.float32) \
             * cfg.lm_head_multiplier
@@ -443,31 +405,3 @@ class FalconH1:
         no live position keeps its state whatever its position says (the
         engine hands idle rows position 0)."""
         return write_mask[:, 0] & (row_pos == 0), write_mask
-
-    def forward(self, params, ids, block_size: int = 16):
-        """Logits [b, s, vocab] of whole sequences from position 0: one
-        `paged_step` over a cache of its own (tests, offline scoring)."""
-        b, s = ids.shape
-        per_row = -(-s // block_size)
-        cache = self.paged_cache(1 + b * per_row, block_size, None, b)
-        tables = 1 + jnp.arange(b * per_row, dtype=jnp.int32).reshape(
-            b, per_row)
-        logits, _ = self.paged_step(
-            params, ids, cache, tables, jnp.zeros((b,), jnp.int32),
-            jnp.ones((b, s), bool), None, jnp.arange(b, dtype=jnp.int32))
-        return logits
-
-    def place_on_mesh(self, params, mesh):
-        """tp = 1 only: the recurrent state and its kernels are not
-        sharded."""
-        axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        if int(axes.get("tp", 1)) != 1:
-            raise ValueError("FalconH1 serves at tp = 1 only")
-        return params, 1
-
-    def early_exit_draft(self, params):
-        raise ValueError("FalconH1 has no draft: speculation needs a "
-                         "rollback of per-slot state")
-
-    def adapter_banks(self, n_rows: int, rank: int, mesh=None):
-        raise ValueError("FalconH1 has no adapter banks")

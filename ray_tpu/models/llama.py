@@ -1,4 +1,5 @@
-"""Llama-family decoder in flax, sharding-annotated, with KV-cache decode.
+"""Llama-family decoder in flax, sharding-annotated, with paged KV-cache
+decode.
 
 Second model family (BASELINE.json names a Llama Serve deployment next to
 the GPT-2 trainer): RMSNorm, rotary position embeddings, SwiGLU MLP,
@@ -6,22 +7,20 @@ grouped-query attention, untied LM head — the same logical-axis annotations
 as `gpt2.py` (tp shards heads/mlp, dp/fsdp shard batch, sp shards seq), so
 `make_train_step`/`mesh_shardings_for` work unchanged.
 
-Three forward paths share parameters:
+Two forward paths share parameters:
 - `__call__(input_ids)` — full-sequence training forward (flash attention).
-- `decode(input_ids, cache, pos)` — incremental inference against a
-  preallocated KV cache: prefill writes the prompt's K/V once, each decode
-  step attends a 1-token query over the cache (O(context) memory reads
-  instead of an O(context^2) recompute per token).
-- `decode_paged(input_ids, arenas, block_tables, pos, write_mask)` — the
-  same incremental math against a PAGED cache (vLLM/PagedAttention shape):
-  K/V live in a shared fixed-size block arena; each row's block table maps
-  logical blocks to physical ones, so the continuous-batching engine
-  (`ray_tpu/inference/`) can admit/evict/preempt sequences without ever
-  reshaping the cache — one compiled program per (batch, step-width)
-  shape, forever. Writes scatter into the arena in place; reads go
-  through `ops/paged_attention.py`, whose Pallas kernel walks each row's
-  block table and copies only the live blocks (GQA inside, nothing
-  repeated or upcast in HBM).
+- `decode_paged(input_ids, arenas, block_tables, pos, write_mask)` —
+  incremental inference against a PAGED cache (vLLM/PagedAttention shape):
+  prefill writes the prompt's K/V once, each decode step attends a 1-token
+  query over the cache (O(context) memory reads instead of an O(context^2)
+  recompute per token). K/V live in a shared fixed-size block arena; each
+  row's block table maps logical blocks to physical ones, so the
+  continuous-batching engine (`ray_tpu/inference/`) can admit/evict/preempt
+  sequences without ever reshaping the cache — one compiled program per
+  (batch, step-width) shape, forever. Writes scatter into the arena in
+  place; reads go through `ops/paged_attention.py`, whose Pallas kernel
+  walks each row's block table and copies only the live blocks (GQA inside,
+  nothing repeated or upcast in HBM). It is the one cache the model has.
 """
 
 from __future__ import annotations
@@ -33,8 +32,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models._nn import (RMSNorm, apply_rope, dense,
+                                paged_write_and_attend)
+from ray_tpu.models._served import PagedModel
 from ray_tpu.ops.attention import flash_attention_sharded, mha_reference
-from ray_tpu.ops.paged_attention import paged_attention
 
 
 @dataclass(frozen=True)
@@ -80,8 +81,8 @@ class LlamaConfig:
 
 
 def llama_preset(name: str, seq: int = 256) -> LlamaConfig:
-    """The preset a deployment names (`LLMServer`, `LlamaSampler`):
-    tiny (at `seq` positions), small or 7b."""
+    """The preset a deployment names (`LLMServer`): tiny (at `seq`
+    positions), small or 7b."""
     presets = {"tiny": lambda: LlamaConfig.tiny(seq=seq),
                "small": LlamaConfig.small, "7b": LlamaConfig.llama7b}
     if name not in presets:
@@ -90,104 +91,16 @@ def llama_preset(name: str, seq: int = 256) -> LlamaConfig:
     return presets[name]()
 
 
-def _dense(features: int, axes: Tuple[str, ...], cfg: LlamaConfig, name: str):
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype,
-                    kernel_init=nn.with_logical_partitioning(
-                        nn.initializers.normal(0.02), axes),
-                    name=name)
-
-
-class RMSNorm(nn.Module):
-    cfg: LlamaConfig
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale",
-                           nn.with_logical_partitioning(
-                               nn.initializers.ones, ("embed",)),
-                           (x.shape[-1],), self.cfg.param_dtype)
-        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
-                       keepdims=True)
-        out = x.astype(jnp.float32) * jax.lax.rsqrt(var + self.cfg.rms_eps)
-        return (out * scale).astype(self.cfg.dtype)
-
-
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """Rotary embedding on [b, heads, s, d] with per-token positions [b, s]
-    (or [s]); rotates feature pairs (even, odd) halves-style."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    if positions.ndim == 1:
-        positions = positions[None, :]
-    ang = positions[:, None, :, None].astype(jnp.float32) * freqs  # [b,1,s,h]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
-
-
-def paged_write_and_attend(q, k, v, k_arena, v_arena, block_tables,
-                           positions, write_mask, sees=None):
-    """Scatter this call's K/V ([b, kv_heads, s, d], after RoPE) into the
-    paged arenas and attend q [b, heads, s, d] over them. Returns (attn
-    [b, heads, s, d], k_arena, v_arena). `sees` [b, s] is the last logical
-    position each query attends to, where that is not its own (a model
-    whose mask is not causal token by token: `models/sdar.py`); the scatter
-    goes by `positions` either way."""
-    hd = q.shape[-1]
-    # Named for the profiler: device ops of the paged path carry
-    # `paged_attn` in their op_name (PERF.md, Open questions).
-    with jax.named_scope("paged_attn"):
-        nb, bsz, kvh, _ = k_arena.shape
-        max_blocks = block_tables.shape[1]
-        # Scatter this call's K/V into the arena. Physical slot
-        # of logical position p in row i: block_tables[i, p // bsz]
-        # * bsz + p % bsz. Masked tokens (batch padding, chunk
-        # padding) are pointed at physical block 0 — reserved as a
-        # trash block the manager never allocates — so one
-        # fixed-shape scatter handles every mix of active/idle
-        # slots without recompiling.
-        kw = k.transpose(0, 2, 1, 3).astype(
-            k_arena.dtype)                        # [b,s,kvh,d]
-        vw = v.transpose(0, 2, 1, 3).astype(v_arena.dtype)
-        blk = jnp.clip(positions // bsz, 0, max_blocks - 1)
-        phys = jnp.take_along_axis(block_tables, blk,
-                                   axis=1)        # [b, s]
-        phys = jnp.where(write_mask, phys, 0)
-        flat = (phys * bsz + positions % bsz).reshape(-1)
-        k_flat = k_arena.reshape(nb * bsz, kvh, hd)
-        v_flat = v_arena.reshape(nb * bsz, kvh, hd)
-        k_flat = k_flat.at[flat].set(kw.reshape(-1, kvh, hd))
-        v_flat = v_flat.at[flat].set(vw.reshape(-1, kvh, hd))
-        k_arena = k_flat.reshape(nb, bsz, kvh, hd)
-        v_arena = v_flat.reshape(nb, bsz, kvh, hd)
-        # Read: each row's live blocks straight out of the arena
-        # (ops/paged_attention.py: the Pallas kernel where the
-        # dispatch rule gives it the call, the dense reference
-        # elsewhere). The scatter above comes first, so the call's
-        # own K/V are in the arena it reads.
-        attn = paged_attention(
-            q.transpose(0, 2, 1, 3), k_arena, v_arena, block_tables,
-            positions if sees is None else sees,
-            write_mask).transpose(0, 2, 1, 3)
-    return attn, k_arena, v_arena
-
-
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Tuple] = None,
                  lora: Optional[Tuple] = None):
-        """cache=None: full causal forward. cache=(k, v) with layout
-        [b, max_len, kv_heads, head_dim]: write this call's K/V at each
-        row's `positions` and attend over the cache; returns (x, cache').
+        """cache=None: full causal forward; returns (x, None, side).
         cache=(k_arena, v_arena, block_tables, write_mask) with arenas
-        [num_blocks, block_size, kv_heads, head_dim]: paged variant —
-        writes land at the physical slot the row's block table maps each
+        [num_blocks, block_size, kv_heads, head_dim]: this call's K/V
+        land at the physical slot the row's block table maps each
         position to (masked-off tokens go to trash block 0), reads are
         `paged_attention` over the arena as the writes left it: each row
         sees its logical positions <= the query's, out of the blocks its
@@ -215,17 +128,17 @@ class LlamaBlock(nn.Module):
         hd = cfg.head_dim
         b, s, _ = x.shape
         h = RMSNorm(cfg, name="attn_norm")(x)
-        q = _dense(cfg.n_head * hd, ("embed", "heads"), cfg, "wq")(h)
-        k = _dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wk")(h)
-        v = _dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wv")(h)
+        q = dense(cfg.n_head * hd, ("embed", "heads"), cfg, "wq")(h)
+        k = dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wk")(h)
+        v = dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wv")(h)
         q = q.reshape(b, s, cfg.n_head, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, s, cfg.n_kv_head, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, s, cfg.n_kv_head, hd).transpose(0, 2, 1, 3)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-        groups = cfg.n_head // cfg.n_kv_head
         if cache is None:
+            groups = cfg.n_head // cfg.n_kv_head
             kf = jnp.repeat(k, groups, axis=1)
             vf = jnp.repeat(v, groups, axis=1)
             if cfg.sp_mesh is not None:
@@ -243,39 +156,14 @@ class LlamaBlock(nn.Module):
             else:
                 attn = mha_reference(q, kf, vf, causal=True)
             new_cache = None
-        elif len(cache) == 4:
+        else:
             k_arena, v_arena, block_tables, write_mask = cache
             attn, k_arena, v_arena = paged_write_and_attend(
                 q, k, v, k_arena, v_arena, block_tables, positions,
                 write_mask)
             new_cache = (k_arena, v_arena, block_tables, write_mask)
-        else:
-            k_cache, v_cache = cache                 # [b, max, kvh, d]
-            max_len = k_cache.shape[1]
-            rows = jnp.arange(b)[:, None]            # [b, 1]
-            # positions is [b, s]: per-row write offsets (rows of a batch
-            # may be at different lengths).
-            k_cache = k_cache.at[rows, positions].set(
-                k.transpose(0, 2, 1, 3).astype(k_cache.dtype))
-            v_cache = v_cache.at[rows, positions].set(
-                v.transpose(0, 2, 1, 3).astype(v_cache.dtype))
-            kf = jnp.repeat(k_cache, groups, axis=2)  # [b, max, h, d]
-            vf = jnp.repeat(v_cache, groups, axis=2)
-            # Causal over absolute positions, per row: query at absolute
-            # position p sees cache slots <= p; unwritten/pad slots are
-            # beyond every query's position and masked out.
-            kv_pos = jnp.arange(max_len)
-            mask = kv_pos[None, None, :] <= positions[:, :, None]  # [b,s,max]
-            scores = jnp.einsum("bhqd,bkhd->bhqk",
-                                q.astype(jnp.float32),
-                                kf.astype(jnp.float32)) / (hd ** 0.5)
-            scores = jnp.where(mask[:, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("bhqk,bkhd->bhqd", probs,
-                              vf.astype(jnp.float32)).astype(cfg.dtype)
-            new_cache = (k_cache, v_cache)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_head * hd)
-        out = _dense(cfg.n_embd, ("heads", "embed"), cfg, "wo")(attn)
+        out = dense(cfg.n_embd, ("heads", "embed"), cfg, "wo")(attn)
         side = None
         if lora is not None:
             aq, bq, ao, bo, aidx = lora
@@ -295,15 +183,15 @@ class LlamaBlock(nn.Module):
         x = x + out
 
         h2 = RMSNorm(cfg, name="mlp_norm")(x)
-        gate = _dense(cfg.intermediate, ("embed", "mlp"), cfg, "w_gate")(h2)
-        up = _dense(cfg.intermediate, ("embed", "mlp"), cfg, "w_up")(h2)
+        gate = dense(cfg.intermediate, ("embed", "mlp"), cfg, "w_gate")(h2)
+        up = dense(cfg.intermediate, ("embed", "mlp"), cfg, "w_up")(h2)
         h2 = nn.silu(gate) * up
-        x = x + _dense(cfg.n_embd, ("mlp", "embed"), cfg, "w_down")(h2)
+        x = x + dense(cfg.n_embd, ("mlp", "embed"), cfg, "w_down")(h2)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed")), \
             new_cache, side
 
 
-class Llama(nn.Module):
+class Llama(nn.Module, PagedModel):
     config: LlamaConfig
 
     def setup(self):
@@ -319,8 +207,8 @@ class Llama(nn.Module):
         self.blocks = [block(cfg, name=f"layer_{i}")
                        for i in range(cfg.n_layer)]
         self.final_norm = RMSNorm(cfg, name="final_norm")
-        self.lm_head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg,
-                              "lm_head")
+        self.lm_head = dense(cfg.vocab_size, ("embed", "vocab"), cfg,
+                             "lm_head")
 
     def __call__(self, input_ids):
         cfg = self.config
@@ -333,21 +221,6 @@ class Llama(nn.Module):
         x = self.final_norm(x)
         logits = self.lm_head(x)
         return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
-
-    def decode(self, input_ids, cache, row_pos):
-        """Incremental forward: each row writes K/V at its own offset
-        (`row_pos` [b]) and gets logits for its s tokens. One jitted
-        program serves both multi-token prefill and 1-token decode."""
-        cfg = self.config
-        b, s = input_ids.shape
-        x = self.embed.astype(cfg.dtype)[input_ids]
-        positions = row_pos[:, None] + jnp.arange(s)[None, :]  # [b, s]
-        new_cache = []
-        for i, blk in enumerate(self.blocks):
-            x, layer_cache, _ = blk(x, positions, cache=cache[i])
-            new_cache.append(layer_cache)
-        x = self.final_norm(x)
-        return self.lm_head(x), new_cache
 
     def decode_paged(self, input_ids, arenas, block_tables, row_pos,
                      write_mask, lora_banks=None, adapter_idx=None,
@@ -403,15 +276,11 @@ class Llama(nn.Module):
         return self.lm_head(x), new_arenas
 
     # What the serving engine asks of the model it is handed
-    # (docs/INFERENCE.md, "The model contract"): all of it, and each a
-    # call into the functions of this file. `nowrap`: they run on the
-    # unbound module, outside `apply`.
-
-    # A prefix of KV blocks alone restores a sequence (so the radix
-    # prefix cache and speculation's no-rollback hold), and a slot holds
-    # nothing of its own.
-    prefix_restores = True
-    slot_state_bytes = 0
+    # (`PagedModel`): what differs from its defaults, each a call into the
+    # functions of this file. A prefix of KV blocks alone restores a
+    # sequence and a slot holds nothing of its own, as the base says.
+    # `nowrap`: they run on the unbound module, outside `apply`; the
+    # mixin's own methods are not the module's, so flax leaves them alone.
 
     @nn.nowrap
     def paged_cache(self, num_blocks: int, block_size: int, mesh=None,
@@ -509,8 +378,8 @@ class LlamaStage(nn.Module):
                        for i in range(start, end)]
         if self.stage == self.pp - 1:
             self.final_norm = RMSNorm(cfg, name="final_norm")
-            self.lm_head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg,
-                                  "lm_head")
+            self.lm_head = dense(cfg.vocab_size, ("embed", "vocab"), cfg,
+                                 "lm_head")
 
     def __call__(self, x):
         """Stage 0 takes token ids [b, s]; later stages take the
@@ -734,14 +603,6 @@ def arena_sharding(cfg: LlamaConfig, mesh):
 def _mesh_tp(mesh) -> int:
     axes = dict(zip(mesh.axis_names, mesh.devices.shape))
     return int(axes.get("tp", 1))
-
-
-def make_cache(cfg: LlamaConfig, batch: int, max_len: int):
-    """Preallocated per-layer (k, v) cache [b, max_len, kv_heads, head_dim]
-    (length-major so per-row writes are a single advanced-index set)."""
-    shape = (batch, max_len, cfg.n_kv_head, cfg.head_dim)
-    return [(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
-            for _ in range(cfg.n_layer)]
 
 
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:

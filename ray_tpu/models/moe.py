@@ -18,8 +18,8 @@ this is net-new capability designed for the TPU from the start:
   auxiliary loss sown into a "losses" collection and added to the training
   objective by `make_moe_train_step`.
 
-Attention/norm/embedding reuse the Llama components so tp/sp/fsdp behave
-exactly as in the dense families.
+Attention/norm/embedding reuse the flax family's components
+(`models/_nn.py`) so tp/sp/fsdp behave exactly as in the dense families.
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import RMSNorm, apply_rope, _dense
+from ray_tpu.models._nn import RMSNorm, apply_rope, dense
 from ray_tpu.models.gpt2 import next_token_loss
 
 
@@ -173,9 +173,9 @@ class MoEBlock(nn.Module):
         hd = cfg.head_dim
         b, s, _ = x.shape
         h = RMSNorm(cfg, name="attn_norm")(x)
-        q = _dense(cfg.n_head * hd, ("embed", "heads"), cfg, "wq")(h)
-        k = _dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wk")(h)
-        v = _dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wv")(h)
+        q = dense(cfg.n_head * hd, ("embed", "heads"), cfg, "wq")(h)
+        k = dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wk")(h)
+        v = dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wv")(h)
         q = q.reshape(b, s, cfg.n_head, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, s, cfg.n_kv_head, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, s, cfg.n_kv_head, hd).transpose(0, 2, 1, 3)
@@ -195,7 +195,7 @@ class MoEBlock(nn.Module):
 
             attn = mha_reference(q, kf, vf, causal=True)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_head * hd)
-        x = x + _dense(cfg.n_embd, ("heads", "embed"), cfg, "wo")(attn)
+        x = x + dense(cfg.n_embd, ("heads", "embed"), cfg, "wo")(attn)
         h2 = RMSNorm(cfg, name="mlp_norm")(x)
         x = x + MoEMLP(cfg, name="moe")(h2)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
@@ -217,8 +217,8 @@ class MoE(nn.Module):
         self.blocks = [block(cfg, name=f"layer_{i}")
                        for i in range(cfg.n_layer)]
         self.final_norm = RMSNorm(cfg, name="final_norm")
-        self.lm_head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg,
-                              "lm_head")
+        self.lm_head = dense(cfg.vocab_size, ("embed", "vocab"), cfg,
+                             "lm_head")
 
     def __call__(self, input_ids):
         cfg = self.config

@@ -83,8 +83,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.falcon_h1 import _normal, _rms_norm, _RowsOfTransposed
-from ray_tpu.models.llama import paged_write_and_attend
+# ouro_controls.py replaces _rms_norm, paged_write_and_attend, _pass_tables.
+from ray_tpu.models._nn import (RowsOfTransposed, normal,
+                                paged_write_and_attend, product,
+                                rms_norm as _rms_norm, rotary_tables, rotate)
+from ray_tpu.models._served import PagedModel
 
 
 @dataclass(frozen=True)
@@ -157,7 +160,7 @@ def init_params(cfg: OuroConfig, key) -> Dict[str, Any]:
     Each tensor is made on the device by one jitted draw."""
     e, dt = cfg.hidden_size, cfg.dtype
     qd = cfg.num_attention_heads * cfg.head_dim
-    draw = jax.jit(_normal, static_argnums=(1, 2, 3))
+    draw = jax.jit(normal, static_argnums=(1, 2, 3))
     keys = iter(jax.random.split(key, 3 + 4 * cfg.num_hidden_layers))
     params = {"embed": draw(next(keys), (cfg.vocab_size, e), dt),
               "lm_head": draw(next(keys), (e, cfg.vocab_size), dt),
@@ -184,7 +187,7 @@ def published_weights(params) -> Tuple[Dict[str, Any], Any]:
            "model.norm.weight": params["final_norm"],
            "model.early_exit_gate.weight": params["exit_gate"]["w"][None, :],
            "model.early_exit_gate.bias": params["exit_gate"]["b"][None],
-           "lm_head.weight": _RowsOfTransposed(params["lm_head"])}
+           "lm_head.weight": RowsOfTransposed(params["lm_head"])}
     def layer(i: int) -> Dict[str, Any]:
         lp = params["layers"][i]
         q, k, v = jnp.split(lp["wqkv"], 3, axis=1)
@@ -205,32 +208,10 @@ def published_weights(params) -> Tuple[Dict[str, Any], Any]:
 # --------------------------------------------------------------------------- #
 
 
-def _rotary_tables(positions, head_dim: int, theta: float):
-    """(cos, sin) [b, 1, s, d/2] in f32 at positions [b, s]: the same in
-    every pass and every layer, so made once a step."""
-    half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = positions[:, None, :, None].astype(jnp.float32) * freqs
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def _rotate(x, cos, sin):
-    """Rotate-half rotary on x [b, heads, s, d], in f32, back in x's type."""
-    half = x.shape[-1] // 2
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                           axis=-1).astype(x.dtype)
-
-
 def _pass_tables(block_tables, u, num_blocks: int):
     """The block tables of pass `u`: logical block b's pages of that pass
     lie `u x num_blocks` blocks up the arena."""
     return block_tables + u * num_blocks
-
-
-def _product(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
 def _layer(cfg: OuroConfig, lp, x, k_arena, v_arena, tables, positions,
@@ -243,19 +224,19 @@ def _layer(cfg: OuroConfig, lp, x, k_arena, v_arena, tables, positions,
     with jax.named_scope("ouro_attn_proj"):
         n = _rms_norm(x, lp["input_norm"], eps).astype(dt)
         q, k, v = (t.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-                   for t in jnp.split(_product(n, lp["wqkv"]).astype(dt), 3,
+                   for t in jnp.split(product(n, lp["wqkv"]).astype(dt), 3,
                                       axis=-1))
-        q, k = _rotate(q, *rotary), _rotate(k, *rotary)
+        q, k = rotate(q, *rotary), rotate(k, *rotary)
     attn, k_arena, v_arena = paged_write_and_attend(
         q, k, v, k_arena, v_arena, tables, positions, write_mask)
     with jax.named_scope("ouro_attn_proj"):
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, heads * hd)
-        a = _product(attn, lp["wo"])
+        a = product(attn, lp["wo"])
         x = (x + _rms_norm(a, lp["attn_post_norm"], eps)).astype(stream)
     with jax.named_scope("ouro_mlp"):
         n = _rms_norm(x, lp["mlp_norm"], eps).astype(dt)
-        gate, up = jnp.split(_product(n, lp["w_gate_up"]), 2, axis=-1)
-        m = _product((jax.nn.silu(gate) * up).astype(dt), lp["w_down"])
+        gate, up = jnp.split(product(n, lp["w_gate_up"]), 2, axis=-1)
+        m = product((jax.nn.silu(gate) * up).astype(dt), lp["w_down"])
         x = (x + _rms_norm(m, lp["mlp_post_norm"], eps)).astype(stream)
     return x, k_arena, v_arena
 
@@ -281,14 +262,11 @@ def exit_pass(p_exit, threshold: float):
                      jnp.argmax(reached, axis=-1) + 1, last)
 
 
-class Ouro:
-    """The model the engine is handed: its configuration and the answers
-    of the model contract. Parameters are a plain pytree (`init_params`)."""
-
-    # A prefix of blocks alone restores a sequence (a block brings every
-    # pass's pages); no slot state.
-    prefix_restores = True
-    slot_state_bytes = 0
+class Ouro(PagedModel):
+    """The model the engine is handed: its configuration and what of the
+    model contract differs from `PagedModel`'s defaults (a prefix of blocks
+    alone restores a sequence, since a block brings every pass's pages; no
+    slot state). Parameters are a plain pytree (`init_params`)."""
 
     def __init__(self, config: OuroConfig):
         self.config = config
@@ -324,8 +302,8 @@ class Ouro:
         if last_idx is not None:
             x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
         with jax.named_scope("lm_head"):
-            return _product(x.astype(self.config.dtype),
-                            params["lm_head"]), cache
+            return product(x.astype(self.config.dtype),
+                           params["lm_head"]), cache
 
     def _passes(self, params, ids, cache, block_tables, row_pos, write_mask,
                 with_gates: bool):
@@ -333,7 +311,7 @@ class Ouro:
         lambda [U, b, s] or None)."""
         cfg = self.config
         positions = row_pos[:, None] + jnp.arange(ids.shape[1])[None, :]
-        rotary = _rotary_tables(positions, cfg.head_dim, cfg.rope_theta)
+        rotary = rotary_tables(positions, cfg.head_dim, cfg.rope_theta)
         num_blocks = cache["kv"][0][0].shape[0] // cfg.total_ut_steps
         live = jnp.sum(write_mask, dtype=jnp.int32)
 
@@ -352,7 +330,7 @@ class Ouro:
             gate = None
             if with_gates:
                 gate = jax.nn.sigmoid(
-                    _product(x.astype(cfg.dtype), params["exit_gate"]["w"])
+                    product(x.astype(cfg.dtype), params["exit_gate"]["w"])
                     + params["exit_gate"]["b"].astype(jnp.float32))
             return (x, out, done + live), gate
 
@@ -407,15 +385,8 @@ class Ouro:
             params, ids, self.paged_cache(1 + b * per_row, 16), tables,
             jnp.zeros((b,), jnp.int32), jnp.ones((b, s), bool),
             with_gates=True)
-        return _product(x.astype(self.config.dtype), params["lm_head"]), \
+        return product(x.astype(self.config.dtype), params["lm_head"]), \
             exit_distribution(jnp.moveaxis(gates, 0, -1))
-
-    def place_on_mesh(self, params, mesh):
-        """tp = 1 only."""
-        axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        if int(axes.get("tp", 1)) != 1:
-            raise ValueError("Ouro serves at tp = 1 only")
-        return params, 1
 
     def early_exit_draft(self, params):
         """(draft model, its params) for speculation when none was
@@ -424,6 +395,3 @@ class Ouro:
         cfg = self.config
         return Ouro(replace(cfg, total_ut_steps=max(
             1, cfg.total_ut_steps // 2))), params
-
-    def adapter_banks(self, n_rows: int, rank: int, mesh=None):
-        raise ValueError("Ouro has no adapter banks")

@@ -18,12 +18,13 @@ top-k, and the layer adds up only what its own experts give
 (`ops/held_experts.py`). With `held_experts = (0, num_experts)` this is the
 whole model.
 
-Shared with `models/llama.py`: `_dense`. Not shared, because the equations
-differ: the norms here are zero-centred (`x / rms * (1 + w)`, w from zeros;
-llama's `RMSNorm` multiplies by a w from ones), the rotary covers the first
-quarter of a head and runs on [batch, seq, heads, d] (llama's `apply_rope`
-rotates whole heads on [batch, heads, seq, d]: two transposes of whole
-activations to borrow ten lines).
+Shared with the flax family (`models/_nn.py`): `dense`. Not shared, because
+the equations differ: the norms here are zero-centred (`x / rms * (1 + w)`,
+w from zeros; `_nn.RMSNorm` multiplies by a w from ones and `_nn.rms_norm`
+reshapes into groups), the rotary covers the first quarter of a head and
+runs on [batch, seq, heads, d] (`_nn.apply_rope` rotates whole heads on
+[batch, heads, seq, d]: two transposes of whole activations to borrow ten
+lines).
 
 Weight layout against the published one (`published_weights` maps ours to
 theirs; the plain reference keeps theirs): the linear layer's `in_proj_qkvz`
@@ -48,8 +49,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models._nn import dense
 from ray_tpu.models.gpt2 import next_token_loss
-from ray_tpu.models.llama import _dense
 from ray_tpu.ops.attention import flash_attention_bse
 from ray_tpu.ops.gated_delta import (gated_delta_rule, gdn_gate,
                                      gdn_prep)
@@ -167,9 +168,9 @@ class GatedDeltaNet(nn.Module):
         hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
         dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
         key_w, val_w = hk * dk, hv * dv
-        qkvz = _dense(2 * key_w + 2 * val_w, ("embed", "mlp"), cfg,
-                      "in_proj_qkvz")(x)
-        ba = _dense(2 * hv, ("embed", "heads"), cfg, "in_proj_ba")(x)
+        qkvz = dense(2 * key_w + 2 * val_w, ("embed", "mlp"), cfg,
+                     "in_proj_qkvz")(x)
+        ba = dense(2 * hv, ("embed", "heads"), cfg, "in_proj_ba")(x)
         conv_w = self.param(
             "conv1d", lambda k, shape: jax.random.uniform(
                 k, shape, jnp.float32, -0.5, 0.5),
@@ -190,7 +191,7 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gdn_gate"):
             o = gdn_gate(o.reshape(b, s, val_w), z_in, norm_w,
                          cfg.rms_norm_eps)
-        return _dense(cfg.hidden_size, ("mlp", "embed"), cfg, "out_proj")(o)
+        return dense(cfg.hidden_size, ("mlp", "embed"), cfg, "out_proj")(o)
 
 
 class GatedAttention(nn.Module):
@@ -203,9 +204,9 @@ class GatedAttention(nn.Module):
         h, kv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                     cfg.head_dim)
         rotary = int(d * cfg.partial_rotary_factor)
-        qg = _dense(2 * h * d, ("embed", "heads"), cfg, "q_proj")(x)
-        k = _dense(kv * d, ("embed", "kv"), cfg, "k_proj")(x)
-        v = _dense(kv * d, ("embed", "kv"), cfg, "v_proj")(x)
+        qg = dense(2 * h * d, ("embed", "heads"), cfg, "q_proj")(x)
+        k = dense(kv * d, ("embed", "kv"), cfg, "k_proj")(x)
+        v = dense(kv * d, ("embed", "kv"), cfg, "v_proj")(x)
         q, gate = qg[..., :h * d], qg[..., h * d:]
         q = ZeroCentredNorm(cfg.rms_norm_eps, name="q_norm")(
             q.reshape(b, s, h, d))
@@ -221,7 +222,7 @@ class GatedAttention(nn.Module):
             causal=True)
         gated = attn.astype(jnp.float32) * jax.nn.sigmoid(
             gate.astype(jnp.float32))
-        return _dense(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(
+        return dense(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(
             gated.astype(cfg.dtype))
 
 
@@ -253,11 +254,11 @@ class SparseMoe(nn.Module):
                 x.reshape(b * s, d), gates, index, w_gate_up, w_down,
                 cfg.held_experts, cfg.num_experts)
         sw = cfg.shared_expert_intermediate_size
-        shared = _dense(d, ("mlp", "embed"), cfg, "shared_down")(
-            jax.nn.silu(_dense(sw, ("embed", "mlp"), cfg, "shared_gate")(x))
-            * _dense(sw, ("embed", "mlp"), cfg, "shared_up")(x))
+        shared = dense(d, ("mlp", "embed"), cfg, "shared_down")(
+            jax.nn.silu(dense(sw, ("embed", "mlp"), cfg, "shared_gate")(x))
+            * dense(sw, ("embed", "mlp"), cfg, "shared_up")(x))
         share = jax.nn.sigmoid(
-            _dense(1, ("embed", None), cfg, "shared_expert_gate")(x).astype(
+            dense(1, ("embed", None), cfg, "shared_expert_gate")(x).astype(
                 jnp.float32))
         out = y.reshape(b, s, d) + share * shared.astype(jnp.float32)
         aux = {"load_balance": load_balance_loss(probs, index,
@@ -304,8 +305,8 @@ class Qwen3Next(nn.Module):
             auxes.append(aux)
         x = ZeroCentredNorm(cfg.rms_norm_eps, name="norm")(x)
         with jax.named_scope("lm_head"):
-            logits = _dense(cfg.vocab_size, ("embed", "vocab"), cfg,
-                            "lm_head")(x.astype(cfg.dtype))
+            logits = dense(cfg.vocab_size, ("embed", "vocab"), cfg,
+                           "lm_head")(x.astype(cfg.dtype))
         if not return_aux:
             return logits
         return logits, jax.tree.map(lambda *a: jnp.stack(a), *auxes)

@@ -82,10 +82,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.deepseek_v3 import cache_locations
-from ray_tpu.models.falcon_h1 import _normal, _rms_norm, _RowsOfTransposed
-from ray_tpu.models.llama import paged_write_and_attend
-from ray_tpu.models.ouro import _product, _rotary_tables, _rotate
+# sdar_controls.py replaces _rms_norm, paged_write_and_attend, moe.route.
+from ray_tpu.models._nn import (RowsOfTransposed, cache_locations, normal,
+                                paged_write_and_attend, product,
+                                rms_norm as _rms_norm, rotary_tables, rotate)
+from ray_tpu.models._served import PagedModel
 from ray_tpu.ops import held_experts as moe
 
 KINDS = ("decode", "prefill")
@@ -225,7 +226,7 @@ def init_params(cfg: SDARConfig, key) -> Dict[str, Any]:
     e, dt, hd = cfg.hidden_size, cfg.dtype, cfg.head_dim
     qd, kvd = cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd
     experts, f = cfg.num_experts, cfg.moe_intermediate_size
-    draw = jax.jit(_normal, static_argnums=(1, 2, 3))
+    draw = jax.jit(normal, static_argnums=(1, 2, 3))
     keys = iter(jax.random.split(key, 2 + 5 * cfg.num_hidden_layers))
     params = {"embed": draw(next(keys), (cfg.vocab_size, e), dt),
               "lm_head": draw(next(keys), (e, cfg.vocab_size), dt),
@@ -252,7 +253,7 @@ def published_weights(cfg: SDARConfig, params) -> Tuple[Dict[str, Any], Any]:
     f = cfg.moe_intermediate_size
     top = {"model.embed_tokens.weight": params["embed"],
            "model.norm.weight": params["final_norm"],
-           "lm_head.weight": _RowsOfTransposed(params["lm_head"])}
+           "lm_head.weight": RowsOfTransposed(params["lm_head"])}
 
     def layer(i: int) -> Dict[str, Any]:
         lp = params["layers"][i]
@@ -315,18 +316,18 @@ def _layer(cfg: SDARConfig, lp, x, k_arena, v_arena, tables, positions, sees,
                       cfg.head_dim)
     with jax.named_scope("sdar_attn_proj"):
         n = _rms_norm(x, lp["input_norm"], eps).astype(dt)
-        q, k, v = jnp.split(_product(n, lp["wqkv"]),
+        q, k, v = jnp.split(product(n, lp["wqkv"]),
                             [heads * hd, (heads + kvh) * hd], axis=-1)
         q = _rms_norm(q.reshape(b, s, heads, hd), lp["q_norm"], eps)
         k = _rms_norm(k.reshape(b, s, kvh, hd), lp["k_norm"], eps)
-        q = _rotate(q.transpose(0, 2, 1, 3), *rotary).astype(dt)
-        k = _rotate(k.transpose(0, 2, 1, 3), *rotary).astype(dt)
+        q = rotate(q.transpose(0, 2, 1, 3), *rotary).astype(dt)
+        k = rotate(k.transpose(0, 2, 1, 3), *rotary).astype(dt)
         v = v.astype(dt).reshape(b, s, kvh, hd).transpose(0, 2, 1, 3)
     attn, k_arena, v_arena = paged_write_and_attend(
         q, k, v, k_arena, v_arena, tables, positions, write_mask, sees)
     with jax.named_scope("sdar_attn_proj"):
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, heads * hd)
-        x = x + _product(attn, lp["wo"])
+        x = x + product(attn, lp["wo"])
     n = _rms_norm(x, lp["mlp_norm"], eps).astype(dt)
     y, counts, routing = routed_experts(cfg, lp, n.reshape(b * s, e),
                                         write_mask.reshape(-1), live_tokens)
@@ -345,16 +346,13 @@ def _count(counters, kind: int, per_layer):
     return {k: v.at[kind].add(add[k]) for k, v in counters.items()}
 
 
-class SDAR:
-    """The model the engine is handed: its configuration and the answers
-    of the model contract. Parameters are a plain pytree (`init_params`)."""
-
-    # A prefix of blocks restores a sequence at any multiple of
-    # `block_length` (keys and values of a position depend on its whole
-    # diffusion block, and the engine holds `block_size % block_length ==
-    # 0`, so every page boundary is one); no slot state.
-    prefix_restores = True
-    slot_state_bytes = 0
+class SDAR(PagedModel):
+    """The model the engine is handed: its configuration and what of the
+    model contract differs from `PagedModel`'s defaults. Parameters are a
+    plain pytree (`init_params`). A prefix of blocks restores a sequence
+    (the default) at any multiple of `block_length`: keys and values of a
+    position depend on its whole diffusion block, and the engine holds
+    `block_size % block_length == 0`, so every page boundary is one."""
 
     def __init__(self, config: SDARConfig):
         self.config = config
@@ -428,7 +426,7 @@ class SDAR:
             (positions // length + 1) * length - 1,
             jnp.max(jnp.where(write_mask, positions, 0), axis=1,
                     keepdims=True))
-        rotary = _rotary_tables(positions, cfg.head_dim, cfg.rope_theta)
+        rotary = rotary_tables(positions, cfg.head_dim, cfg.rope_theta)
         flat = cache_locations(block_tables, positions, write_mask,
                                cache["kv"][0][0].shape[1])
         x = params["embed"][ids].astype(jnp.float32)
@@ -449,7 +447,7 @@ class SDAR:
                 length)[None, :])[:, :, None], axis=1)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-            logits = _product(x.astype(cfg.dtype), params["lm_head"])
+            logits = product(x.astype(cfg.dtype), params["lm_head"])
         # a prefill chunk reads logits at one position (the engine's reads
         # none of them); a block step reads a block's, whatever its width
         return logits, {"kv": kv, "routing": record, "moe": _count(
@@ -482,32 +480,3 @@ class SDAR:
                          "experts_drawn_per_step": sums["drew"] / calls,
                          "max_load_per_step": sums["max_load"] / calls}
         return {"moe": out}
-
-    # ---------------------------------------------------------- the rest
-
-    def forward(self, params, ids):
-        """Logits [b, s, vocab] of whole sequences from position 0 under
-        the block-causal mask: one `paged_step` over a cache of its own,
-        16-token blocks (tests, offline scoring)."""
-        b, s = ids.shape
-        per_row = -(-s // 16)
-        tables = 1 + jnp.arange(b * per_row, dtype=jnp.int32).reshape(
-            b, per_row)
-        logits, _ = self.paged_step(
-            params, ids, self.paged_cache(1 + b * per_row, 16), tables,
-            jnp.zeros((b,), jnp.int32), jnp.ones((b, s), bool))
-        return logits
-
-    def place_on_mesh(self, params, mesh):
-        """tp = 1 only."""
-        axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        if int(axes.get("tp", 1)) != 1:
-            raise ValueError("SDAR serves at tp = 1 only")
-        return params, 1
-
-    def early_exit_draft(self, params):
-        raise ValueError("SDAR has no draft: speculation is refused for a "
-                         "model that decodes by blocks")
-
-    def adapter_banks(self, n_rows: int, rank: int, mesh=None):
-        raise ValueError("SDAR has no adapter banks")

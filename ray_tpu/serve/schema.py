@@ -14,12 +14,12 @@ Config shape::
       - name: default
         import_path: my_module:app        # Application or Deployment
         deployments:                      # optional per-deployment overrides
-          - name: GPT2Sampler
+          - name: LLMServer
             num_replicas: 2
             max_concurrent_queries: 16
             autoscaling: {min_replicas: 1, max_replicas: 4,
                           target_ongoing_requests: 2.0}
-            route_prefix: /gpt2
+            route_prefix: /llm
 """
 
 from __future__ import annotations

@@ -399,17 +399,11 @@ def test_the_latent_walk_reaches_stats(tiny):
 # --------------------------------------------------------------------------- #
 
 
-class _NoFusedStep:
-    """The model with its fused step hidden: an engine over it keeps the
-    two programs."""
+class _NoFusedStep(DeepseekV3):
+    """The model with its fused step hidden (`PagedModel`'s "not offered"):
+    an engine over it keeps the two programs."""
 
-    def __init__(self, model):
-        self._model = model
-
-    def __getattr__(self, name):
-        if name == "paged_step_with_chunk":
-            raise AttributeError(name)
-        return getattr(self._model, name)
+    paged_step_with_chunk = None
 
 
 def test_the_fused_step_is_the_two_steps(tiny):
@@ -512,7 +506,7 @@ def test_a_chunk_aboard_changes_nothing_that_is_served(two_layers):
         return engine, reqs
 
     (fused, got), (plain_engine, want) = serve(model), serve(
-        _NoFusedStep(model))
+        _NoFusedStep(model.config))
     for a, b in zip(got, want):
         assert a.state == b.state == "FINISHED", (a.error, b.error)
         assert a.generated == b.generated

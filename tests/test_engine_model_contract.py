@@ -10,18 +10,20 @@ import pytest
 
 import ray_tpu
 from ray_tpu.inference.engine import EngineConfig, InferenceEngine
+from ray_tpu.models._served import PagedModel
 
 # ------------------------------------------------- a model that is no Llama
 
 
-class BagModel:
+class BagModel(PagedModel):
     """Not a flax module, no attention, no layers: a token's logits come
     from its embedding plus the mean of the embeddings cached at its
     row's positions up to its own. Its paged cache is a dict of one
     [blocks, block_size, width] array and a count of the steps that
     wrote it, which nothing but this class reads. It answers the two
-    questions every engine asks; the other three (mesh, draft, adapters)
-    are asked only of engines that have those."""
+    questions every engine asks; the rest is `PagedModel`'s defaults (its
+    cache is the paged blocks alone; mesh, draft and adapters are asked
+    only of engines that have those)."""
 
     vocab, width = 64, 8
 
@@ -31,9 +33,6 @@ class BagModel:
                     (self.vocab, self.width)).astype(np.float32),
                 "head": rng.standard_normal(
                     (self.width, self.vocab)).astype(np.float32)}
-
-    prefix_restores = True      # its cache is the paged blocks alone
-    slot_state_bytes = 0
 
     def paged_cache(self, num_blocks, block_size, mesh=None,
                     batch_slots=None):
@@ -238,7 +237,7 @@ def test_the_engine_serves_a_model_that_is_no_llama(bag, case):
 # ------------------------------- a model whose cache has no paged part
 
 
-class RunningMeanModel:
+class RunningMeanModel(PagedModel):
     """No blocks at all: its cache is the sum of a slot's embeddings so
     far, and a token's logits come from that sum over its position + 1.
     `pageless_context` says so (docs/INFERENCE.md, finding (e)): the

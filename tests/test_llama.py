@@ -1,7 +1,11 @@
-"""Llama model family: forward, sharded training, KV-cache decode, serving.
+"""Llama model family: forward, sharded training, paged KV-cache decode,
+serving.
 
 Parity target: the second model family next to GPT-2, with the
-decode-against-cache inference shape a Serve LLM deployment needs.
+decode-against-cache inference shape a Serve LLM deployment needs. The
+cache is the paged one (`Llama.decode_paged` over `make_paged_arena`): the
+model has no other, and every decode test's reference is the full causal
+forward, which has no cache at all.
 """
 
 import jax
@@ -14,8 +18,28 @@ from ray_tpu.models.llama import (
     Llama,
     LlamaConfig,
     flops_per_token,
-    make_cache,
+    make_paged_arena,
 )
+
+
+def _paged(cfg, model, params, rows, blocks_per_row=8, block_size=4):
+    """(step, arena, the jitted program): `step(tokens [rows, s], pos [rows],
+    arena, live=None) -> (logits, arena)` is one jitted `decode_paged` over
+    an arena in which row i holds blocks 1 + i * blocks_per_row onwards
+    (block 0 is the trash block); `live` [rows, s] masks batch padding
+    (default: all live)."""
+    arena = make_paged_arena(cfg, 1 + rows * blocks_per_row, block_size)
+    tables = 1 + jnp.arange(rows * blocks_per_row, dtype=jnp.int32).reshape(
+        rows, blocks_per_row)
+    paged = jax.jit(lambda p, tok, arena, pos, live: model.apply(
+        p, tok, arena, tables, pos, live, method=Llama.decode_paged))
+
+    def step(tokens, pos, arena, live=None):
+        live = jnp.ones(tokens.shape, bool) if live is None else live
+        return paged(params, tokens, arena, jnp.asarray(pos, jnp.int32),
+                     live)
+
+    return step, arena, paged
 
 
 @pytest.fixture(scope="module")
@@ -55,19 +79,16 @@ def test_train_step_reduces_loss(tiny_model):
 def test_decode_matches_full_forward(tiny_model):
     cfg, model, ids, params = tiny_model
     full = model.apply(params, ids)
+    step, arena, _ = _paged(cfg, model, params, rows=2)
     # Prefill in one shot.
-    cache = make_cache(cfg, 2, 32)
-    pf, cache = model.apply(params, ids, cache, jnp.zeros(2, jnp.int32),
-                            method=Llama.decode)
+    pf, _ = step(ids, [0, 0], arena)
     np.testing.assert_allclose(np.asarray(pf, np.float32),
                                np.asarray(full, np.float32),
                                atol=0.06, rtol=0.05)
     # Token-by-token decode agrees position-wise.
-    cache2 = make_cache(cfg, 2, 32)
+    _, arena2, _ = _paged(cfg, model, params, rows=2)
     for t in range(ids.shape[1]):
-        lg, cache2 = model.apply(params, ids[:, t:t + 1], cache2,
-                                 jnp.full((2,), t, jnp.int32),
-                                 method=Llama.decode)
+        lg, arena2 = step(ids[:, t:t + 1], [t, t], arena2)
         np.testing.assert_allclose(np.asarray(lg[:, 0], np.float32),
                                    np.asarray(full[:, t], np.float32),
                                    atol=0.06, rtol=0.05)
@@ -77,15 +98,14 @@ def test_decode_per_row_positions(tiny_model):
     """Rows at different lengths decode against their own offsets."""
     cfg, model, ids, params = tiny_model
     full = model.apply(params, ids)
-    cache = make_cache(cfg, 2, 32)
-    model_apply = lambda tok, c, pos: model.apply(  # noqa: E731
-        params, tok, c, pos, method=Llama.decode)
-    # Prefill row 0 with 4 tokens, row 1 with 7 (padded batch prefill).
-    _, cache = model_apply(ids, cache, jnp.zeros(2, jnp.int32))
+    step, arena, _ = _paged(cfg, model, params, rows=2)
+    # Prefill row 0 with 4 tokens, row 1 with 7 (padded batch prefill:
+    # the padding is masked and lands in the trash block).
+    lengths = jnp.asarray([4, 7])
+    _, arena = step(ids, [0, 0], arena,
+                    jnp.arange(ids.shape[1])[None, :] < lengths[:, None])
     # Next-token decode at row-specific positions 4 and 7.
-    lg, cache = model_apply(
-        jnp.stack([ids[0, 4:5], ids[1, 7:8]]), cache,
-        jnp.asarray([4, 7], jnp.int32))
+    lg, arena = step(jnp.stack([ids[0, 4:5], ids[1, 7:8]]), [4, 7], arena)
     np.testing.assert_allclose(np.asarray(lg[0, 0], np.float32),
                                np.asarray(full[0, 4], np.float32),
                                atol=0.06, rtol=0.05)
@@ -105,13 +125,17 @@ def test_sharded_init_on_mesh(tiny_model):
     assert n > 0
 
 
-def test_llama_sampler_through_serve(ray_start_regular):
+def test_llama_through_serve(ray_start_regular):
+    """The one way to serve a language model, `LLMServer`, on the request
+    shapes the static-batch sampler deployment took."""
     import ray_tpu
     from ray_tpu import serve
-    from ray_tpu.serve.examples import LlamaSampler
+    from ray_tpu.inference import LLMServer
 
-    handle = serve.run(LlamaSampler.options(num_replicas=1).bind(
-        "tiny", 64, 8))
+    handle = serve.run(LLMServer.options(num_replicas=1).bind(
+        "tiny", 64, 8,
+        engine_config={"batch_slots": 4, "block_size": 8, "num_blocks": 33,
+                       "max_blocks_per_seq": 8, "prefill_chunk": 8}))
     try:
         out = ray_tpu.get(handle.remote(
             {"ids": [1, 2, 3], "max_new_tokens": 5}), timeout=180)
@@ -139,16 +163,14 @@ def test_decode_parity_and_compile_once(n_kv_head):
     params = model.init(jax.random.PRNGKey(1), ids)
     full = model.apply(params, ids)
 
-    decode_step = jax.jit(lambda p, tok, cache, pos: model.apply(
-        p, tok, cache, pos, method=Llama.decode))
-    cache = make_cache(cfg, 2, 64)
-    # Prefill the first 4 tokens in one shot, then decode one at a time.
-    prefill = jax.jit(lambda p, tok, cache, pos: model.apply(
-        p, tok, cache, pos, method=Llama.decode))
-    _, cache = prefill(params, ids[:, :4], cache, jnp.zeros(2, jnp.int32))
+    # Two jitted programs over the same block tables: a prefill of the
+    # first 4 tokens in one shot, then a decode of one token at a time.
+    prefill, arena, _ = _paged(cfg, model, params, rows=2, blocks_per_row=16)
+    step, _, decode_step = _paged(cfg, model, params, rows=2,
+                                  blocks_per_row=16)
+    _, arena = prefill(ids[:, :4], [0, 0], arena)
     for t in range(4, ids.shape[1]):
-        lg, cache = decode_step(params, ids[:, t:t + 1], cache,
-                                jnp.full((2,), t, jnp.int32))
+        lg, arena = step(ids[:, t:t + 1], [t, t], arena)
         np.testing.assert_allclose(np.asarray(lg[:, 0], np.float32),
                                    np.asarray(full[:, t], np.float32),
                                    atol=0.06, rtol=0.05)
@@ -157,10 +179,9 @@ def test_decode_parity_and_compile_once(n_kv_head):
 
 
 def test_paged_decode_matches_dense(tiny_model):
-    """Paged-arena decode (block tables, scattered physical blocks) must
-    agree with the dense per-row cache path token for token."""
-    from ray_tpu.models.llama import make_paged_arena
-
+    """Paged-arena decode over SCATTERED physical blocks (the logical
+    order comes from the block table, not from the arena's layout) must
+    agree with the full forward token for token."""
     cfg, model, ids, params = tiny_model
     full = model.apply(params, ids)
     arena = make_paged_arena(cfg, 16, 4)

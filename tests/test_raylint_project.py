@@ -844,12 +844,40 @@ def test_mutation_adding_trailing_none_spec_fires_rl023(tmp_path):
     assert findings, "RL023 did not notice the trailing-None spec"
 
 
+# The shape of a static-batch sampler deployment: a jitted closure that
+# reads `self._max_seq`, set once in the constructor. The package holds no
+# such closure since the sampler examples went (its jitted closures read no
+# `self._...`), so the mutation is hosted in a tree of the test's own.
+RL024_SAMPLER = """
+    import jax
+    import jax.numpy as jnp
+
+
+    class Sampler:
+        def __init__(self, max_seq):
+            self._max_seq = max_seq
+
+            def decode_step(tok, lens):
+                return tok, jnp.where(lens < self._max_seq - 1, lens + 1, lens)
+
+            self._decode = jax.jit(decode_step)
+
+        def __call__(self, prompts):
+            pad = 8
+            pad = min(pad, self._max_seq)
+            return self._decode(jnp.zeros((pad,), jnp.int32),
+                                jnp.ones((pad,), jnp.int32))
+"""
+
+
 def test_mutation_steady_state_write_to_captured_attr_fires_rl024(tmp_path):
-    root = copy_package(tmp_path)
-    # LlamaSampler's jitted decode_step closure captures self._max_seq;
+    root = str(write_tree(tmp_path, {"pkg/__init__.py": "",
+                                     "pkg/sampler.py": RL024_SAMPLER}))
+    assert lint_paths_full([root], ["RL024"]).findings == []
+    # The sampler's jitted decode_step closure captures self._max_seq;
     # rebinding it per batch makes the capture stale (jit burned the
     # first-trace value in).
-    mutate(root, "serve/examples.py",
+    mutate(root, "pkg/sampler.py",
            "pad = min(pad, self._max_seq)",
            "pad = min(pad, self._max_seq)\n        self._max_seq = pad")
     findings = [f for f in lint_paths_full([root], ["RL024"]).findings

@@ -192,22 +192,31 @@ def test_handle_composition_between_deployments(serve_cluster):
     assert ray_tpu.get(outer.remote(4)) == 50
 
 
-def test_gpt2_sampler_deployment_batches(serve_cluster):
-    from ray_tpu.serve.examples import GPT2Sampler
+def test_llm_server_deployment_batches(serve_cluster):
+    """Eight concurrent requests to the one language-model deployment share
+    decode steps: the engine's step ledger says more than one row a step."""
+    from ray_tpu.inference import LLMServer
 
-    # Generous deploy budget: replica __init__ jit-compiles a tiny GPT-2,
-    # which can exceed the 60s default when the host is loaded (this test
-    # flaked twice in contended full-suite runs).
-    handle = serve.run(GPT2Sampler.bind("tiny", 64, 4), timeout_s=180.0)
+    # Generous deploy budget: the replica's first requests jit-compile a
+    # tiny Llama's two programs, which can exceed the 60s default when the
+    # host is loaded.
+    handle = serve.run(LLMServer.bind(
+        "tiny", 64, 4,
+        engine_config={"batch_slots": 4, "block_size": 8, "num_blocks": 33,
+                       "max_blocks_per_seq": 8, "prefill_chunk": 8}),
+        timeout_s=180.0)
     refs = [handle.remote({"ids": [1, 2, 3 + i], "max_new_tokens": 4})
             for i in range(8)]
     outs = ray_tpu.get(refs)
     for i, out in enumerate(outs):
         assert out["ids"][:3] == [1, 2, 3 + i]
-        assert len(out["ids"]) > 3
+        assert len(out["ids"]) == 7
     m = ray_tpu.get(handle.metrics.remote(None))
-    assert m["batches_served"] >= 1
-    assert m["mean_batch_size"] > 1.0, "batching never engaged"
+    assert m["requests_finished"] == 8
+    steps = m["steps"]
+    assert steps["decode"] >= 1
+    assert steps["decode_rows"] / steps["decode"] > 1.0, \
+        "batching never engaged"
 
 
 def test_deployment_graph_composition(serve_cluster):
