@@ -50,6 +50,14 @@ A prefix of KV blocks does NOT restore a sequence (`prefix_restores`
 False): the engine adopts nothing from the radix cache and refuses
 speculation until state snapshots exist.
 
+A step runs over ROW GROUPS (`FalconH1._step`): one, a decode step or a
+prefill chunk (`paged_step`), or a decode step with a sequence's chunk
+aboard (`paged_step_with_chunk`, docs/INFERENCE.md finding (g)), the two laid
+end to end through everything that is a token's own and cut apart for
+attention, convolution and recurrence. The chunk's slot is a held row of
+the decode group, whose kernels hand the state on to the chunk group's,
+which resets or carries it: each group by its own `state_rows`.
+
 Precision: parameters and matmul operands bf16 (the published dtype) into
 f32 accumulation; norms, the in-projection's output, the convolution, dt,
 the gate and the carried state are f32.
@@ -59,7 +67,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -231,34 +239,77 @@ def published_weights(params) -> Tuple[Dict[str, Any], Any]:
 # --------------------------------------------------------------------------- #
 
 
-def _attention(cfg, lp, u, kv, block_tables, positions, write_mask):
-    b, s, _ = u.shape
+class _Rows(NamedTuple):
+    """One ROW GROUP of a step: b sequences of s positions each (a decode
+    step's [slots, 1], a prefill chunk's [1, c]) and what belongs to them
+    as sequences: where they attend and write, and whose state they
+    advance. `slots` [b] is each row's batch slot (None: row i is slot i);
+    `fresh` [b] the rows that start from zero state, `live` [b, s] the
+    positions that advance it (a prefix of each row)."""
+    block_tables: Any
+    positions: Any
+    write_mask: Any
+    slots: Any
+    fresh: Any
+    live: Any
+
+
+def _cut(t, groups):
+    """t [b, s, ...] of one group as it is, or [1, T, ...] of several laid
+    end to end cut into each group's [b, s, ...]: slices of rows, the
+    products before them run once over all T."""
+    if len(groups) == 1:
+        return [t]
+    out, start = [], 0
+    for rows in groups:
+        b, s = rows.positions.shape
+        out.append(t[0, start:start + b * s].reshape(b, s, *t.shape[2:]))
+        start += b * s
+    return out
+
+
+def _joined(parts):
+    """The groups' [b, s, n] laid end to end again, [1, T, n]."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate([p.reshape(1, -1, p.shape[-1]) for p in parts],
+                           axis=1)
+
+
+def _attention(cfg, lp, u, kv, groups):
     hd = cfg.head_dim
 
     def heads(t, n):
-        return t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
+        return [p.reshape(*p.shape[:2], n, hd).transpose(0, 2, 1, 3)
+                for p in _cut(t, groups)]
 
     q = heads(u @ lp["wq"], cfg.num_attention_heads)
     k = heads((u @ lp["wk"]) * jnp.asarray(cfg.key_multiplier, u.dtype),
               cfg.num_key_value_heads)
     v = heads(u @ lp["wv"], cfg.num_key_value_heads)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    attn, k_arena, v_arena = paged_write_and_attend(
-        q, k, v, kv[0], kv[1], block_tables, positions, write_mask)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, -1)
-    return attn @ lp["wo"], (k_arena, v_arena)
+    k_arena, v_arena = kv
+    out = []
+    # A group at a time, each call at its own program's shape.
+    for rows, qr, kr, vr in zip(groups, q, k, v):
+        qr = apply_rope(qr, rows.positions, cfg.rope_theta)
+        kr = apply_rope(kr, rows.positions, cfg.rope_theta)
+        attn, k_arena, v_arena = paged_write_and_attend(
+            qr, kr, vr, k_arena, v_arena, rows.block_tables, rows.positions,
+            rows.write_mask)
+        b, _, s, _ = attn.shape
+        out.append(attn.transpose(0, 2, 1, 3).reshape(b, s, -1))
+    return _joined(out) @ lp["wo"], (k_arena, v_arena)
 
 
-def _mixer(cfg, lp, u, state, tail, slots, fresh, live):
-    """The Mamba-2 mixer on u [b, s, hidden] (already times
-    `ssm_in_multiplier`). `state` [slots, heads, N, P] and `tail` [slots,
-    d_conv - 1, conv_dim] hold every slot's; `slots` [b] (None: row i is
-    slot i, one token a row); `fresh` [b] the rows that start from zero
-    state, `live` [b, s] the positions that advance it (a prefix of each
-    row). Returns (out [b, s, hidden], state, tail)."""
+def _mixer(cfg, lp, u, state, tail, groups):
+    """The Mamba-2 mixer on u (already times `ssm_in_multiplier`), [b, s,
+    hidden] of one group or [1, T, hidden] of several laid end to end: the
+    in-projection, the gate, the group norm and the out-projection run once
+    over all T; the convolution and the recurrence a group at a time, in
+    order, each from the state the one before it left. `state` [slots,
+    heads, N, P] and `tail` [slots, d_conv - 1, conv_dim] hold every
+    slot's. Returns (out like u, state, tail)."""
     f32 = jnp.float32
-    b, s, _ = u.shape
     heads, p, n, g = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
                       cfg.mamba_n_groups)
     d_ssm = cfg.mamba_d_ssm
@@ -269,33 +320,46 @@ def _mixer(cfg, lp, u, state, tail, slots, fresh, live):
         xbc = proj[..., d_ssm:d_ssm + cfg.conv_dim].astype(cfg.dtype)
         dt = jax.nn.softplus(proj[..., d_ssm + cfg.conv_dim:]
                              + lp["dt_bias"].astype(f32))
+        convs = []
         with jax.named_scope("ssm_conv"):
-            before = tail if slots is None else tail[slots]
-            before = jnp.where(fresh[:, None, None], 0, before)
-            conv, after = ssd.causal_conv1d_carried(
-                xbc, lp["conv_w"], lp["conv_b"], before, live)
-            tail = after if slots is None else tail.at[slots].set(after)
-            conv = jax.nn.silu(conv).astype(cfg.dtype)
-        x = conv[..., :d_ssm].reshape(b, s, heads, p)
-        bm = conv[..., d_ssm:d_ssm + g * n].reshape(b, s, g, n)
-        cm = conv[..., d_ssm + g * n:].reshape(b, s, g, n)
-        a = -jnp.exp(lp["A_log"].astype(f32))
-        with jax.named_scope("ssm_scan"):
-            if slots is None:
-                y, state = ssd.ssd_step(
-                    x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], lp["D"], state,
-                    fresh, live[:, 0])
-                y = y[:, None]
-            else:
-                y, state = ssd.ssd_chunk_fwd(x, dt, a, bm, cm, lp["D"], state,
-                                             slots, fresh, live)
-        y = y.reshape(b, s, d_ssm) * jax.nn.silu(z)
+            for rows, part in zip(groups, _cut(xbc, groups)):
+                before = tail if rows.slots is None else tail[rows.slots]
+                before = jnp.where(rows.fresh[:, None, None], 0, before)
+                conv, after = ssd.causal_conv1d_carried(
+                    part, lp["conv_w"], lp["conv_b"], before, rows.live)
+                tail = after if rows.slots is None \
+                    else tail.at[rows.slots].set(after)
+                convs.append(jax.nn.silu(conv).astype(cfg.dtype))
+        ys = []
+        for rows, conv, dts in zip(groups, convs, _cut(dt, groups)):
+            b, s, _ = conv.shape
+            x = conv[..., :d_ssm].reshape(b, s, heads, p)
+            bm = conv[..., d_ssm:d_ssm + g * n].reshape(b, s, g, n)
+            cm = conv[..., d_ssm + g * n:].reshape(b, s, g, n)
+            # (traced a group, after the cut, as the one-group programs
+            # always have: their lowered text is held to the parent's)
+            a = -jnp.exp(lp["A_log"].astype(f32))
+            with jax.named_scope("ssm_scan"):
+                if rows.slots is None:
+                    y, state = ssd.ssd_step(
+                        x[:, 0], dts[:, 0], a, bm[:, 0], cm[:, 0], lp["D"],
+                        state, rows.fresh, rows.live[:, 0])
+                    y = y[:, None]
+                else:
+                    y, state = ssd.ssd_chunk_fwd(
+                        x, dts, a, bm, cm, lp["D"], state, rows.slots,
+                        rows.fresh, rows.live)
+            ys.append(y.reshape(b, s, d_ssm))
+        y = _joined(ys) * jax.nn.silu(z)
         y = rms_norm(y, lp["ssm_norm"], cfg.rms_norm_eps, groups=g)
         return y.astype(cfg.dtype) @ lp["out_proj"], state, tail
 
 
-def _block(cfg, lp, x, cache, block_tables, positions, write_mask, slots,
-           fresh, live):
+def _block(cfg, lp, x, cache, groups):
+    """One block on x [b, s, hidden] of one row group, or [1, T, hidden] of
+    several laid end to end. Everything but the attention, the convolution
+    and the recurrence is a token's own and reads its weights once for all
+    T."""
     kv, state, tail = cache
     dt = cfg.dtype
 
@@ -304,9 +368,9 @@ def _block(cfg, lp, x, cache, block_tables, positions, write_mask, slots,
 
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dt)
     attn, kv = _attention(cfg, lp, scaled(h, cfg.attention_in_multiplier),
-                          kv, block_tables, positions, write_mask)
+                          kv, groups)
     mix, state, tail = _mixer(cfg, lp, scaled(h, cfg.ssm_in_multiplier),
-                              state, tail, slots, fresh, live)
+                              state, tail, groups)
     x = x + scaled(attn, cfg.attention_out_multiplier) \
         + scaled(mix, cfg.ssm_out_multiplier)
     h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(dt)
@@ -373,27 +437,70 @@ class FalconH1(PagedModel):
         vocab], or [b, vocab] at `last_idx` [b]; the cache)."""
         if adapters is not None:
             raise ValueError("FalconH1 has no adapter banks")
+
+        def read(x):
+            if last_idx is None:
+                return x
+            return jnp.take_along_axis(x, last_idx[:, None, None],
+                                       axis=1)[:, 0]
+
+        return self._step(
+            params, cache, [(ids, block_tables, row_pos, write_mask, slots)],
+            read)
+
+    def paged_step_with_chunk(self, params, tokens, chunk_ids, cache,
+                              block_tables, row_pos, write_mask, chunk_bt,
+                              chunk_pos, chunk_wmask, chunk_slot, last_idx):
+        """A decode step with one sequence's prefill chunk aboard (the
+        contract's optional answer): `paged_step` of tokens [b, 1] and
+        `paged_step` of chunk_ids [1, c] in slot `chunk_slot` [1] at
+        `last_idx` [1] as ONE execution, in which every weight is read once
+        for the b + c rows and only what belongs to a sequence (attention,
+        convolution, recurrence) is two calls. The engine masks the chunk's
+        own slot among the decode rows: the decode group holds it and the
+        chunk group resets or carries it, each by its own `state_rows`.
+        Returns (the decode rows' logits [b, vocab], the chunk's [1,
+        vocab], the cache)."""
+        b = tokens.shape[0]
+        logits, cache = self._step(
+            params, cache,
+            [(tokens, block_tables, row_pos, write_mask, None),
+             (chunk_ids, chunk_bt, chunk_pos, chunk_wmask, chunk_slot)],
+            lambda x: jnp.concatenate([x[0, :b], x[0, b + last_idx]]))
+        return logits[:b], logits[b:], cache
+
+    def _step(self, params, cache, groups, read):
+        """The one body of a step over ROW GROUPS, each (ids [b, s],
+        block_tables, row_pos, write_mask, slots): (the logits of the rows
+        `read` picks from the last block's x, the cache). One group is a
+        decode step or a prefill chunk, x [b, s, hidden]. Several are laid
+        end to end, x [1, T, hidden], through everything that is a token's
+        own (T rows against one read of the weights) and cut apart for
+        what belongs to a sequence (`_Rows`), a later group from the state
+        an earlier one left."""
         cfg = self.config
-        b, s = ids.shape
-        if slots is None and (s != 1 or b != cache["ssm"][0].shape[0]):
-            raise ValueError("a step that names no slots is one token of "
-                             "every slot")
-        positions = row_pos[:, None] + jnp.arange(s)[None, :]
-        fresh, live = self.state_rows(row_pos, write_mask)
+        rows = []
+        for ids, block_tables, row_pos, write_mask, slots in groups:
+            b, s = ids.shape
+            if slots is None and (s != 1 or b != cache["ssm"][0].shape[0]):
+                raise ValueError("a step that names no slots is one token "
+                                 "of every slot")
+            positions = row_pos[:, None] + jnp.arange(s)[None, :]
+            rows.append(_Rows(block_tables, positions, write_mask, slots,
+                              *self.state_rows(row_pos, write_mask)))
+        ids = groups[0][0] if len(groups) == 1 else jnp.concatenate(
+            [g[0].reshape(1, -1) for g in groups], axis=1)
         x = params["embed"][ids] * jnp.asarray(cfg.embedding_multiplier,
                                                cfg.dtype)
         kvs, states, tails = [], [], []
         for i, lp in enumerate(params["layers"]):
             x, (kv, state, tail) = _block(
                 cfg, lp, x, (cache["kv"][i], cache["ssm"][i],
-                             cache["conv"][i]),
-                block_tables, positions, write_mask, slots, fresh, live)
+                             cache["conv"][i]), rows)
             kvs.append(kv)
             states.append(state)
             tails.append(tail)
-        if last_idx is not None:
-            x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = rms_norm(read(x), params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.dot(x.astype(cfg.dtype), params["lm_head"],
                          preferred_element_type=jnp.float32) \
             * cfg.lm_head_multiplier

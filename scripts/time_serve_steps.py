@@ -1,19 +1,25 @@
-"""The engine's step programs ALONE at the Kanana-2 cell's shapes, on the
-chip: ms an execution of `decode_fn` (31 of 32 slots live at contexts of
-8,300-8,800), of `prefill_fn` (one chunk of 256 positions of which 160 are
-live behind 8,192 cached tokens) and of the fused program that runs both as
-one (a decode step with the chunk aboard, `models/deepseek_v3.py
-paged_step_with_chunk`), and the experts a layer each draws. ROADMAP caveat
-9: time a program alone before predicting what an engine gains from it.
+"""The engine's step programs ALONE at a serve cell's shapes, on the chip:
+ms an execution of `decode_fn` (all slots but one live), of `prefill_fn`
+(one chunk of 256 positions of which 160 are live) and of the fused program
+that runs both as one (a decode step with the chunk aboard, the model's
+`paged_step_with_chunk`), each program's first call (`compile_s`: what a
+start-up pays for it) and, where the model counts them, the experts a layer
+each draws. ROADMAP caveat 9: time a program alone before predicting what
+an engine gains from it.
 
-    chiprun -- python3 scripts/time_serve_steps.py
+    chiprun -- python3 scripts/time_serve_steps.py [--config <name>]
 
+The configuration's `model_type` chooses the model and the shapes (`CELLS`):
+`kanana-2-30b-a3b-l8-serve` (the default; 31 of 32 slots at contexts of
+8,300-8,800, the chunk behind 8,192 cached tokens) or
+`falcon-h1-34b-l6-serve` (63 of 64 slots at contexts of ~290, the chunk
+FRESH, into a slot whose state and tail hold another request's leavings).
 One JSON line on stdout, the same in `chiprun_out/serve_steps.json`. The
 model is the cell's configuration (`benchmarks/configs/<--config>.json`)
-with seeded weights and an arena of seeded rows; the programs are the
+with seeded weights and a cache of seeded rows; the programs are the
 engine's own jitted objects, called as `_decode_step` calls them.
-`--rehearsal` under RAY_TPU_PALLAS_INTERPRET=1 runs a tiny configuration
-on the CPU (times of the interpreter: no device number).
+`--rehearsal` under RAY_TPU_PALLAS_INTERPRET=1 runs the model's tiny
+configuration on the CPU (times of the interpreter: no device number).
 """
 
 from __future__ import annotations
@@ -23,6 +29,7 @@ import json
 import os
 import sys
 import time
+from importlib import import_module
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -34,31 +41,43 @@ import numpy as np  # noqa: E402
 
 from ray_tpu.inference.engine import (EngineConfig,  # noqa: E402
                                       InferenceEngine)
-from ray_tpu.models.deepseek_v3 import (DeepseekV3,  # noqa: E402
-                                        DeepseekV3Config)
+
+# model_type (also the model's module under `ray_tpu.models`) -> its class,
+# the tokens cached before the chunk, the decode rows' contexts beyond them
+# (from, to) and the parts of its cache that hold rows.
+CELLS = {
+    "deepseek_v3": dict(cls="DeepseekV3", prefix=8192, contexts=(108, 609),
+                        rows=("latent",)),
+    "falcon_h1": dict(cls="FalconH1", prefix=0, contexts=(80, 500),
+                      rows=("kv", "ssm", "conv")),
+}
 
 
 def build(args):
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           args.config + ".json")) as f:
+        cfg = json.load(f)
+    cell = CELLS[cfg["model_type"]]
+    module = import_module("ray_tpu.models." + cfg["model_type"])
+    model_cls = getattr(module, cell["cls"])
+    config_cls = getattr(module, cell["cls"] + "Config")
     if args.rehearsal:
-        model = DeepseekV3(DeepseekV3Config.tiny())
+        model = model_cls(config_cls.tiny())
         engine_cfg = dict(batch_slots=3, block_size=16, num_blocks=40,
                           max_blocks_per_seq=12, prefill_chunk=16)
-        prefix, live = 128, 10
+        cell = {**cell, "prefix": min(cell["prefix"], 128)}
+        live = 10
     else:
-        with open(os.path.join(ROOT, "benchmarks", "configs",
-                               args.config + ".json")) as f:
-            cfg = json.load(f)
-        model = DeepseekV3(DeepseekV3Config.from_published(
-            cfg, dtype=jnp.bfloat16))
-        engine_cfg, prefix, live = cfg["engine"], 8192, args.live
+        model = model_cls(config_cls.from_published(cfg, dtype=jnp.bfloat16))
+        engine_cfg, live = cfg["engine"], args.live
     params = model.init(jax.random.PRNGKey(args.seed % (2 ** 31)))
     engine = InferenceEngine(
         EngineConfig(prefix_cache_enabled=False, **engine_cfg), model=model,
         params=params)
-    return model, engine, prefix, live
+    return model, engine, cell, live
 
 
-def arguments(engine, prefix: int, live: int, seed: int):
+def arguments(engine, cell, live: int, seed: int):
     """(decode's, the chunk's) arguments: every slot its own shuffled
     blocks, the last slot the chunk's (dead among the decode rows)."""
     cfg = engine.config
@@ -68,8 +87,9 @@ def arguments(engine, prefix: int, live: int, seed: int):
     reach = width * bsz
     tables = 1 + rng.permutation(cfg.num_blocks - 1)[:slots * width].reshape(
         slots, width).astype(np.int32)
-    pos = rng.integers(min(prefix + 108, reach - 2),
-                       min(prefix + 609, reach), slots).astype(np.int32)
+    prefix, (near, far) = cell["prefix"], cell["contexts"]
+    pos = rng.integers(min(prefix + near, reach - 2),
+                       min(prefix + far, reach), slots).astype(np.int32)
     wmask = np.ones((slots, 1), bool)
     wmask[-1] = False
     chunk = cfg.prefill_chunk
@@ -91,16 +111,17 @@ def main() -> int:
     parser.add_argument("--calls", type=int, default=30)
     parser.add_argument("--rehearsal", action="store_true")
     args = parser.parse_args()
-    model, engine, prefix, live = build(args)
-    # Seeded rows in place of the zeros, an arena at a time into its own
-    # buffer: weights and arenas fill the chip.
+    model, engine, cell, live = build(args)
+    # Seeded rows in place of the zeros, an array at a time into its own
+    # buffer: weights and cache fill the chip.
     fill = jax.jit(lambda a, k: (0.5 * jax.random.normal(
         k, a.shape, jnp.float32)).astype(a.dtype), donate_argnums=0)
-    keys = jax.random.split(jax.random.PRNGKey(1), len(
-        engine._arenas["latent"]))
-    engine._arenas["latent"] = [
-        fill(a, k) for k, a in zip(keys, engine._arenas["latent"])]
-    decode_args, chunk_args = arguments(engine, prefix, live, args.seed)
+    for part in cell["rows"]:
+        leaves, tree = jax.tree.flatten(engine._arenas[part])
+        keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+        engine._arenas[part] = jax.tree.unflatten(
+            tree, [fill(a, k) for k, a in zip(keys, leaves)])
+    decode_args, chunk_args = arguments(engine, cell, live, args.seed)
     vocab = model.config.vocab_size
     engine._tokens = jnp.asarray(np.random.default_rng(args.seed).integers(
         1, vocab, engine.config.batch_slots), jnp.int32)
@@ -115,29 +136,35 @@ def main() -> int:
     if args.rehearsal:
         args.calls = 2
 
+    counted = model.cache_counters is not None
+
     def counters():
         return model.counter_stats(jax.device_get(
             model.cache_counters(engine._arenas)))["moe"]
 
     device = jax.devices()[0]
-    line = {"seed": args.seed, "live": live, "calls": args.calls,
-            "platform": device.platform, "device_kind": device.device_kind,
-            "ms": {}, "experts_drawn_a_layer": {}, "compile_s": {}}
+    line = {"config": args.config, "seed": args.seed, "live": live,
+            "calls": args.calls, "platform": device.platform,
+            "device_kind": device.device_kind, "ms": {}, "compile_s": {}}
+    if counted:
+        line["experts_drawn_a_layer"] = {}
     for name, run in programs.items():
         start = time.perf_counter()
         engine._tokens, engine._arenas = run()
         jax.block_until_ready(engine._tokens)
         line["compile_s"][name] = time.perf_counter() - start
-        before, best = counters(), float("inf")
+        before, best = counters() if counted else None, float("inf")
         for _ in range(3):
             start = time.perf_counter()
             for _ in range(args.calls):
                 # the tokens are not fed back: every call the same rows
                 _, engine._arenas = run()
-            jax.block_until_ready(engine._arenas["moe"]["steps"])
+            jax.block_until_ready(engine._arenas)
             best = min(best, (time.perf_counter() - start) / args.calls)
-        after = counters()
         line["ms"][name] = best * 1e3
+        if not counted:
+            continue
+        after = counters()
         drew = sum(after[k]["drew"] - before[k]["drew"]
                    for k in ("decode", "prefill"))
         line["experts_drawn_a_layer"][name] = drew / (

@@ -25,6 +25,9 @@ from ray_tpu.models.falcon_h1 import (FalconH1, FalconH1Config,  # noqa: E402
 # float32 parameters at the tiny size: the served path and the reference
 # differ by the order of summation alone. Logits are ~3e-3 there.
 TOL = 2e-7
+# Beside states and tails of ~1 (`test_the_fused_step_is_the_two_steps`
+# fills them with a normal draw) where logits are ~3e-3.
+STATE_TOL = 5e-6
 
 
 @pytest.fixture(scope="module")
@@ -106,7 +109,9 @@ def _case_three_rows_interleaved(tiny):
     mix = [(prompt(5, 1), 9), (prompt(3, 2), 8), (prompt(20, 3), 6)]
     reqs = [engine.add_request(p, n) for p, n in mix]
     engine.run_until_idle()
-    assert engine.step_stats()["prefill"] == 1 + 1 + 3
+    # five chunks: the first finds no row decoding, the others ride
+    steps = engine.step_stats()
+    assert (steps["prefill"], steps["chunks_aboard"]) == (1, 1 + 3)
     assert engine.stats()["state"]["resets"] == 3
     return engine, reqs
 
@@ -173,6 +178,153 @@ def test_the_engine_serves_it_as_the_reference_computes_it(tiny, case):
         * engine.config.batch_slots == sum(
             a.size * a.dtype.itemsize
             for a in engine._arenas["ssm"] + engine._arenas["conv"])
+
+
+# --------------------------------------------------------------------------- #
+# a chunk aboard the decode step
+# --------------------------------------------------------------------------- #
+
+
+class _NoFusedStep(FalconH1):
+    """The model with its fused step hidden (`PagedModel`'s "not offered"):
+    an engine over it keeps the two programs."""
+
+    paged_step_with_chunk = None
+
+
+class _NoReset(FalconH1):
+    """`benchmarks/falconh1_controls.py`'s first planted fault: a row that
+    starts at position 0 inherits its slot's state."""
+
+    def state_rows(self, row_pos, write_mask):
+        return jnp.zeros(row_pos.shape, bool), write_mask
+
+
+class _AdvanceMasked(FalconH1):
+    """Its second: masked positions advance the state."""
+
+    def state_rows(self, row_pos, write_mask):
+        fresh, _ = super().state_rows(row_pos, write_mask)
+        return fresh, jnp.ones_like(write_mask)
+
+
+# (the chunk's first position, its live positions of 8)
+CHUNKS_ABOARD = {
+    # position 0, into a slot that holds another request's leavings
+    "fresh": (0, 5),
+    # a later chunk of a long prompt: the slot's state is its own
+    "carried": (8, 7),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNKS_ABOARD))
+def test_the_fused_step_is_the_two_steps(tiny, case):
+    """`paged_step_with_chunk` against `paged_step` called twice, chunk
+    first as the engine's two programs run: the logits, the KV arenas and
+    EVERY slot's recurrent state and convolution tail, with a dead slot
+    among the decode rows (the chunk's own) and a padded chunk."""
+    model, params, _ = tiny
+    slots, chunk, bsz, width = 3, 8, 4, 12
+    start, n_live = CHUNKS_ABOARD[case]
+    cache = model.paged_cache(1 + (slots + 1) * width, bsz, None, slots)
+    # what came before: rows of K/V, states and tails, whatever they hold
+    leaves, tree = jax.tree.flatten(cache)
+    keys = jax.random.split(jax.random.PRNGKey(8), len(leaves))
+    cache = jax.tree.unflatten(tree, [
+        jax.random.normal(k, a.shape, a.dtype) for k, a in zip(keys, leaves)])
+    tables = 1 + jnp.arange((slots + 1) * width, dtype=jnp.int32).reshape(
+        slots + 1, width)
+    tokens = jnp.array([[5], [9], [0]], jnp.int32)
+    pos, wmask = jnp.array([21, 37, 0]), jnp.array([[True], [True], [False]])
+    chunk_live = jnp.arange(chunk)[None] < n_live
+    chunk_ids = jnp.where(chunk_live, jax.random.randint(
+        jax.random.PRNGKey(7), (1, chunk), 1, 96), 0)
+    chunk_pos, last = jnp.array([start]), jnp.array([n_live - 1])
+    chunk_slot = jnp.array([2], jnp.int32)
+
+    @jax.jit
+    def twice(cache):
+        chunk_logits, cache = model.paged_step(
+            params, chunk_ids, cache, tables[3:], chunk_pos, chunk_live,
+            None, chunk_slot, last)
+        logits, cache = model.paged_step(params, tokens, cache, tables[:3],
+                                         pos, wmask)
+        return logits[:, -1], chunk_logits, cache
+
+    @jax.jit
+    def fused(cache):
+        return model.paged_step_with_chunk(
+            params, tokens, chunk_ids, cache, tables[:3], pos, wmask,
+            tables[3:], chunk_pos, chunk_live, chunk_slot, last)
+
+    want, got = twice(cache), fused(cache)
+    assert got[0].shape == (slots, 96) and got[1].shape == (1, 96)
+    for a, b in zip(want[:2], got[:2]):
+        assert float(jnp.abs(a[:2] - b[:2]).max()) <= TOL
+    assert float(jnp.abs(want[0]).max()) > 1e-3
+    # the arenas everywhere but the trash block, where both write their
+    # dead rows; the state and the tail of every slot
+    for (wk, wv), (gk, gv) in zip(want[2]["kv"], got[2]["kv"]):
+        np.testing.assert_allclose(gk[1:], wk[1:], atol=TOL)
+        np.testing.assert_allclose(gv[1:], wv[1:], atol=TOL)
+    for name in ("ssm", "conv"):
+        for was, a, b in zip(cache[name], want[2][name], got[2][name]):
+            np.testing.assert_allclose(b, a, atol=STATE_TOL)
+            # and every slot's moved: two rows decoded, the third took
+            # the chunk
+            assert all(float(jnp.abs(a[i] - was[i]).max()) > 1e-3
+                       for i in range(slots))
+
+
+def _state_gaps(engine, other, name):
+    """A layer each: the largest distance between the two engines' `name`
+    ("ssm" or "conv") of any slot, as a share of the first's largest."""
+    return [float(np.abs(np.asarray(a) - np.asarray(b)).max()
+                  / np.abs(np.asarray(a)).max())
+            for a, b in zip(engine._arenas[name], other._arenas[name])]
+
+
+def test_a_chunk_aboard_changes_nothing_that_is_served(tiny):
+    """The same engine over the model and over the model with its fused
+    step hidden: the same greedy tokens for a mix whose chunks land while
+    others decode (prompts of one to three chunks; an early leaver whose
+    slot the last request is admitted into), and the same state left in
+    every slot. The planted faults of the state's bookkeeping change that
+    state through the fused path as they do through the two programs."""
+    _, params, _ = tiny
+    mix = [(prompt(5, 1), 12), (prompt(12, 2), 2), (prompt(20, 3), 9),
+           (prompt(7, 4), 6)]
+
+    def serve(cls):
+        engine = engine_of((cls(tiny[0].config), params, None))
+        reqs = [engine.add_request(p, n) for p, n in mix]
+        engine.run_until_idle()
+        engine.check_no_leaks()
+        return engine, reqs
+
+    (fused, got), (plain_engine, want) = serve(FalconH1), serve(_NoFusedStep)
+    for a, b in zip(got, want):
+        assert a.state == b.state == "FINISHED", (a.error, b.error)
+        assert a.generated == b.generated
+    assert_served_as_the_reference(tiny, got)
+    for name in ("ssm", "conv"):
+        assert max(_state_gaps(fused, plain_engine, name)) <= 1e-5
+    steps, plain_steps = fused.step_stats(), plain_engine.step_stats()
+    # 1 + 2 + 3 + 1 chunks: all but the first, which found no row
+    # decoding, rode (the fourth request's into the slot the second left)
+    assert (steps["prefill"], steps["chunks_aboard"]) == (1, 6)
+    assert (plain_steps["prefill"], plain_steps["chunks_aboard"]) == (7, 0)
+    stats = fused.stats()
+    assert stats["prefill_compiles"] == stats["decode_compiles"] \
+        == stats["decode_with_chunk_compiles"] == 1
+    assert stats["state"]["resets"] == 4
+    assert plain_engine.stats()["decode_with_chunk_compiles"] == 0
+    # the planted faults, through the fused program
+    for fault in (_NoReset, _AdvanceMasked):
+        engine, _ = serve(fault)
+        assert engine.step_stats()["chunks_aboard"] == 6
+        gaps = _state_gaps(fused, engine, "ssm")
+        assert min(gaps) > 0.05, (fault.__name__, gaps)
 
 
 def test_a_model_without_slot_state_reports_none():
