@@ -14,8 +14,8 @@ The engine knows nothing of the model's family and imports none. It is
 handed `model` and `params`, and what it asks of the model is stated once,
 as code: `ray_tpu.models._served.PagedModel` (`paged_cache`, `paged_step`,
 the attributes `prefix_restores`, `slot_state_bytes`, `pageless_context`,
-the optional `paged_step_with_chunk`, `decode_block` and `cache_counters`,
-and `place_on_mesh`, `early_exit_draft`, `adapter_banks`). The engine reads
+the optional `paged_step_with_chunk`, `decode_block`, `cache_kinds` and
+`cache_counters`, and `place_on_mesh`, `early_exit_draft`, `adapter_banks`). The engine reads
 each of them directly: a model derives from `PagedModel`, whose defaults
 say "the model does not offer it", so a misspelt answer is an error and not
 a silent no (docs/INFERENCE.md, "The model contract", has the reasons and
@@ -84,7 +84,9 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 from ray_tpu.inference.kv_cache import (BlockManager, NoBlocks,
-                                        RadixPrefixCache)
+                                        RadixPrefixCache,
+                                        WindowBlockManager,
+                                        WindowedRadixCache)
 from ray_tpu.observability import tracing as _tracing
 from ray_tpu.observability.phases import PhaseClock
 
@@ -138,6 +140,10 @@ class EngineConfig:
     spec_decode_draft_len: int = 0
     slo_default_class: str = "interactive"
     slo_interactive_reserved_slots: int = 0
+    # The pool of a model's AGEING kind of paged state (`cache_kinds`: a
+    # kind with a `window`), its trash block included; 0 for every model
+    # with one kind.
+    window_blocks: int = 0
 
     @property
     def max_context(self) -> int:
@@ -176,6 +182,10 @@ class Request:
     # Prefix-cache accounting: prompt tokens whose KV was adopted from
     # the radix cache instead of prefilled (across all admissions).
     cached_tokens: int = 0
+    # ... and, of a model with an ageing kind of paged state, those whose
+    # window-kind pages were adopted with them (the tail before the
+    # adopted boundary).
+    cached_window_tokens: int = 0
     # Scheduler-internal:
     slot: Optional[int] = None
     # Tokens whose KV write has been DISPATCHED: it runs ahead of
@@ -280,6 +290,15 @@ class InferenceEngine:
     `api.preset_model()`, `LLMServer`'s default.
     """
 
+    def __new__(cls, config: EngineConfig, model=None, *args, **kwargs):
+        # A model that holds more than one KIND of paged state says so
+        # once, here: it is served by the subclass below. Every other
+        # model has one kind, one pool, and this class as it stands.
+        if (cls is InferenceEngine and model is not None
+                and model.cache_kinds is not None):
+            cls = _KindsEngine
+        return super().__new__(cls)
+
     def __init__(self, config: EngineConfig, model=None, params=None,
                  mesh=None, draft_model=None, draft_params=None):
         cfg = config
@@ -340,7 +359,7 @@ class InferenceEngine:
                 "has no rollback")
         self._prefix: Optional[RadixPrefixCache] = None
         if cfg.prefix_cache_enabled and self._prefix_restores:
-            self._prefix = RadixPrefixCache(self._bm)
+            self._prefix = self._new_prefix_cache()
         # Speculative decoding: the draft shares the target's BLOCK
         # TABLES (host bookkeeping) but writes its own cache — same
         # geometry, so one table addresses both. With none injected the
@@ -839,6 +858,9 @@ class InferenceEngine:
         return model.paged_cache(self._bm.num_blocks, cfg.block_size,
                                  self._mesh, cfg.batch_slots)
 
+    def _new_prefix_cache(self) -> RadixPrefixCache:
+        return RadixPrefixCache(self._bm)
+
     def _fresh_tokens(self):
         """The token vector as the programs return it: replicated under a
         tp mesh, so that the first call's argument and every later one
@@ -1044,7 +1066,6 @@ class InferenceEngine:
         req._pinned_node = None
 
     def _admit(self):
-        cfg = self.config
         while self._waiting:
             free_slots = [i for i, r in enumerate(self._slots) if r is None]
             if not free_slots:
@@ -1061,39 +1082,9 @@ class InferenceEngine:
                 break
             if req is None:
                 return
-            rid = req.request_id
-            # Longest cached prefix: adopt matched blocks (refcount++)
-            # and skip their prefill entirely. Capped one token short of
-            # the stream so at least one token still prefills — the
-            # first emitted token needs fresh logits.
-            matched_tokens = 0
-            pin_node = None
-            if cfg.prefix_cache_enabled and not self._prefix_restores:
-                self._prefix_refused += 1
-            if self._prefix is not None:
-                stream = req.prompt + req.generated
-                cap = (len(stream) - 1) // cfg.block_size * cfg.block_size
-                blocks, pin_node = self._prefix.match(stream[:cap])
-                if blocks:
-                    matched_tokens = len(blocks) * cfg.block_size
-                    self._bm.register_with_blocks(rid, blocks)
-                    self._prefix.pin(pin_node)
-                    req._pinned_node = pin_node
-            if not self._bm.registered(rid):
-                self._bm.register(rid)
-            first = min(req.total_to_prefill,
-                        matched_tokens + cfg.prefill_chunk)
-            while not self._bm.ensure(rid, first):
-                deficit = (self._bm.blocks_for_tokens(first)
-                           - len(self._bm.block_table(rid))
-                           - self._bm.num_free())
-                if (self._prefix is None
-                        or self._prefix.evict_for(deficit) == 0):
-                    # Pool exhausted: stay queued; running sequences
-                    # finishing (or preempting) will free blocks.
-                    self._unpin_req(req)
-                    self._bm.free(rid)
-                    return
+            matched_tokens = self._admit_blocks(req)
+            if matched_tokens is None:
+                return
             self._waiting.remove(req)
             req.slot = free_slots[0]
             req.processed = matched_tokens
@@ -1105,6 +1096,48 @@ class InferenceEngine:
             if req.admitted_at is None:
                 req.admitted_at = time.monotonic()
             self._slots[req.slot] = req
+
+    def _admit_blocks(self, req: Request) -> Optional[int]:
+        """Adopt the longest cached prefix of `req` and claim its first
+        chunk's blocks: the tokens adopted, or None where the pool is
+        exhausted (nothing is kept, and the request stays queued: running
+        sequences finishing, or preempting, will free blocks)."""
+        cfg = self.config
+        rid = req.request_id
+        # Longest cached prefix: adopt matched blocks (refcount++)
+        # and skip their prefill entirely. Capped one token short of
+        # the stream so at least one token still prefills — the
+        # first emitted token needs fresh logits.
+        matched_tokens = 0
+        if cfg.prefix_cache_enabled and not self._prefix_restores:
+            self._prefix_refused += 1
+        if self._prefix is not None:
+            stream = req.prompt + req.generated
+            cap = (len(stream) - 1) // cfg.block_size * cfg.block_size
+            blocks, pin_node = self._prefix.match(stream[:cap])
+            if blocks:
+                matched_tokens = len(blocks) * cfg.block_size
+                self._bm.register_with_blocks(rid, blocks)
+                self._prefix.pin(pin_node)
+                req._pinned_node = pin_node
+        if not self._bm.registered(rid):
+            self._bm.register(rid)
+        first = min(req.total_to_prefill,
+                    matched_tokens + cfg.prefill_chunk)
+        while not self._bm.ensure(rid, first):
+            deficit = (self._bm.blocks_for_tokens(first)
+                       - len(self._bm.block_table(rid))
+                       - self._bm.num_free())
+            if (self._prefix is None
+                    or self._prefix.evict_for(deficit) == 0):
+                self._unpin_req(req)
+                self._release(rid)
+                return None
+        return matched_tokens
+
+    def _release(self, request_id: str) -> None:
+        """Give back every block the sequence holds."""
+        self._bm.free(request_id)
 
     # ---------------------------------------------------------- preemption
 
@@ -1120,7 +1153,7 @@ class InferenceEngine:
             return False
         victim = max(victims, key=self._prio)
         self._unpin_req(victim)
-        self._bm.free(victim.request_id)
+        self._release(victim.request_id)
         self._slots[victim.slot] = None
         victim.slot = None
         victim.state = WAITING
@@ -1520,17 +1553,19 @@ class InferenceEngine:
             stream = req.prompt + req.generated
             nb = min(req.processed, len(stream)) // self.config.block_size
             if nb > 0:
-                self._prefix.insert(
-                    stream[:nb * self.config.block_size],
-                    self._bm.block_table(req.request_id)[:nb])
+                self._donate(req, stream[:nb * self.config.block_size], nb)
         self._unpin_req(req)
-        self._bm.free(req.request_id)
+        self._release(req.request_id)
         if req.slot is not None:
             self._slots[req.slot] = None
             req.slot = None
         self._live.pop(req.request_id, None)
         self._emit_finish(req, emissions)
         self._record_phase_spans(req)
+
+    def _donate(self, req: Request, tokens: List[int], nb: int) -> None:
+        self._prefix.insert(tokens,
+                            self._bm.block_table(req.request_id)[:nb])
 
     def fail_all(self, error: str) -> int:
         """Abort every scheduled and waiting request with `error` (the
@@ -1585,6 +1620,11 @@ class InferenceEngine:
             _tracing.epoch_of(req.first_token_delivered_at),
             parent_ctx=req.trace_ctx, attrs={"request": req.request_id})
 
+    def _adoption_attrs(self, req: Request) -> Dict[str, Any]:
+        """What the `engine.prefill` span says of the cached state a
+        request adopted, beyond `cached_tokens`."""
+        return {}
+
     def _record_phase_spans(self, req: Request):
         """TTFT decomposition, recorded once per finished request under
         its captured trace context: engine.queue (submit -> first
@@ -1609,7 +1649,8 @@ class InferenceEngine:
                 eo(req.first_token_at if req.first_token_at is not None
                    else end),
                 parent_ctx=req.trace_ctx,
-                attrs=dict(attrs, prompt_tokens=len(req.prompt)))
+                attrs=dict(attrs, prompt_tokens=len(req.prompt),
+                           **self._adoption_attrs(req)))
         if req.first_token_at is not None:
             tracer.record_span(
                 "engine.decode", eo(req.first_token_at), eo(end),
@@ -1781,6 +1822,163 @@ class InferenceEngine:
             if self._prefix is None:
                 return 0
             return self._prefix.clear()
+
+
+class _KindsEngine(InferenceEngine):
+    """The engine of a model whose cache holds TWO kinds of paged state
+    (`cache_kinds`; docs/INFERENCE.md finding (j)): the first as every
+    model's (its table bounds a sequence, the radix cache keeps its
+    blocks), the second AGEING: a query reads its last `window` positions
+    alone, so the kind has a pool and a table of its own
+    (`WindowBlockManager`), a live sequence gives back the pages that fall
+    behind its window at every claim, and the radix cache keeps the tail
+    before a node's end (`WindowedRadixCache`). `paged_step` is handed a
+    table a kind. `InferenceEngine(...)` makes one of these where the model
+    states kinds; everything that differs is an override here, and asks
+    the second pool where a request arrives or leaves, never a row a
+    step."""
+
+    def __init__(self, config: EngineConfig, model=None, params=None,
+                 **kwargs):
+        kinds = model.cache_kinds
+        names = list(kinds)
+        windows = [kinds[k].get("window") for k in names]
+        if len(names) != 2 or windows[0] is not None or not windows[1]:
+            raise ValueError(
+                f"cache_kinds {kinds}: the engine holds one kind that keeps "
+                f"every position and one with a window")
+        if model.pageless_context is not None:
+            raise ValueError("cache_kinds with a pageless cache")
+        if int(config.spec_decode_draft_len) > 0:
+            raise ValueError(
+                "spec_decode_draft_len > 0 with a model that holds an "
+                "ageing kind of paged state: a draft shares the target's "
+                "tables, and a rejected position's window page may be gone")
+        if config.window_blocks < 2:
+            raise ValueError(
+                f"the model's {names[1]!r} kind needs a pool: "
+                f"EngineConfig.window_blocks >= 2")
+        self._kind_names = tuple(names)
+        self._wbm = WindowBlockManager(config.window_blocks,
+                                       config.block_size, int(windows[1]))
+        super().__init__(config, model, params, **kwargs)
+
+    def _fresh_cache(self, model):
+        cfg = self.config
+        return model.paged_cache(
+            self._bm.num_blocks, cfg.block_size, self._mesh, cfg.batch_slots,
+            kinds={self._kind_names[1]: self._wbm.num_blocks})
+
+    def _new_prefix_cache(self) -> RadixPrefixCache:
+        return WindowedRadixCache(self._bm, self._wbm)
+
+    def _window_claim(self, req: Request, num_tokens: int,
+                      preempt: bool) -> bool:
+        """The window kind's part of a claim: give back what lies behind
+        the window of the first position still to be written, then grow
+        the table; cold cached tails go before anybody is preempted."""
+        wbm, rid = self._wbm, req.request_id
+        wbm.release_below(rid, req.processed // wbm.block_size - wbm.tail)
+        while not wbm.ensure(rid, num_tokens):
+            deficit = (wbm.blocks_for_tokens(num_tokens)
+                       - len(wbm.block_table(rid)) - wbm.num_free())
+            if (self._prefix is not None
+                    and self._prefix.evict_for(deficit, window=True) > 0):
+                continue
+            if not preempt or not self._preempt_one():
+                return False
+            if req.state == WAITING:
+                return False
+        return True
+
+    def _ensure_blocks(self, req: Request, num_tokens: int,
+                       preempt: bool = True) -> bool:
+        return super()._ensure_blocks(req, num_tokens, preempt) \
+            and self._window_claim(req, num_tokens, preempt)
+
+    def _block_table_rows(self, reqs) -> Dict[str, Any]:
+        import numpy as np
+
+        full, window = self._kind_names
+        out = {full: super()._block_table_rows(reqs),
+               window: np.zeros((len(reqs), self._table_width), np.int32)}
+        for i, req in enumerate(reqs):
+            if req is None or req.done or req.state == WAITING:
+                continue
+            table = self._wbm.block_table(req.request_id)
+            out[window][i, :len(table)] = table
+        return out
+
+    def _admit_blocks(self, req: Request) -> Optional[int]:
+        matched_tokens = super()._admit_blocks(req)
+        if matched_tokens is None:
+            return None
+        cfg, wbm, rid = self.config, self._wbm, req.request_id
+        if matched_tokens:
+            wbm.register_with_blocks(
+                rid, self._prefix.window_table(req._pinned_node))
+        else:
+            wbm.register(rid)
+        adopted = wbm.pages_held(rid)
+        req.processed = matched_tokens         # where its window ends
+        first = min(req.total_to_prefill,
+                    matched_tokens + cfg.prefill_chunk)
+        if not self._window_claim(req, first, preempt=False):
+            req.processed = 0
+            self._unpin_req(req)
+            self._release(rid)
+            return None
+        req.cached_window_tokens += cfg.block_size * adopted
+        return matched_tokens
+
+    def _release(self, request_id: str) -> None:
+        super()._release(request_id)
+        self._wbm.free(request_id)
+
+    def _donate(self, req: Request, tokens: List[int], nb: int) -> None:
+        self._prefix.insert(tokens,
+                            self._bm.block_table(req.request_id)[:nb],
+                            self._wbm.block_table(req.request_id)[:nb])
+
+    def _adoption_attrs(self, req: Request) -> Dict[str, Any]:
+        full, window = self._kind_names
+        return {"adopted_tokens": {full: req.cached_tokens,
+                                   window: req.cached_window_tokens}}
+
+    def _stats_locked(self) -> Dict[str, Any]:
+        return {**super()._stats_locked(), "kv_kinds": self._kinds_stats()}
+
+    def _kinds_stats(self) -> Dict[str, Any]:
+        """`stats()["kv_kinds"]`: a pool a kind. `cached`: the blocks the
+        radix cache holds of it; the window kind also says the pages given
+        back behind a window (cumulative: its readers difference two
+        reads) and the lookups that gave up matched blocks for want of
+        window pages."""
+        full, window = self._kind_names
+        prefix = self._prefix.stats() if self._prefix is not None else {}
+        out = {}
+        for name, bm, cached in (
+                (full, self._bm, prefix.get("cached_blocks", 0)),
+                (window, self._wbm, prefix.get("cached_window_blocks", 0))):
+            st = bm.stats()
+            out[name] = {"blocks": st["num_blocks"],
+                         "in_use": st["blocks_in_use"],
+                         "peak": st["peak_blocks_in_use"],
+                         "free": st["blocks_free"], "cached": cached}
+        out[window].update(
+            window=self._wbm.window, tail_blocks=self._wbm.tail,
+            window_blocks_released=self._wbm.released,
+            adoptions_refused=prefix.get("window_adoptions_refused", 0))
+        return out
+
+    def check_no_leaks(self):
+        super().check_no_leaks()
+        with self._lock:
+            self._wbm.check_consistency()
+            cached = (self._prefix.cached_window_blocks()
+                      if self._prefix is not None else 0)
+            assert self._wbm.blocks_in_use() == cached, (
+                self._wbm.stats(), cached)
 
 
 class EngineLoop:

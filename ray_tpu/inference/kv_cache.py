@@ -451,3 +451,284 @@ class RadixPrefixCache:
             "inserted_blocks": self.inserted_blocks,
             "evicted_blocks": self.evicted_blocks,
         }
+
+
+# --------------------------------------------------------------------------- #
+# A second kind of paged state whose pages AGE OUT (docs/INFERENCE.md (j))
+# --------------------------------------------------------------------------- #
+
+
+def window_tail_blocks(window: int, block_size: int) -> int:
+    """The blocks before a block boundary that the radix cache keeps of a
+    kind whose queries see their last `window` keys, their own among them:
+    ceil((window - 1) / block_size) + 1. The first term is what a query AT
+    the boundary, and every later one, may still read; the one more is
+    what a query in the LAST block before the boundary reads, so that the
+    positions of a cached prefix's last block can be run again over the
+    cache as it is (a check that replays them; a sequence that adopts a
+    boundary and is cut back inside its last block)."""
+    return max(0, -(-(int(window) - 1) // int(block_size))) + 1
+
+
+class WindowBlockManager(BlockManager):
+    """The pool of a kind of paged state of which a query reads only its
+    last `window` positions. A table is as long as its sequence in blocks,
+    like the main pool's, but an entry may be TRASH_BLOCK: a page behind
+    the window that was given back (`release_below`), or one an adopter
+    never held. Only the entries at or after the sequence's window are
+    ever read (`ops/latent_attention.py`'s lower bound)."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int):
+        super().__init__(num_blocks, block_size)
+        self.window = int(window)
+        self.tail = window_tail_blocks(window, block_size)
+        self.released = 0            # pages given back behind a window
+
+    def register_with_blocks(self, seq_id: str, blocks: List[int]) -> None:
+        if seq_id in self._tables:
+            raise ValueError(f"sequence {seq_id!r} already registered")
+        for blk in blocks:
+            if blk != TRASH_BLOCK and blk not in self._ref:
+                raise ValueError(f"block {blk} is not live")
+        for blk in blocks:
+            if blk != TRASH_BLOCK:
+                self._ref[blk] += 1
+        self._tables[seq_id] = list(blocks)
+
+    def _drop(self, blk: int) -> int:
+        self._ref[blk] -= 1
+        if self._ref[blk]:
+            return 0
+        del self._ref[blk]
+        self._free.append(blk)
+        return 1
+
+    def free(self, seq_id: str) -> int:
+        table = self._tables.pop(seq_id, None)
+        return sum(self._drop(blk) for blk in table or ()
+                   if blk != TRASH_BLOCK)
+
+    def release_below(self, seq_id: str, first_kept: int) -> int:
+        """Give back the pages of logical blocks below `first_kept`.
+        Returns how many the sequence let go of (a page the radix cache
+        also holds stays with it)."""
+        table = self._tables[seq_id]
+        let_go = 0
+        for i in range(min(first_kept, len(table))):
+            if table[i] != TRASH_BLOCK:
+                self._drop(table[i])
+                table[i] = TRASH_BLOCK
+                let_go += 1
+        self.released += let_go
+        return let_go
+
+    def pages_held(self, seq_id: str) -> int:
+        return sum(blk != TRASH_BLOCK for blk in self._tables[seq_id])
+
+    def check_consistency(self) -> None:
+        counts: Dict[int, int] = {}
+        for table in self._tables.values():
+            for blk in table:
+                if blk != TRASH_BLOCK:
+                    counts[blk] = counts.get(blk, 0) + 1
+        assert counts == self._ref, (counts, self._ref)
+        free = set(self._free)
+        assert len(free) == len(self._free), "duplicate free blocks"
+        assert not (free & set(self._ref)), "block both free and referenced"
+        assert TRASH_BLOCK not in free and TRASH_BLOCK not in self._ref
+        assert len(free) + len(self._ref) == self.capacity
+
+    def stats(self) -> Dict[str, int]:
+        return {**super().stats(), "window": self.window,
+                "tail_blocks": self.tail,
+                "window_blocks_released": self.released}
+
+
+class _WindowedNode(_RadixNode):
+    """A radix node that may also hold the window kind's pages of the
+    `len(wtail)` blocks before its END (its own blocks or its ancestors':
+    the list is self-contained), under `wseq_id` in the window pool; None
+    where it holds none (the top half of a split: nobody adopts there).
+    `depth` is the node's end in blocks from the root."""
+
+    __slots__ = ("wtail", "wseq_id", "depth")
+
+
+class WindowedRadixCache(RadixPrefixCache):
+    """The radix prefix cache of a model with an AGEING kind of paged
+    state beside the main one. A cached prefix keeps every main-kind block,
+    as before, and of the window kind only the `tail` blocks before a
+    node's end: what a query at that boundary, and every later one, can
+    still read. So a prefix is ADOPTED only at the end of a node that holds
+    its tail: a match that ends inside a node, or at a node without one,
+    falls back to the deepest ancestor's end that has it (`window_refused`
+    counts the lookups that gave up matched blocks for it), and a match
+    never splits a node (the top half would hold no tail). A donor's
+    insert that diverges inside a node does split it: the bottom half ends
+    where the window pages end and keeps them; the top half is given a
+    tail when a later donor ends exactly there."""
+
+    def __init__(self, bm: BlockManager, wbm: WindowBlockManager):
+        super().__init__(bm)
+        self._wbm = wbm
+        self._root = _WindowedNode((), [], None)
+        self._root.wtail, self._root.wseq_id, self._root.depth = None, None, 0
+        self.window_refused = 0
+        self.evicted_window_blocks = 0
+
+    # ------------------------------------------------------------- helpers
+
+    def _attach(self, node: _WindowedNode, wtable: List[int]) -> None:
+        """Give `node` the window pages of the tail before its end out of
+        a donor's window table (one entry a logical block), if the donor
+        still holds them all."""
+        tail = wtable[max(0, node.depth - self._wbm.tail):node.depth]
+        if node.wtail is not None or not tail or TRASH_BLOCK in tail:
+            return
+        node.wseq_id = f"~wradix{next(self._ids)}"
+        self._wbm.register_with_blocks(node.wseq_id, tail)
+        node.wtail = list(tail)
+
+    def _new_node(self, key, blocks, parent, wtable=None) -> _WindowedNode:
+        node = _WindowedNode(tuple(key), list(blocks), parent)
+        node.seq_id = f"~radix{next(self._ids)}"
+        self._bm.register_with_blocks(node.seq_id, node.blocks)
+        node.last_used = next(self._clock)
+        node.wtail, node.wseq_id = None, None
+        node.depth = parent.depth + len(node.key)
+        parent.children[node.key[0]] = node
+        self._cached_blocks += len(node.blocks)
+        if wtable is not None:
+            self._attach(node, wtable)
+        return node
+
+    def _split(self, child: _WindowedNode, m: int) -> _WindowedNode:
+        assert 0 < m < len(child.key)
+        parent = child.parent
+        top = _WindowedNode(child.key[:m], child.blocks[:m], parent)
+        top.seq_id = f"~radix{next(self._ids)}"
+        self._bm.register_with_blocks(top.seq_id, top.blocks)
+        top.wtail, top.wseq_id = None, None
+        top.depth = child.depth - (len(child.key) - m)
+        bottom_id = f"~radix{next(self._ids)}"
+        self._bm.register_with_blocks(bottom_id, child.blocks[m:])
+        self._bm.free(child.seq_id)
+        parent.children[top.key[0]] = top
+        child.key = child.key[m:]
+        child.blocks = child.blocks[m:]
+        child.seq_id = bottom_id
+        child.parent = top
+        top.children = {child.key[0]: child}
+        top.last_used = next(self._clock)
+        return top
+
+    # ----------------------------------------------------------- interface
+
+    def match(self, tokens: List[int]):
+        """Longest cached prefix of `tokens` that ends where window pages
+        are held: (main-kind blocks, that node). The window table an
+        adopter registers is `window_table(node)`."""
+        syms = self._symbols(tokens)
+        self.lookups += 1
+        node, blocks, i = self._root, [], 0
+        best, best_blocks, reached = None, 0, 0
+        while i < len(syms):
+            child = node.children.get(syms[i])
+            if child is None:
+                break
+            m = 0
+            while (m < len(child.key) and i + m < len(syms)
+                   and child.key[m] == syms[i + m]):
+                m += 1
+            reached = i + m
+            if m < len(child.key):
+                break
+            blocks.extend(child.blocks)
+            child.last_used = next(self._clock)
+            node = child
+            i += len(child.key)
+            if child.wtail is not None or child.depth == 0:
+                best, best_blocks = child, len(blocks)
+        self.window_refused += reached > best_blocks
+        if best is None:
+            return [], None
+        self.hits += 1
+        self.hit_tokens += best_blocks * self._bm.block_size
+        return blocks[:best_blocks], best
+
+    def window_table(self, node: _WindowedNode) -> List[int]:
+        """The window-kind table of a sequence that adopts at `node`'s
+        end: nothing below the tail, the node's pages in it."""
+        return [TRASH_BLOCK] * (node.depth - len(node.wtail)) \
+            + list(node.wtail)
+
+    def insert(self, tokens: List[int], blocks: List[int],
+               wtable: Optional[List[int]] = None) -> int:
+        syms = self._symbols(tokens)
+        assert len(syms) == len(blocks), (len(syms), len(blocks))
+        node, i = self._root, 0
+        while i < len(syms):
+            child = node.children.get(syms[i])
+            if child is None:
+                new = self._new_node(syms[i:], blocks[i:], node, wtable)
+                self.inserted_blocks += len(new.blocks)
+                return len(new.blocks)
+            m = 0
+            while (m < len(child.key) and i + m < len(syms)
+                   and child.key[m] == syms[i + m]):
+                m += 1
+            if m < len(child.key):
+                child = self._split(child, m)
+            child.last_used = next(self._clock)
+            node = child
+            i += len(child.key)
+        if wtable is not None and node is not self._root:
+            self._attach(node, wtable)     # a split's top half, made whole
+        return 0
+
+    def evict_for(self, need_blocks: int, window: bool = False) -> int:
+        """As the base class's; with `window` the pages counted are those
+        that went back to the WINDOW pool."""
+        freed = 0
+        while freed < need_blocks:
+            leaves = [n for n in self._nodes()
+                      if not n.children and n.pins == 0]
+            if not leaves:
+                break
+            victim = min(leaves, key=lambda n: n.last_used)
+            before = self._wbm.num_free()
+            main = self._remove(victim)
+            freed += self._wbm.num_free() - before if window else main
+        return freed
+
+    def _remove(self, node: _WindowedNode) -> int:
+        if node.wseq_id is not None:
+            self.evicted_window_blocks += self._wbm.free(node.wseq_id)
+        return super()._remove(node)
+
+    def clear(self) -> int:
+        for node in self._nodes():
+            if node.wseq_id is not None:
+                self._wbm.free(node.wseq_id)
+        return super().clear()
+
+    def cached_window_blocks(self) -> int:
+        """DISTINCT window pages the tree holds: a node's tail may reach
+        into its ancestors' blocks, whose pages it then shares with them."""
+        return len({blk for node in self._nodes()
+                    for blk in node.wtail or ()})
+
+    def check_consistency(self) -> None:
+        super().check_consistency()
+        for node in self._nodes():
+            assert node.depth == node.parent.depth + len(node.key), node
+            if node.wtail is None:
+                continue
+            assert self._wbm.block_table(node.wseq_id) == node.wtail
+            assert len(node.wtail) == min(self._wbm.tail, node.depth)
+
+    def stats(self) -> Dict[str, Any]:
+        return {**super().stats(),
+                "cached_window_blocks": self.cached_window_blocks(),
+                "evicted_window_blocks": self.evicted_window_blocks,
+                "window_adoptions_refused": self.window_refused}

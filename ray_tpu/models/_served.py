@@ -53,6 +53,17 @@ class PagedModel:
     # (i)) and hands `paged_step` `read_from` [b], where in its row the
     # block begins whose logits are read (logits[b, length, vocab]).
     decode_block = None
+    # Of a model whose cache holds more than one KIND of paged state: an
+    # ordered {kind: {"window": None | w}}. The first kind keeps every
+    # position (its table bounds a sequence); a kind with a `window` is
+    # read for a query's last w positions alone, its own among them. Read
+    # once, when the engine is built; it then keeps a pool and a block
+    # table a kind (`EngineConfig.window_blocks`), hands `paged_cache`
+    # `kinds={kind: blocks}` for every kind but the first and `paged_step`
+    # a dict of tables by kind, gives back the pages behind a live
+    # sequence's window and keeps, for a cached prefix, the windowed
+    # kind's last pages alone (docs/INFERENCE.md finding (j)).
+    cache_kinds = None
     # `cache_counters(cache)`: the part of the cache the host may read when
     # `stats()` is asked, small device arrays; `counter_stats` turns a host
     # copy of them into entries of `stats()` (docs/INFERENCE.md finding

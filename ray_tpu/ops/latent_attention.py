@@ -83,17 +83,21 @@ KERNELS = ("latent_decode", "latent_prefill")
 
 
 def latent_attention_reference(q, arena, block_tables, positions, *,
-                               latent: int, scale: float):
+                               latent: int, scale: float, window=None):
     """The dense definition: gather each row's whole logical context out
-    of the arena, float32 scores over the page's full width, mask,
-    softmax, values = the first `latent` lanes. q [b, s, heads, width];
-    returns [b, s, heads, latent] in q's dtype."""
+    of the arena, float32 scores over the page's full width, mask (causal,
+    and with `window` the last `window` keys alone), softmax, values = the
+    first `latent` lanes. q [b, s, heads, width]; returns [b, s, heads,
+    latent] in q's dtype."""
     nb, bsz, width = arena.shape
     max_ctx = block_tables.shape[1] * bsz
     slot = (block_tables * bsz)[:, :, None] + jnp.arange(bsz)[None, None, :]
     ctx = arena.reshape(nb * bsz, width)[slot.reshape(-1, max_ctx)]
     ctx = ctx.astype(jnp.float32)                         # [b, ctx, width]
-    mask = jnp.arange(max_ctx)[None, None, :] <= positions[:, :, None]
+    k_pos = jnp.arange(max_ctx)[None, None, :]
+    mask = k_pos <= positions[:, :, None]
+    if window is not None:
+        mask &= k_pos > positions[:, :, None] - window
     scores = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32), ctx,
                         precision=jax.lax.Precision.HIGHEST) * scale
     scores = jnp.where(mask[:, None], scores, _NEG_INF)
@@ -108,12 +112,12 @@ def latent_attention_reference(q, arena, block_tables, positions, *,
 
 
 # Rows of the walk array (scalar prefetch, one column a grid step).
-_HI, _PLAIN, _BEFORE, _NEXT = range(4)
+_HI, _PLAIN, _BEFORE, _NEXT, _BASE = range(5)
 WALK_COUNTS = ("tiles", "tiles_walked", "kv_chunks", "kv_chunks_masked")
 
 
 def tile_walk(positions, write_mask, *, heads: int, block_size: int,
-              max_ctx: int, dtype):
+              max_ctx: int, dtype, window=None):
     """THE TILE RULE: what the kernel walks for queries at `positions`
     [b, s] of which `write_mask` marks the live ones. Returns
 
@@ -131,6 +135,12 @@ def tile_walk(positions, write_mask, *, heads: int, block_size: int,
         walk, the chunks they copy and multiply, and of those the ones
         that run the masked body.
 
+    With `window` (a query sees its last `window` keys alone) a walk has a
+    LOWER bound too: a fifth row `_BASE`, the first token of the page that
+    holds the oldest key the tile's first live query may see; pages wholly
+    below it are neither copied nor multiplied, chunks are counted from
+    it, and every chunk runs the masked body (`_PLAIN` 0).
+
     The wrapper builds the kernel's scalars from it and a model counts a
     step's walk with it: one definition."""
     b, s = positions.shape
@@ -146,15 +156,23 @@ def tile_walk(positions, write_mask, *, heads: int, block_size: int,
     by_tile = q_pos.reshape(b * tiles, rows)
     hi = jnp.clip(by_tile.max(axis=-1) + 1, 0, max_ctx)
     first = jnp.where(by_tile < 0, max_ctx, by_tile).min(axis=-1)
-    plain = jnp.minimum(first + 1, hi) // chunk
-    chunks = (hi + chunk - 1) // chunk
+    if window is None:
+        plain = jnp.minimum(first + 1, hi) // chunk
+        chunks = (hi + chunk - 1) // chunk
+    else:
+        base = jnp.clip(first - (window - 1), 0, max_ctx) \
+            // block_size * block_size
+        base = jnp.minimum(base, hi // block_size * block_size)
+        plain = jnp.zeros_like(hi)
+        chunks = (hi - base + chunk - 1) // chunk
     before = jnp.cumsum(chunks) - chunks
     step = jnp.arange(b * tiles, dtype=jnp.int32)
     later = jnp.where(chunks > 0, step, b * tiles)
     nxt = jnp.flip(jax.lax.cummin(jnp.flip(later)))        # at or after
     nxt = jnp.concatenate([nxt[1:], jnp.full((1,), b * tiles, jnp.int32)])
     nxt = jnp.where(nxt < b * tiles, nxt, -1)
-    walk = jnp.stack([hi, plain, before, nxt]).astype(jnp.int32)
+    rows_ = [hi, plain, before, nxt] + ([] if window is None else [base])
+    walk = jnp.stack(rows_).astype(jnp.int32)
     counts = (jnp.int32(b * tiles), jnp.sum(chunks > 0, dtype=jnp.int32),
               jnp.sum(chunks, dtype=jnp.int32),
               jnp.sum(chunks - plain, dtype=jnp.int32))
@@ -162,7 +180,8 @@ def tile_walk(positions, write_mask, *, heads: int, block_size: int,
 
 
 def _kernel(walk_ref, bt_ref, q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems,
-            m_scr, l_scr, acc_scr, *, scale: float, latent: int):
+            m_scr, l_scr, acc_scr, *, scale: float, latent: int,
+            window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -174,19 +193,25 @@ def _kernel(walk_ref, bt_ref, q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems,
     n_plain = walk_ref[_PLAIN, step]
     before = walk_ref[_BEFORE, step]
     nxt = walk_ref[_NEXT, step]
-    n_chunks = (hi + chunk - 1) // chunk
+    # A windowed walk begins at `base` (a page's first token), not at 0.
+    base = None if window is None else walk_ref[_BASE, step]
+    span = hi if window is None else hi - base
+    n_chunks = (span + chunk - 1) // chunk
 
-    def for_live_pages(row, hi, c, slot, do):
-        """`do(copy)` for every live page of chunk c of a walk of `row` to
-        `hi` into `slot`: ONE copy a page, which serves the scores and the
-        values."""
+    def for_live_pages(row, span, c, slot, do, base=None):
+        """`do(copy)` for every live page of chunk c of a walk of `row`
+        over `span` tokens (from `base`, or from 0) into `slot`: ONE copy a
+        page, which serves the scores and the values."""
         def body(p, carry):
-            phys = bt_ref[row, c * pages + p]
+            page = c * pages + p
+            if base is not None:
+                page = base // block_size + page
+            phys = bt_ref[row, page]
             do(pltpu.make_async_copy(kv_hbm.at[phys], kv_buf.at[slot, p],
                                      sems.at[slot]))
             return carry
 
-        live = (jnp.minimum(hi - c * chunk, chunk) + block_size - 1) \
+        live = (jnp.minimum(span - c * chunk, chunk) + block_size - 1) \
             // block_size
         jax.lax.fori_loop(0, live, body, 0)
 
@@ -201,32 +226,44 @@ def _kernel(walk_ref, bt_ref, q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems,
     # later one finds it started under its predecessor's last chunk.
     @pl.when((n_chunks > 0) & (before == 0))
     def _():
-        for_live_pages(row, hi, 0, 0, start)
+        for_live_pages(row, span, 0, 0, start, base)
 
     def chunk_step(c, masked: bool):
         slot = (before + c) % 2
 
         @pl.when(c + 1 < n_chunks)
         def _():
-            for_live_pages(row, hi, c + 1, 1 - slot, start)
+            for_live_pages(row, span, c + 1, 1 - slot, start, base)
 
         @pl.when((c + 1 == n_chunks) & (nxt >= 0))
         def _():
-            for_live_pages(nxt // pl.num_programs(1), walk_ref[_HI, nxt], 0,
-                           1 - slot, start)
+            if window is None:
+                for_live_pages(nxt // pl.num_programs(1),
+                               walk_ref[_HI, nxt], 0, 1 - slot, start)
+            else:
+                for_live_pages(nxt // pl.num_programs(1),
+                               walk_ref[_HI, nxt] - walk_ref[_BASE, nxt], 0,
+                               1 - slot, start, walk_ref[_BASE, nxt])
 
-        for_live_pages(row, hi, c, slot, lambda copy: copy.wait())
+        for_live_pages(row, span, c, slot, lambda copy: copy.wait(), base)
         kv = kv_buf[slot].reshape(chunk, width)
         if masked:
             q_pos = qpos_ref[0]                              # [rows, 1]
             k_pos = c * chunk + jax.lax.broadcasted_iota(
                 jnp.int32, (q_pos.shape[0], chunk), 1)
+            if window is not None:
+                k_pos = base + k_pos
             mask = (k_pos <= q_pos) & (k_pos < hi)
+            if window is not None:
+                mask &= k_pos > q_pos - window
             # Rows of the buffer at or past `hi` hold whatever was there;
             # they are the VALUES too, so they are zeroed, or 0 x NaN gets
             # in.
-            kv_live = c * chunk + jax.lax.broadcasted_iota(
-                jnp.int32, (chunk, width), 0) < hi
+            kv_at = c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (chunk, width), 0)
+            if window is not None:
+                kv_at = base + kv_at
+            kv_live = kv_at < hi
             kv = jnp.where(kv_live, kv, jnp.zeros_like(kv))
         s = jax.lax.dot_general(
             q_ref[0], kv, (((1,), (1,)), ((), ())),
@@ -270,11 +307,11 @@ def _tiles(n_rows: int, block_size: int, dtype) -> tuple:
     return rows, max(1, chunk // block_size)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("latent", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("latent", "scale", "interpret",
+                                             "window"))
 def _latent_attention_pallas(q, arena, block_tables, positions, write_mask,
                              *, latent: int, scale: float,
-                             interpret: bool = False):
+                             interpret: bool = False, window=None):
     # Jitted on its own so that a model's layers share one trace and one
     # lowering of the kernel (ops/paged_attention.py says what that saved).
     from jax.experimental import pallas as pl
@@ -286,13 +323,15 @@ def _latent_attention_pallas(q, arena, block_tables, positions, write_mask,
     rows, pages = _tiles(n_rows, bsz, q.dtype)
     q_pos, walk, _ = tile_walk(
         positions, write_mask, heads=heads, block_size=bsz,
-        max_ctx=block_tables.shape[1] * bsz, dtype=q.dtype)
+        max_ctx=block_tables.shape[1] * bsz, dtype=q.dtype,
+        **({} if window is None else {"window": window}))
     n_tiles = q_pos.shape[1] // rows
     qr = jnp.pad(q.reshape(b, n_rows, width),
                  ((0, 0), (0, n_tiles * rows - n_rows), (0, 0)))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, latent=latent),
+        functools.partial(_kernel, scale=scale, latent=latent,
+                          **({} if window is None else {"window": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_tiles),
@@ -368,7 +407,7 @@ def latent_attention_status() -> list:
 
 
 def latent_attention(q, arena, block_tables, positions, write_mask=None, *,
-                     latent: int, scale: float) -> jax.Array:
+                     latent: int, scale: float, window=None) -> jax.Array:
     """Absorbed latent attention of q [b, s, heads, latent + rope] (the
     absorbed query beside the rotated rope query) over the paged latent
     cache `arena` [num_blocks, block_size, width] AS IT IS AFTER this
@@ -380,7 +419,12 @@ def latent_attention(q, arena, block_tables, positions, write_mask=None, *,
     reads a row's pages only up to its last such query, a query tile at a
     time (`tile_walk`): an idle slot reads nothing and gets zeros; a masked
     query's output is finite and otherwise unspecified, on either path, and
-    on the kernel's zero where its whole tile is masked."""
+    on the kernel's zero where its whole tile is masked.
+
+    `window` (None: every earlier key) keeps a query to its last `window`
+    keys, its own among them: the table's entries for pages wholly behind
+    a row's window are never read, so they may name any block (the engine
+    gives such pages back: docs/INFERENCE.md finding (j))."""
     width = arena.shape[-1]
     if q.shape[-1] > width:
         raise ValueError(f"q is {q.shape[-1]} wide, a page {width}")
@@ -390,6 +434,8 @@ def latent_attention(q, arena, block_tables, positions, write_mask=None, *,
     if _dispatch(q, arena, latent):
         return _latent_attention_pallas(
             q, arena, block_tables, positions, write_mask, latent=latent,
-            scale=float(scale), interpret=_attn._interpret())
+            scale=float(scale), interpret=_attn._interpret(),
+            **({} if window is None else {"window": int(window)}))
     return latent_attention_reference(q, arena, block_tables, positions,
-                                      latent=latent, scale=scale)
+                                      latent=latent, scale=scale,
+                                      window=window)

@@ -29,7 +29,8 @@ SERVED = {"llama_serve": ("llama", "Llama", "llama_config"),
           "brumby_serve": ("brumby", "Brumby", "model_config"),
           "kanana2_serve": ("deepseek_v3", "DeepseekV3", "model_config"),
           "ouro_serve": ("ouro", "Ouro", "model_config"),
-          "sdar_serve": ("sdar", "SDAR", "model_config")}
+          "sdar_serve": ("sdar", "SDAR", "model_config"),
+          "dots3_serve": ("dots3", "Dots3", "model_config")}
 
 
 def served_programs(config):
