@@ -25,8 +25,9 @@ Two forward paths share parameters:
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, replace
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -91,24 +92,64 @@ def llama_preset(name: str, seq: int = 256) -> LlamaConfig:
     return presets[name]()
 
 
+class _Rows(NamedTuple):
+    """One ROW GROUP of a step: b sequences of s positions each (a decode
+    step's [slots, 1], a prefill chunk's [1, c]) and what belongs to them
+    as sequences: where they write and what they attend."""
+    block_tables: Any
+    positions: Any
+    write_mask: Any
+
+
+def _cut(t, groups):
+    """t [b, s, ...] of one group as it is, or [1, T, ...] of several laid
+    end to end cut into each group's [b, s, ...]: slices of rows, the
+    products before them run once over all T."""
+    if len(groups) == 1:
+        return [t]
+    out, start = [], 0
+    for rows in groups:
+        b, s = rows.positions.shape
+        out.append(t[0, start:start + b * s].reshape(b, s, *t.shape[2:]))
+        start += b * s
+    return out
+
+
+def _joined(parts):
+    """The groups' [b, s, n] laid end to end again, [1, T, n]."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate([p.reshape(1, -1, p.shape[-1]) for p in parts],
+                           axis=1)
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Tuple] = None,
                  lora: Optional[Tuple] = None):
-        """cache=None: full causal forward; returns (x, None, side).
-        cache=(k_arena, v_arena, block_tables, write_mask) with arenas
-        [num_blocks, block_size, kv_heads, head_dim]: this call's K/V
-        land at the physical slot the row's block table maps each
-        position to (masked-off tokens go to trash block 0), reads are
-        `paged_attention` over the arena as the writes left it: each row
-        sees its logical positions <= the query's, out of the blocks its
-        table maps, and only those below its live length are touched.
+        """cache=None: full causal forward at `positions`; returns (x,
+        None, side).
+        cache=(k_arena, v_arena, groups) with arenas [num_blocks,
+        block_size, kv_heads, head_dim] and `groups` the step's ROW GROUPS
+        (`_Rows`, which state their own positions: `positions` is not
+        looked at). One group is a decode step or a prefill chunk, x [b, s,
+        embd]; several are laid end to end, x [1, T, embd], through
+        everything that is a token's own (the norms and every product: T
+        rows against one read of the weights) and cut apart for what
+        belongs to a sequence, a `paged_write_and_attend` a group at its
+        own program's shape, a later group over the arenas an earlier one
+        left. A group's K/V land at the physical slot the row's block
+        table maps each position to (masked-off tokens go to trash block
+        0), reads are `paged_attention` over the arena as the writes left
+        it: each row sees its logical positions <= the query's, out of the
+        blocks its table maps, and only those below its live length are
+        touched. Returns (x, (k_arena, v_arena), side).
 
         lora=(aq, bq, ao, bo, adapter_idx): model-multiplexed low-rank
-        LATE-FUSION deltas (ladder-style side adapter). The block reads
-        two backbone taps — the attn-normed input (aq/bq) and the
+        LATE-FUSION deltas (ladder-style side adapter), for a step of ONE
+        group. The block reads two backbone taps — the attn-normed input (aq/bq) and the
         flattened attention mixer output (ao/bo) — and returns their
         low-rank projection as a SIDE contribution instead of adding it
         to the residual stream; the caller accumulates the per-layer
@@ -126,21 +167,31 @@ class LlamaBlock(nn.Module):
         recompiles."""
         cfg = self.cfg
         hd = cfg.head_dim
-        b, s, _ = x.shape
+        groups = [_Rows(None, positions, None)] if cache is None \
+            else cache[2]
+
+        def heads(t, n):
+            return [p.reshape(*p.shape[:2], n, hd).transpose(0, 2, 1, 3)
+                    for p in _cut(t, groups)]
+
         h = RMSNorm(cfg, name="attn_norm")(x)
         q = dense(cfg.n_head * hd, ("embed", "heads"), cfg, "wq")(h)
         k = dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wk")(h)
         v = dense(cfg.n_kv_head * hd, ("embed", "heads"), cfg, "wv")(h)
-        q = q.reshape(b, s, cfg.n_head, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, cfg.n_kv_head, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, cfg.n_kv_head, hd).transpose(0, 2, 1, 3)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        # (the three products, then the three layouts, then the rotary: the
+        # one-group programs' lowered text is held to what it was)
+        q, k, v = heads(q, cfg.n_head), heads(k, cfg.n_kv_head), \
+            heads(v, cfg.n_kv_head)
+        q = [apply_rope(t, rows.positions, cfg.rope_theta)
+             for t, rows in zip(q, groups)]
+        k = [apply_rope(t, rows.positions, cfg.rope_theta)
+             for t, rows in zip(k, groups)]
 
         if cache is None:
-            groups = cfg.n_head // cfg.n_kv_head
-            kf = jnp.repeat(k, groups, axis=1)
-            vf = jnp.repeat(v, groups, axis=1)
+            (q,), (k,), (v,) = q, k, v
+            kv_groups = cfg.n_head // cfg.n_kv_head
+            kf = jnp.repeat(k, kv_groups, axis=1)
+            vf = jnp.repeat(v, kv_groups, axis=1)
             if cfg.sp_mesh is not None:
                 from ray_tpu.ops.ring_attention import ring_attention_sharded
 
@@ -155,14 +206,19 @@ class LlamaBlock(nn.Module):
                         ("batch", "heads", None, None)))
             else:
                 attn = mha_reference(q, kf, vf, causal=True)
-            new_cache = None
+            attn, new_cache = [attn], None
         else:
-            k_arena, v_arena, block_tables, write_mask = cache
-            attn, k_arena, v_arena = paged_write_and_attend(
-                q, k, v, k_arena, v_arena, block_tables, positions,
-                write_mask)
-            new_cache = (k_arena, v_arena, block_tables, write_mask)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_head * hd)
+            k_arena, v_arena, _ = cache
+            attn = []
+            # A group at a time, each call at its own program's shape.
+            for rows, qr, kr, vr in zip(groups, q, k, v):
+                out, k_arena, v_arena = paged_write_and_attend(
+                    qr, kr, vr, k_arena, v_arena, rows.block_tables,
+                    rows.positions, rows.write_mask)
+                attn.append(out)
+            new_cache = (k_arena, v_arena)
+        attn = _joined([a.transpose(0, 2, 1, 3).reshape(
+            a.shape[0], a.shape[2], cfg.n_head * hd) for a in attn])
         out = dense(cfg.n_embd, ("heads", "embed"), cfg, "wo")(attn)
         side = None
         if lora is not None:
@@ -189,6 +245,21 @@ class LlamaBlock(nn.Module):
         x = x + dense(cfg.n_embd, ("mlp", "embed"), cfg, "w_down")(h2)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed")), \
             new_cache, side
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _block_traced_once(cfg, layer_params, x, k_arena, v_arena, groups):
+    """`LlamaBlock` over several row groups as a jitted function of one
+    layer's parameters: the layers are alike, so a program traces and
+    lowers the block ONCE and calls it a layer (XLA inlines the calls: the
+    executable is the same). The fused step is a THIRD program a replica
+    loads before it serves, and this takes 1.2 s of tracing and lowering
+    sixteen layers off that (PERF.md section 6, PR 67). The one-group
+    programs call the blocks as they always have: their lowered text is
+    held to what it was."""
+    x, cache, _ = LlamaBlock(cfg, parent=None).apply(
+        {"params": layer_params}, x, None, cache=(k_arena, v_arena, groups))
+    return x, cache
 
 
 class Llama(nn.Module, PagedModel):
@@ -250,10 +321,55 @@ class Llama(nn.Module, PagedModel):
         `last_idx` [b] asks for logits at one position a row only: the
         hidden state is gathered there BEFORE the final norm and the
         head, which then run on [b, embd] (returns logits [b, vocab])."""
+        def read(x):
+            if last_idx is None:
+                return x
+            return jnp.take_along_axis(x, last_idx[:, None, None],
+                                       axis=1)[:, 0]
+
+        return self._step(
+            arenas, [(input_ids, block_tables, row_pos, write_mask)], read,
+            lora_banks, adapter_idx)
+
+    def decode_paged_with_chunk(self, tokens, chunk_ids, arenas,
+                                block_tables, row_pos, write_mask, chunk_bt,
+                                chunk_pos, chunk_wmask, last_idx):
+        """A decode step with one sequence's prefill chunk aboard:
+        `decode_paged` of tokens [b, 1] and `decode_paged` of chunk_ids
+        [1, c] at `last_idx` [1] as ONE execution, in which every weight
+        is read once for the c + b rows, only the attention is two calls
+        and the head runs on b + 1 rows. The chunk's rows lie first: the
+        engine masks the chunk's slot among the decode rows, so the two
+        groups write disjoint rows and either order is the same step, and
+        an operand whose first 512 rows are the chunk's read 0.2 ms under
+        the other order on the chip (PERF.md section 6, PR 67). Returns
+        (the decode rows' logits [b, vocab], the chunk's [1, vocab],
+        new_arenas)."""
+        b, c = tokens.shape[0], chunk_ids.shape[1]
+        logits, arenas = self._step(
+            arenas,
+            [(chunk_ids, chunk_bt, chunk_pos, chunk_wmask),
+             (tokens, block_tables, row_pos, write_mask)],
+            lambda x: jnp.concatenate([x[0, c:], x[0, last_idx]]))
+        return logits[:b], logits[b:], arenas
+
+    def _step(self, arenas, groups, read, lora_banks=None,
+              adapter_idx=None):
+        """The one body of a step over ROW GROUPS, each (ids [b, s],
+        block_tables, row_pos, write_mask): (the logits of the rows `read`
+        picks from the last block's x, new_arenas). One group is a decode
+        step or a prefill chunk, x [b, s, embd]; several are laid end to
+        end, x [1, T, embd] (`LlamaBlock`), and their layers are traced
+        once (`_block_traced_once`). The adapter banks are one group's."""
         cfg = self.config
-        b, s = input_ids.shape
-        x = self.embed.astype(cfg.dtype)[input_ids]
-        positions = row_pos[:, None] + jnp.arange(s)[None, :]  # [b, s]
+        several = len(groups) > 1
+        ids = jnp.concatenate([g[0].reshape(1, -1) for g in groups],
+                              axis=1) if several else groups[0][0]
+        x = self.embed.astype(cfg.dtype)[ids]
+        rows = [_Rows(block_tables,
+                      row_pos[:, None] + jnp.arange(g_ids.shape[1])[None, :],
+                      write_mask)
+                for g_ids, block_tables, row_pos, write_mask in groups]
         new_arenas = []
         side_sum = None
         for i, blk in enumerate(self.blocks):
@@ -262,17 +378,19 @@ class Llama(nn.Module, PagedModel):
             if lora_banks is not None:
                 aq, bq, ao, bo = lora_banks[i]
                 lora = (aq, bq, ao, bo, adapter_idx)
-            x, layer_cache, side = blk(
-                x, positions, cache=(k_a, v_a, block_tables, write_mask),
-                lora=lora)
+            if several:
+                x, layer_cache = _block_traced_once(
+                    cfg, blk.variables["params"], x, k_a, v_a, rows)
+                side = None
+            else:
+                x, layer_cache, side = blk(x, None, cache=(k_a, v_a, rows),
+                                           lora=lora)
             if side is not None:
                 side_sum = side if side_sum is None else side_sum + side
-            new_arenas.append((layer_cache[0], layer_cache[1]))
+            new_arenas.append(layer_cache)
         if side_sum is not None:
             x = x + side_sum.astype(x.dtype)
-        if last_idx is not None:
-            x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-        x = self.final_norm(x)
+        x = self.final_norm(read(x))
         return self.lm_head(x), new_arenas
 
     # What the serving engine asks of the model it is handed
@@ -303,6 +421,20 @@ class Llama(nn.Module, PagedModel):
         return self.apply(params, ids, cache, block_tables, row_pos,
                           write_mask, banks, adapter_idx, last_idx,
                           method=Llama.decode_paged)
+
+    @nn.nowrap
+    def paged_step_with_chunk(self, params, tokens, chunk_ids, cache,
+                              block_tables, row_pos, write_mask, chunk_bt,
+                              chunk_pos, chunk_wmask, chunk_slot, last_idx):
+        """A decode step with one sequence's prefill chunk aboard (the
+        contract's optional answer), `decode_paged_with_chunk`. The engine
+        masks the chunk's own slot among the decode rows and keeps the two
+        programs alone where it holds adapter banks or speculates.
+        `chunk_slot` is not looked at: nothing is kept per slot."""
+        return self.apply(params, tokens, chunk_ids, cache, block_tables,
+                          row_pos, write_mask, chunk_bt, chunk_pos,
+                          chunk_wmask, last_idx,
+                          method=Llama.decode_paged_with_chunk)
 
     @nn.nowrap
     def place_on_mesh(self, params, mesh):

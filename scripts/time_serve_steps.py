@@ -13,7 +13,10 @@ The configuration's `model_type` chooses the model and the shapes (`CELLS`):
 `kanana-2-30b-a3b-l8-serve` (the default; 31 of 32 slots at contexts of
 8,300-8,800, the chunk behind 8,192 cached tokens) or
 `falcon-h1-34b-l6-serve` (63 of 64 slots at contexts of ~290, the chunk
-FRESH, into a slot whose state and tail hold another request's leavings).
+FRESH, into a slot whose state and tail hold another request's leavings) or
+`mistral-7b-v0.3-l16-serve` (`models/llama.py`; 15 of 16 slots at contexts
+of 64-640, the cell's prompts and outputs, the chunk a fresh prompt of 160
+ids in its 512 rows).
 One JSON line on stdout, the same in `chiprun_out/serve_steps.json`. The
 model is the cell's configuration (`benchmarks/configs/<--config>.json`)
 with seeded weights and a cache of seeded rows; the programs are the
@@ -42,14 +45,18 @@ import numpy as np  # noqa: E402
 from ray_tpu.inference.engine import (EngineConfig,  # noqa: E402
                                       InferenceEngine)
 
-# model_type (also the model's module under `ray_tpu.models`) -> its class,
+# model_type -> the model's module under `ray_tpu.models` and its class,
 # the tokens cached before the chunk, the decode rows' contexts beyond them
-# (from, to) and the parts of its cache that hold rows.
+# (from, to) and the parts of its cache that hold rows (None: all of it).
+# The flax family takes its configuration from the cell's builder and its
+# parameters from an `init` that is shown ids.
 CELLS = {
-    "deepseek_v3": dict(cls="DeepseekV3", prefix=8192, contexts=(108, 609),
-                        rows=("latent",)),
-    "falcon_h1": dict(cls="FalconH1", prefix=0, contexts=(80, 500),
-                      rows=("kv", "ssm", "conv")),
+    "deepseek_v3": dict(module="deepseek_v3", cls="DeepseekV3", prefix=8192,
+                        contexts=(108, 609), rows=("latent",)),
+    "falcon_h1": dict(module="falcon_h1", cls="FalconH1", prefix=0,
+                      contexts=(80, 500), rows=("kv", "ssm", "conv")),
+    "mistral": dict(module="llama", cls="Llama", prefix=0,
+                    contexts=(64, 640), rows=None, flax=True),
 }
 
 
@@ -58,7 +65,7 @@ def build(args):
                            args.config + ".json")) as f:
         cfg = json.load(f)
     cell = CELLS[cfg["model_type"]]
-    module = import_module("ray_tpu.models." + cfg["model_type"])
+    module = import_module("ray_tpu.models." + cell["module"])
     model_cls = getattr(module, cell["cls"])
     config_cls = getattr(module, cell["cls"] + "Config")
     if args.rehearsal:
@@ -68,9 +75,20 @@ def build(args):
         cell = {**cell, "prefix": min(cell["prefix"], 128)}
         live = 10
     else:
-        model = model_cls(config_cls.from_published(cfg, dtype=jnp.bfloat16))
+        if cell.get("flax"):
+            from benchmarks import manifest
+
+            model = model_cls(manifest.builder_of(cfg).llama_config(cfg))
+        else:
+            model = model_cls(config_cls.from_published(
+                cfg, dtype=jnp.bfloat16))
         engine_cfg, live = cfg["engine"], args.live
-    params = model.init(jax.random.PRNGKey(args.seed % (2 ** 31)))
+    key = jax.random.PRNGKey(args.seed % (2 ** 31))
+    if cell.get("flax"):
+        params = jax.jit(lambda k: model.init(
+            k, jnp.zeros((1, 8), jnp.int32)))(key)
+    else:
+        params = model.init(key)
     engine = InferenceEngine(
         EngineConfig(prefix_cache_enabled=False, **engine_cfg), model=model,
         params=params)
@@ -116,11 +134,17 @@ def main() -> int:
     # buffer: weights and cache fill the chip.
     fill = jax.jit(lambda a, k: (0.5 * jax.random.normal(
         k, a.shape, jnp.float32)).astype(a.dtype), donate_argnums=0)
-    for part in cell["rows"]:
-        leaves, tree = jax.tree.flatten(engine._arenas[part])
+
+    def filled(rows):
+        leaves, tree = jax.tree.flatten(rows)
         keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
-        engine._arenas[part] = jax.tree.unflatten(
+        return jax.tree.unflatten(
             tree, [fill(a, k) for k, a in zip(keys, leaves)])
+
+    if cell["rows"] is None:
+        engine._arenas = filled(engine._arenas)
+    for part in cell["rows"] or ():
+        engine._arenas[part] = filled(engine._arenas[part])
     decode_args, chunk_args = arguments(engine, cell, live, args.seed)
     vocab = model.config.vocab_size
     engine._tokens = jnp.asarray(np.random.default_rng(args.seed).integers(

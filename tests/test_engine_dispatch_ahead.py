@@ -25,7 +25,19 @@ def tiny_llama():
 
     from ray_tpu.models.llama import Llama, LlamaConfig
 
-    model = Llama(LlamaConfig.tiny(seq=256))
+    class TwoPrograms(Llama):
+        """The fused step hidden (`PagedModel`'s "not offered"). These
+        tests hold the engine's tokens EQUAL to `plain`'s, which runs the
+        prefill and decode programs' own shapes; a chunk aboard a decode
+        step is another shape, the tiny model's bf16 products round
+        otherwise there (an argmax in ~200 moves), and which steps hold a
+        chunk follows the cancelling threads' timing. The fused step's
+        dispatch-ahead is `test_a_chunk_aboard_keeps_one_execution_in_flight`
+        below (integers), its tokens `tests/test_llama.py`."""
+
+        paged_step_with_chunk = None
+
+    model = TwoPrograms(LlamaConfig.tiny(seq=256))
     params = jax.jit(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))()
     return model, params

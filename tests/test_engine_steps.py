@@ -27,6 +27,19 @@ def tiny_llama():
     return model, params
 
 
+@pytest.fixture(scope="module")
+def two_program_llama(tiny_llama):
+    """The model with its fused step hidden (`PagedModel`'s "not
+    offered"): an engine over it keeps the prefill and decode programs
+    alone, for the tests that are about those two."""
+    model, params = tiny_llama
+
+    class TwoPrograms(type(model)):
+        paged_step_with_chunk = None
+
+    return TwoPrograms(model.config), params
+
+
 def _engine(tiny_llama, **kwargs):
     model, params = tiny_llama
     kwargs.setdefault("prefix_cache_enabled", False)
@@ -84,14 +97,19 @@ def test_phase_clock_costs_microseconds_a_step():
 
 def test_ledger_counts_what_the_requests_imply(tiny_llama):
     engine = _engine(tiny_llama, batch_slots=4, prefill_chunk=16)
-    decode_calls = []
-    decode_fn = engine._decode_fn
+    decode_calls, fused_calls = [], []
 
-    def counting(*args):
-        decode_calls.append(1)
-        return decode_fn(*args)
+    def counting(name, calls):
+        fn = getattr(engine, name)
 
-    engine._decode_fn = counting
+        def call(*args):
+            calls.append(1)
+            return fn(*args)
+
+        setattr(engine, name, call)
+
+    counting("_decode_fn", decode_calls)
+    counting("_decode_with_chunk_fn", fused_calls)
     prompts, budgets = (5, 20, 33), (3, 4, 5)
     reqs = [engine.add_request(_prompt(n, 7 * n), max_new_tokens=m)
             for n, m in zip(prompts, budgets)]
@@ -103,12 +121,17 @@ def test_ledger_counts_what_the_requests_imply(tiny_llama):
     steps = engine.stats()["steps"]
     assert steps == engine.step_stats()
     assert steps["n"] == ran
-    # One execution per chunk of 16.
-    assert steps["prefill"] == 1 + 2 + 3
+    # One execution per chunk of 16. Each request's LAST chunk runs alone:
+    # the first's finds no row decoding yet, and by the others' the one
+    # request ahead has its whole budget dispatched (2, then 3 decode
+    # rows, as many as the chunks before the last that rode with them).
+    assert steps["prefill"] + steps["chunks_aboard"] == 1 + 2 + 3
+    assert (steps["prefill"], steps["chunks_aboard"]) == (3, 1 + 2)
+    assert steps["chunks_aboard"] == len(fused_calls)
     # A request's first token comes from its last chunk, the rest from
     # decode rows.
     assert steps["decode_rows"] == sum(m - 1 for m in budgets)
-    assert steps["decode"] == len(decode_calls)
+    assert steps["decode"] == len(decode_calls) + len(fused_calls)
     assert steps["decode_rows"] <= steps["decode"] * 4
     assert set(steps["phase_s"]) == set(eng.PHASES)
     in_step = sum(v for k, v in steps["phase_s"].items()
@@ -260,8 +283,8 @@ def test_step_stats_does_not_wait_for_the_engine_lock(tiny_llama):
 
 
 def test_first_token_is_delivered_while_the_decode_of_its_step_runs(
-        tiny_llama):
-    engine = _engine(tiny_llama, batch_slots=4, prefill_chunk=16)
+        two_program_llama):
+    engine = _engine(two_program_llama, batch_slots=4, prefill_chunk=16)
     seen = []
     first = engine.add_request(_prompt(5, 1), max_new_tokens=40)
     while not first.generated:

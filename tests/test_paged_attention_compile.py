@@ -95,6 +95,79 @@ def test_mistral_decode_and_prefill_compile_with_the_kernel(one_chip,
         assert mem.alias_size_in_bytes >= cache, (b, s)
 
 
+def test_mistral_fused_step_compiles_with_the_kernel_twice_a_layer(
+        one_chip, monkeypatch):
+    """The engine's third program over `Llama.paged_step_with_chunk` at the
+    Mistral cells' sizes (16 decode rows and a chunk of 512 as ONE
+    execution): the kernel twice in every layer, once a row group at the
+    two programs' own shapes, every product over the 528 rows laid end to
+    end and the head over 17, the arenas updated in place, weights + cache
+    + little else inside the chip's memory."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import Llama
+    from ray_tpu.ops import attention
+
+    monkeypatch.syspath_prepend(ROOT)
+    from benchmarks.builders.llama_serve import llama_config
+
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "mistral-7b-v0.3-l16-serve.json")) as f:
+        config = json.load(f)
+    eng = config["engine"]
+    cfg = llama_config(config)
+    model = Llama(cfg)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    arena = spec((eng["num_blocks"], eng["block_size"], cfg.n_kv_head,
+                  cfg.head_dim), cfg.dtype)
+    arenas = [(arena, arena) for _ in range(cfg.n_layer)]
+    b, c, width = (eng["batch_slots"], eng["prefill_chunk"],
+                   eng["max_blocks_per_seq"])
+
+    # `decode_with_chunk_fn` of `InferenceEngine._build_programs`.
+    def step_fn(params, arenas, tokens, bt, pos, wmask, ids, chunk_bt,
+                chunk_pos, chunk_wmask, last_idx, slot):
+        logits, chunk_logits, arenas = model.paged_step_with_chunk(
+            params, tokens[:, None], ids, arenas, bt, pos, wmask, chunk_bt,
+            chunk_pos, chunk_wmask, slot, last_idx)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        first = jnp.argmax(chunk_logits, axis=-1).astype(jnp.int32)
+        return jnp.where(wmask[:, 0], nxt, tokens).at[slot].set(
+            first), arenas
+
+    i32 = jnp.int32
+    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, arenas, spec((b,), i32), spec((b, width), i32),
+        spec((b,), i32), spec((b, 1), jnp.bool_), spec((1, c), i32),
+        spec((1, width), i32), spec((1,), i32), spec((1, c), jnp.bool_),
+        spec((1,), i32), spec((1,), i32)).compile()
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and re.search(r"%paged_attention[.\d]* = ", line)]
+    assert len(kernels) == 2 * cfg.n_layer, len(kernels)
+    # The products run once over all rows, the head over b + 1.
+    assert f"[{b + c},{cfg.intermediate}]" in hlo
+    assert f"[{b + 1},{cfg.vocab_size}]" in hlo
+    assert f"[{b + c},{cfg.vocab_size}]" not in hlo
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    cache = 2 * cfg.n_layer * arena.size * arena.dtype.itemsize
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert weights + cache < need < weights + cache + 0.5e9 < HBM, need
+    assert mem.alias_size_in_bytes >= cache
+
+
 def test_kanana2_decode_and_prefill_compile_with_the_latent_kernels(
         one_chip, monkeypatch):
     """The engine's two programs over `DeepseekV3.paged_step` at the
